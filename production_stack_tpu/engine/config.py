@@ -52,8 +52,8 @@ class EngineConfig:
     # decode windows queued on the device at once (engine.step
     # pipelining). 2 keeps the device saturated in the common case:
     # window N+1 is queued while N runs, and the host processes N's
-    # tokens during N+1. Behind a high-RTT tunnel, 3 can buy extra
-    # overlap (host round-trips hide behind two device windows);
+    # tokens during N+1. 3 hides a host round-trip behind two device
+    # windows (whether a local chip needs that is not measured);
     # deeper queues add latency to composition changes (admission
     # waits behind every queued window).
     pipeline_depth: int = 2
@@ -141,11 +141,12 @@ class EngineConfig:
     # rather than serviced long after its useful-by time. None = never.
     max_queue_delay_ms: Optional[float] = None
     # Efficiency telemetry (engine/efficiency.py; docs/engine.md
-    # "Efficiency telemetry"): the HBM peak bandwidth the MBU gauge
-    # normalizes against (GB/s; v5e-class default — the 819 GB/s the
-    # measured steady state is quoted against in BASELINE.md), and the
-    # bounded ring of per-window breakdowns served on GET /debug/perf.
-    hbm_peak_gbps: float = 819.0
+    # "Efficiency telemetry"): the per-chip HBM peak bandwidth the MBU
+    # gauge normalizes against (GB/s). None = look it up by the
+    # device's kind (efficiency.HBM_PEAK_GBPS); a kind the table does
+    # not know reports no MBU. And the bounded ring of per-window
+    # breakdowns served on GET /debug/perf.
+    hbm_peak_gbps: Optional[float] = None
     perf_ring_entries: int = 256
 
     def __post_init__(self):
@@ -204,7 +205,7 @@ class EngineConfig:
         if self.max_queue_delay_ms is not None \
                 and self.max_queue_delay_ms <= 0:
             raise ValueError("max_queue_delay_ms must be positive")
-        if self.hbm_peak_gbps <= 0:
+        if self.hbm_peak_gbps is not None and self.hbm_peak_gbps <= 0:
             raise ValueError("hbm_peak_gbps must be positive")
         if self.perf_ring_entries < 1:
             raise ValueError("perf_ring_entries must be >= 1")

@@ -11,8 +11,8 @@ drafts burn the same HBM traffic and emit nothing. This module is the
 measure-before-optimize substrate for the roofline push (ROADMAP item
 2) and the fragmentation work (item 3): every decode window and prefill
 dispatch is classified into real / pad / dead token-steps, rolled into
-effective-bandwidth and MBU estimates against the configured HBM peak,
-and every XLA compile is stamped (kind, window, kv bucket, duration) so
+effective-bandwidth and MBU estimates against the device's HBM peak
+(HBM_PEAK_GBPS, by ``device_kind``), and every XLA compile is stamped (kind, window, kv bucket, duration) so
 a compile-stalled serving window is attributable instead of invisible.
 
 Design constraints (the r13 rules, verbatim):
@@ -34,9 +34,11 @@ The byte model is deliberately simple and documented (docs/engine.md
 "Efficiency telemetry"): one decode step streams the full weight set
 once plus, for every batch row, the KV prefix up to the window's kv
 bucket. Effective bytes are total bytes scaled by the window's live
-fraction; MBU is effective bytes/s over the configured
-``hbm_peak_gbps``. On CPU hosts the absolute numbers are meaningless
-but the *fractions* (live/pad/dead) are exact.
+fraction; MBU is effective bytes/s over the device's peak — looked up
+by ``device_kind`` (or ``--hbm-peak-gbps``), and reported as absent
+(``None``) for a device the table does not know: a CPU's or an unknown
+chip's bytes/s are never divided by another device's peak. The
+*fractions* (live/pad/dead) are exact on any device.
 """
 
 import collections
@@ -48,6 +50,14 @@ from typing import Callable, Dict, List, Optional, Tuple
 # not milliseconds — a distinct bucket ladder from PHASE_BUCKETS
 COMPILE_BUCKETS: Tuple[float, ...] = (
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0)
+
+# Peak HBM bandwidth in GB/s by ``jax.Device.device_kind`` — the ONE
+# peaks table; a kind that is not here has no MBU. Source: Google Cloud
+# documentation, "TPU v5e" system architecture (819 GB/s per chip).
+HBM_PEAK_GBPS: Dict[str, float] = {
+    "TPU v5 lite": 819.0,
+}
+
 
 # KV-pool occupancy observed at allocation time (fraction of non-trash
 # blocks held by live sequences)
@@ -74,14 +84,15 @@ class EngineEffAccounting:
 
     def __init__(self, *, weight_bytes: int = 0,
                  kv_position_bytes: int = 0,
-                 hbm_peak_bytes_per_s: float = 0.0,
+                 hbm_peak_bytes_per_s: Optional[float] = None,
                  ring_entries: int = 256,
                  compile_hist=None,
                  now_fn: Callable[[], float] = time.monotonic,
                  wall_fn: Callable[[], float] = time.time):
         self.weight_bytes = int(weight_bytes)
         self.kv_position_bytes = int(kv_position_bytes)
-        self.hbm_peak_bytes_per_s = float(hbm_peak_bytes_per_s)
+        # None = no known peak for this device: MBU is not reported
+        self.hbm_peak_bytes_per_s = hbm_peak_bytes_per_s
         self.compile_hist = compile_hist
         self._now = now_fn
         self._wall = wall_fn
@@ -239,8 +250,8 @@ class EngineEffAccounting:
         """Ring-derived recent rates: effective/total bytes per
         wall-clock second over the last ``horizon_s`` (idle time counts
         against the rate — this is what a roofline comparison wants),
-        MBU against the configured peak, and the recent live
-        fraction.
+        MBU against the device's peak (None when that is unknown), and
+        the recent live fraction.
 
         The divisor is clamped to what the ring can actually witness:
         uptime when younger than the horizon, and — on a busy engine
@@ -274,7 +285,7 @@ class EngineEffAccounting:
             "total_bytes_per_s": round(tot / window, 1),
             "mbu_perc": round(100.0 * eff_rate
                               / self.hbm_peak_bytes_per_s, 4)
-            if self.hbm_peak_bytes_per_s > 0 else 0.0,
+            if self.hbm_peak_bytes_per_s else None,
             "live_fraction": round(real / all_steps, 6)
             if all_steps else 0.0,
             "decode_tokens_per_s": round(real / window, 3),

@@ -207,7 +207,8 @@ class EngineMetrics:
         self.mbu_perc = gauge(
             "tpu:engine_mbu_perc",
             "Model-bandwidth utilization: effective bytes/s over the "
-            "configured --hbm-peak-gbps (0-100)")
+            "device kind's HBM peak or --hbm-peak-gbps (0-100; NaN "
+            "when no peak is known for the device)")
         self.decode_live_fraction = gauge(
             "tpu:decode_window_live_fraction",
             "Recent fraction of decode token-steps that emitted a "
@@ -400,7 +401,8 @@ class EngineMetrics:
         self.compile_in_flight.set(report.get("compile_in_flight", 0))
         self.effective_bytes_per_s.set(
             rates.get("effective_bytes_per_s", 0.0))
-        self.mbu_perc.set(rates.get("mbu_perc", 0.0))
+        mbu = rates.get("mbu_perc")
+        self.mbu_perc.set(float("nan") if mbu is None else mbu)
         self.decode_live_fraction.set(rates.get("live_fraction", 0.0))
 
     def sync_kvpool(self, report: dict) -> None:

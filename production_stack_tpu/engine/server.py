@@ -30,8 +30,8 @@ from production_stack_tpu.engine.engine import (AdmissionRejected,
 from production_stack_tpu.engine.scheduler import SamplingOptions
 from production_stack_tpu.tracing import (TraceRecorder,
                                           debug_traces_handler)
-from production_stack_tpu.utils import (honor_platform_env, init_logger,
-                                          set_ulimit)
+from production_stack_tpu.utils import (init_logger, place_compile_cache,
+                                        set_ulimit)
 from production_stack_tpu.version import __version__
 
 logger = init_logger(__name__)
@@ -1133,10 +1133,12 @@ async def version(request: web.Request) -> web.Response:
 async def debug_perf(request: web.Request) -> web.Response:
     """``GET /debug/perf``: the engine-efficiency ring — recent
     window-level real/pad/dead breakdowns, recent XLA compile events,
-    cumulative totals + rates, and the KV block pool's fragmentation
-    census. Aggregate-only data, but served under the same auth
-    posture as /debug/traces (the /debug namespace is operator
-    surface, not probe surface). Query param ``limit=N`` bounds the
+    cumulative totals + rates, the KV block pool's fragmentation
+    census, and the ``device`` block (platform, device kind and count,
+    bytes in use per device, the attention path of every compiled
+    executable — LLMEngine.device_report). Aggregate-only data, but
+    served under the same auth posture as /debug/traces (the /debug
+    namespace is operator surface, not probe surface). Query param ``limit=N`` bounds the
     rings returned (default 50)."""
     engine = request.app[ENGINE_KEY]
     eng = engine.engine
@@ -1145,6 +1147,7 @@ async def debug_perf(request: web.Request) -> web.Response:
     except ValueError:
         limit = 50
     return web.json_response({
+        "device": eng.device_report(),
         "totals": eng.eff.report(),
         "rates": eng.eff.rates(),
         "windows": eng.eff.recent_windows(limit),
@@ -1442,8 +1445,8 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "decode step (0 = off)")
     p.add_argument("--pipeline-depth", type=int, default=2,
                    help="decode windows queued on the device at once; "
-                        "3 hides more host/tunnel RTT behind device "
-                        "work at the cost of admission latency")
+                        "3 hides one more host round-trip behind "
+                        "device work at the cost of admission latency")
     p.add_argument("--dp-gather-attention-ok", action="store_true",
                    help="acknowledge serving on a dp>1 mesh WITHOUT "
                         "the paged attention kernel (gathered-view "
@@ -1498,11 +1501,13 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--lora-targets", default="q,v",
                    help="comma-separated projections to adapt "
                         "(q,k,v,o,gate,up,down)")
-    p.add_argument("--hbm-peak-gbps", type=float, default=819.0,
-                   help="HBM peak bandwidth the tpu:engine_mbu_perc "
-                        "gauge normalizes effective bytes/s against "
-                        "(GB/s; set to the serving chip's datasheet "
-                        "number)")
+    p.add_argument("--hbm-peak-gbps", type=float, default=None,
+                   help="per-chip HBM peak bandwidth the "
+                        "tpu:engine_mbu_perc gauge normalizes effective "
+                        "bytes/s against (GB/s). Default: looked up by "
+                        "the device's kind (engine/efficiency.py; v5e "
+                        "819); a kind the table does not know reports "
+                        "no MBU")
     p.add_argument("--perf-ring-entries", type=int, default=256,
                    help="window-level efficiency breakdowns kept in "
                         "memory (bounded ring on GET /debug/perf)")
@@ -1528,7 +1533,7 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 def main(argv=None) -> None:
     args = parse_args(argv)
-    honor_platform_env()
+    logger.info("compile cache: %s", place_compile_cache())
     set_ulimit()
     kv_transfer = json.loads(args.kv_transfer_config) \
         if args.kv_transfer_config else None

@@ -1,7 +1,9 @@
 """ctypes loader for the native KV store (native/pskv.cpp).
 
-Looks for ``native/build/libpskv.so`` relative to the repo root, building it
-with ``make`` on first use when a toolchain is present. Every consumer falls
+Loads ``native/build/libpskv.so`` relative to the repo root, running
+``make`` on first use when a toolchain is present — ``native/build/`` is
+not committed, so make builds it where it is missing and rebuilds it
+where ``native/*.cpp`` is newer: never a stale binary. Every consumer falls
 back to a pure-Python store when the library is unavailable
 (store.HostMemoryStore picks the backend), so the stack stays importable on
 machines without g++.
@@ -94,8 +96,7 @@ def load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            _make("build/libpskv.so")
+        _make("build/libpskv.so")    # no-op when up to date
         try:
             _lib = _configure(ctypes.CDLL(_LIB_PATH))
         except AttributeError:

@@ -523,7 +523,10 @@ def cmd_effwatch(args) -> int:
               f"tok/s vs client {d['client_decode_tokens_per_s']} "
               f"(fraction sum {d['fraction_sum']}, live fraction "
               f"{d['live_fraction_steady']}, mbu "
-              f"{d['mbu_perc_steady']}%, 0 steady compiles, 0 errors)")
+              + ("not reported (no HBM peak for this device)"
+                 if d['mbu_perc_steady'] is None
+                 else f"{d['mbu_perc_steady']}%")
+              + ", 0 steady compiles, 0 errors)")
     return 1 if violations else 0
 
 
@@ -1094,8 +1097,12 @@ def build_parser() -> argparse.ArgumentParser:
                     help="measured window per replica point")
     sp.add_argument("--users-per-replica", type=int, default=None)
     sp.add_argument("--platform", default="cpu",
-                    help="JAX_PLATFORMS for engine processes ('' to "
-                         "inherit, e.g. for TPU)")
+                    help="JAX_PLATFORMS for real-engine children. The "
+                         "drills run on the CPU; a chip belongs to one "
+                         "process, so a second real-engine child off "
+                         "the CPU is refused (orchestrator."
+                         "launch_engine) — chip runs are "
+                         "chip_smoke.py's")
     sp.add_argument("--log-dir", default="loadgen-logs")
     sp.add_argument("--startup-timeout", type=float, default=420.0)
     # the scaleout preset is sized to the engine geometry the

@@ -68,11 +68,26 @@ def _spawn(name: str, cmd: List[str], url: str, log_dir: str,
     return Proc(name=name, popen=popen, url=url, log_path=log_path)
 
 
+# real-engine children launched off the CPU: each claims every chip of
+# the host at start-up, so a second one alive at the same time fails or
+# hangs inside JAX with nothing to say why. launch_engine refuses it
+# here instead. (Replicas on one host's chips need an engine pinned to
+# a device in-process — ROADMAP R5.)
+_chip_owners: List[subprocess.Popen] = []
+
+
 def launch_engine(kind: str, port: int, *, log_dir: str,
                   platform: str = "cpu",
-                  extra_args: Optional[List[str]] = None) -> Proc:
+                  extra_args: Optional[List[str]] = None,
+                  geometry: Optional[List[str]] = None,
+                  env: Optional[Dict[str, str]] = None) -> Proc:
     """kind "fake" -> tests/fake_engine.py mock; anything else is a
-    model name served by the real engine server."""
+    model name served by the real engine server at ``geometry``
+    (default ENGINE_ARGS, the CPU drills' small one). ``platform``
+    becomes the child's JAX_PLATFORMS ("" = inherit, i.e. the chip
+    where there is one); the launcher itself never imports JAX. A chip
+    belongs to one process: a second chip-owning child while one is
+    alive raises."""
     url = f"http://127.0.0.1:{port}"
     if kind == "fake":
         # defaults pace the mock like a tiny real engine; extra_args
@@ -85,9 +100,26 @@ def launch_engine(kind: str, port: int, *, log_dir: str,
         return _spawn(f"engine-fake-{port}", cmd, url, log_dir)
     cmd = [sys.executable, "-m", "production_stack_tpu.engine.server",
            "--model", kind, "--host", "127.0.0.1", "--port", str(port),
-           *ENGINE_ARGS, *(extra_args or [])]
-    env = {"JAX_PLATFORMS": platform} if platform else {}
-    return _spawn(f"engine-{kind}-{port}", cmd, url, log_dir, env=env)
+           *(ENGINE_ARGS if geometry is None else geometry),
+           *(extra_args or [])]
+    env = dict(env or {})
+    if platform:
+        env["JAX_PLATFORMS"] = platform
+    on_chip = platform != "cpu"
+    if on_chip:
+        _chip_owners[:] = [p for p in _chip_owners if p.poll() is None]
+        if _chip_owners:
+            raise RuntimeError(
+                f"engine child pid {_chip_owners[0].pid} already owns "
+                f"this host's chip(s): a chip belongs to one process at "
+                f"a time, so a second chip-owning engine "
+                f"(JAX_PLATFORMS={platform or 'inherited'!r}) would "
+                f"fail or hang. Run replicas with platform='cpu', or "
+                f"one engine across chips with --tensor-parallel-size")
+    proc = _spawn(f"engine-{kind}-{port}", cmd, url, log_dir, env=env)
+    if on_chip:
+        _chip_owners.append(proc.popen)
+    return proc
 
 
 def launch_cache_server(port: int, *, log_dir: str,

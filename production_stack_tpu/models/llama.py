@@ -17,6 +17,7 @@ The reference repo contains no model code (models are strings passed to
 this module is the TPU-native engine's compute core.
 """
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -35,25 +36,62 @@ from production_stack_tpu.ops.rope import apply_rope, rope_table
 Params = Dict[str, Any]
 
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Random init (normal 0.02) in cfg.dtype, stacked-layer layout."""
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "quantizer"))
+def _random_leaf(key: jax.Array, *, shape: Tuple[int, ...], dtype,
+                 quantizer=None):
+    """One weight leaf, built (and quantized) in one executable: the
+    float32 draw fuses into the cast, so the transient is the leaf in
+    ``dtype`` plus its int8 copy — never a float32 tensor, never a
+    second full-precision tree."""
+    # reduce_precision to float32's own 8/23 bits is an exact no-op that
+    # XLA does not reassociate across: without it the 0.02 is folded
+    # into the draw's own sqrt(2) and a quarter of the values move by
+    # one float32 step — enough to flip a near-tied greedy token in the
+    # seeded parity tests, which were written against the unfused
+    # ``normal(key) * 0.02``
+    w = jax.lax.reduce_precision(
+        jax.random.normal(key, shape, jnp.float32), 8, 23) * 0.02
+    # round to ``dtype`` where XLA cannot elide it either: a bare
+    # f32 -> bf16 -> f32 convert pair in front of the quantizer is
+    # simplified away, and the int8 leaf would not be that of the
+    # bf16 weights
+    info = jnp.finfo(dtype)
+    w = jax.lax.reduce_precision(w, info.nexp, info.nmant).astype(dtype)
+    return w if quantizer is None else quantizer(w)
+
+
+def init_params(cfg: ModelConfig, key: jax.Array,
+                quantization: Optional[str] = None) -> Params:
+    """Random init (normal 0.02) in cfg.dtype, stacked-layer layout.
+
+    ``quantization="int8"`` quantizes every leaf models/quant.py would
+    AS IT IS MADE — what ``quant.quantize_params`` gives over the plain
+    tree (to a rounding step), without that tree ever existing. A 7B-width model is
+    14.5 GB in bf16: built tree-then-tree it cannot reach its 7.3 GB
+    int8 form on a 16 GB chip; leaf by leaf the peak is the finished
+    leaves plus one leaf's transient."""
     h, i, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     nh, nkv, hd, L = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_, cfg.num_layers
     keys = iter(jax.random.split(key, 16))
+    if quantization not in (None, "int8"):
+        raise ValueError(f"quantization={quantization!r} unsupported")
 
-    def w(k, shape):
-        return (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(cfg.dtype)
+    def w(k, shape, *path):
+        # path = the leaf's place in the tree: quant.leaf_quantizer
+        # holds the one rule for which leaves quantize, and how
+        q = quant.leaf_quantizer(path) if quantization else None
+        return _random_leaf(k, shape=shape, dtype=cfg.dtype, quantizer=q)
 
     norm_init = jnp.zeros if cfg.rms_norm_offset else jnp.ones
     E = cfg.num_experts
     params: Params = {
-        "embed": w(next(keys), (v, h)),
+        "embed": w(next(keys), (v, h), "embed"),
         "layers": {
             "attn_norm": norm_init((L, h), cfg.dtype),
-            "q": w(next(keys), (L, h, nh * hd)),
-            "k": w(next(keys), (L, h, nkv * hd)),
-            "v": w(next(keys), (L, h, nkv * hd)),
-            "o": w(next(keys), (L, nh * hd, h)),
+            "q": w(next(keys), (L, h, nh * hd), "layers", "q"),
+            "k": w(next(keys), (L, h, nkv * hd), "layers", "k"),
+            "v": w(next(keys), (L, h, nkv * hd), "layers", "v"),
+            "o": w(next(keys), (L, nh * hd, h), "layers", "o"),
             "mlp_norm": norm_init((L, h), cfg.dtype),
         },
         "final_norm": norm_init((h,), cfg.dtype),
@@ -67,24 +105,24 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if E:
         mi = cfg.moe_intermediate_size or i
         params["layers"].update({
-            "gate": w(next(keys), (L, E, h, mi)),
-            "up": w(next(keys), (L, E, h, mi)),
-            "down": w(next(keys), (L, E, mi, h)),
-            "router": w(next(keys), (L, h, E)),
+            "gate": w(next(keys), (L, E, h, mi), "layers", "gate"),
+            "up": w(next(keys), (L, E, h, mi), "layers", "up"),
+            "down": w(next(keys), (L, E, mi, h), "layers", "down"),
+            "router": w(next(keys), (L, h, E), "layers", "router"),
         })
         if cfg.shared_expert_size:
             si = cfg.shared_expert_size
             params["layers"].update({
-                "s_gate": w(next(keys), (L, h, si)),
-                "s_up": w(next(keys), (L, h, si)),
-                "s_down": w(next(keys), (L, si, h)),
-                "s_gate_w": w(next(keys), (L, h, 1)),
+                "s_gate": w(next(keys), (L, h, si), "layers", "s_gate"),
+                "s_up": w(next(keys), (L, h, si), "layers", "s_up"),
+                "s_down": w(next(keys), (L, si, h), "layers", "s_down"),
+                "s_gate_w": w(next(keys), (L, h, 1), "layers", "s_gate_w"),
             })
     else:
         params["layers"].update({
-            "gate": w(next(keys), (L, h, i)),
-            "up": w(next(keys), (L, h, i)),
-            "down": w(next(keys), (L, i, h)),
+            "gate": w(next(keys), (L, h, i), "layers", "gate"),
+            "up": w(next(keys), (L, h, i), "layers", "up"),
+            "down": w(next(keys), (L, i, h), "layers", "down"),
         })
     if cfg.attention_bias:
         # Qwen2: biases on the q/k/v projections only
@@ -92,8 +130,40 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         params["layers"]["k_bias"] = jnp.zeros((L, nkv * hd), cfg.dtype)
         params["layers"]["v_bias"] = jnp.zeros((L, nkv * hd), cfg.dtype)
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = w(next(keys), (h, v))
+        params["lm_head"] = w(next(keys), (h, v), "lm_head")
     return params
+
+
+# the gathered-copy jax.numpy attention (ops/attention.py): the test
+# reference, and the serving path only where attention_path says so
+JNP_GATHER = "jnp_gather"
+
+
+def attention_path(cfg: ModelConfig, T: int, block_size: int,
+                   use_flash: bool, mesh=None) -> str:
+    """Which cached-attention implementation a forward over T query
+    positions per row takes. Decided here, by shape, BEFORE anything
+    compiles — a kernel the compiler then refuses is an error, not a
+    reason to take another path (engine/runner.py records this per
+    executable; GET /debug/perf shows it).
+
+    ``pallas_paged_decode``: short windows (decode / speculative
+    verify) on the wide kernel — all kv heads + several pool blocks per
+    grid step, ~16x fewer grid steps than the general one.
+    ``pallas_paged``: prefill chunks on the general paged kernel.
+    ``*_sharded``: either, shard-local per head under a tp-only mesh.
+    ``jnp_gather``: the kernel is off (PSTPU_FLASH / not a TPU), the
+    chunk's working set misses VMEM (paged_viable), or the mesh shards
+    the pool's block axis."""
+    if not (use_flash
+            and pallas_paged.paged_viable(
+                T, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
+                block_size)
+            and (mesh is None or pallas_paged.mesh_tp_only(mesh))):
+        return JNP_GATHER
+    kernel = ("pallas_paged_decode" if T <= pallas_paged.DECODE_T_MAX
+              else "pallas_paged")
+    return kernel + ("_sharded" if mesh is not None else "")
 
 
 def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
@@ -189,11 +259,10 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
         MB = block_tables.shape[1]
         nb = MB if kv_len is None else min(-(-kv_len // Bs), MB)
 
+        path = attention_path(cfg, T, Bs, use_flash, mesh)
+
         def cached_attn(w):
-            if (use_flash
-                    and pallas_paged.paged_viable(T, nh // nkv, hd, Bs)
-                    and (mesh is None
-                         or pallas_paged.mesh_tp_only(mesh))):
+            if path != JNP_GATHER:
                 # paged flash kernel: K/V blocks streamed straight from
                 # the pool through the tables — no gathered copy, no
                 # [T, S] score materialization, per-row causal block
@@ -208,12 +277,8 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                 sc["scale"] = scale_val
                 sc["softcap"] = cap or 0.0
                 if mesh is None:
-                    # short windows (decode / speculative verify) take
-                    # the wide kernel: all kv heads + several pool
-                    # blocks per grid step, ~16x fewer grid steps than
-                    # the general one
                     paged_fn = (pallas_paged.paged_decode_attention
-                                if T <= pallas_paged.DECODE_T_MAX
+                                if path == "pallas_paged_decode"
                                 else pallas_paged.paged_attention)
                     return paged_fn(
                         q, k_cache, v_cache, block_tables, starts,

@@ -72,19 +72,31 @@ def dequant_matmul(x: jnp.ndarray, w: Any, dtype=None) -> jnp.ndarray:
     return y * w["scale"].astype(dtype)
 
 
+def leaf_quantizer(path):
+    """The quantizer the standard int8 recipe applies to the leaf at
+    ``path`` — ("embed",), ("lm_head",) or ("layers", name) in the
+    models/llama.py layout — or None where the leaf stays in the model
+    dtype. Embed quantizes per row so the gather and tied-lm_head roles
+    share one scale axis."""
+    if path == ("embed",):
+        return quantize_embed
+    if path == ("lm_head",) or (path[0] == "layers"
+                                and path[1] not in _SKIP_LAYER):
+        return quantize_tensor
+    return None
+
+
 def quantize_params(params: Dict[str, Any]) -> Dict[str, Any]:
     """Quantize a stacked-params pytree (models/llama.py layout) in the
-    standard int8 recipe. Returns a new pytree; embed quantizes per
-    row so the gather and tied-lm_head roles share one scale axis."""
-    out: Dict[str, Any] = {"final_norm": params["final_norm"]}
-    out["embed"] = quantize_embed(params["embed"])
-    if "lm_head" in params:
-        out["lm_head"] = quantize_tensor(params["lm_head"])
-    layers: Dict[str, Any] = {}
-    for name, w in params["layers"].items():
-        layers[name] = (w if name in _SKIP_LAYER
-                        else quantize_tensor(w))
-    out["layers"] = layers
+    standard int8 recipe (leaf_quantizer). Returns a new pytree."""
+    def q(w, *path):
+        fn = leaf_quantizer(path)
+        return w if fn is None else fn(w)
+
+    out = {name: q(w, name) for name, w in params.items()
+           if name != "layers"}
+    out["layers"] = {name: q(w, "layers", name)
+                     for name, w in params["layers"].items()}
     return out
 
 

@@ -42,7 +42,6 @@ which runs the same kernel in interpret mode on CPU.
 
 import functools
 import os
-from contextlib import contextmanager
 
 import jax
 import jax.numpy as jnp
@@ -70,15 +69,13 @@ _override = None
 
 
 def set_flash_enabled(value) -> None:
-    """Force-enable/disable (True/False) or restore auto (None). Used by
-    the runner to fall back if the kernel fails to compile on a backend."""
+    """Force-enable/disable (True/False) or restore auto (None): tests
+    run the kernels in interpret mode on the CPU through this."""
     global _override
     _override = value
 
 
 def flash_enabled() -> bool:
-    if _force_jnp_depth:
-        return False
     if _override is not None:
         return _override
     env = os.environ.get("PSTPU_FLASH", "auto").lower()
@@ -89,24 +86,6 @@ def flash_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
-# scoped override: the runner retries a SINGLE failed executable on the
-# jnp path without disabling the kernel for every other (shape, bucket)
-# combination — compilation failures are per-geometry (e.g. a VMEM
-# budget miss at one chunk size), not per-backend
-_force_jnp_depth = 0
-
-
-@contextmanager
-def force_jnp():
-    """Scoped flash_enabled() == False, for per-executable fallback."""
-    global _force_jnp_depth
-    _force_jnp_depth += 1
-    try:
-        yield
-    finally:
-        _force_jnp_depth -= 1
-
-
 def flash_viable(S: int, D: int, itemsize: int = 2) -> bool:
     """Can this kv-length/head-dim keep a K and a V panel in VMEM?"""
     return S * D * itemsize <= _VMEM_PANEL_BYTES
@@ -115,6 +94,14 @@ def flash_viable(S: int, D: int, itemsize: int = 2) -> bool:
 def needs_interpret() -> bool:
     """Interpret everywhere but real TPU (kernel targets TPU tiling)."""
     return jax.default_backend() != "tpu"
+
+
+def mode() -> str:
+    """"compiled" | "interpret" | "off": how the Pallas attention
+    kernels run in this process, for logs and GET /debug/perf."""
+    if not flash_enabled():
+        return "off"
+    return "interpret" if needs_interpret() else "compiled"
 
 
 def _flash_kernel(starts_ref, q_ref, k_ref, v_ref, out_ref, *,

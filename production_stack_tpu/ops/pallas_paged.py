@@ -84,7 +84,8 @@ def _paged_kernel(tabs_ref, starts_ref, q_ref, k_ref, v_ref, *refs,
     q_ref   [1, BQ, 1, G, D]       this kv-head's query block
     k_ref   [1, 1, Bs, D]          pool block tabs[b, min(j, jmax)]
     v_ref   [1, 1, Bs, D]
-    refs    (quant only: ks/vs dequant scales [1, 1, Bs] fp32,)
+    refs    (quant only: ks/vs dequant scales [1, Hkv, Bs] fp32 —
+            every kv head of the block, this step reads row h,)
             out [1, BQ, 1, G, D], scratch m/l/acc (online softmax
             state across j)
     """
@@ -93,6 +94,7 @@ def _paged_kernel(tabs_ref, starts_ref, q_ref, k_ref, v_ref, *refs,
         refs = refs[2:]
     out_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
+    h = pl.program_id(1)
     qi = pl.program_id(2)
     j = pl.program_id(3)
     rows = block_q * groups
@@ -127,8 +129,8 @@ def _paged_kernel(tabs_ref, starts_ref, q_ref, k_ref, v_ref, *refs,
         v_blk = v_ref[0, 0].astype(jnp.float32)
         if quant:
             # int8 pool: dequantize the panel in VMEM (per-token scale)
-            k_blk = k_blk * ks_ref[0, 0][:, None]
-            v_blk = v_blk * vs_ref[0, 0][:, None]
+            k_blk = k_blk * ks_ref[0, h][:, None]
+            v_blk = v_blk * vs_ref[0, h][:, None]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)               # [rows, Bs]
@@ -224,8 +226,11 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
         return (tabs[b, jj], h, 0, 0)
 
     def scale_index(b, h, qi, j, tabs, sts):
-        blk, hh, _, _ = kv_index(b, h, qi, j, tabs, sts)
-        return (blk, hh, 0)
+        # the whole head axis rides in the block: the TPU lowering
+        # wants a block's second-minor dim to be a multiple of 8 or the
+        # full axis, and one head's [1, Bs] row is neither
+        blk, _, _, _ = kv_index(b, h, qi, j, tabs, sts)
+        return (blk, 0, 0)
 
     grid = (B, Hkv, nq, nb)
     kernel = functools.partial(
@@ -242,7 +247,7 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     ]
     operands = [q5, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, Bs), scale_index)] * 2
+        in_specs += [pl.BlockSpec((1, Hkv, Bs), scale_index)] * 2
         operands += [k_scales, v_scales]
     out = pl.pallas_call(
         kernel,
@@ -523,7 +528,6 @@ def paged_attention_sharded(q, k_pool, v_pool, tables, starts, mesh, *,
     path. int8 pools pass their [N, Hkv, Bs] scales, sharded over the
     same head axis."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     base = (paged_decode_attention if q.shape[1] <= DECODE_T_MAX
             else paged_attention)
@@ -542,11 +546,11 @@ def paged_attention_sharded(q, k_pool, v_pool, tables, starts, mesh, *,
         fn = functools.partial(base, nb=nb, interpret=interpret,
                                window=window, scale=scale,
                                softcap=softcap)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=in_specs,
         out_specs=P(None, None, "tp", None),
-        check_rep=False)(*args)
+        check_vma=False)(*args)
 
 
 def mesh_tp_only(mesh) -> bool:

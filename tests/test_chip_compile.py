@@ -1,0 +1,271 @@
+"""Compile-only checks for the TPU v5e, made without the chip.
+
+Every other kernel test runs Pallas in interpret mode on the CPU, and
+the interpreter accepts what the TPU's compiler refuses (the int8-KV
+scale block of the general paged kernel passed every interpret test and
+was refused at every prefill chunk size). The TPU compiler is installed
+wherever libtpu is, and compiles for a chip that is described and not
+attached (``on-chip-measurement`` guide, section 2.3). These tests hand
+the serving path's kernels to it at the real widths — Mistral-7B head
+geometry (32 q / 8 kv heads, head dim 128, block 64) and the 16/16-head
+MoE geometry — one to two seconds each, and skip where the topology
+cannot be described. A compile that passes is not a chip run.
+
+``-m slow`` adds one whole decode window and one whole prefill step of
+the runner at Mistral-7B widths (int8 weights, all 32 layers), on one
+chip and on the tp=4 mesh, read against the chip's 16 GB: the rehearsal
+to make before spending chip time on a change to the step programs.
+"""
+
+import os
+from functools import partial
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler
+#                                                   logs under /tmp
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from production_stack_tpu.ops import pallas_paged
+from production_stack_tpu.parallel.mesh import AXES
+
+D, BS = 128, 64          # head dim, KV block size (the engine default)
+MB = 32                  # table width: 2048-token slots
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described v5e 2x2 host, persistent compile cache off: a
+    compile for a described device is written to the cache but cannot
+    be read back without a chip, and the next one would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu / no such topology here
+        pytest.skip(f"TPU v5e topology cannot be described: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _placements(topo, tp: int):
+    """Shardings for (q, pool, replicated, scales): one described chip,
+    or head-sharded over a tp mesh of the four."""
+    if tp == 1:
+        one = SingleDeviceSharding(topo.devices[0])
+        return None, (one, one, one, one)
+    devs = np.array(topo.devices[:tp]).reshape(
+        [tp if a == "tp" else 1 for a in AXES])
+    mesh = Mesh(devs, AXES)
+    return mesh, (NamedSharding(mesh, P(None, None, "tp", None)),
+                  NamedSharding(mesh, P(None, "tp", None, None)),
+                  NamedSharding(mesh, P()),
+                  NamedSharding(mesh, P(None, "tp", None)))
+
+
+def _compile_attention(topo, *, B, T, H, Hkv, int8, window=0, tp=1):
+    """Lower + compile the attention call the serving path makes for
+    this shape (llama.attention_path's choice of kernel) and return the
+    compiled executable."""
+    mesh, (q_sh, kv_sh, rep_sh, sc_sh) = _placements(topo, tp)
+    n_blocks = B * MB + 1
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=q_sh)
+    pool = jax.ShapeDtypeStruct(
+        (n_blocks, Hkv, BS, D), jnp.int8 if int8 else jnp.bfloat16,
+        sharding=kv_sh)
+    tables = jax.ShapeDtypeStruct((B, MB), jnp.int32, sharding=rep_sh)
+    starts = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rep_sh)
+    args = [q, pool, pool, tables, starts]
+    if int8:
+        scales = jax.ShapeDtypeStruct((n_blocks, Hkv, BS), jnp.float32,
+                                      sharding=sc_sh)
+        args += [scales, scales]
+    if mesh is not None:
+        kernel = partial(pallas_paged.paged_attention_sharded, mesh=mesh)
+    elif T <= pallas_paged.DECODE_T_MAX:
+        kernel = pallas_paged.paged_decode_attention
+    else:
+        kernel = pallas_paged.paged_attention
+
+    def call(q, k, v, tables, starts, ks=None, vs=None):
+        return kernel(q, k, v, tables, starts, nb=MB, window=window,
+                      k_scales=ks, v_scales=vs)
+
+    return jax.jit(call).lower(*args).compile()
+
+
+KV = pytest.mark.parametrize("int8", [False, True],
+                             ids=["kv_bf16", "kv_int8"])
+
+
+@KV
+@pytest.mark.parametrize("B", [8, 32])
+def test_wide_decode_kernel_compiles(topo, B, int8):
+    _compile_attention(topo, B=B, T=1, H=32, Hkv=8, int8=int8)
+
+
+@KV
+@pytest.mark.parametrize("window", [0, 4096])
+@pytest.mark.parametrize("T", [16, 128, 512])
+def test_general_paged_kernel_compiles(topo, T, window, int8):
+    # int8: the dequant scales ride as [1, Hkv, Bs] blocks — one head's
+    # [1, Bs] row is neither 8-aligned nor the whole axis, and the TPU
+    # lowering refuses it
+    _compile_attention(topo, B=8, T=T, H=32, Hkv=8, int8=int8,
+                       window=window)
+
+
+@KV
+@pytest.mark.parametrize("T", [1, 128])
+def test_mha_16_16_geometry_compiles(topo, T, int8):
+    """Qwen1.5-MoE attention geometry: 16 q / 16 kv heads (G = 1)."""
+    _compile_attention(topo, B=8, T=T, H=16, Hkv=16, int8=int8)
+
+
+@KV
+@pytest.mark.parametrize("T", [1, 128])
+def test_tp4_sharded_wrapper_compiles(topo, T, int8):
+    """shard_map over the head axis on a mesh of the four described
+    chips: the kernel stays one custom call per shard, and the wrapper
+    adds no collective."""
+    hlo = _compile_attention(topo, B=8, T=T, H=32, Hkv=8, int8=int8,
+                             tp=4).as_text()
+    assert "tpu_custom_call" in hlo
+    for collective in ("all-reduce", "all-gather", "all-to-all",
+                       "collective-permute"):
+        assert collective + "(" not in hlo, collective
+
+
+# ---------------------------------------------------------------------
+# whole step programs of the runner (slow: ~1-2 min each)
+# ---------------------------------------------------------------------
+
+@pytest.fixture
+def tpu_branches(monkeypatch):
+    """The serving path asks ``jax.default_backend()`` which attention
+    to take and whether to interpret the kernel, and here that answers
+    "cpu": steer both gates to their TPU answers for the compile."""
+    from production_stack_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_override", True)
+    monkeypatch.setattr(pallas_attention, "needs_interpret",
+                        lambda: False)
+
+
+def _runner_shapes(topo, tp: int):
+    """A ModelRunner skeleton (no arrays: a described device cannot
+    hold one) plus ShapeDtypeStructs of its params and KV pool at
+    Mistral-7B widths — int8 weights, all 32 layers, the server's
+    default geometry — placed as the runner places them."""
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.engine.runner import ModelRunner
+    from production_stack_tpu.models import llama
+    from production_stack_tpu.models.config import get_config
+    from production_stack_tpu.models.kv import make_cache
+    from production_stack_tpu.ops.rope import rope_table
+    from production_stack_tpu.parallel.sharding import (
+        cache_pspec, param_shardings)
+
+    mesh, (_, _, rep_sh, _) = _placements(topo, tp)
+    mcfg = get_config("mistral-7b")
+    ecfg = EngineConfig(model="mistral-7b", quantization="int8")
+    runner = ModelRunner.__new__(ModelRunner)
+    runner.model_cfg, runner.engine_cfg, runner.mesh = mcfg, ecfg, mesh
+    runner._lora, runner._lora_scaling = None, 1.0
+    runner.rope = rope_table(ecfg.max_model_len, mcfg.head_dim_,
+                             mcfg.rope_theta, scaling=mcfg.rope_scaling)
+
+    params = jax.eval_shape(
+        partial(llama.init_params, mcfg, quantization="int8"),
+        jax.random.PRNGKey(0))
+    cache = jax.eval_shape(partial(
+        make_cache, mcfg.num_layers, ecfg.num_kv_blocks,
+        ecfg.kv_block_size, mcfg.num_kv_heads, mcfg.head_dim_))
+    if mesh is None:
+        p_sh = jax.tree.map(lambda _: rep_sh, params)
+        c_sh = jax.tree.map(lambda _: rep_sh, cache)
+    else:
+        p_sh = param_shardings(mesh, params)
+        c_sh = jax.tree.map(
+            lambda _: NamedSharding(mesh, cache_pspec()), cache)
+
+    def place(tree, shardings):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                              sharding=s),
+            tree, shardings)
+
+    def rep(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep_sh)
+
+    return runner, place(params, p_sh), place(cache, c_sh), rep
+
+
+def _step_args(runner, rep, B: int):
+    """The replicated small operands of a step program, as the runner's
+    host API builds them for an unguided, unpenalized batch."""
+    from production_stack_tpu.engine.sampler import SamplingParams
+    ecfg = runner.engine_cfg
+    sampling = jax.tree.map(
+        lambda x: rep(x.shape, x.dtype), SamplingParams.filled(B))
+    return dict(
+        tables=rep((B, ecfg.max_blocks_per_seq), jnp.int32),
+        sampling=sampling, key=rep((2,), jnp.uint32),
+        guide_next=rep((1, 1, 1), jnp.int32),
+        guide_id=rep((B,), jnp.int32), guide_state=rep((B,), jnp.int32),
+        counts=rep((B, 1), jnp.int32), seen=rep((B, 1), jnp.bool_))
+
+
+def _fits(compiled, what: str) -> None:
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"{what}: arguments {m.argument_size_in_bytes / 2**30:.2f} "
+          f"GiB, temp {m.temp_size_in_bytes / 2**30:.2f} GiB, "
+          f"aliased {m.alias_size_in_bytes / 2**30:.2f} GiB, "
+          f"needs {need / 2**30:.2f} GiB per device")
+    assert need < HBM_BYTES, what
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tp", [1, 4])
+def test_decode_window_compiles_at_mistral_7b(topo, tpu_branches, tp):
+    runner, params, cache, rep = _runner_shapes(topo, tp)
+    B, steps = runner.engine_cfg.max_num_seqs, 8
+    a = _step_args(runner, rep, B)
+    fn = jax.jit(partial(runner._decode_impl, steps=steps, kv_len=512,
+                         greedy=True), donate_argnums=(1,))
+    compiled = fn.lower(
+        params, cache, a["tables"], rep((B,), jnp.int32),
+        rep((B,), jnp.int32), a["sampling"], a["key"], a["guide_next"],
+        a["guide_id"], a["guide_state"], a["counts"], a["seen"]
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled, f"decode window tp={tp}")
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tp", [1, 4])
+def test_prefill_step_compiles_at_mistral_7b(topo, tpu_branches, tp):
+    runner, params, cache, rep = _runner_shapes(topo, tp)
+    B, Tb = runner.engine_cfg.max_num_seqs, 512
+    a = _step_args(runner, rep, B)
+    fn = jax.jit(partial(runner._prefill_impl, kv_len=512),
+                 donate_argnums=(1,))
+    compiled = fn.lower(
+        params, cache, a["tables"], rep((B, Tb), jnp.int32),
+        rep((B,), jnp.int32), rep((B,), jnp.int32), a["sampling"],
+        a["key"], a["guide_next"], a["guide_id"], a["guide_state"],
+        a["counts"], a["seen"]).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled, f"prefill step tp={tp}")

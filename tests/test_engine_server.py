@@ -42,6 +42,31 @@ def test_models_and_health(engine):
     _with_client(engine, body)
 
 
+def test_debug_perf_device_block(engine):
+    """GET /debug/perf says what the engine runs on, as JAX reports it,
+    and which attention path every compiled executable took — here the
+    CPU, where the Pallas kernels are off."""
+    import jax
+
+    async def body(client):
+        r = await client.get("/debug/perf")
+        assert r.status == 200
+        dev = (await r.json())["device"]
+        assert dev["platform"] == "cpu" == jax.devices()[0].platform
+        assert dev["device_kind"] == jax.devices()[0].device_kind
+        assert dev["count"] == len(jax.devices())
+        # one device holds the unsharded engine; the CPU backend has
+        # no memory_stats()
+        assert [d["bytes_in_use"] for d in dev["engine_devices"]] == [None]
+        assert dev["pallas_attention"] == "off"
+        paths = dev["attention_paths"]
+        # every warmed executable, keyed like totals.compiles
+        assert {"decode|8|128|2", "prefill|16|128|2",
+                "prefill|32|128|2"} <= set(paths)
+        assert set(paths.values()) == {"jnp_gather"}
+    _with_client(engine, body)
+
+
 def test_chat_completion(engine):
     async def body(client):
         r = await client.post("/v1/chat/completions", json={

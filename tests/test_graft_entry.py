@@ -15,7 +15,10 @@ def test_dryrun_multichip_after_backend_init():
     assert len(jax.devices()) >= 8
 
 
-def test_entry_shapes():
+def test_entry_shapes(monkeypatch, tmp_path):
+    # with the variable set, entry() sets no cache directory in code:
+    # the session's JAX config stays as it was
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     import __graft_entry__ as g
     fn, args = g.entry()
     logits, cache = jax.eval_shape(fn, *args)
