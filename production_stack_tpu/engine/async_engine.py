@@ -8,6 +8,7 @@ variable so an idle engine burns no CPU.
 
 import asyncio
 import threading
+import time
 import uuid
 from concurrent.futures import ThreadPoolExecutor
 from typing import AsyncIterator, Dict, List, Optional, Tuple
@@ -63,9 +64,13 @@ class AsyncLLMEngine:
         self._lock_pool.shutdown(wait=False)
 
     def _run(self) -> None:
+        # the step timeline (engine/efficiency.py): what this loop
+        # spends between one step()'s return and the next one's lock
+        # is booked as between_steps, the wait for work as no_work
+        phase = self.engine.eff.phase
         while self._running:
             if not self.engine.has_work:
-                with self._wake:
+                with phase("no_work"), self._wake:
                     if not self.engine.has_work and self._running:
                         self._wake.wait(timeout=0.2)
                 continue
@@ -78,7 +83,16 @@ class AsyncLLMEngine:
                 self._loop.call_soon_threadsafe(self._dispatch, outputs)
 
     def _dispatch(self, outputs: List[StepOutput]) -> None:
+        # one stamp per batch of outputs, not per token: when a
+        # request's first and last tokens reach its queue (the
+        # first_token_emit / emit_lag trace events)
+        now = time.monotonic()
         for out in outputs:
+            if out.waits is not None:
+                if out.waits.first_emit is None:
+                    out.waits.first_emit = now
+                if out.finished:
+                    out.waits.last_emit = now
             q = self._queues.get(out.seq_id)
             if q is not None:
                 q.put_nowait(out)

@@ -64,6 +64,58 @@ HBM_PEAK_GBPS: Dict[str, float] = {
 OCCUPANCY_BUCKETS: Tuple[float, ...] = (
     0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0)
 
+# The step timeline's phases (docs/observability.md "Step timeline"):
+# every second of the engine thread, from its first step on, is booked
+# under exactly one of them. The first thirteen partition one step()
+# (``housekeeping`` also takes what a step spends outside any other
+# phase); ``between_steps`` and ``no_work`` lie outside the engine
+# lock; ``compile`` is what an XLA compile took out of the phase it
+# fell in.
+STEP_PHASES: Tuple[str, ...] = (
+    "expire", "schedule", "drain_sync", "drain_process",
+    "prefill_host", "prefill_dispatch", "prefill_sync", "prefill_process",
+    "decode_host", "decode_dispatch", "decode_sync", "decode_process",
+    "housekeeping", "between_steps", "no_work", "compile")
+
+
+class _Span:
+    """One entry of one phase on the engine thread: a context manager
+    made by ``EngineEffAccounting.phase``. Spans nest; a span's own
+    seconds (``self_s``) are its elapsed time less the spans and
+    compiles inside it, so nested phases never count a second twice.
+    After exit ``t0``/``t1`` are its monotonic stamps: the step loop
+    reads them instead of keeping a clock of its own."""
+
+    __slots__ = ("eff", "name", "label", "dispatches", "ann",
+                 "t0", "t1", "inner_s", "self_s")
+
+    def __init__(self, eff: "EngineEffAccounting", name: str,
+                 label: str, dispatches: bool):
+        self.eff, self.name, self.label = eff, name, label
+        self.dispatches = dispatches
+        self.ann = None
+        self.t0 = self.t1 = self.inner_s = self.self_s = 0.0
+
+    @property
+    def elapsed_s(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "_Span":
+        eff = self.eff
+        if eff.annotate is not None:
+            self.ann = eff.annotate(self.label)
+            self.ann.__enter__()
+        self.t0 = eff._now()
+        eff._open(self)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = self.eff._now()
+        self.eff._close(self)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False
+
 
 class EngineEffAccounting:
     """Plain-int efficiency totals + bounded rings.
@@ -80,6 +132,12 @@ class EngineEffAccounting:
     an external reader — the obsplane flight recorder — can align
     engine windows/compiles with trace spans and other processes'
     rings without sharing this process's monotonic epoch.
+
+    ``annotate`` (the engine passes ``jax.profiler.TraceAnnotation``)
+    makes a context manager from a name: every phase of the step
+    timeline enters one named ``pstpu.<phase>``, so the same intervals
+    lie on the host plane of a profiler capture, on the device trace's
+    clock. This module itself stays off JAX.
     """
 
     def __init__(self, *, weight_bytes: int = 0,
@@ -88,7 +146,8 @@ class EngineEffAccounting:
                  ring_entries: int = 256,
                  compile_hist=None,
                  now_fn: Callable[[], float] = time.monotonic,
-                 wall_fn: Callable[[], float] = time.time):
+                 wall_fn: Callable[[], float] = time.time,
+                 annotate: Optional[Callable[[str], object]] = None):
         self.weight_bytes = int(weight_bytes)
         self.kv_position_bytes = int(kv_position_bytes)
         # None = no known peak for this device: MBU is not reported
@@ -133,17 +192,44 @@ class EngineEffAccounting:
         self._compile_events: "collections.deque[tuple]" = \
             collections.deque(maxlen=128)
         self._lock = threading.Lock()
+        # step timeline (phase/step below). Totals and the ring are
+        # read under the micro-lock; everything with a leading
+        # underscore below is the engine thread's alone and is folded
+        # into the totals once per step.
+        self.annotate = annotate
+        self.steps = 0
+        self.step_wall_s = 0.0
+        self.phase_s: Dict[str, float] = dict.fromkeys(STEP_PHASES, 0.0)
+        self.starved_s: Dict[str, float] = {}
+        # exit stamp of the latest ``*_sync`` phase: a pipelined
+        # window's seconds start here, not at its dispatch
+        self.synced_at = 0.0
+        self._steps: "collections.deque[dict]" = collections.deque(
+            maxlen=max(1, ring_entries))
+        self._stack: List[_Span] = []
+        self._cur: Dict[str, float] = {}        # phase -> s, this step
+        self._cur_starved: Dict[str, float] = {}
+        self._root_end: Optional[float] = None  # last outermost exit
+        # since when the device has had nothing of ours outstanding
+        # while work waited (None: it is busy). Set where the timeline
+        # begins, by device_idle(), and moved up past a wait for work
+        self._idle_since: Optional[float] = None
 
     # -- step-loop writes ------------------------------------------------
 
     def note_window(self, *, steps: int, positions: int, batch: int,
                     live_rows: int, kv_len: int, real: int, pad: int,
-                    dead: int, window_s: float) -> None:
+                    dead: int, window_s: float, host_s: float = 0.0,
+                    sync_s: float = 0.0) -> None:
         """One fused decode window: ``batch * steps * positions``
         token-step computations, of which ``real`` emitted tokens the
         client keeps, ``pad`` ran on parked rows, and ``dead`` ran on
         finished rows' tails / discarded rows / rejected draft
-        positions."""
+        positions. ``window_s`` runs from its dispatch (or the sync
+        before it, if later) to its own sync's return; ``host_s`` is
+        what the host itself spent on it (preparing and making the
+        dispatch, walking its tokens) and ``sync_s`` what it spent
+        blocked on the sync."""
         total = batch * steps * positions
         useful = real / total if total else 0.0
         win_bytes = steps * (self.weight_bytes
@@ -161,6 +247,8 @@ class EngineEffAccounting:
             "pad": pad,
             "dead": dead,
             "window_s": round(window_s, 6),
+            "host_s": round(host_s, 6),
+            "sync_s": round(sync_s, 6),
             "bytes": win_bytes,
             "effective_bytes": eff_bytes,
         }
@@ -186,6 +274,84 @@ class EngineEffAccounting:
             self.prefill_pad += max(0, total - real_tokens)
             self.prefill_dispatches += 1
 
+    # -- step timeline (engine thread only) ------------------------------
+
+    def phase(self, name: str, dispatches: bool = False) -> _Span:
+        """Context manager for one phase of the step loop (a name of
+        STEP_PHASES). ``dispatches`` marks the phases that hand the
+        device work (the ``runner.decode`` / ``runner.prefill`` calls):
+        the device stops counting as starved when one returns."""
+        return _Span(self, name, "pstpu." + name, dispatches)
+
+    def step(self) -> _Span:
+        """Context manager around one whole ``step()``: closes the
+        step's record into the totals and the ``steps`` ring. What the
+        step spends outside any other phase is ``housekeeping``."""
+        return _Span(self, "housekeeping", "pstpu.step", False)
+
+    def device_idle(self) -> None:
+        """The sync that just returned left nothing outstanding on the
+        device: from that stamp on, while work waits, it is starved."""
+        self._idle_since = self.synced_at
+
+    def _starve(self, name: str, until: float) -> None:
+        if self._idle_since is not None:
+            self._cur_starved[name] = (self._cur_starved.get(name, 0.0)
+                                       + until - self._idle_since)
+            self._idle_since = until
+
+    def _open(self, span: _Span) -> None:
+        if self._stack:
+            self._starve(self._stack[-1].name, span.t0)
+        else:
+            if self._root_end is None:
+                self._idle_since = span.t0      # nothing dispatched yet
+            else:
+                self._cur["between_steps"] = span.t0 - self._root_end
+            self._starve("between_steps", span.t0)
+        self._stack.append(span)
+
+    def _close(self, span: _Span) -> None:
+        self._stack.pop()
+        elapsed = span.t1 - span.t0
+        span.self_s = elapsed - span.inner_s
+        self._cur[span.name] = self._cur.get(span.name, 0.0) + span.self_s
+        if span.name != "no_work":
+            self._starve(span.name, span.t1)
+        elif self._idle_since is not None:
+            # nothing waited, so nobody starved; what arrives now does
+            self._idle_since = span.t1
+        if span.dispatches:
+            self._idle_since = None
+        if span.name.endswith("_sync"):
+            self.synced_at = span.t1
+        if self._stack:
+            self._stack[-1].inner_s += elapsed
+            return
+        # outermost span (a step, or the wait for work): fold into the
+        # totals what was booked since the last one closed. The wall
+        # is taken from the stamps, not from the sum of the phases
+        wall = span.t1 - (span.t0 if self._root_end is None
+                          else self._root_end)
+        self._root_end = span.t1
+        cur, starved = self._cur, self._cur_starved
+        self._cur, self._cur_starved = {}, {}
+        is_step = span.label == "pstpu.step"
+        with self._lock:
+            for k, v in cur.items():
+                self.phase_s[k] += v
+            for k, v in starved.items():
+                self.starved_s[k] = self.starved_s.get(k, 0.0) + v
+            self.step_wall_s += wall
+            if is_step:
+                self.steps += 1
+                self._steps.append({
+                    "at": span.t0,
+                    "at_unix": round(self._wall() - elapsed, 4),
+                    "wall_s": round(elapsed, 6),
+                    "phase_s": {k: round(v, 6) for k, v in cur.items()},
+                    "starved_s": round(sum(starved.values()), 6)})
+
     # -- compile observer (ModelRunner hook) -----------------------------
 
     def compile_started(self, kind: str, window: int, kv_len: int,
@@ -197,6 +363,15 @@ class EngineEffAccounting:
                          started_at: float, dur_s: float,
                          batch: int = 0) -> None:
         key = (kind, int(window), int(kv_len), int(batch))
+        if self._stack:
+            # a compile inside a phase of the step loop (they happen
+            # on the engine thread, in the dispatch phases) is taken
+            # out of that phase and booked as ``compile``
+            top = self._stack[-1]
+            top.inner_s += dur_s
+            self._cur["compile"] = self._cur.get("compile", 0.0) + dur_s
+            self._starve(top.name, started_at)
+            self._starve("compile", started_at + dur_s)
         with self._lock:
             self.compile_in_flight = max(0, self.compile_in_flight - 1)
             slot = self.compiles.setdefault(key, [0, 0.0])
@@ -243,6 +418,15 @@ class EngineEffAccounting:
                 "weight_bytes": self.weight_bytes,
                 "kv_position_bytes": self.kv_position_bytes,
                 "hbm_peak_bytes_per_s": self.hbm_peak_bytes_per_s,
+                "step": {
+                    "steps": self.steps,
+                    "wall_s": round(self.step_wall_s, 6),
+                    "phase_s": {k: round(v, 6)
+                                for k, v in self.phase_s.items()},
+                    "starved_s": round(sum(self.starved_s.values()), 6),
+                    "starved_by_phase": {
+                        k: round(v, 6)
+                        for k, v in self.starved_s.items()}},
             }
 
     def rates(self, horizon_s: float = 10.0,
@@ -311,6 +495,10 @@ class EngineEffAccounting:
     def recent_windows(self, limit: int = 50) -> List[dict]:
         with self._lock:
             return list(self._windows)[-max(1, limit):]
+
+    def recent_steps(self, limit: int = 50) -> List[dict]:
+        with self._lock:
+            return list(self._steps)[-max(1, limit):]
 
     def recent_compiles(self, limit: int = 50) -> List[dict]:
         with self._lock:

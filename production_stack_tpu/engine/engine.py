@@ -21,7 +21,8 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.metrics import EngineMetrics
 from production_stack_tpu.engine.runner import ModelRunner
 from production_stack_tpu.engine.sampler import SamplingParams
-from production_stack_tpu.engine.scheduler import (Scheduler, SamplingOptions,
+from production_stack_tpu.engine.scheduler import (RequestWaits, Scheduler,
+                                                   SamplingOptions,
                                                    SeqStatus, Sequence)
 from production_stack_tpu.engine.tokenizer import (DetokenizeStream,
                                                    load_tokenizer)
@@ -48,6 +49,30 @@ class StepOutput:
     # trace spans without reaching into scheduler internals
     # (tracing.py; docs/observability.md "Tracing")
     timing: Optional[dict] = None
+    # first-token and terminal outputs only: the sequence's
+    # RequestWaits, for AsyncLLMEngine to stamp the moment the output
+    # is handed to the request's queue
+    waits: Optional[RequestWaits] = None
+
+
+@dataclass
+class _Window:
+    """One decode window in flight: dispatched, not yet synced. The
+    arrays are the device's until ``_sync_inflight`` replaces them with
+    host copies."""
+    ids: object
+    lps: object
+    counts: object          # None without speculation
+    tops: object            # None unless top_logprobs was asked
+    steps: int
+    seqs: List[Sequence]    # the running sequences at dispatch
+    # where its seconds start: the exit stamp of its decode_dispatch
+    # phase, moved up to the previous sync's return at its own sync
+    t0: float
+    spec_ok: object
+    kv_len: int
+    batch: int
+    host_s: float           # decode_host + decode_dispatch spent on it
 
 
 # finished sequences kept for post-hoc inspection (bounded; see _remember)
@@ -224,7 +249,11 @@ class LLMEngine:
             hbm_peak_bytes_per_s=(peak_gbps * 1e9 * len(self.devices)
                                   if peak_gbps else None),
             ring_entries=engine_cfg.perf_ring_entries,
-            compile_hist=self.metrics.compile_hist)
+            compile_hist=self.metrics.compile_hist,
+            annotate=jax.profiler.TraceAnnotation)
+        # the step timeline (efficiency.STEP_PHASES): every phase of
+        # step() runs under `with self._phase(name)`
+        self._phase = self.eff.phase
         self.runner.compile_observer = self.eff
         # advertised once: the router's per-endpoint concurrency cap
         # reads this gauge (0 = unbounded admission, nothing to cap on)
@@ -317,15 +346,14 @@ class LLMEngine:
         # DRAFT quality, never correctness (verification ignores it)
         self._hist_dirty = True
         # decode windows kept in flight between step() calls (FIFO of
-        # (ids_device, lps, counts, window, [seqs at dispatch], t0)).
+        # _Window).
         # Up to cfg.pipeline_depth windows ride the device queue at once:
         # window N+1 is dispatched BEFORE window N's results are synced,
         # so the device starts N+1 the instant N retires instead of
-        # idling one host round-trip (how long that is on a local chip
-        # is not measured). Valid because decode inputs are
+        # idling one host round-trip. Valid because decode inputs are
         # device-carried; the host only has to stay out of the way
         # (no mirror uploads) until every queued window is processed.
-        self._inflight: List[tuple] = []
+        self._inflight: List[_Window] = []
         # continuous batching across windows (docs/engine.md
         # "Continuous batching across windows"): the device carry's
         # current batch bucket (dispatches at a different bucket must
@@ -504,6 +532,7 @@ class LLMEngine:
                 seq.kv_prefetch_wait_s = seq.kv_prefetch.wait_s
                 seq.kv_cached_tokens = seq.kv_prefetch.cached_tokens
         with self._lock:
+            seq.waits.locked = time.monotonic()
             # bounded admission: shed at submit rather than queue
             # forever. Admission happens only at step time, so a fresh
             # submit ALWAYS lands in waiting first — the bound is
@@ -554,87 +583,91 @@ class LLMEngine:
         head-of-line blocking; the reference exposes the same property as
         --enable-chunked-prefill, reference:
         helm/templates/deployment-vllm-multi.yaml:69-72)."""
-        with self._lock:
+        with self._lock, self.eff.step():
             outputs: List[StepOutput] = []
-            # overload protection: drop expired-deadline / over-delayed
-            # sequences from the waiting queue BEFORE admission, so no
-            # prefill compute is burned on a request whose client has
-            # already given up (ISSUE 4; docs/engine.md)
-            delay_cap = self.cfg.max_queue_delay_ms
-            expired = self.scheduler.expire_waiting(
-                max_queue_delay_s=delay_cap / 1e3
-                if delay_cap is not None else None)
-            for seq in expired:
-                self._free_seq_blocks(seq)
-                self._remember(seq)
-                if seq.finish_reason == "deadline":
-                    self.metrics.deadline_expired.inc()
-                else:
-                    self.metrics.queue_delay_shed.inc()
-                logger.info("dropped %s while waiting (%s): queued "
-                            "%.0fms", seq.seq_id, seq.finish_reason,
-                            1e3 * (time.monotonic() - seq.arrival_time))
-                drop_now = time.monotonic()
-                # a WAITING-dropped request's whole remaining life IS
-                # queue wait — close its open interval so shed storms
-                # show up in the phase histograms, not just counters
-                seq.queue_wait_s += drop_now - seq.enqueued_time
-                self.metrics.engine_phases.observe(
-                    "queue_wait", seq.queue_wait_s)
-                outputs.append(StepOutput(
-                    seq.seq_id, None, "", True, seq.finish_reason,
-                    timing=self._seq_timing(seq, drop_now)))
-            works, decode_seqs = self.scheduler.schedule()
+            with self._phase("expire"):
+                outputs.extend(self._drop_expired())
+            with self._phase("schedule"):
+                works, decode_seqs = self.scheduler.schedule()
             if works:
                 # drain the in-flight window first: it was dispatched
                 # from pre-prefill state and stays valid; the prefill's
                 # writes are ordered after it on device
                 outputs.extend(self._drain_decode())
-                outputs.extend(self._do_prefill(works))
+                with self._phase("prefill_host"):
+                    outputs.extend(self._do_prefill(works))
                 # re-snapshot: sequences whose prefill just completed are
                 # RUNNING now and must join this step's decode window —
                 # the device generates tokens for every live row, and a
                 # row the host skipped would desync the device carry
                 decode_seqs = list(self.scheduler.running.values())
             if decode_seqs or self._inflight:
-                if not self._inflight:
-                    self._dispatch_decode(decode_seqs)
-                # optimistic pipelining: top the device queue up to
-                # cfg.pipeline_depth windows BEFORE blocking on the front
-                # window's sync — with window N+1 already queued behind
-                # N, the device starts N+1 the instant N retires instead
-                # of idling one host round-trip (its share of a window
-                # on a local chip is not measured), and
-                # it keeps decoding while the host walks tokens (detok,
-                # stop checks, callbacks). Valid because decode inputs
-                # are device-carried: each window continues from its
-                # predecessor's final tokens/positions regardless of
-                # what the host decides; rows whose sequence turns out
-                # to have finished are discarded at the next drain
-                # (their writes only touch blocks still owned by the
-                # finished sequence — never registered-prefix blocks,
-                # which are always full). Only when the device carry is
-                # self-contained: a dirty decode/sampling state means
-                # the next dispatch must upload host mirrors, and
-                # mid-processing mirrors lag the device (uploading them
-                # would rewind live rows and duplicate tokens).
-                self._top_up_pipeline()
-                t_win = time.monotonic()
-                synced = self._sync_inflight()
-                # per-window host-visible decode latency: the blocking
-                # device sync for one fused window — the batching-level
-                # signal (how long a window takes end to end) the
-                # roofline work reads next to the per-request phases
-                self.metrics.engine_phases.observe(
-                    "decode_window", time.monotonic() - t_win)
-                outputs.extend(self._process_window(synced))
+                with self._phase("decode_host"):
+                    if not self._inflight:
+                        self._dispatch_decode(decode_seqs)
+                    # optimistic pipelining: top the device queue up to
+                    # cfg.pipeline_depth windows BEFORE blocking on the
+                    # front window's sync — with window N+1 already
+                    # queued behind N, the device starts N+1 the instant
+                    # N retires instead of idling one host round-trip
+                    # (the timeline's starved seconds say how long that
+                    # is), and it keeps decoding while the host walks
+                    # tokens (detok, stop checks, callbacks). Valid
+                    # because decode inputs are device-carried: each
+                    # window continues from its predecessor's final
+                    # tokens/positions regardless of what the host
+                    # decides; rows whose sequence turns out to have
+                    # finished are discarded at the next drain (their
+                    # writes only touch blocks still owned by the
+                    # finished sequence — never registered-prefix
+                    # blocks, which are always full). Only when the
+                    # device carry is self-contained: a dirty
+                    # decode/sampling state means the next dispatch
+                    # must upload host mirrors, and mid-processing
+                    # mirrors lag the device (uploading them would
+                    # rewind live rows and duplicate tokens).
+                    self._top_up_pipeline()
+                outputs.extend(self._retire_window("decode"))
                 if not self._inflight:
                     decode_seqs = list(self.scheduler.running.values())
                     if decode_seqs:
                         self._dispatch_decode(decode_seqs)
-            self._maybe_defrag()
-            self._refresh_gauges()
+            with self._phase("housekeeping"):
+                self._maybe_defrag()
+                self._refresh_gauges()
             return outputs
+
+    def _drop_expired(self) -> List[StepOutput]:
+        """Overload protection: drop expired-deadline / over-delayed
+        sequences from the waiting queue BEFORE admission, so no
+        prefill compute is burned on a request whose client has
+        already given up (ISSUE 4; docs/engine.md)."""
+        outputs: List[StepOutput] = []
+        delay_cap = self.cfg.max_queue_delay_ms
+        expired = self.scheduler.expire_waiting(
+            max_queue_delay_s=delay_cap / 1e3
+            if delay_cap is not None else None)
+        for seq in expired:
+            self._free_seq_blocks(seq)
+            self._remember(seq)
+            if seq.finish_reason == "deadline":
+                self.metrics.deadline_expired.inc()
+            else:
+                self.metrics.queue_delay_shed.inc()
+            logger.info("dropped %s while waiting (%s): queued "
+                        "%.0fms", seq.seq_id, seq.finish_reason,
+                        1e3 * (time.monotonic() - seq.arrival_time))
+            drop_now = time.monotonic()
+            # a WAITING-dropped request's whole remaining life IS
+            # queue wait — close its open interval so shed storms
+            # show up in the phase histograms, not just counters
+            seq.queue_wait_s += drop_now - seq.enqueued_time
+            self.metrics.engine_phases.observe(
+                "queue_wait", seq.queue_wait_s)
+            outputs.append(StepOutput(
+                seq.seq_id, None, "", True, seq.finish_reason,
+                timing=self._seq_timing(seq, drop_now)))
+        return outputs
 
     def _maybe_defrag(self) -> None:
         """kvplane intra-replica defrag, between fused windows: if this
@@ -745,7 +778,7 @@ class LLMEngine:
                and not (self.cfg.window_adapt
                         and self._admission_imminent())
                and self._worth_dispatch_ahead()):
-            ahead = sum(w[4] for w in self._inflight)
+            ahead = sum(w.steps for w in self._inflight)
             if not self._dispatch_decode(
                     list(self.scheduler.running.values()), ahead=ahead):
                 break
@@ -755,7 +788,7 @@ class LLMEngine:
         reach its token budget within the windows already in flight —
         then the whole dispatch would likely be discarded work (and
         would delay the next admission wave by one window)."""
-        inflight_steps = sum(w[4] for w in self._inflight)
+        inflight_steps = sum(w.steps for w in self._inflight)
         live = [s for s in self.scheduler.running.values()
                 if s.status is SeqStatus.RUNNING]
         if not live:
@@ -924,7 +957,10 @@ class LLMEngine:
 
     def _do_prefill(self, works) -> List[StepOutput]:
         """Batch-prefill every scheduled chunk: one device dispatch per
-        chunk-length bucket (usually one total), all slots at once."""
+        chunk-length bucket (usually one total), all slots at once.
+        Runs under the ``prefill_host`` phase: what is not inside one
+        of the three phases below is host preparation (grouping, table
+        and sampling uploads)."""
         outputs: List[StepOutput] = []
         for w in works:
             self._sync_sampling(w.seq)
@@ -970,73 +1006,93 @@ class LLMEngine:
                 # this very prefill samples, which prefill executables
                 # don't record device-side
                 self.runner.set_penalty_state(*self._penalty_arrays())
-            ids_dev, lps_dev, tops_dev = self.runner.prefill(
-                tokens, starts, lengths, self._dev_sampling, kv_len,
-                guide_table=gtable, guide_ids=gids,
-                guide_states=gstates, penalized=penalized, topk=topk)
+            with self._phase("prefill_dispatch", dispatches=True) as call:
+                devs = self.runner.prefill(
+                    tokens, starts, lengths, self._dev_sampling, kv_len,
+                    guide_table=gtable, guide_ids=gids,
+                    guide_states=gstates, penalized=penalized, topk=topk)
             # bucket-padding accounting: the dispatch computed B*bucket
             # positions; only the scheduled chunks' tokens were real
             self.eff.note_prefill(
                 bucket=bucket, batch=B,
                 real_tokens=sum(len(w.chunk) for w in group))
-            ids = lps = tops = None
-            for w in group:
-                self.scheduler.on_prefill_done(w)
-                self.metrics.prompt_tokens.inc(len(w.chunk))
-                if (self.cfg.enable_prefix_caching
-                        and not w.seq.rolled_blocks):
-                    # LIVE progressive registration: a full block's
-                    # K/V is final the moment its last position is
-                    # written (write-then-attend; full blocks are
-                    # never rewritten), so a concurrent same-prefix
-                    # request can attach it WITHOUT waiting for this
-                    # sequence to finish. The hasher chain state rides
-                    # the sequence so each chunk keys only its NEW
-                    # blocks (O(L^2) otherwise on long prompts).
-                    seq = w.seq
-                    seq.reg_state = self.block_mgr.register_incremental(
-                        seq.prefill_tokens[:seq.num_prefilled],
-                        seq.block_ids, seq.reg_state,
-                        salt=self._adapter_salt(seq.adapter_id))
-                if self.connector is not None:
-                    # progressive publish: disagg decode engines can pull
-                    # the prefix while later chunks still prefill
-                    self.connector.on_prefill_progress(
-                        w.seq, salt=self._adapter_salt(w.seq.adapter_id))
-                if not w.is_last:
-                    continue
+            with self._phase("prefill_process"):
+                outputs.extend(self._land_prefill(group, devs, call.t1))
+        # prefill changed slot contents/positions: refresh decode carry
+        self._decode_dirty = True
+        self._hist_dirty = True
+        return outputs
+
+    def _land_prefill(self, group, devs, t_called: float
+                      ) -> List[StepOutput]:
+        """Host side of one dispatched prefill group: scheduler and
+        cache bookkeeping per chunk and, for rows whose prompt is now
+        whole, the first token (one sync per group, ``prefill_sync``).
+        ``t_called``: when ``runner.prefill`` returned."""
+        outputs: List[StepOutput] = []
+        ids_dev, lps_dev, tops_dev = devs
+        ids = lps = tops = None
+        for w in group:
+            if w.seq.waits.prefill_call is None:
+                w.seq.waits.prefill_call = t_called
+            w.seq.waits.prefill_chunks += 1
+            self.scheduler.on_prefill_done(w)
+            self.metrics.prompt_tokens.inc(len(w.chunk))
+            if (self.cfg.enable_prefix_caching
+                    and not w.seq.rolled_blocks):
+                # LIVE progressive registration: a full block's
+                # K/V is final the moment its last position is
+                # written (write-then-attend; full blocks are
+                # never rewritten), so a concurrent same-prefix
+                # request can attach it WITHOUT waiting for this
+                # sequence to finish. The hasher chain state rides
+                # the sequence so each chunk keys only its NEW
+                # blocks (O(L^2) otherwise on long prompts).
                 seq = w.seq
-                if seq.output_tokens:
-                    # preemption-recompute resume: emitted output was
-                    # teacher-forced back in; the prefill's sampled id
-                    # is discarded (the last emitted token is the next
-                    # decode input — _sync_slot restores it)
-                    self._sync_slot(seq)
-                    continue
-                if ids is None:
-                    ids = np.asarray(ids_dev)  # one sync per bucket group
+                seq.reg_state = self.block_mgr.register_incremental(
+                    seq.prefill_tokens[:seq.num_prefilled],
+                    seq.block_ids, seq.reg_state,
+                    salt=self._adapter_salt(seq.adapter_id))
+            if self.connector is not None:
+                # progressive publish: disagg decode engines can pull
+                # the prefix while later chunks still prefill
+                self.connector.on_prefill_progress(
+                    w.seq, salt=self._adapter_salt(w.seq.adapter_id))
+            if not w.is_last:
+                continue
+            seq = w.seq
+            if seq.output_tokens:
+                # preemption-recompute resume: emitted output was
+                # teacher-forced back in; the prefill's sampled id
+                # is discarded (the last emitted token is the next
+                # decode input — _sync_slot restores it)
+                self._sync_slot(seq)
+                continue
+            if ids is None:
+                with self._phase("prefill_sync"):
+                    ids = np.asarray(ids_dev)  # one sync per group
                     lps = np.asarray(lps_dev)
                     tops = (None if tops_dev is None else
                             (np.asarray(tops_dev[0]),
                              np.asarray(tops_dev[1])))
-                # prompt fully prefilled: the sampled id is the first
-                # output token
-                k = seq.options.top_logprobs
-                alts = None
-                if tops is not None and k:
-                    alts = [(int(t), float(l)) for t, l in
-                            zip(tops[0][seq.slot, :k],
-                                tops[1][seq.slot, :k])
-                            if l > -1e29]
-                seq.first_token_time = time.monotonic()
-                self.metrics.ttft.observe(
-                    seq.first_token_time - seq.arrival_time)
-                outputs.extend(self._accept_token(
-                    seq, int(ids[seq.slot]), float(lps[seq.slot]),
-                    alts))
-        # prefill changed slot contents/positions: refresh decode carry
-        self._decode_dirty = True
-        self._hist_dirty = True
+                # a prefill runs with every window drained: its
+                # sync leaves the device with nothing to do
+                self.eff.device_idle()
+            # prompt fully prefilled: the sampled id is the first
+            # output token
+            k = seq.options.top_logprobs
+            alts = None
+            if tops is not None and k:
+                alts = [(int(t), float(l)) for t, l in
+                        zip(tops[0][seq.slot, :k],
+                            tops[1][seq.slot, :k])
+                        if l > -1e29]
+            seq.first_token_time = time.monotonic()
+            self.metrics.ttft.observe(
+                seq.first_token_time - seq.arrival_time)
+            outputs.extend(self._accept_token(
+                seq, int(ids[seq.slot]), float(lps[seq.slot]),
+                alts))
         return outputs
 
     def _ensure_dev_sampling(self) -> None:
@@ -1115,6 +1171,16 @@ class LLMEngine:
         return self._guided_table, self._guided_gids
 
     def _dispatch_decode(self, decode_seqs, ahead: int = 0) -> bool:
+        """Launch one decode window (_launch_window) under the
+        ``decode_host`` phase; False if none was dispatched."""
+        with self._phase("decode_host") as host:
+            win = self._launch_window(decode_seqs, ahead)
+        if win is None:
+            return False
+        win.host_s += host.self_s
+        return True
+
+    def _launch_window(self, decode_seqs, ahead: int) -> Optional[_Window]:
         """Launch one decode window (async dispatch; no host sync).
 
         With ``window_adapt`` on, the dispatch tracks the LIVE batch
@@ -1136,7 +1202,7 @@ class LLMEngine:
         `ahead` steps past the host mirrors, so block coverage and the
         kv bucket are computed from position + ahead. An optimistic
         dispatch must leave host state untouched by the device's view:
-        it returns False WITHOUT dispatching if it would have to
+        it returns None WITHOUT dispatching if it would have to
         preempt (parking rewrites the decode carry) or upload host
         mirrors (they lag the device by `ahead` steps until the synced
         window is processed) — the caller then falls back to the
@@ -1181,11 +1247,11 @@ class LLMEngine:
                                           allow_preempt=ahead == 0)
             if not covered:
                 if ahead:
-                    return False   # pool pressure: no optimistic window
+                    return None   # pool pressure: no optimistic window
                 self._preempt(s)
         decode_seqs = list(self.scheduler.running.values())
         if not decode_seqs:
-            return False
+            return None
         # batch bucket: smallest executable covering every live slot
         # (compaction just packed them low). An optimistic dispatch
         # continues the device carry, whose batch is fixed.
@@ -1199,7 +1265,7 @@ class LLMEngine:
                 # and an optimistic dispatch may not reshape the
                 # carry. Fall back to the process-first path: its
                 # ahead == 0 dispatch re-uploads at the full batch.
-                return False
+                return None
         else:
             # a non-hot variant window (adapt False) pins the full
             # batch; crossing between that and a bucketed hot window
@@ -1254,7 +1320,7 @@ class LLMEngine:
             # carry: uploading mid-processing mirrors would rewind the
             # device — bail, the normal path re-dispatches after
             # processing
-            return False
+            return None
         hist = None
         if spec and (self._hist_dirty or self._decode_dirty):
             # only built for windows that will actually read it; spec=0
@@ -1287,14 +1353,17 @@ class LLMEngine:
         plain = all(s.options.top_p >= 1.0 and not s.options.top_k
                     and not s.options.min_p
                     for s in decode_seqs)
-        ids_dev, lps_dev, counts_dev, tops_dev = self.runner.decode(
-            self._dev_sampling, steps=W, kv_len=kv_len, greedy=greedy,
-            seeded=seeded, guide_table=gtable, guide_ids=gids, spec=spec,
-            spec_ok=spec_ok, plain=plain, penalized=penalized, topk=topk)
-        self._inflight.append((ids_dev, lps_dev, counts_dev, tops_dev,
-                               W, list(decode_seqs), time.monotonic(),
-                               spec_ok, kv_len, batch))
-        return True
+        with self._phase("decode_dispatch", dispatches=True) as call:
+            ids_dev, lps_dev, counts_dev, tops_dev = self.runner.decode(
+                self._dev_sampling, steps=W, kv_len=kv_len, greedy=greedy,
+                seeded=seeded, guide_table=gtable, guide_ids=gids,
+                spec=spec, spec_ok=spec_ok, plain=plain,
+                penalized=penalized, topk=topk)
+        win = _Window(ids_dev, lps_dev, counts_dev, tops_dev, W,
+                      list(decode_seqs), call.t1, spec_ok, kv_len, batch,
+                      host_s=call.self_s)
+        self._inflight.append(win)
+        return win
 
     def _drain_decode(self) -> List[StepOutput]:
         """Sync + process every in-flight window. A sequence that
@@ -1302,34 +1371,56 @@ class LLMEngine:
         (its slot is parked and the decode carry marked dirty)."""
         outputs: List[StepOutput] = []
         while self._inflight:
-            outputs.extend(self._process_window(self._sync_inflight()))
+            outputs.extend(self._retire_window("drain"))
         return outputs
 
-    def _sync_inflight(self):
-        """Device->host sync of the OLDEST in-flight window's arrays (no
-        token processing): (ids, lps, counts, tops, W, seqs, t0,
-        spec_ok, kv_len, batch) or None. t0
-        is clamped to the previous sync's completion so pipelined
-        windows report per-window wall, not time-since-dispatch."""
+    def _retire_window(self, kind: str) -> List[StepOutput]:
+        """Sync the OLDEST in-flight window and walk its tokens, under
+        the phases ``<kind>_sync`` and ``<kind>_process`` (``decode`` in
+        the step proper, ``drain`` ahead of a prefill). The window's
+        clock is the timeline's: its seconds end where the sync phase
+        does."""
         if not self._inflight:
-            return None
-        (ids_dev, lps_dev, counts_dev, tops_dev, W, seqs,
-         t0, spec_ok, kv_len, batch) = self._inflight.pop(0)
-        t0 = max(t0, getattr(self, "_last_sync_t", 0.0))
-        ids = np.asarray(ids_dev)  # the window's single sync
-        lps = np.asarray(lps_dev)
-        counts = None if counts_dev is None else np.asarray(counts_dev)
-        tops = (None if tops_dev is None else
-                (np.asarray(tops_dev[0]), np.asarray(tops_dev[1])))
-        self._last_sync_t = time.monotonic()
-        return (ids, lps, counts, tops, W, seqs, t0, spec_ok, kv_len,
-                batch)
-
-    def _process_window(self, synced) -> List[StepOutput]:
-        if synced is None:
             return []
-        ids, lps, counts, tops, W, seqs, t0, spec_ok, kv_len, B = synced
-        dt = time.monotonic() - t0
+        with self._phase(kind + "_sync") as sync:
+            win = self._sync_inflight()
+        if not self._inflight:
+            self.eff.device_idle()
+        if kind == "decode":
+            # per-window host-visible decode latency: the blocking
+            # device sync for one fused window — the batching-level
+            # signal (how long a window takes end to end) the
+            # roofline work reads next to the per-request phases
+            self.metrics.engine_phases.observe("decode_window",
+                                               sync.elapsed_s)
+        window_s = sync.t1 - win.t0
+        with self._phase(kind + "_process") as walk:
+            outputs, counted = self._process_window(win, window_s)
+        self.eff.note_window(**counted, window_s=window_s,
+                             host_s=win.host_s + walk.self_s,
+                             sync_s=sync.self_s)
+        return outputs
+
+    def _sync_inflight(self) -> _Window:
+        """Device->host sync of the OLDEST in-flight window's arrays (no
+        token processing). Its t0 is clamped to the previous sync's
+        completion so pipelined windows report per-window wall, not
+        time-since-dispatch."""
+        win = self._inflight.pop(0)
+        win.t0 = max(win.t0, self.eff.synced_at)
+        win.ids = np.asarray(win.ids)  # the window's single sync
+        win.lps = np.asarray(win.lps)
+        if win.counts is not None:
+            win.counts = np.asarray(win.counts)
+        if win.tops is not None:
+            win.tops = (np.asarray(win.tops[0]), np.asarray(win.tops[1]))
+        return win
+
+    def _process_window(self, win: _Window, dt: float):
+        """Walk a synced window's tokens. ``dt``: the window's seconds.
+        Returns (outputs, what ``eff.note_window`` counts of it)."""
+        ids, lps, counts, tops = win.ids, win.lps, win.counts, win.tops
+        W, seqs, spec_ok, B = win.steps, win.seqs, win.spec_ok, win.batch
         outputs: List[StepOutput] = []
         alive = [s for s in seqs if s.status is not SeqStatus.FINISHED]
         walkers = len(alive)   # rows that will actually walk steps
@@ -1418,11 +1509,9 @@ class LLMEngine:
             self._eos_rate = 0.8 * self._eos_rate + 0.2 * obs
         pad = (B - len(seqs)) * W * P
         dead = B * W * P - pad - accepted
-        self.eff.note_window(steps=W, positions=P, batch=B,
-                             live_rows=len(seqs), kv_len=kv_len,
-                             real=accepted, pad=pad, dead=dead,
-                             window_s=dt)
-        return outputs
+        return outputs, dict(steps=W, positions=P, batch=B,
+                             live_rows=len(seqs), kv_len=win.kv_len,
+                             real=accepted, pad=pad, dead=dead)
 
     @staticmethod
     def _seq_timing(seq: Sequence, end: float) -> dict:
@@ -1439,6 +1528,7 @@ class LLMEngine:
             "output_tokens": len(seq.output_tokens),
             "kv_prefetch_wait_s": seq.kv_prefetch_wait_s,
             "kv_cached_tokens": seq.kv_cached_tokens,
+            "waits": seq.waits,
         }
 
     def _accept_token(self, seq: Sequence, token: int,
@@ -1522,10 +1612,13 @@ class LLMEngine:
             phases.observe("decode", max(0.0, now - max(first, admit)))
             return [StepOutput(seq.seq_id, token, text_delta, True, reason,
                                logprob, top_alts,
-                               timing=self._seq_timing(seq, now))]
+                               timing=self._seq_timing(seq, now),
+                               waits=seq.waits)]
         self._sync_slot(seq)
         return [StepOutput(seq.seq_id, token, text_delta, False, None,
-                           logprob, top_alts)]
+                           logprob, top_alts,
+                           waits=seq.waits if len(seq.output_tokens) == 1
+                           else None)]
 
     def _stop_reason(self, seq: Sequence, token: int,
                      delta: str) -> Optional[str]:

@@ -46,6 +46,18 @@ from production_stack_tpu.utils import init_logger
 logger = init_logger(__name__)
 
 
+def _named(name: str, fn, **static):
+    """``fn`` with ``static`` bound, under a name of its own. jax.jit
+    names an executable after its function's ``__name__``
+    (``jit_<name>``: the HLO module, and the ``XLA Modules`` events of
+    a profiler capture); a bare functools.partial has none and every
+    step function would read ``jit__unknown``. (The cache-free helpers
+    below are plain local functions and carry their own names.)"""
+    bound = partial(fn, **static)
+    bound.__name__ = bound.__qualname__ = name
+    return bound
+
+
 class ModelRunner:
     def __init__(self, model_cfg: ModelConfig, engine_cfg: EngineConfig,
                  params=None, mesh=None, lora_stacked=None,
@@ -262,6 +274,7 @@ class ModelRunner:
     # jitted impls (pure)
     # ------------------------------------------------------------------
 
+    @jax.named_scope("sample")
     def _sample_position(self, last, sampling: SamplingParams, counts,
                          prompt_seen, pos, gstate, guide_next, guide_id,
                          key, *, greedy: bool, seeded: bool, plain: bool,
@@ -532,31 +545,33 @@ class ModelRunner:
             use_flash=None, mesh=self.mesh,
             lora_params=self._lora, adapter_ids=sampling.adapter,
             lora_scaling=self._lora_scaling, token_valid=token_valid)
-        last = jnp.take_along_axis(
-            logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
-        )[:, 0, :]
-        if penalized:
-            # first sampled token: counts cover any already-emitted
-            # output (preemption-resume rows), prompt_seen the prompt
-            last = adjust_logits(
-                last, sampling, out_counts, prompt_seen,
-                starts + lengths - sampling.prompt_len, eos_id)
-        if guided:
-            # first output token: mask from each guided row's start state
-            nxt_row = guide_next[guide_id, guide_state, :]
-            is_g = (guide_id > 0)[:, None]
-            last = jnp.where(is_g & (nxt_row < 0), -jnp.inf, last)
-        ids = sample(last, sampling, key,
-                     positions=starts + jnp.maximum(lengths, 1))
-        lsm = jax.nn.log_softmax(last, axis=-1)
-        lp = jnp.take_along_axis(lsm, ids[:, None], axis=-1)[:, 0]
-        if topk:
-            tl, ti = jax.lax.top_k(lsm, topk)
-        else:
-            B2 = last.shape[0]
-            tl = jnp.zeros((B2, 1), jnp.float32)
-            ti = jnp.zeros((B2, 1), jnp.int32)
-        return ids, lp, ti, tl, cache
+        with jax.named_scope("sample"):
+            last = jnp.take_along_axis(
+                logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
+            )[:, 0, :]
+            if penalized:
+                # first sampled token: counts cover any already-emitted
+                # output (preemption-resume rows), prompt_seen the prompt
+                last = adjust_logits(
+                    last, sampling, out_counts, prompt_seen,
+                    starts + lengths - sampling.prompt_len, eos_id)
+            if guided:
+                # first output token: mask from each guided row's
+                # start state
+                nxt_row = guide_next[guide_id, guide_state, :]
+                is_g = (guide_id > 0)[:, None]
+                last = jnp.where(is_g & (nxt_row < 0), -jnp.inf, last)
+            ids = sample(last, sampling, key,
+                         positions=starts + jnp.maximum(lengths, 1))
+            lsm = jax.nn.log_softmax(last, axis=-1)
+            lp = jnp.take_along_axis(lsm, ids[:, None], axis=-1)[:, 0]
+            if topk:
+                tl, ti = jax.lax.top_k(lsm, topk)
+            else:
+                B2 = last.shape[0]
+                tl = jnp.zeros((B2, 1), jnp.float32)
+                ti = jnp.zeros((B2, 1), jnp.int32)
+            return ids, lp, ti, tl, cache
 
     # ------------------------------------------------------------------
     # host API
@@ -707,11 +722,11 @@ class ModelRunner:
                             " penalized" if penalized else "",
                             f" topk={topk}" if topk else "")
                 return jax.jit(
-                    partial(self._decode_spec_impl, steps=steps,
-                            kv_len=kv_len, spec=spec, mixed=mixed,
-                            seeded=seeded, guided=guided, plain=plain,
-                            penalized=penalized, eos_id=self._eos_id,
-                            topk=topk),
+                    _named("decode_spec_window", self._decode_spec_impl,
+                           steps=steps, kv_len=kv_len, spec=spec,
+                           mixed=mixed, seeded=seeded, guided=guided,
+                           plain=plain, penalized=penalized,
+                           eos_id=self._eos_id, topk=topk),
                     donate_argnums=(1,))
 
             fn = self._compile(self._decode_fns, key, make_spec, args,
@@ -740,10 +755,10 @@ class ModelRunner:
                         " guided" if guided else "",
                         " penalized" if penalized else "")
             return jax.jit(
-                partial(self._decode_impl, steps=steps, kv_len=kv_len,
-                        greedy=greedy, seeded=seeded, guided=guided,
-                        plain=plain, penalized=penalized,
-                        eos_id=self._eos_id, topk=topk),
+                _named("decode_window", self._decode_impl, steps=steps,
+                       kv_len=kv_len, greedy=greedy, seeded=seeded,
+                       guided=guided, plain=plain, penalized=penalized,
+                       eos_id=self._eos_id, topk=topk),
                 donate_argnums=(1,))
 
         fn = self._compile(self._decode_fns, cache_key, make_decode,
@@ -836,9 +851,10 @@ class ModelRunner:
             logger.info("compiling prefill (chunk=%d kv=%d%s%s)", Tb,
                         kv_len, " guided" if guided else "",
                         " penalized" if penalized else "")
-            return jax.jit(partial(self._prefill_impl, kv_len=kv_len,
-                                   guided=guided, penalized=penalized,
-                                   eos_id=self._eos_id, topk=topk),
+            return jax.jit(_named("prefill_chunk", self._prefill_impl,
+                                  kv_len=kv_len, guided=guided,
+                                  penalized=penalized,
+                                  eos_id=self._eos_id, topk=topk),
                            donate_argnums=(1,))
 
         fn = self._compile(
@@ -862,7 +878,7 @@ class ModelRunner:
         if fn is None:
             logger.info("compiling embed (batch=%d len=%d)", N, Tb)
 
-            def _impl(params, toks, lens):
+            def embed(params, toks, lens):
                 mask = (jnp.arange(Tb)[None, :] < lens[:, None])
                 h = llama.encode(params, self.model_cfg, toks,
                                  rope=self.rope, token_valid=mask)
@@ -870,7 +886,7 @@ class ModelRunner:
                     h.astype(jnp.float32) * mask[:, :, None], axis=1)
                 return pooled / jnp.maximum(lens, 1)[:, None]
 
-            fn = self._embed_fns[(N, Tb)] = jax.jit(_impl)
+            fn = self._embed_fns[(N, Tb)] = jax.jit(embed)
         return fn(self.params, jnp.asarray(tokens, jnp.int32),
                   jnp.asarray(lengths, jnp.int32))
 
@@ -901,7 +917,7 @@ class ModelRunner:
             C = min(256, Tb)
             n_chunks = -(-(Tb - 1) // C)
 
-            def _impl(params, toks):
+            def prompt_logprobs(params, toks):
                 h = llama.encode(params, self.model_cfg, toks,
                                  rope=self.rope)
                 hh = h[:, :-1]
@@ -924,7 +940,8 @@ class ModelRunner:
                 _, lps = jax.lax.scan(body, None, (hh, tg))
                 return lps.transpose(1, 0, 2).reshape(N, -1)[:, :Tb - 1]
 
-            fn = self._prompt_lp_fns[(N, Tb)] = jax.jit(_impl)
+            fn = self._prompt_lp_fns[(N, Tb)] = jax.jit(
+                prompt_logprobs)
         return fn(self.params, jnp.asarray(pad, jnp.int32))
 
     def _slot_block_offsets(self, tables, slot, start, size: int):
@@ -944,7 +961,7 @@ class ModelRunner:
         np.asarray() later blocks."""
         fn = self._extract_fns.get(size)
         if fn is None:
-            def _impl(cache: KVCache, tables, slot, start):
+            def kv_extract(cache: KVCache, tables, slot, start):
                 blk, off = self._slot_block_offsets(tables, slot, start,
                                                     size)
                 # advanced indices (block, offset) put [size] first:
@@ -965,7 +982,7 @@ class ModelRunner:
                          * vs[..., None]).astype(jnp.bfloat16)
                 return k, v
 
-            fn = self._extract_fns[size] = jax.jit(_impl)
+            fn = self._extract_fns[size] = jax.jit(kv_extract)
         return fn(self.cache, self._dev_tables(), jnp.int32(slot),
                   jnp.int32(start))
 
@@ -977,8 +994,8 @@ class ModelRunner:
         size = k_chunk.shape[1]
         fn = self._inject_fns.get(size)
         if fn is None:
-            def _impl(cache: KVCache, tables, k_chunk, v_chunk, slot,
-                      start):
+            def kv_inject(cache: KVCache, tables, k_chunk, v_chunk, slot,
+                          start):
                 blk, off = self._slot_block_offsets(tables, slot, start,
                                                     size)
                 if cache.quantized:
@@ -1004,7 +1021,7 @@ class ModelRunner:
                 v = cache.v.at[:, blk, :, off, :].set(vc)
                 return KVCache(k, v)
 
-            fn = self._inject_fns[size] = jax.jit(_impl,
+            fn = self._inject_fns[size] = jax.jit(kv_inject,
                                                   donate_argnums=(0,))
         self.cache = fn(self.cache, self._dev_tables(), jnp.asarray(k_chunk),
                         jnp.asarray(v_chunk), jnp.int32(slot),

@@ -70,6 +70,24 @@ class SamplingOptions:
 
 
 @dataclass
+class RequestWaits:
+    """Stamps (monotonic) of the waits a request sits through inside
+    the phases of its trace; the server writes them as event spans
+    (docs/observability.md "Tracing"). One object per sequence, shared
+    by reference with its StepOutputs, so that AsyncLLMEngine can stamp
+    the two emits after ``step()`` has returned."""
+    locked: Optional[float] = None      # add_request holds the engine lock
+    first_look: Optional[float] = None  # first schedule() pass after that
+    first_pass: int = 0                 # ... and that pass's number
+    refused_passes: int = 0             # passes that left it waiting
+    refused_reason: Optional[str] = None    # the last one's: slot | kv
+    prefill_call: Optional[float] = None    # first runner.prefill returned
+    prefill_chunks: int = 0
+    first_emit: Optional[float] = None  # first token put on the queue
+    last_emit: Optional[float] = None   # last one
+
+
+@dataclass
 class Sequence:
     seq_id: str
     prompt_tokens: List[int]
@@ -113,6 +131,7 @@ class Sequence:
     queue_wait_s: float = 0.0
     admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
+    waits: RequestWaits = field(default_factory=RequestWaits)
     finish_reason: Optional[str] = None
     # KV-tier prefetch cost paid for this request at add time
     # (kvcache/connector.py): wall seconds of the tier walk and the
@@ -192,6 +211,11 @@ class Scheduler:
         # admission gate (can_admit): a waiter + free slot does not
         # imply the next pass admits (read by engine._admission_imminent)
         self.kv_deferred = False
+        # for RequestWaits: schedule() passes so far, why the last one
+        # left sequences waiting, and the sequences no pass has seen yet
+        self.passes = 0
+        self._refusal = "slot"
+        self._unseen: List[Sequence] = []
         self._prefilling: Dict[int, Sequence] = {}    # slot -> seq
         # invoked right after a slot is assigned, before the first prefill
         # chunk is cut — may rewind seq.num_prefilled past a cached prefix
@@ -227,6 +251,7 @@ class Scheduler:
             self.waiting.append(seq)
         else:
             self.waiting.insert(i, seq)
+        self._unseen.append(seq)
 
     def abort(self, seq_id: str) -> bool:
         for seq in list(self.waiting):
@@ -306,6 +331,13 @@ class Scheduler:
         """
         works = [self._chunk_of(seq) for seq in self._prefilling.values()]
         self.kv_deferred = False
+        self.passes += 1
+        if self._unseen:
+            now = time.monotonic()
+            for seq in self._unseen:
+                seq.waits.first_look = now
+                seq.waits.first_pass = self.passes
+            self._unseen.clear()
         while self.waiting and self.free_slots:
             seq = self.waiting[0]
             if self.can_admit is not None and not self.can_admit(seq):
@@ -318,12 +350,17 @@ class Scheduler:
             self.waiting.popleft()
             seq.slot = self.free_slots.pop()
             seq.status = SeqStatus.PREFILLING
+            if seq.admit_time is None and self.passes > seq.waits.first_pass:
+                seq.waits.refused_passes = (self.passes
+                                            - seq.waits.first_pass)
+                seq.waits.refused_reason = self._refusal
             seq.admit_time = time.monotonic()
             seq.queue_wait_s += seq.admit_time - seq.enqueued_time
             self._prefilling[seq.slot] = seq
             if self.on_admit is not None:
                 self.on_admit(seq)
             works.append(self._chunk_of(seq))
+        self._refusal = "kv" if self.kv_deferred else "slot"
         return works, list(self.running.values())
 
     def _chunk_of(self, seq: Sequence) -> PrefillWork:

@@ -15,6 +15,7 @@ import argparse
 import asyncio
 import json
 import math
+import tempfile
 import time
 from contextlib import aclosing
 from typing import List, Optional
@@ -38,6 +39,11 @@ logger = init_logger(__name__)
 
 ENGINE_KEY = web.AppKey("engine", AsyncLLMEngine)
 TRACER_KEY = web.AppKey("tracer", TraceRecorder)
+# held while POST /debug/profile captures: one capture at a time
+PROFILE_LOCK_KEY = web.AppKey("profile_lock", asyncio.Lock)
+# the longest capture POST /debug/profile takes (a trace of a busy chip
+# grows by tens of MB a second)
+PROFILE_MAX_S = 60.0
 
 # paths whose requests get an engine-side trace (tracing.py): the
 # generation endpoints the router's span chain continues into
@@ -75,6 +81,11 @@ def _seal_engine_trace(tracer: TraceRecorder, trace, request: web.Request,
 
     Requests that never produced a sequence (400s, sheds, deadline
     504s) get a single ``preprocess`` phase covering their whole life.
+
+    The waits a request sits through INSIDE those phases ride along as
+    EVENT spans (scheduler.RequestWaits): ``lock_wait`` and
+    ``sched_wait`` in ``queue_wait``, ``prefill_wait`` in ``prefill``,
+    ``first_token_emit`` in ``decode``, ``emit_lag`` in ``postprocess``.
 
     XLA compiles that overlapped this request's life are attached as
     ``xla_compile`` EVENT spans (engine/efficiency.py keeps the bounded
@@ -119,6 +130,7 @@ def _seal_engine_trace(tracer: TraceRecorder, trace, request: web.Request,
                 "kv_prefetch", None, timing["kv_prefetch_wait_s"],
                 attrs={"cached_tokens": timing.get("kv_cached_tokens",
                                                    0)})
+        _add_wait_events(trace, timing)
         trace.attrs["prompt_tokens"] = timing.get("prompt_tokens")
         trace.attrs["output_tokens"] = timing.get("output_tokens")
     else:
@@ -126,6 +138,29 @@ def _seal_engine_trace(tracer: TraceRecorder, trace, request: web.Request,
     if tok_s:
         trace.add_event("tokenize", None, tok_s)
     tracer.finish(trace, status)
+
+
+def _add_wait_events(trace, timing: dict) -> None:
+    """The request's waits as event spans, each from the stamps that
+    exist (a request dropped while waiting has no prefill, one served
+    without AsyncLLMEngine no emit)."""
+    waits = timing.get("waits")
+    if waits is None:
+        return
+
+    def event(name, start, end, **attrs):
+        if start is not None and end is not None:
+            trace.add_event(name, start, max(0.0, end - start),
+                            attrs=attrs or None)
+
+    event("lock_wait", timing["arrival"], waits.locked)
+    event("sched_wait", waits.locked, waits.first_look,
+          refused_passes=waits.refused_passes,
+          reason=waits.refused_reason)
+    event("prefill_wait", timing["admit"], waits.prefill_call,
+          chunks=waits.prefill_chunks)
+    event("first_token_emit", timing["first_token"], waits.first_emit)
+    event("emit_lag", timing["end"], waits.last_emit)
 
 
 def _trace_middleware(tracer: TraceRecorder):
@@ -1132,7 +1167,8 @@ async def version(request: web.Request) -> web.Response:
 
 async def debug_perf(request: web.Request) -> web.Response:
     """``GET /debug/perf``: the engine-efficiency ring — recent
-    window-level real/pad/dead breakdowns, recent XLA compile events,
+    window-level real/pad/dead breakdowns, the step timeline's recent
+    steps (seconds per phase), recent XLA compile events,
     cumulative totals + rates, the KV block pool's fragmentation
     census, and the ``device`` block (platform, device kind and count,
     bytes in use per device, the attention path of every compiled
@@ -1151,9 +1187,51 @@ async def debug_perf(request: web.Request) -> web.Response:
         "totals": eng.eff.report(),
         "rates": eng.eff.rates(),
         "windows": eng.eff.recent_windows(limit),
+        "steps": eng.eff.recent_steps(limit),
         "compiles": eng.eff.recent_compiles(limit),
         "kv_pool": eng.block_mgr.frag_report(),
     })
+
+
+async def debug_profile(request: web.Request) -> web.Response:
+    """``POST /debug/profile {"seconds": n}``: hold ``jax.profiler``
+    for ``n`` seconds (default 3, at most PROFILE_MAX_S) and answer
+    with the directory the capture was written to (under the process's
+    temporary directory; open it with TensorBoard's profile plugin or
+    ``jax.profiler.ProfileData``). The capture holds the device's
+    executables by name (``jit_decode_window``, ``jit_prefill_chunk``),
+    their ``jax.named_scope`` paths, and the step timeline's phases as
+    ``pstpu.*`` intervals on the host plane. 409 while another capture
+    runs. Operator surface: same auth posture as the rest of /debug."""
+    try:
+        body = await request.json()
+    except ValueError:
+        body = {}
+    try:
+        seconds = float(body.get("seconds", 3.0))
+    except (AttributeError, TypeError, ValueError):
+        return _error(400, 'body must be {"seconds": <number>}')
+    if not 0.0 < seconds <= PROFILE_MAX_S:
+        return _error(400, f"seconds must be in (0, {PROFILE_MAX_S:g}]")
+    lock = request.app[PROFILE_LOCK_KEY]
+    if lock.locked():
+        return _error(409, "a profile capture is already running")
+    async with lock:
+        import jax
+        out_dir = tempfile.mkdtemp(prefix="pstpu-profile-")
+        try:
+            jax.profiler.start_trace(out_dir)
+        except RuntimeError as e:
+            # the profiler is held from elsewhere in this process (a
+            # client of jax.profiler.start_server, a launcher's hook)
+            return _error(409, f"the profiler is busy: {e}")
+        started_unix = time.time()
+        try:
+            await asyncio.sleep(seconds)
+        finally:
+            await asyncio.to_thread(jax.profiler.stop_trace)
+    return web.json_response({"dir": out_dir, "seconds": seconds,
+                              "started_unix": round(started_unix, 4)})
 
 
 async def metrics(request: web.Request) -> web.Response:
@@ -1283,8 +1361,9 @@ async def detokenize(request: web.Request) -> web.Response:
 # helm/templates/deployment-vllm-multi.yaml:143-150 + probe blocks)
 AUTH_EXEMPT_PATHS = frozenset({"/health", "/metrics", "/version",
                                "/load"})
-# NOTE: the /debug namespace (/debug/traces, /debug/perf) is
-# deliberately NOT exempt — /debug/traces carries per-request data
+# NOTE: the /debug namespace (/debug/traces, /debug/perf,
+# /debug/profile) is deliberately NOT exempt — /debug/traces carries
+# per-request data
 # (trace ids, timings, token counts) and /debug/perf shares the
 # operator-surface posture; readers on a secured deployment present
 # the engine key
@@ -1347,9 +1426,11 @@ def build_app(engine: AsyncLLMEngine,
                           middlewares=middlewares)
     app[ENGINE_KEY] = engine
     app[TRACER_KEY] = tracer
+    app[PROFILE_LOCK_KEY] = asyncio.Lock()
     app.router.add_get("/debug/traces",
                        debug_traces_handler(lambda: tracer))
     app.router.add_get("/debug/perf", debug_perf)
+    app.router.add_post("/debug/profile", debug_profile)
     app.router.add_post("/v1/chat/completions", chat_completions)
     app.router.add_post("/v1/completions", completions)
     app.router.add_post("/v1/embeddings", embeddings)

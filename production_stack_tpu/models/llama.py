@@ -193,6 +193,13 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     expert-capacity competition.
     lora_layer: this layer's stacked adapters {proj: {a, b}} + per-row
     adapter_ids [B] (models/lora.py) — batched multi-LoRA.
+
+    Each stage runs under a ``jax.named_scope`` (attn_norm, qkv_proj,
+    rope, kv_write, attention, o_proj, mlp_norm, then mlp or
+    moe_router / moe_experts / moe_combine / shared_expert): the names
+    are metadata on the compiled operations, where a profiler capture
+    finds them (docs/observability.md "Names on the device"); they cost
+    nothing at run time.
     """
     B, T, _ = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
@@ -209,12 +216,16 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
         return out
 
     offset = 1.0 if cfg.rms_norm_offset else 0.0
-    hidden = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, offset=offset)
-    q = proj(hidden, "q").reshape(B, T, nh, hd)
-    k = proj(hidden, "k").reshape(B, T, nkv, hd)
-    v = proj(hidden, "v").reshape(B, T, nkv, hd)
-    q = apply_rope(q, positions, cos, sin)
-    k = apply_rope(k, positions, cos, sin)
+    with jax.named_scope("attn_norm"):
+        hidden = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                          offset=offset)
+    with jax.named_scope("qkv_proj"):
+        q = proj(hidden, "q").reshape(B, T, nh, hd)
+        k = proj(hidden, "k").reshape(B, T, nkv, hd)
+        v = proj(hidden, "v").reshape(B, T, nkv, hd)
+    with jax.named_scope("rope"):
+        q = apply_rope(q, positions, cos, sin)
+        k = apply_rope(k, positions, cos, sin)
 
     # Gemma-2 deviations from the Llama baseline: attention scale from
     # query_pre_attn_scalar, tanh score softcap, and (alternating)
@@ -234,27 +245,29 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
         return attn_fn_w(sw)
 
     if kv is None:
-        if attention_fn is not None:
-            attn = attention_fn(q, k, v)
-        else:
-            attn = _windowed(lambda w: causal_attention(
-                q, k, v, scale=scale_val, sliding_window=w,
-                logit_softcap=cap))
+        with jax.named_scope("attention"):
+            if attention_fn is not None:
+                attn = attention_fn(q, k, v)
+            else:
+                attn = _windowed(lambda w: causal_attention(
+                    q, k, v, scale=scale_val, sliding_window=w,
+                    logit_softcap=cap))
         new_kv = None
     else:
         quant_kv = len(kv) == 4   # (k, v, ks, vs): int8 pool + scales
-        if quant_kv:
-            k_cache, k_scales = write_chunk_q(
-                kv[0], kv[2], k, block_tables, positions,
-                valid=token_valid)
-            v_cache, v_scales = write_chunk_q(
-                kv[1], kv[3], v, block_tables, positions,
-                valid=token_valid)
-        else:
-            k_cache = write_chunk(kv[0], k, block_tables, positions,
-                                  valid=token_valid)
-            v_cache = write_chunk(kv[1], v, block_tables, positions,
-                                  valid=token_valid)
+        with jax.named_scope("kv_write"):
+            if quant_kv:
+                k_cache, k_scales = write_chunk_q(
+                    kv[0], kv[2], k, block_tables, positions,
+                    valid=token_valid)
+                v_cache, v_scales = write_chunk_q(
+                    kv[1], kv[3], v, block_tables, positions,
+                    valid=token_valid)
+            else:
+                k_cache = write_chunk(kv[0], k, block_tables, positions,
+                                      valid=token_valid)
+                v_cache = write_chunk(kv[1], v, block_tables, positions,
+                                      valid=token_valid)
         Bs = k_cache.shape[2]
         MB = block_tables.shape[1]
         nb = MB if kv_len is None else min(-(-kv_len // Bs), MB)
@@ -299,20 +312,25 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                                         sliding_window=w,
                                         logit_softcap=cap)
 
-        attn = _windowed(cached_attn)
+        with jax.named_scope("attention"):
+            attn = _windowed(cached_attn)
         new_kv = ((k_cache, v_cache, k_scales, v_scales) if quant_kv
                   else (k_cache, v_cache))
-    o_out = proj(attn.reshape(B, T, nh * hd), "o")
-    if cfg.sandwich_norms:
-        # Gemma-2: normalize the attention OUTPUT before the residual
-        o_out = rms_norm(o_out, lp["post_attn_norm"], cfg.rms_norm_eps,
-                         offset=offset)
-    x = x + o_out
+    with jax.named_scope("o_proj"):
+        o_out = proj(attn.reshape(B, T, nh * hd), "o")
+        if cfg.sandwich_norms:
+            # Gemma-2: normalize the attention OUTPUT before the residual
+            o_out = rms_norm(o_out, lp["post_attn_norm"],
+                             cfg.rms_norm_eps, offset=offset)
+        x = x + o_out
 
-    hidden = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, offset=offset)
+    with jax.named_scope("mlp_norm"):
+        hidden = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps,
+                          offset=offset)
     act = jax.nn.silu if cfg.activation == "silu" else _gelu_tanh
     if cfg.num_experts:
         H = hidden.shape[-1]
+        # scopes moe_router / moe_experts / moe_combine: ops/moe.py
         y = moe.moe_mlp(
             hidden.reshape(B * T, H), lp["router"], lp["gate"],
             lp["up"], lp["down"], top_k=cfg.num_experts_per_tok,
@@ -326,22 +344,24 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
         if cfg.shared_expert_size:
             # Qwen2-MoE: an always-on shared expert, scaled by a
             # per-token sigmoid gate
-            shared = quant.dequant_matmul(
-                act(quant.dequant_matmul(hidden, lp["s_gate"]))
-                * quant.dequant_matmul(hidden, lp["s_up"]),
-                lp["s_down"])
-            y = y.reshape(B, T, H) + jax.nn.sigmoid(
-                hidden @ lp["s_gate_w"]) * shared
+            with jax.named_scope("shared_expert"):
+                shared = quant.dequant_matmul(
+                    act(quant.dequant_matmul(hidden, lp["s_gate"]))
+                    * quant.dequant_matmul(hidden, lp["s_up"]),
+                    lp["s_down"])
+                y = y.reshape(B, T, H) + jax.nn.sigmoid(
+                    hidden @ lp["s_gate_w"]) * shared
             x = x + y
         else:
             x = x + y.reshape(B, T, H)
     else:
-        gated = act(proj(hidden, "gate")) * proj(hidden, "up")
-        mlp_out = proj(gated, "down")
-        if cfg.sandwich_norms:
-            mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"],
-                               cfg.rms_norm_eps, offset=offset)
-        x = x + mlp_out
+        with jax.named_scope("mlp"):
+            gated = act(proj(hidden, "gate")) * proj(hidden, "up")
+            mlp_out = proj(gated, "down")
+            if cfg.sandwich_norms:
+                mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"],
+                                   cfg.rms_norm_eps, offset=offset)
+            x = x + mlp_out
     return x, new_kv
 
 
@@ -391,7 +411,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         n_per = (cache.k.shape[1] - 1) // B
         block_tables = linear_tables(B, n_per * Bs, Bs)
     starts = positions[:, 0]
-    x = _embed(params, cfg, tokens)
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, tokens)
 
     quant_kv = cache.quantized
     has_lora = lora_params is not None
@@ -426,10 +447,13 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if alternating:
         # Gemma-2 layer pattern: even layers sliding, odd global
         xs = xs + (jnp.arange(cfg.num_layers) % 2 == 0,)
-    x, new = jax.lax.scan(scan_body, x, xs)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
-                 offset=1.0 if cfg.rms_norm_offset else 0.0)
-    logits = _lm_head(params, cfg, x)
+    with jax.named_scope("layers"):
+        x, new = jax.lax.scan(scan_body, x, xs)
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
+                     offset=1.0 if cfg.rms_norm_offset else 0.0)
+    with jax.named_scope("lm_head"):
+        logits = _lm_head(params, cfg, x)
     new_cache = (KVCache(k=new[0], v=new[1], ks=new[2], vs=new[3])
                  if quant_kv else KVCache(k=new[0], v=new[1]))
     return logits, new_cache

@@ -107,14 +107,16 @@ def _moe_exact(x, top_p, top_i, gate, up, down, act):
     """All experts over all tokens, combined by routing weight."""
     N = x.shape[0]
     E = _wshape(gate)[0]
-    # combine [N, E]: routing weight where selected, else 0
-    combine = jnp.zeros((N, E), jnp.float32)
-    combine = combine.at[
-        jnp.arange(N)[:, None], top_i].set(top_p)
-    xb = jnp.broadcast_to(x, (E,) + x.shape)            # [E, N, h]
-    y_e = _expert_ffn(xb, gate, up, down, act)          # [E, N, h]
-    return jnp.einsum("enh,ne->nh", y_e,
-                      combine.astype(x.dtype))
+    with jax.named_scope("moe_experts"):
+        xb = jnp.broadcast_to(x, (E,) + x.shape)        # [E, N, h]
+        y_e = _expert_ffn(xb, gate, up, down, act)      # [E, N, h]
+    with jax.named_scope("moe_combine"):
+        # combine [N, E]: routing weight where selected, else 0
+        combine = jnp.zeros((N, E), jnp.float32)
+        combine = combine.at[
+            jnp.arange(N)[:, None], top_i].set(top_p)
+        return jnp.einsum("enh,ne->nh", y_e,
+                          combine.astype(x.dtype))
 
 
 def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
@@ -144,12 +146,14 @@ def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
     x_rep = jnp.repeat(x, k, axis=0)                    # [N*k, h]
     buf = jnp.zeros((E * capacity + 1, h), x.dtype).at[dest].set(x_rep)
     xb = buf[:-1].reshape(E, capacity, h)
-    y_e = _expert_ffn(xb, gate, up, down, act)          # [E, C, h]
-    y_flat = jnp.concatenate(
-        [y_e.reshape(E * capacity, h), jnp.zeros((1, h), y_e.dtype)])
-    y_rep = y_flat[dest]                                # dropped -> zeros
-    w = top_p.reshape(-1)[:, None].astype(x.dtype)
-    return jnp.sum((y_rep * w).reshape(N, k, h), axis=1)
+    with jax.named_scope("moe_experts"):
+        y_e = _expert_ffn(xb, gate, up, down, act)      # [E, C, h]
+    with jax.named_scope("moe_combine"):
+        y_flat = jnp.concatenate(
+            [y_e.reshape(E * capacity, h), jnp.zeros((1, h), y_e.dtype)])
+        y_rep = y_flat[dest]                            # dropped -> zeros
+        w = top_p.reshape(-1)[:, None].astype(x.dtype)
+        return jnp.sum((y_rep * w).reshape(N, k, h), axis=1)
 
 
 def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
@@ -168,9 +172,10 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
     """
     N = x.shape[0]
     E = _wshape(gate)[0]
-    top_p, top_i = route(x, router_w, top_k, renormalize=renormalize)
-    if valid is not None:
-        top_p = top_p * valid.astype(top_p.dtype)[:, None]
+    with jax.named_scope("moe_router"):
+        top_p, top_i = route(x, router_w, top_k, renormalize=renormalize)
+        if valid is not None:
+            top_p = top_p * valid.astype(top_p.dtype)[:, None]
     capacity = capacity_for(N, E, top_k, capacity_factor)
     if exact is None:
         exact = N <= dense_threshold or capacity >= N

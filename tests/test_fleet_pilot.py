@@ -719,10 +719,13 @@ def test_migrate_out_retires_least_recently_active_first():
     else:
         pytest.fail("sequences never all reached decode")
     # stamp activity out of order vs both arrival and block count:
-    # b is coldest, then a; c is hottest
-    eng.seqs[sids["a"]].last_active = 200.0
-    eng.seqs[sids["b"]].last_active = 100.0
-    eng.seqs[sids["c"]].last_active = 300.0
+    # b is coldest, then a; c is hottest. Relative to the clock the
+    # engine stamps with: a machine that started a minute ago reads
+    # time.monotonic() below any fixed number of seconds
+    now = time.monotonic()
+    eng.seqs[sids["a"]].last_active = now - 200.0
+    eng.seqs[sids["b"]].last_active = now - 300.0
+    eng.seqs[sids["c"]].last_active = now - 100.0
     out = eng.migrate_out(max_seqs=2)
     assert out["migrated"] == [sids["b"], sids["a"]]
     assert out["freed_blocks"] > 0
