@@ -20,6 +20,26 @@ TPU-first invariants:
   parked rows, padding tokens, and beyond-capacity window tails are
   routed to it via the ``valid`` mask. Invalid writes all land in a
   block no table references.
+- **The pool is carried, never stacked.** A step program takes the
+  whole ``[L, N, Hkv, Bs, D]`` pool as a donated argument and gives it
+  back as its result, and in between it is ONE buffer: the layer loop
+  (``models/llama.forward``) carries it next to the hidden state, each
+  layer appends its chunk in place (``append_chunk(..., layer=i)``)
+  and the kernels stream blocks from ``pool[layer]`` through their
+  index maps. No layer's pool is ever sliced out or stacked back: a
+  scan's ``xs`` cannot alias its ``ys``, and handing the pool through
+  them cost a read and a write of a layer's pool per layer and of the
+  whole pool per step — half of a decode step on the chip (PERF.md,
+  PR 25).
+- **Appends rewrite whole blocks.** A token's K/V is ``Hkv`` rows of
+  ``D``, one in each head's panel of its block. Scattered token by
+  token (``pool.at[layer, blk, :, off].set``), the TPU compiler wants
+  the pool token-major ([.., Bs, Hkv, D]) for the scatter and
+  head-major for the kernel, and copies the whole pool between the two
+  layouts around every layer's write. ``append_chunk`` instead gathers
+  the few blocks a chunk touches, merges the new rows into them and
+  scatters whole ``[Hkv, Bs, D]`` blocks back: every pool-shaped
+  operation keeps the pool's own layout and updates it in place.
 - Reads go through the Pallas paged kernel (blocks streamed straight
   from the pool through scalar-prefetched tables — each KV byte read
   once) or, on backends/meshes the kernel does not cover, a *gathered
@@ -155,6 +175,75 @@ def write_chunk(cache_layer: jnp.ndarray, new: jnp.ndarray,
         new.reshape((B * T,) + new.shape[2:]))
 
 
+def _span_addresses(tables: jnp.ndarray, starts: jnp.ndarray, T: int,
+                    block_size: int, valid: Optional[jnp.ndarray],
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """_chunk_addresses by block, for rows of T CONTIGUOUS positions
+    starts[b]..starts[b]+T-1: the J blocks such a span can touch as
+    (pool block ids [B, J], the token each of their rows takes
+    [B, J*Bs], which rows take one [B, J, Bs]). Same contract: a row is
+    live only for a valid token at a position in [0, MB*Bs); a (b, j)
+    with no live row is routed to trash block 0, so every real block
+    appears at most once."""
+    Bs, (B, MB) = block_size, tables.shape
+    J = (T + Bs - 2) // Bs + 1
+    vb = starts[:, None] // Bs + jnp.arange(J)                # [B, J]
+    pos = vb[..., None] * Bs + jnp.arange(Bs)                 # [B, J, Bs]
+    t = pos - starts[:, None, None]
+    live = (t >= 0) & (t < T) & (pos >= 0) & (pos < MB * Bs)
+    t = jnp.clip(t, 0, T - 1).reshape(B, J * Bs)
+    if valid is not None:
+        live = live & jnp.take_along_axis(valid, t, axis=1
+                                          ).reshape(B, J, Bs)
+    ids = jnp.take_along_axis(tables, jnp.clip(vb, 0, MB - 1), axis=1)
+    return jnp.where(live.any(-1), ids, 0), t, live
+
+
+def _merge_blocks(pool: jnp.ndarray, new: jnp.ndarray, layer,
+                  ids: jnp.ndarray, t: jnp.ndarray,
+                  live: jnp.ndarray) -> jnp.ndarray:
+    """pool [L,N,Hkv,Bs(,D)] with the live rows of blocks ``ids`` of
+    ``layer`` taken from new [B,T,Hkv(,D)]: the blocks gathered, merged
+    and scattered back whole, in place."""
+    B, J, Bs = live.shape
+    tail = (1,) * (new.ndim - 3)                              # D, if any
+    rows = jnp.take_along_axis(new.astype(pool.dtype),
+                               t.reshape(B, J * Bs, 1, *tail), axis=1)
+    # [B, J*Bs, Hkv(, D)] -> head-major blocks [B, J, Hkv, Bs(, D)]
+    rows = jnp.moveaxis(rows.reshape((B, J, Bs) + new.shape[2:]), 2, 3)
+    return pool.at[layer, ids].set(
+        jnp.where(live.reshape(B, J, 1, Bs, *tail), rows,
+                  pool[layer, ids]))
+
+
+def append_chunk(pool: jnp.ndarray, new: jnp.ndarray,
+                 tables: jnp.ndarray, starts: jnp.ndarray,
+                 valid: Optional[jnp.ndarray], layer) -> jnp.ndarray:
+    """The serving path's write: new [B,T,Hkv,D] — row b's tokens at
+    the contiguous positions starts[b]..starts[b]+T-1 — into layer
+    ``layer`` of the whole pool [L,N,Hkv,Bs,D], which comes back
+    updated in place (module text: appends rewrite whole blocks).
+    Every block a table references ends up as write_chunk would leave
+    it on that layer; invalid, negative and beyond-capacity tokens are
+    written nowhere a table points."""
+    return _merge_blocks(pool, new, layer, *_span_addresses(
+        tables, starts, new.shape[1], pool.shape[3], valid))
+
+
+def append_chunk_q(pool: jnp.ndarray, scales: jnp.ndarray,
+                   new: jnp.ndarray, tables: jnp.ndarray,
+                   starts: jnp.ndarray, valid: Optional[jnp.ndarray],
+                   layer) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """append_chunk for the int8 pool: quantize new [B,T,Hkv,D] and
+    append payload and scales ([L,N,Hkv,Bs,D] int8, [L,N,Hkv,Bs] f32)
+    through the same addresses."""
+    q, scale = quantize_chunk(new)
+    at = _span_addresses(tables, starts, new.shape[1], pool.shape[3],
+                         valid)
+    return (_merge_blocks(pool, q, layer, *at),
+            _merge_blocks(scales, scale, layer, *at))
+
+
 def quantize_chunk(new: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Symmetric per-(token, head) int8 over the head dim.
 
@@ -189,36 +278,42 @@ def write_chunk_q(cache_layer: jnp.ndarray, scale_layer: jnp.ndarray,
     return layer, scales
 
 
-def gather_view(cache_layer: jnp.ndarray, tables: jnp.ndarray,
-                nb: int) -> jnp.ndarray:
+def _blocks(pool: jnp.ndarray, tables: jnp.ndarray, nb: int, layer):
+    """The first nb blocks of every slot, [B, nb, Hkv, Bs(, D)]: from
+    one layer [N, ...] or, with ``layer``, straight out of the whole
+    pool [L, N, ...]."""
+    t = tables[:, :nb]
+    return pool[t] if layer is None else pool[layer, t]
+
+
+def gather_view(pool: jnp.ndarray, tables: jnp.ndarray,
+                nb: int, layer=None) -> jnp.ndarray:
     """Materialize the first nb blocks of every slot as a contiguous
-    [B, nb*Bs, Hkv, D] view; view index s is virtual position s.
+    [B, nb*Bs, Hkv, D] view; view index s is virtual position s. pool
+    is one layer [N,Hkv,Bs,D] or, with ``layer``, the whole pool.
     Unallocated table entries read trash block 0 — garbage that the
     causal position mask always hides (a query at position p only
     attends positions <= p, all of which are allocated and written)."""
-    Hkv, Bs = cache_layer.shape[1], cache_layer.shape[2]
-    t = tables[:, :nb]                                       # [B, nb]
-    g = cache_layer[t]                                       # [B,nb,Hkv,Bs,D]
+    g = _blocks(pool, tables, nb, layer)                     # [B,nb,Hkv,Bs,D]
+    B, _, Hkv, Bs, D = g.shape
     g = g.transpose(0, 1, 3, 2, 4)                           # [B,nb,Bs,Hkv,D]
-    return g.reshape(t.shape[0], nb * Bs, Hkv,
-                     cache_layer.shape[-1])
+    return g.reshape(B, nb * Bs, Hkv, D)
 
 
-def gather_view_q(cache_layer: jnp.ndarray, scale_layer: jnp.ndarray,
+def gather_view_q(pool: jnp.ndarray, scales: jnp.ndarray,
                   tables: jnp.ndarray, nb: int,
-                  dtype=jnp.bfloat16) -> jnp.ndarray:
+                  dtype=jnp.bfloat16, layer=None) -> jnp.ndarray:
     """gather_view for the int8 pool: dequantized [B, nb*Bs, Hkv, D]
     in `dtype`. The HBM read is int8 + one scale per vector — half the
     bf16 pool's traffic; the dequantized product is a fused temporary
     feeding attention, never resident."""
-    Hkv, Bs = cache_layer.shape[1], cache_layer.shape[2]
-    t = tables[:, :nb]
     # dequantize in f32 and cast the PRODUCT — the pallas kernels
     # dequantize at f32 too, so the fallback and kernel paths stay
     # numerically identical (greedy streams must not depend on which
     # backend served a window)
-    g = cache_layer[t].astype(jnp.float32)            # [B,nb,Hkv,Bs,D]
-    s = scale_layer[t].astype(jnp.float32)            # [B,nb,Hkv,Bs]
+    g = _blocks(pool, tables, nb, layer).astype(jnp.float32)
+    s = _blocks(scales, tables, nb, layer).astype(jnp.float32)
+    B, _, Hkv, Bs, D = g.shape                        # s [B,nb,Hkv,Bs]
     g = (g * s[..., None]).astype(dtype)
     g = g.transpose(0, 1, 3, 2, 4)
-    return g.reshape(t.shape[0], nb * Bs, Hkv, cache_layer.shape[-1])
+    return g.reshape(B, nb * Bs, Hkv, D)

@@ -25,9 +25,9 @@ import jax.numpy as jnp
 
 from production_stack_tpu.models import lora, quant
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.models.kv import (KVCache, gather_view,
-                                            gather_view_q, write_chunk,
-                                            write_chunk_q)
+from production_stack_tpu.models.kv import (KVCache, append_chunk,
+                                            append_chunk_q, gather_view,
+                                            gather_view_q)
 from production_stack_tpu.ops import moe, pallas_attention, pallas_paged
 from production_stack_tpu.ops.attention import attention_with_cache, causal_attention
 from production_stack_tpu.ops.norms import rms_norm
@@ -169,17 +169,21 @@ def attention_path(cfg: ModelConfig, T: int, block_size: int,
 def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                 positions: jnp.ndarray, starts: Optional[jnp.ndarray],
                 x: jnp.ndarray, lp: Params,
-                kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]],
+                kv: Optional[Tuple[jnp.ndarray, ...]],
                 attention_fn=None, kv_len: Optional[int] = None,
                 use_flash: bool = False, lora_layer=None,
                 adapter_ids: Optional[jnp.ndarray] = None,
                 lora_scaling: float = 1.0,
                 token_valid: Optional[jnp.ndarray] = None,
                 block_tables: Optional[jnp.ndarray] = None,
-                mesh=None, layer_local=None):
-    """One transformer block. x [B,T,H]; kv = this layer's paged pool
-    (k, v) [N,Bs,Hkv,D] addressed through block_tables [B,MB]
-    (models/kv.py).
+                mesh=None, layer_local=None, layer=None):
+    """One transformer block. x [B,T,H]; kv = the WHOLE paged pool
+    (k, v) [L,N,Hkv,Bs,D] — with (ks, vs) [L,N,Hkv,Bs] behind them for
+    the int8 pool — of which this block appends to and reads
+    ``layer`` (its index, traced), addressed through block_tables
+    [B,MB]. The pool comes back as the second result, the same buffer
+    with this layer's chunk written (models/kv.py: carried, never
+    stacked). kv None (encode, the pipeline stages): no cache.
 
     attention_fn(q, k, v) overrides the no-cache attention — used to swap
     in ring attention when the sequence dim is sharded (parallel/train.py).
@@ -257,18 +261,18 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
         quant_kv = len(kv) == 4   # (k, v, ks, vs): int8 pool + scales
         with jax.named_scope("kv_write"):
             if quant_kv:
-                k_cache, k_scales = write_chunk_q(
-                    kv[0], kv[2], k, block_tables, positions,
-                    valid=token_valid)
-                v_cache, v_scales = write_chunk_q(
-                    kv[1], kv[3], v, block_tables, positions,
-                    valid=token_valid)
+                k_cache, k_scales = append_chunk_q(
+                    kv[0], kv[2], k, block_tables, starts, token_valid,
+                    layer)
+                v_cache, v_scales = append_chunk_q(
+                    kv[1], kv[3], v, block_tables, starts, token_valid,
+                    layer)
             else:
-                k_cache = write_chunk(kv[0], k, block_tables, positions,
-                                      valid=token_valid)
-                v_cache = write_chunk(kv[1], v, block_tables, positions,
-                                      valid=token_valid)
-        Bs = k_cache.shape[2]
+                k_cache = append_chunk(kv[0], k, block_tables, starts,
+                                       token_valid, layer)
+                v_cache = append_chunk(kv[1], v, block_tables, starts,
+                                       token_valid, layer)
+        Bs = k_cache.shape[-2]
         MB = block_tables.shape[1]
         nb = MB if kv_len is None else min(-(-kv_len // Bs), MB)
 
@@ -277,11 +281,12 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
         def cached_attn(w):
             if path != JNP_GATHER:
                 # paged flash kernel: K/V blocks streamed straight from
-                # the pool through the tables — no gathered copy, no
-                # [T, S] score materialization, per-row causal block
-                # skipping. Covers prefill chunks AND decode/spec
-                # windows; under a tp-only mesh it runs shard-local per
-                # head via shard_map.
+                # pool[layer] through the tables — no slice of the
+                # pool, no gathered copy, no [T, S] score
+                # materialization, per-row causal block skipping.
+                # Covers prefill chunks AND decode/spec windows; under
+                # a tp-only mesh it runs shard-local per head via
+                # shard_map.
                 interp = pallas_attention.needs_interpret()
                 sc = (dict(k_scales=k_scales, v_scales=v_scales)
                       if quant_kv else {})
@@ -289,6 +294,7 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                     sc["window"] = w
                 sc["scale"] = scale_val
                 sc["softcap"] = cap or 0.0
+                sc["layer"] = layer
                 if mesh is None:
                     paged_fn = (pallas_paged.paged_decode_attention
                                 if path == "pallas_paged_decode"
@@ -301,12 +307,14 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                     nb=nb, interpret=interp, **sc)
             if quant_kv:
                 k_att = gather_view_q(k_cache, k_scales, block_tables,
-                                      nb, dtype=q.dtype)
+                                      nb, dtype=q.dtype, layer=layer)
                 v_att = gather_view_q(v_cache, v_scales, block_tables,
-                                      nb, dtype=q.dtype)
+                                      nb, dtype=q.dtype, layer=layer)
             else:
-                k_att = gather_view(k_cache, block_tables, nb)
-                v_att = gather_view(v_cache, block_tables, nb)
+                k_att = gather_view(k_cache, block_tables, nb,
+                                    layer=layer)
+                v_att = gather_view(v_cache, block_tables, nb,
+                                    layer=layer)
             return attention_with_cache(q, k_att, v_att, positions,
                                         scale=scale_val,
                                         sliding_window=w,
@@ -407,56 +415,41 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if block_tables is None:
         from production_stack_tpu.models.kv import linear_tables
         B = tokens.shape[0]
-        Bs = cache.k.shape[2]
+        Bs = cache.block_size
         n_per = (cache.k.shape[1] - 1) // B
         block_tables = linear_tables(B, n_per * Bs, Bs)
     starts = positions[:, 0]
     with jax.named_scope("embed"):
         x = _embed(params, cfg, tokens)
 
-    quant_kv = cache.quantized
-    has_lora = lora_params is not None
-    alternating = cfg.alternating_sliding
-    nkv_leaves = 4 if quant_kv else 2
-
     def scan_body(carry, xs):
-        i = 1
-        lp = xs[0]
-        kv_tuple = xs[i:i + nkv_leaves]
-        i += nkv_leaves
-        ll = None
-        if has_lora:
-            ll = xs[i]
-            i += 1
-        local = xs[i] if alternating else None
-        out, new_kv = _layer_body(cfg, rope, positions, starts, carry,
-                                  lp, kv_tuple, kv_len=kv_len,
-                                  use_flash=use_flash, lora_layer=ll,
-                                  adapter_ids=adapter_ids,
-                                  lora_scaling=lora_scaling,
-                                  token_valid=token_valid,
-                                  block_tables=block_tables,
-                                  mesh=mesh, layer_local=local)
-        return out, new_kv
+        # the pool rides in the CARRY, one buffer from the executable's
+        # donated argument to its result: as the scan's xs -> ys it was
+        # sliced, rewritten and stacked, a layer's pool per layer and
+        # the whole pool per step (models/kv.py)
+        h, pool = carry
+        lp, layer, ll, local = xs
+        return _layer_body(cfg, rope, positions, starts, h, lp, pool,
+                           kv_len=kv_len, use_flash=use_flash,
+                           lora_layer=ll, adapter_ids=adapter_ids,
+                           lora_scaling=lora_scaling,
+                           token_valid=token_valid,
+                           block_tables=block_tables, mesh=mesh,
+                           layer_local=local, layer=layer), None
 
-    xs = (params["layers"], cache.k, cache.v)
-    if quant_kv:
-        xs = xs + (cache.ks, cache.vs)
-    if has_lora:
-        xs = xs + (lora_params,)
-    if alternating:
-        # Gemma-2 layer pattern: even layers sliding, odd global
-        xs = xs + (jnp.arange(cfg.num_layers) % 2 == 0,)
+    layers = jnp.arange(cfg.num_layers)
+    xs = (params["layers"], layers, lora_params,
+          # Gemma-2 layer pattern: even layers sliding, odd global
+          layers % 2 == 0 if cfg.alternating_sliding else None)
+    pool = tuple(a for a in cache if a is not None)
     with jax.named_scope("layers"):
-        x, new = jax.lax.scan(scan_body, x, xs)
+        (x, pool), _ = jax.lax.scan(scan_body, (x, pool), xs)
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                      offset=1.0 if cfg.rms_norm_offset else 0.0)
     with jax.named_scope("lm_head"):
         logits = _lm_head(params, cfg, x)
-    new_cache = (KVCache(k=new[0], v=new[1], ks=new[2], vs=new[3])
-                 if quant_kv else KVCache(k=new[0], v=new[1]))
-    return logits, new_cache
+    return logits, KVCache(*pool)
 
 
 def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,

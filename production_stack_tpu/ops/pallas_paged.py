@@ -23,6 +23,13 @@ an already-resident index (Pallas elides the re-fetch) and their grid
 steps are `pl.when`-masked away, so decode cost scales with each row's
 LIVE prefix, not the kv bucket.
 
+The serving path hands in the WHOLE pool ``[L, N, Hkv, Bs, D]`` and a
+``layer`` index, a third scalar-prefetched operand that the index maps
+put in front (``(layer, tables[b, j], ...)``): no layer's pool is
+sliced out of the buffer the step program carries (models/kv.py
+"carried, never stacked"). A bare 4-D layer is the same call on a pool
+of one layer.
+
 Grid ``(B, Hkv, NQ, nb)``; per step the q block [BQ, G, D] for one kv
 head and one pool block's [Bs, D] K and V panels live in VMEM. Online
 (max, sum, acc) statistics persist in VMEM scratch across the
@@ -72,7 +79,19 @@ def paged_viable(T: int, groups: int, head_dim: int,
     return work <= _VMEM_WORK_BYTES
 
 
-def _paged_kernel(tabs_ref, starts_ref, q_ref, k_ref, v_ref, *refs,
+def _whole_pool(layer, k_pool, v_pool, k_scales, v_scales):
+    """(layer [1] int32, pools and scales with a leading layer axis): a
+    bare layer [N, Hkv, Bs, D] becomes a pool of one (a bitcast), so
+    the kernels have one indexing."""
+    pools = (k_pool, v_pool, k_scales, v_scales)
+    if layer is None:
+        layer = 0
+        pools = tuple(p if p is None else p[None] for p in pools)
+    return (jnp.asarray(layer, jnp.int32).reshape(1),) + pools
+
+
+def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, v_ref,
+                  *refs,
                   block_q: int, groups: int,
                   block_size: int, nb: int, scale: float,
                   quant: bool = False, window: int = 0,
@@ -81,10 +100,11 @@ def _paged_kernel(tabs_ref, starts_ref, q_ref, k_ref, v_ref, *refs,
 
     tabs_ref   (SMEM) [B, MB]      block tables
     starts_ref (SMEM) [B]          absolute position of q[:, 0]
+    layer_ref  (SMEM) [1]          the pool's layer (index maps only)
     q_ref   [1, BQ, 1, G, D]       this kv-head's query block
-    k_ref   [1, 1, Bs, D]          pool block tabs[b, min(j, jmax)]
-    v_ref   [1, 1, Bs, D]
-    refs    (quant only: ks/vs dequant scales [1, Hkv, Bs] fp32 —
+    k_ref   [1, 1, 1, Bs, D]       pool block tabs[b, min(j, jmax)]
+    v_ref   [1, 1, 1, Bs, D]
+    refs    (quant only: ks/vs dequant scales [1, 1, Hkv, Bs] fp32 —
             every kv head of the block, this step reads row h,)
             out [1, BQ, 1, G, D], scratch m/l/acc (online softmax
             state across j)
@@ -125,12 +145,12 @@ def _paged_kernel(tabs_ref, starts_ref, q_ref, k_ref, v_ref, *refs,
             jnp.int32, (rows, 1), 0) // groups
         q_pos = start + qi * block_q + row_ids                # [rows, 1]
         q = q_ref[0].reshape(rows, D).astype(jnp.float32) * scale
-        k_blk = k_ref[0, 0].astype(jnp.float32)               # [Bs, D]
-        v_blk = v_ref[0, 0].astype(jnp.float32)
+        k_blk = k_ref[0, 0, 0].astype(jnp.float32)            # [Bs, D]
+        v_blk = v_ref[0, 0, 0].astype(jnp.float32)
         if quant:
             # int8 pool: dequantize the panel in VMEM (per-token scale)
-            k_blk = k_blk * ks_ref[0, h][:, None]
-            v_blk = v_blk * vs_ref[0, h][:, None]
+            k_blk = k_blk * ks_ref[0, 0, h][:, None]
+            v_blk = v_blk * vs_ref[0, 0, h][:, None]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)               # [rows, Bs]
@@ -169,10 +189,13 @@ def _paged_kernel(tabs_ref, starts_ref, q_ref, k_ref, v_ref, *refs,
 def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
                     block_q: int = 0, interpret: bool = False,
                     k_scales=None, v_scales=None, window: int = 0,
-                    scale: float = None, softcap: float = 0.0):
+                    scale: float = None, softcap: float = 0.0,
+                    layer=None):
     """Causal GQA over paged K/V, positions contiguous per row.
 
-    q [B, T, H, D]; k/v pool [N, Hkv, Bs, D]; tables [B, MB] int32;
+    q [B, T, H, D]; k/v pool [N, Hkv, Bs, D], or with ``layer`` (an
+    int32 scalar, traced) the whole pool [L, N, Hkv, Bs, D] of which
+    that layer is read in place; tables [B, MB] int32;
     starts [B] = absolute position of q[:, 0] (every call site —
     prefill chunks, decode windows, speculative windows — queries
     contiguous positions start..start+T-1). A query at position p
@@ -181,12 +204,14 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     in models/kv.py). Rows parked at start >= MB*Bs return garbage
     the caller discards, exactly like the jnp path.
 
-    k_scales/v_scales [N, Hkv, Bs] fp32 activate the int8-pool mode:
-    panels stream from HBM as int8 (half the bytes) and dequantize in
-    VMEM next to the dot.
+    k_scales/v_scales [(L,) N, Hkv, Bs] fp32 activate the int8-pool
+    mode: panels stream from HBM as int8 (half the bytes) and
+    dequantize in VMEM next to the dot.
     """
     B, T, H, D = q.shape
-    Hkv, Bs = k_pool.shape[1], k_pool.shape[2]
+    layer, k_pool, v_pool, k_scales, v_scales = _whole_pool(
+        layer, k_pool, v_pool, k_scales, v_scales)
+    Hkv, Bs = k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
     MB = tables.shape[1]
     if scale is None:
@@ -209,7 +234,7 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     # out of the native layout, (G, D) minor
     q5 = q.reshape(B, Tp, Hkv, G, D)
 
-    def kv_index(b, h, qi, j, tabs, sts):
+    def kv_index(b, h, qi, j, tabs, sts, lyr):
         # clamp out-of-range blocks (past-causal above, before the
         # sliding window below) onto the nearest visible one: the index
         # stops changing, so Pallas skips the DMA re-fetch and pl.when
@@ -223,14 +248,16 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
                 jnp.maximum(sts[b] + qi * block_q - (window - 1), 0), Bs)
             jj = jnp.maximum(jj, jnp.minimum(jmin, jnp.int32(MB - 1)))
         jj = jnp.maximum(jj, 0)
-        return (tabs[b, jj], h, 0, 0)
+        return (lyr[0], tabs[b, jj], h, 0, 0)
 
-    def scale_index(b, h, qi, j, tabs, sts):
+    def scale_index(b, h, qi, j, tabs, sts, lyr):
         # the whole head axis rides in the block: the TPU lowering
         # wants a block's second-minor dim to be a multiple of 8 or the
         # full axis, and one head's [1, Bs] row is neither
-        blk, _, _, _ = kv_index(b, h, qi, j, tabs, sts)
-        return (blk, 0, 0)
+        return kv_index(b, h, qi, j, tabs, sts, lyr)[:2] + (0, 0)
+
+    def q_index(b, h, qi, j, tabs, sts, lyr):
+        return (b, qi, h, 0, 0)
 
     grid = (B, Hkv, nq, nb)
     kernel = functools.partial(
@@ -239,25 +266,21 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
         softcap=softcap)
     rows = block_q * G
     in_specs = [
-        pl.BlockSpec((1, block_q, 1, G, D),
-                     lambda b, h, qi, j, tabs, sts:
-                     (b, qi, h, 0, 0)),
-        pl.BlockSpec((1, 1, Bs, D), kv_index),
-        pl.BlockSpec((1, 1, Bs, D), kv_index),
+        pl.BlockSpec((1, block_q, 1, G, D), q_index),
+        pl.BlockSpec((1, 1, 1, Bs, D), kv_index),
+        pl.BlockSpec((1, 1, 1, Bs, D), kv_index),
     ]
     operands = [q5, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, Hkv, Bs), scale_index)] * 2
+        in_specs += [pl.BlockSpec((1, 1, Hkv, Bs), scale_index)] * 2
         operands += [k_scales, v_scales]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, block_q, 1, G, D),
-                                   lambda b, h, qi, j, tabs, sts:
-                                   (b, qi, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, block_q, 1, G, D), q_index),
             scratch_shapes=[
                 pltpu.VMEM((rows, 1), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.float32),
@@ -272,7 +295,7 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(starts, jnp.int32),
-      *operands)
+      layer, *operands)
 
     return out.reshape(B, Tp, H, D)[:, :T]
 
@@ -327,7 +350,8 @@ def _env_blocks_per_step(default: int = 4) -> int:
 _BLOCKS_PER_STEP = _env_blocks_per_step()
 
 
-def _paged_decode_kernel(tabs_ref, starts_ref, q_ref, *refs, T: int,
+def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, *refs,
+                         T: int,
                          heads_kv: int, groups: int, block_size: int,
                          ngrp: int, R: int, scale: float,
                          quant: bool = False, window: int = 0,
@@ -336,9 +360,10 @@ def _paged_decode_kernel(tabs_ref, starts_ref, q_ref, *refs, T: int,
 
     tabs_ref   (SMEM) [B, MB]     block tables
     starts_ref (SMEM) [B]         absolute position of q[:, 0]
+    layer_ref  (SMEM) [1]         the pool's layer (index maps only)
     q_ref   [1, Hkv, T*G, D]      all heads' queries (rows = t*G + g)
-    refs    R k panels [1, Hkv, Bs, D], R v panels, (quant only:
-            R ks + R vs dequant scales [1, Hkv, Bs] fp32,) out
+    refs    R k panels [1, 1, Hkv, Bs, D], R v panels, (quant only:
+            R ks + R vs dequant scales [1, 1, Hkv, Bs] fp32,) out
             [1, Hkv, T*G, D], scratch m/l [Hkv*T*G, 1], acc
             [Hkv*T*G, D] — online softmax state across the group axis.
     """
@@ -381,11 +406,11 @@ def _paged_decode_kernel(tabs_ref, starts_ref, q_ref, *refs, T: int,
             acc_prev = acc_ref[sl]
             for i in range(R):
                 j = jg * R + i
-                k_blk = k_refs[i][0, h].astype(jnp.float32)  # [Bs, D]
-                v_blk = v_refs[i][0, h].astype(jnp.float32)
+                k_blk = k_refs[i][0, 0, h].astype(jnp.float32)  # [Bs, D]
+                v_blk = v_refs[i][0, 0, h].astype(jnp.float32)
                 if quant:
-                    k_blk = k_blk * ks_refs[i][0, h][:, None]
-                    v_blk = v_blk * vs_refs[i][0, h][:, None]
+                    k_blk = k_blk * ks_refs[i][0, 0, h][:, None]
+                    v_blk = v_blk * vs_refs[i][0, 0, h][:, None]
                 s = jax.lax.dot_general(
                     q, k_blk, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)      # [rows, Bs]
@@ -424,17 +449,21 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
                            nb: int, interpret: bool = False,
                            k_scales=None, v_scales=None,
                            window: int = 0,
-                           scale: float = None, softcap: float = 0.0):
+                           scale: float = None, softcap: float = 0.0,
+                           layer=None):
     """paged_attention specialized for short query windows (T <=
     DECODE_T_MAX): same contract, same result, far fewer grid steps.
 
-    q [B, T, H, D]; k/v pool [N, Hkv, Bs, D]; tables [B, MB] int32;
-    starts [B]. See paged_attention for semantics. k_scales/v_scales
-    [N, Hkv, Bs] fp32 activate the int8-pool mode (panels stream as
-    int8, dequantized in VMEM — half the KV bytes of the bf16 pool).
+    q [B, T, H, D]; k/v pool [N, Hkv, Bs, D], or with ``layer`` the
+    whole pool [L, N, Hkv, Bs, D]; tables [B, MB] int32; starts [B].
+    See paged_attention for semantics. k_scales/v_scales
+    [(L,) N, Hkv, Bs] fp32 activate the int8-pool mode (panels stream
+    as int8, dequantized in VMEM — half the KV bytes of the bf16 pool).
     """
     B, T, H, D = q.shape
-    Hkv, Bs = k_pool.shape[1], k_pool.shape[2]
+    layer, k_pool, v_pool, k_scales, v_scales = _whole_pool(
+        layer, k_pool, v_pool, k_scales, v_scales)
+    Hkv, Bs = k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
     MB = tables.shape[1]
     if scale is None:
@@ -450,7 +479,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
     qh = qh.reshape(B, Hkv, rows, D)
 
     def kv_index(i):
-        def index(b, jg, tabs, sts):
+        def index(b, jg, tabs, sts, lyr):
             jmax = jax.lax.div(sts[b] + (T - 1), jnp.int32(Bs))
             jj = jnp.minimum(jnp.minimum(jg * R + i, jmax),
                              jnp.int32(MB - 1))
@@ -459,43 +488,39 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
                     jnp.maximum(sts[b] - (window - 1), 0), jnp.int32(Bs))
                 jj = jnp.maximum(jj, jnp.minimum(jmin,
                                                  jnp.int32(MB - 1)))
-            return (tabs[b, jnp.maximum(jj, 0)], 0, 0, 0)
+            return (lyr[0], tabs[b, jnp.maximum(jj, 0)], 0, 0, 0)
         return index
+
+    def q_index(b, jg, tabs, sts, lyr):
+        return (b, 0, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, T=T, heads_kv=Hkv, groups=G,
         block_size=Bs, ngrp=ngrp, R=R, scale=scale, quant=quant,
         window=window, softcap=softcap)
-    kv_specs = [pl.BlockSpec((1, Hkv, Bs, D), kv_index(i))
+    kv_specs = [pl.BlockSpec((1, 1, Hkv, Bs, D), kv_index(i))
                 for i in range(R)]
     in_specs = [
-        pl.BlockSpec((1, Hkv, rows, D),
-                     lambda b, jg, tabs, sts: (b, 0, 0, 0)),
+        pl.BlockSpec((1, Hkv, rows, D), q_index),
         *kv_specs, *kv_specs,
     ]
     operands = [qh, *([k_pool] * R), *([v_pool] * R)]
     if quant:
         def sc_index(i):
             ki = kv_index(i)
+            return lambda *a: ki(*a)[:4]
 
-            def index(b, jg, tabs, sts):
-                blk, _, _, _ = ki(b, jg, tabs, sts)
-                return (blk, 0, 0)
-            return index
-
-        sc_specs = [pl.BlockSpec((1, Hkv, Bs), sc_index(i))
+        sc_specs = [pl.BlockSpec((1, 1, Hkv, Bs), sc_index(i))
                     for i in range(R)]
         in_specs += [*sc_specs, *sc_specs]
         operands += [*([k_scales] * R), *([v_scales] * R)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, ngrp),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, Hkv, rows, D),
-                                   lambda b, jg, tabs, sts:
-                                   (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, Hkv, rows, D), q_index),
             scratch_shapes=[
                 pltpu.VMEM((Hkv * rows, 1), jnp.float32),
                 pltpu.VMEM((Hkv * rows, 1), jnp.float32),
@@ -508,7 +533,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(starts, jnp.int32),
-      qh, *operands[1:])
+      layer, *operands)
 
     # [B, Hkv, T*G, D] -> [B, T, H, D]
     out = out.reshape(B, Hkv, T, G, D).transpose(0, 2, 1, 3, 4)
@@ -519,38 +544,37 @@ def paged_attention_sharded(q, k_pool, v_pool, tables, starts, mesh, *,
                             nb: int, interpret: bool = False,
                             k_scales=None, v_scales=None,
                             window: int = 0,
-                            scale: float = None, softcap: float = 0.0):
+                            scale: float = None, softcap: float = 0.0,
+                            layer=None):
     """paged_attention under a tp-only mesh: shard_map over the head
     axis (q heads and pool kv heads both shard by tp, tables/starts
-    replicated) — shard-local, no collectives. Caller guarantees the
-    mesh has no other axis of size > 1 (mesh_tp_only). Short windows
-    (decode/spec) take the wide decode kernel, like the unsharded
-    path. int8 pools pass their [N, Hkv, Bs] scales, sharded over the
-    same head axis."""
+    and the layer index replicated) — shard-local, no collectives.
+    Caller guarantees the mesh has no other axis of size > 1
+    (mesh_tp_only). Short windows (decode/spec) take the wide decode
+    kernel, like the unsharded path. int8 pools pass their
+    [(L,) N, Hkv, Bs] scales, sharded over the same head axis."""
     from jax.sharding import PartitionSpec as P
 
     base = (paged_decode_attention if q.shape[1] <= DECODE_T_MAX
             else paged_attention)
-    in_specs = (P(None, None, "tp", None),
-                P(None, "tp", None, None),
-                P(None, "tp", None, None), P(), P())
-    args = (q, k_pool, v_pool, tables, starts)
-    if k_scales is not None:
-        def fn(qq, kk, vv, tt, ss, ks, vs):
-            return base(qq, kk, vv, tt, ss, nb=nb, interpret=interpret,
-                        k_scales=ks, v_scales=vs, window=window,
-                        scale=scale, softcap=softcap)
-        in_specs = in_specs + (P(None, "tp", None), P(None, "tp", None))
-        args = args + (k_scales, v_scales)
-    else:
-        fn = functools.partial(base, nb=nb, interpret=interpret,
-                               window=window, scale=scale,
-                               softcap=softcap)
+    # the whole pool's layer axis leads, unsharded
+    lead = (None,) * (k_pool.ndim - 4)
+    kv_spec = P(*lead, None, "tp", None, None)
+    sc_spec = P(*lead, None, "tp", None)
+
+    def fn(qq, kk, vv, tt, ss, ks, vs, lyr):
+        return base(qq, kk, vv, tt, ss, nb=nb, interpret=interpret,
+                    k_scales=ks, v_scales=vs, window=window,
+                    scale=scale, softcap=softcap, layer=lyr)
+
+    # None (no scales, no layer) is an empty pytree: its spec is unused
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=in_specs,
+        in_specs=(P(None, None, "tp", None), kv_spec, kv_spec, P(), P(),
+                  sc_spec, sc_spec, P()),
         out_specs=P(None, None, "tp", None),
-        check_vma=False)(*args)
+        check_vma=False)(q, k_pool, v_pool, tables, starts,
+                         k_scales, v_scales, layer)
 
 
 def mesh_tp_only(mesh) -> bool:

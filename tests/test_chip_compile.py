@@ -9,7 +9,14 @@ attached (``on-chip-measurement`` guide, section 2.3). These tests hand
 the serving path's kernels to it at the real widths — Mistral-7B head
 geometry (32 q / 8 kv heads, head dim 128, block 64) and the 16/16-head
 MoE geometry — one to two seconds each, and skip where the topology
-cannot be described. A compile that passes is not a chip run.
+cannot be described. A compile that passes is not a chip run. Each
+kernel case compiles twice: on one bare layer of the pool and, as the
+serving path calls it, on the whole pool with the layer as an operand.
+
+One decode window and one prefill chunk of the runner, at the same head
+geometry with two layers and a small pool, are compiled whole and their
+optimised HLO is read: nothing in it may copy or slice the KV pool
+(models/kv.py: the pool is carried, never stacked).
 
 ``-m slow`` adds one whole decode window and one whole prefill step of
 the runner at Mistral-7B widths (int8 weights, all 32 layers), on one
@@ -59,38 +66,44 @@ def topo():
     compilation_cache.reset_cache()
 
 
-def _placements(topo, tp: int):
+def _placements(topo, tp: int, layers: int = 0):
     """Shardings for (q, pool, replicated, scales): one described chip,
-    or head-sharded over a tp mesh of the four."""
+    or head-sharded over a tp mesh of the four (a whole pool's leading
+    layer axis unsharded)."""
     if tp == 1:
         one = SingleDeviceSharding(topo.devices[0])
         return None, (one, one, one, one)
     devs = np.array(topo.devices[:tp]).reshape(
         [tp if a == "tp" else 1 for a in AXES])
     mesh = Mesh(devs, AXES)
+    lead = (None,) * bool(layers)
     return mesh, (NamedSharding(mesh, P(None, None, "tp", None)),
-                  NamedSharding(mesh, P(None, "tp", None, None)),
+                  NamedSharding(mesh, P(*lead, None, "tp", None, None)),
                   NamedSharding(mesh, P()),
-                  NamedSharding(mesh, P(None, "tp", None)))
+                  NamedSharding(mesh, P(*lead, None, "tp", None)))
 
 
-def _compile_attention(topo, *, B, T, H, Hkv, int8, window=0, tp=1):
+def _compile_attention(topo, *, B, T, H, Hkv, int8, window=0, tp=1,
+                       layers=0):
     """Lower + compile the attention call the serving path makes for
     this shape (llama.attention_path's choice of kernel) and return the
-    compiled executable."""
-    mesh, (q_sh, kv_sh, rep_sh, sc_sh) = _placements(topo, tp)
+    compiled executable. layers: 0 = one bare layer of the pool
+    [N, Hkv, Bs, D]; n = the whole pool [n, N, Hkv, Bs, D] and the
+    layer index as an operand, as llama._layer_body calls it."""
+    mesh, (q_sh, kv_sh, rep_sh, sc_sh) = _placements(topo, tp, layers)
     n_blocks = B * MB + 1
+    lead = (layers,) * bool(layers)
     q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=q_sh)
     pool = jax.ShapeDtypeStruct(
-        (n_blocks, Hkv, BS, D), jnp.int8 if int8 else jnp.bfloat16,
-        sharding=kv_sh)
+        lead + (n_blocks, Hkv, BS, D),
+        jnp.int8 if int8 else jnp.bfloat16, sharding=kv_sh)
     tables = jax.ShapeDtypeStruct((B, MB), jnp.int32, sharding=rep_sh)
     starts = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rep_sh)
-    args = [q, pool, pool, tables, starts]
-    if int8:
-        scales = jax.ShapeDtypeStruct((n_blocks, Hkv, BS), jnp.float32,
-                                      sharding=sc_sh)
-        args += [scales, scales]
+    layer = (jax.ShapeDtypeStruct((), jnp.int32, sharding=rep_sh)
+             if layers else None)
+    scales = (jax.ShapeDtypeStruct(lead + (n_blocks, Hkv, BS),
+                                   jnp.float32, sharding=sc_sh)
+              if int8 else None)
     if mesh is not None:
         kernel = partial(pallas_paged.paged_attention_sharded, mesh=mesh)
     elif T <= pallas_paged.DECODE_T_MAX:
@@ -98,49 +111,58 @@ def _compile_attention(topo, *, B, T, H, Hkv, int8, window=0, tp=1):
     else:
         kernel = pallas_paged.paged_attention
 
-    def call(q, k, v, tables, starts, ks=None, vs=None):
+    def call(q, k, v, tables, starts, ks, vs, layer):
         return kernel(q, k, v, tables, starts, nb=MB, window=window,
-                      k_scales=ks, v_scales=vs)
+                      k_scales=ks, v_scales=vs, layer=layer)
 
-    return jax.jit(call).lower(*args).compile()
+    return jax.jit(call).lower(q, pool, pool, tables, starts, scales,
+                               scales, layer).compile()
 
 
 KV = pytest.mark.parametrize("int8", [False, True],
                              ids=["kv_bf16", "kv_int8"])
+POOL = pytest.mark.parametrize("layers", [0, 4],
+                               ids=["layer_4d", "whole_pool"])
 
 
+@POOL
 @KV
 @pytest.mark.parametrize("B", [8, 32])
-def test_wide_decode_kernel_compiles(topo, B, int8):
-    _compile_attention(topo, B=B, T=1, H=32, Hkv=8, int8=int8)
+def test_wide_decode_kernel_compiles(topo, B, int8, layers):
+    _compile_attention(topo, B=B, T=1, H=32, Hkv=8, int8=int8,
+                       layers=layers)
 
 
+@POOL
 @KV
 @pytest.mark.parametrize("window", [0, 4096])
 @pytest.mark.parametrize("T", [16, 128, 512])
-def test_general_paged_kernel_compiles(topo, T, window, int8):
+def test_general_paged_kernel_compiles(topo, T, window, int8, layers):
     # int8: the dequant scales ride as [1, Hkv, Bs] blocks — one head's
     # [1, Bs] row is neither 8-aligned nor the whole axis, and the TPU
     # lowering refuses it
     _compile_attention(topo, B=8, T=T, H=32, Hkv=8, int8=int8,
-                       window=window)
+                       window=window, layers=layers)
 
 
+@POOL
 @KV
 @pytest.mark.parametrize("T", [1, 128])
-def test_mha_16_16_geometry_compiles(topo, T, int8):
+def test_mha_16_16_geometry_compiles(topo, T, int8, layers):
     """Qwen1.5-MoE attention geometry: 16 q / 16 kv heads (G = 1)."""
-    _compile_attention(topo, B=8, T=T, H=16, Hkv=16, int8=int8)
+    _compile_attention(topo, B=8, T=T, H=16, Hkv=16, int8=int8,
+                       layers=layers)
 
 
+@POOL
 @KV
 @pytest.mark.parametrize("T", [1, 128])
-def test_tp4_sharded_wrapper_compiles(topo, T, int8):
+def test_tp4_sharded_wrapper_compiles(topo, T, int8, layers):
     """shard_map over the head axis on a mesh of the four described
     chips: the kernel stays one custom call per shard, and the wrapper
     adds no collective."""
     hlo = _compile_attention(topo, B=8, T=T, H=32, Hkv=8, int8=int8,
-                             tp=4).as_text()
+                             tp=4, layers=layers).as_text()
     assert "tpu_custom_call" in hlo
     for collective in ("all-reduce", "all-gather", "all-to-all",
                        "collective-permute"):
@@ -148,7 +170,8 @@ def test_tp4_sharded_wrapper_compiles(topo, T, int8):
 
 
 # ---------------------------------------------------------------------
-# whole step programs of the runner (slow: ~1-2 min each)
+# whole step programs of the runner (at all 32 layers slow: ~1-2 min
+# each; at two layers ~10 s each)
 # ---------------------------------------------------------------------
 
 @pytest.fixture
@@ -162,11 +185,13 @@ def tpu_branches(monkeypatch):
                         lambda: False)
 
 
-def _runner_shapes(topo, tp: int):
+def _runner_shapes(topo, tp: int, layers=None, kv_blocks=None):
     """A ModelRunner skeleton (no arrays: a described device cannot
     hold one) plus ShapeDtypeStructs of its params and KV pool at
     Mistral-7B widths — int8 weights, all 32 layers, the server's
-    default geometry — placed as the runner places them."""
+    default geometry — placed as the runner places them. layers and
+    kv_blocks cut the depth and the pool, and nothing else."""
+    import dataclasses
     from production_stack_tpu.engine.config import EngineConfig
     from production_stack_tpu.engine.runner import ModelRunner
     from production_stack_tpu.models import llama
@@ -178,6 +203,8 @@ def _runner_shapes(topo, tp: int):
 
     mesh, (_, _, rep_sh, _) = _placements(topo, tp)
     mcfg = get_config("mistral-7b")
+    if layers:
+        mcfg = dataclasses.replace(mcfg, num_layers=layers)
     ecfg = EngineConfig(model="mistral-7b", quantization="int8")
     runner = ModelRunner.__new__(ModelRunner)
     runner.model_cfg, runner.engine_cfg, runner.mesh = mcfg, ecfg, mesh
@@ -189,7 +216,7 @@ def _runner_shapes(topo, tp: int):
         partial(llama.init_params, mcfg, quantization="int8"),
         jax.random.PRNGKey(0))
     cache = jax.eval_shape(partial(
-        make_cache, mcfg.num_layers, ecfg.num_kv_blocks,
+        make_cache, mcfg.num_layers, kv_blocks or ecfg.num_kv_blocks,
         ecfg.kv_block_size, mcfg.num_kv_heads, mcfg.head_dim_))
     if mesh is None:
         p_sh = jax.tree.map(lambda _: rep_sh, params)
@@ -237,19 +264,72 @@ def _fits(compiled, what: str) -> None:
     assert need < HBM_BYTES, what
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("tp", [1, 4])
-def test_decode_window_compiles_at_mistral_7b(topo, tpu_branches, tp):
-    runner, params, cache, rep = _runner_shapes(topo, tp)
-    B, steps = runner.engine_cfg.max_num_seqs, 8
+def _compile_decode_window(runner, params, cache, rep):
+    """A greedy decode window of 8 steps at the first kv bucket, the
+    pool donated, as the runner jits it."""
+    B = runner.engine_cfg.max_num_seqs
     a = _step_args(runner, rep, B)
-    fn = jax.jit(partial(runner._decode_impl, steps=steps, kv_len=512,
+    fn = jax.jit(partial(runner._decode_impl, steps=8, kv_len=512,
                          greedy=True), donate_argnums=(1,))
-    compiled = fn.lower(
+    return fn.lower(
         params, cache, a["tables"], rep((B,), jnp.int32),
         rep((B,), jnp.int32), a["sampling"], a["key"], a["guide_next"],
         a["guide_id"], a["guide_state"], a["counts"], a["seen"]
     ).compile()
+
+
+def _compile_prefill_chunk(runner, params, cache, rep, Tb: int):
+    B = runner.engine_cfg.max_num_seqs
+    a = _step_args(runner, rep, B)
+    fn = jax.jit(partial(runner._prefill_impl, kv_len=512),
+                 donate_argnums=(1,))
+    return fn.lower(
+        params, cache, a["tables"], rep((B, Tb), jnp.int32),
+        rep((B,), jnp.int32), rep((B,), jnp.int32), a["sampling"],
+        a["key"], a["guide_next"], a["guide_id"], a["guide_state"],
+        a["counts"], a["seen"]).compile()
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk"])
+def test_step_program_never_copies_the_pool(topo, tpu_branches,
+                                            program):
+    """Mistral head geometry, two layers, a pool of 97 blocks: in the
+    optimised HLO no copy, dynamic-slice or dynamic-update-slice (nor
+    a fusion named for one) yields an array of the pool's shape or of
+    one layer's. The pool handed through the layer scan's xs -> ys was
+    sliced, re-laid-out and stacked per layer and copied whole per step
+    (`copy.108`, `dynamic-slice_bitcast_fusion.5`,
+    `bitcast_dynamic-update-slice_fusion.4` of PERF.md, PR 25); scattered
+    token by token it is copied whole into a token-major layout and
+    back, per layer (models/kv.py: appends rewrite whole blocks)."""
+    import re
+    L, N = 2, 97
+    runner, params, cache, rep = _runner_shapes(topo, 1, layers=L,
+                                                kv_blocks=N)
+    if program == "decode_window":
+        compiled = _compile_decode_window(runner, params, cache, rep)
+    else:
+        compiled = _compile_prefill_chunk(runner, params, cache, rep, 128)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # "%name = bf16[2,97,8,64,128]{layout} opcode(": a pool-shaped result
+    pool_result = re.compile(
+        r"([\w.\-]+) = \(?\w+\[(?:{},)?{},8,{},{}\]\S* ([\w\-]+)\("
+        .format(L, N, BS, D))
+    moved = [m.group(1) + ": " + m.group(2)
+             for m in map(pool_result.search, hlo.splitlines()) if m
+             and re.search(r"copy|dynamic.slice|dynamic.update.slice",
+                           m.group(1) + " " + m.group(2))]
+    assert not moved, moved
+    # and the pool is one buffer from argument to result
+    pool_bytes = 2 * L * N * 8 * BS * D * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tp", [1, 4])
+def test_decode_window_compiles_at_mistral_7b(topo, tpu_branches, tp):
+    compiled = _compile_decode_window(*_runner_shapes(topo, tp))
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled, f"decode window tp={tp}")
 
@@ -257,15 +337,6 @@ def test_decode_window_compiles_at_mistral_7b(topo, tpu_branches, tp):
 @pytest.mark.slow
 @pytest.mark.parametrize("tp", [1, 4])
 def test_prefill_step_compiles_at_mistral_7b(topo, tpu_branches, tp):
-    runner, params, cache, rep = _runner_shapes(topo, tp)
-    B, Tb = runner.engine_cfg.max_num_seqs, 512
-    a = _step_args(runner, rep, B)
-    fn = jax.jit(partial(runner._prefill_impl, kv_len=512),
-                 donate_argnums=(1,))
-    compiled = fn.lower(
-        params, cache, a["tables"], rep((B, Tb), jnp.int32),
-        rep((B,), jnp.int32), rep((B,), jnp.int32), a["sampling"],
-        a["key"], a["guide_next"], a["guide_id"], a["guide_state"],
-        a["counts"], a["seen"]).compile()
+    compiled = _compile_prefill_chunk(*_runner_shapes(topo, tp), 512)
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled, f"prefill step tp={tp}")

@@ -14,8 +14,9 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models.kv import (
-    KVCache, gather_view, gather_view_q, make_cache, quantize_chunk,
-    write_chunk, write_chunk_q)
+    KVCache, append_chunk, append_chunk_q, gather_view, gather_view_q,
+    make_cache, quantize_chunk, write_chunk, write_chunk_q)
+from tests.whole_pool import WHOLE, call as _call
 
 
 def test_quantize_chunk_error_bound():
@@ -55,6 +56,97 @@ def test_write_gather_roundtrip_q():
             want = np.asarray(new[b, t])
             bound = np.abs(want).max(axis=-1, keepdims=True) / 127 + 1e-6
             assert (np.abs(got - want) <= bound).all()
+
+
+KV_DTYPES = pytest.mark.parametrize(
+    "dtype", [jnp.bfloat16, jnp.int8], ids=["kv_bf16", "kv_int8"])
+
+
+@KV_DTYPES
+@pytest.mark.parametrize("T", [1, 5, 16, 40])
+def test_append_chunk_is_write_chunk_on_that_layer(T, dtype):
+    """append_chunk on the whole pool (the serving path's write: whole
+    blocks gathered, merged, scattered back) leaves in every block a
+    table references what write_chunk leaves on that layer's slice,
+    and nothing anywhere else: other layers untouched, and tokens that
+    are invalid, parked past the capacity or before position 0 land in
+    no referenced block. decode step, speculative window across a
+    block boundary, one whole block, a prefill chunk over four."""
+    L, N, Hkv, Bs, D, MB, B = 3, 21, 2, 16, 8, 5, 4
+    rng = np.random.default_rng(T)
+    tables = jnp.asarray(
+        1 + rng.permutation(N - 1).reshape(B, MB), jnp.int32)
+    cache = make_cache(L, N, Bs, Hkv, D, dtype=dtype)
+    # a pool that already holds something, in every layer
+    cache = KVCache(*(jnp.asarray(rng.integers(-99, 99, a.shape), a.dtype)
+                      for a in cache if a is not None))
+    new = jnp.asarray(rng.normal(size=(B, T, Hkv, D)), jnp.float32)
+    # row 0 mid-block; row 1 runs over the capacity MB*Bs; row 2 is
+    # parked at it; row 3 starts before position 0
+    starts = jnp.asarray([Bs - 2, MB * Bs - T // 2 - 1, MB * Bs, -2],
+                         jnp.int32)
+    positions = starts[:, None] + jnp.arange(T)[None, :]
+    valid = jnp.asarray(rng.random((B, T)) < 0.8)
+    layer = jnp.int32(1)
+
+    for v in (valid, None):
+        if cache.quantized:
+            got = append_chunk_q(cache.k, cache.ks, new, tables, starts,
+                                 v, layer)
+            want = write_chunk_q(cache.k[1], cache.ks[1], new, tables,
+                                 positions, v)
+            before = (cache.k, cache.ks)
+        else:
+            got = (append_chunk(cache.k, new, tables, starts, v, layer),)
+            want = (write_chunk(cache.k[1], new, tables, positions, v),)
+            before = (cache.k,)
+        for g, w, b in zip(got, want, before):
+            g, w, b = np.asarray(g), np.asarray(w), np.asarray(b)
+            # block 0 is the trash block: written or not, never read
+            np.testing.assert_array_equal(g[1, 1:], w[1:])
+            np.testing.assert_array_equal(g[[0, 2]], b[[0, 2]])
+
+
+@KV_DTYPES
+def test_layer_scan_carries_the_pool(dtype):
+    """The structure that keeps the pool one buffer on the chip
+    (models/kv.py: carried, never stacked): in llama.forward's layer
+    scan every pool-shaped array is a carry, and none goes in as a
+    per-layer input or comes out as a stacked output. XLA cannot alias
+    a scan's xs with its ys: handed through them, a layer's pool was
+    copied per layer and the whole pool per step."""
+    from production_stack_tpu.models import llama
+    from production_stack_tpu.models.config import get_config
+
+    cfg = get_config("debug-tiny")
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: make_cache(
+        cfg.num_layers, 9, 8, cfg.num_kv_heads, cfg.head_dim_, dtype))
+    tokens = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, t, c: llama.forward(p, cfg, t, t, c, use_flash=False)
+    )(params, tokens, cache)
+
+    pools = {a.shape for a in cache if a is not None}
+    per_layer = {s[1:] for s in pools}
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"
+             and e.params["length"] == cfg.num_layers]
+    assert len(scans) == 1
+    scan, = scans
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    shapes = [v.aval.shape for v in scan.invars]
+    carried = shapes[n_consts:n_consts + n_carry]
+    assert sorted(s for s in carried if s in pools) == sorted(
+        a.shape for a in cache if a is not None)
+    # neither closed over nor sliced per layer on the way in
+    for s in shapes[:n_consts] + shapes[n_consts + n_carry:]:
+        assert s not in pools, s
+    # nor stacked per layer on the way out
+    for v in scan.outvars[n_carry:]:
+        assert v.aval.shape not in pools, v.aval
+    for v in scan.params["jaxpr"].jaxpr.outvars[n_carry:]:
+        assert v.aval.shape not in per_layer, v.aval
 
 
 def test_forward_logits_close_to_bf16_cache():
@@ -180,7 +272,8 @@ def _int8_pool_setup(key, B, n_blocks, Bs, Hkv, D, lens, T):
 
 
 @pytest.mark.parametrize("T", [1, 5, 48])
-def test_paged_kernels_int8_parity(T):
+@WHOLE
+def test_paged_kernels_int8_parity(T, layer):
     """Both pallas kernels in int8 mode (interpret, CPU) match the
     dequantized jnp reference exactly-ish: same dequantized values
     feed both paths, so tolerance is fp accumulation only."""
@@ -205,13 +298,14 @@ def test_paged_kernels_int8_parity(T):
     want = attention_with_cache(q, k_att, v_att, positions)
 
     fn = paged_decode_attention if T <= 8 else paged_attention
-    got = fn(q, k8, v8, tables, starts, nb=nb, interpret=True,
-             k_scales=ks, v_scales=vs)
+    got = _call(fn, q, k8, v8, tables, starts, nb=nb, interpret=True,
+                k_scales=ks, v_scales=vs, layer=layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_sharded_int8_parity():
+@WHOLE
+def test_paged_sharded_int8_parity(layer):
     """int8 kernels under a 2-device tp mesh (scales shard with the
     head axis)."""
     from jax.sharding import Mesh
@@ -235,9 +329,9 @@ def test_paged_sharded_int8_parity():
     v_att = gather_view_q(v8, vs, tables, nb, dtype=jnp.float32)
     positions = starts[:, None] + jnp.arange(T)[None, :]
     want = attention_with_cache(q, k_att, v_att, positions)
-    got = paged_attention_sharded(q, k8, v8, tables, starts, mesh,
-                                  nb=nb, interpret=True,
-                                  k_scales=ks, v_scales=vs)
+    got = _call(paged_attention_sharded, q, k8, v8, tables, starts,
+                mesh, nb=nb, interpret=True, k_scales=ks, v_scales=vs,
+                layer=layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 

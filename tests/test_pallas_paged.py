@@ -13,6 +13,7 @@ from production_stack_tpu.ops.attention import attention_with_cache
 from production_stack_tpu.ops.pallas_paged import (
     mesh_tp_only, paged_attention, paged_attention_sharded,
     paged_decode_attention)
+from tests.whole_pool import WHOLE, call as _call
 
 
 def _random_paged(key, B, n_blocks, Bs, Hkv, D, lens, t_extra=8):
@@ -42,7 +43,8 @@ def _reference(q, k_pool, v_pool, tables, starts, nb):
     (5, 4, 16, 32),      # speculative window (draft + 1)
     (48, 2, 16, 64),     # prefill chunk, ragged block boundary
 ])
-def test_paged_matches_dense(T, G, Bs, D):
+@WHOLE
+def test_paged_matches_dense(T, G, Bs, D, layer):
     B, Hkv = 3, 2
     H = Hkv * G
     key = jax.random.PRNGKey(T * 1000 + G)
@@ -62,8 +64,8 @@ def test_paged_matches_dense(T, G, Bs, D):
     v_pool = write_chunk(v_pool, newv, tables, positions)
 
     nb = -(-(max(lens) + T) // Bs)
-    got = paged_attention(q, k_pool, v_pool, tables, starts, nb=nb,
-                          interpret=True)
+    got = _call(paged_attention, q, k_pool, v_pool, tables, starts,
+                nb=nb, interpret=True, layer=layer)
     want = _reference(q, k_pool, v_pool, tables, starts, nb)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -75,7 +77,8 @@ def test_paged_matches_dense(T, G, Bs, D):
     (5, 4, 16, 32),      # speculative window (draft + 1)
     (8, 2, 16, 64),      # DECODE_T_MAX boundary
 ])
-def test_paged_decode_matches_dense(T, G, Bs, D):
+@WHOLE
+def test_paged_decode_matches_dense(T, G, Bs, D, layer):
     """The wide decode kernel (all kv heads + R blocks per grid step)
     matches the dense jnp path on the same shuffled pools."""
     B, Hkv = 3, 2
@@ -98,8 +101,8 @@ def test_paged_decode_matches_dense(T, G, Bs, D):
     # nb NOT a multiple of the kernel's blocks-per-step: the ragged
     # last group must mask correctly
     nb = -(-(max(lens) + T) // Bs)
-    got = paged_decode_attention(q, k_pool, v_pool, tables, starts,
-                                 nb=nb, interpret=True)
+    got = _call(paged_decode_attention, q, k_pool, v_pool, tables,
+                starts, nb=nb, interpret=True, layer=layer)
     want = _reference(q, k_pool, v_pool, tables, starts, nb)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -131,7 +134,8 @@ def test_paged_decode_short_row_isolation():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_decode_sharded_tp_parity():
+@WHOLE
+def test_paged_decode_sharded_tp_parity(layer):
     """paged_attention_sharded routes short windows through the decode
     kernel; parity on a 2-device tp mesh."""
     from jax.sharding import Mesh
@@ -153,8 +157,8 @@ def test_paged_decode_sharded_tp_parity():
     k_pool = write_chunk(k_pool, newk, tables, positions)
     v_pool = write_chunk(v_pool, newv, tables, positions)
     nb = -(-(44 + T) // Bs)
-    got = paged_attention_sharded(q, k_pool, v_pool, tables, starts,
-                                  mesh, nb=nb, interpret=True)
+    got = _call(paged_attention_sharded, q, k_pool, v_pool, tables,
+                starts, mesh, nb=nb, interpret=True, layer=layer)
     want = _reference(q, k_pool, v_pool, tables, starts, nb)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -211,7 +215,8 @@ def test_paged_small_block_q_splits():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_sharded_tp_parity():
+@WHOLE
+def test_paged_sharded_tp_parity(layer):
     """shard_map over the head axis on the 8-device CPU mesh matches
     the unsharded kernel."""
     from jax.sharding import Mesh
@@ -234,8 +239,8 @@ def test_paged_sharded_tp_parity():
     k_pool = write_chunk(k_pool, newk, tables, positions)
     v_pool = write_chunk(v_pool, newv, tables, positions)
     nb = -(-(44 + T) // Bs)
-    got = paged_attention_sharded(q, k_pool, v_pool, tables, starts,
-                                  mesh, nb=nb, interpret=True)
+    got = _call(paged_attention_sharded, q, k_pool, v_pool, tables,
+                starts, mesh, nb=nb, interpret=True, layer=layer)
     want = paged_attention(q, k_pool, v_pool, tables, starts, nb=nb,
                            interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from production_stack_tpu.models import ModelConfig, llama, make_slot_cache
 from production_stack_tpu.models.kv import write_chunk, gather_view
+from tests.whole_pool import WHOLE, call as _call
 
 
 def test_hf_mistral_sliding_parity():
@@ -70,7 +71,8 @@ def test_hf_config_parses_sliding_window():
 
 
 @pytest.mark.parametrize("T", [1, 5, 48])
-def test_paged_kernels_windowed_parity(T):
+@WHOLE
+def test_paged_kernels_windowed_parity(T, layer):
     """Both pallas kernels with a window (interpret, CPU) match the
     windowed jnp reference through shuffled tables."""
     from production_stack_tpu.ops.attention import attention_with_cache
@@ -106,8 +108,8 @@ def test_paged_kernels_windowed_parity(T):
     want = attention_with_cache(q, k_att, v_att, positions,
                                 sliding_window=W)
     fn = paged_decode_attention if T <= 8 else paged_attention
-    got = fn(q, k_pool, v_pool, tables, starts, nb=nb, window=W,
-             interpret=True)
+    got = _call(fn, q, k_pool, v_pool, tables, starts, nb=nb, window=W,
+                interpret=True, layer=layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
