@@ -295,6 +295,20 @@ class EngineConfig:
                 return b
         return self.kv_len_buckets[-1]
 
+    def prefill_rows_for(self, chunks: int) -> int:
+        """Rows of the prefill dispatches that serve ``chunks`` chunks
+        due at once in one chunk bucket: 1 (a dispatch per chunk) up to
+        a quarter of the batch, max_num_seqs (one dispatch for all)
+        above. The ends of the decode batch buckets' range and nothing
+        between: every row count is one more executable per (chunk
+        bucket, kv bucket, variant) to warm or to compile mid-serving,
+        and a one-row chunk of 128 tokens already costs a v5e more in
+        arithmetic than in reading the weights, so that rows beyond the
+        chunks due buy nothing and a few weight reads cost less than
+        computing four times the rows."""
+        return (1 if chunks <= max(1, self.max_num_seqs // 4)
+                else self.max_num_seqs)
+
     def batch_bucket_for(self, rows: int) -> int:
         """Smallest decode batch bucket covering `rows` slots. (The
         window axis has no covering lookup on purpose: the dispatch

@@ -172,10 +172,12 @@ class EngineEffAccounting:
         self.decode_token_steps_total = 0
         self.decode_windows = 0
         self.decode_busy_s = 0.0
-        # prefill bucket-padding waste (idle rows + right padding)
+        # prefill bucket-padding waste (spare rows + right padding),
+        # and the dispatches by the rows they ran
         self.prefill_real = 0
         self.prefill_pad = 0
         self.prefill_dispatches = 0
+        self.prefill_by_rows: Dict[int, int] = {}
         # modeled HBM traffic (decode windows only — see module doc)
         self.bytes_total = 0
         self.bytes_effective = 0
@@ -265,14 +267,17 @@ class EngineEffAccounting:
 
     def note_prefill(self, *, bucket: int, batch: int,
                      real_tokens: int) -> None:
-        """One prefill bucket group: ``batch * bucket`` token positions
-        were computed; ``real_tokens`` were actual prompt-chunk tokens,
-        the rest bucket right-padding and idle parked rows."""
+        """One prefill bucket group, dispatched at ``batch`` rows:
+        ``batch * bucket`` token positions were computed;
+        ``real_tokens`` were actual prompt-chunk tokens, the rest
+        bucket right-padding and spare parked rows."""
         total = batch * bucket
         with self._lock:
             self.prefill_real += real_tokens
             self.prefill_pad += max(0, total - real_tokens)
             self.prefill_dispatches += 1
+            self.prefill_by_rows[batch] = (
+                self.prefill_by_rows.get(batch, 0) + 1)
 
     # -- step timeline (engine thread only) ------------------------------
 
@@ -404,7 +409,10 @@ class EngineEffAccounting:
                            "busy_s": round(self.decode_busy_s, 4)},
                 "prefill": {"real": self.prefill_real,
                             "pad": self.prefill_pad,
-                            "dispatches": self.prefill_dispatches},
+                            "dispatches": self.prefill_dispatches,
+                            "by_rows": {
+                                str(r): n for r, n in
+                                sorted(self.prefill_by_rows.items())}},
                 "bytes_total": self.bytes_total,
                 "bytes_effective": self.bytes_effective,
                 "compiles_total": self.compiles_total,

@@ -956,8 +956,14 @@ class LLMEngine:
         self._sampling_dirty = True
 
     def _do_prefill(self, works) -> List[StepOutput]:
-        """Batch-prefill every scheduled chunk: one device dispatch per
-        chunk-length bucket (usually one total), all slots at once.
+        """Prefill every scheduled chunk. The chunks due in one
+        chunk-length bucket run in dispatches of cfg.prefill_rows_for
+        rows: up to a quarter of the batch, a one-row dispatch each
+        (one prompt due is one row computed, the steady case); more,
+        one dispatch of max_num_seqs rows for all. Rows are not slots:
+        a dispatch's chunks take its rows 0.. in order, ``slots`` tells
+        the executable which slot each serves, and what it returns is
+        read by row (_land_prefill).
         Runs under the ``prefill_host`` phase: what is not inside one
         of the three phases below is host preparation (grouping, table
         and sampling uploads)."""
@@ -969,17 +975,34 @@ class LLMEngine:
         for w in works:
             by_bucket.setdefault(self.cfg.bucket_for(len(w.chunk)),
                                  []).append(w)
+        dispatches = []
+        for bucket, due in sorted(by_bucket.items()):
+            rows = self.cfg.prefill_rows_for(len(due))
+            dispatches += [(bucket, rows, due[i:i + rows])
+                           for i in range(0, len(due), rows)]
+        if any(w.seq.options.shaped for w in works if w.is_last):
+            # last-chunk rows sample their first token with shaped
+            # logits; mirrors are current (all in-flight windows were
+            # drained before prefill), and one upload serves every
+            # dispatch of the step: the state is per slot. The next
+            # decode dispatch rebuilds AGAIN on the same step — not
+            # redundant: that rebuild includes the first tokens this
+            # very prefill samples, which prefill executables don't
+            # record device-side
+            self.runner.set_penalty_state(*self._penalty_arrays())
         B, S = self.cfg.max_num_seqs, self.cfg.max_model_len
-        for bucket, group in sorted(by_bucket.items()):
-            tokens = np.zeros((B, bucket), np.int32)
-            starts = np.full((B,), S, np.int32)   # parked rows: clamp on S-1
-            lengths = np.ones((B,), np.int32)
+        for bucket, rows, group in dispatches:
+            tokens = np.zeros((rows, bucket), np.int32)
+            # spare rows: parked at S, where nothing is written or read
+            starts = np.full((rows,), S, np.int32)
+            lengths = np.ones((rows,), np.int32)
+            slots = np.zeros((rows,), np.int32)
             kv_need = bucket
-            for w in group:
-                slot = w.seq.slot
-                tokens[slot, :len(w.chunk)] = w.chunk
-                starts[slot] = w.start
-                lengths[slot] = len(w.chunk)
+            for row, w in enumerate(group):
+                tokens[row, :len(w.chunk)] = w.chunk
+                starts[row] = w.start
+                lengths[row] = len(w.chunk)
+                slots[row] = w.seq.slot
                 kv_need = max(kv_need, w.start + bucket)
             kv_len = self.cfg.kv_bucket_for(min(kv_need, S))
             gtable = gids = gstates = None
@@ -997,24 +1020,17 @@ class LLMEngine:
                         if w.is_last), default=0)
             if topk:
                 topk = 1 << (topk - 1).bit_length()
-            if penalized:
-                # the group's last-chunk rows sample their first token
-                # with shaped logits; mirrors are current (all in-flight
-                # windows were drained before prefill). The next decode
-                # dispatch rebuilds AGAIN on the same step — not
-                # redundant: that rebuild includes the first tokens
-                # this very prefill samples, which prefill executables
-                # don't record device-side
-                self.runner.set_penalty_state(*self._penalty_arrays())
             with self._phase("prefill_dispatch", dispatches=True) as call:
                 devs = self.runner.prefill(
                     tokens, starts, lengths, self._dev_sampling, kv_len,
                     guide_table=gtable, guide_ids=gids,
-                    guide_states=gstates, penalized=penalized, topk=topk)
-            # bucket-padding accounting: the dispatch computed B*bucket
-            # positions; only the scheduled chunks' tokens were real
+                    guide_states=gstates, penalized=penalized, topk=topk,
+                    slots=slots)
+            # bucket-padding accounting: the dispatch computed
+            # rows*bucket positions; only the scheduled chunks' tokens
+            # were real
             self.eff.note_prefill(
-                bucket=bucket, batch=B,
+                bucket=bucket, batch=rows,
                 real_tokens=sum(len(w.chunk) for w in group))
             with self._phase("prefill_process"):
                 outputs.extend(self._land_prefill(group, devs, call.t1))
@@ -1028,11 +1044,12 @@ class LLMEngine:
         """Host side of one dispatched prefill group: scheduler and
         cache bookkeeping per chunk and, for rows whose prompt is now
         whole, the first token (one sync per group, ``prefill_sync``).
+        The group's n-th chunk ran in row n of the dispatch.
         ``t_called``: when ``runner.prefill`` returned."""
         outputs: List[StepOutput] = []
         ids_dev, lps_dev, tops_dev = devs
         ids = lps = tops = None
-        for w in group:
+        for row, w in enumerate(group):
             if w.seq.waits.prefill_call is None:
                 w.seq.waits.prefill_call = t_called
             w.seq.waits.prefill_chunks += 1
@@ -1084,15 +1101,13 @@ class LLMEngine:
             alts = None
             if tops is not None and k:
                 alts = [(int(t), float(l)) for t, l in
-                        zip(tops[0][seq.slot, :k],
-                            tops[1][seq.slot, :k])
+                        zip(tops[0][row, :k], tops[1][row, :k])
                         if l > -1e29]
             seq.first_token_time = time.monotonic()
             self.metrics.ttft.observe(
                 seq.first_token_time - seq.arrival_time)
             outputs.extend(self._accept_token(
-                seq, int(ids[seq.slot]), float(lps[seq.slot]),
-                alts))
+                seq, int(ids[row]), float(lps[row]), alts))
         return outputs
 
     def _ensure_dev_sampling(self) -> None:

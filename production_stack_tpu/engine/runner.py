@@ -9,10 +9,15 @@ TPU execution model:
   per (window, kv-length bucket, greedy): attention cost scales with the
   live context (kv bucket), not max_model_len, and all-greedy batches
   skip the [B, V] sampling sort entirely.
-- ``prefill``: FULL-BATCH — every admissible sequence's next chunk is
-  prefilled in ONE dispatch (tokens [B, Tb]; idle rows are parked at
-  position S where their writes clamp harmlessly onto S-1). One
-  executable per (chunk-length bucket, kv bucket).
+- ``prefill``: every admissible sequence's next chunk of one
+  chunk-length bucket is prefilled in a dispatch of ``rows`` rows
+  (tokens [rows, Tb]; the engine runs one row a chunk, or all
+  max_num_seqs for a burst: engine._do_prefill). A row is not a
+  slot: ``slots`` [rows] says which slot each row serves, and the
+  executable gathers whatever is kept per slot (block tables,
+  sampling, guided and penalty state) by it. Spare rows are parked at position S, where
+  nothing they compute is written or read. One executable per (rows,
+  chunk-length bucket, kv bucket).
 - Decode inputs are *device-carried*: each window's last sampled ids and
   advanced positions stay on device and feed the next window directly —
   the host uploads fresh state only when slot composition changes
@@ -223,7 +228,8 @@ class ModelRunner:
         # in tests).
         self.compile_observer = None
         # executable caches: decode keyed (batch, steps, kv_len,
-        # variant), prefill keyed (chunk bucket, kv bucket)
+        # variant), prefill keyed (rows, chunk bucket, kv bucket,
+        # variant)
         self._decode_fns = {}
         self._prefill_fns = {}
         # "kind|window|kv|batch" (the compile observer's key) -> the
@@ -510,7 +516,7 @@ class ModelRunner:
                 cnt.T, toks, pos, hist, gstate, counts, cache)
 
     def _prefill_impl(self, params, cache: KVCache, tables: jnp.ndarray,
-                      tokens: jnp.ndarray,
+                      slots: jnp.ndarray, tokens: jnp.ndarray,
                       starts: jnp.ndarray, lengths: jnp.ndarray,
                       sampling: SamplingParams, key: jax.Array,
                       guide_next: jnp.ndarray, guide_id: jnp.ndarray,
@@ -519,20 +525,32 @@ class ModelRunner:
                       *, kv_len: int, guided: bool = False,
                       penalized: bool = False, eos_id: int = 0,
                       topk: int = 0):
-        """Full-batch chunk prefill. tokens [B, Tb], starts/lengths [B].
+        """Chunk prefill of R rows. tokens [R, Tb], starts/lengths/
+        slots [R]; everything else that has a leading axis is per SLOT
+        ([max_num_seqs, ...]: tables, every leaf of sampling, guide_id,
+        guide_state, out_counts, prompt_seen) and is gathered by
+        ``slots`` here, before use, so a row may serve any slot.
 
-        Every row writes its chunk at its own offset through its block
-        table; idle rows (parked at start S) and right-padding tokens
-        are masked invalid and write to the trash block. Attention
-        reads the first ceil(kv_len/Bs) blocks; host guarantees
-        start + real chunk length <= kv_len for every participating
-        row, whose table covers its whole chunk (blocks are allocated
-        for the full prompt at admission).
-        Returns (sampled id of each row's last real token [B], its
-        logprob [B], cache').
+        Every row writes its chunk at its own offset through its slot's
+        block table; spare rows (parked at start S, whatever slot they
+        name) and right-padding tokens are masked invalid and write to
+        the trash block. Attention reads the first ceil(kv_len/Bs)
+        blocks; host guarantees start + real chunk length <= kv_len for
+        every participating row, whose table covers its whole chunk
+        (blocks are allocated for the full prompt at admission). On MoE
+        models the experts' capacity is reckoned on max_num_seqs rows
+        whatever R is (ops/moe.moe_mlp ``capacity_tokens``): fewer rows
+        never hold less per expert than the full dispatch does.
+        Returns (sampled id of each row's last real token [R], its
+        logprob [R], top ids and logprobs [R, K], cache').
         """
         Tb = tokens.shape[1]
         S = self.engine_cfg.max_model_len
+        tables, sampling, guide_id, guide_state, out_counts, \
+            prompt_seen = jax.tree_util.tree_map(
+                lambda x: jnp.take(x, slots, axis=0),
+                (tables, sampling, guide_id, guide_state, out_counts,
+                 prompt_seen))
         positions = starts[:, None] + jnp.arange(Tb)[None, :]
         # real tokens per row: right-padding and idle rows must not
         # write K/V, route in MoE layers, or steal expert capacity
@@ -544,7 +562,8 @@ class ModelRunner:
             rope=self.rope, kv_len=kv_len,
             use_flash=None, mesh=self.mesh,
             lora_params=self._lora, adapter_ids=sampling.adapter,
-            lora_scaling=self._lora_scaling, token_valid=token_valid)
+            lora_scaling=self._lora_scaling, token_valid=token_valid,
+            moe_capacity_tokens=self.engine_cfg.max_num_seqs * Tb)
         with jax.named_scope("sample"):
             last = jnp.take_along_axis(
                 logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
@@ -818,18 +837,41 @@ class ModelRunner:
     def prefill(self, tokens, starts, lengths, sampling: SamplingParams,
                 kv_len: int, guide_table=None, guide_ids=None,
                 guide_states=None, penalized: bool = False,
-                topk: int = 0):
-        """Full-batch chunk prefill (see _prefill_impl). tokens [B, Tb]
-        int32 np; starts/lengths [B]. Returns device (ids, logprobs,
-        tops) — ids/logprobs [B]; tops None unless topk > 0, then
-        ([B, K] ids, [B, K] logprobs) alternatives.
+                topk: int = 0, slots=None):
+        """Chunk prefill of ``tokens.shape[0]`` rows (see
+        _prefill_impl). tokens [R, Tb] int32 np; starts/lengths [R];
+        slots [R] the slot each row serves (None: rows are slots
+        0..R-1, which at R == max_num_seqs is the full-batch dispatch).
+        sampling, guide_ids and guide_states stay per slot
+        ([max_num_seqs]), as the engine keeps them. Returns device
+        (ids, logprobs, tops) — ids/logprobs [R], by row; tops None
+        unless topk > 0, then ([R, K] ids, [R, K] logprobs)
+        alternatives.
 
-        Prefill executables compile lazily per (chunk, kv bucket), each
-        on the attention path its shape selects (_compile).
+        Prefill executables compile lazily per (rows, chunk, kv
+        bucket), each on the attention path its shape selects
+        (_compile). A shape (chunk bucket, kv bucket, variant) first
+        built at several rows is built at one row in the same call (a
+        parked dispatch): one chunk due is the steady case of every
+        shape (cfg.prefill_rows_for), and whoever warms a shape at
+        max_num_seqs rows (warmup(), a benchmark's launcher) has then
+        warmed what serving runs, and no row count compiles mid-serving.
         """
-        Tb = tokens.shape[1]
+        R, Tb = tokens.shape
         guided = guide_table is not None
         B = self.engine_cfg.max_num_seqs
+        gshape = guide_table.shape if guided else None
+        key = (R, Tb, kv_len, guided, gshape, penalized, topk)
+        if R > 1 and key not in self._prefill_fns:
+            S = self.engine_cfg.max_model_len
+            self.prefill(np.zeros((1, Tb), np.int32),
+                         np.full((1,), S, np.int32),
+                         np.ones((1,), np.int32), sampling, kv_len,
+                         guide_table=guide_table, guide_ids=guide_ids,
+                         guide_states=guide_states, penalized=penalized,
+                         topk=topk)
+        if slots is None:
+            slots = np.arange(R, dtype=np.int32)
         if not guided:
             guide_table = jnp.zeros((1, 1, 1), jnp.int32)
             guide_ids = np.zeros((B,), np.int32)
@@ -840,16 +882,16 @@ class ModelRunner:
             counts = jnp.zeros((B, 1), jnp.int32)
             seen = jnp.zeros((B, 1), bool)
         args = (self.params, self.cache, self._dev_tables(),
+                jnp.asarray(slots, jnp.int32),
                 jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(starts, jnp.int32),
                 jnp.asarray(lengths, jnp.int32), sampling, self._next_key(),
                 guide_table, jnp.asarray(guide_ids, jnp.int32),
                 jnp.asarray(guide_states, jnp.int32), counts, seen)
-        gshape = guide_table.shape if guided else None
 
         def make_prefill():
-            logger.info("compiling prefill (chunk=%d kv=%d%s%s)", Tb,
-                        kv_len, " guided" if guided else "",
+            logger.info("compiling prefill (rows=%d chunk=%d kv=%d%s%s)",
+                        R, Tb, kv_len, " guided" if guided else "",
                         " penalized" if penalized else "")
             return jax.jit(_named("prefill_chunk", self._prefill_impl,
                                   kv_len=kv_len, guided=guided,
@@ -858,10 +900,9 @@ class ModelRunner:
                            donate_argnums=(1,))
 
         fn = self._compile(
-            self._prefill_fns,
-            (Tb, kv_len, guided, gshape, penalized, topk),
+            self._prefill_fns, key,
             make_prefill, args, kind="prefill", window=Tb,
-            kv_len=kv_len, batch=B, positions=Tb)
+            kv_len=kv_len, batch=R, positions=Tb)
         ids, lps, tis, tls, self.cache = fn(*args)
         return ids, lps, (tis, tls) if topk else None
 
@@ -1037,10 +1078,14 @@ class ModelRunner:
         pins this) — plus the full-sort sampled variant and the
         speculative executable at the full shape only. With adaptation
         off, just the three variants at (max_num_seqs, decode_window).
-        Every prefill bucket compiles at its minimal kv bucket. Larger
-        kv buckets and rarely-hit variants (guided/penalized/topk,
-        adapted sampled-sort shapes) compile lazily on first use
-        (one-time, logged). Returns seconds spent."""
+        Every prefill bucket compiles at its minimal kv bucket, at
+        max_num_seqs rows (a burst) and, with it, at one row (one
+        chunk due, the steady case: prefill() builds the two
+        together), the only row counts the engine dispatches
+        (cfg.prefill_rows_for). Larger kv buckets and rarely-hit
+        variants (guided/penalized/topk, adapted sampled-sort shapes)
+        compile lazily on first use (one-time, logged). Returns
+        seconds spent."""
         import numpy as np
         t0 = time.time()
         cfg = self.engine_cfg
@@ -1092,6 +1137,7 @@ class ModelRunner:
         dt = time.time() - t0
         logger.info(
             "warmup compiled decode grid (batch %s x window %s, kv %d) "
-            "+ %d prefill buckets in %.1fs", list(batches),
-            list(windows), kv0, len(cfg.prefill_buckets), dt)
+            "+ %d prefill buckets (rows 1 and %d) in %.1fs",
+            list(batches), list(windows), kv0,
+            len(cfg.prefill_buckets), B, dt)
         return dt

@@ -176,7 +176,8 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                 lora_scaling: float = 1.0,
                 token_valid: Optional[jnp.ndarray] = None,
                 block_tables: Optional[jnp.ndarray] = None,
-                mesh=None, layer_local=None, layer=None):
+                mesh=None, layer_local=None, layer=None,
+                moe_capacity_tokens: Optional[int] = None):
     """One transformer block. x [B,T,H]; kv = the WHOLE paged pool
     (k, v) [L,N,Hkv,Bs,D] — with (ks, vs) [L,N,Hkv,Bs] behind them for
     the int8 pool — of which this block appends to and reads
@@ -194,7 +195,9 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     < kv_len.
     token_valid [B,T] marks real tokens: invalid tokens' K/V writes are
     routed to the trash block and (on MoE models) they are kept out of
-    expert-capacity competition.
+    expert-capacity competition. moe_capacity_tokens (static): the
+    token count the experts' capacity is reckoned on, where that is not
+    B*T (ops/moe.moe_mlp ``capacity_tokens``).
     lora_layer: this layer's stacked adapters {proj: {a, b}} + per-row
     adapter_ids [B] (models/lora.py) — batched multi-LoRA.
 
@@ -342,7 +345,8 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
         y = moe.moe_mlp(
             hidden.reshape(B * T, H), lp["router"], lp["gate"],
             lp["up"], lp["down"], top_k=cfg.num_experts_per_tok,
-            capacity_factor=cfg.moe_capacity_factor, act=act,
+            capacity_factor=cfg.moe_capacity_factor,
+            capacity_tokens=moe_capacity_tokens, act=act,
             valid=None if token_valid is None
             else token_valid.reshape(B * T),
             renormalize=cfg.norm_topk_prob,
@@ -387,7 +391,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             lora_params=None, adapter_ids: Optional[jnp.ndarray] = None,
             lora_scaling: float = 1.0,
             token_valid: Optional[jnp.ndarray] = None,
-            mesh=None) -> Tuple[jnp.ndarray, KVCache]:
+            mesh=None, moe_capacity_tokens: Optional[int] = None,
+            ) -> Tuple[jnp.ndarray, KVCache]:
     """Incremental forward. tokens/positions [B,T] -> (logits fp32 [B,T,V], cache').
 
     cache is the paged block pool (models/kv.py); block_tables [B, MB]
@@ -406,6 +411,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     token_valid [B,T] bool marks real (non-padding) tokens — their K/V
     writes are routed to the trash block, and MoE models keep them out
     of expert-capacity competition (ops/moe.py).
+    moe_capacity_tokens (static): reckon the experts' capacity on this
+    many tokens instead of B*T — a prefill of fewer rows than the full
+    batch passes the full batch's count, so that it never holds less
+    per expert (ops/moe.moe_mlp ``capacity_tokens``).
     """
     if rope is None:
         rope = rope_table(cfg.max_position_embeddings, cfg.head_dim_,
@@ -435,7 +444,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                            lora_scaling=lora_scaling,
                            token_valid=token_valid,
                            block_tables=block_tables, mesh=mesh,
-                           layer_local=local, layer=layer), None
+                           layer_local=local, layer=layer,
+                           moe_capacity_tokens=moe_capacity_tokens), None
 
     layers = jnp.arange(cfg.num_layers)
     xs = (params["layers"], layers, lora_params,

@@ -160,7 +160,8 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             up: jnp.ndarray, down: jnp.ndarray, *, top_k: int,
             capacity_factor: float = 2.0, dense_threshold: int = 64,
             act: Callable = jax.nn.silu, valid=None,
-            exact=None, renormalize: bool = True) -> jnp.ndarray:
+            exact=None, renormalize: bool = True,
+            capacity_tokens=None) -> jnp.ndarray:
     """MoE feed-forward. x [N, h]; router_w [h, E]; gate/up [E, h, i];
     down [E, i, h]. Returns [N, h] in x.dtype.
 
@@ -169,6 +170,13 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
     path regardless of N (the decode path passes it — decode must never
     drop a token); exact=None auto-selects it for N ≤ dense_threshold
     or whenever capacity covers every possible assignment.
+    capacity_tokens: the token count the per-expert capacity is
+    reckoned on (default N), clamped to N. The engine's prefill passes
+    max_num_seqs x chunk bucket whatever rows it dispatches
+    (runner._prefill_impl): a chunk alone in a one-row dispatch then
+    holds as much per expert as it did among the parked rows of a full
+    one, and where that covers its N tokens (Qwen1.5-MoE, 256 tokens:
+    552) it takes the exact path and drops nothing.
     """
     N = x.shape[0]
     E = _wshape(gate)[0]
@@ -176,7 +184,8 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
         top_p, top_i = route(x, router_w, top_k, renormalize=renormalize)
         if valid is not None:
             top_p = top_p * valid.astype(top_p.dtype)[:, None]
-    capacity = capacity_for(N, E, top_k, capacity_factor)
+    capacity = min(N, capacity_for(capacity_tokens or N, E, top_k,
+                                   capacity_factor))
     if exact is None:
         exact = N <= dense_threshold or capacity >= N
     if exact:

@@ -278,19 +278,26 @@ def _compile_decode_window(runner, params, cache, rep):
     ).compile()
 
 
-def _compile_prefill_chunk(runner, params, cache, rep, Tb: int):
+def _compile_prefill_chunk(runner, params, cache, rep, Tb: int,
+                           rows: int = 0):
+    """A prefill chunk of ``rows`` rows (0: all max_num_seqs); what is
+    kept per slot keeps max_num_seqs rows and is gathered by slots."""
     B = runner.engine_cfg.max_num_seqs
+    R = rows or B
     a = _step_args(runner, rep, B)
     fn = jax.jit(partial(runner._prefill_impl, kv_len=512),
                  donate_argnums=(1,))
     return fn.lower(
-        params, cache, a["tables"], rep((B, Tb), jnp.int32),
-        rep((B,), jnp.int32), rep((B,), jnp.int32), a["sampling"],
+        params, cache, a["tables"], rep((R,), jnp.int32),
+        rep((R, Tb), jnp.int32),
+        rep((R,), jnp.int32), rep((R,), jnp.int32), a["sampling"],
         a["key"], a["guide_next"], a["guide_id"], a["guide_state"],
         a["counts"], a["seen"]).compile()
 
 
-@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk"])
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk",
+                                     "prefill_chunk_1row",
+                                     "prefill_chunk_2rows"])
 def test_step_program_never_copies_the_pool(topo, tpu_branches,
                                             program):
     """Mistral head geometry, two layers, a pool of 97 blocks: in the
@@ -309,7 +316,10 @@ def test_step_program_never_copies_the_pool(topo, tpu_branches,
     if program == "decode_window":
         compiled = _compile_decode_window(runner, params, cache, rep)
     else:
-        compiled = _compile_prefill_chunk(runner, params, cache, rep, 128)
+        rows = {"prefill_chunk": 0, "prefill_chunk_1row": 1,
+                "prefill_chunk_2rows": 2}[program]
+        compiled = _compile_prefill_chunk(runner, params, cache, rep,
+                                          128, rows)
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     # "%name = bf16[2,97,8,64,128]{layout} opcode(": a pool-shaped result
@@ -335,8 +345,11 @@ def test_decode_window_compiles_at_mistral_7b(topo, tpu_branches, tp):
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("rows", [0, 1])
 @pytest.mark.parametrize("tp", [1, 4])
-def test_prefill_step_compiles_at_mistral_7b(topo, tpu_branches, tp):
-    compiled = _compile_prefill_chunk(*_runner_shapes(topo, tp), 512)
+def test_prefill_step_compiles_at_mistral_7b(topo, tpu_branches, tp,
+                                             rows):
+    compiled = _compile_prefill_chunk(*_runner_shapes(topo, tp), 512,
+                                      rows)
     assert "tpu_custom_call" in compiled.as_text()
-    _fits(compiled, f"prefill step tp={tp}")
+    _fits(compiled, f"prefill step tp={tp} rows={rows or 'all'}")

@@ -102,7 +102,12 @@ def test_prefill_padding_accounting():
     acct.note_prefill(bucket=64, batch=8, real_tokens=100)
     p = acct.report()["prefill"]
     assert p["real"] == 100 and p["pad"] == 412
-    assert p["dispatches"] == 1
+    assert p["dispatches"] == 1 and p["by_rows"] == {"8": 1}
+    # a one-row dispatch pads only to its chunk bucket
+    acct.note_prefill(bucket=64, batch=1, real_tokens=40)
+    p = acct.report()["prefill"]
+    assert p["real"] == 140 and p["pad"] == 412 + 24
+    assert p["by_rows"] == {"1": 1, "8": 1}
 
 
 def test_compile_tracking_and_event_overlap():

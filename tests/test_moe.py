@@ -136,6 +136,32 @@ def test_exact_flag_overrides_capacity():
                                atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("rows,bucket", [(1, 256), (1, 128), (2, 256)])
+def test_small_prefill_reckons_capacity_on_the_full_batch(rows, bucket):
+    """Qwen1.5-MoE's routing shape (60 experts, top-4, factor 2) with a
+    router rigged so that EVERY token's first choice is expert 0: a
+    one-row chunk sends ``bucket`` tokens there, more than
+    capacity_for(bucket, ...) (40 at 256) holds. Reckoned on the full
+    batch's 16 x bucket tokens (what runner._prefill_impl passes) and
+    clamped to N, the capacity covers every token, the chunk takes the
+    exact path and loses none; reckoned on its own N it loses most."""
+    E, k, N = 60, 4, rows * bucket
+    x, rw, g, u, d = _rand_moe(jax.random.PRNGKey(8), N=N, E=E)
+    x = x.at[:, 0].set(jnp.abs(x[:, 0]) + 1.0)
+    rw = rw.at[0, 0].set(50.0)          # expert 0 wins every token
+    top_p, top_i = moe.route(x, rw, k, renormalize=False)
+    assert (np.asarray(top_i)[:, 0] == 0).all()
+    assert moe.capacity_for(N, E, k, 2.0) < N
+    exact = moe._moe_exact(x, top_p, top_i, g, u, d, jax.nn.silu)
+    got = moe.moe_mlp(x, rw, g, u, d, top_k=k, renormalize=False,
+                      capacity_tokens=16 * bucket)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(exact),
+                               atol=1e-5, rtol=1e-5)
+    own = moe.moe_mlp(x, rw, g, u, d, top_k=k, renormalize=False)
+    lost = np.abs(np.asarray(own) - np.asarray(exact)).max(-1) > 1e-3
+    assert lost.sum() >= N - moe.capacity_for(N, E, k, 2.0)
+
+
 def test_capacity_for():
     assert moe.capacity_for(512, 8, 2, 1.0) == 128
     assert moe.capacity_for(512, 8, 2, 100.0) == 512   # clamped to N
