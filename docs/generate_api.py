@@ -75,7 +75,6 @@ MODULES = {
         "production_stack_tpu.models.lora",
         "production_stack_tpu.models.quant",
         "production_stack_tpu.ops.attention",
-        "production_stack_tpu.ops.pallas_attention",
         "production_stack_tpu.ops.pallas_paged",
         "production_stack_tpu.ops.moe",
         "production_stack_tpu.ops.norms",
@@ -84,9 +83,6 @@ MODULES = {
     "Parallelism": [
         "production_stack_tpu.parallel.mesh",
         "production_stack_tpu.parallel.sharding",
-        "production_stack_tpu.parallel.pipeline",
-        "production_stack_tpu.parallel.ring_attention",
-        "production_stack_tpu.parallel.train",
     ],
     "KV cache tiering": [
         "production_stack_tpu.kvcache.chunks",

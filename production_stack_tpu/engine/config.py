@@ -163,11 +163,9 @@ class EngineConfig:
             raise NotImplementedError(
                 "pipeline-parallel SERVING is not implemented: decode "
                 "would pipeline one token at a time (pure bubble) "
-                "without multi-batch in-flight scheduling. PP exists "
-                "for training (parallel/pipeline.py, GPipe over the "
-                "'pp' mesh axis); serving scales via tensor_parallel_"
-                "size/expert_parallel_size within a slice and "
-                "replicaCount across slices")
+                "without multi-batch in-flight scheduling. Serving "
+                "scales via tensor_parallel_size/expert_parallel_size "
+                "within a slice and replicaCount across slices")
         if self.expert_parallel_size < 1:
             raise ValueError("expert_parallel_size must be >= 1")
         if not 0 <= self.speculative_ngram_tokens <= 16:

@@ -166,7 +166,7 @@ class LLMEngine:
                     raise ValueError(
                         f"expert_parallel_size={ep} does not divide "
                         f"num_experts={E}")
-            mesh = build_mesh(MeshConfig(dp=1, sp=1, tp=tp, ep=ep),
+            mesh = build_mesh(MeshConfig(dp=1, tp=tp, ep=ep),
                               jax.devices()[:tp * ep])
         # what this engine runs on, said BEFORE the weights are built (a
         # launcher that must stay off JAX reads it here first, and in
@@ -176,7 +176,7 @@ class LLMEngine:
         # entry for this device kind, else none — never another chip's
         from production_stack_tpu.engine.efficiency import (
             HBM_PEAK_GBPS, EngineEffAccounting)
-        from production_stack_tpu.ops import pallas_attention
+        from production_stack_tpu.ops import pallas_paged
         self.devices = (list(mesh.devices.flat) if mesh is not None
                         else jax.devices()[:1])
         kind = self.devices[0].device_kind
@@ -188,7 +188,7 @@ class LLMEngine:
             "engine device: platform=%s device_kind=%r devices=%d "
             "(process sees %d) hbm_peak_gbps=%s pallas_attention=%s",
             self.devices[0].platform, kind, len(self.devices),
-            jax.device_count(), peak_gbps, pallas_attention.mode())
+            jax.device_count(), peak_gbps, pallas_paged.mode())
         self.runner = ModelRunner(self.model_cfg, engine_cfg, params=params,
                                   mesh=mesh, lora_stacked=lora_stacked,
                                   lora_scaling=lora_scaling)
@@ -1924,7 +1924,7 @@ class LLMEngine:
         compiled executable took ("kind|window|kv_bucket|batch", the
         ``totals.compiles`` key); ``bytes_in_use`` is per device where
         ``memory_stats()`` gives it (None on the CPU)."""
-        from production_stack_tpu.ops import pallas_attention
+        from production_stack_tpu.ops import pallas_paged
         devs = []
         for d in self.devices:
             stats = d.memory_stats() or {}
@@ -1941,7 +1941,7 @@ class LLMEngine:
             # the ones this engine's mesh spans
             "count": jax.device_count(),
             "engine_devices": devs,
-            "pallas_attention": pallas_attention.mode(),
+            "pallas_attention": pallas_paged.mode(),
             "attention_paths": dict(self.runner.attention_paths),
         }
 

@@ -45,6 +45,7 @@ from production_stack_tpu.engine.sampler import (SamplingParams,
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.models.kv import KVCache, make_cache
 from production_stack_tpu.models import llama
+from production_stack_tpu.ops.pallas_paged import JNP_GATHER, attention_path
 from production_stack_tpu.ops.rope import rope_table
 from production_stack_tpu.utils import init_logger
 
@@ -128,16 +129,13 @@ class ModelRunner:
             from jax.sharding import NamedSharding
             from production_stack_tpu.parallel.sharding import (
                 cache_pspec, param_shardings)
-            from production_stack_tpu.ops import (pallas_attention,
-                                                  pallas_paged)
-            if not pallas_paged.mesh_tp_only(mesh):
+            if (self._attention_path(1, mesh) == JNP_GATHER
+                    and self._attention_path(1, None) != JNP_GATHER):
                 # block-axis-sharded pools (dp > 1) forfeit the paged
-                # kernel (ops/pallas_paged.py mesh_tp_only): the
-                # gathered-view fallback re-materializes ~3x the KV
-                # traffic. Never let a helm value stumble into that —
-                # and never stumble into it SILENTLY: the fallback is
-                # announced at engine start in every world, not just
-                # when the kernel would otherwise have run.
+                # kernel this backend would otherwise run
+                # (ops/pallas_paged.attention_path): the gathered-view
+                # fallback re-materializes ~3x the KV traffic. Never
+                # let a helm value stumble into that.
                 cliff = (
                     "serving mesh %s shards the KV pool's block axis: "
                     "the pallas paged-attention kernel only runs "
@@ -146,13 +144,7 @@ class ModelRunner:
                     "KV traffic). Prefer tp-only serving meshes with "
                     "replicaCount for data parallelism." % dict(
                         mesh.shape))
-                if not pallas_attention.flash_enabled():
-                    # kernel unavailable on this backend anyway (CPU /
-                    # interpret): informational, nothing to refuse
-                    logger.warning(
-                        "paged-attention kernel disabled for this "
-                        "mesh: " + cliff)
-                elif engine_cfg.dp_gather_attention_ok:
+                if engine_cfg.dp_gather_attention_ok:
                     logger.warning(
                         "dp_gather_attention_ok=True: " + cliff)
                 else:
@@ -376,8 +368,7 @@ class ModelRunner:
             logits, cache = llama.forward(
                 params, self.model_cfg, toks[:, None], pos[:, None],
                 cache, block_tables=tables,
-                rope=self.rope, kv_len=kv_len, use_flash=None,
-                mesh=self.mesh,
+                rope=self.rope, kv_len=kv_len, mesh=self.mesh,
                 lora_params=self._lora, adapter_ids=sampling.adapter,
                 lora_scaling=self._lora_scaling,
                 token_valid=(pos < S)[:, None])
@@ -465,8 +456,7 @@ class ModelRunner:
             logits, cache = llama.forward(
                 params, self.model_cfg, step_toks, step_pos, cache,
                 block_tables=tables,
-                rope=self.rope, kv_len=kv_len, use_flash=None,
-                mesh=self.mesh,
+                rope=self.rope, kv_len=kv_len, mesh=self.mesh,
                 lora_params=self._lora, adapter_ids=sampling.adapter,
                 lora_scaling=self._lora_scaling,
                 token_valid=step_pos < S_max)
@@ -559,8 +549,7 @@ class ModelRunner:
         logits, cache = llama.forward(
             params, self.model_cfg, tokens, positions, cache,
             block_tables=tables,
-            rope=self.rope, kv_len=kv_len,
-            use_flash=None, mesh=self.mesh,
+            rope=self.rope, kv_len=kv_len, mesh=self.mesh,
             lora_params=self._lora, adapter_ids=sampling.adapter,
             lora_scaling=self._lora_scaling, token_valid=token_valid,
             moe_capacity_tokens=self.engine_cfg.max_num_seqs * Tb)
@@ -789,12 +778,20 @@ class ModelRunner:
             self._dec_counts = counts_out
         return ids, lps, None, (tis, tls) if topk else None
 
+    def _attention_path(self, positions: int, mesh) -> str:
+        """ops/pallas_paged.attention_path for this model's head
+        geometry and this engine's block size."""
+        cfg = self.model_cfg
+        return attention_path(
+            positions, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
+            self.engine_cfg.kv_block_size, mesh)
+
     def _compile(self, cache: dict, key, make_fn, args, *, kind: str,
                  window: int, kv_len: int, batch: int, positions: int):
         """Fetch-or-compile an executable. ``positions`` is its query
         positions per row (1 decode, draft+1 speculative, the chunk
         bucket for prefill): with the static config that fixes its
-        attention path (llama.attention_path), which is logged once and
+        attention path (``_attention_path``), which is logged once and
         kept for the ``device`` block of GET /debug/perf. The path is
         chosen by shape before compiling and never changed after: a
         kernel the compiler refuses raises, naming the executable.
@@ -808,10 +805,7 @@ class ModelRunner:
         fn = cache.get(key)
         if fn is not None:
             return fn
-        from production_stack_tpu.ops import pallas_attention
-        path = llama.attention_path(
-            self.model_cfg, positions, self.engine_cfg.kv_block_size,
-            pallas_attention.flash_enabled(), self.mesh)
+        path = self._attention_path(positions, self.mesh)
         logger.info("%s executable (batch=%d window=%d kv=%d): "
                     "attention path %s", kind, batch, window, kv_len,
                     path)

@@ -2,7 +2,7 @@
 
 Two committed shapes:
 
-- BENCH-schema JSON (the repo's existing perf record format, bench.py):
+- BENCH-schema JSON (the shape of the root-level ``BENCH_*.json`` records):
   ``{"metric", "value", "unit", "platform", "detail": {...}}`` — one
   headline number plus full methodology in ``detail``.
 - ``SCALEOUT_*.json`` — the replicas → aggregate tokens/s curve with
@@ -175,7 +175,7 @@ def aggregate(records: List[RequestRecord],
 def bench_schema(metric: str, agg: Dict, *, platform: str = "cpu",
                  detail: Optional[Dict] = None) -> Dict:
     """Wrap an aggregate into the BENCH_*.json record shape so driver
-    tooling that scrapes bench.py output can scrape loadgen output
+    tooling that scrapes those records can scrape loadgen output
     unchanged."""
     d = dict(agg)
     d.update(detail or {})

@@ -40,6 +40,11 @@ TPU-first invariants:
   the few blocks a chunk touches, merges the new rows into them and
   scatters whole ``[Hkv, Bs, D]`` blocks back: every pool-shaped
   operation keeps the pool's own layout and updates it in place.
+- **A layer sees two calls.** ``append`` writes its chunk and
+  ``attend`` reads it back under the queries; whether the pool is
+  quantized, which implementation reads it and that a kernel exists
+  are this module's business and ops/pallas_paged.attention_path's,
+  not the model's (models/llama._layer_body).
 - Reads go through the Pallas paged kernel (blocks streamed straight
   from the pool through scalar-prefetched tables — each KV byte read
   once) or, on backends/meshes the kernel does not cover, a *gathered
@@ -66,6 +71,9 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
+from production_stack_tpu.ops import pallas_paged
+from production_stack_tpu.ops.attention import attention_with_cache
+
 
 class KVCache(NamedTuple):
     k: jnp.ndarray  # [L, N, Hkv, Bs, D]
@@ -87,6 +95,11 @@ class KVCache(NamedTuple):
     @property
     def quantized(self) -> bool:
         return self.ks is not None
+
+
+# the pool as a step program's layer loop carries it: a KVCache's
+# arrays without the Nones — (k, v) or, int8, (k, v, ks, vs)
+Pool = Tuple[jnp.ndarray, ...]
 
 
 def make_cache(num_layers: int, num_blocks: int, block_size: int,
@@ -317,3 +330,75 @@ def gather_view_q(pool: jnp.ndarray, scales: jnp.ndarray,
     g = (g * s[..., None]).astype(dtype)
     g = g.transpose(0, 1, 3, 2, 4)
     return g.reshape(B, nb * Bs, Hkv, D)
+
+
+def append(pool: Pool, k: jnp.ndarray, v: jnp.ndarray,
+           tables: jnp.ndarray, starts: jnp.ndarray,
+           valid: Optional[jnp.ndarray], layer) -> Pool:
+    """One layer's write: k, v [B,T,Hkv,D] — row b's tokens at
+    positions starts[b]..starts[b]+T-1 — appended to layer ``layer``
+    of the whole pool, which comes back as the same tuple it came in
+    as (append_chunk, or append_chunk_q where the pool carries
+    scales)."""
+    k_cache, v_cache, *scales = pool
+    if scales:
+        k_cache, k_scales = append_chunk_q(k_cache, scales[0], k, tables,
+                                           starts, valid, layer)
+        v_cache, v_scales = append_chunk_q(v_cache, scales[1], v, tables,
+                                           starts, valid, layer)
+        return k_cache, v_cache, k_scales, v_scales
+    return (append_chunk(k_cache, k, tables, starts, valid, layer),
+            append_chunk(v_cache, v, tables, starts, valid, layer))
+
+
+def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
+           starts: jnp.ndarray, positions: jnp.ndarray,
+           kv_len: Optional[int], layer, *, window: Optional[int],
+           scale: float, softcap: Optional[float],
+           mesh=None) -> jnp.ndarray:
+    """One layer's read: q [B,T,H,D] at ``positions`` [B,T] (contiguous
+    from starts [B]) over layer ``layer`` of the pool, which already
+    holds the chunk's own K/V (append, then attend) -> [B,T,H,D].
+
+    kv_len (static) bounds the read to the first ceil(kv_len/Bs)
+    blocks of every slot; the caller guarantees every real query
+    position is < kv_len. window: sliding window (None/0 = full
+    causal); softcap: tanh cap on the raw scores (None/0 = off).
+
+    On a kernel path (pallas_paged.attention_path) the K/V blocks are
+    streamed straight from pool[layer] through the tables — no slice
+    of the pool, no gathered copy, no [T, S] score materialization,
+    per-row causal block skipping; prefill chunks AND decode/spec
+    windows, shard-local per head via shard_map under a tp-only mesh.
+    Elsewhere the gathered view feeds the position-masked jnp
+    attention (ops/attention.py)."""
+    k_cache, v_cache, *scales = pool
+    Bs, MB = k_cache.shape[-2], tables.shape[1]
+    nb = MB if kv_len is None else min(-(-kv_len // Bs), MB)
+    T, H, D = q.shape[1:]
+    path = pallas_paged.attention_path(T, H // k_cache.shape[2], D, Bs,
+                                       mesh)
+    if path != pallas_paged.JNP_GATHER:
+        kw = dict(nb=nb, interpret=pallas_paged.needs_interpret(),
+                  window=window or 0, scale=scale,
+                  softcap=softcap or 0.0, layer=layer)
+        if scales:
+            kw.update(k_scales=scales[0], v_scales=scales[1])
+        if mesh is not None:
+            return pallas_paged.paged_attention_sharded(
+                q, k_cache, v_cache, tables, starts, mesh, **kw)
+        paged_fn = (pallas_paged.paged_decode_attention
+                    if path == "pallas_paged_decode"
+                    else pallas_paged.paged_attention)
+        return paged_fn(q, k_cache, v_cache, tables, starts, **kw)
+    if scales:
+        k_att = gather_view_q(k_cache, scales[0], tables, nb,
+                              dtype=q.dtype, layer=layer)
+        v_att = gather_view_q(v_cache, scales[1], tables, nb,
+                              dtype=q.dtype, layer=layer)
+    else:
+        k_att = gather_view(k_cache, tables, nb, layer=layer)
+        v_att = gather_view(v_cache, tables, nb, layer=layer)
+    return attention_with_cache(q, k_att, v_att, positions, scale=scale,
+                                sliding_window=window,
+                                logit_softcap=softcap)

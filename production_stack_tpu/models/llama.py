@@ -3,11 +3,13 @@
 Design (TPU-first, not a torch translation):
 - All L layers' weights are stacked along a leading layer axis and the
   forward pass runs ``lax.scan`` over layers: one traced layer body, O(1)
-  compile time in depth, and a natural seam for pipeline parallelism.
+  compile time in depth.
 - Weights live in bf16 (MXU-native); norms/softmax/logits in fp32.
-- Two entry points: ``forward`` (incremental, serving; reads/writes the
-  slot KV cache) and ``forward_train`` (full-sequence, no cache; used by
-  the training step and numerics tests).
+- Two entry points: ``forward`` (incremental, serving; appends to and
+  attends over the paged KV pool through models/kv.py) and
+  ``forward_train`` (full-sequence, no cache: the reference forward the
+  numerics tests and the on-chip benchmark compare against; ``encode``
+  is the same without the LM head, for the embeddings endpoints).
 - Sharding is NOT baked in here — parallel/sharding.py assigns
   PartitionSpecs to this pytree by path (megatron-style column/row rules),
   so the same model code runs single-chip or on any mesh.
@@ -23,13 +25,12 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from production_stack_tpu.models import kv as kv_pool
 from production_stack_tpu.models import lora, quant
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.models.kv import (KVCache, append_chunk,
-                                            append_chunk_q, gather_view,
-                                            gather_view_q)
-from production_stack_tpu.ops import moe, pallas_attention, pallas_paged
-from production_stack_tpu.ops.attention import attention_with_cache, causal_attention
+from production_stack_tpu.models.kv import KVCache
+from production_stack_tpu.ops import moe
+from production_stack_tpu.ops.attention import causal_attention
 from production_stack_tpu.ops.norms import rms_norm
 from production_stack_tpu.ops.rope import apply_rope, rope_table
 
@@ -134,44 +135,11 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     return params
 
 
-# the gathered-copy jax.numpy attention (ops/attention.py): the test
-# reference, and the serving path only where attention_path says so
-JNP_GATHER = "jnp_gather"
-
-
-def attention_path(cfg: ModelConfig, T: int, block_size: int,
-                   use_flash: bool, mesh=None) -> str:
-    """Which cached-attention implementation a forward over T query
-    positions per row takes. Decided here, by shape, BEFORE anything
-    compiles — a kernel the compiler then refuses is an error, not a
-    reason to take another path (engine/runner.py records this per
-    executable; GET /debug/perf shows it).
-
-    ``pallas_paged_decode``: short windows (decode / speculative
-    verify) on the wide kernel — all kv heads + several pool blocks per
-    grid step, ~16x fewer grid steps than the general one.
-    ``pallas_paged``: prefill chunks on the general paged kernel.
-    ``*_sharded``: either, shard-local per head under a tp-only mesh.
-    ``jnp_gather``: the kernel is off (PSTPU_FLASH / not a TPU), the
-    chunk's working set misses VMEM (paged_viable), or the mesh shards
-    the pool's block axis."""
-    if not (use_flash
-            and pallas_paged.paged_viable(
-                T, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
-                block_size)
-            and (mesh is None or pallas_paged.mesh_tp_only(mesh))):
-        return JNP_GATHER
-    kernel = ("pallas_paged_decode" if T <= pallas_paged.DECODE_T_MAX
-              else "pallas_paged")
-    return kernel + ("_sharded" if mesh is not None else "")
-
-
 def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                 positions: jnp.ndarray, starts: Optional[jnp.ndarray],
                 x: jnp.ndarray, lp: Params,
                 kv: Optional[Tuple[jnp.ndarray, ...]],
-                attention_fn=None, kv_len: Optional[int] = None,
-                use_flash: bool = False, lora_layer=None,
+                kv_len: Optional[int] = None, lora_layer=None,
                 adapter_ids: Optional[jnp.ndarray] = None,
                 lora_scaling: float = 1.0,
                 token_valid: Optional[jnp.ndarray] = None,
@@ -184,10 +152,9 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     ``layer`` (its index, traced), addressed through block_tables
     [B,MB]. The pool comes back as the second result, the same buffer
     with this layer's chunk written (models/kv.py: carried, never
-    stacked). kv None (encode, the pipeline stages): no cache.
+    stacked); what is done to it is done behind models/kv.py
+    (``append``, ``attend``). kv None (encode): no cache.
 
-    attention_fn(q, k, v) overrides the no-cache attention — used to swap
-    in ring attention when the sequence dim is sharded (parallel/train.py).
     kv_len (static) bounds attention to the first ceil(kv_len/Bs) blocks
     of every slot: K/V writes target the pool via the tables, and
     score/value matmuls scale with the live context instead of
@@ -253,80 +220,17 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
 
     if kv is None:
         with jax.named_scope("attention"):
-            if attention_fn is not None:
-                attn = attention_fn(q, k, v)
-            else:
-                attn = _windowed(lambda w: causal_attention(
-                    q, k, v, scale=scale_val, sliding_window=w,
-                    logit_softcap=cap))
-        new_kv = None
+            attn = _windowed(lambda w: causal_attention(
+                q, k, v, scale=scale_val, sliding_window=w,
+                logit_softcap=cap))
     else:
-        quant_kv = len(kv) == 4   # (k, v, ks, vs): int8 pool + scales
         with jax.named_scope("kv_write"):
-            if quant_kv:
-                k_cache, k_scales = append_chunk_q(
-                    kv[0], kv[2], k, block_tables, starts, token_valid,
-                    layer)
-                v_cache, v_scales = append_chunk_q(
-                    kv[1], kv[3], v, block_tables, starts, token_valid,
-                    layer)
-            else:
-                k_cache = append_chunk(kv[0], k, block_tables, starts,
-                                       token_valid, layer)
-                v_cache = append_chunk(kv[1], v, block_tables, starts,
-                                       token_valid, layer)
-        Bs = k_cache.shape[-2]
-        MB = block_tables.shape[1]
-        nb = MB if kv_len is None else min(-(-kv_len // Bs), MB)
-
-        path = attention_path(cfg, T, Bs, use_flash, mesh)
-
-        def cached_attn(w):
-            if path != JNP_GATHER:
-                # paged flash kernel: K/V blocks streamed straight from
-                # pool[layer] through the tables — no slice of the
-                # pool, no gathered copy, no [T, S] score
-                # materialization, per-row causal block skipping.
-                # Covers prefill chunks AND decode/spec windows; under
-                # a tp-only mesh it runs shard-local per head via
-                # shard_map.
-                interp = pallas_attention.needs_interpret()
-                sc = (dict(k_scales=k_scales, v_scales=v_scales)
-                      if quant_kv else {})
-                if w:
-                    sc["window"] = w
-                sc["scale"] = scale_val
-                sc["softcap"] = cap or 0.0
-                sc["layer"] = layer
-                if mesh is None:
-                    paged_fn = (pallas_paged.paged_decode_attention
-                                if path == "pallas_paged_decode"
-                                else pallas_paged.paged_attention)
-                    return paged_fn(
-                        q, k_cache, v_cache, block_tables, starts,
-                        nb=nb, interpret=interp, **sc)
-                return pallas_paged.paged_attention_sharded(
-                    q, k_cache, v_cache, block_tables, starts, mesh,
-                    nb=nb, interpret=interp, **sc)
-            if quant_kv:
-                k_att = gather_view_q(k_cache, k_scales, block_tables,
-                                      nb, dtype=q.dtype, layer=layer)
-                v_att = gather_view_q(v_cache, v_scales, block_tables,
-                                      nb, dtype=q.dtype, layer=layer)
-            else:
-                k_att = gather_view(k_cache, block_tables, nb,
-                                    layer=layer)
-                v_att = gather_view(v_cache, block_tables, nb,
-                                    layer=layer)
-            return attention_with_cache(q, k_att, v_att, positions,
-                                        scale=scale_val,
-                                        sliding_window=w,
-                                        logit_softcap=cap)
-
+            kv = kv_pool.append(kv, k, v, block_tables, starts,
+                                token_valid, layer)
         with jax.named_scope("attention"):
-            attn = _windowed(cached_attn)
-        new_kv = ((k_cache, v_cache, k_scales, v_scales) if quant_kv
-                  else (k_cache, v_cache))
+            attn = _windowed(lambda w: kv_pool.attend(
+                q, kv, block_tables, starts, positions, kv_len, layer,
+                window=w, scale=scale_val, softcap=cap, mesh=mesh))
     with jax.named_scope("o_proj"):
         o_out = proj(attn.reshape(B, T, nh * hd), "o")
         if cfg.sandwich_norms:
@@ -374,7 +278,7 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                 mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"],
                                    cfg.rms_norm_eps, offset=offset)
             x = x + mlp_out
-    return x, new_kv
+    return x, kv
 
 
 def _gelu_tanh(x: jnp.ndarray) -> jnp.ndarray:
@@ -387,7 +291,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             block_tables: Optional[jnp.ndarray] = None,
             rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
             kv_len: Optional[int] = None,
-            use_flash: Optional[bool] = None,
             lora_params=None, adapter_ids: Optional[jnp.ndarray] = None,
             lora_scaling: float = 1.0,
             token_valid: Optional[jnp.ndarray] = None,
@@ -403,9 +306,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     offset through the tables.
     kv_len (static) bounds attention to the first ceil(kv_len/Bs)
     blocks — see _layer_body.
-    use_flash: None = auto (pallas flash prefill when the runtime gate is
-    on); pass False on sharded executables — pallas_call has no GSPMD
-    partitioning rule (see ops/pallas_attention.py).
     lora_params: layer-leading stacked adapters (models/lora.layer_slice)
     + adapter_ids [B] selecting each row's adapter (0 = base).
     token_valid [B,T] bool marks real (non-padding) tokens — their K/V
@@ -419,14 +319,11 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if rope is None:
         rope = rope_table(cfg.max_position_embeddings, cfg.head_dim_,
                           cfg.rope_theta, scaling=cfg.rope_scaling)
-    if use_flash is None:
-        use_flash = pallas_attention.flash_enabled()
     if block_tables is None:
-        from production_stack_tpu.models.kv import linear_tables
         B = tokens.shape[0]
         Bs = cache.block_size
         n_per = (cache.k.shape[1] - 1) // B
-        block_tables = linear_tables(B, n_per * Bs, Bs)
+        block_tables = kv_pool.linear_tables(B, n_per * Bs, Bs)
     starts = positions[:, 0]
     with jax.named_scope("embed"):
         x = _embed(params, cfg, tokens)
@@ -439,8 +336,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         h, pool = carry
         lp, layer, ll, local = xs
         return _layer_body(cfg, rope, positions, starts, h, lp, pool,
-                           kv_len=kv_len, use_flash=use_flash,
-                           lora_layer=ll, adapter_ids=adapter_ids,
+                           kv_len=kv_len, lora_layer=ll, adapter_ids=adapter_ids,
                            lora_scaling=lora_scaling,
                            token_valid=token_valid,
                            block_tables=block_tables, mesh=mesh,
@@ -464,7 +360,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
 def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
            rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-           attention_fn=None,
            token_valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Full-sequence causal forward WITHOUT the LM head: final-normed
     hidden states [B,T,H]. The embeddings/rerank/score endpoints pool
@@ -482,7 +377,6 @@ def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     def scan_body(carry, xs):
         lp, local = xs
         out, _ = _layer_body(cfg, rope, positions, None, carry, lp, None,
-                             attention_fn=attention_fn,
                              token_valid=token_valid,
                              layer_local=local)
         return out, None
@@ -497,15 +391,11 @@ def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
 def forward_train(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                   rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-                  attention_fn=None) -> jnp.ndarray:
-    """Full-sequence causal forward without cache. tokens [B,T] -> logits fp32.
-
-    attention_fn(q, k, v) -> out replaces dense causal attention when given
-    (e.g. ring attention over an 'sp'-sharded sequence).
-    """
-    return _lm_head(params, cfg,
-                    encode(params, cfg, tokens, rope=rope,
-                           attention_fn=attention_fn))
+                  ) -> jnp.ndarray:
+    """Full-sequence causal forward without cache. tokens [B,T] ->
+    logits fp32: the reference the cached ``forward`` is compared
+    against (no serving path calls it)."""
+    return _lm_head(params, cfg, encode(params, cfg, tokens, rope=rope))
 
 
 def _embed(params: Params, cfg: ModelConfig,

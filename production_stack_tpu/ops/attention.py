@@ -8,8 +8,9 @@ TPU design notes:
 - All shapes are static under jit: the serving path attends over the full
   preallocated cache [B, S, Hkv, D] with a position mask rather than
   dynamically slicing to the live length (dynamic shapes would defeat XLA
-  tiling). A Pallas flash/chunked variant lives in ops/pallas_attention.py
-  for long-context; these jnp versions are the reference semantics.
+  tiling). The serving path's Pallas kernels stream the paged pool
+  instead (ops/pallas_paged.py, which also decides who runs); these jnp
+  versions are the reference semantics.
 
 Reference behavior lives inside the external vLLM engine (reference repo
 ships no kernels; see SURVEY.md §2.9) — this module is new TPU-first work.
@@ -55,7 +56,8 @@ def causal_attention(
 ) -> jnp.ndarray:
     """Full-sequence causal GQA. q [B,T,H,D]; k,v [B,T,Hkv,D] -> [B,T,H,D].
 
-    Used by the training step and by single-shot (non-incremental) forward.
+    Used by the single-shot (non-incremental) forward: encode and the
+    no-cache reference forward.
     Optional segment_ids [B,T] confine attention within packed segments.
     sliding_window W (Mistral/Gemma-2 local layers) further confines a
     query at t to keys in (t - W, t]. logit_softcap applies Gemma-2's

@@ -2,10 +2,8 @@
 
 ONE kernel for both serving phases:
 
-- **prefill** chunks (T up to the chunk bucket) — replaces the
-  gather-view + flash path (ops/pallas_attention.py), deleting the
-  per-layer gathered K/V copy AND that kernel's head-major relayout
-  copy;
+- **prefill** chunks (T up to the chunk bucket) — no per-layer
+  gathered K/V copy and no head-major relayout copy of it;
 - **decode / speculative windows** (T = 1 or draft+1, inside the
   lax.scan of engine/runner.py) — replaces the gather-view + dense jnp
   path, which materialized a [B, kv, Hkv, D] copy of the live cache
@@ -43,6 +41,12 @@ tp; tables/starts replicate) — embarrassingly parallel, no collectives.
 Meshes that shard the pool's block axis (dp > 1) keep the jnp gather
 path, whose collectives XLA inserts.
 
+Which implementation a cached attention takes is decided HERE, by
+``attention_path``: it owns every fact the decision consults (the
+run-time gate, ``paged_viable``, ``mesh_tp_only``, ``DECODE_T_MAX``).
+models/kv.py asks it at trace time and engine/runner.py when it
+compiles an executable; nobody else reads those facts.
+
 The reference repo ships no kernels (attention lives in the external
 vLLM engine, SURVEY.md §2.9); this is TPU-first work. Numerics are
 pinned against the dense jnp path in tests/test_pallas_paged.py via
@@ -58,9 +62,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from production_stack_tpu.ops.pallas_attention import VMEM_LIMIT_BYTES
-
 _NEG_INF = -1e30
+
+# Per-kernel scoped-VMEM budget: XLA may place a chunk-sized kernel
+# OUTPUT on the scoped-VMEM stack (a batch-8 512-chunk bf16 output is
+# ~17 MB), and the default 16 MiB budget then fails the compile even
+# though the kernel's own working set is small. v5e/v5p cores carry
+# 128 MiB VMEM — raise the budget so chunk-sized outputs may live
+# on-chip; outputs too big for it simply land in HBM.
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+
+# runtime gate: PSTPU_FLASH=1/0 forces; "auto" (default) enables the
+# compiled kernels on TPU and leaves CPU/other backends on the jnp path
+# (interpret mode is for tests, far too slow for serving).
+_override = None
+
+
+def set_flash_enabled(value) -> None:
+    """Force-enable/disable (True/False) or restore auto (None): tests
+    run the kernels in interpret mode on the CPU through this."""
+    global _override
+    _override = value
+
+
+def flash_enabled() -> bool:
+    if _override is not None:
+        return _override
+    env = os.environ.get("PSTPU_FLASH", "auto").lower()
+    if env in ("1", "true", "on"):
+        return True
+    if env in ("0", "false", "off"):
+        return False
+    return jax.default_backend() == "tpu"
+
+
+def needs_interpret() -> bool:
+    """Interpret everywhere but real TPU (kernel targets TPU tiling)."""
+    return jax.default_backend() != "tpu"
+
+
+def mode() -> str:
+    """"compiled" | "interpret" | "off": how the Pallas attention
+    kernels run in this process, for logs and GET /debug/perf."""
+    if not flash_enabled():
+        return "off"
+    return "interpret" if needs_interpret() else "compiled"
+
 
 # VMEM ceiling for the per-grid-step working set (q + acc + scores,
 # fp32): conservative slice of the ~16 MB/core budget, leaving room
@@ -291,7 +338,6 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
-            # see pallas_attention.VMEM_LIMIT_BYTES for the rationale
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(starts, jnp.int32),
@@ -582,3 +628,33 @@ def mesh_tp_only(mesh) -> bool:
     configuration where the kernel can run shard-local per head."""
     return mesh is not None and all(
         size == 1 for name, size in mesh.shape.items() if name != "tp")
+
+
+# the gathered-copy jax.numpy attention (ops/attention.py): the test
+# reference, and the serving path only where attention_path says so
+JNP_GATHER = "jnp_gather"
+
+
+def attention_path(T: int, groups: int, head_dim: int, block_size: int,
+                   mesh=None) -> str:
+    """Which cached-attention implementation a forward over T query
+    positions per row takes, for ``groups`` query heads per kv head.
+    Decided here, by shape, BEFORE anything compiles — a kernel the
+    compiler then refuses is an error, not a reason to take another
+    path (engine/runner.py records this per executable; GET /debug/perf
+    shows it).
+
+    ``pallas_paged_decode``: short windows (decode / speculative
+    verify) on the wide kernel — all kv heads + several pool blocks per
+    grid step, ~16x fewer grid steps than the general one.
+    ``pallas_paged``: prefill chunks on the general paged kernel.
+    ``*_sharded``: either, shard-local per head under a tp-only mesh.
+    ``jnp_gather``: the kernel is off (PSTPU_FLASH / not a TPU), the
+    chunk's working set misses VMEM (paged_viable), or the mesh shards
+    the pool's block axis."""
+    if not (flash_enabled()
+            and paged_viable(T, groups, head_dim, block_size)
+            and (mesh is None or mesh_tp_only(mesh))):
+        return JNP_GATHER
+    kernel = "pallas_paged_decode" if T <= DECODE_T_MAX else "pallas_paged"
+    return kernel + ("_sharded" if mesh is not None else "")

@@ -1,5 +1,4 @@
 from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
-from production_stack_tpu.parallel.sharding import (data_sharding,
-                                                    param_shardings)
+from production_stack_tpu.parallel.sharding import param_shardings
 
-__all__ = ["MeshConfig", "build_mesh", "param_shardings", "data_sharding"]
+__all__ = ["MeshConfig", "build_mesh", "param_shardings"]

@@ -1,4 +1,4 @@
-"""Device-mesh construction for dp/sp/tp parallelism.
+"""Device-mesh construction for the serving meshes: dp / ep / tp.
 
 The TPU-native replacement for the reference's NCCL-implied distributed
 backend (reference: the /dev/shm mount for NCCL at
@@ -8,18 +8,14 @@ jax.sharding.Mesh over the slice's chips, with XLA inserting ICI
 collectives from sharding annotations — no process groups, no shm.
 
 Axes:
-  pp — pipeline parallel (layer stages, parallel/pipeline.py)
-  dp — data parallel (batch)
-  sp — sequence parallel (ring attention over sequence blocks)
+  dp — data parallel (the KV pool's block axis; forfeits the paged
+       attention kernel, engine/runner.py's dp cliff)
   ep — expert parallel (MoE expert weights, ops/moe.py)
   tp — tensor parallel (megatron column/row sharding of matmuls)
 
 tp stays innermost (ICI-nearest: its per-layer psums are the most
 latency-sensitive collectives); ep sits just above it so expert
-dispatch/combine also rides ICI before dp/sp cross slice boundaries.
-pp is outermost: stages exchange one activation per microbatch hop —
-the lowest-bandwidth axis, the natural one to place across DCN
-(multi-slice) while everything else stays within a slice.
+dispatch/combine also rides ICI.
 
 Multi-replica scaling above a slice stays at the stack level (router over
 engine replicas), exactly like the reference's L1/L3 split.
@@ -31,39 +27,29 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import Mesh
 
-AXES = ("pp", "dp", "sp", "ep", "tp")
+AXES = ("dp", "ep", "tp")
 
 
 @dataclasses.dataclass(frozen=True)
 class MeshConfig:
     dp: int = 1
-    sp: int = 1
     tp: int = 1
     ep: int = 1
-    pp: int = 1
 
     @property
     def size(self) -> int:
-        return self.dp * self.sp * self.tp * self.ep * self.pp
+        return self.dp * self.tp * self.ep
 
     @staticmethod
-    def for_devices(n: int, tp: Optional[int] = None,
-                    sp: Optional[int] = None) -> "MeshConfig":
-        """Factor n devices into (dp, sp, tp). Defaults favor a balanced
-        mesh that activates every axis when divisibility allows (8 chips
-        -> 2x2x2), with tp on the innermost (ICI-nearest) axis."""
+    def for_devices(n: int, tp: Optional[int] = None) -> "MeshConfig":
+        """Factor n devices into (dp, tp), tp on the innermost
+        (ICI-nearest) axis: tp = 2 where n is even unless given, dp
+        the rest."""
         if tp is None:
             tp = 2 if n % 2 == 0 else 1
         if n % tp:
             raise ValueError(f"tp={tp} does not divide {n} devices")
-        rest = n // tp
-        if sp is None:
-            sp = 2 if rest % 2 == 0 and rest >= 2 else 1
-        if rest % sp:
-            raise ValueError(f"sp={sp} does not divide {rest} devices")
-        cfg = MeshConfig(dp=rest // sp, sp=sp, tp=tp)
-        assert cfg.size == n
-        return cfg
+        return MeshConfig(dp=n // tp, tp=tp)
 
 
 def build_mesh(cfg: Optional[MeshConfig] = None,
@@ -74,6 +60,5 @@ def build_mesh(cfg: Optional[MeshConfig] = None,
         raise ValueError(
             f"mesh {cfg} needs {cfg.size} devices, have {len(devices)}")
     import numpy as np
-    dev_array = np.asarray(devices).reshape(cfg.pp, cfg.dp, cfg.sp,
-                                            cfg.ep, cfg.tp)
+    dev_array = np.asarray(devices).reshape(cfg.dp, cfg.ep, cfg.tp)
     return Mesh(dev_array, AXES)

@@ -91,12 +91,6 @@ def param_shardings(mesh: Mesh, params: Dict[str, Any]) -> Dict[str, Any]:
                         is_leaf=lambda x: isinstance(x, P))
 
 
-def data_sharding(mesh: Mesh, sequence_parallel: bool = False):
-    """Sharding for [B, T] token batches: batch over dp, optionally
-    sequence over sp (ring attention consumes the sp axis)."""
-    return NamedSharding(mesh, P("dp", "sp" if sequence_parallel else None))
-
-
 def cache_pspec() -> P:
     """KV pool [L, N, Hkv, Bs, D]: blocks over dp, kv heads over tp."""
     return P(None, "dp", "tp", None, None)

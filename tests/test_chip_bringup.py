@@ -188,15 +188,33 @@ def test_refused_compile_raises_naming_the_executable():
     assert built == [1] and not cache and not runner.attention_paths
 
 
-def test_attention_path_is_chosen_by_shape_and_recorded():
-    from production_stack_tpu.models import llama
-    from production_stack_tpu.models.config import get_config
-    cfg = get_config("mistral-7b")
-    assert llama.attention_path(cfg, 1, 64, True) == "pallas_paged_decode"
-    assert llama.attention_path(cfg, 512, 64, True) == "pallas_paged"
-    assert llama.attention_path(cfg, 512, 64, False) == llama.JNP_GATHER
+@pytest.mark.parametrize("T,gate,mesh_dims,want", [
+    (1, True, None, "pallas_paged_decode"),
+    (8, True, None, "pallas_paged_decode"),       # T = DECODE_T_MAX
+    (9, True, None, "pallas_paged"),              # DECODE_T_MAX + 1
+    (512, True, None, "pallas_paged"),
+    (512, False, None, "jnp_gather"),             # the gate off
     # a chunk whose working set misses VMEM (paged_viable)
-    assert llama.attention_path(cfg, 1 << 16, 64, True) == llama.JNP_GATHER
+    (1 << 16, True, None, "jnp_gather"),
+    (1, True, dict(dp=1, tp=2), "pallas_paged_decode_sharded"),
+    (1, True, dict(dp=2, tp=2), "jnp_gather"),    # the pool's blocks
+    #                                               sharded: the dp cliff
+], ids=["decode", "decode_t_max", "past_decode_t_max", "prefill_chunk",
+        "gate_off", "misses_vmem", "tp_only_mesh", "dp_mesh"])
+def test_attention_path_is_chosen_by_shape_and_recorded(
+        monkeypatch, T, gate, mesh_dims, want):
+    from production_stack_tpu.models.config import get_config
+    from production_stack_tpu.ops import pallas_paged
+    from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
+    assert pallas_paged.DECODE_T_MAX == 8
+    monkeypatch.setattr(pallas_paged, "_override", gate)
+    mesh = mesh_dims and build_mesh(
+        MeshConfig(**mesh_dims),
+        jax.devices()[:mesh_dims["dp"] * mesh_dims["tp"]])
+    cfg = get_config("mistral-7b")
+    assert pallas_paged.attention_path(
+        T, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_, 64,
+        mesh) == want
 
 
 # ---------------------------------------------------------------------

@@ -86,10 +86,10 @@ def _placements(topo, tp: int, layers: int = 0):
 def _compile_attention(topo, *, B, T, H, Hkv, int8, window=0, tp=1,
                        layers=0):
     """Lower + compile the attention call the serving path makes for
-    this shape (llama.attention_path's choice of kernel) and return the
-    compiled executable. layers: 0 = one bare layer of the pool
+    this shape (pallas_paged.attention_path's choice of kernel) and
+    return the compiled executable. layers: 0 = one bare layer of the pool
     [N, Hkv, Bs, D]; n = the whole pool [n, N, Hkv, Bs, D] and the
-    layer index as an operand, as llama._layer_body calls it."""
+    layer index as an operand, as models/kv.attend calls it."""
     mesh, (q_sh, kv_sh, rep_sh, sc_sh) = _placements(topo, tp, layers)
     n_blocks = B * MB + 1
     lead = (layers,) * bool(layers)
@@ -179,10 +179,8 @@ def tpu_branches(monkeypatch):
     """The serving path asks ``jax.default_backend()`` which attention
     to take and whether to interpret the kernel, and here that answers
     "cpu": steer both gates to their TPU answers for the compile."""
-    from production_stack_tpu.ops import pallas_attention
-    monkeypatch.setattr(pallas_attention, "_override", True)
-    monkeypatch.setattr(pallas_attention, "needs_interpret",
-                        lambda: False)
+    monkeypatch.setattr(pallas_paged, "_override", True)
+    monkeypatch.setattr(pallas_paged, "needs_interpret", lambda: False)
 
 
 def _runner_shapes(topo, tp: int, layers=None, kv_blocks=None):

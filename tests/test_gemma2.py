@@ -199,5 +199,5 @@ def test_gemma2_tp_sharded_parity():
             assert guard < 500
         return [eng.seqs[s].output_tokens for s in sids]
 
-    mesh = build_mesh(MeshConfig(dp=1, sp=1, tp=2), jax.devices()[:2])
+    mesh = build_mesh(MeshConfig(dp=1, tp=2), jax.devices()[:2])
     assert run(mesh) == run(None)

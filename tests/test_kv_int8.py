@@ -125,7 +125,7 @@ def test_layer_scan_carries_the_pool(dtype):
         cfg.num_layers, 9, 8, cfg.num_kv_heads, cfg.head_dim_, dtype))
     tokens = jax.ShapeDtypeStruct((2, 4), jnp.int32)
     jaxpr = jax.make_jaxpr(
-        lambda p, t, c: llama.forward(p, cfg, t, t, c, use_flash=False)
+        lambda p, t, c: llama.forward(p, cfg, t, t, c)
     )(params, tokens, cache)
 
     pools = {a.shape for a in cache if a is not None}
@@ -174,8 +174,7 @@ def test_forward_logits_close_to_bf16_cache():
         cache = make_cache(cfg.num_layers, n_blocks, Bs,
                            cfg.num_kv_heads, cfg.head_dim_, dtype=dtype)
         logits, _ = llama.forward(params, cfg, tokens, positions, cache,
-                                  block_tables=tables, kv_len=32,
-                                  use_flash=False)
+                                  block_tables=tables, kv_len=32)
         return np.asarray(logits, np.float32)
 
     ref = run(jnp.float32)
@@ -343,10 +342,10 @@ def test_engine_int8_kv_with_flash_kernel():
     from production_stack_tpu.engine.config import EngineConfig
     from production_stack_tpu.engine.engine import LLMEngine
     from production_stack_tpu.engine.scheduler import SamplingOptions
-    from production_stack_tpu.ops import pallas_attention
+    from production_stack_tpu.ops import pallas_paged
 
     def run(force_flash):
-        pallas_attention.set_flash_enabled(force_flash)
+        pallas_paged.set_flash_enabled(force_flash)
         try:
             cfg = EngineConfig(model="debug-tiny", max_model_len=128,
                                max_num_seqs=2, prefill_chunk=32,
@@ -357,7 +356,7 @@ def test_engine_int8_kv_with_flash_kernel():
             return [eng.generate(p, opts)
                     for p in ("int8 kernel probe", "second row")]
         finally:
-            pallas_attention.set_flash_enabled(None)
+            pallas_paged.set_flash_enabled(None)
 
     assert run(True) == run(False)
 

@@ -194,7 +194,7 @@ def test_aggregate_and_bench_schema():
     assert agg["aborted_injected"] == 1
     assert agg["total_output_tokens"] == 100
     assert agg["ttft_s"]["p99"] == pytest.approx(0.1)
-    # BENCH_*.json record shape (bench.py): metric/value/unit/platform/detail
+    # BENCH_*.json record shape: metric/value/unit/platform/detail
     b = report.bench_schema("loadgen test", agg, platform="cpu",
                             detail={"workload": "chat"})
     assert set(b) >= {"metric", "value", "unit", "platform", "detail"}

@@ -292,10 +292,14 @@ def test_effwatch_ab_smoke_fake_engine(tmp_path):
     record = asyncio.run(run_effwatch_ab(
         engine="fake", users=3, duration_s=4.0, warmup_s=1.5,
         num_tokens=8, fake_pad_fraction=0.08, fake_dead_fraction=0.05,
-        fake_tokens_per_s=280.0,
+        # pacing of 10 ms against 20 ms a token: the improvement gate
+        # compares two wall-clock rates, and a gap of 1.4 ms a token
+        # (280 against 200 tokens/s) was less than what a loaded host
+        # adds to every streamed token, on both sides alike
+        fake_tokens_per_s=100.0,
         fake_control_pad_fraction=0.40,
         fake_control_dead_fraction=0.10,
-        fake_control_tokens_per_s=200.0,
+        fake_control_tokens_per_s=50.0,
         log_dir=str(tmp_path / "logs")))
     violations = effwatch_ab_violations(record, live_floor=0.80,
                                         improve_floor=0.15)

@@ -182,7 +182,7 @@ def test_ep_sharded_forward_matches_single_device():
     """ep=4 x tp=2 mesh: expert weights shard over ep, logits must match
     the unsharded forward exactly (no drops at these sizes: N=32 tokens
     stay on the exact all-expert path)."""
-    mesh = build_mesh(MeshConfig(dp=1, sp=1, ep=4, tp=2))
+    mesh = build_mesh(MeshConfig(dp=1, ep=4, tp=2))
     params = llama.init_params(MOE_CFG, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0,
                               MOE_CFG.vocab_size)

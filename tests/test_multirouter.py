@@ -686,11 +686,23 @@ def test_multirouter_smoke_fake_engines(tmp_path):
     # carry connection-setup warmup and the suite runs it on a loaded
     # host — the smoke pins the MECHANICS, MULTIROUTER_r16.json pins
     # the numbers
-    violations = multirouter_violations(record, min_tier0_hold=0.8,
+    # tier0's hold is asserted below on what the routers admitted and
+    # shed, not as the ratio of two goodputs (min_tier0_hold): that
+    # ratio compares a 2.5 s window with a 4.5 s one, in the second of
+    # which the tier2 clients share this host's cores with the routers,
+    # and read 0.57-0.99 on one tree as the host's load varied
+    violations = multirouter_violations(record, min_tier0_hold=0.0,
                                         affinity_tolerance=0.08,
                                         convergence_bound_s=1.5)
     assert not violations, violations
     d = record["detail"]
+    pre0 = d["saturation"]["presat"]["tier0"]
+    sat0 = d["saturation"]["saturated"]["tier0"]
+    assert pre0["ok"] > 0 and pre0["shed"] == 0, pre0
+    # saturated: tier2 sheds at least half (a violation above if not),
+    # tier0 is admitted all but never (it sheds only where nothing is
+    # left to preempt)
+    assert sat0["ok"] > 0 and sat0["shed_fraction"] <= 0.1, sat0
     assert d["router_kill"]["kill_fired"]
     assert d["router_kill"]["post_restart_ok"] > 0
 

@@ -1,8 +1,8 @@
 """Parallelism tests on the 8-device virtual CPU mesh.
 
-Exercises exactly the sharding/collective paths a v5e-8 slice would run:
-tp param sharding, dp/sp batch sharding, ring attention vs the reference
-dense attention, and the full sharded training step.
+Exercises exactly the sharding/collective paths a v5e-8 slice would
+serve on: tp param sharding, the tp serving engine against the
+unsharded one, and the dp cliff.
 """
 
 import numpy as np
@@ -13,10 +13,7 @@ import jax.numpy as jnp
 
 from production_stack_tpu.models import ModelConfig, llama
 from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
-from production_stack_tpu.parallel.ring_attention import ring_causal_attention
 from production_stack_tpu.parallel.sharding import shard_params
-from production_stack_tpu.parallel.train import jit_train_step
-from production_stack_tpu.ops.attention import causal_attention
 
 
 CFG = ModelConfig(name="t", vocab_size=128, hidden_size=64,
@@ -26,15 +23,19 @@ CFG = ModelConfig(name="t", vocab_size=128, hidden_size=64,
 
 
 def test_mesh_factoring():
-    assert MeshConfig.for_devices(8) == MeshConfig(dp=2, sp=2, tp=2)
-    assert MeshConfig.for_devices(8, tp=4) == MeshConfig(dp=1, sp=2, tp=4)
-    assert MeshConfig.for_devices(1) == MeshConfig(dp=1, sp=1, tp=1)
+    assert MeshConfig.for_devices(8) == MeshConfig(dp=4, tp=2)
+    assert MeshConfig.for_devices(8, tp=4) == MeshConfig(dp=2, tp=4)
+    assert MeshConfig.for_devices(1) == MeshConfig(dp=1, tp=1)
+    with pytest.raises(ValueError, match="does not divide"):
+        MeshConfig.for_devices(8, tp=3)
     with pytest.raises(ValueError):
-        build_mesh(MeshConfig(dp=3, sp=1, tp=1))
+        build_mesh(MeshConfig(dp=3, tp=1))
+    assert build_mesh(MeshConfig(dp=2, ep=2, tp=2)).shape == {
+        "dp": 2, "ep": 2, "tp": 2}
 
 
 def test_tp_sharded_forward_matches_single_device():
-    mesh = build_mesh(MeshConfig(dp=1, sp=1, tp=8))
+    mesh = build_mesh(MeshConfig(dp=1, tp=8))
     key = jax.random.PRNGKey(0)
     params = llama.init_params(CFG, key)
     toks = jax.random.randint(key, (2, 16), 0, CFG.vocab_size)
@@ -44,54 +45,6 @@ def test_tp_sharded_forward_matches_single_device():
     got = jax.jit(lambda p, t: llama.forward_train(p, CFG, t))(sharded, toks)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                atol=2e-4, rtol=2e-4)
-
-
-def test_ring_attention_matches_dense():
-    mesh = build_mesh(MeshConfig(dp=1, sp=8, tp=1))
-    key = jax.random.PRNGKey(1)
-    B, T, H, Hkv, D = 2, 64, 4, 2, 16
-    q = jax.random.normal(key, (B, T, H, D), jnp.float32)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (B, T, Hkv, D))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (B, T, Hkv, D))
-
-    dense = causal_attention(q, k, v)
-    ring = ring_causal_attention(q, k, v, mesh)
-    np.testing.assert_allclose(np.asarray(ring), np.asarray(dense),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_sharded_train_step_runs_and_learns():
-    mesh = build_mesh(MeshConfig(dp=2, sp=2, tp=2))
-    params = llama.init_params(CFG, jax.random.PRNGKey(0))
-    state, step_fn = jit_train_step(mesh, CFG, params)
-    toks = jax.random.randint(jax.random.PRNGKey(3), (4, 32), 0,
-                              CFG.vocab_size)
-    losses = []
-    for _ in range(5):
-        state, loss = step_fn(state, toks)
-        losses.append(float(loss))
-    assert np.isfinite(losses).all()
-    assert losses[-1] < losses[0], f"loss did not decrease: {losses}"
-
-
-def test_sp_train_step_matches_dp_loss():
-    """First-step loss must be identical whether the sequence is sharded
-    (ring attention) or not — same math, different layout."""
-    toks = jax.random.randint(jax.random.PRNGKey(3), (4, 64), 0,
-                              CFG.vocab_size)
-
-    # params are consumed by jit_train_step (donation/aliasing) — build
-    # a fresh pytree per mesh
-    mesh_dp = build_mesh(MeshConfig(dp=4, sp=1, tp=2))
-    state, step = jit_train_step(
-        mesh_dp, CFG, llama.init_params(CFG, jax.random.PRNGKey(0)))
-    _, loss_dp = step(state, toks)
-
-    mesh_sp = build_mesh(MeshConfig(dp=1, sp=4, tp=2))
-    state, step = jit_train_step(
-        mesh_sp, CFG, llama.init_params(CFG, jax.random.PRNGKey(0)))
-    _, loss_sp = step(state, toks)
-    assert abs(float(loss_dp) - float(loss_sp)) < 1e-4
 
 
 def test_tp_serving_engine_matches_unsharded():
@@ -128,13 +81,13 @@ def test_dp_mesh_gather_cliff_is_explicit():
     the cliff is forced visible here via the explicit override.)"""
     from production_stack_tpu.engine.config import EngineConfig
     from production_stack_tpu.engine.engine import LLMEngine
-    from production_stack_tpu.ops import pallas_attention
+    from production_stack_tpu.ops import pallas_paged
 
     import jax
-    mesh = build_mesh(MeshConfig(dp=2, sp=1, tp=2), jax.devices()[:4])
+    mesh = build_mesh(MeshConfig(dp=2, tp=2), jax.devices()[:4])
     cfg = dict(model="debug-tiny", max_model_len=128, max_num_seqs=4,
                prefill_chunk=32, prefill_buckets=(32,))
-    pallas_attention.set_flash_enabled(True)
+    pallas_paged.set_flash_enabled(True)
     try:
         with pytest.raises(ValueError, match="gathered-view"):
             LLMEngine(EngineConfig(**cfg), mesh=mesh)
@@ -143,8 +96,8 @@ def test_dp_mesh_gather_cliff_is_explicit():
                         mesh=mesh)
         assert eng is not None
         # tp-only meshes never trip the guard
-        tp_mesh = build_mesh(MeshConfig(dp=1, sp=1, tp=2),
+        tp_mesh = build_mesh(MeshConfig(dp=1, tp=2),
                              jax.devices()[:2])
         LLMEngine(EngineConfig(**cfg), mesh=tp_mesh)
     finally:
-        pallas_attention.set_flash_enabled(None)
+        pallas_paged.set_flash_enabled(None)

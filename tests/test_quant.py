@@ -94,7 +94,7 @@ def test_quantized_moe_forward_runs():
 
 
 def test_quantized_tp_sharded_matches_single_device():
-    mesh = build_mesh(MeshConfig(dp=1, sp=1, tp=8))
+    mesh = build_mesh(MeshConfig(dp=1, tp=8))
     params = quant.quantize_params(llama.init_params(CFG,
                                                      jax.random.PRNGKey(0)))
     toks = jax.random.randint(jax.random.PRNGKey(5), (2, 16), 0,
