@@ -1,25 +1,30 @@
 """Paged flash attention for TPU: block-table-aware online-softmax GQA.
 
-ONE kernel for both serving phases:
+Two kernels, one contract:
 
-- **prefill** chunks (T up to the chunk bucket) — no per-layer
-  gathered K/V copy and no head-major relayout copy of it;
-- **decode / speculative windows** (T = 1 or draft+1, inside the
-  lax.scan of engine/runner.py) — replaces the gather-view + dense jnp
-  path, which materialized a [B, kv, Hkv, D] copy of the live cache
-  per layer per step: ~3x the minimal KV HBM traffic, the dominant
-  cost of long-context decode.
+- ``paged_attention``, **prefill** chunks (T up to the chunk bucket) —
+  no per-layer gathered K/V copy and no head-major relayout copy of it;
+- ``paged_decode_attention``, **decode / speculative windows** (T = 1
+  or draft+1 <= DECODE_T_MAX, inside the lax.scan of engine/runner.py)
+  — replaces the gather-view + dense jnp path, which materialized a
+  [B, kv, Hkv, D] copy of the live cache per layer per step: ~3x the
+  minimal KV HBM traffic, the dominant cost of long-context decode.
+  Its account stands above it, further down.
 
 K/V pool blocks ``[N, Hkv, Bs, D]`` (models/kv.py, head-major: the
 per-(block, head) panel is a contiguous [Bs, D] tile) are streamed
-straight from HBM through *scalar-prefetched* block tables: the grid's
-innermost dimension walks a row's blocks, the BlockSpec index map reads
-``tables[b, j]`` to point the next DMA at the right block, and each KV
-byte a row needs is read exactly once. Per-row causal skipping falls
-out of the index map: blocks past a row's last query position clamp to
-an already-resident index (Pallas elides the re-fetch) and their grid
-steps are `pl.when`-masked away, so decode cost scales with each row's
-LIVE prefix, not the kv bucket.
+straight from HBM through *scalar-prefetched* block tables, and each
+KV byte a row needs is read exactly once. In the prefill kernel the
+grid's innermost dimension walks a row's blocks and the BlockSpec
+index map reads ``tables[b, j]`` to point the next DMA at the right
+block; blocks past a row's last query position clamp to the last live
+one and their grid steps are `pl.when`-masked away. Pallas' pipeline
+skips a copy only when an operand's block index is the one it had the
+grid step before: true of ONE operand that walks the blocks one a
+step, as here; not of R operands that take R blocks a step, where
+each clamped operand has a buffer of its own and fetches the clamped
+block again. That is why the decode kernel issues its own copies, for
+live blocks alone.
 
 The serving path hands in the WHOLE pool ``[L, N, Hkv, Bs, D]`` and a
 ``layer`` index, a third scalar-prefetched operand that the index maps
@@ -28,7 +33,7 @@ sliced out of the buffer the step program carries (models/kv.py
 "carried, never stacked"). A bare 4-D layer is the same call on a pool
 of one layer.
 
-Grid ``(B, Hkv, NQ, nb)``; per step the q block [BQ, G, D] for one kv
+Prefill grid ``(B, Hkv, NQ, nb)``; per step the q block [BQ, G, D] for one kv
 head and one pool block's [Bs, D] K and V panels live in VMEM. Online
 (max, sum, acc) statistics persist in VMEM scratch across the
 ``nb``-axis (sequential "arbitrary" dimension), initialized at j == 0
@@ -55,7 +60,6 @@ interpret mode on CPU.
 
 import functools
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -347,145 +351,237 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
 
 
 # ---------------------------------------------------------------------
-# decode-specialized kernel: all kv heads + several pool blocks per
-# grid step.
+# decode-specialized kernel: one grid step per batch row, which walks
+# the row's LIVE blocks in chunks of R and copies them in itself.
 #
-# The general kernel's grid is (B, Hkv, NQ, nb) with ONE 64-token block
-# per step — for decode (T = 1) each step is a [G, D] x [D, Bs] dot,
-# so small that fixed per-grid-step cost (DMA issue, program dispatch)
-# dominates: at batch 32, kv 768, 22 layers that is ~34k grid steps per
-# decode step and the measured device time is ~3x the HBM floor. Here
-# the grid is (B, ceil(nb / R)): each step fetches one [Hkv, Bs, D]
-# K and V panel per sub-block (all kv heads ride one DMA — they are
-# contiguous in the pool's [N, Hkv, Bs, D] layout) and statically
-# unrolls Hkv x R small dots, cutting grid steps by Hkv*R (16x for
-# TinyLlama geometry) while reading exactly the same KV bytes.
+# Grid ``(B,)``. The K and V pools stay in HBM; q, the output (and the
+# int8 pool's scales, below) are BlockSpec operands. A grid step reads
+# the row's ``start`` and table row from SMEM, reckons the live block
+# range [lo, hi] (causal above, the sliding window below) and loops
+# over the chunks lo // R .. hi // R, a dynamic trip count; chunk g
+# holds blocks g*R .. g*R + R - 1. Per chunk it
+#   - starts one DMA per live block and pool: a block's [Hkv, Bs, D]
+#     panel is contiguous in the [L, N, Hkv, Bs, D] pool, so all kv
+#     heads ride one descriptor. The copies land in one of two VMEM
+#     slots [R, Hkv, Bs, D]; the next chunk (or the next row's first
+#     chunk) is started before the current one is waited for, so the
+#     copies run under the arithmetic;
+#   - per kv head, multiplies q [T*G, D] with the chunk's K panel
+#     [R*Bs, D] as both lie in memory (bf16 x bf16, or the int8 pool
+#     converted to q's dtype, which is exact) into float32 scores, and
+#     stores them as rows h*T*G.. of ONE [Hkv*T*G, R*Bs] panel;
+#   - scales, (soft-caps,) masks and updates the online-softmax
+#     statistics of every head in one pass over that panel: one max,
+#     one exp, one sum and one correction per chunk, on full vector
+#     registers, float32;
+#   - per kv head, multiplies the probabilities, cast to the dots'
+#     operand dtype as ops/attention.py does, with the V panel
+#     [R*Bs, D] into the float32 accumulator.
+#
+# What costs nothing: a block past hi or before lo is never copied,
+# and is computed only as masked columns of a row's ragged first or
+# last chunk (the V slots are zeroed once per call, so a masked
+# probability never meets an uninitialized V). A parked row
+# (start >= MB*Bs) has no chunk: no copy, no arithmetic, zeros out.
+#
+# The int8 pool's per-token scales multiply the score columns and the
+# probabilities ([T*G, R*Bs]), never a [Bs, D] panel. The TPU compiler
+# cannot slice a float32 HBM array whose last dimension is under 128
+# for a DMA, so the wrapper gathers the rows' scales through the
+# tables into [B, chunks, Hkv, R*Bs] (2 KB a block beside the block's
+# 64 KB), and they ride in as one BlockSpec block per row.
+#
+# R (``decode_blocks_per_step``) follows from the shapes: as many
+# blocks as bring _DECODE_CHUNK_BYTES of K and V, so that a chunk's
+# copies outlast what starting and waiting for them costs, within
+# _DECODE_PANEL_TOKENS score columns and the row's nb blocks.
 # ---------------------------------------------------------------------
 
 # decode/spec windows have T <= spec+1 << this; prefill chunks go to
 # the general kernel
 DECODE_T_MAX = 8
-# KV pool blocks fetched+processed per decode-kernel grid step. More
-# blocks per step = fewer grid steps (less per-step overhead) but a
-# bigger VMEM working set (R panels of [Hkv, Bs, D] K and V each).
-# Env-tunable for hardware sweeps: PSTPU_DECODE_BLOCKS_PER_STEP.
+# K and V bytes a chunk aims to bring: on the v5e, chunks of 1 and 2 MiB
+# ran a call within 4 % of each other and 0.5 MiB 16-26 % slower
+# (PERF.md, PR 30); two slots of it lie in VMEM
+_DECODE_CHUNK_BYTES = _VMEM_WORK_BYTES // 4
+# and the widest score panel a chunk computes, in tokens (columns):
+# wider was not measured
+_DECODE_PANEL_TOKENS = 512
 
 
-def _env_blocks_per_step(default: int = 4) -> int:
-    """Validated at import: a malformed or non-positive value must not
-    crash module import or reach the decode-kernel grid math — warn and
-    serve on the default instead."""
-    raw = os.environ.get("PSTPU_DECODE_BLOCKS_PER_STEP")
-    if raw is None:
-        return default
-    try:
-        value = int(raw)
-    except (TypeError, ValueError):
-        warnings.warn(
-            f"PSTPU_DECODE_BLOCKS_PER_STEP={raw!r} is not an integer; "
-            f"falling back to {default}", RuntimeWarning)
-        return default
-    if value < 1:
-        warnings.warn(
-            f"PSTPU_DECODE_BLOCKS_PER_STEP={value} must be >= 1; "
-            f"falling back to {default}", RuntimeWarning)
-        return default
-    return value
+def decode_blocks_per_step(nb: int, heads_kv: int, block_size: int,
+                           head_dim: int, kv_itemsize: int) -> int:
+    """R, the pool blocks one chunk of the decode kernel takes: a
+    function of the trace-time shapes alone (the kv bucket, the kv
+    heads a block holds, its tokens and head dim, the pool's dtype)."""
+    block_bytes = 2 * heads_kv * block_size * head_dim * kv_itemsize
+    return max(1, min(_DECODE_CHUNK_BYTES // block_bytes,
+                      _DECODE_PANEL_TOKENS // block_size, nb))
 
 
-_BLOCKS_PER_STEP = _env_blocks_per_step()
-
-
-def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, *refs,
-                         T: int,
-                         heads_kv: int, groups: int, block_size: int,
-                         ngrp: int, R: int, scale: float,
+def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
+                         v_hbm, *refs,
+                         T: int, heads_kv: int, groups: int,
+                         block_size: int, nb: int, R: int, scale: float,
                          quant: bool = False, window: int = 0,
                          softcap: float = 0.0):
-    """One (batch row, block group) grid step.
+    """One batch row: every live block of it, R at a time.
 
     tabs_ref   (SMEM) [B, MB]     block tables
     starts_ref (SMEM) [B]         absolute position of q[:, 0]
-    layer_ref  (SMEM) [1]         the pool's layer (index maps only)
+    layer_ref  (SMEM) [1]         the pool's layer
     q_ref   [1, Hkv, T*G, D]      all heads' queries (rows = t*G + g)
-    refs    R k panels [1, 1, Hkv, Bs, D], R v panels, (quant only:
-            R ks + R vs dequant scales [1, 1, Hkv, Bs] fp32,) out
-            [1, Hkv, T*G, D], scratch m/l [Hkv*T*G, 1], acc
-            [Hkv*T*G, D] — online softmax state across the group axis.
+    k_hbm, v_hbm (HBM) [L, N, Hkv, Bs, D]    the pools
+    refs    (quant only: the row's k and v scales
+            [1, chunks, Hkv, R*Bs] fp32,) out [1, Hkv, T*G, D];
+            scratch: the K and V slots [2, R, Hkv, Bs, D], DMA
+            semaphores [2 slots, K and V], the score/probability panel
+            [Hkv*T*G, R*Bs], m/l [Hkv*T*G, 1], acc and the chunk's p.V
+            [Hkv*T*G, D], all fp32, and (SMEM) [2]: the slot the row's
+            first chunk lies in and whether the row before already
+            started its copies.
     """
-    k_refs = refs[:R]
-    v_refs = refs[R:2 * R]
-    refs = refs[2 * R:]
     if quant:
-        ks_refs = refs[:R]
-        vs_refs = refs[R:2 * R]
-        refs = refs[2 * R:]
-    out_ref, m_ref, l_ref, acc_ref = refs
+        ks_ref, vs_ref = refs[:2]
+        refs = refs[2:]
+    (out_ref, k_buf, v_buf, sems, s_ref, m_ref, l_ref, acc_ref, pv_ref,
+     state_ref) = refs
     b = pl.program_id(0)
-    jg = pl.program_id(1)
+    B, MB = tabs_ref.shape
     rows = T * groups
-    D = q_ref.shape[-1]
+    Bs, D = block_size, q_ref.shape[-1]
+    cols = R * Bs
+    layer = layer_ref[0]
+    cdt = q_ref.dtype if quant else k_buf.dtype     # the dots' operands
 
-    @pl.when(jg == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def live_range(row):
+        """(first live block, last, first chunk, chunks) of a row; no
+        chunk where it is parked or its window lies past the kv
+        bucket."""
+        start = starts_ref[row]
+        hi = jnp.minimum(jax.lax.div(start + (T - 1), Bs), nb - 1)
+        lo = (jax.lax.div(jnp.maximum(start - (window - 1), 0), Bs)
+              if window else 0)
+        g0 = jax.lax.div(lo, R)
+        nch = jnp.where((start >= MB * Bs) | (lo > hi), 0,
+                        jax.lax.div(hi, R) - g0 + 1)
+        return lo, hi, g0, nch
 
-    start = starts_ref[b]
-    jmax = jax.lax.div(start + (T - 1), block_size)
-    # sliding window: whole groups before the earliest query's window
-    # are skipped (window == 0 means full causal)
-    jmin = (jax.lax.div(jnp.maximum(start - (window - 1), 0), block_size)
-            if window else 0)
+    def copies(row, lo, hi, g, slot, wait=False):
+        """Start (or wait for) the copy of every live block of the
+        row's chunk g, from both pools into ``slot``."""
+        for i in range(R):
+            j = g * R + i
 
-    @pl.when((jg * R <= jmax) & (jg * R + (R - 1) >= jmin))
-    def _compute():
-        # row r (within a head) queries position start + r // G
-        row_pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, 1), 0) // groups
+            @pl.when((j >= lo) & (j <= hi))
+            def _():
+                blk = tabs_ref[row, j]
+                for o, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                    cp = pltpu.make_async_copy(
+                        hbm.at[layer, blk], buf.at[slot, i],
+                        sems.at[slot, o])
+                    cp.wait() if wait else cp.start()
+
+    @pl.when(b == 0)
+    def _first_row():
+        state_ref[0] = 0
+        state_ref[1] = 0
+        # a dead block's columns are masked to probability 0, which
+        # must not meet whatever the V slots held before the call
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    lo, hi, g0, nch = live_range(b)
+    nxt = jnp.minimum(b + 1, B - 1)
+    nlo, nhi, ng0, nnch = live_range(nxt)
+    nnch = jnp.where(b + 1 < B, nnch, 0)
+    slot0 = state_ref[0]
+
+    @pl.when((nch > 0) & (state_ref[1] == 0))
+    def _start_first():
+        copies(b, lo, hi, g0, slot0)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    # row r (within a head) queries position start + r // G
+    row_pos = starts_ref[b] + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (heads_kv * rows, 1), 0),
+        rows) // groups
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+
+    def chunk(c, carry):
+        g = g0 + c
+        slot = jax.lax.rem(slot0 + c, 2)
+
+        @pl.when(c + 1 < nch)
+        def _next_chunk():
+            copies(b, lo, hi, g + 1, 1 - slot)
+
+        @pl.when((c + 1 == nch) & (nnch > 0))
+        def _next_row():
+            copies(nxt, nlo, nhi, ng0, 1 - slot)
+
+        copies(b, lo, hi, g, slot, wait=True)
+
         for h in range(heads_kv):
-            q = q_ref[0, h].astype(jnp.float32) * scale      # [rows, D]
-            sl = slice(h * rows, (h + 1) * rows)
-            m_prev = m_ref[sl]
-            l_prev = l_ref[sl]
-            acc_prev = acc_ref[sl]
-            for i in range(R):
-                j = jg * R + i
-                k_blk = k_refs[i][0, 0, h].astype(jnp.float32)  # [Bs, D]
-                v_blk = v_refs[i][0, 0, h].astype(jnp.float32)
-                if quant:
-                    k_blk = k_blk * ks_refs[i][0, 0, h][:, None]
-                    v_blk = v_blk * vs_refs[i][0, 0, h][:, None]
-                s = jax.lax.dot_general(
-                    q, k_blk, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)      # [rows, Bs]
-                if softcap:
-                    s = softcap * jnp.tanh(s / softcap)
-                k_pos = j * block_size + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, block_size), 1)
-                live = (k_pos <= row_pos) & (j <= jmax)
-                if window:
-                    live = live & (k_pos > row_pos - window)
-                s = jnp.where(live, s, _NEG_INF)
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1,
-                                                    keepdims=True))
-                p = jnp.exp(s - m_new)
-                corr = jnp.exp(m_prev - m_new)
-                l_prev = l_prev * corr + jnp.sum(p, axis=-1,
+            k = k_buf[slot, :, h].reshape(cols, D).astype(cdt)
+            s = jax.lax.dot_general(
+                q_ref[0, h].astype(cdt), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [rows, cols]
+            if quant:
+                s = s * ks_ref[0, g, h:h + 1, :]
+            s_ref[h * rows:(h + 1) * rows, :] = s
+        s = s_ref[...] * scale                       # [Hkv*rows, cols]
+        if softcap:
+            s = softcap * jnp.tanh(s / softcap)
+        k_pos = g * cols + col
+        live = (k_pos <= row_pos) & (k_pos < (hi + 1) * Bs)
+        if window:
+            live = live & (k_pos > row_pos - window)
+        s = jnp.where(live, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1,
                                                  keepdims=True)
-                acc_prev = acc_prev * corr + jax.lax.dot_general(
-                    p, v_blk, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)      # [rows, D]
-                m_prev = m_new
-            m_ref[sl] = m_prev
-            l_ref[sl] = l_prev
-            acc_ref[sl] = acc_prev
+        s_ref[...] = p
+        for h in range(heads_kv):
+            p_h = s_ref[h * rows:(h + 1) * rows, :]
+            if quant:
+                p_h = p_h * vs_ref[0, g, h:h + 1, :]
+            v = v_buf[slot, :, h].reshape(cols, D).astype(cdt)
+            pv_ref[h * rows:(h + 1) * rows, :] = jax.lax.dot_general(
+                p_h.astype(cdt), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [rows, D]
+        acc_ref[...] = acc_ref[...] * corr + pv_ref[...]
+        return carry
 
-    @pl.when(jg == ngrp - 1)
-    def _emit():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        out_ref[0] = out.reshape(heads_kv, rows, D).astype(out_ref.dtype)
+    jax.lax.fori_loop(0, nch, chunk, 0)
+
+    @pl.when(nch > 0)
+    def _hand_over():
+        state_ref[0] = jax.lax.rem(slot0 + nch, 2)
+        state_ref[1] = (nnch > 0).astype(jnp.int32)
+
+    # a parked row has l == 0 and acc == 0: finite zeros
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    out_ref[0] = out.reshape(heads_kv, rows, D).astype(out_ref.dtype)
+
+
+def _chunked_scales(scales, layer, tables, ngrp: int, R: int):
+    """The rows' per-token scales, gathered through the tables:
+    [L, N, Hkv, Bs] -> [B, ngrp, Hkv, R*Bs], chunk g holding blocks
+    g*R .. g*R + R - 1 of each row side by side as the kernel's score
+    columns lie."""
+    B, MB = tables.shape
+    _, _, Hkv, Bs = scales.shape
+    blocks = tables[:, jnp.minimum(jnp.arange(ngrp * R), MB - 1)]
+    got = scales[layer[0], blocks]               # [B, ngrp*R, Hkv, Bs]
+    got = got.reshape(B, ngrp, R, Hkv, Bs).transpose(0, 1, 3, 2, 4)
+    return got.reshape(B, ngrp, Hkv, R * Bs)
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "interpret",
@@ -498,88 +594,76 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
                            scale: float = None, softcap: float = 0.0,
                            layer=None):
     """paged_attention specialized for short query windows (T <=
-    DECODE_T_MAX): same contract, same result, far fewer grid steps.
+    DECODE_T_MAX): same contract, same result, work in proportion to
+    the rows' live tokens.
 
     q [B, T, H, D]; k/v pool [N, Hkv, Bs, D], or with ``layer`` the
     whole pool [L, N, Hkv, Bs, D]; tables [B, MB] int32; starts [B].
-    See paged_attention for semantics. k_scales/v_scales
+    See paged_attention for semantics, but a row parked at
+    start >= MB*Bs returns zeros. k_scales/v_scales
     [(L,) N, Hkv, Bs] fp32 activate the int8-pool mode (panels stream
-    as int8, dequantized in VMEM — half the KV bytes of the bf16 pool).
+    as int8, half the KV bytes of the bf16 pool; the scales multiply
+    scores and probabilities).
     """
     B, T, H, D = q.shape
     layer, k_pool, v_pool, k_scales, v_scales = _whole_pool(
         layer, k_pool, v_pool, k_scales, v_scales)
     Hkv, Bs = k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
-    MB = tables.shape[1]
     if scale is None:
         scale = D ** -0.5
     quant = k_scales is not None
-    R = min(_BLOCKS_PER_STEP, nb)
+    R = decode_blocks_per_step(nb, Hkv, Bs, D, k_pool.dtype.itemsize)
     ngrp = -(-nb // R)
     rows = T * G
+    tables = jnp.asarray(tables, jnp.int32)
 
     # [B, T, Hkv, G, D] -> [B, Hkv, T*G, D]: rows ordered t*G + g per
     # head, matching the kernel's row_pos formula
     qh = q.reshape(B, T, Hkv, G, D).transpose(0, 2, 1, 3, 4)
     qh = qh.reshape(B, Hkv, rows, D)
 
-    def kv_index(i):
-        def index(b, jg, tabs, sts, lyr):
-            jmax = jax.lax.div(sts[b] + (T - 1), jnp.int32(Bs))
-            jj = jnp.minimum(jnp.minimum(jg * R + i, jmax),
-                             jnp.int32(MB - 1))
-            if window:
-                jmin = jax.lax.div(
-                    jnp.maximum(sts[b] - (window - 1), 0), jnp.int32(Bs))
-                jj = jnp.maximum(jj, jnp.minimum(jmin,
-                                                 jnp.int32(MB - 1)))
-            return (lyr[0], tabs[b, jnp.maximum(jj, 0)], 0, 0, 0)
-        return index
-
-    def q_index(b, jg, tabs, sts, lyr):
+    def row_index(b, tabs, sts, lyr):
         return (b, 0, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, T=T, heads_kv=Hkv, groups=G,
-        block_size=Bs, ngrp=ngrp, R=R, scale=scale, quant=quant,
+        block_size=Bs, nb=nb, R=R, scale=scale, quant=quant,
         window=window, softcap=softcap)
-    kv_specs = [pl.BlockSpec((1, 1, Hkv, Bs, D), kv_index(i))
-                for i in range(R)]
-    in_specs = [
-        pl.BlockSpec((1, Hkv, rows, D), q_index),
-        *kv_specs, *kv_specs,
-    ]
-    operands = [qh, *([k_pool] * R), *([v_pool] * R)]
+    in_specs = [pl.BlockSpec((1, Hkv, rows, D), row_index),
+                pl.BlockSpec(memory_space=pltpu.HBM),
+                pl.BlockSpec(memory_space=pltpu.HBM)]
+    operands = [qh, k_pool, v_pool]
     if quant:
-        def sc_index(i):
-            ki = kv_index(i)
-            return lambda *a: ki(*a)[:4]
-
-        sc_specs = [pl.BlockSpec((1, 1, Hkv, Bs), sc_index(i))
-                    for i in range(R)]
-        in_specs += [*sc_specs, *sc_specs]
-        operands += [*([k_scales] * R), *([v_scales] * R)]
+        in_specs += [pl.BlockSpec((1, ngrp, Hkv, R * Bs), row_index)] * 2
+        operands += [_chunked_scales(s, layer, tables, ngrp, R)
+                     for s in (k_scales, v_scales)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, ngrp),
+            grid=(B,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, Hkv, rows, D), q_index),
+            out_specs=pl.BlockSpec((1, Hkv, rows, D), row_index),
             scratch_shapes=[
+                pltpu.VMEM((2, R, Hkv, Bs, D), k_pool.dtype),
+                pltpu.VMEM((2, R, Hkv, Bs, D), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((Hkv * rows, R * Bs), jnp.float32),
                 pltpu.VMEM((Hkv * rows, 1), jnp.float32),
                 pltpu.VMEM((Hkv * rows, 1), jnp.float32),
                 pltpu.VMEM((Hkv * rows, D), jnp.float32),
+                pltpu.VMEM((Hkv * rows, D), jnp.float32),
+                pltpu.SMEM((2,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # a row hands the next its first chunk, already in flight
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(jnp.asarray(tables, jnp.int32), jnp.asarray(starts, jnp.int32),
-      layer, *operands)
+    )(tables, jnp.asarray(starts, jnp.int32), layer, *operands)
 
     # [B, Hkv, T*G, D] -> [B, T, H, D]
     out = out.reshape(B, Hkv, T, G, D).transpose(0, 2, 1, 3, 4)
@@ -596,7 +680,7 @@ def paged_attention_sharded(q, k_pool, v_pool, tables, starts, mesh, *,
     axis (q heads and pool kv heads both shard by tp, tables/starts
     and the layer index replicated) — shard-local, no collectives.
     Caller guarantees the mesh has no other axis of size > 1
-    (mesh_tp_only). Short windows (decode/spec) take the wide decode
+    (mesh_tp_only). Short windows (decode/spec) take the decode
     kernel, like the unsharded path. int8 pools pass their
     [(L,) N, Hkv, Bs] scales, sharded over the same head axis."""
     from jax.sharding import PartitionSpec as P
@@ -645,8 +729,8 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
     shows it).
 
     ``pallas_paged_decode``: short windows (decode / speculative
-    verify) on the wide kernel — all kv heads + several pool blocks per
-    grid step, ~16x fewer grid steps than the general one.
+    verify) on the decode kernel — one grid step a row, all kv heads
+    and several live pool blocks a chunk, copied in by the kernel.
     ``pallas_paged``: prefill chunks on the general paged kernel.
     ``*_sharded``: either, shard-local per head under a tp-only mesh.
     ``jnp_gather``: the kernel is off (PSTPU_FLASH / not a TPU), the

@@ -83,13 +83,14 @@ def _placements(topo, tp: int, layers: int = 0):
                   NamedSharding(mesh, P(*lead, None, "tp", None)))
 
 
-def _compile_attention(topo, *, B, T, H, Hkv, int8, window=0, tp=1,
-                       layers=0):
-    """Lower + compile the attention call the serving path makes for
-    this shape (pallas_paged.attention_path's choice of kernel) and
-    return the compiled executable. layers: 0 = one bare layer of the pool
-    [N, Hkv, Bs, D]; n = the whole pool [n, N, Hkv, Bs, D] and the
-    layer index as an operand, as models/kv.attend calls it."""
+def _lower_attention(topo, *, B, T, H, Hkv, int8, window=0, tp=1,
+                     layers=0, nb=MB, softcap=0.0):
+    """Lower the attention call the serving path makes for this shape
+    (pallas_paged.attention_path's choice of kernel) for the described
+    chip. layers: 0 = one bare layer of the pool [N, Hkv, Bs, D]; n =
+    the whole pool [n, N, Hkv, Bs, D] and the layer index as an
+    operand, as models/kv.attend calls it. nb: the kv bucket in
+    blocks (8: the 512 bucket both cells decode in)."""
     mesh, (q_sh, kv_sh, rep_sh, sc_sh) = _placements(topo, tp, layers)
     n_blocks = B * MB + 1
     lead = (layers,) * bool(layers)
@@ -112,25 +113,98 @@ def _compile_attention(topo, *, B, T, H, Hkv, int8, window=0, tp=1,
         kernel = pallas_paged.paged_attention
 
     def call(q, k, v, tables, starts, ks, vs, layer):
-        return kernel(q, k, v, tables, starts, nb=MB, window=window,
-                      k_scales=ks, v_scales=vs, layer=layer)
+        return kernel(q, k, v, tables, starts, nb=nb, window=window,
+                      softcap=softcap, k_scales=ks, v_scales=vs,
+                      layer=layer)
 
     return jax.jit(call).lower(q, pool, pool, tables, starts, scales,
-                               scales, layer).compile()
+                               scales, layer)
+
+
+def _compile_attention(topo, **kw):
+    """_lower_attention, compiled: raises what the chip's compiler
+    would raise."""
+    return _lower_attention(topo, **kw).compile()
 
 
 KV = pytest.mark.parametrize("int8", [False, True],
                              ids=["kv_bf16", "kv_int8"])
 POOL = pytest.mark.parametrize("layers", [0, 4],
                                ids=["layer_4d", "whole_pool"])
+# the kv bucket: 512 tokens (where both cells decode) and 2048
+NB = pytest.mark.parametrize("nb", [8, 32], ids=["kv512", "kv2048"])
+# (H, Hkv): Mistral-7B's GQA and Qwen1.5-MoE's MHA
+GEOMETRY = pytest.mark.parametrize("heads", [(32, 8), (16, 16)],
+                                   ids=["gqa_32_8", "mha_16_16"])
 
 
 @POOL
 @KV
+@NB
 @pytest.mark.parametrize("B", [8, 32])
-def test_wide_decode_kernel_compiles(topo, B, int8, layers):
+def test_wide_decode_kernel_compiles(topo, B, nb, int8, layers):
     _compile_attention(topo, B=B, T=1, H=32, Hkv=8, int8=int8,
-                       layers=layers)
+                       layers=layers, nb=nb)
+
+
+@KV
+@GEOMETRY
+@pytest.mark.parametrize("how", [
+    dict(T=5), dict(T=8), dict(window=1000), dict(softcap=50.0),
+    dict(T=4, window=1000, softcap=50.0, nb=8)],
+    ids=["T5", "T8", "window", "softcap", "all_at_once"])
+def test_decode_kernel_variants_compile(topo, how, heads, int8):
+    """What no benchmark cell runs of the decode kernel: speculative
+    windows (T*G rows a head that are no multiple of 8), a sliding
+    window whose first live block lies inside a chunk, Gemma-2's
+    softcap, on either pool."""
+    H, Hkv = heads
+    _compile_attention(topo, B=16, H=H, Hkv=Hkv, int8=int8, layers=4,
+                       **{"T": 1, **how})
+
+
+def _kernel_module(lowered) -> str:
+    """The Mosaic module of the one kernel in a lowered program, as
+    text: the custom call carries it serialized."""
+    import base64
+    import re
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+    bodies = re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                        lowered.as_text())
+    assert len(bodies) == 1, len(bodies)
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True   # the serialized dialect
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(bodies[0]))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+@KV
+@GEOMETRY
+def test_decode_kernel_feeds_the_mxu_native_panels(topo, heads, int8):
+    """In the decode kernel's module for the described v5e no matmul
+    takes a float32 operand, and nothing converts a K or V panel
+    ([>= Bs, D]) to float32: the pool's bf16 goes to the MXU as it
+    lies (int8: converted to bf16, which is exact), products
+    accumulate in float32. The kernel this replaced up-cast every
+    [Bs, D] panel on the vector unit and ran float32 matmuls of
+    several MXU passes each (PERF.md, PR 30)."""
+    import re
+    H, Hkv = heads
+    text = _kernel_module(_lower_attention(
+        topo, B=16, T=1, H=H, Hkv=Hkv, int8=int8, layers=4, nb=8))
+    matmuls = re.findall(
+        r'tpu\.matmul"?\(.*?: \(vector<([\dx]+)x(\w+)>, '
+        r'vector<([\dx]+)x(\w+)>, vector<[\dx]+xf32>\)', text)
+    assert matmuls, "no matmul found in the kernel's module"
+    assert {(a, b) for _, a, _, b in matmuls} == {("bf16", "bf16")}, \
+        matmuls
+    widened = [m for m in re.findall(
+        r'arith\.(?:extf|sitofp|uitofp)"?\(.*?-> vector<([\dx]+)xf32>',
+        text) if int(m.split("x")[-1]) == D
+        and int(np.prod([int(n) for n in m.split("x")[:-1]])) >= BS]
+    assert not widened, widened
 
 
 @POOL
@@ -147,11 +221,12 @@ def test_general_paged_kernel_compiles(topo, T, window, int8, layers):
 
 @POOL
 @KV
+@NB
 @pytest.mark.parametrize("T", [1, 128])
-def test_mha_16_16_geometry_compiles(topo, T, int8, layers):
+def test_mha_16_16_geometry_compiles(topo, T, nb, int8, layers):
     """Qwen1.5-MoE attention geometry: 16 q / 16 kv heads (G = 1)."""
     _compile_attention(topo, B=8, T=T, H=16, Hkv=16, int8=int8,
-                       layers=layers)
+                       layers=layers, nb=nb)
 
 
 @POOL

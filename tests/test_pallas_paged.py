@@ -8,11 +8,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from production_stack_tpu.models.kv import make_cache, write_chunk, gather_view
+from production_stack_tpu.models.kv import (
+    gather_view, gather_view_q, make_cache, quantize_chunk, write_chunk)
 from production_stack_tpu.ops.attention import attention_with_cache
 from production_stack_tpu.ops.pallas_paged import (
-    mesh_tp_only, paged_attention, paged_attention_sharded,
-    paged_decode_attention)
+    decode_blocks_per_step, mesh_tp_only, paged_attention,
+    paged_attention_sharded, paged_decode_attention)
 from tests.whole_pool import WHOLE, call as _call
 
 
@@ -89,32 +90,121 @@ def test_paged_matches_dense(T, G, Bs, D, Hkv, dtype, layer):
                 layer)
 
 
-@pytest.mark.parametrize("T,G,Bs,D,Hkv,dtype", [
-    (1, 4, 16, 32, 2, F32),      # decode window step, GQA
-    (1, 1, 16, 32, 2, F32),      # decode, MHA (G == 1)
-    (5, 4, 16, 32, 2, F32),      # speculative window (draft + 1)
-    (8, 2, 16, 64, 2, F32),      # DECODE_T_MAX boundary
-    (5, 4, 16, 32, 1, F32),      # one kv head
-    (1, 4, 16, 128, 2, F32),     # head dim 128, as both benchmark cells
-    (4, 2, 16, 64, 2, BF16),     # the serving dtype
+PARKED = -1     # a row parked at start = MB * Bs
+
+
+def _decode_case(seed, T, G, Bs, D, Hkv, dtype, layer, *, starts, nb,
+                 window=0, softcap=0.0, int8=False):
+    """The decode kernel against the dense path on a shuffled pool, one
+    row per entry of ``starts`` (PARKED: a row that holds no request),
+    every row's table as wide as the kv bucket ``nb`` and one block
+    more, every block of the pool random: a row's dead blocks hold
+    other rows' values, and nothing may read them. The int8 pool is
+    the float pool quantized per token; both sides read the same
+    dequantized values."""
+    B, H, MB = len(starts), Hkv * G, nb + 1
+    key = jax.random.PRNGKey(seed)
+    kk, kv, kt, kq = jax.random.split(key, 4)
+    n_blocks = B * MB + 1
+    k_pool = jax.random.normal(kk, (n_blocks, Hkv, Bs, D), dtype)
+    v_pool = jax.random.normal(kv, (n_blocks, Hkv, Bs, D), dtype)
+    tables = jnp.asarray(np.asarray(
+        jax.random.permutation(kt, n_blocks - 1)).reshape(B, MB) + 1,
+        jnp.int32)
+    starts = jnp.asarray([MB * Bs if s == PARKED else s for s in starts],
+                         jnp.int32)
+    q = jax.random.normal(kq, (B, T, H, D), dtype)
+    kw = dict(window=window, softcap=softcap)
+    if int8:
+        # [N, Hkv, Bs, D]: quantize_chunk takes the amax over D
+        k_pool, ks = quantize_chunk(k_pool.astype(F32))
+        v_pool, vs = quantize_chunk(v_pool.astype(F32))
+        kw.update(k_scales=ks, v_scales=vs)
+        k_att = gather_view_q(k_pool, ks, tables, nb, dtype=dtype)
+        v_att = gather_view_q(v_pool, vs, tables, nb, dtype=dtype)
+    else:
+        k_att = gather_view(k_pool, tables, nb)
+        v_att = gather_view(v_pool, tables, nb)
+    got = np.asarray(_call(paged_decode_attention, q, k_pool, v_pool,
+                           tables, starts, nb=nb, interpret=True,
+                           layer=layer, **kw), np.float32)
+    positions = starts[:, None] + jnp.arange(T)[None, :]
+    want = np.asarray(attention_with_cache(
+        q, k_att, v_att, positions, sliding_window=window or None,
+        logit_softcap=softcap or None), np.float32)
+    live = np.asarray(starts) < MB * Bs
+    tol = 2e-2 if dtype == BF16 else 2e-5
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+    # a parked row costs nothing and says nothing: finite zeros
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("T,G,Bs,D,Hkv,dtype,more", [
+    (1, 4, 16, 32, 2, F32, {}),      # decode window step, GQA
+    (1, 1, 16, 32, 2, F32, {}),      # decode, MHA (G == 1)
+    (5, 4, 16, 32, 2, F32, {}),      # speculative window (draft + 1)
+    (8, 2, 16, 64, 2, F32, {}),      # DECODE_T_MAX boundary
+    (5, 4, 16, 32, 1, F32, {}),      # one kv head
+    (1, 4, 16, 128, 2, F32, {}),     # head dim 128, as both benchmark cells
+    (4, 2, 16, 64, 2, BF16, {}),     # the serving dtype
+    # the two cells' head geometries in the serving dtype: under the
+    # 512 bucket (nb 8, one chunk of R = 8) a row of five live blocks,
+    # one of one, one of all eight, and a parked row
+    (1, 4, 64, 128, 2, BF16, dict(starts=[300, 40, 510, PARKED], nb=8)),
+    (1, 1, 64, 128, 4, BF16, dict(starts=[300, 40, 510, PARKED], nb=8)),
+    # the same rows in float32, where a dead block read shows at 2e-5
+    (1, 4, 16, 32, 2, F32, dict(starts=[75, 10, 127, PARKED], nb=8)),
+    # nb not a multiple of any R above 1, the last row on the last block
+    (1, 2, 16, 32, 2, F32, dict(starts=[100, 3, 111], nb=7)),
+    (1, 2, 16, 32, 2, F32, dict(starts=[9], nb=1)),
+    # blocks long enough that a row takes several chunks (R = 2 at
+    # Bs 256, R = 4 at Bs 128): rows of 3, 1 and 4 chunks, the next
+    # chunk and the next row's first copied under the current one
+    (1, 2, 256, 32, 2, F32, dict(starts=[1500, 300, 2047, PARKED], nb=8)),
+    (5, 1, 256, 32, 2, F32, dict(starts=[1020, 250, 2043], nb=8)),
+    (1, 4, 128, 32, 2, F32, dict(starts=[700, 100, 1023, PARKED], nb=8,
+                                 int8=True)),
+    (1, 2, 128, 32, 2, F32, dict(starts=[900, 200, 1023], nb=8,
+                                 window=300)),
+    # speculative windows across a block boundary and at a row's end
+    (5, 1, 16, 32, 2, F32, dict(starts=[60, 14, 123, PARKED], nb=8)),
+    (8, 4, 16, 32, 2, F32, dict(starts=[57, 0, 120], nb=8)),
+    # the int8 pool with its per-token scales
+    (1, 4, 16, 32, 2, F32, dict(starts=[75, 10, 127, PARKED], nb=8,
+                                int8=True)),
+    (1, 4, 64, 128, 2, BF16, dict(starts=[300, 40, 510], nb=8,
+                                  int8=True)),
+    # sliding windows whose first live block lies inside a chunk (and,
+    # on the second row, whose window holds the whole row)
+    (1, 2, 16, 32, 2, F32, dict(starts=[100, 20, 127], nb=8, window=40)),
+    (5, 2, 16, 32, 2, F32, dict(starts=[100, 20, 123, PARKED], nb=8,
+                                window=24)),
+    # Gemma-2's cap on the raw scores, alone and under a window
+    (1, 2, 16, 32, 2, F32, dict(starts=[75, 10, 127], nb=8,
+                                softcap=3.0)),
+    (4, 2, 16, 32, 2, F32, dict(starts=[75, 10, 124], nb=8,
+                                softcap=3.0, window=40, int8=True)),
 ])
 @WHOLE
-def test_paged_decode_matches_dense(T, G, Bs, D, Hkv, dtype, layer):
-    """The wide decode kernel (all kv heads + R blocks per grid step)
-    matches the dense jnp path on the same shuffled pools."""
-    _paged_case(paged_decode_attention, T * 77 + G, T, G, Bs, D, Hkv,
-                dtype, layer)
+def test_paged_decode_matches_dense(T, G, Bs, D, Hkv, dtype, more, layer):
+    """The decode kernel (every live block of a row, R at a time,
+    copied in by the kernel itself) matches the dense jnp path on
+    shuffled pools."""
+    _decode_case(T * 77 + G, T, G, Bs, D, Hkv, dtype, layer,
+                 **{"starts": [70, 33, 51], "nb": 5, **more})
 
 
-def test_paged_decode_short_row_isolation():
-    """A short row must not read long rows' blocks through the group
-    clamp (per-row jmax in the decode kernel's index maps)."""
-    B, Hkv, G, Bs, D, T = 2, 2, 2, 16, 32, 1
+@pytest.mark.parametrize("lens", [[90, 5], [5, 90], [40, 127, 17]])
+def test_paged_decode_short_row_isolation(lens):
+    """A short row must read no block of a long row, before or after it
+    in the batch, nor its own dead blocks (its chunks end at its last
+    live block; the slots still hold what the row before copied)."""
+    B, Hkv, G, Bs, D, T = len(lens), 2, 2, 16, 32, 1
     H = Hkv * G
     key = jax.random.PRNGKey(11)
     k_pool, v_pool, tables = _random_paged(
-        key, B, n_blocks=32, Bs=Bs, Hkv=Hkv, D=D, lens=[90, 5])
-    starts = jnp.asarray([90, 5], jnp.int32)
+        key, B, n_blocks=32, Bs=Bs, Hkv=Hkv, D=D, lens=lens)
+    starts = jnp.asarray(lens, jnp.int32)
     q = jax.random.normal(jax.random.fold_in(key, 1),
                           (B, T, H, D), jnp.float32)
     positions = starts[:, None]
@@ -124,12 +214,24 @@ def test_paged_decode_short_row_isolation():
                              (B, T, Hkv, D), jnp.float32)
     k_pool = write_chunk(k_pool, newk, tables, positions)
     v_pool = write_chunk(v_pool, newv, tables, positions)
-    nb = -(-(90 + T) // Bs)
+    nb = -(-(max(lens) + T) // Bs)
     got = paged_decode_attention(q, k_pool, v_pool, tables, starts,
                                  nb=nb, interpret=True)
     want = _reference(q, k_pool, v_pool, tables, starts, nb)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+    # what a row's dead blocks and the other rows' blocks hold must not
+    # reach it: poison every block the first row does not own or has
+    # not reached, and it answers the same to the bit
+    own = np.asarray(tables[0, :lens[0] // Bs + 1])
+    poison = np.ones(k_pool.shape[0], bool)
+    poison[own] = False
+    bad = jnp.where(jnp.asarray(poison)[:, None, None, None], 1e4, 0.0)
+    got_bad = paged_decode_attention(q, k_pool + bad, v_pool + bad,
+                                     tables, starts, nb=nb,
+                                     interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_bad[0]),
+                                  np.asarray(got[0]))
 
 
 @WHOLE
@@ -301,22 +403,26 @@ def test_engine_paged_kernel_matches_gather_path(engine_kw, prompts,
     assert run(True) == run(False)
 
 
-def test_env_blocks_per_step_validation(monkeypatch):
-    """PSTPU_DECODE_BLOCKS_PER_STEP must never crash import or reach
-    the decode grid math as 0/negative: malformed values warn and fall
-    back to the default."""
-    import pytest
-
-    from production_stack_tpu.ops.pallas_paged import _env_blocks_per_step
-
-    monkeypatch.delenv("PSTPU_DECODE_BLOCKS_PER_STEP", raising=False)
-    assert _env_blocks_per_step() == 4
-    monkeypatch.setenv("PSTPU_DECODE_BLOCKS_PER_STEP", "8")
-    assert _env_blocks_per_step() == 8
-    monkeypatch.setenv("PSTPU_DECODE_BLOCKS_PER_STEP", "banana")
-    with pytest.warns(RuntimeWarning, match="not an integer"):
-        assert _env_blocks_per_step() == 4
-    for bad in ("0", "-3"):
-        monkeypatch.setenv("PSTPU_DECODE_BLOCKS_PER_STEP", bad)
-        with pytest.warns(RuntimeWarning, match="must be >= 1"):
-            assert _env_blocks_per_step() == 4
+@pytest.mark.parametrize("itemsize", [2, 1], ids=["bf16", "int8"])
+@pytest.mark.parametrize("heads_kv", [2, 8, 16])
+@pytest.mark.parametrize("nb", [1, 8, 32])
+def test_decode_blocks_per_step_follows_the_shapes(nb, heads_kv,
+                                                   itemsize):
+    """R, the blocks a chunk of the decode kernel takes, is a function
+    of the trace-time shapes: at least one block, never more than the
+    kv bucket holds, a score panel of whole 128-lane registers where
+    the bucket allows one, and two slots of K and V panels inside the
+    VMEM the module budgets for a kernel's working set."""
+    from production_stack_tpu.ops import pallas_paged
+    Bs, D = 64, 128
+    R = decode_blocks_per_step(nb, heads_kv, Bs, D, itemsize)
+    assert 1 <= R <= nb
+    assert (R * Bs) % 128 == 0 or R == nb
+    slots = 2 * 2 * R * heads_kv * Bs * D * itemsize
+    assert slots <= pallas_paged._VMEM_WORK_BYTES
+    # the cells: Mistral's 8 kv heads take the whole 512 bucket in one
+    # chunk, Qwen's 16 half of it; the int8 pool twice the blocks
+    if nb >= 8:
+        assert R == {(8, 2): 8, (16, 2): 4}.get((heads_kv, itemsize), 8)
+    # wider heads or longer blocks: fewer blocks, still at least one
+    assert decode_blocks_per_step(nb, 64, 256, 256, 4) == 1
