@@ -33,7 +33,8 @@ Design constraints (the r13 rules, verbatim):
 The byte model is deliberately simple and documented (docs/engine.md
 "Efficiency telemetry"): one decode step streams the full weight set
 once plus, for every batch row, the KV prefix up to the window's kv
-bucket. Effective bytes are total bytes scaled by the window's live
+bucket; a mixture of experts less the experts its steps' lists left
+out (``note_window(experts_read=)``; ops/moe.py, the list path). Effective bytes are total bytes scaled by the window's live
 fraction; MBU is effective bytes/s over the device's peak — looked up
 by ``device_kind`` (or ``--hbm-peak-gbps``), and reported as absent
 (``None``) for a device the table does not know: a CPU's or an unknown
@@ -144,7 +145,7 @@ class EngineEffAccounting:
                  kv_position_bytes: int = 0,
                  hbm_peak_bytes_per_s: Optional[float] = None,
                  ring_entries: int = 256,
-                 compile_hist=None,
+                 compile_hist=None, expert_bytes: int = 0,
                  now_fn: Callable[[], float] = time.monotonic,
                  wall_fn: Callable[[], float] = time.time,
                  annotate: Optional[Callable[[str], object]] = None):
@@ -178,6 +179,13 @@ class EngineEffAccounting:
         self.prefill_pad = 0
         self.prefill_dispatches = 0
         self.prefill_by_rows: Dict[int, int] = {}
+        # MoE decode steps: experts whose weights were read, and what
+        # reading every expert would have read (note_window); reported
+        # as ``totals.moe`` by a MoE engine alone. expert_bytes: one
+        # expert's weights in one layer, 0 for a dense model
+        self.expert_bytes = int(expert_bytes)
+        self.experts_read = 0
+        self.experts_resident = 0
         # modeled HBM traffic (decode windows only — see module doc)
         self.bytes_total = 0
         self.bytes_effective = 0
@@ -222,7 +230,8 @@ class EngineEffAccounting:
     def note_window(self, *, steps: int, positions: int, batch: int,
                     live_rows: int, kv_len: int, real: int, pad: int,
                     dead: int, window_s: float, host_s: float = 0.0,
-                    sync_s: float = 0.0) -> None:
+                    sync_s: float = 0.0, experts_read: int = 0,
+                    experts_resident: int = 0) -> None:
         """One fused decode window: ``batch * steps * positions``
         token-step computations, of which ``real`` emitted tokens the
         client keeps, ``pad`` ran on parked rows, and ``dead`` ran on
@@ -231,11 +240,17 @@ class EngineEffAccounting:
         before it, if later) to its own sync's return; ``host_s`` is
         what the host itself spent on it (preparing and making the
         dispatch, walking its tokens) and ``sync_s`` what it spent
-        blocked on the sync."""
+        blocked on the sync. A MoE model: over the window's steps and
+        layers ``experts_read`` experts' weights were fetched, of the
+        ``experts_resident`` (steps x layers x experts) that steps
+        reading every expert fetch (ops/moe.py, the list path); the
+        bytes of the others are not in the window's."""
         total = batch * steps * positions
         useful = real / total if total else 0.0
-        win_bytes = steps * (self.weight_bytes
-                             + batch * self.kv_position_bytes * kv_len)
+        win_bytes = (steps * (self.weight_bytes
+                              + batch * self.kv_position_bytes * kv_len)
+                     - (experts_resident - experts_read)
+                     * self.expert_bytes)
         eff_bytes = int(win_bytes * useful)
         entry = {
             "at": self._now(),
@@ -263,6 +278,8 @@ class EngineEffAccounting:
             self.decode_busy_s += window_s
             self.bytes_total += win_bytes
             self.bytes_effective += eff_bytes
+            self.experts_read += experts_read
+            self.experts_resident += experts_resident
             self._windows.append(entry)
 
     def note_prefill(self, *, bucket: int, batch: int,
@@ -399,6 +416,9 @@ class EngineEffAccounting:
     def report(self) -> Dict[str, object]:
         """Cumulative totals (the scrape-time delta-sync source)."""
         with self._lock:
+            moe = {"moe": {"experts_read": self.experts_read,
+                           "experts_resident": self.experts_resident}
+                   } if self.expert_bytes else {}
             return {
                 "decode": {"real": self.decode_real,
                            "pad": self.decode_pad,
@@ -413,6 +433,7 @@ class EngineEffAccounting:
                             "by_rows": {
                                 str(r): n for r, n in
                                 sorted(self.prefill_by_rows.items())}},
+                **moe,
                 "bytes_total": self.bytes_total,
                 "bytes_effective": self.bytes_effective,
                 "compiles_total": self.compiles_total,

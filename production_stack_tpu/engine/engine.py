@@ -73,6 +73,9 @@ class _Window:
     kv_len: int
     batch: int
     host_s: float           # decode_host + decode_dispatch spent on it
+    # MoE models: the experts whose weights each step read, summed over
+    # the layers [steps]; None on a dense model
+    experts_read: object = None
 
 
 # finished sequences kept for post-hoc inspection (bounded; see _remember)
@@ -241,6 +244,13 @@ class LLMEngine:
         weight_bytes = sum(
             x.size * x.dtype.itemsize
             for x in _tree_util.tree_leaves(self.runner.params))
+        # one expert's gate, up and down (and scales) in one layer: what
+        # a decode step leaves unread of an expert off its list
+        expert_bytes = sum(
+            x.size * x.dtype.itemsize
+            for n in ("gate", "up", "down")
+            for x in _tree_util.tree_leaves(self.runner.params["layers"][n])
+        ) // (mc.num_layers * mc.num_experts) if mc.num_experts else 0
         self.eff = EngineEffAccounting(
             weight_bytes=weight_bytes,
             kv_position_bytes=kv_pos_bytes,
@@ -250,6 +260,7 @@ class LLMEngine:
                                   if peak_gbps else None),
             ring_entries=engine_cfg.perf_ring_entries,
             compile_hist=self.metrics.compile_hist,
+            expert_bytes=expert_bytes,
             annotate=jax.profiler.TraceAnnotation)
         # the step timeline (efficiency.STEP_PHASES): every phase of
         # step() runs under `with self._phase(name)`
@@ -1369,14 +1380,15 @@ class LLMEngine:
                     and not s.options.min_p
                     for s in decode_seqs)
         with self._phase("decode_dispatch", dispatches=True) as call:
-            ids_dev, lps_dev, counts_dev, tops_dev = self.runner.decode(
+            (ids_dev, lps_dev, counts_dev, tops_dev,
+             experts_read_dev) = self.runner.decode(
                 self._dev_sampling, steps=W, kv_len=kv_len, greedy=greedy,
                 seeded=seeded, guide_table=gtable, guide_ids=gids,
                 spec=spec, spec_ok=spec_ok, plain=plain,
                 penalized=penalized, topk=topk)
         win = _Window(ids_dev, lps_dev, counts_dev, tops_dev, W,
                       list(decode_seqs), call.t1, spec_ok, kv_len, batch,
-                      host_s=call.self_s)
+                      host_s=call.self_s, experts_read=experts_read_dev)
         self._inflight.append(win)
         return win
 
@@ -1411,6 +1423,11 @@ class LLMEngine:
         window_s = sync.t1 - win.t0
         with self._phase(kind + "_process") as walk:
             outputs, counted = self._process_window(win, window_s)
+        if win.experts_read is not None:
+            counted.update(
+                experts_read=int(win.experts_read.sum()),
+                experts_resident=win.steps * self.model_cfg.num_layers
+                * self.model_cfg.num_experts)
         self.eff.note_window(**counted, window_s=window_s,
                              host_s=win.host_s + walk.self_s,
                              sync_s=sync.self_s)
@@ -1429,6 +1446,9 @@ class LLMEngine:
             win.counts = np.asarray(win.counts)
         if win.tops is not None:
             win.tops = (np.asarray(win.tops[0]), np.asarray(win.tops[1]))
+        if win.experts_read is not None:
+            # one int32 a step, in the fetch of the window's token ids
+            win.experts_read = np.asarray(win.experts_read)
         return win
 
     def _process_window(self, win: _Window, dt: float):

@@ -338,7 +338,9 @@ class ModelRunner:
                      penalized: bool = False, eos_id: int = 0,
                      topk: int = 0):
         """tokens/positions [B] -> (ids [B, steps], logprobs [B, steps],
-        tokens', positions', cache').
+        tokens', positions', cache', experts read [steps]: the experts
+        whose weights each step read, summed over the layers; None on a
+        dense model).
 
         `steps` forwards are fused via lax.scan; each step feeds its
         sampled ids back as the next step's tokens, and the final
@@ -365,7 +367,7 @@ class ModelRunner:
 
         def body(carry, i):
             cache, toks, pos, gstate, counts = carry
-            logits, cache = llama.forward(
+            logits, cache, read = llama.forward(
                 params, self.model_cfg, toks[:, None], pos[:, None],
                 cache, block_tables=tables,
                 rope=self.rope, kv_len=kv_len, mesh=self.mesh,
@@ -379,16 +381,16 @@ class ModelRunner:
                 seeded=seeded, plain=plain, guided=guided,
                 penalized=penalized, eos_id=eos_id, topk=topk)
             return ((cache, ids, pos + 1, gstate, counts),
-                    (ids, lp, ti, tl))
+                    (ids, lp, ti, tl, read))
 
-        (cache, toks, pos, gstate, counts), (ids, lps, tis, tls) = \
+        (cache, toks, pos, gstate, counts), (ids, lps, tis, tls, read) = \
             jax.lax.scan(
                 body, (cache, tokens, positions, guide_state, out_counts),
                 jnp.arange(steps))
         # ids/lps [B, steps]; tis/tls [B, steps, K]
         return (ids.T, lps.T, tis.transpose(1, 0, 2),
                 tls.transpose(1, 0, 2), toks, pos, gstate, counts,
-                cache)
+                cache, read)
 
     def _decode_spec_impl(self, params, cache: KVCache,
                           tables: jnp.ndarray,
@@ -428,7 +430,8 @@ class ModelRunner:
 
         Returns (ids [B, steps, spec+1], logprobs same, top-K ids/lps
         [B, steps, K], counts [B, steps] valid-token counts, tokens',
-        positions', history', gstate', out_counts', cache'). Rejected
+        positions', history', gstate', out_counts', cache', experts
+        read [steps] as _decode_impl). Rejected
         draft positions hold garbage K/V past the live length; the
         write-then-attend invariant (models/kv.py) makes them
         unobservable, exactly like window tail waste.
@@ -453,7 +456,7 @@ class ModelRunner:
             draft = jax.vmap(draft_row)(hist, pos)          # [B, K]
             step_toks = jnp.concatenate([toks[:, None], draft], axis=1)
             step_pos = pos[:, None] + jnp.arange(K + 1)[None, :]
-            logits, cache = llama.forward(
+            logits, cache, read = llama.forward(
                 params, self.model_cfg, step_toks, step_pos, cache,
                 block_tables=tables,
                 rope=self.rope, kv_len=kv_len, mesh=self.mesh,
@@ -493,17 +496,17 @@ class ModelRunner:
                                                     (p + 1,))
             hist = jax.vmap(write_row)(hist, pos, expected)
             return ((cache, new_toks, new_pos, hist, gstate, counts),
-                    (expected, lp, ti, tl, count))
+                    (expected, lp, ti, tl, count, read))
 
         ((cache, toks, pos, hist, gstate, counts),
-         (ids, lps, tis, tls, cnt)) = jax.lax.scan(
+         (ids, lps, tis, tls, cnt, read)) = jax.lax.scan(
             body, (cache, tokens, positions, history, guide_state,
                    out_counts),
             jnp.arange(steps))
         # scan stacks on axis 0: -> [B, steps, K+1] / [B, steps]
         return (ids.transpose(1, 0, 2), lps.transpose(1, 0, 2),
                 tis.transpose(1, 0, 2), tls.transpose(1, 0, 2),
-                cnt.T, toks, pos, hist, gstate, counts, cache)
+                cnt.T, toks, pos, hist, gstate, counts, cache, read)
 
     def _prefill_impl(self, params, cache: KVCache, tables: jnp.ndarray,
                       slots: jnp.ndarray, tokens: jnp.ndarray,
@@ -546,7 +549,7 @@ class ModelRunner:
         # write K/V, route in MoE layers, or steal expert capacity
         token_valid = ((jnp.arange(Tb)[None, :] < lengths[:, None])
                        & (starts < S)[:, None])
-        logits, cache = llama.forward(
+        logits, cache, _ = llama.forward(
             params, self.model_cfg, tokens, positions, cache,
             block_tables=tables,
             rope=self.rope, kv_len=kv_len, mesh=self.mesh,
@@ -664,15 +667,17 @@ class ModelRunner:
         guided ids, penalty carry) is sliced to that bucket, so parked
         rows beyond it are simply not computed. Executables are cached
         per (batch, steps, kv bucket, variant). Returns
-        (ids, logprobs, counts, tops): without speculation ids/logprobs
+        (ids, logprobs, counts, tops, experts_read): without speculation ids/logprobs
         are [B, steps] and counts is None; with spec > 0 they are
         [B, steps, spec+1] plus counts [B, steps] of valid tokens per
         macro-step (_decode_spec_impl) — speculation is PER-ROW via
         spec_ok [B] bool (rows with False single-step with the full
         shaping/guided/sampling treatment). tops is None unless
         topk > 0: then (ids [B, steps, K], logprobs [B, steps, K])
-        top-K alternatives per step. The first np.asarray() is the
-        window's single sync.
+        top-K alternatives per step. experts_read [steps] int32 (MoE
+        models; None on a dense one): the experts whose weights each
+        step read, summed over the layers. The first np.asarray() is
+        the window's single sync.
 
         guide_table [G, S, V] device int32 + guide_ids [B] activate
         constrained sampling (engine/guided.py); the per-row DFA state
@@ -743,10 +748,10 @@ class ModelRunner:
                                positions=spec + 1)
             (ids, lps, tis, tls, cnt, self._dec_tokens, self._dec_pos,
              self._dec_hist, self._dec_gstate, counts_out,
-             self.cache) = fn(*args)
+             self.cache, read) = fn(*args)
             if penalized:
                 self._dec_counts = counts_out
-            return ids, lps, cnt, (tis, tls) if topk else None
+            return ids, lps, cnt, (tis, tls) if topk else None, read
         cache_key = (B, steps, kv_len, greedy, seeded, guided, gshape,
                      plain, penalized, topk)
         args = (self.params, self.cache, tables,
@@ -773,10 +778,10 @@ class ModelRunner:
                            args, kind="decode", window=steps,
                            kv_len=kv_len, batch=B, positions=1)
         (ids, lps, tis, tls, self._dec_tokens, self._dec_pos,
-         self._dec_gstate, counts_out, self.cache) = fn(*args)
+         self._dec_gstate, counts_out, self.cache, read) = fn(*args)
         if penalized:
             self._dec_counts = counts_out
-        return ids, lps, None, (tis, tls) if topk else None
+        return ids, lps, None, (tis, tls) if topk else None, read
 
     def _attention_path(self, positions: int, mesh) -> str:
         """ops/pallas_paged.attention_path for this model's head

@@ -145,7 +145,8 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                 token_valid: Optional[jnp.ndarray] = None,
                 block_tables: Optional[jnp.ndarray] = None,
                 mesh=None, layer_local=None, layer=None,
-                moe_capacity_tokens: Optional[int] = None):
+                moe_capacity_tokens: Optional[int] = None,
+                expert_stacks: Optional[Params] = None):
     """One transformer block. x [B,T,H]; kv = the WHOLE paged pool
     (k, v) [L,N,Hkv,Bs,D] — with (ks, vs) [L,N,Hkv,Bs] behind them for
     the int8 pool — of which this block appends to and reads
@@ -153,7 +154,9 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     [B,MB]. The pool comes back as the second result, the same buffer
     with this layer's chunk written (models/kv.py: carried, never
     stacked); what is done to it is done behind models/kv.py
-    (``append``, ``attend``). kv None (encode): no cache.
+    (``append``, ``attend``). kv None (encode): no cache. Returns (x',
+    the pool, the experts whose weights the block read: None on a
+    dense model).
 
     kv_len (static) bounds attention to the first ceil(kv_len/Bs) blocks
     of every slot: K/V writes target the pool via the tables, and
@@ -164,7 +167,9 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     routed to the trash block and (on MoE models) they are kept out of
     expert-capacity competition. moe_capacity_tokens (static): the
     token count the experts' capacity is reckoned on, where that is not
-    B*T (ops/moe.moe_mlp ``capacity_tokens``).
+    B*T (ops/moe.moe_mlp ``capacity_tokens``). expert_stacks: the
+    experts' gate/up/down of ALL layers, where the block reads its own
+    in place (ops/moe.py, the list path); ``lp`` then lacks them.
     lora_layer: this layer's stacked adapters {proj: {a, b}} + per-row
     adapter_ids [B] (models/lora.py) — batched multi-LoRA.
 
@@ -243,12 +248,18 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
         hidden = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps,
                           offset=offset)
     act = jax.nn.silu if cfg.activation == "silu" else _gelu_tanh
+    experts_read = None
     if cfg.num_experts:
         H = hidden.shape[-1]
+        # the list path reads its experts in place in the whole stacks
+        # (ops/moe.list_path, asked by ``forward``); else this layer's
+        gate, up, down = (
+            lp[n] if expert_stacks is None else expert_stacks[n]
+            for n in ("gate", "up", "down"))
         # scopes moe_router / moe_experts / moe_combine: ops/moe.py
-        y = moe.moe_mlp(
-            hidden.reshape(B * T, H), lp["router"], lp["gate"],
-            lp["up"], lp["down"], top_k=cfg.num_experts_per_tok,
+        y, experts_read = moe.moe_mlp(
+            hidden.reshape(B * T, H), lp["router"], gate, up, down,
+            top_k=cfg.num_experts_per_tok,
             capacity_factor=cfg.moe_capacity_factor,
             capacity_tokens=moe_capacity_tokens, act=act,
             valid=None if token_valid is None
@@ -256,7 +267,8 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
             renormalize=cfg.norm_topk_prob,
             # decode (T == 1) must be exact: a dropped token would
             # corrupt a live sequence's residual stream mid-generation
-            exact=True if T == 1 else None)
+            exact=True if T == 1 else None,
+            layer=None if expert_stacks is None else layer)
         if cfg.shared_expert_size:
             # Qwen2-MoE: an always-on shared expert, scaled by a
             # per-token sigmoid gate
@@ -278,7 +290,7 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                 mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"],
                                    cfg.rms_norm_eps, offset=offset)
             x = x + mlp_out
-    return x, kv
+    return x, kv, experts_read
 
 
 def _gelu_tanh(x: jnp.ndarray) -> jnp.ndarray:
@@ -295,8 +307,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             lora_scaling: float = 1.0,
             token_valid: Optional[jnp.ndarray] = None,
             mesh=None, moe_capacity_tokens: Optional[int] = None,
-            ) -> Tuple[jnp.ndarray, KVCache]:
-    """Incremental forward. tokens/positions [B,T] -> (logits fp32 [B,T,V], cache').
+            ) -> Tuple[jnp.ndarray, KVCache, Optional[jnp.ndarray]]:
+    """Incremental forward. tokens/positions [B,T] -> (logits fp32
+    [B,T,V], cache', experts read).
 
     cache is the paged block pool (models/kv.py); block_tables [B, MB]
     map each row's virtual positions to pool blocks (None = identity
@@ -315,6 +328,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     many tokens instead of B*T — a prefill of fewer rows than the full
     batch passes the full batch's count, so that it never holds less
     per expert (ops/moe.moe_mlp ``capacity_tokens``).
+    experts read: the experts whose weights the forward read, summed
+    over the layers (int32 scalar; None on a dense model): layers x
+    experts, or the experts its valid rows were routed to where the
+    expert matmuls walk that list (ops/moe.list_path: decode steps).
     """
     if rope is None:
         rope = rope_table(cfg.max_position_embeddings, cfg.head_dim_,
@@ -335,27 +352,43 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         # the whole pool per step (models/kv.py)
         h, pool = carry
         lp, layer, ll, local = xs
-        return _layer_body(cfg, rope, positions, starts, h, lp, pool,
-                           kv_len=kv_len, lora_layer=ll, adapter_ids=adapter_ids,
-                           lora_scaling=lora_scaling,
-                           token_valid=token_valid,
-                           block_tables=block_tables, mesh=mesh,
-                           layer_local=local, layer=layer,
-                           moe_capacity_tokens=moe_capacity_tokens), None
+        h, pool, experts_read = _layer_body(
+            cfg, rope, positions, starts, h, lp, pool,
+            kv_len=kv_len, lora_layer=ll, adapter_ids=adapter_ids,
+            lora_scaling=lora_scaling, token_valid=token_valid,
+            block_tables=block_tables, mesh=mesh,
+            layer_local=local, layer=layer,
+            moe_capacity_tokens=moe_capacity_tokens,
+            expert_stacks=expert_stacks)
+        return (h, pool), experts_read
 
+    layer_params = params["layers"]
+    expert_stacks = None
+    if cfg.num_experts and moe.list_path(
+            *tokens.shape, cfg.hidden_size,
+            cfg.moe_intermediate_size or cfg.intermediate_size,
+            moe.stored_dtype(layer_params["gate"]), x.dtype, mesh):
+        # the list path's kernel reads a layer of the expert stacks in
+        # place: closed over whole, like the pool, where the scan's xs
+        # would hand it a copy of the layer (a custom call cannot fuse
+        # its operand's slice)
+        expert_stacks = {n: layer_params[n] for n in ("gate", "up", "down")}
+        layer_params = {n: w for n, w in layer_params.items()
+                        if n not in expert_stacks}
     layers = jnp.arange(cfg.num_layers)
-    xs = (params["layers"], layers, lora_params,
+    xs = (layer_params, layers, lora_params,
           # Gemma-2 layer pattern: even layers sliding, odd global
           layers % 2 == 0 if cfg.alternating_sliding else None)
     pool = tuple(a for a in cache if a is not None)
     with jax.named_scope("layers"):
-        (x, pool), _ = jax.lax.scan(scan_body, (x, pool), xs)
+        (x, pool), experts_read = jax.lax.scan(scan_body, (x, pool), xs)
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                      offset=1.0 if cfg.rms_norm_offset else 0.0)
     with jax.named_scope("lm_head"):
         logits = _lm_head(params, cfg, x)
-    return logits, KVCache(*pool)
+    return (logits, KVCache(*pool),
+            None if experts_read is None else jnp.sum(experts_read))
 
 
 def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -376,9 +409,9 @@ def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
     def scan_body(carry, xs):
         lp, local = xs
-        out, _ = _layer_body(cfg, rope, positions, None, carry, lp, None,
-                             token_valid=token_valid,
-                             layer_local=local)
+        out, _, _ = _layer_body(cfg, rope, positions, None, carry, lp,
+                                None, token_valid=token_valid,
+                                layer_local=local)
         return out, None
 
     local_flags = (jnp.arange(cfg.num_layers) % 2 == 0

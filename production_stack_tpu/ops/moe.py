@@ -8,15 +8,30 @@ helm/templates/deployment-vllm-multi.yaml:57-64; expert parallelism is a
 - Routing, dispatch and combine are all static-shape jnp — no
   data-dependent shapes, so the whole block lives inside the engine's
   jitted prefill/decode executables and XLA can schedule it.
-- Two dispatch strategies, chosen at trace time by token count N:
+- Three strategies, chosen at trace time from the shapes (rows and
+  positions, so the token count N; experts E, top-k, the experts'
+  widths) and the mesh; no option selects one:
 
-  **Exact (small N, the decode path).** Every expert runs over all N
-  tokens and results are combined with the routing weights ([N, E],
-  zero for unselected experts). At decode sizes (N = batch ≤ ~tens)
-  this is bandwidth-equivalent to "perfect" dispatch — with N*k
-  assignments over E experts nearly every expert is touched anyway, so
-  the step still streams every expert's weights once — and it is exact:
-  no token is ever dropped.
+  **Exact (small N).** Every expert runs over all N tokens and results
+  are combined with the routing weights ([N, E], zero for unselected
+  experts). It is exact (no token is ever dropped) and streams every
+  expert's weights once, whatever the rows chose. How much of that a
+  perfect dispatch would read is the expected share of experts hit,
+  1 - (1 - 1/E)^(N k) under even routing: 99 % for Mixtral's 8 experts
+  top-2 at 16 rows (nothing to gain), 66 % for Qwen1.5-MoE's 60 top-4
+  at 16 rows (a third of the bytes are multiplied by a combine weight
+  of zero), 99 % at 64 rows.
+
+  **List (a decode step whose experts fit VMEM).** The same sum over
+  only the experts that a valid row chose: ``experts_hit`` compacts
+  their ids, a Pallas kernel walks the list and copies those experts'
+  weights alone out of the stacks, in place (``_moe_list``, further
+  down). ``list_path`` is the rule: one position a row, at most
+  DENSE_THRESHOLD rows, nothing sharded, two slots of an expert's
+  matrices fit VMEM (Mixtral-8x7B's do not: the exact path), the
+  kernels on (pallas_paged.flash_enabled: not on the CPU). As exact as
+  the exact path: an expert no valid row chose contributes exactly
+  zero there.
 
   **Capacity dispatch (large N, the prefill path).** The GShard/Switch
   pattern reshaped for scatter/gather instead of [N, E, C] one-hots:
@@ -47,6 +62,10 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from production_stack_tpu.ops import pallas_paged
 
 
 def capacity_for(n_tokens: int, num_experts: int, top_k: int,
@@ -86,6 +105,11 @@ def _wshape(w) -> tuple:
     return (w["w8"] if _quant().is_quantized(w) else w).shape
 
 
+def stored_dtype(w):
+    """Dtype a raw or int8-quantized weight is stored in."""
+    return (w["w8"] if _quant().is_quantized(w) else w).dtype
+
+
 def _edot(xb: jnp.ndarray, w) -> jnp.ndarray:
     """einsum('ec?,e?o->eco') with weight-only int8 dequant applied in
     the epilogue (per-expert, per-output-channel scale)."""
@@ -117,6 +141,249 @@ def _moe_exact(x, top_p, top_i, gate, up, down, act):
             jnp.arange(N)[:, None], top_i].set(top_p)
         return jnp.einsum("enh,ne->nh", y_e,
                           combine.astype(x.dtype))
+
+
+# ---------------------------------------------------------------------
+# the list path: a decode step reads only the experts its rows chose.
+#
+# ``experts_hit`` compacts the distinct expert ids that a valid row
+# selected to the front of a static-length vector, with their count;
+# ``_moe_list`` hands that list, the count and the layer index to one
+# Pallas call as scalar-prefetched operands. The expert stacks
+# [L, E, h, i] / [L, E, i, h] stay in HBM, whole (a custom call cannot
+# fuse a slice of its operand: handed one layer it would be handed a
+# copy of it, so models/llama.py closes over the stacks and passes the
+# layer's index, as models/kv.py does with the KV pool). The kernel
+# walks the list with a dynamic trip count; per listed expert it copies
+# the three matrices into one of two VMEM slots (the next expert's
+# copies run under this one's arithmetic), converts them to the
+# activation dtype there, computes ``act(x @ gate) * (x @ up) @ down``
+# for all N rows
+# with the per-channel scales applied to the float32 products, weighs
+# the rows by the expert's combine column (zero for a row that did not
+# choose it) and accumulates [N, h] in float32. An expert off the list
+# costs no copy and no arithmetic.
+# ---------------------------------------------------------------------
+
+# the decode batches the list path takes: the rows at which moe_mlp
+# itself takes the exact path, whose sum the list path computes
+DENSE_THRESHOLD = 64
+
+# the share of pallas_paged.VMEM_LIMIT_BYTES the kernel's scratch may
+# take; the rest is the compiler's (the float32 products, the rows).
+# The largest compiled: Qwen1.5-MoE's experts in bfloat16, 0.33
+_LIST_VMEM_SHARE = 0.5
+
+
+def list_scratch_bytes(hidden: int, inter: int, weight_dtype,
+                       act_dtype) -> int:
+    """VMEM the list kernel holds for experts of [hidden, inter]: two
+    slots of gate, up and down as stored, and one matrix converted to
+    the activation dtype (none where the weights already are)."""
+    stored = jnp.dtype(weight_dtype)
+    act = jnp.dtype(act_dtype)
+    converted = act.itemsize if stored != act else 0
+    return hidden * inter * (2 * 3 * stored.itemsize + converted)
+
+
+def list_path(rows: int, positions: int, hidden: int, inter: int,
+              weight_dtype, act_dtype, mesh=None) -> bool:
+    """Do the expert matmuls of a forward over ``positions`` tokens of
+    each of ``rows`` rows walk the list of experts hit (``_moe_list``)?
+    Decided here, from the shapes and the mesh, at trace time: a decode
+    step (one position a row) of at most DENSE_THRESHOLD rows, no mesh
+    axis shards anything, the widths are multiples of the 128 lanes,
+    two slots of one expert's matrices fit VMEM (list_scratch_bytes:
+    Qwen1.5-MoE's 2048 x 1408 do, 23 MB in int8; Mixtral-8x7B's 4096 x
+    14336 do not, 470 MB), and the Pallas kernels run at all
+    (pallas_paged.flash_enabled: compiled on a TPU, off on the CPU,
+    interpret mode where a test forces it). Elsewhere today's paths:
+    prefill chunks and speculative windows (positions > 1) among them.
+    No expected share of experts hit enters: with every expert on the
+    list the kernel takes at most 1 % longer than ``_moe_exact``
+    (PERF.md, PR 34), with fewer it takes less. models/llama.forward asks it to know
+    whether to hand the stacks over whole."""
+    return (pallas_paged.flash_enabled()
+            and positions == 1 and rows <= DENSE_THRESHOLD
+            and (mesh is None
+                 or all(size == 1 for size in mesh.shape.values()))
+            and hidden % 128 == 0 and inter % 128 == 0
+            and list_scratch_bytes(hidden, inter, weight_dtype, act_dtype)
+            <= _LIST_VMEM_SHARE * pallas_paged.VMEM_LIMIT_BYTES)
+
+
+def experts_hit(top_i: jnp.ndarray, valid, num_experts: int):
+    """top_i [N, k] int32, valid [N] bool (None: all) -> (ids [M]
+    int32, count int32), M = min(E, N*k): the distinct experts that a
+    valid row chose, ascending, compacted to the front (the tail holds
+    zeros), and how many they are. A one-hot ``any``, a rank by an
+    [E, E] compare and a [M, E] compare, all of which fuse: nothing
+    sorted, nothing scattered, no shape depends on the data."""
+    N, k = top_i.shape
+    E = num_experts
+    M = min(E, N * k)
+    ids = jnp.arange(E, dtype=jnp.int32)
+    chose = top_i[:, :, None] == ids                      # [N, k, E]
+    if valid is not None:
+        chose = chose & valid[:, None, None]
+    hit = jnp.any(chose, axis=(0, 1))                     # [E]
+    # an expert's rank among the hit: the hit experts up to it, less 1
+    pos = jnp.sum(hit[None, :] & (ids[None, :] <= ids[:, None]),
+                  axis=1, dtype=jnp.int32) - 1
+    at = hit[None, :] & (pos[None, :]
+                         == jnp.arange(M, dtype=jnp.int32)[:, None])
+    return (jnp.sum(jnp.where(at, ids[None, :], 0), axis=1),
+            jnp.sum(hit.astype(jnp.int32)))
+
+
+def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
+                     *refs, act: Callable, quant: bool):
+    """Every listed expert over all N rows.
+
+    ids_ref   (SMEM) [M]     the experts hit, compacted
+    count_ref (SMEM) [1]     how many of them are live
+    layer_ref (SMEM) [1]     the stacks' layer
+    x_ref  [N, h]            the rows
+    ti_ref [N, k] int32, tp_ref [N, k] fp32    routing (weights zeroed
+                             on invalid rows)
+    refs   gate, up (HBM) [L, E, h, i], down (HBM) [L, E, i, h];
+           (quant only: the listed experts' scale rows, gate and up
+           [M8, i], down [M8, h] fp32, M8 = M rounded up to 8;)
+           out [N, h]; scratch: the gate/up slots [2, 2, h, i], the
+           down slots [2, i, h], DMA semaphores [2 slots, 3 matrices],
+           acc [N, h] fp32
+    """
+    gate_hbm, up_hbm, down_hbm = refs[:3]
+    refs = refs[3:]
+    if quant:
+        scale_refs, refs = refs[:3], refs[3:]
+    out_ref, gu_buf, d_buf, sems, acc_ref = refs
+    layer = layer_ref[0]
+    count = count_ref[0]
+    cdt = x_ref.dtype                              # the dots' operands
+
+    def copies(c, slot):
+        """The copies of listed expert c's gate, up and down into
+        ``slot``: to start, or to wait for one by one."""
+        e = ids_ref[c]
+        return [pltpu.make_async_copy(hbm.at[layer, e], buf,
+                                      sems.at[slot, o])
+                for o, (hbm, buf) in enumerate((
+                    (gate_hbm, gu_buf.at[slot, 0]),
+                    (up_hbm, gu_buf.at[slot, 1]),
+                    (down_hbm, d_buf.at[slot])))]
+
+    def scale_row(ref, c):
+        """Row c of a [M8, w] block as [1, w]. Mosaic loads a dynamic
+        sublane only at a multiple of 8: take the aligned group of 8
+        rows and keep the one."""
+        base = pl.multiple_of(jax.lax.div(c, 8) * 8, 8)
+        rows = ref[pl.ds(base, 8), :]
+        keep = jax.lax.broadcasted_iota(jnp.int32, rows.shape,
+                                        0) == c - base
+        return jnp.sum(jnp.where(keep, rows, 0.0), axis=0, keepdims=True)
+
+    @pl.when(count > 0)
+    def _first():
+        for cp in copies(0, 0):
+            cp.start()
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    x = x_ref[...]
+
+    def expert(c, carry):
+        slot = jax.lax.rem(c, 2)
+        e = ids_ref[c]
+
+        @pl.when(c + 1 < count)
+        def _next():
+            for cp in copies(c + 1, 1 - slot):
+                cp.start()
+
+        # this expert's combine column [N, 1]: its routing weight on
+        # the rows that chose it, zero on the others
+        comb = jnp.sum(jnp.where(ti_ref[...] == e, tp_ref[...], 0.0),
+                       axis=1, keepdims=True)
+        sg, su, sd = ((scale_row(ref, c) for ref in scale_refs)
+                      if quant else (None,) * 3)
+
+        def dot(a, w, scale):
+            """a @ w in float32, w converted to the operands' dtype in
+            VMEM and its per-channel scale applied to the products (the
+            compiler tiles the width: panels of 128 to 512 channels
+            cut by hand ran a layer within 1 % of this; PERF.md, PR
+            34)."""
+            y = jnp.dot(a, w.astype(cdt),
+                        preferred_element_type=jnp.float32)
+            return y * scale if quant else y
+
+        # each matrix is waited for where it is first read: gate's
+        # products run under up's and down's copies
+        gate_copy, up_copy, down_copy = copies(c, slot)
+        gate_copy.wait()
+        g = dot(x, gu_buf[slot, 0], sg)
+        up_copy.wait()
+        a = (act(g) * dot(x, gu_buf[slot, 1], su)).astype(cdt)  # [N, i]
+        down_copy.wait()
+        y = dot(a, d_buf[slot], sd)
+        acc_ref[...] += y * comb
+        return carry
+
+    jax.lax.fori_loop(0, count, expert, 0)
+    out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
+    """The experts on the list over all tokens, combined by routing
+    weight: ``_moe_exact``'s result (an expert no valid row chose
+    contributes exactly zero there), reading ``count`` experts' weights
+    where that reads all E. gate/up [L, E, h, i], down [L, E, i, h]
+    (raw or int8-quantized), layer: int32 scalar, traced."""
+    quant = _quant().is_quantized(gate)
+    N, h = x.shape
+    k = top_i.shape[1]
+    L, E, _, inter = _wshape(gate)
+    mats = [w["w8"] if quant else w for w in (gate, up, down)]
+
+    def whole(*_):
+        return (0, 0)
+
+    in_specs = [pl.BlockSpec((N, h), whole),
+                pl.BlockSpec((N, k), whole),
+                pl.BlockSpec((N, k), whole)]
+    in_specs += [pl.BlockSpec(memory_space=pltpu.HBM)] * 3
+    operands = [x, top_i, top_p.astype(jnp.float32)] + mats
+    if quant:
+        # the listed experts' scale rows, gathered out of the stacks
+        # (1.2 MB a layer beside the experts' 8.65 MB each)
+        rows = jnp.pad(ids, (0, -ids.shape[0] % 8))
+        for w in (gate, up, down):
+            sc = w["scale"][layer, rows]                  # [M8, w]
+            in_specs.append(pl.BlockSpec(sc.shape, whole))
+            operands.append(sc)
+    with jax.named_scope("moe_experts"):
+        return pl.pallas_call(
+            functools.partial(_moe_list_kernel, act=act, quant=quant),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(1,),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec((N, h), whole),
+                scratch_shapes=[
+                    pltpu.VMEM((2, 2, h, inter), mats[0].dtype),
+                    pltpu.VMEM((2, inter, h), mats[2].dtype),
+                    pltpu.SemaphoreType.DMA((2, 3)),
+                    pltpu.VMEM((N, h), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((N, h), x.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
+            interpret=pallas_paged.needs_interpret(),
+            name="moe_list_experts",
+        )(ids, count.reshape(1),
+          jnp.asarray(layer, jnp.int32).reshape(1), *operands)
 
 
 def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
@@ -158,12 +425,14 @@ def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
 
 def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             up: jnp.ndarray, down: jnp.ndarray, *, top_k: int,
-            capacity_factor: float = 2.0, dense_threshold: int = 64,
+            capacity_factor: float = 2.0,
+            dense_threshold: int = DENSE_THRESHOLD,
             act: Callable = jax.nn.silu, valid=None,
             exact=None, renormalize: bool = True,
-            capacity_tokens=None) -> jnp.ndarray:
+            capacity_tokens=None, layer=None):
     """MoE feed-forward. x [N, h]; router_w [h, E]; gate/up [E, h, i];
-    down [E, i, h]. Returns [N, h] in x.dtype.
+    down [E, i, h]. Returns ([N, h] in x.dtype, the experts whose
+    weights the call read: an int32 scalar).
 
     valid [N] bool marks real tokens: padding rows contribute nothing
     and never consume expert capacity. exact=True forces the all-expert
@@ -177,18 +446,36 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
     holds as much per expert as it did among the parked rows of a full
     one, and where that covers its N tokens (Qwen1.5-MoE, 256 tokens:
     552) it takes the exact path and drops nothing.
+    layer (int32 scalar, traced): gate/up/down are the whole stacks
+    [L, E, ...] of which that layer is read in place, by the list path.
+    Where ``list_path`` says so and nowhere else (models/llama.forward
+    asks it, with the mesh, before it hands the stacks over); it is the
+    exact path's sum, so ``exact`` False is refused and the capacity
+    arguments do not apply.
     """
     N = x.shape[0]
-    E = _wshape(gate)[0]
+    E = _wshape(gate)[-3]
     with jax.named_scope("moe_router"):
         top_p, top_i = route(x, router_w, top_k, renormalize=renormalize)
         if valid is not None:
             top_p = top_p * valid.astype(top_p.dtype)[:, None]
+    if layer is not None:
+        h, inter = _wshape(gate)[-2:]
+        assert exact is not False and list_path(
+            N, 1, h, inter, stored_dtype(gate), x.dtype), (
+            "moe_mlp was handed whole stacks where list_path says no: "
+            f"{N} rows, experts [{h}, {inter}], exact={exact}")
+        with jax.named_scope("moe_list"):
+            ids, count = experts_hit(top_i, valid, E)
+        return _moe_list(x, top_p, top_i, gate, up, down, act, ids,
+                         count, layer), count
     capacity = min(N, capacity_for(capacity_tokens or N, E, top_k,
                                    capacity_factor))
     if exact is None:
         exact = N <= dense_threshold or capacity >= N
     if exact:
-        return _moe_exact(x, top_p, top_i, gate, up, down, act)
-    return _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
-                         valid=valid)
+        y = _moe_exact(x, top_p, top_i, gate, up, down, act)
+    else:
+        y = _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
+                          valid=valid)
+    return y, jnp.int32(E)

@@ -258,12 +258,14 @@ def tpu_branches(monkeypatch):
     monkeypatch.setattr(pallas_paged, "needs_interpret", lambda: False)
 
 
-def _runner_shapes(topo, tp: int, layers=None, kv_blocks=None):
+def _runner_shapes(topo, tp: int, layers=None, kv_blocks=None,
+                   model: str = "mistral-7b"):
     """A ModelRunner skeleton (no arrays: a described device cannot
     hold one) plus ShapeDtypeStructs of its params and KV pool at
     Mistral-7B widths — int8 weights, all 32 layers, the server's
     default geometry — placed as the runner places them. layers and
-    kv_blocks cut the depth and the pool, and nothing else."""
+    kv_blocks cut the depth and the pool, and nothing else; model
+    names another preset's widths."""
     import dataclasses
     from production_stack_tpu.engine.config import EngineConfig
     from production_stack_tpu.engine.runner import ModelRunner
@@ -275,10 +277,10 @@ def _runner_shapes(topo, tp: int, layers=None, kv_blocks=None):
         cache_pspec, param_shardings)
 
     mesh, (_, _, rep_sh, _) = _placements(topo, tp)
-    mcfg = get_config("mistral-7b")
+    mcfg = get_config(model)
     if layers:
         mcfg = dataclasses.replace(mcfg, num_layers=layers)
-    ecfg = EngineConfig(model="mistral-7b", quantization="int8")
+    ecfg = EngineConfig(model=model, quantization="int8")
     runner = ModelRunner.__new__(ModelRunner)
     runner.model_cfg, runner.engine_cfg, runner.mesh = mcfg, ecfg, mesh
     runner._lora, runner._lora_scaling = None, 1.0
@@ -337,10 +339,11 @@ def _fits(compiled, what: str) -> None:
     assert need < HBM_BYTES, what
 
 
-def _compile_decode_window(runner, params, cache, rep):
+def _compile_decode_window(runner, params, cache, rep, rows: int = 0):
     """A greedy decode window of 8 steps at the first kv bucket, the
-    pool donated, as the runner jits it."""
-    B = runner.engine_cfg.max_num_seqs
+    pool donated, as the runner jits it, at the batch bucket ``rows``
+    (0: all max_num_seqs)."""
+    B = rows or runner.engine_cfg.max_num_seqs
     a = _step_args(runner, rep, B)
     fn = jax.jit(partial(runner._decode_impl, steps=8, kv_len=512,
                          greedy=True), donate_argnums=(1,))
@@ -407,6 +410,144 @@ def test_step_program_never_copies_the_pool(topo, tpu_branches,
     # and the pool is one buffer from argument to result
     pool_bytes = 2 * L * N * 8 * BS * D * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+# ---------------------------------------------------------------------
+# the experts of a decode step (ops/moe.py, the list path)
+# ---------------------------------------------------------------------
+
+QWEN_MOE = dict(E=60, h=2048, i=1408, k=4)    # Qwen1.5-MoE-A2.7B
+
+
+@pytest.mark.parametrize("weights,N", [
+    ("int8", 16), ("bf16", 16), ("int8", 1), ("int8", 4), ("int8", 64)])
+def test_moe_list_kernel_compiles(topo, tpu_branches, weights, N):
+    """The list path's kernel at the Qwen cell's widths (60 experts of
+    2048 x 1408, top-4) on a 12-layer stack with the layer as an
+    operand, as models/llama.forward calls it, at the cell's 16 rows,
+    at the smallest decode batch buckets and at the most rows the rule
+    sends it (moe.DENSE_THRESHOLD): two slots of three 2.88 MB int8
+    matrices (5.77 MB in bf16) and the converted copy have to fit the
+    VMEM limit, and the copies' slices the tiling."""
+    from production_stack_tpu.ops import moe
+    L = 12
+    assert moe.list_path(N, 1, QWEN_MOE["h"], QWEN_MOE["i"],
+                         jnp.dtype(weights if weights == "int8"
+                                   else jnp.bfloat16), jnp.bfloat16)
+    E, h, i, k = (QWEN_MOE[n] for n in "Ehik")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def stack(dims):
+        if weights == "bf16":
+            return shape(dims, jnp.bfloat16)
+        return {"w8": shape(dims, jnp.int8),
+                "scale": shape(dims[:2] + dims[3:], jnp.float32)}
+
+    def call(x, top_p, top_i, gate, up, down, ids, count, layer):
+        return moe._moe_list(x, top_p, top_i, gate, up, down,
+                             jax.nn.silu, ids, count, layer)
+
+    lowered = jax.jit(call).lower(
+        shape((N, h), jnp.bfloat16), shape((N, k), jnp.float32),
+        shape((N, k), jnp.int32), stack((L, E, h, i)),
+        stack((L, E, h, i)), stack((L, E, i, h)),
+        shape((min(E, N * k),), jnp.int32), shape((), jnp.int32),
+        shape((), jnp.int32))
+    hlo = lowered.compile().as_text()
+    assert "moe_list_experts" in hlo and "tpu_custom_call" in hlo
+
+
+def _stack_makers(hlo: str, dims: str) -> list:
+    """Instructions of the optimised HLO whose result has the
+    dimensions ``dims`` (a regex) and which are more than a name for
+    an operand (parameter, get-tuple-element, bitcast): "name: opcode"
+    each. A fusion that yields such an array is a copy of it, whatever
+    its name."""
+    import re
+    result = re.compile(
+        r"([\w.\-]+) = \(?\w+\[(?:{})\]\S* ([\w\-]+)\(".format(dims))
+    return [m.group(1) + ": " + m.group(2)
+            for m in map(result.search, hlo.splitlines()) if m
+            and m.group(2) not in ("parameter", "get-tuple-element",
+                                   "bitcast")]
+
+
+def test_decode_window_reads_the_experts_in_place(topo, tpu_branches):
+    """One decode window of the runner at the Qwen1.5-MoE geometry with
+    two layers, compiled whole: the expert matmuls are the list path's
+    custom call, and nothing in the optimised HLO yields an array of
+    an expert stack's shape [2, 60, 2048, 1408] / [2, 60, 1408, 2048]
+    or of one layer's: no copy, no slice, no fusion. Handed a layer as the layer scan's xs, the custom call
+    would be handed a 173 MB copy of each matrix stack per layer
+    (it cannot fuse its operand's slice; Mistral's
+    ``constant_dynamic-slice_fusion.6 s8[1,4096,4096]`` is that kind
+    of copy), so models/llama.forward closes over the stacks."""
+    L = 2
+    runner, params, cache, rep = _runner_shapes(
+        topo, 1, layers=L, kv_blocks=97, model="qwen1.5-moe-a2.7b")
+    hlo = _compile_decode_window(runner, params, cache, rep).as_text()
+    assert "moe_list_experts" in hlo
+    E, h, i = (QWEN_MOE[n] for n in "Ehi")
+    stack = r"(?:(?:{}|1),)?{},(?:{},{}|{},{})".format(L, E, h, i, i, h)
+    made = _stack_makers(hlo, stack)
+    assert not made, made
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_mixtral_decode_window_keeps_the_exact_path(topo, tpu_branches,
+                                                    rows):
+    """Mixtral-8x7B unsharded, two layers: one expert matrix is 58.7 MB
+    in int8, two slots of three miss VMEM (moe.list_path), so a decode
+    window of a small batch bucket (4 rows x top-2 hit 5.3 of 8 experts
+    under even routing, one row 2: shares a list would pay for)
+    compiles the exact path, as it did."""
+    runner, params, cache, rep = _runner_shapes(
+        topo, 1, layers=2, kv_blocks=97, model="mixtral-8x7b")
+    hlo = _compile_decode_window(runner, params, cache, rep,
+                                 rows=rows).as_text()
+    assert "moe_list_experts" not in hlo and "moe_experts" in hlo
+
+
+def test_qwen_speculative_window_keeps_the_exact_path(topo,
+                                                      tpu_branches):
+    """A speculative window of 4 rows x 4 positions holds as many
+    tokens as a decode batch and is not the list path's: that is the
+    decode step's (one position a row), the only program measured with
+    it. (A prefill chunk, of whatever length: tests/test_moe.py.)"""
+    runner, params, cache, rep = _runner_shapes(
+        topo, 1, layers=2, kv_blocks=97, model="qwen1.5-moe-a2.7b")
+    B, K = 4, 3
+    a = _step_args(runner, rep, B)
+    fn = jax.jit(partial(runner._decode_spec_impl, steps=4,
+                         kv_len=512, spec=K), donate_argnums=(1,))
+    hlo = fn.lower(
+        params, cache, a["tables"], rep((B,), jnp.int32),
+        rep((B,), jnp.int32), rep((B, 64), jnp.int32),
+        rep((B,), jnp.bool_), a["sampling"], a["key"],
+        a["guide_next"], a["guide_id"], a["guide_state"],
+        a["counts"], a["seen"]).compile().as_text()
+    assert "moe_list_experts" not in hlo and "moe_experts" in hlo
+
+
+def test_dense_decode_window_has_no_expert_call(topo, tpu_branches):
+    """The dense model's decode window knows nothing of the list path:
+    its only custom call is the attention kernel's, and no instruction
+    carries a ``moe_`` scope. (That its optimised HLO is the parent
+    commit's, instruction for instruction, was read off both trees'
+    compiles for PR 34: PERF.md.)"""
+    import re
+    runner, params, cache, rep = _runner_shapes(topo, 1, layers=2,
+                                                kv_blocks=97)
+    hlo = _compile_decode_window(runner, params, cache, rep).as_text()
+    calls = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]+)[\w.\-]* = \S+ custom-call\(", hlo)
+        if "tpu_custom_call" in hlo}
+    kernels = {c for c in calls if c.startswith(("paged", "moe"))}
+    assert kernels == {"paged_decode_attention"}, calls
+    assert "moe_" not in hlo
 
 
 @pytest.mark.slow

@@ -38,6 +38,31 @@ class _Clock:
         return self.t
 
 
+@pytest.mark.parametrize("read,resident", [(96, 96), (60, 96), (0, 96)])
+def test_window_bytes_leave_out_the_experts_not_read(read, resident):
+    """A MoE window's modelled bytes are the steps' whole weight set
+    less the experts off the steps' lists (ops/moe.py, the list path),
+    and ``totals.moe`` sums what note_window was told; a dense engine
+    (expert_bytes 0) reports no ``moe`` and subtracts nothing."""
+    acct = EngineEffAccounting(weight_bytes=1000, kv_position_bytes=10,
+                               expert_bytes=7)
+    dense = EngineEffAccounting(weight_bytes=1000, kv_position_bytes=10)
+    window = dict(steps=8, positions=1, batch=4, live_rows=4, kv_len=100,
+                  real=32, pad=0, dead=0, window_s=0.5)
+    for _ in range(2):
+        acct.note_window(**window, experts_read=read,
+                         experts_resident=resident)
+        dense.note_window(**window)
+    whole = 2 * 8 * (1000 + 4 * 10 * 100)
+    r = acct.report()
+    assert r["moe"] == {"experts_read": 2 * read,
+                        "experts_resident": 2 * resident}
+    assert r["bytes_total"] == whole - 2 * (resident - read) * 7
+    assert r["bytes_effective"] == r["bytes_total"]
+    assert "moe" not in dense.report()
+    assert dense.report()["bytes_total"] == whole
+
+
 def test_window_accounting_reconciles_with_injected_clock():
     """A steady synthetic stream of windows: kind totals must equal the
     independent token_steps_total, and the ring-derived rates must

@@ -173,8 +173,8 @@ def test_forward_logits_close_to_bf16_cache():
     def run(dtype):
         cache = make_cache(cfg.num_layers, n_blocks, Bs,
                            cfg.num_kv_heads, cfg.head_dim_, dtype=dtype)
-        logits, _ = llama.forward(params, cfg, tokens, positions, cache,
-                                  block_tables=tables, kv_len=32)
+        logits, _, _ = llama.forward(params, cfg, tokens, positions, cache,
+                                     block_tables=tables, kv_len=32)
         return np.asarray(logits, np.float32)
 
     ref = run(jnp.float32)

@@ -55,7 +55,8 @@ def test_incremental_decode_matches_hf(tiny_pair):
     cache, tables = make_slot_cache(cfg.num_layers, 1, 64, cfg.num_kv_heads, cfg.head_dim_,
                        dtype=jnp.float32)
     pos = jnp.arange(10)[None, :]
-    logits, cache = llama.forward(params, cfg, jnp.asarray(prompt), pos, cache)
+    logits, cache, _ = llama.forward(params, cfg, jnp.asarray(prompt), pos,
+                                     cache)
 
     seq = list(prompt[0])
     for step in range(5):
@@ -63,7 +64,7 @@ def test_incremental_decode_matches_hf(tiny_pair):
         seq.append(nxt)
         with torch.no_grad():
             ref = hf_model(torch.tensor([seq])).logits[0, -1].numpy()
-        logits, cache = llama.forward(
+        logits, cache, _ = llama.forward(
             params, cfg, jnp.asarray([[nxt]]),
             jnp.asarray([[len(seq) - 1]]), cache)
         np.testing.assert_allclose(
@@ -133,7 +134,7 @@ def test_qwen2_incremental_decode_matches_full(tiny_qwen2_pair):
                        cfg.head_dim_, dtype=jnp.float32)
     outs = []
     for t in range(toks.shape[1]):
-        logits, cache = llama.forward(
+        logits, cache, _ = llama.forward(
             params, cfg, jnp.asarray(toks[:, t:t + 1]),
             jnp.asarray([[t]]), cache)
         outs.append(np.asarray(logits)[:, 0])
@@ -216,7 +217,7 @@ def test_mixtral_incremental_decode_matches_full(tiny_mixtral_pair):
                        cfg.head_dim_, dtype=jnp.float32)
     outs = []
     for t in range(toks.shape[1]):
-        logits, cache = llama.forward(
+        logits, cache, _ = llama.forward(
             params, cfg, jnp.asarray(toks[:, t:t + 1]),
             jnp.asarray([[t]]), cache)
         outs.append(np.asarray(logits)[:, 0])
@@ -282,7 +283,7 @@ def test_qwen2_moe_incremental_decode_matches_full(tiny_qwen2_moe_pair):
                        cfg.head_dim_, dtype=jnp.float32)
     outs = []
     for t in range(toks.shape[1]):
-        logits, cache = llama.forward(
+        logits, cache, _ = llama.forward(
             params, cfg, jnp.asarray(toks[:, t:t + 1]),
             jnp.asarray([[t]]), cache)
         outs.append(np.asarray(logits)[:, 0])

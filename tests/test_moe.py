@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models import ModelConfig, llama
-from production_stack_tpu.ops import moe
+from production_stack_tpu.models import quant
+from production_stack_tpu.ops import moe, pallas_paged
 from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
 from production_stack_tpu.parallel.sharding import shard_params
 
@@ -34,7 +35,8 @@ def _rand_moe(key, N=96, h=32, E=4, i=64):
     return x, rw, g, u, d
 
 
-def _reference_moe(x, rw, g, u, d, k, capacity=None, valid=None):
+def _reference_moe(x, rw, g, u, d, k, capacity=None, valid=None,
+                   renormalize=True):
     """Per-token numpy loop: softmax-all, top-k, renormalize, run the
     selected experts one by one. Independent of ops/moe.py's vectorized
     dispatch. capacity simulates per-expert slots filled in token-major
@@ -52,7 +54,7 @@ def _reference_moe(x, rw, g, u, d, k, capacity=None, valid=None):
         p = np.exp(logits - logits.max())
         p /= p.sum()
         top = np.argsort(-p)[:k]
-        w = p[top] / p[top].sum()
+        w = p[top] / p[top].sum() if renormalize else p[top]
         for wi, e in zip(w, top):
             if capacity is not None:
                 if counts[e] >= capacity:
@@ -75,7 +77,7 @@ def test_route_weights_normalized():
 
 def test_exact_path_matches_reference():
     x, rw, g, u, d = _rand_moe(jax.random.PRNGKey(1))
-    got = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1000)
+    got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1000)
     np.testing.assert_allclose(np.asarray(got),
                                _reference_moe(x, rw, g, u, d, 2),
                                atol=1e-4, rtol=1e-4)
@@ -85,7 +87,7 @@ def test_dispatch_path_matches_reference():
     x, rw, g, u, d = _rand_moe(jax.random.PRNGKey(2))
     # capacity_factor 1.6 -> capacity < N (dispatch branch) but above the
     # realized max expert load for this seed, so no token is dropped
-    got = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
+    got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
                       capacity_factor=1.6)
     cap = moe.capacity_for(x.shape[0], 4, 2, 1.6)
     assert cap < x.shape[0], "capacity must not force the exact branch"
@@ -98,7 +100,7 @@ def test_dispatch_with_drops_matches_reference():
     """Over-capacity assignments drop in token-major rank order — the
     numpy reference simulates the same fill and must agree exactly."""
     x, rw, g, u, d = _rand_moe(jax.random.PRNGKey(3))
-    got = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
+    got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
                       capacity_factor=0.5)
     cap = moe.capacity_for(x.shape[0], 4, 2, 0.5)
     ref = _reference_moe(x, rw, g, u, d, 2, capacity=cap)
@@ -115,13 +117,13 @@ def test_padding_never_routes_or_steals_capacity():
     valid = np.zeros(N, bool)
     valid[: N // 3] = True          # 2/3 of the batch is padding
     cap = moe.capacity_for(N, 4, 2, 0.5)
-    got = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
+    got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
                       capacity_factor=0.5, valid=jnp.asarray(valid))
     ref = _reference_moe(x, rw, g, u, d, 2, capacity=cap, valid=valid)
     np.testing.assert_allclose(np.asarray(got), ref, atol=1e-4, rtol=1e-4)
     assert (np.asarray(got)[~valid] == 0).all()
     # exact path masks padding too
-    got_exact = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1000,
+    got_exact, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1000,
                             valid=jnp.asarray(valid))
     assert (np.asarray(got_exact)[~valid] == 0).all()
 
@@ -129,7 +131,7 @@ def test_padding_never_routes_or_steals_capacity():
 def test_exact_flag_overrides_capacity():
     """exact=True (the decode path) never drops, whatever N/capacity."""
     x, rw, g, u, d = _rand_moe(jax.random.PRNGKey(7))
-    got = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
+    got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
                       capacity_factor=0.5, exact=True)
     np.testing.assert_allclose(np.asarray(got),
                                _reference_moe(x, rw, g, u, d, 2),
@@ -153,11 +155,11 @@ def test_small_prefill_reckons_capacity_on_the_full_batch(rows, bucket):
     assert (np.asarray(top_i)[:, 0] == 0).all()
     assert moe.capacity_for(N, E, k, 2.0) < N
     exact = moe._moe_exact(x, top_p, top_i, g, u, d, jax.nn.silu)
-    got = moe.moe_mlp(x, rw, g, u, d, top_k=k, renormalize=False,
+    got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=k, renormalize=False,
                       capacity_tokens=16 * bucket)
     np.testing.assert_allclose(np.asarray(got), np.asarray(exact),
                                atol=1e-5, rtol=1e-5)
-    own = moe.moe_mlp(x, rw, g, u, d, top_k=k, renormalize=False)
+    own, _ = moe.moe_mlp(x, rw, g, u, d, top_k=k, renormalize=False)
     lost = np.abs(np.asarray(own) - np.asarray(exact)).max(-1) > 1e-3
     assert lost.sum() >= N - moe.capacity_for(N, E, k, 2.0)
 
@@ -277,3 +279,257 @@ def test_encode_moe_ignores_padding_content():
     h_b = np.asarray(llama.encode(params, cfg, jnp.asarray(toks_b),
                                   token_valid=jnp.asarray(mask)))
     np.testing.assert_array_equal(h_a[mask], h_b[mask])
+
+
+# ---------------------------------------------------------------------
+# the list path (ops/moe.py): the decode step's expert matmuls walk the
+# experts its valid rows were routed to, in place in the weight stacks.
+# The Pallas kernel runs in interpret mode here.
+# ---------------------------------------------------------------------
+
+@pytest.fixture
+def kernels_on():
+    pallas_paged.set_flash_enabled(True)
+    yield
+    pallas_paged.set_flash_enabled(None)
+
+
+def _rigged_stacks(key, routing, *, N=16, E=60, k=4, h=128, i=256, L=2,
+                   weights="int8"):
+    """x [N, h] bf16, a router and [L, E, ...] expert stacks at the
+    Qwen cell's routing shape and small widths. ``routing`` rigs the
+    router: feature n of row n is large, and the router's row n points
+    at the experts that row is to choose ("random": nothing rigged;
+    "same4": every row the experts 0-3; "all": row n the experts
+    4n..4n+3 mod E, which 16 rows spread over all 60)."""
+    ks = jax.random.split(key, 5)
+    x = jax.random.normal(ks[0], (N, h), jnp.float32)
+    rw = jax.random.normal(ks[1], (h, E), jnp.float32) * 0.2
+    if routing != "random":
+        x = x.at[jnp.arange(N), jnp.arange(N)].set(30.0)
+        for n in range(N):
+            first = 0 if routing == "same4" else 4 * n
+            rw = rw.at[n, (first + jnp.arange(k)) % E].add(5.0)
+    stacks = [jax.random.normal(kk, shape, jnp.float32) * 0.1
+              for kk, shape in zip(ks[2:], ((L, E, h, i), (L, E, h, i),
+                                           (L, E, i, h)))]
+    stacks = [w.astype(jnp.bfloat16) for w in stacks]
+    if weights == "int8":
+        stacks = [quant.quantize_tensor(w) for w in stacks]
+    return x.astype(jnp.bfloat16), rw.astype(jnp.bfloat16), stacks
+
+
+def _float32(w):
+    """A raw or int8 weight as the float32 array it stands for."""
+    if quant.is_quantized(w):
+        return (np.asarray(w["w8"], np.float32)
+                * np.asarray(w["scale"], np.float32)[..., None, :])
+    return np.asarray(w.astype(jnp.float32))
+
+
+LIST_CASES = {
+    # name: (routing, rows, valid rows, weights, renormalize)
+    "int8": ("random", 16, 16, "int8", False),
+    "bf16-weights": ("random", 16, 16, "bf16", False),
+    "one-row": ("random", 1, 1, "int8", False),
+    "parked-row": ("random", 16, 15, "int8", False),
+    "no-valid-row": ("random", 16, 0, "int8", False),
+    "same-4-experts": ("same4", 16, 16, "int8", False),
+    "all-60-hit": ("all", 16, 16, "int8", False),
+    "renormalized": ("random", 16, 15, "int8", True),
+}
+
+
+@pytest.mark.parametrize("case", LIST_CASES)
+def test_list_path_matches_exact_and_reference(kernels_on, case):
+    """moe_mlp handed the whole stacks and a layer (the list path)
+    against _moe_exact on that layer's slice and against the float32
+    per-token reference; the experts it reports reading are the
+    distinct experts its valid rows chose."""
+    routing, N, n_valid, weights, renorm = LIST_CASES[case]
+    E, k, layer = 60, 4, 1
+    x, rw, stacks = _rigged_stacks(jax.random.PRNGKey(11), routing, N=N,
+                                   weights=weights)
+    valid = jnp.arange(N) < n_valid
+    got, read = jax.jit(lambda x, *w: moe.moe_mlp(
+        x, rw, *w, top_k=k, valid=valid, renormalize=renorm,
+        exact=True, layer=jnp.int32(layer)))(x, *stacks)
+
+    top_p, top_i = moe.route(x, rw, k, renormalize=renorm)
+    chosen = np.unique(np.asarray(top_i)[:n_valid])
+    assert int(read) == len(chosen)
+    assert len(chosen) == {"same4": 4, "all": 60}.get(routing, len(chosen))
+    one = [jax.tree_util.tree_map(lambda a: a[layer], w) for w in stacks]
+    exact = moe._moe_exact(x, top_p * valid[:, None], top_i, *one,
+                           jax.nn.silu)
+    ref = _reference_moe(x.astype(jnp.float32), rw.astype(jnp.float32),
+                         *(_float32(w) for w in one), k,
+                         valid=np.asarray(valid), renormalize=renorm)
+    got = np.asarray(got.astype(jnp.float32))
+    scale = max(np.abs(ref).max(), 1e-6)
+    # bf16 activations on both paths; the list path rounds less often
+    assert np.abs(got - np.asarray(exact.astype(jnp.float32))).max() \
+        < 0.03 * scale
+    assert np.abs(got - ref).max() < 0.02 * scale
+    assert (got[n_valid:] == 0).all()
+
+
+@pytest.mark.parametrize("case", ["all-valid", "parked-rows",
+                                  "one-row", "none-valid",
+                                  "more-assignments-than-experts"])
+def test_experts_hit_lists_distinct_experts_of_valid_rows(case):
+    rng = np.random.default_rng(5)
+    N, k, E = {"one-row": (1, 4, 60),
+               "more-assignments-than-experts": (16, 2, 8)}.get(
+                   case, (16, 4, 60))
+    top_i = np.stack([rng.choice(E, k, replace=False) for _ in range(N)])
+    valid = np.ones(N, bool)
+    if case == "parked-rows":
+        valid[[3, 15]] = False
+    if case == "none-valid":
+        valid[:] = False
+    ids, count = jax.jit(lambda t, v: moe.experts_hit(t, v, E))(
+        jnp.asarray(top_i, jnp.int32), jnp.asarray(valid))
+    want = np.unique(top_i[valid])
+    assert ids.shape == (min(E, N * k),) and ids.dtype == jnp.int32
+    assert int(count) == len(want)
+    np.testing.assert_array_equal(np.asarray(ids)[:len(want)], want)
+    assert (np.asarray(ids)[len(want):] == 0).all()
+    # valid=None: every row counts
+    _, count_all = moe.experts_hit(jnp.asarray(top_i, jnp.int32), None, E)
+    assert int(count_all) == len(np.unique(top_i))
+
+
+QWEN = (2048, 1408)            # Qwen1.5-MoE-A2.7B's experts [h, i]
+MIXTRAL = (4096, 14336)        # Mixtral-8x7B's
+
+
+@pytest.mark.parametrize("what,rows,positions,widths,mesh,kernels,want", [
+    ("qwen decode, 16 rows", 16, 1, QWEN, None, True, True),
+    ("qwen decode, one row", 1, 1, QWEN, None, True, True),
+    ("qwen decode, 64 rows", 64, 1, QWEN, None, True, True),
+    ("qwen decode, 128 rows", 128, 1, QWEN, None, True, False),
+    ("qwen prefill chunk", 1, 256, QWEN, None, True, False),
+    ("qwen one-row prefill of 16 tokens", 1, 16, QWEN, None, True, False),
+    ("qwen speculative window", 4, 4, QWEN, None, True, False),
+    ("mixtral decode, 16 rows", 16, 1, MIXTRAL, None, True, False),
+    ("mixtral decode, 4 rows", 4, 1, MIXTRAL, None, True, False),
+    ("mixtral decode, 2 rows", 2, 1, MIXTRAL, None, True, False),
+    ("mixtral decode, one row", 1, 1, MIXTRAL, None, True, False),
+    ("ep mesh", 16, 1, QWEN, dict(dp=1, ep=2, tp=1), True, False),
+    ("tp mesh", 16, 1, QWEN, dict(dp=1, ep=1, tp=2), True, False),
+    ("mesh of one device", 16, 1, QWEN, dict(dp=1, ep=1, tp=1), True,
+     True),
+    ("kernels off (the CPU)", 16, 1, QWEN, None, False, False),
+])
+def test_list_path_rule(what, rows, positions, widths, mesh, kernels,
+                        want):
+    """The path follows from the rows and positions, the experts'
+    widths and the mesh (and whether Pallas kernels run at all): no
+    option selects it."""
+    pallas_paged.set_flash_enabled(kernels)
+    try:
+        if mesh is not None:
+            mesh = build_mesh(MeshConfig(**mesh),
+                              devices=jax.devices()[:np.prod(
+                                  list(mesh.values()))])
+        assert moe.list_path(rows, positions, *widths, jnp.int8,
+                             jnp.bfloat16, mesh) is want
+    finally:
+        pallas_paged.set_flash_enabled(None)
+
+
+@pytest.mark.parametrize("widths,weights,fits", [
+    (QWEN, jnp.int8, True), (QWEN, jnp.bfloat16, True),
+    (MIXTRAL, jnp.int8, False), (MIXTRAL, jnp.bfloat16, False),
+    ((4096, 1408), jnp.bfloat16, False)])
+def test_list_path_needs_experts_that_fit_vmem(kernels_on, widths,
+                                               weights, fits):
+    """Two slots of an expert's three matrices as stored, and one
+    converted to the activation dtype, in half of the kernel's VMEM
+    limit: what does not fit keeps the exact path, which compiles."""
+    need = moe.list_scratch_bytes(*widths, weights, jnp.bfloat16)
+    h, i = widths
+    assert need == h * i * (6 * jnp.dtype(weights).itemsize
+                            + (2 if weights == jnp.int8 else 0))
+    assert (need <= pallas_paged.VMEM_LIMIT_BYTES // 2) is fits
+    assert moe.list_path(16, 1, *widths, weights, jnp.bfloat16) is fits
+
+
+def test_list_path_needs_widths_that_tile(kernels_on):
+    assert moe.list_path(16, 1, 2048, 1408, jnp.int8, jnp.bfloat16)
+    assert not moe.list_path(16, 1, 2048, 1400, jnp.int8, jnp.bfloat16)
+    assert not moe.list_path(16, 1, 64, 128, jnp.int8, jnp.bfloat16)
+
+
+def test_moe_mlp_refuses_stacks_where_the_rule_says_no(kernels_on):
+    """Whole stacks and a layer are the list path's operands: handed
+    them at a size ``list_path`` refuses (more rows than a decode
+    batch), or with exact=False, moe_mlp raises instead of walking a
+    list nobody chose."""
+    x, rw, stacks = _rigged_stacks(jax.random.PRNGKey(3), "random",
+                                   N=moe.DENSE_THRESHOLD + 8)
+    with pytest.raises(AssertionError, match="list_path says no"):
+        moe.moe_mlp(x, rw, *stacks, top_k=4, layer=jnp.int32(0))
+    with pytest.raises(AssertionError, match="list_path says no"):
+        moe.moe_mlp(x[:16], rw, *stacks, top_k=4, exact=False,
+                    layer=jnp.int32(0))
+
+
+def test_forward_takes_the_list_path_in_place(kernels_on):
+    """llama.forward over one position per row of a many-expert model:
+    the logits of the list path against those of the exact path (the
+    kernels off), and the experts it reports reading against the
+    layers x experts the exact path reads."""
+    cfg = ModelConfig(name="t-moe16", vocab_size=128, hidden_size=128,
+                      intermediate_size=128, num_layers=2, num_heads=2,
+                      num_kv_heads=2, max_position_embeddings=64,
+                      num_experts=16, num_experts_per_tok=2,
+                      dtype=jnp.float32)
+    from production_stack_tpu.models import make_slot_cache
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    B = 2
+    toks = jnp.asarray([[5], [9]], jnp.int32)
+    pos = jnp.zeros((B, 1), jnp.int32)
+
+    def run():
+        cache, tables = make_slot_cache(
+            cfg.num_layers, B, 64, cfg.num_kv_heads, cfg.head_dim_,
+            dtype=jnp.float32)
+        return llama.forward(params, cfg, toks, pos, cache,
+                             block_tables=tables)
+
+    assert moe.list_path(B, 1, 128, 128, jnp.float32, jnp.float32)
+    logits, _, read = run()
+    pallas_paged.set_flash_enabled(False)
+    want, _, read_all = run()
+    assert int(read_all) == cfg.num_layers * cfg.num_experts
+    assert 2 * 2 <= int(read) <= 2 * B * 2 < int(read_all)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("B,T", [(1, 16), (4, 4)],
+                         ids=["one-row-prefill", "speculative-window"])
+def test_forward_of_several_positions_reads_every_expert(kernels_on, B, T):
+    """A short prefill chunk and a speculative window hold as few
+    tokens as a decode batch does, and keep today's path all the same:
+    the list path is the decode step's (one position a row)."""
+    cfg = ModelConfig(name="t-moe16", vocab_size=128, hidden_size=128,
+                      intermediate_size=128, num_layers=2, num_heads=2,
+                      num_kv_heads=2, max_position_embeddings=64,
+                      num_experts=16, num_experts_per_tok=2,
+                      dtype=jnp.float32)
+    from production_stack_tpu.models import make_slot_cache
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    cache, tables = make_slot_cache(
+        cfg.num_layers, B, 64, cfg.num_kv_heads, cfg.head_dim_,
+        dtype=jnp.float32)
+    toks = jnp.arange(B * T, dtype=jnp.int32).reshape(B, T)
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    jaxpr = jax.make_jaxpr(lambda: llama.forward(
+        params, cfg, toks, pos, cache, block_tables=tables))()
+    assert "moe_list_experts" not in str(jaxpr)
+    _, _, read = llama.forward(params, cfg, toks, pos, cache,
+                               block_tables=tables)
+    assert int(read) == cfg.num_layers * cfg.num_experts

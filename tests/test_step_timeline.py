@@ -324,6 +324,77 @@ def test_debug_perf_step_block_and_ring(served):
     assert 0 < w["host_s"] < 5 and 0 <= w["sync_s"] <= w["window_s"] + 1e-3
 
 
+def _moe_totals_after_each(model: str, prompts) -> list:
+    """``totals`` of /debug/perf after each of ``prompts`` has been
+    answered by a fresh engine serving ``model``."""
+    from production_stack_tpu.engine.async_engine import AsyncLLMEngine
+    from production_stack_tpu.engine.config import EngineConfig
+    eng = AsyncLLMEngine(EngineConfig(
+        model=model, max_model_len=128, max_num_seqs=2,
+        prefill_chunk=16, prefill_buckets=(16,)))
+
+    async def body(client):
+        seen = []
+        for prompt in prompts:
+            r = await client.post("/v1/completions", json={
+                "model": model, "max_tokens": 6, "temperature": 0.0,
+                "ignore_eos": True, "prompt": prompt})
+            assert r.status == 200
+            perf = await (await client.get("/debug/perf")).json()
+            seen.append(perf["totals"])
+        return seen
+    return _with_client(eng, body)
+
+
+def test_debug_perf_counts_the_experts_a_decode_step_read():
+    """``totals.moe`` of a MoE engine: both sums grow with decode steps,
+    resident by steps x layers x experts, and read never passes it (on
+    the CPU the kernels are off and every step reads every expert)."""
+    first, second = _moe_totals_after_each(
+        "debug-moe", ["count my experts", "and once more"])
+    for totals in (first, second):
+        moe = totals["moe"]
+        assert 0 < moe["experts_read"] <= moe["experts_resident"]
+    # debug-moe: 2 layers x 4 experts, whole windows of steps
+    grew = (second["moe"]["experts_resident"]
+            - first["moe"]["experts_resident"])
+    assert grew > 0 and grew % (2 * 4) == 0
+    assert second["moe"]["experts_read"] > first["moe"]["experts_read"]
+
+
+def test_debug_perf_has_no_expert_count_for_a_dense_model(served):
+    _, perf, _ = served
+    assert "moe" not in perf["totals"]
+    assert perf["totals"]["decode"]["windows"] > 0
+
+
+def test_debug_perf_expert_count_follows_the_list_path():
+    """A model wide enough for the list path (ops/moe.list_path; the
+    kernels forced on, in interpret mode): a decode step of two rows
+    reads at most 2 x top-2 of its 16 experts a layer, and
+    ``experts_read`` says so."""
+    import jax.numpy as jnp
+    from production_stack_tpu.models import config as model_configs
+    from production_stack_tpu.ops import pallas_paged
+    name = "t-moe16-wide"
+    model_configs.PRESETS[name] = model_configs.ModelConfig(
+        name=name, vocab_size=512, hidden_size=128,
+        intermediate_size=128, num_layers=2, num_heads=2,
+        num_kv_heads=2, max_position_embeddings=256, num_experts=16,
+        num_experts_per_tok=2, dtype=jnp.float32)
+    pallas_paged.set_flash_enabled(True)
+    try:
+        (totals,) = _moe_totals_after_each(name, ["walk the list"])
+    finally:
+        pallas_paged.set_flash_enabled(None)
+        del model_configs.PRESETS[name]
+    moe = totals["moe"]
+    steps_layers = moe["experts_resident"] // 16
+    assert steps_layers > 0
+    # one live row (and a parked one): 1 to 2 experts a layer and step
+    assert steps_layers <= moe["experts_read"] <= 2 * steps_layers
+
+
 def test_debug_profile_captures_and_refuses_a_second(engine):
     async def body(client):
         r = await client.post("/debug/profile", json={"seconds": 99999})
