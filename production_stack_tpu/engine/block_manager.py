@@ -39,11 +39,17 @@ logger = init_logger(__name__)
 class BlockManager:
     def __init__(self, num_blocks: int, block_size: int,
                  enable_prefix_caching: bool = False,
-                 namespace: str = ""):
+                 namespace: str = "", bytes_per_token: int = 0,
+                 layout: str = "kv_heads"):
         if num_blocks < 2:
             raise ValueError("pool needs at least one non-trash block")
         self.num_blocks = num_blocks          # includes trash block 0
         self.block_size = block_size
+        # what a token takes in the device pool, all layers, as
+        # allocated, and how it lies there (models/kv.KVCache:
+        # "kv_heads" | "latent"); 0 where no pool was described
+        self.bytes_per_token = bytes_per_token
+        self.layout = layout
         self.hasher = (ChunkHasher(block_size, namespace="blk|" + namespace)
                        if enable_prefix_caching else None)
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
@@ -117,6 +123,8 @@ class BlockManager:
         and ``GET /debug/perf`` both serve exactly this dict."""
         return {
             "num_blocks": self.num_blocks - 1,   # allocatable, no trash
+            "bytes_per_token": self.bytes_per_token,
+            "layout": self.layout,
             "free": self.free_blocks,
             "active": self.active_blocks,
             "cached": self.cached_blocks,

@@ -211,7 +211,9 @@ class LLMEngine:
             self.runner.cache.num_blocks, engine_cfg.kv_block_size,
             enable_prefix_caching=engine_cfg.enable_prefix_caching,
             namespace=model_fingerprint(self.model_cfg,
-                                        engine_cfg.kv_dtype))
+                                        engine_cfg.kv_dtype),
+            bytes_per_token=self.runner.cache.bytes_per_token,
+            layout=self.runner.cache.layout)
         self._tables = np.zeros((engine_cfg.max_num_seqs,
                                  engine_cfg.max_blocks_per_seq), np.int32)
         self.scheduler.can_admit = self._try_admit

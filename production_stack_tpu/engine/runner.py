@@ -43,7 +43,8 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.sampler import (SamplingParams,
                                                  adjust_logits, sample)
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.models.kv import KVCache, make_cache
+from production_stack_tpu.models.kv import (LATENT, KVCache, cache_for,
+                                            latent_pool_width)
 from production_stack_tpu.models import llama
 from production_stack_tpu.ops.pallas_paged import JNP_GATHER, attention_path
 from production_stack_tpu.ops.rope import rope_table
@@ -78,7 +79,7 @@ class ModelRunner:
         self._lora_scaling = lora_scaling
         # rope table must cover the cache length, not just the model's
         # native max (see ops/rope.py clamping note)
-        self.rope = rope_table(engine_cfg.max_model_len, model_cfg.head_dim_,
+        self.rope = rope_table(engine_cfg.max_model_len, model_cfg.rope_dim_,
                                model_cfg.rope_theta,
                                scaling=model_cfg.rope_scaling)
         if params is None:
@@ -112,10 +113,16 @@ class ModelRunner:
             n_blocks = -(-n_blocks // dp_size) * dp_size
         kv_dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
                  "int8": jnp.int8}[engine_cfg.kv_dtype]
-        self.cache: KVCache = make_cache(
-            model_cfg.num_layers, n_blocks,
-            engine_cfg.kv_block_size, model_cfg.num_kv_heads,
-            model_cfg.head_dim_, dtype=kv_dt)
+        if model_cfg.mla and mesh is not None and any(
+                size > 1 for size in mesh.shape.values()):
+            raise ValueError(
+                f"{model_cfg.name}: a latent-attention model (KV pool "
+                f"layout 'latent') runs on one chip only; mesh "
+                f"{dict(mesh.shape)} shards it (tp, ep, dp must be 1)")
+        # K and V per kv head, or the latent pool (models/kv.cache_for;
+        # it refuses an int8 latent pool by name)
+        self.cache: KVCache = cache_for(
+            model_cfg, n_blocks, engine_cfg.kv_block_size, dtype=kv_dt)
         self._tables = jnp.zeros(
             (engine_cfg.max_num_seqs, engine_cfg.max_blocks_per_seq),
             jnp.int32)
@@ -787,6 +794,11 @@ class ModelRunner:
         """ops/pallas_paged.attention_path for this model's head
         geometry and this engine's block size."""
         cfg = self.model_cfg
+        if cfg.mla:     # every head on the one cached vector a token
+            return attention_path(
+                positions, cfg.num_heads, latent_pool_width(cfg.latent_dim),
+                self.engine_cfg.kv_block_size, mesh,
+                value_dim=cfg.kv_lora_rank)
         return attention_path(
             positions, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
             self.engine_cfg.kv_block_size, mesh)
@@ -994,11 +1006,21 @@ class ModelRunner:
         blk = jnp.take(row, jnp.clip(pos // Bs, 0, MB - 1))   # [size]
         return blk, pos % Bs
 
+    def _refuse_latent(self, what: str) -> None:
+        """KV chunks on the wire are K and V per kv head
+        [L, size, Hkv, D]; the latent pool holds neither."""
+        if self.cache.layout == LATENT:
+            raise ValueError(
+                f"{what}: the KV pool of {self.model_cfg.name} has the "
+                f"layout 'latent' (one [c | k_rope] vector a token), "
+                f"which KV chunks [L, size, Hkv, D] cannot carry")
+
     def extract_chunk(self, slot: int, start: int, size: int):
         """Gather [L, size, Hkv, D] k/v out of a slot's blocks (no
         donation; the result is an independent buffer, safe to D2H after
         later steps donate the cache). Dispatch is async —
         np.asarray() later blocks."""
+        self._refuse_latent("extract_chunk")
         fn = self._extract_fns.get(size)
         if fn is None:
             def kv_extract(cache: KVCache, tables, slot, start):
@@ -1031,6 +1053,7 @@ class ModelRunner:
         (donates cache — in-place HBM update). The slot's table must
         already cover start+size positions (admission allocates the
         full prompt's blocks before tier injection runs)."""
+        self._refuse_latent("inject_chunk")
         size = k_chunk.shape[1]
         fn = self._inject_fns.get(size)
         if fn is None:

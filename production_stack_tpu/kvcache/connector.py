@@ -117,6 +117,12 @@ class KVConnector:
                  store: Optional[KVStore] = None):
         self.runner = runner
         self.cfg = cfg
+        if runner.cache.layout != "kv_heads":
+            raise ValueError(
+                f"KV transfer: the KV pool of {model_cfg.name} has the "
+                f"layout {runner.cache.layout!r} (latent attention: one "
+                f"[c | k_rope] vector a token); tiers, codecs and the "
+                f"wire hold K and V per kv head and cannot carry it")
         self.chunk_size = cfg.chunk_size
         # namespace by the WIRE dtype, not the pool dtype: an int8 pool
         # extracts/injects full-precision (bf16) chunks, so int8 and

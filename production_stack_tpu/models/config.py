@@ -91,6 +91,35 @@ class ModelConfig:
     moe_intermediate_size: Optional[int] = None   # default: intermediate
     shared_expert_size: int = 0                   # 0 = no shared expert
     moe_naming: str = "mixtral"   # HF weight naming: "mixtral" | "qwen2" 
+    # GLM-4.7-Flash / DeepSeek-style routing (ops/moe.route): scores
+    # from a sigmoid instead of a softmax, a per-expert bias that enters
+    # the SELECTION only (e_score_correction_bias), a scale on the
+    # renormalised weights, and a shared expert with no gate in front
+    router_score: str = "softmax"            # "softmax" | "sigmoid"
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    shared_expert_gate: bool = True
+    # the layer plan: this many leading layers carry a dense MLP of
+    # intermediate_size, the rest experts (first_k_dense_replace). They
+    # run before the layer scan with parameters of their own
+    # (params["dense_layers"], models/llama.py)
+    first_dense_layers: int = 0
+    # random weights only (llama.init_params): the sd the ROUTED
+    # experts' output projection is drawn at where a configuration's
+    # file states one (its ``assumed.routed_down_init_std``); None: the
+    # 0.02 of every other leaf
+    routed_down_init_std: Optional[float] = None
+    # latent attention (MLA): kv_lora_rank > 0 turns it on. Queries go
+    # through a q_lora_rank bottleneck; per token ONE latent of
+    # kv_lora_rank values and one rotated key part of qk_rope_head_dim
+    # are cached, shared by every head (models/kv.py, the latent pool);
+    # each head's keys are qk_nope_head_dim + qk_rope_head_dim wide and
+    # its values v_head_dim
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     dtype: Any = jnp.bfloat16
 
     @property
@@ -98,26 +127,55 @@ class ModelConfig:
         return self.head_dim or self.hidden_size // self.num_heads
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim_(self) -> int:
+        """Width the rotary embedding turns: the whole head, or the
+        rope part of a latent-attention head."""
+        return self.qk_rope_head_dim if self.mla else self.head_dim_
+
+    @property
+    def latent_dim(self) -> int:
+        """Values the latent pool holds per token and layer: the
+        normalised latent and the rotated key part (0: no MLA)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
     def num_params(self) -> int:
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
-        hd = self.head_dim_
+        hd, nh = self.head_dim_, self.num_heads
         E = self.num_experts
+        dense = 3 * h * i
         if E:
             mi = self.moe_intermediate_size or i
             mlp = 3 * h * mi * E + h * E
+            if self.router_bias:
+                mlp += E
             if self.shared_expert_size:
-                mlp += 3 * h * self.shared_expert_size + h
+                mlp += (3 * h * self.shared_expert_size
+                        + (h if self.shared_expert_gate else 0))
         else:
-            mlp = 3 * h * i
-        per_layer = (
-            h * (self.num_heads * hd)            # q
-            + 2 * h * (self.num_kv_heads * hd)   # k, v
-            + (self.num_heads * hd) * h          # o
-            + mlp                                # experts (+ router) or dense
-            + 2 * h                              # norms
-        )
+            mlp = dense
+        if self.mla:
+            qr, kr = self.q_lora_rank, self.kv_lora_rank
+            attn = (h * qr + qr                              # q_a, norm
+                    + qr * nh * (self.qk_nope_head_dim
+                                 + self.qk_rope_head_dim)    # q_b
+                    + h * self.latent_dim + kr               # kv_a, norm
+                    + kr * nh * (self.qk_nope_head_dim
+                                 + self.v_head_dim)          # kv_b
+                    + nh * self.v_head_dim * h)              # o
+        else:
+            attn = (h * (nh * hd)                        # q
+                    + 2 * h * (self.num_kv_heads * hd)   # k, v
+                    + (nh * hd) * h)                     # o
+        per_layer = attn + 2 * h                         # + norms
         emb = v * h * (1 if self.tie_word_embeddings else 2)
-        return self.num_layers * per_layer + emb + h
+        Ld = self.first_dense_layers if E else 0
+        return (self.num_layers * per_layer + Ld * dense
+                + (self.num_layers - Ld) * mlp + emb + h)
 
     @staticmethod
     def from_hf_config(cfg: Dict[str, Any], name: str = "",
@@ -126,7 +184,9 @@ class ModelConfig:
 
         Families: Llama-2/3, TinyLlama, Mistral (the baseline), Qwen2
         (adds q/k/v biases), Gemma (GeGLU via gelu, scaled embeddings,
-        unit-offset RMSNorm, tied embeddings).
+        unit-offset RMSNorm, tied embeddings), Gemma-2, Mixtral,
+        Qwen2-MoE, and GLM-4.7-Flash (``glm4_moe_lite``:
+        _glm4_moe_lite). Keys the mapping does not know are ignored.
         """
         archs = cfg.get("architectures") or []
         arch = archs[0] if archs else ""
@@ -142,14 +202,20 @@ class ModelConfig:
                       or arch == "MixtralForCausalLM")
         is_qwen2_moe = (model_type == "qwen2_moe"
                         or arch == "Qwen2MoeForCausalLM")
+        is_glm_lite = (model_type == "glm4_moe_lite"
+                       or arch == "Glm4MoeLiteForCausalLM")
         is_llama_like = (model_type in ("llama", "mistral") or arch in
                          ("LlamaForCausalLM", "MistralForCausalLM"))
         if not (is_qwen2 or is_gemma or is_gemma2 or is_mixtral
-                or is_qwen2_moe or is_llama_like) and (model_type or arch):
+                or is_qwen2_moe or is_glm_lite
+                or is_llama_like) and (model_type or arch):
             raise ValueError(
                 f"unsupported model family (model_type={model_type!r}, "
                 f"architecture={arch!r}); supported: llama, mistral, "
-                f"qwen2, gemma, gemma2, mixtral, qwen2_moe")
+                f"qwen2, gemma, gemma2, mixtral, qwen2_moe, "
+                f"glm4_moe_lite")
+        if is_glm_lite:
+            return _glm4_moe_lite(cfg, name, dtype)
         if is_qwen2_moe:
             if (cfg.get("decoder_sparse_step", 1) != 1
                     or cfg.get("mlp_only_layers")):
@@ -212,6 +278,67 @@ class ModelConfig:
     def from_json(path: str, dtype: Any = jnp.bfloat16) -> "ModelConfig":
         with open(os.path.join(path, "config.json") if os.path.isdir(path) else path) as f:
             return ModelConfig.from_hf_config(json.load(f), name=path, dtype=dtype)
+
+
+def _glm4_moe_lite(cfg: Dict[str, Any], name: str,
+                   dtype: Any) -> ModelConfig:
+    """GLM-4.7-Flash (``glm4_moe_lite``): latent attention, leading
+    dense layers, a sigmoid router with a selection bias and a routing
+    scale, ungated shared experts. What the tree does not build is
+    refused by name; the multi-token-prediction block
+    (``num_nextn_predict_layers``) is not built and not served, as HF's
+    own model class drops those weights on load."""
+    if cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
+        raise ValueError(
+            "glm4_moe_lite with grouped routing (n_group / topk_group "
+            "!= 1) is not supported")
+    if cfg.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError(f"glm4_moe_lite topk_method "
+                         f"{cfg['topk_method']!r} is not supported "
+                         f"(supported: noaux_tc)")
+    if cfg.get("partial_rotary_factor", 1) != 1:
+        raise ValueError("glm4_moe_lite with partial_rotary_factor != 1 "
+                         "is not supported")
+    if not cfg.get("q_lora_rank"):
+        raise ValueError("glm4_moe_lite without q_lora_rank (full-rank "
+                         "queries) is not supported")
+    if cfg.get("attention_bias"):
+        raise ValueError("glm4_moe_lite with attention_bias is not "
+                         "supported")
+    return ModelConfig(
+        name=name or cfg.get("_name_or_path", "hf-model"),
+        vocab_size=cfg["vocab_size"],
+        hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg["intermediate_size"],
+        num_layers=cfg["num_hidden_layers"],
+        num_heads=cfg["num_attention_heads"],
+        # one latent serves every head: the cache has one "kv head"
+        num_kv_heads=1,
+        head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+        rope_theta=cfg.get("rope_theta", 10000.0),
+        rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        rope_scaling=_rope_scaling_spec(cfg.get("rope_scaling")),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+        num_experts=cfg["n_routed_experts"],
+        num_experts_per_tok=cfg["num_experts_per_tok"],
+        norm_topk_prob=cfg.get("norm_topk_prob", True),
+        moe_intermediate_size=cfg["moe_intermediate_size"],
+        shared_expert_size=(cfg.get("n_shared_experts", 0)
+                            * cfg["moe_intermediate_size"]),
+        moe_naming="glm4_moe_lite",
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+        shared_expert_gate=False,
+        first_dense_layers=cfg.get("first_k_dense_replace", 0),
+        routed_down_init_std=(cfg.get("assumed") or {}).get(
+            "routed_down_init_std"),
+        q_lora_rank=cfg["q_lora_rank"], kv_lora_rank=cfg["kv_lora_rank"],
+        qk_nope_head_dim=cfg["qk_nope_head_dim"],
+        qk_rope_head_dim=cfg["qk_rope_head_dim"],
+        v_head_dim=cfg["v_head_dim"],
+        dtype=dtype,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -310,6 +437,38 @@ PRESETS: Dict[str, ModelConfig] = {
         intermediate_size=256, num_layers=2, num_heads=4, num_kv_heads=2,
         max_position_embeddings=512, num_experts=4, num_experts_per_tok=2,
     ),
+    # Tiny GLM-4.7-Flash-style model for CPU tests: latent attention,
+    # one leading dense layer, sigmoid router with bias and scale,
+    # ungated shared expert. Widths are multiples of 128 where the
+    # kernels (interpret mode) want them.
+    "debug-mla": ModelConfig(
+        name="debug-mla", vocab_size=512, hidden_size=128,
+        intermediate_size=256, num_layers=3, num_heads=4, num_kv_heads=1,
+        head_dim=48, max_position_embeddings=512, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=128,
+        shared_expert_size=128, moe_naming="glm4_moe_lite",
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=1.8, shared_expert_gate=False,
+        first_dense_layers=1, q_lora_rank=64, kv_lora_rank=128,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+    ),
+    # GLM-4.7-Flash (glm4_moe_lite, 30B-A3B): latent attention, one
+    # leading dense layer of width 10240, 64 sigmoid-routed experts
+    # top-4 of width 1536 with a selection bias and a routing scale of
+    # 1.8, one ungated shared expert. 47 layers; the 48th, a
+    # multi-token-prediction block, is not built
+    "glm-4.7-flash": ModelConfig(
+        name="glm-4.7-flash", vocab_size=154880, hidden_size=2048,
+        intermediate_size=10240, num_layers=47, num_heads=20,
+        num_kv_heads=1, head_dim=256, rope_theta=1000000.0,
+        max_position_embeddings=202752, num_experts=64,
+        num_experts_per_tok=4, moe_intermediate_size=1536,
+        shared_expert_size=1536, moe_naming="glm4_moe_lite",
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=1.8, shared_expert_gate=False,
+        first_dense_layers=1, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+    ),
     "mixtral-8x7b": ModelConfig(
         name="mixtral-8x7b", vocab_size=32000, hidden_size=4096,
         intermediate_size=14336, num_layers=32, num_heads=32,
@@ -402,6 +561,7 @@ HF_ALIASES: Dict[str, str] = {
     "mistralai/Mixtral-8x7B-Instruct-v0.1": "mixtral-8x7b",
     "Qwen/Qwen1.5-MoE-A2.7B": "qwen1.5-moe-a2.7b",
     "Qwen/Qwen1.5-MoE-A2.7B-Chat": "qwen1.5-moe-a2.7b",
+    "zai-org/GLM-4.7-Flash": "glm-4.7-flash",
     "google/gemma-2b": "gemma-2b",
     "google/gemma-2b-it": "gemma-2b",
     "google/gemma-7b": "gemma-7b",

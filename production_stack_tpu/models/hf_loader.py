@@ -46,6 +46,17 @@ def params_from_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Dict:
     """Build the stacked-params pytree from an HF LlamaForCausalLM state dict."""
     import jax.numpy as jnp
 
+    if cfg.mla or cfg.first_dense_layers:
+        # the tree is there (models/llama._init_params_mla); what is
+        # missing is the checkpoint's naming (q_a_proj, kv_a_proj_with_mqa,
+        # kv_b_proj, e_score_correction_bias, shared_experts) and the
+        # permutation of its INTERLEAVED rotary columns into the
+        # half-split layout ops/rope.py turns
+        raise NotImplementedError(
+            f"loading a checkpoint of the {cfg.moe_naming!r} family "
+            f"(latent attention, leading dense layers) is not "
+            f"supported yet: {cfg.name} runs on seeded random weights")
+
     def get(name: str, bare: bool = False) -> np.ndarray:
         return _to_numpy(_lookup(sd, name, bare=bare))
 

@@ -60,6 +60,19 @@ TPU-first invariants:
   (indices replicated, gathered axis unsharded) are shard-local: no
   extra collectives.
 
+- **The latent pool** (latent attention, MLA: GLM-4.7-Flash). Per
+  token and layer ONE vector ``[c | k_rope]`` of ``kv_lora_rank +
+  qk_rope_head_dim`` values, after the inner RMSNorm and the rotation,
+  shared by every query head, zero-padded to whole lanes of 128
+  (``latent_pool_width``): ``k [L, N, 1, Bs, W]`` and no ``v`` at
+  all — the values are the first ``kv_lora_rank`` columns of the keys'
+  own block, which the kernels slice out of the block they already
+  hold, so a decode step reads each live token's W values once. The
+  same tables, trash block, carried buffer and whole-block appends; a
+  pool of ONE array is the latent pool (``KVCache.layout``). What
+  assumes K and V per head (the int8 pool, tp meshes, the KV
+  connector's chunks) refuses it by name.
+
 The reference stack's KV management is configuration around LMCache env
 vars (reference: helm/templates/deployment-vllm-multi.yaml:154-178) and
 its engine's paged KV lives inside vLLM (the stack passes
@@ -75,9 +88,13 @@ from production_stack_tpu.ops import pallas_paged
 from production_stack_tpu.ops.attention import attention_with_cache
 
 
+LATENT = "latent"        # KVCache.layout: [c | k_rope], no v
+KV_HEADS = "kv_heads"     # separate K and V per kv head
+
+
 class KVCache(NamedTuple):
-    k: jnp.ndarray  # [L, N, Hkv, Bs, D]
-    v: jnp.ndarray  # [L, N, Hkv, Bs, D]
+    k: jnp.ndarray  # [L, N, Hkv, Bs, D]; latent pool [L, N, 1, Bs, W]
+    v: Optional[jnp.ndarray] = None  # [L, N, Hkv, Bs, D]; latent: None
     # int8 KV mode only: symmetric per-(token, head) dequant scales
     # (models/quant.py recipe applied to the cache): value = int8 *
     # scale. None = full-precision cache.
@@ -96,9 +113,22 @@ class KVCache(NamedTuple):
     def quantized(self) -> bool:
         return self.ks is not None
 
+    @property
+    def layout(self) -> str:
+        return LATENT if self.v is None else KV_HEADS
+
+    @property
+    def bytes_per_token(self) -> int:
+        """Bytes one token takes in the pool, all layers, as allocated
+        (payload and, int8, scales)."""
+        tokens = self.num_blocks * self.block_size
+        return sum(a.dtype.itemsize * (a.size // tokens)
+                   for a in self if a is not None)
+
 
 # the pool as a step program's layer loop carries it: a KVCache's
-# arrays without the Nones — (k, v) or, int8, (k, v, ks, vs)
+# arrays without the Nones — (k, v), int8 (k, v, ks, vs), or the
+# latent pool's one array (k,)
 Pool = Tuple[jnp.ndarray, ...]
 
 
@@ -119,6 +149,44 @@ def make_cache(num_layers: int, num_blocks: int, block_size: int,
                        ks=jnp.zeros(sshape, jnp.float32),
                        vs=jnp.zeros(sshape, jnp.float32))
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+
+def latent_pool_width(latent_dim: int) -> int:
+    """The latent pool's minor dimension: latent_dim padded up to whole
+    lanes of 128. The TPU lays an array's minor dimension out in tiles
+    of 128 anyway (GLM-4.7-Flash's 576 values take 640 in HBM), and a
+    kernel's copy out of HBM must cover whole tiles: the padding is
+    allocated either way, so it is made explicit, zero, and counted
+    (KVCache.bytes_per_token)."""
+    return -(-latent_dim // 128) * 128
+
+
+def make_latent_cache(num_layers: int, num_blocks: int, block_size: int,
+                      latent_dim: int, dtype=jnp.bfloat16) -> KVCache:
+    """The latent pool [L, N, 1, Bs, W], W = latent_pool_width(
+    latent_dim) (module text). num_blocks includes the trash block. No
+    quantized form: the int8 pool's scales are per (token, kv head)
+    over K and over V."""
+    if dtype == jnp.int8:
+        raise ValueError(
+            "the latent KV pool (layout 'latent': one [c | k_rope] "
+            "vector a token, MLA) has no int8 form; use "
+            "--kv-cache-dtype bfloat16 or float32")
+    return KVCache(k=jnp.zeros(
+        (num_layers, num_blocks, 1, block_size,
+         latent_pool_width(latent_dim)), dtype))
+
+
+def cache_for(cfg, num_blocks: int, block_size: int,
+              dtype=jnp.bfloat16) -> KVCache:
+    """The pool a model's layers append to and attend over
+    (cfg: models/config.ModelConfig): the latent pool for latent
+    attention, K and V per kv head for everything else."""
+    if cfg.mla:
+        return make_latent_cache(cfg.num_layers, num_blocks, block_size,
+                                 cfg.latent_dim, dtype)
+    return make_cache(cfg.num_layers, num_blocks, block_size,
+                      cfg.num_kv_heads, cfg.head_dim_, dtype)
 
 
 def linear_tables(num_slots: int, max_len: int,
@@ -339,7 +407,10 @@ def append(pool: Pool, k: jnp.ndarray, v: jnp.ndarray,
     positions starts[b]..starts[b]+T-1 — appended to layer ``layer``
     of the whole pool, which comes back as the same tuple it came in
     as (append_chunk, or append_chunk_q where the pool carries
-    scales)."""
+    scales). The latent pool (one array) takes k [B,T,1,W] and no
+    v."""
+    if len(pool) == 1:
+        return (append_chunk(pool[0], k, tables, starts, valid, layer),)
     k_cache, v_cache, *scales = pool
     if scales:
         k_cache, k_scales = append_chunk_q(k_cache, scales[0], k, tables,
@@ -355,7 +426,7 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
            starts: jnp.ndarray, positions: jnp.ndarray,
            kv_len: Optional[int], layer, *, window: Optional[int],
            scale: float, softcap: Optional[float],
-           mesh=None) -> jnp.ndarray:
+           mesh=None, value_dim: int = 0) -> jnp.ndarray:
     """One layer's read: q [B,T,H,D] at ``positions`` [B,T] (contiguous
     from starts [B]) over layer ``layer`` of the pool, which already
     holds the chunk's own K/V (append, then attend) -> [B,T,H,D].
@@ -371,24 +442,31 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
     per-row causal block skipping; prefill chunks AND decode/spec
     windows, shard-local per head via shard_map under a tp-only mesh.
     Elsewhere the gathered view feeds the position-masked jnp
-    attention (ops/attention.py)."""
-    k_cache, v_cache, *scales = pool
+    attention (ops/attention.py).
+
+    The latent pool (one array; value_dim = kv_lora_rank, static): q
+    [B,T,H,W] are the ABSORBED queries ``[q_lat | q_rope]``, every head
+    on the one cached vector a token, and the values are the first
+    value_dim columns of the keys -> [B,T,H,value_dim]."""
+    k_cache, v_cache, *scales = pool if len(pool) > 1 else (pool[0], None)
     Bs, MB = k_cache.shape[-2], tables.shape[1]
     nb = MB if kv_len is None else min(-(-kv_len // Bs), MB)
     T, H, D = q.shape[1:]
     path = pallas_paged.attention_path(T, H // k_cache.shape[2], D, Bs,
-                                       mesh)
+                                       mesh, value_dim=value_dim)
     if path != pallas_paged.JNP_GATHER:
         kw = dict(nb=nb, interpret=pallas_paged.needs_interpret(),
                   window=window or 0, scale=scale,
                   softcap=softcap or 0.0, layer=layer)
+        if v_cache is None:
+            kw.update(value_dim=value_dim)
         if scales:
             kw.update(k_scales=scales[0], v_scales=scales[1])
         if mesh is not None:
             return pallas_paged.paged_attention_sharded(
                 q, k_cache, v_cache, tables, starts, mesh, **kw)
         paged_fn = (pallas_paged.paged_decode_attention
-                    if path == "pallas_paged_decode"
+                    if path.startswith("pallas_paged_decode")
                     else pallas_paged.paged_attention)
         return paged_fn(q, k_cache, v_cache, tables, starts, **kw)
     if scales:
@@ -398,7 +476,8 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
                               dtype=q.dtype, layer=layer)
     else:
         k_att = gather_view(k_cache, tables, nb, layer=layer)
-        v_att = gather_view(v_cache, tables, nb, layer=layer)
+        v_att = (k_att[..., :value_dim] if v_cache is None
+                 else gather_view(v_cache, tables, nb, layer=layer))
     return attention_with_cache(q, k_att, v_att, positions, scale=scale,
                                 sliding_window=window,
                                 logit_softcap=softcap)

@@ -37,9 +37,10 @@ from production_stack_tpu.ops.rope import apply_rope, rope_table
 Params = Dict[str, Any]
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "dtype", "quantizer"))
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "quantizer",
+                                             "std"))
 def _random_leaf(key: jax.Array, *, shape: Tuple[int, ...], dtype,
-                 quantizer=None):
+                 quantizer=None, std: float = 0.02):
     """One weight leaf, built (and quantized) in one executable: the
     float32 draw fuses into the cast, so the transient is the leaf in
     ``dtype`` plus its int8 copy — never a float32 tensor, never a
@@ -51,7 +52,7 @@ def _random_leaf(key: jax.Array, *, shape: Tuple[int, ...], dtype,
     # seeded parity tests, which were written against the unfused
     # ``normal(key) * 0.02``
     w = jax.lax.reduce_precision(
-        jax.random.normal(key, shape, jnp.float32), 8, 23) * 0.02
+        jax.random.normal(key, shape, jnp.float32), 8, 23) * std
     # round to ``dtype`` where XLA cannot elide it either: a bare
     # f32 -> bf16 -> f32 convert pair in front of the quantizer is
     # simplified away, and the int8 leaf would not be that of the
@@ -77,12 +78,15 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     if quantization not in (None, "int8"):
         raise ValueError(f"quantization={quantization!r} unsupported")
 
-    def w(k, shape, *path):
+    def w(k, shape, *path, std=0.02):
         # path = the leaf's place in the tree: quant.leaf_quantizer
         # holds the one rule for which leaves quantize, and how
         q = quant.leaf_quantizer(path) if quantization else None
-        return _random_leaf(k, shape=shape, dtype=cfg.dtype, quantizer=q)
+        return _random_leaf(k, shape=shape, dtype=cfg.dtype, quantizer=q,
+                            std=std)
 
+    if cfg.mla:
+        return _init_params_mla(cfg, key, w)
     norm_init = jnp.zeros if cfg.rms_norm_offset else jnp.ones
     E = cfg.num_experts
     params: Params = {
@@ -133,6 +137,162 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), (h, v), "lm_head")
     return params
+
+
+def _init_params_mla(cfg: ModelConfig, key: jax.Array, w) -> Params:
+    """init_params for a latent-attention model with a layer plan
+    (GLM-4.7-Flash): ``dense_layers`` holds the leading
+    first_dense_layers layers (attention + a dense MLP), ``layers`` the
+    expert layers that the scan runs, each group stacked on its own
+    leading axis. Every leaf is drawn from the seed, the router's
+    selection bias too and NOT zero (normal, sd 0.1: a zero bias would
+    make selecting with and without it the same, and sd 0.02 moves no
+    selection); it stays float32, as the publication keeps it. Every
+    weight at sd 0.02, but the routed experts' output projection where
+    the configuration states another (cfg.routed_down_init_std)."""
+    h, v, nh = cfg.hidden_size, cfg.vocab_size, cfg.num_heads
+    qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+    E, mi = cfg.num_experts, cfg.moe_intermediate_size
+    Ld = cfg.first_dense_layers
+    Le = cfg.num_layers - Ld
+    keys = iter(jax.random.split(key, 32))
+
+    def attention(L, group):
+        return {
+            "attn_norm": jnp.ones((L, h), cfg.dtype),
+            "q_a": w(next(keys), (L, h, qr), group, "q_a"),
+            "q_a_norm": jnp.ones((L, qr), cfg.dtype),
+            "q_b": w(next(keys), (L, qr, nh * cfg.head_dim_), group, "q_b"),
+            "kv_a": w(next(keys), (L, h, cfg.latent_dim), group, "kv_a"),
+            "kv_a_norm": jnp.ones((L, kr), cfg.dtype),
+            "kv_b": w(next(keys), (L, kr, nh * (cfg.qk_nope_head_dim
+                                                + cfg.v_head_dim)),
+                      group, "kv_b"),
+            "o": w(next(keys), (L, nh * cfg.v_head_dim, h), group, "o"),
+            "mlp_norm": jnp.ones((L, h), cfg.dtype),
+        }
+
+    params: Params = {
+        "embed": w(next(keys), (v, h), "embed"),
+        "final_norm": jnp.ones((h,), cfg.dtype),
+        "lm_head": w(next(keys), (h, v), "lm_head"),
+    }
+    if cfg.tie_word_embeddings:
+        del params["lm_head"]
+    if Ld:
+        i = cfg.intermediate_size
+        params["dense_layers"] = {
+            **attention(Ld, "dense_layers"),
+            "gate": w(next(keys), (Ld, h, i), "dense_layers", "gate"),
+            "up": w(next(keys), (Ld, h, i), "dense_layers", "up"),
+            "down": w(next(keys), (Ld, i, h), "dense_layers", "down"),
+        }
+    layers = {
+        **attention(Le, "layers"),
+        "gate": w(next(keys), (Le, E, h, mi), "layers", "gate"),
+        "up": w(next(keys), (Le, E, h, mi), "layers", "up"),
+        "down": w(next(keys), (Le, E, mi, h), "layers", "down",
+                  std=cfg.routed_down_init_std or 0.02),
+        "router": w(next(keys), (Le, h, E), "layers", "router"),
+    }
+    if cfg.router_bias:
+        layers["router_bias"] = 0.1 * jax.random.normal(
+            next(keys), (Le, E), jnp.float32)
+    if cfg.shared_expert_size:
+        si = cfg.shared_expert_size
+        layers.update({
+            "s_gate": w(next(keys), (Le, h, si), "layers", "s_gate"),
+            "s_up": w(next(keys), (Le, h, si), "layers", "s_up"),
+            "s_down": w(next(keys), (Le, si, h), "layers", "s_down"),
+        })
+        if cfg.shared_expert_gate:
+            layers["s_gate_w"] = w(next(keys), (Le, h, 1), "layers",
+                                   "s_gate_w")
+    params["layers"] = layers
+    return params
+
+
+def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
+                   lp: Params, kv, kv_len, token_valid, block_tables,
+                   mesh, layer):
+    """Latent attention (MLA) on the normed input ``hidden`` [B,T,H] ->
+    (heads' outputs [B,T,nh*v_head_dim], the pool).
+
+    c_q = RMSNorm(x W_qa), q = c_q W_qb -> per head [q_nope | q_rope];
+    [c_kv | k_rope] = x W_kva, c = RMSNorm(c_kv); the rope parts rotated
+    at the token's position. With a pool (serving) the layer caches
+    ``[c | k_rope]`` — ONE vector a token — and attends ABSORBED: W_kvb
+    split per head into W_uk [nope, r] and W_uv [r, v], q_lat = q_nope
+    W_uk^T, scores of [q_lat | q_rope] against the cached vectors, the
+    weighted sum of the cached c, then W_uv: no key or value of a head
+    is ever made. W_kvb's int8 scales are per output channel, which in
+    those two products are q_nope's channels and o's: they multiply
+    there and nothing is requantised. Without a pool (encode) it is
+    the EXPANDED form, [k_nope | v] = c W_kvb per head: the two forms
+    are what tests/test_mla.py compares."""
+    B, T, _ = hidden.shape
+    nh, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    cos, sin = rope
+    eps = cfg.rms_norm_eps
+    with jax.named_scope("mla_q_proj"):
+        c_q = rms_norm(quant.dequant_matmul(hidden, lp["q_a"]),
+                       lp["q_a_norm"], eps)
+        q = quant.dequant_matmul(c_q, lp["q_b"]).reshape(B, T, nh, dn + dr)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
+    with jax.named_scope("mla_kv_proj"):
+        ckv = quant.dequant_matmul(hidden, lp["kv_a"])
+        c = rms_norm(ckv[..., :r], lp["kv_a_norm"], eps)
+        k_rope = ckv[..., None, r:]                       # [B,T,1,dr]
+    with jax.named_scope("rope"):
+        q_rope = apply_rope(q_rope, positions, cos, sin)
+        k_rope = apply_rope(k_rope, positions, cos, sin)
+    scale = (dn + dr) ** -0.5
+    kv_b = lp["kv_b"]
+    quantized = quant.is_quantized(kv_b)
+    w_kvb = (kv_b["w8"] if quantized else kv_b).reshape(r, nh, dn + dv)
+    ch_scale = (kv_b["scale"].reshape(nh, dn + dv) if quantized else None)
+    if kv is None:
+        with jax.named_scope("attention"):
+            kvh = jnp.einsum("btr,rhd->bthd", c, w_kvb.astype(c.dtype))
+            if quantized:
+                kvh = kvh * ch_scale.astype(c.dtype)
+            k = jnp.concatenate(
+                [kvh[..., :dn], jnp.broadcast_to(k_rope, (B, T, nh, dr))],
+                axis=-1)
+            attn = causal_attention(
+                jnp.concatenate([q_nope, q_rope], axis=-1), k,
+                kvh[..., dn:], scale=scale)
+        return attn.reshape(B, T, nh * dv), None
+    with jax.named_scope("mla_absorb_q"):
+        if quantized:
+            q_nope = (q_nope.astype(jnp.float32)
+                      * ch_scale[:, :dn]).astype(q_nope.dtype)
+        q_lat = jnp.einsum("bthd,rhd->bthr", q_nope,
+                           w_kvb[..., :dn].astype(q_nope.dtype))
+    # the pool's vectors are padded to whole lanes (kv.latent_pool_width):
+    # zeros in the cache and in the queries, which add nothing to a score
+    pad = kv[0].shape[-1] - (r + dr)
+
+    def padded(parts):
+        if pad:
+            parts.append(jnp.zeros(parts[0].shape[:-1] + (pad,),
+                                   parts[0].dtype))
+        return jnp.concatenate(parts, axis=-1)
+    with jax.named_scope("kv_write"):
+        kv = kv_pool.append(kv, padded([c[:, :, None, :], k_rope]), None,
+                            block_tables, starts, token_valid, layer)
+    with jax.named_scope("attention"):
+        ctx = kv_pool.attend(
+            padded([q_lat, q_rope]), kv, block_tables, starts, positions,
+            kv_len, layer, window=None, scale=scale, softcap=None,
+            mesh=mesh, value_dim=r)                       # [B,T,nh,r]
+    with jax.named_scope("mla_absorb_o"):
+        attn = jnp.einsum("bthr,rhd->bthd", ctx,
+                          w_kvb[..., dn:].astype(ctx.dtype))
+        if quantized:
+            attn = attn * ch_scale[:, dn:].astype(attn.dtype)
+    return attn.reshape(B, T, nh * dv), kv
 
 
 def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
@@ -198,6 +358,14 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     with jax.named_scope("attn_norm"):
         hidden = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
                           offset=offset)
+    if cfg.mla:
+        attn, kv = _mla_attention(cfg, rope, positions, starts, hidden,
+                                  lp, kv, kv_len, token_valid,
+                                  block_tables, mesh, layer)
+        with jax.named_scope("o_proj"):
+            x = x + proj(attn, "o")
+        return _mlp_block(cfg, x, lp, kv, token_valid,
+                          moe_capacity_tokens, expert_stacks, layer, proj)
     with jax.named_scope("qkv_proj"):
         q = proj(hidden, "q").reshape(B, T, nh, hd)
         k = proj(hidden, "k").reshape(B, T, nkv, hd)
@@ -243,19 +411,35 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
             o_out = rms_norm(o_out, lp["post_attn_norm"],
                              cfg.rms_norm_eps, offset=offset)
         x = x + o_out
+    return _mlp_block(cfg, x, lp, kv, token_valid, moe_capacity_tokens,
+                      expert_stacks, layer, proj)
 
+
+def _mlp_block(cfg: ModelConfig, x, lp: Params, kv, token_valid,
+               moe_capacity_tokens, expert_stacks, layer, proj):
+    """The block's second half: mlp_norm, then the dense MLP or, where
+    the layer's parameters hold a router, the experts (a model with
+    leading dense layers has both kinds of layer). Returns
+    _layer_body's triple."""
+    B, T, _ = x.shape
+    offset = 1.0 if cfg.rms_norm_offset else 0.0
     with jax.named_scope("mlp_norm"):
         hidden = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps,
                           offset=offset)
     act = jax.nn.silu if cfg.activation == "silu" else _gelu_tanh
     experts_read = None
-    if cfg.num_experts:
+    if "router" in lp:
         H = hidden.shape[-1]
         # the list path reads its experts in place in the whole stacks
         # (ops/moe.list_path, asked by ``forward``); else this layer's
         gate, up, down = (
             lp[n] if expert_stacks is None else expert_stacks[n]
             for n in ("gate", "up", "down"))
+        # the stacks hold the expert layers alone: the pool's layer
+        # index less the leading dense layers
+        moe_layer = None if expert_stacks is None else layer
+        if moe_layer is not None and cfg.first_dense_layers:
+            moe_layer = layer - cfg.first_dense_layers
         # scopes moe_router / moe_experts / moe_combine: ops/moe.py
         y, experts_read = moe.moe_mlp(
             hidden.reshape(B * T, H), lp["router"], gate, up, down,
@@ -268,17 +452,22 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
             # decode (T == 1) must be exact: a dropped token would
             # corrupt a live sequence's residual stream mid-generation
             exact=True if T == 1 else None,
-            layer=None if expert_stacks is None else layer)
+            layer=moe_layer,
+            router_score=cfg.router_score,
+            router_bias=lp.get("router_bias"),
+            routed_scale=cfg.routed_scaling_factor)
         if cfg.shared_expert_size:
-            # Qwen2-MoE: an always-on shared expert, scaled by a
-            # per-token sigmoid gate
+            # an always-on shared expert: Qwen2-MoE's behind a
+            # per-token sigmoid gate, GLM-4.7-Flash's with none
             with jax.named_scope("shared_expert"):
                 shared = quant.dequant_matmul(
                     act(quant.dequant_matmul(hidden, lp["s_gate"]))
                     * quant.dequant_matmul(hidden, lp["s_up"]),
                     lp["s_down"])
-                y = y.reshape(B, T, H) + jax.nn.sigmoid(
-                    hidden @ lp["s_gate_w"]) * shared
+                if cfg.shared_expert_gate:
+                    shared = jax.nn.sigmoid(
+                        hidden @ lp["s_gate_w"]) * shared
+                y = y.reshape(B, T, H) + shared
             x = x + y
         else:
             x = x + y.reshape(B, T, H)
@@ -334,8 +523,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     expert matmuls walk that list (ops/moe.list_path: decode steps).
     """
     if rope is None:
-        rope = rope_table(cfg.max_position_embeddings, cfg.head_dim_,
+        rope = rope_table(cfg.max_position_embeddings, cfg.rope_dim_,
                           cfg.rope_theta, scaling=cfg.rope_scaling)
+    if lora_params is not None and (cfg.mla or cfg.first_dense_layers):
+        raise ValueError(
+            "LoRA adapters are not supported on a latent-attention "
+            "model or one with leading dense layers")
     if block_tables is None:
         B = tokens.shape[0]
         Bs = cache.block_size
@@ -375,11 +568,21 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         expert_stacks = {n: layer_params[n] for n in ("gate", "up", "down")}
         layer_params = {n: w for n, w in layer_params.items()
                         if n not in expert_stacks}
-    layers = jnp.arange(cfg.num_layers)
+    # the layer plan: leading dense layers run before the scan with
+    # parameters of their own, on the first layers of the pool; the
+    # scan runs the rest (the expert layers) at the pool's layer index,
+    # so the pool stays the one carried buffer
+    Ld = cfg.first_dense_layers
+    layers = jnp.arange(Ld, cfg.num_layers)
     xs = (layer_params, layers, lora_params,
           # Gemma-2 layer pattern: even layers sliding, odd global
           layers % 2 == 0 if cfg.alternating_sliding else None)
     pool = tuple(a for a in cache if a is not None)
+    with jax.named_scope("dense_layers"):
+        for i in range(Ld):
+            lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
+            (x, pool), _ = scan_body((x, pool),
+                                     (lp, jnp.int32(i), None, None))
     with jax.named_scope("layers"):
         (x, pool), experts_read = jax.lax.scan(scan_body, (x, pool), xs)
     with jax.named_scope("final_norm"):
@@ -401,7 +604,7 @@ def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     MoE models padding must not compete for expert capacity.
     """
     if rope is None:
-        rope = rope_table(cfg.max_position_embeddings, cfg.head_dim_,
+        rope = rope_table(cfg.max_position_embeddings, cfg.rope_dim_,
                           cfg.rope_theta, scaling=cfg.rope_scaling)
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T), (B, T))
@@ -414,9 +617,13 @@ def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                 layer_local=local)
         return out, None
 
+    Ld = cfg.first_dense_layers
+    for i in range(Ld):
+        x, _ = scan_body(x, (jax.tree.map(lambda a: a[i],
+                                          params["dense_layers"]), None))
     local_flags = (jnp.arange(cfg.num_layers) % 2 == 0
                    if cfg.alternating_sliding
-                   else jnp.zeros((cfg.num_layers,), bool))
+                   else jnp.zeros((cfg.num_layers - Ld,), bool))
     x, _ = jax.lax.scan(scan_body, x, (params["layers"], local_flags))
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                     offset=1.0 if cfg.rms_norm_offset else 0.0)

@@ -30,7 +30,11 @@ import jax.numpy as jnp
 
 # layer-dict entries that stay un-quantized (small or accuracy-critical)
 _SKIP_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_mlp_norm",
-               "q_bias", "k_bias", "v_bias", "router", "s_gate_w")
+               "q_bias", "k_bias", "v_bias", "router", "s_gate_w",
+               "q_a_norm", "kv_a_norm", "router_bias")
+# the groups of stacked layers a tree may hold: the scanned layers and
+# a layer plan's leading dense ones (models/llama.py)
+_LAYER_GROUPS = ("layers", "dense_layers")
 
 
 def quantize_tensor(w: jnp.ndarray) -> Dict[str, jnp.ndarray]:
@@ -74,13 +78,13 @@ def dequant_matmul(x: jnp.ndarray, w: Any, dtype=None) -> jnp.ndarray:
 
 def leaf_quantizer(path):
     """The quantizer the standard int8 recipe applies to the leaf at
-    ``path`` — ("embed",), ("lm_head",) or ("layers", name) in the
-    models/llama.py layout — or None where the leaf stays in the model
+    ``path`` — ("embed",), ("lm_head",) or (group, name), group
+    "layers" or "dense_layers", in the models/llama.py layout — or None where the leaf stays in the model
     dtype. Embed quantizes per row so the gather and tied-lm_head roles
     share one scale axis."""
     if path == ("embed",):
         return quantize_embed
-    if path == ("lm_head",) or (path[0] == "layers"
+    if path == ("lm_head",) or (path[0] in _LAYER_GROUPS
                                 and path[1] not in _SKIP_LAYER):
         return quantize_tensor
     return None
@@ -93,11 +97,9 @@ def quantize_params(params: Dict[str, Any]) -> Dict[str, Any]:
         fn = leaf_quantizer(path)
         return w if fn is None else fn(w)
 
-    out = {name: q(w, name) for name, w in params.items()
-           if name != "layers"}
-    out["layers"] = {name: q(w, "layers", name)
-                     for name, w in params["layers"].items()}
-    return out
+    return {group: ({name: q(w, group, name) for name, w in tree.items()}
+                    if group in _LAYER_GROUPS else q(tree, group))
+            for group, tree in params.items()}
 
 
 def dequant_rows(w: Any, rows: jnp.ndarray, dtype) -> jnp.ndarray:

@@ -85,7 +85,7 @@ def causal_attention(
     probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
     probs = probs / probs.sum(axis=-1, keepdims=True)
     out = _grouped_out(probs, v)
-    return out.reshape(B, T, H, D)
+    return out.reshape(B, T, H, v.shape[-1])
 
 
 def attention_with_cache(
@@ -101,7 +101,7 @@ def attention_with_cache(
 
     q           [B,T,H,D]   — the new chunk (T=1 for decode, >1 for prefill)
     k_cache     [B,S,Hkv,D] — cache ALREADY containing the new chunk's K
-    v_cache     [B,S,Hkv,D]
+    v_cache     [B,S,Hkv,Dv] — Dv = D, or narrower (latent attention)
     q_positions [B,T]       — absolute position of each query token
 
     Query token at position p attends to cache slots s <= p (and
@@ -126,4 +126,4 @@ def attention_with_cache(
     probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
     probs = probs / probs.sum(axis=-1, keepdims=True)
     out = _grouped_out(probs, v_cache)
-    return out.reshape(B, T, H, D)
+    return out.reshape(B, T, H, v_cache.shape[-1])

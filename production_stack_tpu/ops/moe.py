@@ -54,7 +54,9 @@ helm/templates/deployment-vllm-multi.yaml:57-64; expert parallelism is a
   dispatch/combine collectives from the sharding annotations.
 
 Routing follows Mixtral semantics: fp32 softmax over all experts, then
-top-k, then renormalize the selected probabilities to sum to 1.
+top-k, then renormalize the selected probabilities to sum to 1; Qwen's
+(raw probabilities) and GLM-4.7-Flash's (sigmoid scores, a bias in the
+selection alone, a routing scale) are ``route``'s arguments.
 """
 
 import functools
@@ -78,17 +80,35 @@ def capacity_for(n_tokens: int, num_experts: int, top_k: int,
 
 
 def route(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
-          renormalize: bool = True):
+          renormalize: bool = True, score: str = "softmax",
+          bias=None, scale: float = 1.0):
     """Top-k routing. x [N, h], router_w [h, E] ->
     (weights [N, k] fp32, expert ids [N, k] int32). renormalize=True is
     Mixtral semantics (selected weights re-sum to 1); False keeps the
-    raw softmax probabilities (Qwen2-MoE's norm_topk_prob=False)."""
+    raw softmax probabilities (Qwen2-MoE's norm_topk_prob=False).
+
+    GLM-4.7-Flash / DeepSeek-V3 (``noaux_tc``): score="sigmoid" scores
+    each expert by itself; bias [E] (e_score_correction_bias) is added
+    for the SELECTION alone, the weights are the chosen experts' scores
+    without it; renormalised with the publication's 1e-20 in the
+    denominator; then times ``scale`` (routed_scaling_factor)."""
     logits = jnp.einsum("nh,he->ne", x, router_w,
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, top_k)
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"router score {score!r}")
+    sigmoid = score == "sigmoid"
+    probs = (jax.nn.sigmoid(logits) if sigmoid
+             else jax.nn.softmax(logits, axis=-1))
+    if bias is None:
+        top_p, top_i = jax.lax.top_k(probs, top_k)
+    else:
+        _, top_i = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        top_p = jnp.take_along_axis(probs, top_i, axis=-1)
     if renormalize:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p = top_p / (total + 1e-20 if sigmoid else total)
+    if scale != 1.0:
+        top_p = top_p * scale
     return top_p, top_i.astype(jnp.int32)
 
 
@@ -429,7 +449,9 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             dense_threshold: int = DENSE_THRESHOLD,
             act: Callable = jax.nn.silu, valid=None,
             exact=None, renormalize: bool = True,
-            capacity_tokens=None, layer=None):
+            capacity_tokens=None, layer=None,
+            router_score: str = "softmax", router_bias=None,
+            routed_scale: float = 1.0):
     """MoE feed-forward. x [N, h]; router_w [h, E]; gate/up [E, h, i];
     down [E, i, h]. Returns ([N, h] in x.dtype, the experts whose
     weights the call read: an int32 scalar).
@@ -452,11 +474,15 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
     asks it, with the mesh, before it hands the stacks over); it is the
     exact path's sum, so ``exact`` False is refused and the capacity
     arguments do not apply.
+    router_score, router_bias, routed_scale: ``route``'s score, bias
+    and scale; every path takes the weights it gives unchanged.
     """
     N = x.shape[0]
     E = _wshape(gate)[-3]
     with jax.named_scope("moe_router"):
-        top_p, top_i = route(x, router_w, top_k, renormalize=renormalize)
+        top_p, top_i = route(x, router_w, top_k, renormalize=renormalize,
+                             score=router_score, bias=router_bias,
+                             scale=routed_scale)
         if valid is not None:
             top_p = top_p * valid.astype(top_p.dtype)[:, None]
     if layer is not None:

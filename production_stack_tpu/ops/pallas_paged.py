@@ -120,14 +120,19 @@ _VMEM_WORK_BYTES = 8 * 1024 * 1024
 
 
 def paged_viable(T: int, groups: int, head_dim: int,
-                 block_size: int) -> bool:
+                 block_size: int, value_dim: int = 0) -> bool:
     """Can a [T*G, D] q panel + accumulator + one [T*G, Bs] score
     block hold in VMEM? (Decode windows always can; only very long
-    prefill chunks on wide-GQA models cannot.)"""
+    prefill chunks on wide-GQA models cannot.) value_dim: the
+    accumulator's width where it is not the keys' (the latent pool)."""
     rows = max(T * groups, 8)
-    work = rows * head_dim * 4 * 2 + rows * block_size * 4 * 2 \
-        + rows * head_dim * 2
+    work = rows * (head_dim + (value_dim or head_dim)) * 4 \
+        + rows * block_size * 4 * 2 + rows * head_dim * 2
     return work <= _VMEM_WORK_BYTES
+
+
+# the smallest q block the prefill kernel cuts a chunk into
+_MIN_BLOCK_Q = 16
 
 
 def _whole_pool(layer, k_pool, v_pool, k_scales, v_scales):
@@ -141,12 +146,11 @@ def _whole_pool(layer, k_pool, v_pool, k_scales, v_scales):
     return (jnp.asarray(layer, jnp.int32).reshape(1),) + pools
 
 
-def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, v_ref,
-                  *refs,
+def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
                   block_q: int, groups: int,
                   block_size: int, nb: int, scale: float,
                   quant: bool = False, window: int = 0,
-                  softcap: float = 0.0):
+                  softcap: float = 0.0, value_dim: int = 0):
     """One (batch row, kv head, q block, pool block) grid step.
 
     tabs_ref   (SMEM) [B, MB]      block tables
@@ -154,12 +158,19 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, v_ref,
     layer_ref  (SMEM) [1]          the pool's layer (index maps only)
     q_ref   [1, BQ, 1, G, D]       this kv-head's query block
     k_ref   [1, 1, 1, Bs, D]       pool block tabs[b, min(j, jmax)]
-    v_ref   [1, 1, 1, Bs, D]
-    refs    (quant only: ks/vs dequant scales [1, 1, Hkv, Bs] fp32 —
+    refs    v_ref [1, 1, 1, Bs, D], (quant only: ks/vs dequant scales [1, 1, Hkv, Bs] fp32 —
             every kv head of the block, this step reads row h,)
             out [1, BQ, 1, G, D], scratch m/l/acc (online softmax
             state across j)
+
+    The latent pool (value_dim > 0, static): no v_ref — the values are
+    the first value_dim columns of the K block — q_ref [1, BQ*G, D]
+    and out [1, BQ*G, value_dim] come with their rows flattened by the
+    wrapper (one kv head), and the dots take the operands as stored
+    (bf16 to the MXU, float32 products), as the decode kernel's do.
     """
+    if not value_dim:
+        v_ref, refs = refs[0], refs[1:]
     if quant:
         ks_ref, vs_ref = refs[0], refs[1]
         refs = refs[2:]
@@ -195,16 +206,25 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, v_ref,
         row_ids = jax.lax.broadcasted_iota(
             jnp.int32, (rows, 1), 0) // groups
         q_pos = start + qi * block_q + row_ids                # [rows, 1]
-        q = q_ref[0].reshape(rows, D).astype(jnp.float32) * scale
-        k_blk = k_ref[0, 0, 0].astype(jnp.float32)            # [Bs, D]
-        v_blk = v_ref[0, 0, 0].astype(jnp.float32)
-        if quant:
-            # int8 pool: dequantize the panel in VMEM (per-token scale)
-            k_blk = k_blk * ks_ref[0, 0, h][:, None]
-            v_blk = v_blk * vs_ref[0, 0, h][:, None]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [rows, Bs]
+        if value_dim:
+            k_blk = k_ref[0, 0, 0]                            # [Bs, D]
+            v_blk = k_blk[:, :value_dim]
+            s = jax.lax.dot_general(
+                q_ref[0].astype(k_blk.dtype), k_blk,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [rows, Bs]
+        else:
+            q = q_ref[0].reshape(rows, D).astype(jnp.float32) * scale
+            k_blk = k_ref[0, 0, 0].astype(jnp.float32)        # [Bs, D]
+            v_blk = v_ref[0, 0, 0].astype(jnp.float32)
+            if quant:
+                # int8 pool: dequantize the panel in VMEM (per-token
+                # scale)
+                k_blk = k_blk * ks_ref[0, 0, h][:, None]
+                v_blk = v_blk * vs_ref[0, 0, h][:, None]
+            s = jax.lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [rows, Bs]
         if softcap:
             # Gemma-2 tanh cap on RAW scores, before -inf masking
             s = softcap * jnp.tanh(s / softcap)
@@ -223,25 +243,27 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, v_ref,
         l_ref[...] = l_prev * correction + jnp.sum(
             p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [rows, D]
 
     @pl.when(j == nb - 1)
     def _emit():
         # fully-masked (padding/parked) rows have l == 0; keep finite
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        out_ref[0] = out.reshape(block_q, 1, groups, D).astype(
-            out_ref.dtype)
+        if not value_dim:
+            out = out.reshape(block_q, 1, groups, D)
+        out_ref[0] = out.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("nb", "block_q", "interpret",
-                                    "window", "scale", "softcap"))
+                                    "window", "scale", "softcap",
+                                    "value_dim"))
 def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
                     block_q: int = 0, interpret: bool = False,
                     k_scales=None, v_scales=None, window: int = 0,
                     scale: float = None, softcap: float = 0.0,
-                    layer=None):
+                    layer=None, value_dim: int = 0):
     """Causal GQA over paged K/V, positions contiguous per row.
 
     q [B, T, H, D]; k/v pool [N, Hkv, Bs, D], or with ``layer`` (an
@@ -258,6 +280,10 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     k_scales/v_scales [(L,) N, Hkv, Bs] fp32 activate the int8-pool
     mode: panels stream from HBM as int8 (half the bytes) and
     dequantize in VMEM next to the dot.
+
+    The latent pool: k_pool [(L,) N, 1, Bs, W], v_pool None and
+    value_dim (static) the leading columns of a key that are its value;
+    q [B, T, H, W] the absorbed queries -> [B, T, H, value_dim].
     """
     B, T, H, D = q.shape
     layer, k_pool, v_pool, k_scales, v_scales = _whole_pool(
@@ -272,7 +298,8 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
         # whole chunk per q block while VMEM allows: K/V are streamed
         # once per (batch, head) instead of once per q block
         block_q = T
-        while block_q > 16 and not paged_viable(block_q, G, D, Bs):
+        while block_q > _MIN_BLOCK_Q and not paged_viable(
+                block_q, G, D, Bs, value_dim):
             block_q //= 2
     block_q = min(block_q, T)
     pad_t = (-T) % block_q
@@ -282,8 +309,11 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     nq = Tp // block_q
 
     # q as [B, Tp, Hkv, G, D]: BlockSpec carves per-(b, kv-head) panels
-    # out of the native layout, (G, D) minor
-    q5 = q.reshape(B, Tp, Hkv, G, D)
+    # out of the native layout, (G, D) minor. The latent pool's one kv
+    # head: [B, Tp*G, D], the rows (t*G + g) the kernel wants, as q lies
+    Dv = value_dim or D
+    q5 = (q.reshape(B, Tp * G, D) if value_dim
+          else q.reshape(B, Tp, Hkv, G, D))
 
     def kv_index(b, h, qi, j, tabs, sts, lyr):
         # clamp out-of-range blocks (past-causal above, before the
@@ -308,20 +338,25 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
         return kv_index(b, h, qi, j, tabs, sts, lyr)[:2] + (0, 0)
 
     def q_index(b, h, qi, j, tabs, sts, lyr):
-        return (b, qi, h, 0, 0)
+        return (b, qi, 0) if value_dim else (b, qi, h, 0, 0)
 
     grid = (B, Hkv, nq, nb)
     kernel = functools.partial(
         _paged_kernel, block_q=block_q, groups=G, block_size=Bs,
         nb=nb, scale=scale, quant=quant, window=window,
-        softcap=softcap)
+        softcap=softcap, value_dim=value_dim)
     rows = block_q * G
+    q_block, out_block = (
+        ((1, rows, D), (1, rows, Dv)) if value_dim
+        else ((1, block_q, 1, G, D),) * 2)
     in_specs = [
-        pl.BlockSpec((1, block_q, 1, G, D), q_index),
-        pl.BlockSpec((1, 1, 1, Bs, D), kv_index),
+        pl.BlockSpec(q_block, q_index),
         pl.BlockSpec((1, 1, 1, Bs, D), kv_index),
     ]
-    operands = [q5, k_pool, v_pool]
+    operands = [q5, k_pool]
+    if not value_dim:
+        in_specs.append(pl.BlockSpec((1, 1, 1, Bs, D), kv_index))
+        operands.append(v_pool)
     if quant:
         in_specs += [pl.BlockSpec((1, 1, Hkv, Bs), scale_index)] * 2
         operands += [k_scales, v_scales]
@@ -331,14 +366,15 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
             num_scalar_prefetch=3,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, block_q, 1, G, D), q_index),
+            out_specs=pl.BlockSpec(out_block, q_index),
             scratch_shapes=[
                 pltpu.VMEM((rows, 1), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, D), jnp.float32),
+                pltpu.VMEM((rows, Dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Tp, Hkv, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            q5.shape[:-1] + (Dv,), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
@@ -347,7 +383,7 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(starts, jnp.int32),
       layer, *operands)
 
-    return out.reshape(B, Tp, H, D)[:, :T]
+    return out.reshape(B, Tp, H, Dv)[:, :T]
 
 
 # ---------------------------------------------------------------------
@@ -410,21 +446,24 @@ _DECODE_PANEL_TOKENS = 512
 
 
 def decode_blocks_per_step(nb: int, heads_kv: int, block_size: int,
-                           head_dim: int, kv_itemsize: int) -> int:
+                           head_dim: int, kv_itemsize: int,
+                           latent: bool = False) -> int:
     """R, the pool blocks one chunk of the decode kernel takes: a
     function of the trace-time shapes alone (the kv bucket, the kv
-    heads a block holds, its tokens and head dim, the pool's dtype)."""
-    block_bytes = 2 * heads_kv * block_size * head_dim * kv_itemsize
+    heads a block holds, its tokens and head dim, the pool's dtype,
+    and whether a block is K and V or the latent pool's one panel)."""
+    block_bytes = ((1 if latent else 2) * heads_kv * block_size
+                   * head_dim * kv_itemsize)
     return max(1, min(_DECODE_CHUNK_BYTES // block_bytes,
                       _DECODE_PANEL_TOKENS // block_size, nb))
 
 
 def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
-                         v_hbm, *refs,
+                         *refs,
                          T: int, heads_kv: int, groups: int,
                          block_size: int, nb: int, R: int, scale: float,
                          quant: bool = False, window: int = 0,
-                         softcap: float = 0.0):
+                         softcap: float = 0.0, value_dim: int = 0):
     """One batch row: every live block of it, R at a time.
 
     tabs_ref   (SMEM) [B, MB]     block tables
@@ -440,12 +479,24 @@ def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
             [Hkv*T*G, D], all fp32, and (SMEM) [2]: the slot the row's
             first chunk lies in and whether the row before already
             started its copies.
+
+    The latent pool (value_dim > 0, static): no v_hbm and no V slots —
+    a block's one [1, Bs, W] panel is copied once, and its first
+    value_dim columns are the values; out, acc and p.V are value_dim
+    wide.
     """
+    hbm = (k_hbm,)
+    if not value_dim:
+        hbm, refs = hbm + refs[:1], refs[1:]
     if quant:
         ks_ref, vs_ref = refs[:2]
         refs = refs[2:]
-    (out_ref, k_buf, v_buf, sems, s_ref, m_ref, l_ref, acc_ref, pv_ref,
-     state_ref) = refs
+    out_ref, *refs = refs
+    bufs, refs = refs[:len(hbm)], refs[len(hbm):]
+    sems, s_ref, m_ref, l_ref, acc_ref, pv_ref, state_ref = refs
+    pools = tuple(zip(hbm, bufs))       # (K, V), or the latents alone
+    k_buf, v_buf = bufs[0], bufs[-1]    # the latents' values: their keys
+    Dv = value_dim or q_ref.shape[-1]
     b = pl.program_id(0)
     B, MB = tabs_ref.shape
     rows = T * groups
@@ -476,8 +527,7 @@ def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
             @pl.when((j >= lo) & (j <= hi))
             def _():
                 blk = tabs_ref[row, j]
-                for o, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                                (v_hbm, v_buf))):
+                for o, (hbm, buf) in enumerate(pools):
                     cp = pltpu.make_async_copy(
                         hbm.at[layer, blk], buf.at[slot, i],
                         sems.at[slot, o])
@@ -488,7 +538,8 @@ def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
         state_ref[0] = 0
         state_ref[1] = 0
         # a dead block's columns are masked to probability 0, which
-        # must not meet whatever the V slots held before the call
+        # must not meet whatever the V slots (the latent pool's one
+        # set of slots) held before the call
         v_buf[...] = jnp.zeros_like(v_buf)
 
     lo, hi, g0, nch = live_range(b)
@@ -552,7 +603,10 @@ def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
             p_h = s_ref[h * rows:(h + 1) * rows, :]
             if quant:
                 p_h = p_h * vs_ref[0, g, h:h + 1, :]
-            v = v_buf[slot, :, h].reshape(cols, D).astype(cdt)
+            v = v_buf[slot, :, h].reshape(cols, D)
+            if value_dim:
+                v = v[:, :value_dim]
+            v = v.astype(cdt)
             pv_ref[h * rows:(h + 1) * rows, :] = jax.lax.dot_general(
                 p_h.astype(cdt), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)          # [rows, D]
@@ -568,7 +622,7 @@ def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
 
     # a parked row has l == 0 and acc == 0: finite zeros
     out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-    out_ref[0] = out.reshape(heads_kv, rows, D).astype(out_ref.dtype)
+    out_ref[0] = out.reshape(heads_kv, rows, Dv).astype(out_ref.dtype)
 
 
 def _chunked_scales(scales, layer, tables, ngrp: int, R: int):
@@ -586,13 +640,13 @@ def _chunked_scales(scales, layer, tables, ngrp: int, R: int):
 
 @functools.partial(jax.jit, static_argnames=("nb", "interpret",
                                              "window", "scale",
-                                             "softcap"))
+                                             "softcap", "value_dim"))
 def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
                            nb: int, interpret: bool = False,
                            k_scales=None, v_scales=None,
                            window: int = 0,
                            scale: float = None, softcap: float = 0.0,
-                           layer=None):
+                           layer=None, value_dim: int = 0):
     """paged_attention specialized for short query windows (T <=
     DECODE_T_MAX): same contract, same result, work in proportion to
     the rows' live tokens.
@@ -603,7 +657,10 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
     start >= MB*Bs returns zeros. k_scales/v_scales
     [(L,) N, Hkv, Bs] fp32 activate the int8-pool mode (panels stream
     as int8, half the KV bytes of the bf16 pool; the scales multiply
-    scores and probabilities).
+    scores and probabilities). The latent pool: k_pool
+    [(L,) N, 1, Bs, W], v_pool None, value_dim its value columns, q the
+    absorbed queries [B, T, H, W] -> [B, T, H, value_dim]; each live
+    block is copied once.
     """
     B, T, H, D = q.shape
     layer, k_pool, v_pool, k_scales, v_scales = _whole_pool(
@@ -613,7 +670,9 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
     if scale is None:
         scale = D ** -0.5
     quant = k_scales is not None
-    R = decode_blocks_per_step(nb, Hkv, Bs, D, k_pool.dtype.itemsize)
+    R = decode_blocks_per_step(nb, Hkv, Bs, D, k_pool.dtype.itemsize,
+                               latent=bool(value_dim))
+    Dv = value_dim or D
     ngrp = -(-nb // R)
     rows = T * G
     tables = jnp.asarray(tables, jnp.int32)
@@ -629,11 +688,11 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
     kernel = functools.partial(
         _paged_decode_kernel, T=T, heads_kv=Hkv, groups=G,
         block_size=Bs, nb=nb, R=R, scale=scale, quant=quant,
-        window=window, softcap=softcap)
-    in_specs = [pl.BlockSpec((1, Hkv, rows, D), row_index),
-                pl.BlockSpec(memory_space=pltpu.HBM),
-                pl.BlockSpec(memory_space=pltpu.HBM)]
-    operands = [qh, k_pool, v_pool]
+        window=window, softcap=softcap, value_dim=value_dim)
+    pools = [k_pool] if value_dim else [k_pool, v_pool]
+    in_specs = [pl.BlockSpec((1, Hkv, rows, D), row_index)] + [
+        pl.BlockSpec(memory_space=pltpu.HBM) for _ in pools]
+    operands = [qh, *pools]
     if quant:
         in_specs += [pl.BlockSpec((1, ngrp, Hkv, R * Bs), row_index)] * 2
         operands += [_chunked_scales(s, layer, tables, ngrp, R)
@@ -644,20 +703,19 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
             num_scalar_prefetch=3,
             grid=(B,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, Hkv, rows, D), row_index),
+            out_specs=pl.BlockSpec((1, Hkv, rows, Dv), row_index),
             scratch_shapes=[
-                pltpu.VMEM((2, R, Hkv, Bs, D), k_pool.dtype),
-                pltpu.VMEM((2, R, Hkv, Bs, D), v_pool.dtype),
+                *(pltpu.VMEM((2, R, Hkv, Bs, D), p.dtype) for p in pools),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((Hkv * rows, R * Bs), jnp.float32),
                 pltpu.VMEM((Hkv * rows, 1), jnp.float32),
                 pltpu.VMEM((Hkv * rows, 1), jnp.float32),
-                pltpu.VMEM((Hkv * rows, D), jnp.float32),
-                pltpu.VMEM((Hkv * rows, D), jnp.float32),
+                pltpu.VMEM((Hkv * rows, Dv), jnp.float32),
+                pltpu.VMEM((Hkv * rows, Dv), jnp.float32),
                 pltpu.SMEM((2,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # a row hands the next its first chunk, already in flight
             dimension_semantics=("arbitrary",),
@@ -665,9 +723,9 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
         interpret=interpret,
     )(tables, jnp.asarray(starts, jnp.int32), layer, *operands)
 
-    # [B, Hkv, T*G, D] -> [B, T, H, D]
-    out = out.reshape(B, Hkv, T, G, D).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, T, H, D)
+    # [B, Hkv, T*G, Dv] -> [B, T, H, Dv]
+    out = out.reshape(B, Hkv, T, G, Dv).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, T, H, Dv)
 
 
 def paged_attention_sharded(q, k_pool, v_pool, tables, starts, mesh, *,
@@ -720,7 +778,7 @@ JNP_GATHER = "jnp_gather"
 
 
 def attention_path(T: int, groups: int, head_dim: int, block_size: int,
-                   mesh=None) -> str:
+                   mesh=None, value_dim: int = 0) -> str:
     """Which cached-attention implementation a forward over T query
     positions per row takes, for ``groups`` query heads per kv head.
     Decided here, by shape, BEFORE anything compiles — a kernel the
@@ -733,9 +791,21 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
     and several live pool blocks a chunk, copied in by the kernel.
     ``pallas_paged``: prefill chunks on the general paged kernel.
     ``*_sharded``: either, shard-local per head under a tp-only mesh.
+    ``*_latent``: either, over the latent pool (value_dim > 0: every
+    query head on the one cached vector a token, head_dim wide, of
+    which value_dim columns are the value; no mesh). Twenty heads of
+    576 make a 256-token chunk's q panel miss VMEM whole, so what must
+    fit is the smallest q block the prefill kernel cuts it into.
     ``jnp_gather``: the kernel is off (PSTPU_FLASH / not a TPU), the
     chunk's working set misses VMEM (paged_viable), or the mesh shards
     the pool's block axis."""
+    if value_dim:
+        if not (flash_enabled() and mesh is None and paged_viable(
+                min(T, _MIN_BLOCK_Q), groups, head_dim, block_size,
+                value_dim)):
+            return JNP_GATHER
+        return ("pallas_paged_decode_latent" if T <= DECODE_T_MAX
+                else "pallas_paged_latent")
     if not (flash_enabled()
             and paged_viable(T, groups, head_dim, block_size)
             and (mesh is None or mesh_tp_only(mesh))):

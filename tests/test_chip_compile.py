@@ -271,7 +271,7 @@ def _runner_shapes(topo, tp: int, layers=None, kv_blocks=None,
     from production_stack_tpu.engine.runner import ModelRunner
     from production_stack_tpu.models import llama
     from production_stack_tpu.models.config import get_config
-    from production_stack_tpu.models.kv import make_cache
+    from production_stack_tpu.models.kv import cache_for
     from production_stack_tpu.ops.rope import rope_table
     from production_stack_tpu.parallel.sharding import (
         cache_pspec, param_shardings)
@@ -284,15 +284,15 @@ def _runner_shapes(topo, tp: int, layers=None, kv_blocks=None,
     runner = ModelRunner.__new__(ModelRunner)
     runner.model_cfg, runner.engine_cfg, runner.mesh = mcfg, ecfg, mesh
     runner._lora, runner._lora_scaling = None, 1.0
-    runner.rope = rope_table(ecfg.max_model_len, mcfg.head_dim_,
+    runner.rope = rope_table(ecfg.max_model_len, mcfg.rope_dim_,
                              mcfg.rope_theta, scaling=mcfg.rope_scaling)
 
     params = jax.eval_shape(
         partial(llama.init_params, mcfg, quantization="int8"),
         jax.random.PRNGKey(0))
     cache = jax.eval_shape(partial(
-        make_cache, mcfg.num_layers, kv_blocks or ecfg.num_kv_blocks,
-        ecfg.kv_block_size, mcfg.num_kv_heads, mcfg.head_dim_))
+        cache_for, mcfg, kv_blocks or ecfg.num_kv_blocks,
+        ecfg.kv_block_size))
     if mesh is None:
         p_sh = jax.tree.map(lambda _: rep_sh, params)
         c_sh = jax.tree.map(lambda _: rep_sh, cache)
@@ -548,6 +548,136 @@ def test_dense_decode_window_has_no_expert_call(topo, tpu_branches):
     kernels = {c for c in calls if c.startswith(("paged", "moe"))}
     assert kernels == {"paged_decode_attention"}, calls
     assert "moe_" not in hlo
+
+
+# ---------------------------------------------------------------------
+# latent attention over the latent pool (GLM-4.7-Flash: 20 heads on one
+# cached vector of 512 + 64 values a token, the first 512 its value)
+# ---------------------------------------------------------------------
+
+GLM = dict(H=20, W=640, R=512)     # W: 576 values in whole lanes
+
+
+def _lower_latent_attention(topo, *, B, T, nb, layers=13):
+    """The latent pool's attention call at GLM-4.7-Flash's widths as
+    models/kv.attend makes it: the whole pool [L, N, 1, Bs, W] with the
+    layer as an operand, no V pool, the absorbed queries [B, T, 20, W]."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    kernel = (pallas_paged.paged_decode_attention
+              if T <= pallas_paged.DECODE_T_MAX
+              else pallas_paged.paged_attention)
+
+    def call(q, pool, tables, starts, layer):
+        return kernel(q, pool, None, tables, starts, nb=nb, layer=layer,
+                      scale=256 ** -0.5, value_dim=GLM["R"])
+
+    return jax.jit(call).lower(
+        shape((B, T, GLM["H"], GLM["W"]), jnp.bfloat16),
+        shape((layers, 386, 1, BS, GLM["W"]), jnp.bfloat16),
+        shape((B, MB), jnp.int32), shape((B,), jnp.int32),
+        shape((), jnp.int32))
+
+
+@pytest.mark.parametrize("B,T,nb", [
+    (16, 1, 8), (16, 1, 32), (1, 1, 8),          # decode steps
+    (1, 256, 4), (1, 256, 8), (16, 256, 4), (16, 256, 8),  # prefill
+    (1, 64, 4), (1, 128, 4)])
+def test_latent_kernels_compile(topo, B, T, nb):
+    """Both paged kernels' latent case at the cell's shapes: decode
+    steps of 16 rows (and one) at the 512 and 2048 kv buckets, prefill
+    chunks of 1 and 16 rows x 256 tokens (and the smaller buckets)
+    against contexts of 256 and 512. The keys are 576 values padded to
+    640 (five lanes of 128: the compiler refuses to copy 576 columns
+    out of the 640 the array takes in HBM anyway) and the values their
+    first 512 columns."""
+    hlo = _lower_latent_attention(topo, B=B, T=T, nb=nb).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    name = ("paged_decode_attention" if T <= pallas_paged.DECODE_T_MAX
+            else "paged_attention")
+    assert f"%{name}" in hlo, "the trace readers find the kernel by name"
+
+
+def test_latent_decode_kernel_copies_a_block_once(topo):
+    """The latent decode kernel's module has ONE operand in HBM (the
+    pool: no V pool beside it) and one set of VMEM slots, [2, R, 1, Bs,
+    W]; bf16 panels go to the MXU as they lie."""
+    import re
+    text = _kernel_module(_lower_latent_attention(topo, B=16, T=1, nb=8))
+    signature = text[:text.index("):")]
+    assert re.findall(r"memref<([\dx]+)xbf16, #tpu.memory_space<hbm>>",
+                      signature) == ["13x386x1x64x640"]
+    assert signature.count("2x8x1x64x640xbf16") == 1
+    matmuls = re.findall(
+        r'tpu\.matmul"?\(.*?: \(vector<([\dx]+)x(\w+)>, '
+        r'vector<([\dx]+)x(\w+)>, vector<[\dx]+xf32>\)', text)
+    assert matmuls and {(a, b) for _, a, _, b in matmuls} == {
+        ("bf16", "bf16")}, matmuls
+
+
+def _glm_runner(topo, layers=3):
+    """The runner skeleton at GLM-4.7-Flash's widths: one dense layer
+    and ``layers - 1`` expert layers, a latent pool of 97 blocks."""
+    return _runner_shapes(topo, 1, layers=layers, kv_blocks=97,
+                          model="glm-4.7-flash")
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk",
+                                     "prefill_chunk_1row"])
+def test_latent_step_program_never_copies_the_pool(topo, tpu_branches,
+                                                   program):
+    """never_copies_the_pool for the latent pool [3, 97, 1, 64, 576]:
+    the leading dense layer outside the scan and the scanned expert
+    layers append to and read ONE carried buffer."""
+    import re
+    L, N = 3, 97
+    runner, params, cache, rep = _glm_runner(topo, L)
+    if program == "decode_window":
+        compiled = _compile_decode_window(runner, params, cache, rep)
+    else:
+        compiled = _compile_prefill_chunk(
+            runner, params, cache, rep, 256,
+            {"prefill_chunk": 0, "prefill_chunk_1row": 1}[program])
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    pool_result = re.compile(
+        r"([\w.\-]+) = \(?\w+\[(?:{},)?{},1,{},{}\]\S* ([\w\-]+)\("
+        .format(L, N, BS, GLM["W"]))
+    moved = [m.group(1) + ": " + m.group(2)
+             for m in map(pool_result.search, hlo.splitlines()) if m
+             and re.search(r"copy|dynamic.slice|dynamic.update.slice",
+                           m.group(1) + " " + m.group(2))]
+    assert not moved, moved
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= L * N * BS * GLM["W"] * 2)
+
+
+def test_latent_decode_window_makes_no_key_or_value_per_head(
+        topo, tpu_branches):
+    """The decode executable of the latent model is absorbed: its
+    attention is the paged decode kernel on the pool, the experts are
+    the list path's call on the expert layers' stacks in place, and no
+    instruction yields keys or values per head — an array whose minor
+    dimensions are [20, 256] / [20, 192] / [20, 448] over the batch's
+    16 rows and a context axis (>= one block of 64 tokens)."""
+    import re
+    runner, params, cache, rep = _glm_runner(topo)
+    hlo = _compile_decode_window(runner, params, cache, rep).as_text()
+    assert "%paged_decode_attention" in hlo
+    assert "moe_list_experts" in hlo
+    def leading(m):
+        return [int(n) for n in m.group(1).split(",") if n]
+    # (W_kvb itself is [512, 20, 448]: a context axis comes with the
+    # batch's 16 rows beside it)
+    per_head = [m.group(0) for m in re.finditer(
+        r"\w+\[((?:\d+,)*)20,(?:256|192|448)\]", hlo)
+        if 16 in leading(m) and max(leading(m)) >= BS]
+    assert not per_head, per_head[:5]
+    stack = r"(?:(?:2|1),)?64,(?:2048,1536|1536,2048)"
+    assert not _stack_makers(hlo, stack)
 
 
 @pytest.mark.slow

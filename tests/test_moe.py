@@ -533,3 +533,93 @@ def test_forward_of_several_positions_reads_every_expert(kernels_on, B, T):
     _, _, read = llama.forward(params, cfg, toks, pos, cache,
                                block_tables=tables)
     assert int(read) == cfg.num_layers * cfg.num_experts
+
+
+# ---------------------------------------------------------------------
+# route: GLM-4.7-Flash's score, selection bias, renormalisation, scale
+# ---------------------------------------------------------------------
+
+def _route_numpy(x, w, k, bias, scale):
+    """noaux_tc in ten lines: sigmoid scores, the top k of scores +
+    bias, the chosen scores WITHOUT the bias, renormalised, scaled."""
+    sc = 1.0 / (1.0 + np.exp(-(x.astype(np.float64)
+                               @ w.astype(np.float64))))
+    top_i = np.argsort(-(sc + bias), axis=-1, kind="stable")[:, :k]
+    top_p = np.take_along_axis(sc, top_i, axis=-1)
+    top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+    return scale * top_p, top_i
+
+
+def _route_parent(x, router_w, top_k, renormalize=True):
+    """ops/moe.route as it stood before the score, bias and scale
+    arguments (PR 34), verbatim."""
+    logits = jnp.einsum("nh,he->ne", x, router_w,
+                        preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_i.astype(jnp.int32)
+
+
+def _router_inputs(n=48, h=64, e=16, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (n, h), jnp.float32),
+            0.3 * jax.random.normal(ks[1], (h, e), jnp.float32),
+            0.1 * jax.random.normal(ks[2], (e,), jnp.float32))
+
+
+def test_route_sigmoid_bias_renormalise_scale_matches_numpy():
+    x, w, bias = _router_inputs()
+    top_p, top_i = moe.route(x, w, 4, renormalize=True, score="sigmoid",
+                             bias=bias, scale=1.8)
+    want_p, want_i = _route_numpy(np.asarray(x), np.asarray(w), 4,
+                                  np.asarray(bias, np.float64), 1.8)
+    assert np.array_equal(np.asarray(top_i), want_i)
+    np.testing.assert_allclose(np.asarray(top_p), want_p, rtol=2e-6)
+    np.testing.assert_allclose(np.asarray(top_p).sum(-1), 1.8, rtol=1e-6)
+
+
+def test_route_bias_moves_the_selection_and_never_the_weights():
+    """A bias that lifts expert 0 over everything puts it into every
+    row's chosen set; its WEIGHT is still its bias-free score."""
+    x, w, _ = _router_inputs(seed=1)
+    none = jnp.zeros((16,), jnp.float32)
+    lift = none.at[0].set(10.0)
+    p0, i0 = moe.route(x, w, 4, renormalize=False, score="sigmoid",
+                       bias=none)
+    p1, i1 = moe.route(x, w, 4, renormalize=False, score="sigmoid",
+                       bias=lift)
+    assert not np.array_equal(np.asarray(i0), np.asarray(i1))
+    assert np.all(np.asarray(i1)[:, 0] == 0)
+    sc = jax.nn.sigmoid(x @ w)
+    np.testing.assert_array_equal(
+        np.asarray(p1), np.asarray(jnp.take_along_axis(sc, i1, -1)))
+    assert float(p1.max()) < 1.0            # no 10.0 leaked in
+    # a zero bias selects what no bias selects
+    pn, i_n = moe.route(x, w, 4, renormalize=False, score="sigmoid")
+    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i_n))
+    np.testing.assert_array_equal(np.asarray(p0), np.asarray(pn))
+
+
+@pytest.mark.parametrize("renormalize", [True, False],
+                         ids=["mixtral", "qwen"])
+def test_route_softmax_is_bit_equal_to_the_parents(renormalize):
+    """Mixtral's (renormalised) and Qwen's (raw) routing did not move
+    by a bit when route learnt the sigmoid, the bias and the scale."""
+    x, w, _ = _router_inputs(n=256, seed=2)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        got = jax.jit(lambda a, b: moe.route(
+            a, b, 4, renormalize=renormalize))(x.astype(dtype),
+                                               w.astype(dtype))
+        want = jax.jit(lambda a, b: _route_parent(
+            a, b, 4, renormalize))(x.astype(dtype), w.astype(dtype))
+        for g, t in zip(got, want):
+            assert g.dtype == t.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(t))
+
+
+def test_route_refuses_an_unknown_score():
+    x, w, _ = _router_inputs()
+    with pytest.raises(ValueError, match="router score"):
+        moe.route(x, w, 2, score="tanh")
