@@ -79,6 +79,13 @@ STEP_PHASES: Tuple[str, ...] = (
     "housekeeping", "between_steps", "no_work", "compile")
 
 
+# Why a prefill dispatch waited for the device queue to empty where it
+# could not join it (engine._prefill_drains; docs/engine.md "The
+# in-flight queue"): each names state that only the host holds.
+DRAIN_REASONS: Tuple[str, ...] = (
+    "guided", "shaped", "resume", "speculation", "reshape", "pressure")
+
+
 class _Span:
     """One entry of one phase on the engine thread: a context manager
     made by ``EngineEffAccounting.phase``. Spans nest; a span's own
@@ -179,6 +186,11 @@ class EngineEffAccounting:
         self.prefill_pad = 0
         self.prefill_dispatches = 0
         self.prefill_by_rows: Dict[int, int] = {}
+        # prefill dispatches that went behind the windows in flight,
+        # and those that drained the queue first, by reason
+        self.prefill_behind = 0
+        self.prefill_drained: Dict[str, int] = dict.fromkeys(
+            DRAIN_REASONS, 0)
         # MoE decode steps: experts whose weights were read, and what
         # reading every expert would have read (note_window); reported
         # as ``totals.moe`` by a MoE engine alone. expert_bytes: one
@@ -219,6 +231,7 @@ class EngineEffAccounting:
         self._stack: List[_Span] = []
         self._cur: Dict[str, float] = {}        # phase -> s, this step
         self._cur_starved: Dict[str, float] = {}
+        self._cur_prefill: Dict[str, int] = {}  # dispatches, this step
         self._root_end: Optional[float] = None  # last outermost exit
         # since when the device has had nothing of ours outstanding
         # while work waited (None: it is busy). Set where the timeline
@@ -283,18 +296,27 @@ class EngineEffAccounting:
             self._windows.append(entry)
 
     def note_prefill(self, *, bucket: int, batch: int,
-                     real_tokens: int) -> None:
+                     real_tokens: int,
+                     drained: Optional[str] = None) -> None:
         """One prefill bucket group, dispatched at ``batch`` rows:
         ``batch * bucket`` token positions were computed;
         ``real_tokens`` were actual prompt-chunk tokens, the rest
-        bucket right-padding and spare parked rows."""
+        bucket right-padding and spare parked rows. ``drained``: why
+        the device queue was emptied before it (a name of
+        DRAIN_REASONS); None where it went behind the queue."""
         total = batch * bucket
+        path = "prefill_behind" if drained is None else "drained_" + drained
+        self._cur_prefill[path] = self._cur_prefill.get(path, 0) + 1
         with self._lock:
             self.prefill_real += real_tokens
             self.prefill_pad += max(0, total - real_tokens)
             self.prefill_dispatches += 1
             self.prefill_by_rows[batch] = (
                 self.prefill_by_rows.get(batch, 0) + 1)
+            if drained is None:
+                self.prefill_behind += 1
+            else:
+                self.prefill_drained[drained] += 1
 
     # -- step timeline (engine thread only) ------------------------------
 
@@ -357,6 +379,7 @@ class EngineEffAccounting:
                           else self._root_end)
         self._root_end = span.t1
         cur, starved = self._cur, self._cur_starved
+        prefills, self._cur_prefill = self._cur_prefill, {}
         self._cur, self._cur_starved = {}, {}
         is_step = span.label == "pstpu.step"
         with self._lock:
@@ -372,7 +395,10 @@ class EngineEffAccounting:
                     "at_unix": round(self._wall() - elapsed, 4),
                     "wall_s": round(elapsed, 6),
                     "phase_s": {k: round(v, 6) for k, v in cur.items()},
-                    "starved_s": round(sum(starved.values()), 6)})
+                    "starved_s": round(sum(starved.values()), 6),
+                    # prefill dispatches of the step: ``prefill_behind``
+                    # or ``drained_<reason>``, where it made any
+                    **prefills})
 
     # -- compile observer (ModelRunner hook) -----------------------------
 
@@ -455,7 +481,9 @@ class EngineEffAccounting:
                     "starved_s": round(sum(self.starved_s.values()), 6),
                     "starved_by_phase": {
                         k: round(v, 6)
-                        for k, v in self.starved_s.items()}},
+                        for k, v in self.starved_s.items()},
+                    "prefill_behind": self.prefill_behind,
+                    "prefill_drained": dict(self.prefill_drained)},
             }
 
     def rates(self, horizon_s: float = 10.0,

@@ -78,6 +78,23 @@ class _Window:
     experts_read: object = None
 
 
+@dataclass
+class _Prefill:
+    """One prefill dispatch in flight: its place in the device queue is
+    its place in ``_inflight``. What needs its result (a first token,
+    prefix registration, connector progress) happens when it is
+    retired (_land_prefill)."""
+    group: list             # the PrefillWork of each row, in row order
+    devs: tuple             # device (ids, logprobs, tops), by row
+    # each row's sequence's admit_time at dispatch: a sequence that was
+    # preempted since, admitted again or not, is not this entry's
+    admitted: List[float]
+    # the sequences whose first token was joined to the decode carry on
+    # the device (_join_carry): the device is one token past the host
+    # for them, plus the windows dispatched since
+    joined: List[Sequence]
+
+
 # finished sequences kept for post-hoc inspection (bounded; see _remember)
 _FINISHED_RETENTION = 1024
 
@@ -358,15 +375,18 @@ class LLMEngine:
         # actually speculate — a stale device history can only degrade
         # DRAFT quality, never correctness (verification ignores it)
         self._hist_dirty = True
-        # decode windows kept in flight between step() calls (FIFO of
-        # _Window).
+        # what is queued on the device between step() calls, in
+        # dispatch order (FIFO of _Window and _Prefill; docs/engine.md
+        # "The in-flight queue").
         # Up to cfg.pipeline_depth windows ride the device queue at once:
         # window N+1 is dispatched BEFORE window N's results are synced,
         # so the device starts N+1 the instant N retires instead of
         # idling one host round-trip. Valid because decode inputs are
         # device-carried; the host only has to stay out of the way
-        # (no mirror uploads) until every queued window is processed.
-        self._inflight: List[_Window] = []
+        # (no mirror uploads) until every queued entry is retired. A
+        # prefill joins the queue behind them (_prefill_drains says
+        # when it may) and the carry is edited by slot on the device.
+        self._inflight: List[object] = []
         # continuous batching across windows (docs/engine.md
         # "Continuous batching across windows"): the device carry's
         # current batch bucket (dispatches at a different bucket must
@@ -603,16 +623,21 @@ class LLMEngine:
             with self._phase("schedule"):
                 works, decode_seqs = self.scheduler.schedule()
             if works:
-                # drain the in-flight window first: it was dispatched
-                # from pre-prefill state and stays valid; the prefill's
-                # writes are ordered after it on device
-                outputs.extend(self._drain_decode())
+                # the chunk joins the device queue behind the windows in
+                # flight: they were dispatched from pre-prefill state
+                # and stay valid, its writes are ordered after them on
+                # the device, and its first token joins the carry there
+                # (_do_prefill). Where that needs state only the host
+                # holds, the queue is emptied first.
+                drained = self._prefill_drains(works)
+                if drained is not None:
+                    outputs.extend(self._drain_decode())
                 with self._phase("prefill_host"):
-                    outputs.extend(self._do_prefill(works))
-                # re-snapshot: sequences whose prefill just completed are
-                # RUNNING now and must join this step's decode window —
-                # the device generates tokens for every live row, and a
-                # row the host skipped would desync the device carry
+                    self._do_prefill(works, drained)
+                # re-snapshot: sequences whose prompt is now whole are
+                # RUNNING and part of the next window dispatched — the
+                # device generates tokens for every live row, and a row
+                # the host skipped would desync the device carry
                 decode_seqs = list(self.scheduler.running.values())
             if decode_seqs or self._inflight:
                 with self._phase("decode_host"):
@@ -620,7 +645,7 @@ class LLMEngine:
                         self._dispatch_decode(decode_seqs)
                     # optimistic pipelining: top the device queue up to
                     # cfg.pipeline_depth windows BEFORE blocking on the
-                    # front window's sync — with window N+1 already
+                    # front entry's sync — with window N+1 already
                     # queued behind N, the device starts N+1 the instant
                     # N retires instead of idling one host round-trip
                     # (the timeline's starved seconds say how long that
@@ -630,15 +655,15 @@ class LLMEngine:
                     # window continues from its predecessor's final
                     # tokens/positions regardless of what the host
                     # decides; rows whose sequence turns out to have
-                    # finished are discarded at the next drain (their
-                    # writes only touch blocks still owned by the
-                    # finished sequence — never registered-prefix
-                    # blocks, which are always full). Only when the
-                    # device carry is self-contained: a dirty
-                    # decode/sampling state means the next dispatch
-                    # must upload host mirrors, and mid-processing
-                    # mirrors lag the device (uploading them would
-                    # rewind live rows and duplicate tokens).
+                    # finished are discarded when their window is
+                    # retired (their writes only touch blocks still
+                    # owned by the finished sequence — never
+                    # registered-prefix blocks, which are always full).
+                    # Only when the device carry is self-contained: a
+                    # dirty decode/sampling state means the next
+                    # dispatch must upload host mirrors, and
+                    # mid-processing mirrors lag the device (uploading
+                    # them would rewind live rows and duplicate tokens).
                     self._top_up_pipeline()
                 outputs.extend(self._retire_window("decode"))
                 if not self._inflight:
@@ -775,12 +800,13 @@ class LLMEngine:
         return {"warmed": warmed, "missed": missed}
 
     def _top_up_pipeline(self) -> None:
-        """Queue optimistic decode windows behind the in-flight one(s)
-        up to cfg.pipeline_depth, provided the device carry is
+        """Queue optimistic decode windows behind what is in flight, up
+        to cfg.pipeline_depth windows, provided the device carry is
         self-contained (no pending mirror uploads) and the extra window
         is unlikely to be pure discarded work."""
         while (self._inflight
-               and len(self._inflight) < self.cfg.pipeline_depth
+               and sum(isinstance(e, _Window) for e in self._inflight)
+               < self.cfg.pipeline_depth
                and not self._decode_dirty and not self._sampling_dirty
                and not (self.cfg.speculative_ngram_tokens
                         and self._hist_dirty)
@@ -791,17 +817,31 @@ class LLMEngine:
                and not (self.cfg.window_adapt
                         and self._admission_imminent())
                and self._worth_dispatch_ahead()):
-            ahead = sum(w.steps for w in self._inflight)
             if not self._dispatch_decode(
-                    list(self.scheduler.running.values()), ahead=ahead):
+                    list(self.scheduler.running.values())):
                 break
+
+    def _device_leads(self):
+        """How many tokens the device is past the host, by row: (the
+        decode steps of every window in flight: the lead of a row that
+        is part of them all, {seq_id: lead} for the rows a prefill
+        entry in flight joined to the carry: one for the first token,
+        plus the steps of the windows dispatched behind that entry)."""
+        steps, joined = 0, {}
+        for entry in reversed(self._inflight):
+            if isinstance(entry, _Window):
+                steps += entry.steps
+            else:
+                for seq in entry.joined:
+                    joined[seq.seq_id] = steps + 1
+        return steps, joined
 
     def _worth_dispatch_ahead(self) -> bool:
         """Skip the optimistic window when every live sequence could
         reach its token budget within the windows already in flight —
         then the whole dispatch would likely be discarded work (and
         would delay the next admission wave by one window)."""
-        inflight_steps = sum(w.steps for w in self._inflight)
+        inflight_steps = self._device_leads()[0]
         live = [s for s in self.scheduler.running.values()
                 if s.status is SeqStatus.RUNNING]
         if not live:
@@ -810,6 +850,83 @@ class LLMEngine:
             s.options.max_tokens is None
             or s.options.max_tokens - len(s.output_tokens) > inflight_steps
             for s in live)
+
+    def _prefill_drains(self, works) -> Optional[str]:
+        """THE rule for a prefill dispatch (in the manner of
+        ops/pallas_paged.attention_path and ops/moe.list_path: no
+        option selects): None where its chunks go behind the windows in
+        flight, else why the queue is emptied first (a name of
+        efficiency.DRAIN_REASONS). Behind is the rule; each exception
+        needs, before the next window may be dispatched, state that only
+        the host holds once the chunk's result is back:
+
+        ``speculation``  the n-gram history [B, S] of the new row;
+        ``guided``       the DFA state its first token leads to;
+        ``shaped``       the [B, V] counts with its first token in them;
+        ``resume``       a preempted sequence's last EMITTED token is its
+                         next input, not the id the chunk samples;
+        ``reshape``      the carry is replaced whole anyway (mirrors to
+                         upload, a row outside its batch, another batch
+                         bucket or compaction due);
+        ``pressure``     the pool cannot cover the window behind the
+                         chunk: someone has to be preempted, which an
+                         optimistic dispatch never does.
+
+        The first four hold for every chunk of such a sequence (one
+        rule a sequence, whatever the chunk). The last two are about
+        the join: a step whose chunks all leave their prompts unfinished
+        joins nothing and goes behind the queue whatever the carry is
+        due."""
+        if self.cfg.speculative_ngram_tokens:
+            return "speculation"
+        seqs = [w.seq for w in works]
+        if any(s.grammar is not None for s in seqs):
+            return "guided"
+        if any(s.options.shaped for s in seqs):
+            return "shaped"
+        if any(s.output_tokens for s in seqs):
+            return "resume"
+        joining = [w.seq for w in works if w.is_last]
+        if not joining:
+            return None
+        if (self._decode_dirty
+                or self._wanted_batch() != self._carry_batch
+                or any(s.slot >= self._carry_batch for s in joining)):
+            return "reshape"
+        if self._pool_short(joining):
+            return "pressure"
+        return None
+
+    def _wanted_batch(self) -> int:
+        """The batch bucket a dispatch into an empty queue would give
+        the carry now (_launch_window: compaction packs the live rows
+        low, a variant off the warmed grid pins the full batch). Where
+        it is not the carry's, a reshape is due, and only an empty
+        queue lets one happen."""
+        live = ([s for s in self.scheduler.running.values()
+                 if s.status is SeqStatus.RUNNING]
+                + list(self.scheduler._prefilling.values()))
+        if not live:
+            return self._carry_batch
+        if not self._adapts(live, self._device_leads()[0]):
+            return self.cfg.max_num_seqs
+        return self.cfg.batch_bucket_for(len(live))
+
+    def _pool_short(self, joining) -> bool:
+        """The free blocks do not cover the longest window that could
+        be queued behind the chunks that make ``joining`` rows live
+        (_launch_window's coverage, at cfg.decode_window)."""
+        ahead, joined = self._device_leads()
+        rows = [(s, joined.get(s.seq_id, ahead))
+                for s in self.scheduler.running.values()
+                if s.status is SeqStatus.RUNNING]
+        rows += [(s, 1) for s in joining]
+        need = sum(
+            max(0, self.block_mgr.blocks_for(min(
+                s.next_position + lead + self.cfg.decode_window + 1,
+                self.cfg.max_model_len)) - len(s.block_ids))
+            for s, lead in rows)
+        return need > self.block_mgr.available
 
     # adaptive window sizing: the largest window bucket whose EXPECTED
     # dead fraction (finished-row tails, from remaining max_tokens
@@ -960,16 +1077,20 @@ class LLMEngine:
                     self._slot_stop_ids, self._slot_gstate):
             arr[new] = arr[old]
         self._set_table_row(new, seq.block_ids)
-        # park AFTER copying (resets old's mirrors, marks carries
-        # dirty); the moved row's sampling differs from the parked
-        # defaults park left at `new`, so force the sampling re-upload
+        # the next dispatch rebuilds every carry from the mirrors; park
+        # AFTER copying (resets old's mirrors). The moved row's
+        # sampling differs from the parked defaults park left at
+        # `new`, so force the sampling re-upload
+        self._decode_dirty = True
+        self._hist_dirty = True
         self._park_slot(old)
         self._set_table_row(old, [])
         sched._free_slot(old)
         self._sampling_dirty = True
 
-    def _do_prefill(self, works) -> List[StepOutput]:
-        """Prefill every scheduled chunk. The chunks due in one
+    def _do_prefill(self, works, drained: Optional[str]) -> None:
+        """Dispatch every scheduled chunk; each dispatch becomes a
+        _Prefill entry of the in-flight queue. The chunks due in one
         chunk-length bucket run in dispatches of cfg.prefill_rows_for
         rows: up to a quarter of the batch, a one-row dispatch each
         (one prompt due is one row computed, the steady case); more,
@@ -977,10 +1098,18 @@ class LLMEngine:
         a dispatch's chunks take its rows 0.. in order, ``slots`` tells
         the executable which slot each serves, and what it returns is
         read by row (_land_prefill).
-        Runs under the ``prefill_host`` phase: what is not inside one
-        of the three phases below is host preparation (grouping, table
-        and sampling uploads)."""
-        outputs: List[StepOutput] = []
+
+        What makes a sequence RUNNING and its row part of the next
+        window happens here, at dispatch (scheduler.on_prefill_done,
+        and with ``drained`` None the first token's join to the carry,
+        on the device: _join_carry); what needs the chunk's result
+        happens when the entry is retired. ``drained``: why the queue
+        was emptied first (_prefill_drains), None if it was not; then
+        the next decode dispatch uploads the host mirrors, which the
+        entry's landing, due before it, brings up to date.
+        Runs under the ``prefill_host`` phase: what is not inside
+        ``prefill_dispatch`` is host preparation (grouping, table and
+        sampling uploads, the join)."""
         for w in works:
             self._sync_sampling(w.seq)
         self._ensure_dev_sampling()
@@ -995,13 +1124,12 @@ class LLMEngine:
                            for i in range(0, len(due), rows)]
         if any(w.seq.options.shaped for w in works if w.is_last):
             # last-chunk rows sample their first token with shaped
-            # logits; mirrors are current (all in-flight windows were
-            # drained before prefill), and one upload serves every
-            # dispatch of the step: the state is per slot. The next
-            # decode dispatch rebuilds AGAIN on the same step — not
-            # redundant: that rebuild includes the first tokens this
-            # very prefill samples, which prefill executables don't
-            # record device-side
+            # logits; mirrors are current (a shaped prefill drains the
+            # queue first), and one upload serves every dispatch of
+            # the step: the state is per slot. The next decode dispatch
+            # rebuilds AGAIN — not redundant: that rebuild includes the
+            # first tokens this very prefill samples, which prefill
+            # executables don't record device-side
             self.runner.set_penalty_state(*self._penalty_arrays())
         B, S = self.cfg.max_num_seqs, self.cfg.max_model_len
         for bucket, rows, group in dispatches:
@@ -1044,32 +1172,72 @@ class LLMEngine:
             # were real
             self.eff.note_prefill(
                 bucket=bucket, batch=rows,
-                real_tokens=sum(len(w.chunk) for w in group))
+                real_tokens=sum(len(w.chunk) for w in group),
+                drained=drained)
+            entry = _Prefill(
+                group, devs, [w.seq.admit_time for w in group],
+                joined=[w.seq for w in group
+                        if w.is_last and drained is None])
+            for w in group:
+                if w.seq.waits.prefill_call is None:
+                    w.seq.waits.prefill_call = call.t1
+                w.seq.waits.prefill_chunks += 1
+                self.scheduler.on_prefill_done(w)
+                self.metrics.prompt_tokens.inc(len(w.chunk))
+            if entry.joined:
+                self._join_carry(entry, rows, starts + lengths)
+            self._inflight.append(entry)
+        if drained is not None:
+            # the carry is rebuilt from the mirrors, first tokens
+            # included, once these entries have landed
+            self._decode_dirty = True
+            self._hist_dirty = True
+
+    def _join_carry(self, entry: _Prefill, rows: int, ends) -> None:
+        """Make the rows of a prefill dispatch whose prompt is now
+        whole part of the decode carry, on the device: the id the
+        chunk sampled (its result, which the host has not seen) at the
+        position after the prompt (``ends`` [rows]: start + length).
+        The other rows of the dispatch name no slot."""
+        slots = np.full((rows,), self.cfg.max_num_seqs, np.int32)
+        for row, w in enumerate(entry.group):
+            if w.is_last:
+                slots[row] = w.seq.slot
+        self.runner.edit_carry(slots, entry.devs[0], ends)
+
+    def _land_prefills(self, top_up: bool) -> List[StepOutput]:
+        """Retire the prefill entries at the head of the queue.
+        ``top_up``: queue decode windows behind them first, as far as
+        the pipeline goes, so that the sync of a chunk's result does
+        not leave the device without work."""
+        outputs: List[StepOutput] = []
+        while self._inflight and isinstance(self._inflight[0], _Prefill):
+            if top_up:
+                with self._phase("decode_host"):
+                    self._top_up_pipeline()
             with self._phase("prefill_process"):
-                outputs.extend(self._land_prefill(group, devs, call.t1))
-        # prefill changed slot contents/positions: refresh decode carry
-        self._decode_dirty = True
-        self._hist_dirty = True
+                outputs.extend(self._land_prefill(self._inflight.pop(0)))
         return outputs
 
-    def _land_prefill(self, group, devs, t_called: float
-                      ) -> List[StepOutput]:
-        """Host side of one dispatched prefill group: scheduler and
-        cache bookkeeping per chunk and, for rows whose prompt is now
-        whole, the first token (one sync per group, ``prefill_sync``).
-        The group's n-th chunk ran in row n of the dispatch.
-        ``t_called``: when ``runner.prefill`` returned."""
+    def _land_prefill(self, entry: _Prefill) -> List[StepOutput]:
+        """Host side of one prefill dispatch, once it is the oldest
+        entry in flight: cache bookkeeping per chunk and, for rows
+        whose prompt is whole, the first token (one sync per entry,
+        ``prefill_sync``; no first token, no sync). The n-th chunk ran
+        in row n of the dispatch. A sequence that finished, was
+        aborted or was preempted since the dispatch has its row
+        discarded, as a finished row of a window is."""
         outputs: List[StepOutput] = []
-        ids_dev, lps_dev, tops_dev = devs
+        ids_dev, lps_dev, tops_dev = entry.devs
         ids = lps = tops = None
-        for row, w in enumerate(group):
-            if w.seq.waits.prefill_call is None:
-                w.seq.waits.prefill_call = t_called
-            w.seq.waits.prefill_chunks += 1
-            self.scheduler.on_prefill_done(w)
-            self.metrics.prompt_tokens.inc(len(w.chunk))
+        for row, (w, admitted) in enumerate(zip(entry.group,
+                                                entry.admitted)):
+            seq = w.seq
+            if (seq.admit_time != admitted or seq.status not in
+                    (SeqStatus.PREFILLING, SeqStatus.RUNNING)):
+                continue
             if (self.cfg.enable_prefix_caching
-                    and not w.seq.rolled_blocks):
+                    and not seq.rolled_blocks):
                 # LIVE progressive registration: a full block's
                 # K/V is final the moment its last position is
                 # written (write-then-attend; full blocks are
@@ -1078,7 +1246,6 @@ class LLMEngine:
                 # sequence to finish. The hasher chain state rides
                 # the sequence so each chunk keys only its NEW
                 # blocks (O(L^2) otherwise on long prompts).
-                seq = w.seq
                 seq.reg_state = self.block_mgr.register_incremental(
                     seq.prefill_tokens[:seq.num_prefilled],
                     seq.block_ids, seq.reg_state,
@@ -1087,10 +1254,9 @@ class LLMEngine:
                 # progressive publish: disagg decode engines can pull
                 # the prefix while later chunks still prefill
                 self.connector.on_prefill_progress(
-                    w.seq, salt=self._adapter_salt(w.seq.adapter_id))
+                    seq, salt=self._adapter_salt(seq.adapter_id))
             if not w.is_last:
                 continue
-            seq = w.seq
             if seq.output_tokens:
                 # preemption-recompute resume: emitted output was
                 # teacher-forced back in; the prefill's sampled id
@@ -1100,14 +1266,14 @@ class LLMEngine:
                 continue
             if ids is None:
                 with self._phase("prefill_sync"):
-                    ids = np.asarray(ids_dev)  # one sync per group
+                    ids = np.asarray(ids_dev)  # one sync per entry
                     lps = np.asarray(lps_dev)
                     tops = (None if tops_dev is None else
                             (np.asarray(tops_dev[0]),
                              np.asarray(tops_dev[1])))
-                # a prefill runs with every window drained: its
-                # sync leaves the device with nothing to do
-                self.eff.device_idle()
+                if not self._inflight:
+                    # nothing was queued behind the chunk
+                    self.eff.device_idle()
             # prompt fully prefilled: the sampled id is the first
             # output token
             k = seq.options.top_logprobs
@@ -1198,17 +1364,35 @@ class LLMEngine:
             self._decode_dirty = True   # gids/states must re-upload
         return self._guided_table, self._guided_gids
 
-    def _dispatch_decode(self, decode_seqs, ahead: int = 0) -> bool:
+    def _dispatch_decode(self, decode_seqs) -> bool:
         """Launch one decode window (_launch_window) under the
         ``decode_host`` phase; False if none was dispatched."""
         with self._phase("decode_host") as host:
-            win = self._launch_window(decode_seqs, ahead)
+            win = self._launch_window(decode_seqs)
         if win is None:
             return False
         win.host_s += host.self_s
         return True
 
-    def _launch_window(self, decode_seqs, ahead: int) -> Optional[_Window]:
+    def _adapts(self, live, ahead: int) -> bool:
+        """Whether a window over ``live`` rows may take adapted (batch,
+        window) geometry: a variant of the warmed grid (_grid_hot), at
+        the SMALLEST kv bucket, where alone the grid exists — adapted
+        geometry at a larger bucket would compile cold per (batch,
+        window) combination reached mid-serving, so the full fixed
+        geometry is pinned there instead (one lazy compile per variant,
+        the pre-r17 cost). Long-context fleets that want adaptation
+        should size --kv-len-buckets so the first bucket spans their
+        serving contexts. Probed at the largest possible window so the
+        actual kv pick (made after W is) can never exceed the probe."""
+        if not (self.cfg.window_adapt and live and self._grid_hot(live)):
+            return False
+        probe = (max(s.next_position for s in live)
+                 + self.cfg.decode_window + ahead + 1)
+        return (self.cfg.kv_bucket_for(min(probe, self.cfg.max_model_len))
+                == self.cfg.kv_len_buckets[0])
+
+    def _launch_window(self, decode_seqs) -> Optional[_Window]:
         """Launch one decode window (async dispatch; no host sync).
 
         With ``window_adapt`` on, the dispatch tracks the LIVE batch
@@ -1225,37 +1409,26 @@ class LLMEngine:
         penalized / top-k / full-sort sampling) pin the full fixed
         geometry instead (_grid_hot).
 
-        ahead > 0 = optimistic dispatch while the previous window's
-        tokens are still unprocessed on the host: device positions are
-        `ahead` steps past the host mirrors, so block coverage and the
-        kv bucket are computed from position + ahead. An optimistic
-        dispatch must leave host state untouched by the device's view:
-        it returns None WITHOUT dispatching if it would have to
-        preempt (parking rewrites the decode carry) or upload host
-        mirrors (they lag the device by `ahead` steps until the synced
-        window is processed) — the caller then falls back to the
-        ordinary process-first path. It also keeps the carry's batch
-        bucket (a bucket change is a mirror upload by definition)."""
+        With anything in flight the dispatch is OPTIMISTIC: the tokens
+        of the entries queued are still unprocessed on the host, so
+        the device is past the host mirrors, by ``ahead`` steps for a
+        row that is part of every window in flight and by its own lead
+        for a row that a prefill entry joined since (_device_leads):
+        block coverage and the kv bucket are computed from each row's
+        position on the device. An optimistic dispatch must leave host
+        state untouched by the device's view: it returns None WITHOUT
+        dispatching if it would have to preempt (that replaces the
+        decode carry) or upload host mirrors (they lag the device
+        until every entry queued is retired) — the caller then falls
+        back to the ordinary retire-first path. It also keeps the
+        carry's batch bucket (a bucket change is a mirror upload by
+        definition)."""
+        queued = bool(self._inflight)
+        ahead, joined = self._device_leads()
         live0 = [s for s in self.scheduler.running.values()
                  if s.status is SeqStatus.RUNNING]
-        adapt = self.cfg.window_adapt and self._grid_hot(live0)
-        if adapt and live0:
-            # the warmup grid exists at the SMALLEST kv bucket only:
-            # adapted geometry at a larger bucket would compile cold
-            # per (batch, window) combination reached mid-serving —
-            # pin the full fixed geometry there instead (one lazy
-            # compile per variant, the pre-r17 cost). Long-context
-            # fleets that want adaptation should size
-            # --kv-len-buckets so the first bucket spans their
-            # serving contexts. Probed at the largest possible
-            # window so the actual kv pick (made after W below) can
-            # never exceed the probe.
-            probe = (max(s.next_position for s in live0)
-                     + self.cfg.decode_window + ahead + 1)
-            adapt = (self.cfg.kv_bucket_for(
-                min(probe, self.cfg.max_model_len))
-                == self.cfg.kv_len_buckets[0])
-        if ahead == 0 and adapt and not self._inflight:
+        adapt = self._adapts(live0, ahead)
+        if adapt and not queued:
             self._compact_slots()
         W = self._choose_window(ahead) if adapt else self.cfg.decode_window
         if self._roll_window:
@@ -1267,14 +1440,18 @@ class LLMEngine:
         # Pool pressure preempts youngest-first; a sequence that cannot
         # be covered even then is preempted itself (recompute later).
         spec_w = self.cfg.speculative_ngram_tokens + 1
-        horizon = (W + ahead) * spec_w + 1
+
+        def reach(s: Sequence, per_step: int) -> int:
+            # one past the last position the window makes of row s
+            return (s.next_position
+                    + (W + joined.get(s.seq_id, ahead)) * per_step + 1)
         for s in list(decode_seqs):
             if s.status is not SeqStatus.RUNNING:
                 continue   # already preempted as a victim this pass
-            covered = self._ensure_blocks(s, s.next_position + horizon,
-                                          allow_preempt=ahead == 0)
+            covered = self._ensure_blocks(s, reach(s, spec_w),
+                                          allow_preempt=not queued)
             if not covered:
-                if ahead:
+                if queued:
                     return None   # pool pressure: no optimistic window
                 self._preempt(s)
         decode_seqs = list(self.scheduler.running.values())
@@ -1283,7 +1460,7 @@ class LLMEngine:
         # batch bucket: smallest executable covering every live slot
         # (compaction just packed them low). An optimistic dispatch
         # continues the device carry, whose batch is fixed.
-        if ahead:
+        if queued:
             batch = self._carry_batch
             if not adapt and batch != self.cfg.max_num_seqs:
                 # a pinned-geometry window (non-hot variant, or the kv
@@ -1291,8 +1468,9 @@ class LLMEngine:
                 # continue a BUCKETED carry here — that (carry batch,
                 # full window, higher kv) executable was never warmed,
                 # and an optimistic dispatch may not reshape the
-                # carry. Fall back to the process-first path: its
-                # ahead == 0 dispatch re-uploads at the full batch.
+                # carry. Fall back to the retire-first path: its
+                # dispatch into an empty queue re-uploads at the full
+                # batch.
                 return None
         else:
             # a non-hot variant window (adapt False) pins the full
@@ -1304,7 +1482,6 @@ class LLMEngine:
             if batch != self._carry_batch:
                 self._decode_dirty = True
                 self._hist_dirty = True
-        max_pos = max(s.next_position for s in decode_seqs)
         greedy = all(s.options.temperature <= 0.0 for s in decode_seqs)
         self._ensure_dev_sampling()
         gtable = gids = None
@@ -1341,9 +1518,9 @@ class LLMEngine:
             for s in spec_rows:
                 spec_ok[s.slot] = True
         kv_len = self.cfg.kv_bucket_for(
-            min(max_pos + (W + ahead) * (spec + 1) + 1,
+            min(max(reach(s, spec + 1) for s in decode_seqs),
                 self.cfg.max_model_len))
-        if ahead and (self._decode_dirty or self._sampling_dirty):
+        if queued and (self._decode_dirty or self._sampling_dirty):
             # the guided-table rebuild (or any path above) dirtied the
             # carry: uploading mid-processing mirrors would rewind the
             # device — bail, the normal path re-dispatches after
@@ -1395,22 +1572,29 @@ class LLMEngine:
         return win
 
     def _drain_decode(self) -> List[StepOutput]:
-        """Sync + process every in-flight window. A sequence that
+        """Sync + process every entry in flight. A sequence that
         finished or aborted after dispatch simply has its rows discarded
-        (its slot is parked and the decode carry marked dirty)."""
+        (its slot is parked, on the device too)."""
         outputs: List[StepOutput] = []
         while self._inflight:
             outputs.extend(self._retire_window("drain"))
         return outputs
 
     def _retire_window(self, kind: str) -> List[StepOutput]:
-        """Sync the OLDEST in-flight window and walk its tokens, under
-        the phases ``<kind>_sync`` and ``<kind>_process`` (``decode`` in
-        the step proper, ``drain`` ahead of a prefill). The window's
+        """Retire the OLDEST window in flight, in order: the prefill
+        entries ahead of it land first, then it is synced and its tokens
+        walked, under the phases ``<kind>_sync`` and ``<kind>_process``
+        (``decode`` in the step proper, ``drain`` where a prefill must
+        have the queue empty), then the prefill entries that have come
+        to the head land too — so a first token is never kept waiting
+        behind a window dispatched after its chunk, and no window is
+        walked before the first token of a row it holds. The window's
         clock is the timeline's: its seconds end where the sync phase
         does."""
+        top_up = kind == "decode"
+        outputs = self._land_prefills(top_up)
         if not self._inflight:
-            return []
+            return outputs
         with self._phase(kind + "_sync") as sync:
             win = self._sync_inflight()
         if not self._inflight:
@@ -1424,7 +1608,8 @@ class LLMEngine:
                                                sync.elapsed_s)
         window_s = sync.t1 - win.t0
         with self._phase(kind + "_process") as walk:
-            outputs, counted = self._process_window(win, window_s)
+            walked, counted = self._process_window(win, window_s)
+        outputs.extend(walked)
         if win.experts_read is not None:
             counted.update(
                 experts_read=int(win.experts_read.sum()),
@@ -1433,6 +1618,7 @@ class LLMEngine:
         self.eff.note_window(**counted, window_s=window_s,
                              host_s=win.host_s + walk.self_s,
                              sync_s=sync.self_s)
+        outputs.extend(self._land_prefills(top_up))
         return outputs
 
     def _sync_inflight(self) -> _Window:
@@ -1459,7 +1645,9 @@ class LLMEngine:
         ids, lps, counts, tops = win.ids, win.lps, win.counts, win.tops
         W, seqs, spec_ok, B = win.steps, win.seqs, win.spec_ok, win.batch
         outputs: List[StepOutput] = []
-        alive = [s for s in seqs if s.status is not SeqStatus.FINISHED]
+        # a row whose sequence finished, was aborted or was preempted
+        # since the dispatch is discarded
+        alive = [s for s in seqs if s.status is SeqStatus.RUNNING]
         walkers = len(alive)   # rows that will actually walk steps
         # window efficiency accounting: every row of the DISPATCHED
         # batch bucket B computes W steps of P positions each (P =
@@ -1747,30 +1935,45 @@ class LLMEngine:
             self._sampling_dirty = True
 
     def _park_slot(self, slot: int) -> None:
-        """Return a freed slot's mirrors to the idle state (position S —
-        its window writes clamp onto S-1, harmless because real K/V is
-        always written before attention reads; see models/kv.py)."""
-        if slot >= 0:
-            self._slot_token[slot] = 0
-            self._slot_pos[slot] = self.cfg.max_model_len
-            self._slot_gstate[slot] = 0
-            if (self._slot_presence[slot] or self._slot_frequency[slot]
-                    or self._slot_repetition[slot] != 1.0
-                    or self._slot_min_tokens[slot]
-                    or self._slot_min_p[slot]
-                    or self._slot_bias_ids[slot, 0] >= 0
-                    or self._slot_stop_ids[slot, 0] >= 0):
-                self._slot_presence[slot] = 0.0
-                self._slot_frequency[slot] = 0.0
-                self._slot_repetition[slot] = 1.0
-                self._slot_min_p[slot] = 0.0
-                self._slot_min_tokens[slot] = 0
-                self._slot_bias_ids[slot, :] = -1
-                self._slot_bias_vals[slot, :] = 0.0
-                self._slot_stop_ids[slot, :] = -1
-                self._sampling_dirty = True
+        """Return a freed slot to the idle state (position S — its
+        window writes clamp onto S-1, harmless because real K/V is
+        always written before attention reads; see models/kv.py): the
+        host mirrors, which a later upload carries, and the device
+        carry, edited at the slot behind whatever is queued
+        (runner.edit_carry), so that the pipeline keeps going across a
+        finish. Where the carry is to be replaced whole anyway the
+        upload does it: mirrors already due, a shaped row (the [B, V]
+        counts are rebuilt with the sampling mirrors), or a live batch
+        that now fits a smaller bucket (the queue is left to run dry,
+        and the dispatch into the empty queue compacts and reshapes)."""
+        if slot < 0:
+            return
+        self._slot_token[slot] = 0
+        self._slot_pos[slot] = self.cfg.max_model_len
+        self._slot_gstate[slot] = 0
+        if (self._slot_presence[slot] or self._slot_frequency[slot]
+                or self._slot_repetition[slot] != 1.0
+                or self._slot_min_tokens[slot]
+                or self._slot_min_p[slot]
+                or self._slot_bias_ids[slot, 0] >= 0
+                or self._slot_stop_ids[slot, 0] >= 0):
+            self._slot_presence[slot] = 0.0
+            self._slot_frequency[slot] = 0.0
+            self._slot_repetition[slot] = 1.0
+            self._slot_min_p[slot] = 0.0
+            self._slot_min_tokens[slot] = 0
+            self._slot_bias_ids[slot, :] = -1
+            self._slot_bias_vals[slot, :] = 0.0
+            self._slot_stop_ids[slot, :] = -1
+            self._sampling_dirty = True
+            self._decode_dirty = True
+        if (self._decode_dirty
+                or self._wanted_batch() != self._carry_batch):
             self._decode_dirty = True
             self._hist_dirty = True
+        else:
+            self.runner.edit_carry(
+                [slot], [0], [self.cfg.max_model_len])
 
     @property
     def embedding_source(self) -> str:
@@ -2153,6 +2356,9 @@ class LLMEngine:
         seq.rolled_blocks = 0   # recompute re-prefills from position 0
         seq.reg_state = None    # re-register the recomputed blocks
         self.scheduler.preempt(seq)
+        # a preemption replaces the carry: its sequence comes back
+        # through a prefill that resumes from host state
+        self._decode_dirty = True
         self._park_slot(slot)
         self._set_table_row(slot, [])
         self.metrics.preemptions.inc()

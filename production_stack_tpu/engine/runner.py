@@ -19,17 +19,21 @@ TPU execution model:
   nothing they compute is written or read. One executable per (rows,
   chunk-length bucket, kv bucket).
 - Decode inputs are *device-carried*: each window's last sampled ids and
-  advanced positions stay on device and feed the next window directly —
-  the host uploads fresh state only when slot composition changes
-  (admission / finish). A steady decode window costs exactly one
-  dispatch + one device→host sync (what that saves on a local chip is
-  not measured).
+  advanced positions stay on device and feed the next window directly.
+  A row that leaves or joins is an edit of the carry AT ITS SLOT, on
+  the device and in device order (``edit_carry``: a parked row, or the
+  id a prefill has just sampled, never seen by the host before the
+  next window reads it); the host uploads its mirrors only where the
+  carry is replaced whole (a batch-bucket change, compaction, a
+  preemption, guided or shaped rows: engine._decode_dirty). A steady
+  decode window costs exactly one dispatch + one device→host sync.
 - Both donate the KV cache => XLA updates it in place in HBM.
 
 The reference has no equivalent (engine external, SURVEY.md §1 L2); this
 is the TPU-native core the stack serves from.
 """
 
+import contextlib
 import time
 from functools import partial
 
@@ -63,6 +67,16 @@ def _named(name: str, fn, **static):
     bound = partial(fn, **static)
     bound.__name__ = bound.__qualname__ = name
     return bound
+
+
+def _carry_edit_impl(tokens, positions, gstate, slots, new_tokens,
+                     new_positions):
+    """The decode carry with rows ``slots`` [R] set to ``new_tokens`` /
+    ``new_positions`` [R] and DFA state 0. A slot outside the carry's
+    batch (the engine's "no row here" is max_num_seqs) is dropped."""
+    return (tokens.at[slots].set(new_tokens, mode="drop"),
+            positions.at[slots].set(new_positions, mode="drop"),
+            gstate.at[slots].set(0, mode="drop"))
 
 
 class ModelRunner:
@@ -231,6 +245,11 @@ class ModelRunner:
         # variant)
         self._decode_fns = {}
         self._prefill_fns = {}
+        # the carry's edit by slot (edit_carry): one jitted function;
+        # the (carry batch, rows) shapes it has run at, each built when
+        # a carry of that batch is first uploaded (set_decode_state)
+        self._carry_edit = jax.jit(_named("carry_edit", _carry_edit_impl))
+        self._carry_edit_shapes = set()
         # "kind|window|kv|batch" (the compile observer's key) -> the
         # attention path that executable was compiled on (_compile)
         self.attention_paths: Dict[str, str] = {}
@@ -632,6 +651,45 @@ class ModelRunner:
                             else jnp.asarray(guide_states, jnp.int32))
         self._dec_hist = (None if history is None
                           else jnp.asarray(history, jnp.int32))
+        # a carry of a new batch brings its edits with it: whoever
+        # warms a decode shape (warmup(), a benchmark's launcher) has
+        # then warmed what a finish and a join run at that batch
+        B = int(self._dec_tokens.shape[0])
+        for R in (1, self.engine_cfg.max_num_seqs):
+            if self._carry_edit_key(R) not in self._carry_edit_shapes:
+                self.edit_carry(np.full((R,), B, np.int32),
+                                np.zeros((R,), np.int32),
+                                np.zeros((R,), np.int32))
+
+    def _carry_edit_key(self, rows: int):
+        """What an edit of ``rows`` rows of the present carry compiles
+        for: the carry's batch and where it lies (under a mesh an
+        uploaded carry and a window's result are placed differently)."""
+        return (int(self._dec_tokens.shape[0]), rows,
+                self._dec_tokens.sharding)
+
+    def edit_carry(self, slots, tokens, positions) -> None:
+        """Edit the device carry by slot, behind whatever is queued on
+        the device and with no host sync: rows ``slots`` [R] get
+        ``tokens`` / ``positions`` [R] as their next decode input (DFA
+        state 0). ``tokens`` may be a device array: the ids a prefill
+        dispatch has just sampled join the carry without the host
+        seeing them. Parking a row is token 0 at position
+        max_model_len. A slot outside the carry's batch is dropped
+        (rows that are not to be touched name max_num_seqs)."""
+        key = self._carry_edit_key(len(slots))
+        args = (self._dec_tokens, self._dec_pos, self._dec_gstate,
+                jnp.asarray(slots, jnp.int32),
+                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(positions, jnp.int32))
+        if key in self._carry_edit_shapes:
+            out = self._carry_edit(*args)
+        else:
+            # the call compiles: stamped like every serving executable
+            with self._observed("carry_edit", key[1], 0, key[0]):
+                out = self._carry_edit(*args)
+            self._carry_edit_shapes.add(key)
+        self._dec_tokens, self._dec_pos, self._dec_gstate = out
 
     def set_penalty_state(self, out_counts, prompt_seen) -> None:
         """Upload OpenAI logit-shaping state: generated-token counts
@@ -803,6 +861,20 @@ class ModelRunner:
             positions, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
             self.engine_cfg.kv_block_size, mesh)
 
+    @contextlib.contextmanager
+    def _observed(self, kind: str, window: int, kv_len: int, batch: int):
+        """Stamp the compile made inside through ``compile_observer``."""
+        obs = self.compile_observer
+        t0 = time.monotonic()
+        if obs is not None:
+            obs.compile_started(kind, window, kv_len, batch)
+        try:
+            yield
+        finally:
+            if obs is not None:
+                obs.compile_finished(kind, window, kv_len, t0,
+                                     time.monotonic() - t0, batch)
+
     def _compile(self, cache: dict, key, make_fn, args, *, kind: str,
                  window: int, kv_len: int, batch: int, positions: int):
         """Fetch-or-compile an executable. ``positions`` is its query
@@ -826,21 +898,14 @@ class ModelRunner:
         logger.info("%s executable (batch=%d window=%d kv=%d): "
                     "attention path %s", kind, batch, window, kv_len,
                     path)
-        obs = self.compile_observer
-        t0 = time.monotonic()
-        if obs is not None:
-            obs.compile_started(kind, window, kv_len, batch)
-        try:
-            fn = make_fn()
-            fn.lower(*args).compile()   # donation applies at execution
-        except Exception as e:
-            raise RuntimeError(
-                f"{kind} executable {key!r} failed to compile on the "
-                f"{path} attention path: {e}") from e
-        finally:
-            if obs is not None:
-                obs.compile_finished(kind, window, kv_len, t0,
-                                     time.monotonic() - t0, batch)
+        with self._observed(kind, window, kv_len, batch):
+            try:
+                fn = make_fn()
+                fn.lower(*args).compile()   # donation applies at execution
+            except Exception as e:
+                raise RuntimeError(
+                    f"{kind} executable {key!r} failed to compile on the "
+                    f"{path} attention path: {e}") from e
         cache[key] = fn
         self.attention_paths[f"{kind}|{window}|{kv_len}|{batch}"] = path
         return fn

@@ -22,7 +22,8 @@ import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
 from production_stack_tpu.engine.block_manager import BlockManager
-from production_stack_tpu.engine.efficiency import (EngineEffAccounting,
+from production_stack_tpu.engine.efficiency import (DRAIN_REASONS,
+                                                    EngineEffAccounting,
                                                     OCCUPANCY_BUCKETS)
 from production_stack_tpu.engine.metrics import EngineMetrics
 from production_stack_tpu.tracing import PhaseHistograms
@@ -133,6 +134,32 @@ def test_prefill_padding_accounting():
     p = acct.report()["prefill"]
     assert p["real"] == 140 and p["pad"] == 412 + 24
     assert p["by_rows"] == {"1": 1, "8": 1}
+
+
+@pytest.mark.parametrize("drained", [None, *DRAIN_REASONS])
+def test_prefill_dispatches_are_counted_by_their_path(drained):
+    """``totals.step``: a dispatch behind the windows in flight, or one
+    that drained the queue, by reason; the step's ring entry names it;
+    the two sum to ``totals.prefill.dispatches``."""
+    acct = EngineEffAccounting(now_fn=_Clock(1.0))
+    with acct.step():
+        acct.note_prefill(bucket=64, batch=1, real_tokens=40,
+                          drained=drained)
+        acct.note_prefill(bucket=64, batch=1, real_tokens=40)
+    with acct.step():
+        pass
+    r = acct.report()
+    step = r["step"]
+    assert set(step["prefill_drained"]) == set(DRAIN_REASONS)
+    assert step["prefill_behind"] == (2 if drained is None else 1)
+    assert step["prefill_drained"] == {
+        k: int(k == drained) for k in DRAIN_REASONS}
+    assert (step["prefill_behind"] + sum(step["prefill_drained"].values())
+            == r["prefill"]["dispatches"] == 2)
+    first, second = acct.recent_steps()
+    key = "prefill_behind" if drained is None else "drained_" + drained
+    assert first[key] >= 1 and first["prefill_behind"] >= 1
+    assert not [k for k in second if "prefill" in k or "drained" in k]
 
 
 def test_compile_tracking_and_event_overlap():
@@ -551,7 +578,7 @@ def test_engine_perf_surfaces_and_compile_trace(cold_engine):
         assert perf["compile_in_flight"] == 0
         assert perf["weight_bytes"] > 0
         # /debug/perf: window ring + compile events + pool census
-        r = await client.get("/debug/perf?limit=5")
+        r = await client.get("/debug/perf?limit=50")
         assert r.status == 200
         dp = await r.json()
         assert dp["windows"], "no window breakdowns recorded"
@@ -566,6 +593,8 @@ def test_engine_perf_surfaces_and_compile_trace(cold_engine):
                 "window_s"} <= set(w)
         kinds = [e["kind"] for e in dp["compiles"]]
         assert "decode" in kinds and "prefill" in kinds
+        # a carry brings its edit by slot with it (runner.edit_carry)
+        assert "carry_edit" in kinds
         # compile events carry the dispatched batch bucket
         assert all("batch" in e for e in dp["compiles"])
         assert dp["kv_pool"]["active"] == 0   # request finished

@@ -175,6 +175,57 @@ def test_starved_seconds(device, work, expect):
         assert step["phase_s"]["no_work"] == pytest.approx(3.0)
 
 
+def _starved_around_a_prefill_entry(behind: bool) -> dict:
+    """A chunk is dispatched; either a window is queued behind it
+    (``behind``) or not; its result is synced (``prefill_sync``, inside
+    the entry's ``prefill_process``); the next step dispatches a
+    window."""
+    clock = _Clock()
+    acct = EngineEffAccounting(now_fn=clock)
+    with acct.step():
+        with acct.phase("prefill_host"):
+            with acct.phase("prefill_dispatch", dispatches=True):
+                clock.t += 0.01
+        if behind:
+            with acct.phase("decode_host"):
+                with acct.phase("decode_dispatch", dispatches=True):
+                    clock.t += 0.01
+        with acct.phase("prefill_process"):
+            with acct.phase("prefill_sync"):
+                clock.t += 0.03
+            if not behind:
+                acct.device_idle()      # the queue ran dry
+            clock.t += 0.02
+    clock.t += 0.004
+    with acct.step():
+        with acct.phase("decode_host"):
+            clock.t += 0.04
+            with acct.phase("decode_dispatch", dispatches=True):
+                clock.t += 0.01
+    return acct.report()["step"]
+
+
+# before the first dispatch of all returns the device has nothing: its
+# 0.01 s are starved in both cases
+@pytest.mark.parametrize("behind,expect", [
+    # a window is queued behind the chunk: its sync leaves the device
+    # with work, and nothing is booked
+    (True, {"prefill_dispatch": 0.01}),
+    # nothing behind it: from the sync's return to the next dispatch's
+    (False, {"prefill_dispatch": 0.01, "prefill_process": 0.02,
+             "between_steps": 0.004, "decode_host": 0.04,
+             "decode_dispatch": 0.01}),
+], ids=["window_behind_the_chunk", "queue_ran_dry"])
+def test_starved_seconds_around_a_prefill_entry(behind, expect):
+    step = _starved_around_a_prefill_entry(behind)
+    got = {k: v for k, v in step["starved_by_phase"].items() if v}
+    assert got == pytest.approx(expect, abs=1e-6)
+    assert step["starved_s"] == pytest.approx(sum(expect.values()),
+                                              abs=1e-6)
+    assert sum(step["phase_s"].values()) == pytest.approx(
+        step["wall_s"], abs=1e-6)
+
+
 @pytest.mark.parametrize("ring,limit,expect", [
     (4, 50, [6, 7, 8, 9]),      # the ring keeps the newest ring_entries
     (16, 3, [7, 8, 9]),         # limit cuts what a read returns
@@ -322,6 +373,67 @@ def test_debug_perf_step_block_and_ring(served):
     assert set(entry["phase_s"]) <= set(STEP_PHASES)
     w = perf["windows"][-1]
     assert 0 < w["host_s"] < 5 and 0 <= w["sync_s"] <= w["window_s"] + 1e-3
+
+
+@pytest.fixture(scope="module")
+def turnover():
+    """A real engine (no server) through one turnover of a slot with
+    windows in flight: three rows decode, a fourth request's chunks go
+    behind the queue, a row ends. ``totals.step`` before the fourth
+    request, after its first token, and at the end, with the shortest
+    the in-flight queue was in between."""
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.engine.engine import LLMEngine
+    from production_stack_tpu.engine.scheduler import SamplingOptions
+
+    def greedy(n):
+        return SamplingOptions(temperature=0.0, max_tokens=n,
+                               ignore_eos=True)
+    eng = LLMEngine(EngineConfig(
+        model="debug-tiny", max_model_len=256, max_num_seqs=4,
+        prefill_chunk=32, prefill_buckets=(32,), decode_window=4))
+    longs = [eng.add_request(list(range(20 + 10 * i, 30 + 10 * i)),
+                             greedy(60)) for i in range(3)]
+    while min(len(eng.seqs[s].output_tokens) for s in longs) < 6:
+        eng.step()
+    assert eng._inflight
+    before = eng.eff.report()["step"]
+    new = eng.add_request([2 + (7 * i) % 190 for i in range(70)], greedy(5))
+    shortest = 99
+    while not eng.seqs[new].output_tokens:
+        eng.step()
+        shortest = min(shortest, len(eng._inflight))
+    joined = eng.eff.report()["step"]
+    while eng.has_work:
+        eng.step()
+    return before, joined, eng.eff.report()["step"], shortest
+
+
+def test_phases_partition_the_wall_with_prefill_entries_in_flight(turnover):
+    before, joined, end, shortest = turnover
+    assert joined["prefill_behind"] - before["prefill_behind"] == 3
+    assert joined["prefill_drained"] == before["prefill_drained"]
+    assert shortest >= 1
+    for step in (before, joined, end):
+        assert set(step["phase_s"]) == set(STEP_PHASES)
+        assert sum(step["phase_s"].values()) == pytest.approx(
+            step["wall_s"], rel=1e-3)
+    moved = {k for k in STEP_PHASES
+             if joined["phase_s"][k] > before["phase_s"][k]}
+    assert {"prefill_host", "prefill_dispatch", "prefill_sync",
+            "prefill_process", "decode_sync"} <= moved
+    assert not {"drain_sync", "drain_process"} & moved
+
+
+def test_no_second_is_starved_while_the_queue_holds_an_entry(turnover):
+    """Across the join the queue never ran dry, so the device had work
+    all the time; once every row has ended it does run dry, and the
+    first dispatch had nothing before it."""
+    before, joined, end, _ = turnover
+    assert before["starved_s"] > 0
+    assert joined["starved_s"] == before["starved_s"]
+    assert joined["starved_by_phase"] == before["starved_by_phase"]
+    assert end["starved_s"] >= joined["starved_s"]
 
 
 def _moe_totals_after_each(model: str, prompts) -> list:
