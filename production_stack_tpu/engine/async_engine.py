@@ -8,7 +8,6 @@ variable so an idle engine burns no CPU.
 
 import asyncio
 import threading
-import time
 import uuid
 from concurrent.futures import ThreadPoolExecutor
 from typing import AsyncIterator, Dict, List, Optional, Tuple
@@ -22,6 +21,11 @@ logger = init_logger(__name__)
 
 _SENTINEL: Tuple = ()
 
+# the loop timeline's lag probe (efficiency.LoopAccounting.sample): one
+# task on the event loop sleeps this long and books what its wake-up
+# overshot. 100 ms: ten entries a second of the ``loop`` ring
+LAG_PROBE_S = 0.1
+
 
 class AsyncLLMEngine:
     def __init__(self, cfg: EngineConfig, params=None, mesh=None):
@@ -31,6 +35,7 @@ class AsyncLLMEngine:
         self._wake = threading.Condition()
         self._running = False
         self._thread: Optional[threading.Thread] = None
+        self._lag_probe: Optional[asyncio.Task] = None
         # dedicated pool for calls that wait on the ENGINE LOCK
         # (add_request/abort): during a multi-second lazy compile the
         # lock is held and each waiting call pins a thread — on the
@@ -54,9 +59,25 @@ class AsyncLLMEngine:
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="engine-loop")
         self._thread.start()
+        # on the loop's own thread, whichever thread calls start()
+        self._loop.call_soon_threadsafe(self._start_lag_probe)
+
+    def _start_lag_probe(self) -> None:
+        if self._running and self._lag_probe is None:
+            self._lag_probe = self._loop.create_task(self._probe_lag())
+
+    async def _probe_lag(self) -> None:
+        acct = self.engine.eff.loop
+        acct.sample(None)
+        while self._running:
+            await asyncio.sleep(LAG_PROBE_S)
+            acct.sample(LAG_PROBE_S)
 
     def stop(self) -> None:
         self._running = False
+        probe, self._lag_probe = self._lag_probe, None
+        if probe is not None and not self._loop.is_closed():
+            self._loop.call_soon_threadsafe(probe.cancel)
         with self._wake:
             self._wake.notify_all()
         if self._thread:
@@ -85,19 +106,21 @@ class AsyncLLMEngine:
     def _dispatch(self, outputs: List[StepOutput]) -> None:
         # one stamp per batch of outputs, not per token: when a
         # request's first and last tokens reach its queue (the
-        # first_token_emit / emit_lag trace events)
-        now = time.monotonic()
-        for out in outputs:
-            if out.waits is not None:
-                if out.waits.first_emit is None:
-                    out.waits.first_emit = now
-                if out.finished:
-                    out.waits.last_emit = now
-            q = self._queues.get(out.seq_id)
-            if q is not None:
-                q.put_nowait(out)
-                if out.finished:
-                    self._queues.pop(out.seq_id, None)
+        # first_token_emit / emit_lag trace events). The loop timeline
+        # books the whole hand-over (totals.loop.dispatch_s)
+        with self.engine.eff.loop.dispatching() as span:
+            now = span.t0
+            for out in outputs:
+                if out.waits is not None:
+                    if out.waits.first_emit is None:
+                        out.waits.first_emit = now
+                    if out.finished:
+                        out.waits.last_emit = now
+                q = self._queues.get(out.seq_id)
+                if q is not None:
+                    q.put_nowait(out)
+                    if out.finished:
+                        self._queues.pop(out.seq_id, None)
 
     # ------------------------------------------------------------------
 

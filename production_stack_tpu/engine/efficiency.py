@@ -79,6 +79,25 @@ STEP_PHASES: Tuple[str, ...] = (
     "housekeeping", "between_steps", "no_work", "compile")
 
 
+# The phases in which the engine thread does its own work (the host's
+# share of a step; chipbench's step_host_work_share sums the same):
+# every phase but the three syncs, the wait for work and the compiles.
+# Off-processor seconds inside them are seconds the thread wanted to
+# run and could not; inside the others they are the device's.
+HOST_WORK_PHASES: Tuple[str, ...] = tuple(
+    p for p in STEP_PHASES
+    if not p.endswith("_sync") and p not in ("no_work", "compile"))
+
+# How often at most the timeline asks the device whether it has run dry
+# (_look): on the chip one answer costs several microseconds, and a
+# step closes thirty phases, most of them a few of those long
+LOOK_EVERY_S = 0.001
+
+# ``totals.step.dispatch_depth``: how many entries of the engine's
+# device queue the device had not finished when a dispatch was made
+DEPTH_KEYS: Tuple[str, ...] = ("0", "1", "2", "3_or_more")
+
+
 # Why a prefill dispatch waited for the device queue to empty where it
 # could not join it (engine._prefill_drains; docs/engine.md "The
 # in-flight queue"): each names state that only the host holds.
@@ -92,10 +111,12 @@ class _Span:
     seconds (``self_s``) are its elapsed time less the spans and
     compiles inside it, so nested phases never count a second twice.
     After exit ``t0``/``t1`` are its monotonic stamps: the step loop
-    reads them instead of keeping a clock of its own."""
+    reads them instead of keeping a clock of its own. ``c0``/``c1``
+    are the thread's processor seconds (``time.thread_time``), read
+    inside the wall stamps."""
 
     __slots__ = ("eff", "name", "label", "dispatches", "ann",
-                 "t0", "t1", "inner_s", "self_s")
+                 "t0", "t1", "inner_s", "self_s", "c0", "c1", "inner_cpu")
 
     def __init__(self, eff: "EngineEffAccounting", name: str,
                  label: str, dispatches: bool):
@@ -103,6 +124,7 @@ class _Span:
         self.dispatches = dispatches
         self.ann = None
         self.t0 = self.t1 = self.inner_s = self.self_s = 0.0
+        self.c0 = self.c1 = self.inner_cpu = 0.0
 
     @property
     def elapsed_s(self) -> float:
@@ -114,15 +136,116 @@ class _Span:
             self.ann = eff.annotate(self.label)
             self.ann.__enter__()
         self.t0 = eff._now()
+        self.c0 = eff._cpu()
         eff._open(self)
         return self
 
     def __exit__(self, *exc) -> bool:
+        self.c1 = self.eff._cpu()
         self.t1 = self.eff._now()
         self.eff._close(self)
         if self.ann is not None:
             self.ann.__exit__(*exc)
         return False
+
+
+class _LoopSpan:
+    """One piece of the event loop's work for a stream, timed into one
+    total of LoopAccounting; ``label`` also puts it on the host plane
+    of a profiler capture. ``t1`` is its exit stamp."""
+
+    __slots__ = ("acct", "total", "label", "ann", "t0", "t1")
+
+    def __init__(self, acct: "LoopAccounting", total: str,
+                 label: Optional[str]):
+        self.acct, self.total, self.label = acct, total, label
+        self.ann = None
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "_LoopSpan":
+        acct = self.acct
+        if self.label is not None and acct.annotate is not None:
+            self.ann = acct.annotate(self.label)
+            self.ann.__enter__()
+        self.t0 = acct._now()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        acct = self.acct
+        self.t1 = acct._now()
+        acct._seconds[self.total] += self.t1 - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False
+
+
+class LoopAccounting:
+    """The event-loop thread's account (``totals.loop`` and the
+    ``loop`` ring of ``GET /debug/perf``; docs/observability.md "Loop
+    timeline"): the thread that parses requests and carries every
+    token from the engine's outputs to the socket. Written by the loop
+    thread alone, so it takes no lock; a reader on another thread reads
+    each number whole, and the sums of one payload possibly half made.
+
+    ``wall_s`` / ``cpu_s`` advance once a sample of the lag probe
+    (``sample``; AsyncLLMEngine runs the task): the loop thread's
+    processor seconds over the same wall seconds. ``dispatch_s``,
+    ``serialize_s`` and ``write_s`` are the three pieces of a token's
+    way out: AsyncLLMEngine._dispatch handing outputs to the requests'
+    queues, the server building a payload's JSON, and the payload's
+    ``write``; over ``payloads`` they are the loop's seconds a token.
+    """
+
+    def __init__(self, *, ring_entries: int = 256,
+                 now_fn: Callable[[], float] = time.monotonic,
+                 wall_fn: Callable[[], float] = time.time,
+                 cpu_fn: Callable[[], float] = time.thread_time,
+                 annotate: Optional[Callable[[str], object]] = None):
+        self._now, self._wall, self._cpu = now_fn, wall_fn, cpu_fn
+        self.annotate = annotate
+        self.wall_s = self.cpu_s = 0.0
+        self._seconds = {"dispatch_s": 0.0, "serialize_s": 0.0,
+                         "write_s": 0.0}
+        self.payloads = 0
+        self._ring: "collections.deque[dict]" = collections.deque(
+            maxlen=max(1, ring_entries))
+        self._last: Optional[Tuple[float, float]] = None    # (now, cpu)
+
+    def dispatching(self) -> _LoopSpan:
+        return _LoopSpan(self, "dispatch_s", "pstpu.loop.dispatch")
+
+    def serializing(self) -> _LoopSpan:
+        return _LoopSpan(self, "serialize_s", None)
+
+    def writing(self) -> _LoopSpan:
+        """Around one payload's ``write``, call to return."""
+        self.payloads += 1
+        return _LoopSpan(self, "write_s", "pstpu.loop.write")
+
+    def sample(self, slept_s: Optional[float]) -> None:
+        """The lag probe woke from a sleep of ``slept_s`` that it began
+        at the previous sample: what the wake-up overshot is how long a
+        callback that became ready waited for the loop. None: the
+        probe starts here, nothing is booked."""
+        now, cpu = self._now(), self._cpu()
+        if slept_s is not None and self._last is not None:
+            wall, on_cpu = now - self._last[0], cpu - self._last[1]
+            self.wall_s += wall
+            self.cpu_s += on_cpu
+            self._ring.append({
+                "at": now, "at_unix": round(self._wall(), 4),
+                "lag_s": round(max(0.0, wall - slept_s), 6),
+                "cpu_s": round(on_cpu, 6)})
+        self._last = (now, cpu)
+
+    def report(self) -> Dict[str, object]:
+        return {"wall_s": round(self.wall_s, 6),
+                "cpu_s": round(min(self.cpu_s, self.wall_s), 6),
+                **{k: round(v, 6) for k, v in self._seconds.items()},
+                "payloads": self.payloads}
+
+    def recent(self, limit: int = 50) -> List[dict]:
+        return list(self._ring)[-max(1, limit):]
 
 
 class EngineEffAccounting:
@@ -146,6 +269,15 @@ class EngineEffAccounting:
     timeline enters one named ``pstpu.<phase>``, so the same intervals
     lie on the host plane of a profiler capture, on the device trace's
     clock. This module itself stays off JAX.
+
+    ``queue_depth`` (the engine passes ``LLMEngine._device_queue_depth``)
+    answers without blocking how many entries of the engine's device
+    queue the device has NOT finished (``jax.Array.is_ready``): the
+    timeline asks it at the open of every dispatching phase
+    (``dispatch_depth``) and, while it holds the device busy, at the
+    close of every phase and the open of every step (the starved
+    seconds). ``cpu_fn`` is the calling
+    thread's processor clock, injectable as ``now_fn`` is.
     """
 
     def __init__(self, *, weight_bytes: int = 0,
@@ -155,7 +287,9 @@ class EngineEffAccounting:
                  compile_hist=None, expert_bytes: int = 0,
                  now_fn: Callable[[], float] = time.monotonic,
                  wall_fn: Callable[[], float] = time.time,
-                 annotate: Optional[Callable[[str], object]] = None):
+                 annotate: Optional[Callable[[str], object]] = None,
+                 queue_depth: Optional[Callable[[], int]] = None,
+                 cpu_fn: Callable[[], float] = time.thread_time):
         self.weight_bytes = int(weight_bytes)
         self.kv_position_bytes = int(kv_position_bytes)
         # None = no known peak for this device: MBU is not reported
@@ -163,6 +297,7 @@ class EngineEffAccounting:
         self.compile_hist = compile_hist
         self._now = now_fn
         self._wall = wall_fn
+        self._cpu = cpu_fn
         self._started_at = now_fn()
         # decode-window token-step classification (cumulative ints).
         # token_steps_total accumulates batch*steps*positions in a
@@ -219,10 +354,17 @@ class EngineEffAccounting:
         # underscore below is the engine thread's alone and is folded
         # into the totals once per step.
         self.annotate = annotate
+        self.queue_depth = queue_depth
         self.steps = 0
         self.step_wall_s = 0.0
         self.phase_s: Dict[str, float] = dict.fromkeys(STEP_PHASES, 0.0)
+        # the engine thread's processor seconds of each phase; what is
+        # left of phase_s it spent off the processor (report())
+        self.cpu_s: Dict[str, float] = dict.fromkeys(STEP_PHASES, 0.0)
         self.starved_s: Dict[str, float] = {}
+        # dispatches by the depth of the device queue they found,
+        # indexed as DEPTH_KEYS
+        self.dispatch_depth: List[int] = [0] * len(DEPTH_KEYS)
         # exit stamp of the latest ``*_sync`` phase: a pipelined
         # window's seconds start here, not at its dispatch
         self.synced_at = 0.0
@@ -230,13 +372,24 @@ class EngineEffAccounting:
             maxlen=max(1, ring_entries))
         self._stack: List[_Span] = []
         self._cur: Dict[str, float] = {}        # phase -> s, this step
+        self._cur_cpu: Dict[str, float] = {}    # ... on the processor
         self._cur_starved: Dict[str, float] = {}
         self._cur_prefill: Dict[str, int] = {}  # dispatches, this step
+        self._cur_depth: List[int] = [0] * len(DEPTH_KEYS)
         self._root_end: Optional[float] = None  # last outermost exit
+        self._root_cpu_end = 0.0                # ... on the thread's clock
+        self._compile_c0 = 0.0      # the thread's clock at compile_started
         # since when the device has had nothing of ours outstanding
         # while work waited (None: it is busy). Set where the timeline
-        # begins, by device_idle(), and moved up past a wait for work
+        # begins, by device_idle(), at the first close of a phase (or
+        # open of a step) at which queue_depth() says 0, and moved up
+        # past a wait for work
         self._idle_since: Optional[float] = None
+        self._looked_at = float("-inf")     # when _look last asked
+        # the event loop's account (LoopAccounting; ``totals.loop``)
+        self.loop = LoopAccounting(
+            ring_entries=ring_entries, now_fn=now_fn, wall_fn=wall_fn,
+            cpu_fn=cpu_fn, annotate=annotate)
 
     # -- step-loop writes ------------------------------------------------
 
@@ -344,6 +497,31 @@ class EngineEffAccounting:
                                        + until - self._idle_since)
             self._idle_since = until
 
+    def _look(self, at: float) -> None:
+        """A span boundary of the engine thread at which work exists
+        (the close of a phase, the open of a step): where the timeline
+        holds the device busy and the device says it has finished all
+        it was given, the starved seconds start here, late by at most
+        the span that just ended or LOOK_EVERY_S. Not asked at the
+        open of a nested phase, which follows a close or its parent's
+        first lines by microseconds."""
+        if (self._idle_since is None and self.queue_depth is not None
+                and at - self._looked_at >= LOOK_EVERY_S):
+            self._looked_at = at
+            if self.queue_depth() == 0:
+                self._idle_since = at
+
+    def _book(self, name: str, wall: float, cpu: float) -> None:
+        """``wall`` seconds of phase ``name``, of them ``cpu`` on the
+        processor, as the thread's clock read them. That clock may
+        tick coarsely (10 ms under gVisor, where the chip's machines
+        run: a span of 2 ms then reads 0 or 10 ms), so nothing is
+        clamped span by span: a tick lands in the phase that was
+        running, and over many spans a phase's sum is right. report()
+        holds the totals to ``cpu_s <= phase_s``."""
+        self._cur[name] = self._cur.get(name, 0.0) + wall
+        self._cur_cpu[name] = self._cur_cpu.get(name, 0.0) + cpu
+
     def _open(self, span: _Span) -> None:
         if self._stack:
             self._starve(self._stack[-1].name, span.t0)
@@ -351,15 +529,24 @@ class EngineEffAccounting:
             if self._root_end is None:
                 self._idle_since = span.t0      # nothing dispatched yet
             else:
-                self._cur["between_steps"] = span.t0 - self._root_end
+                self._book("between_steps", span.t0 - self._root_end,
+                           span.c0 - self._root_cpu_end)
             self._starve("between_steps", span.t0)
+            if span.name != "no_work":
+                self._look(span.t0)
         self._stack.append(span)
+        if span.dispatches and self.queue_depth is not None:
+            depth = self.queue_depth()
+            self._cur_depth[min(depth, len(DEPTH_KEYS) - 1)] += 1
+            if depth == 0 and self._idle_since is None:
+                self._idle_since = span.t0
 
     def _close(self, span: _Span) -> None:
         self._stack.pop()
         elapsed = span.t1 - span.t0
+        on_cpu = span.c1 - span.c0
         span.self_s = elapsed - span.inner_s
-        self._cur[span.name] = self._cur.get(span.name, 0.0) + span.self_s
+        self._book(span.name, span.self_s, on_cpu - span.inner_cpu)
         if span.name != "no_work":
             self._starve(span.name, span.t1)
         elif self._idle_since is not None:
@@ -367,26 +554,35 @@ class EngineEffAccounting:
             self._idle_since = span.t1
         if span.dispatches:
             self._idle_since = None
+        elif span.name != "no_work":
+            self._look(span.t1)
         if span.name.endswith("_sync"):
             self.synced_at = span.t1
         if self._stack:
             self._stack[-1].inner_s += elapsed
+            self._stack[-1].inner_cpu += on_cpu
             return
         # outermost span (a step, or the wait for work): fold into the
         # totals what was booked since the last one closed. The wall
         # is taken from the stamps, not from the sum of the phases
         wall = span.t1 - (span.t0 if self._root_end is None
                           else self._root_end)
-        self._root_end = span.t1
-        cur, starved = self._cur, self._cur_starved
+        self._root_end, self._root_cpu_end = span.t1, span.c1
+        cur, cur_cpu, starved = self._cur, self._cur_cpu, self._cur_starved
         prefills, self._cur_prefill = self._cur_prefill, {}
-        self._cur, self._cur_starved = {}, {}
+        depths, self._cur_depth = self._cur_depth, [0] * len(DEPTH_KEYS)
+        self._cur, self._cur_cpu, self._cur_starved = {}, {}, {}
+        if depths[0]:
+            prefills["dry_dispatches"] = depths[0]
         is_step = span.label == "pstpu.step"
         with self._lock:
             for k, v in cur.items():
                 self.phase_s[k] += v
+                self.cpu_s[k] += cur_cpu[k]
             for k, v in starved.items():
                 self.starved_s[k] = self.starved_s.get(k, 0.0) + v
+            for i, n in enumerate(depths):
+                self.dispatch_depth[i] += n
             self.step_wall_s += wall
             if is_step:
                 self.steps += 1
@@ -396,14 +592,23 @@ class EngineEffAccounting:
                     "wall_s": round(elapsed, 6),
                     "phase_s": {k: round(v, 6) for k, v in cur.items()},
                     "starved_s": round(sum(starved.values()), 6),
-                    # prefill dispatches of the step: ``prefill_behind``
-                    # or ``drained_<reason>``, where it made any
+                    # the thread off the processor in the phases of
+                    # its own work (HOST_WORK_PHASES); one step is
+                    # only as fine as the thread clock's tick
+                    "offcpu_s": round(max(0.0, sum(
+                        cur[k] - cur_cpu[k] for k in HOST_WORK_PHASES
+                        if k in cur)), 6),
+                    # prefill dispatches of the step (``prefill_behind``
+                    # or ``drained_<reason>``) and the dispatches that
+                    # found the device queue finished
+                    # (``dry_dispatches``), where it made any
                     **prefills})
 
     # -- compile observer (ModelRunner hook) -----------------------------
 
     def compile_started(self, kind: str, window: int, kv_len: int,
                         batch: int = 0) -> None:
+        self._compile_c0 = self._cpu()
         with self._lock:
             self.compile_in_flight += 1
 
@@ -416,8 +621,10 @@ class EngineEffAccounting:
             # on the engine thread, in the dispatch phases) is taken
             # out of that phase and booked as ``compile``
             top = self._stack[-1]
+            on_cpu = self._cpu() - self._compile_c0
             top.inner_s += dur_s
-            self._cur["compile"] = self._cur.get("compile", 0.0) + dur_s
+            top.inner_cpu += on_cpu
+            self._book("compile", dur_s, on_cpu)
             self._starve(top.name, started_at)
             self._starve("compile", started_at + dur_s)
         with self._lock:
@@ -440,11 +647,24 @@ class EngineEffAccounting:
     # -- reads (off the hot path) ----------------------------------------
 
     def report(self) -> Dict[str, object]:
-        """Cumulative totals (the scrape-time delta-sync source)."""
+        """Cumulative totals (the scrape-time delta-sync source, and
+        ``totals`` of GET /debug/perf). ``step`` is the step timeline
+        (docs/observability.md "Step timeline"): ``steps``, ``wall_s``,
+        ``phase_s`` / ``cpu_s`` / ``offcpu_s`` (all STEP_PHASES keys
+        each; processor and off-processor seconds add up to the phase's
+        seconds), ``starved_s`` and ``starved_by_phase``,
+        ``dispatch_depth`` (all DEPTH_KEYS), ``prefill_behind`` and
+        ``prefill_drained`` (all DRAIN_REASONS). ``loop`` is the event
+        loop's account (LoopAccounting.report)."""
         with self._lock:
             moe = {"moe": {"experts_read": self.experts_read,
                            "experts_resident": self.experts_resident}
                    } if self.expert_bytes else {}
+            # rounded before the subtraction, so that what is reported
+            # adds up: cpu_s + offcpu_s == phase_s, phase by phase
+            phase_s = {k: round(v, 6) for k, v in self.phase_s.items()}
+            cpu_s = {k: min(max(round(v, 6), 0.0), phase_s[k])
+                     for k, v in self.cpu_s.items()}
             return {
                 "decode": {"real": self.decode_real,
                            "pad": self.decode_pad,
@@ -476,14 +696,19 @@ class EngineEffAccounting:
                 "step": {
                     "steps": self.steps,
                     "wall_s": round(self.step_wall_s, 6),
-                    "phase_s": {k: round(v, 6)
-                                for k, v in self.phase_s.items()},
+                    "phase_s": phase_s,
+                    "cpu_s": cpu_s,
+                    "offcpu_s": {k: round(phase_s[k] - cpu_s[k], 6)
+                                 for k in phase_s},
                     "starved_s": round(sum(self.starved_s.values()), 6),
                     "starved_by_phase": {
                         k: round(v, 6)
                         for k, v in self.starved_s.items()},
+                    "dispatch_depth": dict(zip(DEPTH_KEYS,
+                                               self.dispatch_depth)),
                     "prefill_behind": self.prefill_behind,
                     "prefill_drained": dict(self.prefill_drained)},
+                "loop": self.loop.report(),
             }
 
     def rates(self, horizon_s: float = 10.0,

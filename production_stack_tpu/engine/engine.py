@@ -280,7 +280,8 @@ class LLMEngine:
             ring_entries=engine_cfg.perf_ring_entries,
             compile_hist=self.metrics.compile_hist,
             expert_bytes=expert_bytes,
-            annotate=jax.profiler.TraceAnnotation)
+            annotate=jax.profiler.TraceAnnotation,
+            queue_depth=self._device_queue_depth)
         # the step timeline (efficiency.STEP_PHASES): every phase of
         # step() runs under `with self._phase(name)`
         self._phase = self.eff.phase
@@ -387,6 +388,11 @@ class LLMEngine:
         # prefill joins the queue behind them (_prefill_drains says
         # when it may) and the carry is edited by slot on the device.
         self._inflight: List[object] = []
+        # the newest result the device was asked for (a window's or a
+        # prefill's ids): the device runs its queue in order, so once
+        # this is ready nothing of ours is left on it
+        # (_device_queue_depth); None: known to be finished
+        self._queue_tail = None
         # continuous batching across windows (docs/engine.md
         # "Continuous batching across windows"): the device carry's
         # current batch bucket (dispatches at a different bucket must
@@ -821,6 +827,31 @@ class LLMEngine:
                     list(self.scheduler.running.values())):
                 break
 
+    def _device_queue_depth(self) -> int:
+        """How many entries of ``_inflight`` the device has NOT
+        finished, from the device's own answer and without blocking
+        (``jax.Array.is_ready``); the step timeline asks
+        (efficiency.EngineEffAccounting ``queue_depth``). 0 means the
+        chip has nothing of ours left to do. The queue runs in order:
+        the newest result decides between 0 and more, and the walk from
+        the newest entry stops at the first finished one. An entry
+        taken off the list to be synced or landed may still be running,
+        so an unfinished newest result counts as 1 at least."""
+        tail = self._queue_tail
+        if tail is None:
+            return 0
+        if tail.is_ready():
+            self._queue_tail = None
+            return 0
+        depth = 0
+        for entry in reversed(self._inflight):
+            result = (entry.ids if isinstance(entry, _Window)
+                      else entry.devs[0])
+            if result is not tail and result.is_ready():
+                break
+            depth += 1
+        return max(depth, 1)
+
     def _device_leads(self):
         """How many tokens the device is past the host, by row: (the
         decode steps of every window in flight: the lead of a row that
@@ -1187,6 +1218,7 @@ class LLMEngine:
             if entry.joined:
                 self._join_carry(entry, rows, starts + lengths)
             self._inflight.append(entry)
+            self._queue_tail = devs[0]
         if drained is not None:
             # the carry is rebuilt from the mirrors, first tokens
             # included, once these entries have landed
@@ -1569,6 +1601,7 @@ class LLMEngine:
                       list(decode_seqs), call.t1, spec_ok, kv_len, batch,
                       host_s=call.self_s, experts_read=experts_read_dev)
         self._inflight.append(win)
+        self._queue_tail = ids_dev
         return win
 
     def _drain_decode(self) -> List[StepOutput]:

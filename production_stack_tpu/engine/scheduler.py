@@ -75,7 +75,8 @@ class RequestWaits:
     the phases of its trace; the server writes them as event spans
     (docs/observability.md "Tracing"). One object per sequence, shared
     by reference with its StepOutputs, so that AsyncLLMEngine can stamp
-    the two emits after ``step()`` has returned."""
+    the two emits after ``step()`` has returned, and the server the two
+    writes of a streamed response."""
     locked: Optional[float] = None      # add_request holds the engine lock
     first_look: Optional[float] = None  # first schedule() pass after that
     first_pass: int = 0                 # ... and that pass's number
@@ -85,6 +86,9 @@ class RequestWaits:
     prefill_chunks: int = 0
     first_emit: Optional[float] = None  # first token put on the queue
     last_emit: Optional[float] = None   # last one
+    # the write of the SSE payload that carries it has returned
+    first_write: Optional[float] = None
+    last_write: Optional[float] = None
 
 
 @dataclass

@@ -85,7 +85,8 @@ def _seal_engine_trace(tracer: TraceRecorder, trace, request: web.Request,
     The waits a request sits through INSIDE those phases ride along as
     EVENT spans (scheduler.RequestWaits): ``lock_wait`` and
     ``sched_wait`` in ``queue_wait``, ``prefill_wait`` in ``prefill``,
-    ``first_token_emit`` in ``decode``, ``emit_lag`` in ``postprocess``.
+    ``first_token_emit`` and ``first_token_write`` in ``decode``,
+    ``emit_lag`` in ``postprocess``.
 
     XLA compiles that overlapped this request's life are attached as
     ``xla_compile`` EVENT spans (engine/efficiency.py keeps the bounded
@@ -160,7 +161,10 @@ def _add_wait_events(trace, timing: dict) -> None:
     event("prefill_wait", timing["admit"], waits.prefill_call,
           chunks=waits.prefill_chunks)
     event("first_token_emit", timing["first_token"], waits.first_emit)
-    event("emit_lag", timing["end"], waits.last_emit)
+    # a streamed response: from its queue to the socket; emit_lag is
+    # the last token's whole way out, to its write where there was one
+    event("first_token_write", waits.first_emit, waits.first_write)
+    event("emit_lag", timing["end"], waits.last_write or waits.last_emit)
 
 
 def _trace_middleware(tracer: TraceRecorder):
@@ -263,7 +267,12 @@ def _check_overload_finish(out) -> None:
         raise _QueueDelayShed()
 
 
-async def _guarded_payloads(merged, lead_payloads, chunk_for):
+# request key: the outputs that carry a RequestWaits (first-token and
+# terminal ones) whose payload _sse_stream has not written yet
+UNWRITTEN = "sse_unwritten"
+
+
+async def _guarded_payloads(request, merged, lead_payloads, chunk_for):
     """Shared streaming shape for the chat/completions SSE paths: pull
     the FIRST engine output off ``merged`` before emitting the
     ``lead_payloads`` (role/echo chunks), so an admission shed or a
@@ -273,7 +282,19 @@ async def _guarded_payloads(merged, lead_payloads, chunk_for):
     response started (another choice's shed, or a preempted sequence's
     deadline) is NOT an error: the transport is healthy, so that choice
     simply terminates with its finish_reason chunk ("deadline" /
-    "queue_delay") while its siblings stream on to [DONE]."""
+    "queue_delay") while its siblings stream on to [DONE].
+
+    Building a payload is booked on the loop timeline
+    (``totals.loop.serialize_s``)."""
+    acct = request.app[ENGINE_KEY].engine.eff.loop
+
+    def serialized(i, out):
+        with acct.serializing():
+            payload = chunk_for(i, out)
+        if out.waits is not None:
+            request.setdefault(UNWRITTEN, []).append(out)
+        return payload
+
     try:
         head = await merged.__anext__()
     except StopAsyncIteration:
@@ -283,11 +304,11 @@ async def _guarded_payloads(merged, lead_payloads, chunk_for):
     for payload in lead_payloads:
         yield payload
     if head is not None:
-        payload = chunk_for(*head)
+        payload = serialized(*head)
         if payload is not None:
             yield payload
         async for i, out in merged:
-            payload = chunk_for(i, out)
+            payload = serialized(i, out)
             if payload is not None:
                 yield payload
 
@@ -480,8 +501,14 @@ async def _sse_stream(request: web.Request, gen) -> web.StreamResponse:
     or a deadline expiry that surfaces before any byte is written
     becomes a clean structured 503/504 instead of a truncated stream.
     (Raised after bytes have been relayed, the same failures can only
-    truncate — the connection is dropped.)"""
+    truncate — the connection is dropped.)
+
+    Every payload's write is booked on the loop timeline
+    (``totals.loop.write_s``, ``payloads``), and its return stamps
+    ``first_write`` / ``last_write`` of the requests whose first or
+    last token the payload carried (scheduler.RequestWaits)."""
     engine = request.app[ENGINE_KEY]
+    acct = engine.engine.eff.loop
     resp: Optional[web.StreamResponse] = None
 
     async def ensure_prepared() -> web.StreamResponse:
@@ -503,9 +530,16 @@ async def _sse_stream(request: web.Request, gen) -> web.StreamResponse:
     try:
         async for payload in gen:
             await ensure_prepared()
-            await resp.write(f"data: {payload}\n\n".encode())
+            with acct.writing() as write:
+                await resp.write(f"data: {payload}\n\n".encode())
+            for out in request.pop(UNWRITTEN, ()):
+                if out.waits.first_write is None:
+                    out.waits.first_write = write.t1
+                if out.finished:
+                    out.waits.last_write = write.t1
         await ensure_prepared()
-        await resp.write(b"data: [DONE]\n\n")
+        with acct.writing():
+            await resp.write(b"data: [DONE]\n\n")
         await resp.write_eof()
     except (ConnectionResetError, ConnectionError):
         # client went away mid-stream; generator cleanup aborts the request
@@ -733,7 +767,7 @@ async def chat_completions(request: web.Request) -> web.StreamResponse:
                     engine, _choice_jobs([prompt_ids], options, req.n),
                     req.model or None, deadline)) as it:
                 async for payload in _guarded_payloads(
-                        it, role_chunks, chunk_for):
+                        request, it, role_chunks, chunk_for):
                     yield payload
             if include_usage:
                 # OpenAI semantics: one final chunk, empty choices, usage
@@ -894,7 +928,7 @@ async def completions(request: web.Request) -> web.StreamResponse:
                     engine, _choice_jobs(prompts, options, req.n),
                     req.model or None, deadline)) as it:
                 async for payload in _guarded_payloads(
-                        it, echo_chunks, chunk_for):
+                        request, it, echo_chunks, chunk_for):
                     yield payload
             if include_usage:
                 n_prompt = sum(len(p) for p in prompts)
@@ -1168,7 +1202,8 @@ async def version(request: web.Request) -> web.Response:
 async def debug_perf(request: web.Request) -> web.Response:
     """``GET /debug/perf``: the engine-efficiency ring — recent
     window-level real/pad/dead breakdowns, the step timeline's recent
-    steps (seconds per phase), recent XLA compile events,
+    steps (seconds per phase), the loop timeline's recent lag samples
+    (``loop``), recent XLA compile events,
     cumulative totals + rates, the KV block pool's fragmentation
     census, and the ``device`` block (platform, device kind and count,
     bytes in use per device, the attention path of every compiled
@@ -1188,6 +1223,7 @@ async def debug_perf(request: web.Request) -> web.Response:
         "rates": eng.eff.rates(),
         "windows": eng.eff.recent_windows(limit),
         "steps": eng.eff.recent_steps(limit),
+        "loop": eng.eff.loop.recent(limit),
         "compiles": eng.eff.recent_compiles(limit),
         "kv_pool": eng.block_mgr.frag_report(),
     })

@@ -357,10 +357,21 @@ def test_each_reason_drains_and_is_counted(engines, reference,
         eng._preempt(eng.seqs[sid])
     elif reason == "pressure":
         # a window behind the chunk needs blocks the pool has not got:
-        # take all but the prompt's own out of it
-        _step_until(eng, lambda: _short_by(eng) > 0)
+        # take all but the prompt's own out of it. The set-up waits on
+        # that state (the rows short of blocks for the window behind,
+        # the prompt's own and more still free, every long row still
+        # running), not on a number of steps, and holds it to be there
+        # once the blocks are taken
         own = eng.block_mgr.blocks_for(len(PROMPT) + 1)
+        for _ in range(500):
+            if (_short_by(eng) > 0 and eng.block_mgr.available > own
+                    and len(eng.scheduler.running) == rows):
+                break
+            eng.step()
         hog = eng.block_mgr.alloc(eng.block_mgr.available - own)
+        assert hog and eng.block_mgr.available == own
+        assert _short_by(eng) > 0 and eng._inflight
+        assert not eng._decode_dirty and eng._carry_batch == 4
     step = eng.eff.report()["step"]
     if sid is None:
         sid = eng.add_request(PROMPT, opts)
