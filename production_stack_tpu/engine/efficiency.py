@@ -333,6 +333,11 @@ class EngineEffAccounting:
         self.expert_bytes = int(expert_bytes)
         self.experts_read = 0
         self.experts_resident = 0
+        # MoE prefill dispatches: the rows their experts multiplied and
+        # the rows routed, real tokens x top-k x layers
+        # (note_expert_rows); in ``totals.prefill`` of a MoE engine
+        self.prefill_expert_rows = 0
+        self.prefill_routed_rows = 0
         # modeled HBM traffic (decode windows only — see module doc)
         self.bytes_total = 0
         self.bytes_effective = 0
@@ -470,6 +475,14 @@ class EngineEffAccounting:
                 self.prefill_behind += 1
             else:
                 self.prefill_drained[drained] += 1
+
+    def note_expert_rows(self, expert_rows: int, routed_rows: int) -> None:
+        """One prefill dispatch of a MoE engine: its experts multiplied
+        ``expert_rows`` rows, summed over the layers, where
+        ``routed_rows`` were routed (real tokens x top-k x layers)."""
+        with self._lock:
+            self.prefill_expert_rows += expert_rows
+            self.prefill_routed_rows += routed_rows
 
     # -- step timeline (engine thread only) ------------------------------
 
@@ -660,6 +673,9 @@ class EngineEffAccounting:
             moe = {"moe": {"experts_read": self.experts_read,
                            "experts_resident": self.experts_resident}
                    } if self.expert_bytes else {}
+            moe_rows = {"expert_rows": self.prefill_expert_rows,
+                        "routed_rows": self.prefill_routed_rows
+                        } if self.expert_bytes else {}
             # rounded before the subtraction, so that what is reported
             # adds up: cpu_s + offcpu_s == phase_s, phase by phase
             phase_s = {k: round(v, 6) for k, v in self.phase_s.items()}
@@ -678,7 +694,8 @@ class EngineEffAccounting:
                             "dispatches": self.prefill_dispatches,
                             "by_rows": {
                                 str(r): n for r, n in
-                                sorted(self.prefill_by_rows.items())}},
+                                sorted(self.prefill_by_rows.items())},
+                            **moe_rows},
                 **moe,
                 "bytes_total": self.bytes_total,
                 "bytes_effective": self.bytes_effective,

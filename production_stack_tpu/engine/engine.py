@@ -85,7 +85,9 @@ class _Prefill:
     prefix registration, connector progress) happens when it is
     retired (_land_prefill)."""
     group: list             # the PrefillWork of each row, in row order
-    devs: tuple             # device (ids, logprobs, tops), by row
+    # device (ids, logprobs, tops) by row, and the rows the experts
+    # multiplied (None on a dense model)
+    devs: tuple
     # each row's sequence's admit_time at dispatch: a sequence that was
     # preempted since, admitted again or not, is not this entry's
     admitted: List[float]
@@ -388,6 +390,10 @@ class LLMEngine:
         # prefill joins the queue behind them (_prefill_drains says
         # when it may) and the carry is edited by slot on the device.
         self._inflight: List[object] = []
+        # (device scalar, routed rows) of the prefill entries retired
+        # without a sync of their own, a MoE engine's: the rows their
+        # experts multiplied, read at the next sync (_land_prefill)
+        self._expert_rows_due: List[tuple] = []
         # the newest result the device was asked for (a window's or a
         # prefill's ids): the device runs its queue in order, so once
         # this is ready nothing of ours is left on it
@@ -1260,8 +1266,17 @@ class LLMEngine:
         aborted or was preempted since the dispatch has its row
         discarded, as a finished row of a window is."""
         outputs: List[StepOutput] = []
-        ids_dev, lps_dev, tops_dev = entry.devs
+        ids_dev, lps_dev, tops_dev, expert_rows_dev = entry.devs
         ids = lps = tops = None
+        if expert_rows_dev is not None:
+            # counted at the next sync of a prefill's result, this
+            # entry's or a later one's: by then the device has it
+            mc = self.model_cfg
+            self._expert_rows_due.append((
+                expert_rows_dev,
+                sum(len(w.chunk) for w in entry.group)
+                * mc.num_experts_per_tok
+                * (mc.num_layers - mc.first_dense_layers)))
         for row, (w, admitted) in enumerate(zip(entry.group,
                                                 entry.admitted)):
             seq = w.seq
@@ -1303,6 +1318,9 @@ class LLMEngine:
                     tops = (None if tops_dev is None else
                             (np.asarray(tops_dev[0]),
                              np.asarray(tops_dev[1])))
+                    for rows_dev, routed in self._expert_rows_due:
+                        self.eff.note_expert_rows(int(rows_dev), routed)
+                    self._expert_rows_due.clear()
                 if not self._inflight:
                     # nothing was queued behind the chunk
                     self.eff.device_idle()
@@ -2180,7 +2198,9 @@ class LLMEngine:
         (one process owns the chip) reads the device from here.
         ``attention_paths`` names the attention implementation every
         compiled executable took ("kind|window|kv_bucket|batch", the
-        ``totals.compiles`` key); ``bytes_in_use`` is per device where
+        ``totals.compiles`` key), ``moe_paths`` the strategy its experts
+        take (ops/moe.moe_path; empty on a dense model);
+        ``bytes_in_use`` is per device where
         ``memory_stats()`` gives it (None on the CPU)."""
         from production_stack_tpu.ops import pallas_paged
         devs = []
@@ -2201,6 +2221,7 @@ class LLMEngine:
             "engine_devices": devs,
             "pallas_attention": pallas_paged.mode(),
             "attention_paths": dict(self.runner.attention_paths),
+            "moe_paths": dict(self.runner.moe_paths),
         }
 
     def load_report(self) -> Dict[str, object]:

@@ -50,6 +50,7 @@ from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.models.kv import (LATENT, KVCache, cache_for,
                                             latent_pool_width)
 from production_stack_tpu.models import llama
+from production_stack_tpu.ops import moe
 from production_stack_tpu.ops.pallas_paged import JNP_GATHER, attention_path
 from production_stack_tpu.ops.rope import rope_table
 from production_stack_tpu.utils import init_logger
@@ -253,6 +254,9 @@ class ModelRunner:
         # "kind|window|kv|batch" (the compile observer's key) -> the
         # attention path that executable was compiled on (_compile)
         self.attention_paths: Dict[str, str] = {}
+        # the same key -> the strategy its experts take (ops/moe
+        # moe_path); empty on a dense model
+        self.moe_paths: Dict[str, str] = {}
         # per-batch-bucket sliced views of the sampling params and
         # block tables (invalidated when the source object changes):
         # batch-bucketed dispatches must not pay a 14-array re-slice
@@ -393,7 +397,7 @@ class ModelRunner:
 
         def body(carry, i):
             cache, toks, pos, gstate, counts = carry
-            logits, cache, read = llama.forward(
+            logits, cache, work = llama.forward(
                 params, self.model_cfg, toks[:, None], pos[:, None],
                 cache, block_tables=tables,
                 rope=self.rope, kv_len=kv_len, mesh=self.mesh,
@@ -407,7 +411,8 @@ class ModelRunner:
                 seeded=seeded, plain=plain, guided=guided,
                 penalized=penalized, eos_id=eos_id, topk=topk)
             return ((cache, ids, pos + 1, gstate, counts),
-                    (ids, lp, ti, tl, read))
+                    (ids, lp, ti, tl,
+                     None if work is None else work.experts_read))
 
         (cache, toks, pos, gstate, counts), (ids, lps, tis, tls, read) = \
             jax.lax.scan(
@@ -482,7 +487,7 @@ class ModelRunner:
             draft = jax.vmap(draft_row)(hist, pos)          # [B, K]
             step_toks = jnp.concatenate([toks[:, None], draft], axis=1)
             step_pos = pos[:, None] + jnp.arange(K + 1)[None, :]
-            logits, cache, read = llama.forward(
+            logits, cache, work = llama.forward(
                 params, self.model_cfg, step_toks, step_pos, cache,
                 block_tables=tables,
                 rope=self.rope, kv_len=kv_len, mesh=self.mesh,
@@ -522,7 +527,8 @@ class ModelRunner:
                                                     (p + 1,))
             hist = jax.vmap(write_row)(hist, pos, expected)
             return ((cache, new_toks, new_pos, hist, gstate, counts),
-                    (expected, lp, ti, tl, count, read))
+                    (expected, lp, ti, tl, count,
+                     None if work is None else work.experts_read))
 
         ((cache, toks, pos, hist, gstate, counts),
          (ids, lps, tis, tls, cnt, read)) = jax.lax.scan(
@@ -561,7 +567,9 @@ class ModelRunner:
         whatever R is (ops/moe.moe_mlp ``capacity_tokens``): fewer rows
         never hold less per expert than the full dispatch does.
         Returns (sampled id of each row's last real token [R], its
-        logprob [R], top ids and logprobs [R, K], cache').
+        logprob [R], top ids and logprobs [R, K], cache', the rows the
+        experts multiplied, summed over the layers: None on a dense
+        model).
         """
         Tb = tokens.shape[1]
         S = self.engine_cfg.max_model_len
@@ -575,13 +583,14 @@ class ModelRunner:
         # write K/V, route in MoE layers, or steal expert capacity
         token_valid = ((jnp.arange(Tb)[None, :] < lengths[:, None])
                        & (starts < S)[:, None])
-        logits, cache, _ = llama.forward(
+        logits, cache, work = llama.forward(
             params, self.model_cfg, tokens, positions, cache,
             block_tables=tables,
             rope=self.rope, kv_len=kv_len, mesh=self.mesh,
             lora_params=self._lora, adapter_ids=sampling.adapter,
             lora_scaling=self._lora_scaling, token_valid=token_valid,
             moe_capacity_tokens=self.engine_cfg.max_num_seqs * Tb)
+        expert_rows = None if work is None else work.expert_rows
         with jax.named_scope("sample"):
             last = jnp.take_along_axis(
                 logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
@@ -608,7 +617,7 @@ class ModelRunner:
                 B2 = last.shape[0]
                 tl = jnp.zeros((B2, 1), jnp.float32)
                 ti = jnp.zeros((B2, 1), jnp.int32)
-            return ids, lp, ti, tl, cache
+            return ids, lp, ti, tl, cache, expert_rows
 
     # ------------------------------------------------------------------
     # host API
@@ -861,6 +870,20 @@ class ModelRunner:
             positions, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
             self.engine_cfg.kv_block_size, mesh)
 
+    def _moe_path(self, rows: int, positions: int) -> str:
+        """ops/moe.moe_path for this model's experts, as a forward of
+        ``rows`` x ``positions`` tokens calls them (a prefill reckons
+        its capacity on max_num_seqs rows: _prefill_impl)."""
+        cfg = self.model_cfg
+        experts = self.params["layers"]["gate"]
+        return moe.moe_path(
+            rows, positions, cfg.num_experts, cfg.num_experts_per_tok,
+            cfg.hidden_size,
+            cfg.moe_intermediate_size or cfg.intermediate_size,
+            moe.stored_dtype(experts), cfg.dtype, self.mesh,
+            capacity_factor=cfg.moe_capacity_factor,
+            capacity_tokens=self.engine_cfg.max_num_seqs * positions)
+
     @contextlib.contextmanager
     def _observed(self, kind: str, window: int, kv_len: int, batch: int):
         """Stamp the compile made inside through ``compile_observer``."""
@@ -907,7 +930,10 @@ class ModelRunner:
                     f"{kind} executable {key!r} failed to compile on the "
                     f"{path} attention path: {e}") from e
         cache[key] = fn
-        self.attention_paths[f"{kind}|{window}|{kv_len}|{batch}"] = path
+        name = f"{kind}|{window}|{kv_len}|{batch}"
+        self.attention_paths[name] = path
+        if self.model_cfg.num_experts:
+            self.moe_paths[name] = self._moe_path(batch, positions)
         return fn
 
     def prefill(self, tokens, starts, lengths, sampling: SamplingParams,
@@ -922,7 +948,8 @@ class ModelRunner:
         ([max_num_seqs]), as the engine keeps them. Returns device
         (ids, logprobs, tops) — ids/logprobs [R], by row; tops None
         unless topk > 0, then ([R, K] ids, [R, K] logprobs)
-        alternatives.
+        alternatives — and the rows the experts multiplied (a device
+        int32 scalar; None on a dense model).
 
         Prefill executables compile lazily per (rows, chunk, kv
         bucket), each on the attention path its shape selects
@@ -979,8 +1006,8 @@ class ModelRunner:
             self._prefill_fns, key,
             make_prefill, args, kind="prefill", window=Tb,
             kv_len=kv_len, batch=R, positions=Tb)
-        ids, lps, tis, tls, self.cache = fn(*args)
-        return ids, lps, (tis, tls) if topk else None
+        ids, lps, tis, tls, self.cache, expert_rows = fn(*args)
+        return ids, lps, (tis, tls) if topk else None, expert_rows
 
     def embed(self, tokens, lengths):
         """Mean-pooled final hidden states for padded prompts.

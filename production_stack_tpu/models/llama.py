@@ -315,7 +315,7 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     with this layer's chunk written (models/kv.py: carried, never
     stacked); what is done to it is done behind models/kv.py
     (``append``, ``attend``). kv None (encode): no cache. Returns (x',
-    the pool, the experts whose weights the block read: None on a
+    the pool, the experts' work in the block, ops/moe.Work: None on a
     dense model).
 
     kv_len (static) bounds attention to the first ceil(kv_len/Bs) blocks
@@ -329,7 +329,8 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     token count the experts' capacity is reckoned on, where that is not
     B*T (ops/moe.moe_mlp ``capacity_tokens``). expert_stacks: the
     experts' gate/up/down of ALL layers, where the block reads its own
-    in place (ops/moe.py, the list path); ``lp`` then lacks them.
+    in place (ops/moe.py, the list and grouped paths); ``lp`` then
+    lacks them.
     lora_layer: this layer's stacked adapters {proj: {a, b}} + per-row
     adapter_ids [B] (models/lora.py) — batched multi-LoRA.
 
@@ -427,11 +428,12 @@ def _mlp_block(cfg: ModelConfig, x, lp: Params, kv, token_valid,
         hidden = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps,
                           offset=offset)
     act = jax.nn.silu if cfg.activation == "silu" else _gelu_tanh
-    experts_read = None
+    work = None
     if "router" in lp:
         H = hidden.shape[-1]
-        # the list path reads its experts in place in the whole stacks
-        # (ops/moe.list_path, asked by ``forward``); else this layer's
+        # the list and grouped paths read their experts in place in
+        # the whole stacks (ops/moe.list_path and grouped_path, asked
+        # by ``forward``); else this layer's
         gate, up, down = (
             lp[n] if expert_stacks is None else expert_stacks[n]
             for n in ("gate", "up", "down"))
@@ -441,7 +443,7 @@ def _mlp_block(cfg: ModelConfig, x, lp: Params, kv, token_valid,
         if moe_layer is not None and cfg.first_dense_layers:
             moe_layer = layer - cfg.first_dense_layers
         # scopes moe_router / moe_experts / moe_combine: ops/moe.py
-        y, experts_read = moe.moe_mlp(
+        y, work = moe.moe_mlp(
             hidden.reshape(B * T, H), lp["router"], gate, up, down,
             top_k=cfg.num_experts_per_tok,
             capacity_factor=cfg.moe_capacity_factor,
@@ -452,7 +454,7 @@ def _mlp_block(cfg: ModelConfig, x, lp: Params, kv, token_valid,
             # decode (T == 1) must be exact: a dropped token would
             # corrupt a live sequence's residual stream mid-generation
             exact=True if T == 1 else None,
-            layer=moe_layer,
+            layer=moe_layer, positions=T,
             router_score=cfg.router_score,
             router_bias=lp.get("router_bias"),
             routed_scale=cfg.routed_scaling_factor)
@@ -479,7 +481,7 @@ def _mlp_block(cfg: ModelConfig, x, lp: Params, kv, token_valid,
                 mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"],
                                    cfg.rms_norm_eps, offset=offset)
             x = x + mlp_out
-    return x, kv, experts_read
+    return x, kv, work
 
 
 def _gelu_tanh(x: jnp.ndarray) -> jnp.ndarray:
@@ -498,7 +500,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             mesh=None, moe_capacity_tokens: Optional[int] = None,
             ) -> Tuple[jnp.ndarray, KVCache, Optional[jnp.ndarray]]:
     """Incremental forward. tokens/positions [B,T] -> (logits fp32
-    [B,T,V], cache', experts read).
+    [B,T,V], cache', the experts' work).
 
     cache is the paged block pool (models/kv.py); block_tables [B, MB]
     map each row's virtual positions to pool blocks (None = identity
@@ -517,10 +519,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     many tokens instead of B*T — a prefill of fewer rows than the full
     batch passes the full batch's count, so that it never holds less
     per expert (ops/moe.moe_mlp ``capacity_tokens``).
-    experts read: the experts whose weights the forward read, summed
-    over the layers (int32 scalar; None on a dense model): layers x
-    experts, or the experts its valid rows were routed to where the
-    expert matmuls walk that list (ops/moe.list_path: decode steps).
+    the experts' work (ops/moe.Work, summed over the layers; None on
+    a dense model): ``experts_read``, the experts whose weights the
+    forward read (layers x experts, or the experts its valid rows were
+    routed to where the expert matmuls walk that list: ops/moe
+    list_path, decode steps, and grouped_path, prefill chunks), and
+    ``expert_rows``, the rows those experts multiplied.
     """
     if rope is None:
         rope = rope_table(cfg.max_position_embeddings, cfg.rope_dim_,
@@ -545,7 +549,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         # the whole pool per step (models/kv.py)
         h, pool = carry
         lp, layer, ll, local = xs
-        h, pool, experts_read = _layer_body(
+        h, pool, work = _layer_body(
             cfg, rope, positions, starts, h, lp, pool,
             kv_len=kv_len, lora_layer=ll, adapter_ids=adapter_ids,
             lora_scaling=lora_scaling, token_valid=token_valid,
@@ -553,18 +557,19 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             layer_local=local, layer=layer,
             moe_capacity_tokens=moe_capacity_tokens,
             expert_stacks=expert_stacks)
-        return (h, pool), experts_read
+        return (h, pool), work
 
     layer_params = params["layers"]
     expert_stacks = None
-    if cfg.num_experts and moe.list_path(
+    if cfg.num_experts and any(in_place(
             *tokens.shape, cfg.hidden_size,
             cfg.moe_intermediate_size or cfg.intermediate_size,
-            moe.stored_dtype(layer_params["gate"]), x.dtype, mesh):
-        # the list path's kernel reads a layer of the expert stacks in
-        # place: closed over whole, like the pool, where the scan's xs
-        # would hand it a copy of the layer (a custom call cannot fuse
-        # its operand's slice)
+            moe.stored_dtype(layer_params["gate"]), x.dtype, mesh)
+            for in_place in (moe.list_path, moe.grouped_path)):
+        # the list path's kernel and the grouped path's read a layer of
+        # the expert stacks in place: closed over whole, like the pool,
+        # where the scan's xs would hand them a copy of the layer (a
+        # custom call cannot fuse its operand's slice)
         expert_stacks = {n: layer_params[n] for n in ("gate", "up", "down")}
         layer_params = {n: w for n, w in layer_params.items()
                         if n not in expert_stacks}
@@ -584,14 +589,14 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             (x, pool), _ = scan_body((x, pool),
                                      (lp, jnp.int32(i), None, None))
     with jax.named_scope("layers"):
-        (x, pool), experts_read = jax.lax.scan(scan_body, (x, pool), xs)
+        (x, pool), work = jax.lax.scan(scan_body, (x, pool), xs)
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                      offset=1.0 if cfg.rms_norm_offset else 0.0)
     with jax.named_scope("lm_head"):
         logits = _lm_head(params, cfg, x)
     return (logits, KVCache(*pool),
-            None if experts_read is None else jnp.sum(experts_read))
+            None if work is None else moe.Work(*map(jnp.sum, work)))
 
 
 def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,

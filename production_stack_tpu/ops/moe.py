@@ -8,9 +8,15 @@ helm/templates/deployment-vllm-multi.yaml:57-64; expert parallelism is a
 - Routing, dispatch and combine are all static-shape jnp — no
   data-dependent shapes, so the whole block lives inside the engine's
   jitted prefill/decode executables and XLA can schedule it.
-- Three strategies, chosen at trace time from the shapes (rows and
+- Four strategies, chosen at trace time from the shapes (rows and
   positions, so the token count N; experts E, top-k, the experts'
-  widths) and the mesh; no option selects one:
+  widths) and the mesh; no option selects one. ``list_path`` and
+  ``grouped_path`` are the rules for the two that read the experts in
+  place (models/llama.forward asks them, to hand the stacks over
+  whole); where both say no, ``moe_mlp`` chooses between the other two
+  by the tokens and the capacity; ``moe_path`` names the outcome
+  (engine/runner.py records it per executable: GET /debug/perf
+  ``device.moe_paths``):
 
   **Exact (small N).** Every expert runs over all N tokens and results
   are combined with the routing weights ([N, E], zero for unselected
@@ -33,7 +39,23 @@ helm/templates/deployment-vllm-multi.yaml:57-64; expert parallelism is a
   the exact path: an expert no valid row chose contributes exactly
   zero there.
 
-  **Capacity dispatch (large N, the prefill path).** The GShard/Switch
+  **Grouped (a prefill chunk whose experts fit VMEM).** Every expert
+  over the rows routed to it and no others: the N k assignments are
+  laid out by expert in one buffer (each expert's segment starting at
+  a multiple of the rows a copy is aligned to; the assignments of
+  invalid tokens left out), one Pallas call a layer walks the experts
+  that have a row, copying each one's weights as the list kernel does
+  and multiplying its segment in passes of GROUPED_ROWS rows, and each
+  assignment's row is gathered back, weighed and the k terms summed in
+  float32 (``_moe_grouped``, further down). ``grouped_path`` is the
+  rule: a prefill chunk (more positions a row than the decode
+  attention kernel takes), and what the list path needs of the mesh,
+  the widths, VMEM and the kernels. Nothing is dropped at any routing: the exact path's
+  sum with the zeros left out, at 1/15 (Qwen1.5-MoE) to 1/16
+  (GLM-4.7-Flash) of its arithmetic.
+
+  **Capacity dispatch (large N where the kernels are off or the mesh
+  shards the experts).** The GShard/Switch
   pattern reshaped for scatter/gather instead of [N, E, C] one-hots:
   each (token, choice) assignment gets a rank within its expert (an
   O(N*k*E) cumsum — integers, negligible next to the FFN matmuls) and
@@ -60,7 +82,7 @@ selection alone, a routing scale) are ``route``'s arguments.
 """
 
 import functools
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +90,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from production_stack_tpu.ops import pallas_paged
+
+
+class Work(NamedTuple):
+    """What a call's experts did (int32 scalars; models/llama.forward
+    sums them over the layers)."""
+    experts_read: jnp.ndarray       # experts whose weights were read
+    expert_rows: jnp.ndarray        # rows the experts multiplied
 
 
 def capacity_for(n_tokens: int, num_experts: int, top_k: int,
@@ -189,6 +218,11 @@ def _moe_exact(x, top_p, top_i, gate, up, down, act):
 # itself takes the exact path, whose sum the list path computes
 DENSE_THRESHOLD = 64
 
+# the grouped path (a prefill chunk's experts, further down): the rows
+# of an expert's segment that one pass of its three matmuls takes (the
+# MXU's 128 rows: a pass over fewer costs the same weight loads)
+GROUPED_ROWS = 128
+
 # the share of pallas_paged.VMEM_LIMIT_BYTES the kernel's scratch may
 # take; the rest is the compiler's (the float32 products, the rows).
 # The largest compiled: Qwen1.5-MoE's experts in bfloat16, 0.33
@@ -206,30 +240,75 @@ def list_scratch_bytes(hidden: int, inter: int, weight_dtype,
     return hidden * inter * (2 * 3 * stored.itemsize + converted)
 
 
-def list_path(rows: int, positions: int, hidden: int, inter: int,
-              weight_dtype, act_dtype, mesh=None) -> bool:
-    """Do the expert matmuls of a forward over ``positions`` tokens of
-    each of ``rows`` rows walk the list of experts hit (``_moe_list``)?
-    Decided here, from the shapes and the mesh, at trace time: a decode
-    step (one position a row) of at most DENSE_THRESHOLD rows, no mesh
-    axis shards anything, the widths are multiples of the 128 lanes,
-    two slots of one expert's matrices fit VMEM (list_scratch_bytes:
-    Qwen1.5-MoE's 2048 x 1408 do, 23 MB in int8; Mixtral-8x7B's 4096 x
-    14336 do not, 470 MB), and the Pallas kernels run at all
+def _experts_in_vmem(hidden: int, inter: int, weight_dtype, act_dtype,
+                     mesh) -> bool:
+    """What both kernels that read experts in place need (the list
+    path's and the grouped path's): the Pallas kernels run at all
     (pallas_paged.flash_enabled: compiled on a TPU, off on the CPU,
-    interpret mode where a test forces it). Elsewhere today's paths:
-    prefill chunks and speculative windows (positions > 1) among them.
-    No expected share of experts hit enters: with every expert on the
-    list the kernel takes at most 1 % longer than ``_moe_exact``
-    (PERF.md, PR 34), with fewer it takes less. models/llama.forward asks it to know
-    whether to hand the stacks over whole."""
+    interpret mode where a test forces it), no mesh axis shards
+    anything, the widths are multiples of the 128 lanes, and two slots
+    of one expert's matrices fit VMEM (list_scratch_bytes:
+    Qwen1.5-MoE's 2048 x 1408 do, 23 MB in int8; Mixtral-8x7B's 4096 x
+    14336 do not, 470 MB)."""
     return (pallas_paged.flash_enabled()
-            and positions == 1 and rows <= DENSE_THRESHOLD
             and (mesh is None
                  or all(size == 1 for size in mesh.shape.values()))
             and hidden % 128 == 0 and inter % 128 == 0
             and list_scratch_bytes(hidden, inter, weight_dtype, act_dtype)
             <= _LIST_VMEM_SHARE * pallas_paged.VMEM_LIMIT_BYTES)
+
+
+def list_path(rows: int, positions: int, hidden: int, inter: int,
+              weight_dtype, act_dtype, mesh=None) -> bool:
+    """Do the expert matmuls of a forward over ``positions`` tokens of
+    each of ``rows`` rows walk the list of experts hit (``_moe_list``)?
+    Decided here, from the shapes and the mesh, at trace time: a decode
+    step (one position a row) of at most DENSE_THRESHOLD rows whose
+    experts can be read in place (``_experts_in_vmem``). Elsewhere
+    ``grouped_path`` is asked (prefill chunks), then today's paths.
+    No expected share of experts hit enters: with every expert on the
+    list the kernel takes at most 1 % longer than ``_moe_exact``
+    (PERF.md, PR 34), with fewer it takes less. models/llama.forward
+    asks both rules to know whether to hand the stacks over whole."""
+    return (positions == 1 and rows <= DENSE_THRESHOLD
+            and _experts_in_vmem(hidden, inter, weight_dtype, act_dtype,
+                                 mesh))
+
+
+def grouped_path(rows: int, positions: int, hidden: int, inter: int,
+                 weight_dtype, act_dtype, mesh=None) -> bool:
+    """Do the expert matmuls of that forward run grouped: every expert
+    over the rows routed to it and no others (``_moe_grouped``)?
+    Decided as ``list_path`` is: a prefill chunk, which is more
+    positions a row than the decode attention kernel takes
+    (pallas_paged.DECODE_T_MAX: a speculative window's draft + 1
+    positions keep the exact path, and no cell runs one), and experts
+    that can be read in place (``_experts_in_vmem``, the list kernel's
+    clause: the grouped kernel holds the same two slots). No token
+    count enters: at every chunk bucket from 16 tokens up it was
+    level with the exact path or faster (PERF.md, PR 39). Nothing is
+    dropped at any routing, so no capacity enters."""
+    return (positions > pallas_paged.DECODE_T_MAX
+            and _experts_in_vmem(hidden, inter, weight_dtype, act_dtype,
+                                 mesh))
+
+
+def moe_path(rows: int, positions: int, num_experts: int, top_k: int,
+             hidden: int, inter: int, weight_dtype, act_dtype, mesh=None,
+             capacity_factor: float = 2.0, capacity_tokens=None) -> str:
+    """The strategy the experts of that forward take, as
+    models/llama.py calls ``moe_mlp`` (a decode step exact): "list",
+    "grouped", "exact" or "dispatch". engine/runner.py keeps it per
+    executable (``moe_paths``, GET /debug/perf ``device.moe_paths``)."""
+    shape = (rows, positions, hidden, inter, weight_dtype, act_dtype, mesh)
+    if list_path(*shape):
+        return "list"
+    if grouped_path(*shape):
+        return "grouped"
+    N = rows * positions
+    covered = N <= DENSE_THRESHOLD or N <= capacity_for(
+        capacity_tokens or N, num_experts, top_k, capacity_factor)
+    return "exact" if positions == 1 or covered else "dispatch"
 
 
 def experts_hit(top_i: jnp.ndarray, valid, num_experts: int):
@@ -254,6 +333,54 @@ def experts_hit(top_i: jnp.ndarray, valid, num_experts: int):
                          == jnp.arange(M, dtype=jnp.int32)[:, None])
     return (jnp.sum(jnp.where(at, ids[None, :], 0), axis=1),
             jnp.sum(hit.astype(jnp.int32)))
+
+
+def _rank_in_expert(top_i: jnp.ndarray, valid, num_experts: int):
+    """top_i [N, k], valid [N] bool or None -> (flat_e [N k] int32, the
+    assignments' experts, token-major; rank [N k] int32, how many
+    earlier assignments of valid tokens chose the same expert (an
+    O(N k E) cumsum of integers); rows [E] int32, the valid
+    assignments of each expert; keep [N k] bool, the assignment's
+    token is valid: None where valid is)."""
+    k = top_i.shape[1]
+    flat_e = top_i.reshape(-1)
+    onehot = jax.nn.one_hot(flat_e, num_experts, dtype=jnp.int32)
+    keep = None
+    if valid is not None:
+        keep = jnp.repeat(valid, k)
+        onehot = onehot * keep.astype(jnp.int32)[:, None]
+    prior = jnp.cumsum(onehot, axis=0) - onehot
+    rank = jnp.take_along_axis(prior, flat_e[:, None], axis=1)[:, 0]
+    return flat_e, rank, jnp.sum(onehot, axis=0), keep
+
+
+def _expert_copies(ids_ref, layer, hbms, gu_buf, d_buf, sems, c, slot):
+    """The copies of listed expert c's gate, up and down out of the
+    stacks in HBM into ``slot``: to start, or to wait for one by one."""
+    e = ids_ref[c]
+    return [pltpu.make_async_copy(hbm.at[layer, e], buf, sems.at[slot, o])
+            for o, (hbm, buf) in enumerate(zip(
+                hbms, (gu_buf.at[slot, 0], gu_buf.at[slot, 1],
+                       d_buf.at[slot])))]
+
+
+def _scale_row(ref, c):
+    """Row c of a [M8, w] block as [1, w]. Mosaic loads a dynamic
+    sublane only at a multiple of 8: take the aligned group of 8 rows
+    and keep the one."""
+    base = pl.multiple_of(jax.lax.div(c, 8) * 8, 8)
+    rows = ref[pl.ds(base, 8), :]
+    keep = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0) == c - base
+    return jnp.sum(jnp.where(keep, rows, 0.0), axis=0, keepdims=True)
+
+
+def _scaled_dot(a, w, scale):
+    """a @ w in float32, w converted to a's dtype in VMEM and its
+    per-channel scale (None: raw weights) applied to the products (the
+    compiler tiles the width: panels of 128 to 512 channels cut by
+    hand ran a layer within 1 % of this; PERF.md, PR 34)."""
+    y = jnp.dot(a, w.astype(a.dtype), preferred_element_type=jnp.float32)
+    return y if scale is None else y * scale
 
 
 def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
@@ -282,26 +409,9 @@ def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
     count = count_ref[0]
     cdt = x_ref.dtype                              # the dots' operands
 
-    def copies(c, slot):
-        """The copies of listed expert c's gate, up and down into
-        ``slot``: to start, or to wait for one by one."""
-        e = ids_ref[c]
-        return [pltpu.make_async_copy(hbm.at[layer, e], buf,
-                                      sems.at[slot, o])
-                for o, (hbm, buf) in enumerate((
-                    (gate_hbm, gu_buf.at[slot, 0]),
-                    (up_hbm, gu_buf.at[slot, 1]),
-                    (down_hbm, d_buf.at[slot])))]
-
-    def scale_row(ref, c):
-        """Row c of a [M8, w] block as [1, w]. Mosaic loads a dynamic
-        sublane only at a multiple of 8: take the aligned group of 8
-        rows and keep the one."""
-        base = pl.multiple_of(jax.lax.div(c, 8) * 8, 8)
-        rows = ref[pl.ds(base, 8), :]
-        keep = jax.lax.broadcasted_iota(jnp.int32, rows.shape,
-                                        0) == c - base
-        return jnp.sum(jnp.where(keep, rows, 0.0), axis=0, keepdims=True)
+    copies = functools.partial(_expert_copies, ids_ref, layer,
+                               (gate_hbm, up_hbm, down_hbm), gu_buf,
+                               d_buf, sems)
 
     @pl.when(count > 0)
     def _first():
@@ -324,28 +434,18 @@ def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
         # the rows that chose it, zero on the others
         comb = jnp.sum(jnp.where(ti_ref[...] == e, tp_ref[...], 0.0),
                        axis=1, keepdims=True)
-        sg, su, sd = ((scale_row(ref, c) for ref in scale_refs)
+        sg, su, sd = ((_scale_row(ref, c) for ref in scale_refs)
                       if quant else (None,) * 3)
-
-        def dot(a, w, scale):
-            """a @ w in float32, w converted to the operands' dtype in
-            VMEM and its per-channel scale applied to the products (the
-            compiler tiles the width: panels of 128 to 512 channels
-            cut by hand ran a layer within 1 % of this; PERF.md, PR
-            34)."""
-            y = jnp.dot(a, w.astype(cdt),
-                        preferred_element_type=jnp.float32)
-            return y * scale if quant else y
-
         # each matrix is waited for where it is first read: gate's
         # products run under up's and down's copies
         gate_copy, up_copy, down_copy = copies(c, slot)
         gate_copy.wait()
-        g = dot(x, gu_buf[slot, 0], sg)
+        g = _scaled_dot(x, gu_buf[slot, 0], sg)
         up_copy.wait()
-        a = (act(g) * dot(x, gu_buf[slot, 1], su)).astype(cdt)  # [N, i]
+        a = (act(g) * _scaled_dot(x, gu_buf[slot, 1], su)
+             ).astype(cdt)                                      # [N, i]
         down_copy.wait()
-        y = dot(a, d_buf[slot], sd)
+        y = _scaled_dot(a, d_buf[slot], sd)
         acc_ref[...] += y * comb
         return carry
 
@@ -406,6 +506,235 @@ def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
           jnp.asarray(layer, jnp.int32).reshape(1), *operands)
 
 
+# ---------------------------------------------------------------------
+# the grouped path: a prefill chunk's experts multiply only the rows
+# routed to them.
+#
+# ``_group_rows`` sorts the N k assignments by expert without sorting:
+# an assignment's place is its expert's segment start plus its rank
+# within the expert (the integer cumsum ``_moe_dispatch`` ranks by);
+# a segment starts at a multiple of the row alignment the copies need
+# (8 sublanes of 32 bits), so the buffer has N k + E (align - 1) rows,
+# rounded up, whatever the routing, and one pass more of slack; the
+# assignments of invalid tokens fall off its end and are never
+# computed. ``_moe_grouped`` gathers the tokens' rows into it and makes
+# one Pallas call a layer, built from the list path's: the stacks in
+# HBM, whole, the layer's index and the list of experts that have a
+# row scalar-prefetched, two VMEM slots, the next expert's three copies
+# under this expert's arithmetic. Per listed expert it walks the
+# segment in passes of GROUPED_ROWS rows (a dynamic trip count): a
+# pass's rows come in by a copy started a pass ahead and its products
+# [rows, h] leave by one that the next pass waits for. The last pass of
+# a segment runs over into the next expert's rows and writes what that
+# expert then overwrites (the experts are walked in the segments'
+# order and a pass's write is awaited before the next starts). Outside,
+# each assignment's row is gathered back, weighed and the k terms
+# summed in float32: ``_moe_exact``'s sum with the zeros left out.
+# ---------------------------------------------------------------------
+
+def _row_align(dtype) -> int:
+    """Rows a dynamic row offset into an array of ``dtype`` has to be
+    a multiple of: a tile of 8 sublanes of 32 bits."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def _group_rows(top_i: jnp.ndarray, valid, num_experts: int, align: int,
+                slack: int):
+    """top_i [N, k], valid [N] bool or None -> (dest [N k] int32: the
+    buffer row of each assignment, P where its token is invalid; rows
+    [E] int32: the rows of each expert; seg [E] int32: where each
+    expert's segment starts, a multiple of ``align``; P: the buffer's
+    rows, static)."""
+    N, k = top_i.shape
+    E = num_experts
+    flat_e, rank, rows, keep = _rank_in_expert(top_i, valid, E)
+    padded = -(-rows // align) * align
+    seg = jnp.cumsum(padded) - padded
+    P = -(-(N * k + E * (align - 1)) // align) * align + slack
+    dest = seg[flat_e] + rank
+    if keep is not None:
+        dest = jnp.where(keep, dest, P)
+    return dest, rows, seg, P
+
+
+def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
+                        xs_hbm, *refs, act: Callable, quant: bool,
+                        align: int):
+    """Every listed expert over its own rows.
+
+    ids_ref    (SMEM) [M]    the experts that have a row, compacted
+    count_ref  (SMEM) [1]    how many of them are live
+    layer_ref  (SMEM) [1]    the stacks' layer
+    seg_ref    (SMEM) [M]    where each listed expert's segment starts
+    passes_ref (SMEM) [M]    its passes: ceil(rows / R)
+    xs_hbm (HBM) [P, h]      the tokens' rows, sorted by expert
+    refs   gate, up (HBM) [L, E, h, i], down (HBM) [L, E, i, h];
+           (quant only: the listed experts' scale rows, as the list
+           kernel's;) out (HBM) [P, h]; scratch: the gate/up slots
+           [2, 2, h, i], the down slots [2, i, h], their semaphores
+           [2, 3], the rows' slots [2, R, h] in and [2, R, h] out,
+           their semaphores [2] and [2]
+    """
+    gate_hbm, up_hbm, down_hbm = refs[:3]
+    refs = refs[3:]
+    if quant:
+        scale_refs, refs = refs[:3], refs[3:]
+    out_hbm, gu_buf, d_buf, sems, x_buf, y_buf, x_sems, y_sems = refs
+    layer = layer_ref[0]
+    count = count_ref[0]
+    cdt = x_buf.dtype                              # the dots' operands
+    R = x_buf.shape[1]
+
+    copies = functools.partial(_expert_copies, ids_ref, layer,
+                               (gate_hbm, up_hbm, down_hbm), gu_buf,
+                               d_buf, sems)
+
+    def rows_in(row, slot):
+        return pltpu.make_async_copy(
+            xs_hbm.at[pl.ds(pl.multiple_of(row, align), R)],
+            x_buf.at[slot], x_sems.at[slot])
+
+    def rows_out(row, slot):
+        return pltpu.make_async_copy(
+            y_buf.at[slot],
+            out_hbm.at[pl.ds(pl.multiple_of(row, align), R)],
+            y_sems.at[slot])
+
+    @pl.when(count > 0)
+    def _first():
+        for cp in copies(0, 0):
+            cp.start()
+        rows_in(seg_ref[0], 0).start()
+
+    def expert(c, done):
+        slot = jax.lax.rem(c, 2)
+        more = c + 1 < count
+
+        @pl.when(more)
+        def _next():
+            for cp in copies(c + 1, 1 - slot):
+                cp.start()
+
+        sg, su, sd = ((_scale_row(ref, c) for ref in scale_refs)
+                      if quant else (None,) * 3)
+        gate_copy, up_copy, down_copy = copies(c, slot)
+        first = seg_ref[c]
+        passes = passes_ref[c]
+        # the segment after this one (the list's last: not read)
+        after = seg_ref[jnp.minimum(c + 1, ids_ref.shape[0] - 1)]
+
+        def one_pass(t, n):
+            """Rows first + t R .. + R of the buffer; n: the passes
+            made so far, whose parity names the rows' slot."""
+            rs = jax.lax.rem(n, 2)
+            row = first + t * R
+            inside = t + 1 < passes
+
+            @pl.when(inside | more)
+            def _rows_ahead():
+                rows_in(jnp.where(inside, row + R, after), 1 - rs).start()
+
+            rows_in(row, rs).wait()
+            x = x_buf[rs]
+            # each matrix is waited for where it is first read
+            pl.when(t == 0)(gate_copy.wait)
+            g = _scaled_dot(x, gu_buf[slot, 0], sg)
+            pl.when(t == 0)(up_copy.wait)
+            a = (act(g) * _scaled_dot(x, gu_buf[slot, 1], su)
+                 ).astype(cdt)
+            pl.when(t == 0)(down_copy.wait)
+            y_buf[rs] = _scaled_dot(a, d_buf[slot], sd
+                                    ).astype(y_buf.dtype)
+            # one write at a time, in the segments' order: what this
+            # pass writes past its segment the next overwrites
+
+            @pl.when(n > 0)
+            def _written():
+                rows_out(row, 1 - rs).wait()
+
+            rows_out(row, rs).start()
+            return n + 1
+
+        return jax.lax.fori_loop(0, passes, one_pass, done)
+
+    done = jax.lax.fori_loop(0, count, expert, 0)
+
+    @pl.when(done > 0)
+    def _last():
+        rows_out(0, jax.lax.rem(done - 1, 2)).wait()
+
+
+def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer):
+    """Every expert over the rows routed to it, combined by routing
+    weight: ``_moe_exact``'s result with nothing multiplied by a
+    weight of zero. gate/up [L, E, h, i], down [L, E, i, h] (raw or
+    int8-quantized), layer: int32 scalar, traced. Returns ([N, h], the
+    experts that had a row, the rows the experts multiplied: passes x
+    GROUPED_ROWS)."""
+    quant = _quant().is_quantized(gate)
+    N, h = x.shape
+    k = top_i.shape[1]
+    L, E, _, inter = _wshape(gate)
+    mats = [w["w8"] if quant else w for w in (gate, up, down)]
+    R = GROUPED_ROWS
+    align = _row_align(x.dtype)
+    with jax.named_scope("moe_group"):
+        dest, rows, seg, P = _group_rows(top_i, valid, E, align, R)
+        # the buffer's rows by the token each holds (padding: token 0,
+        # computed and never read back)
+        src = jnp.zeros((P,), jnp.int32).at[dest].set(
+            jnp.arange(N * k, dtype=jnp.int32) // k, mode="drop")
+        xs = x[src]                                       # [P, h]
+        ids, count = experts_hit(top_i, valid, E)
+        passes = -(-rows // R)
+
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [hbm] * 4
+    operands = [xs] + mats
+    if quant:
+        listed = jnp.pad(ids, (0, -ids.shape[0] % 8))
+        for w in (gate, up, down):
+            sc = w["scale"][layer, listed]                # [M8, w]
+            in_specs.append(pl.BlockSpec(sc.shape, lambda *_: (0, 0)))
+            operands.append(sc)
+    with jax.named_scope("moe_experts"):
+        ys = pl.pallas_call(
+            functools.partial(_moe_grouped_kernel, act=act, quant=quant,
+                              align=align),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(1,),
+                in_specs=in_specs,
+                out_specs=hbm,
+                scratch_shapes=[
+                    pltpu.VMEM((2, 2, h, inter), mats[0].dtype),
+                    pltpu.VMEM((2, inter, h), mats[2].dtype),
+                    pltpu.SemaphoreType.DMA((2, 3)),
+                    pltpu.VMEM((2, R, h), x.dtype),
+                    pltpu.VMEM((2, R, h), x.dtype),
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SemaphoreType.DMA((2,)),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((P, h), x.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
+            interpret=pallas_paged.needs_interpret(),
+            name="moe_grouped_experts",
+        )(ids, count.reshape(1), jnp.asarray(layer, jnp.int32).reshape(1),
+          seg[ids], passes[ids], *operands)
+    with jax.named_scope("moe_combine"):
+        # an invalid token's assignments read row 0, which may hold
+        # anything, and count as zero
+        kept = dest < P
+        y = jnp.where(kept[:, None],
+                      ys[jnp.where(kept, dest, 0)].astype(jnp.float32), 0.0)
+        y = jnp.sum((y * top_p.reshape(-1)[:, None]).reshape(N, k, h),
+                    axis=1)
+    return y.astype(x.dtype), count, jnp.sum(passes) * R
+
+
 def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
                   valid=None):
     """Scatter-based capacity dispatch (see module docstring)."""
@@ -413,20 +742,12 @@ def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
     E = _wshape(gate)[0]
     k = top_i.shape[1]
 
-    flat_e = top_i.reshape(-1)                          # [N*k] token-major
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-    if valid is not None:
-        # padding tokens must not compete for expert capacity: drop
-        # their assignments from the rank count and the buffers
-        valid_rep = jnp.repeat(valid.astype(jnp.int32), k)
-        onehot = onehot * valid_rep[:, None]
-    # rank of each assignment within its expert (how many earlier
-    # assignments chose the same expert)
-    prior = jnp.cumsum(onehot, axis=0) - onehot
-    rank = jnp.take_along_axis(prior, flat_e[:, None], axis=1)[:, 0]
+    # padding tokens must not compete for expert capacity: they are
+    # left out of the rank count and the buffers
+    flat_e, rank, _, real = _rank_in_expert(top_i, valid, E)
     keep = rank < capacity
-    if valid is not None:
-        keep = keep & (valid_rep > 0)
+    if real is not None:
+        keep = keep & real
     trash = E * capacity                                # overflow row
     dest = jnp.where(keep, flat_e * capacity + rank, trash)
 
@@ -449,12 +770,13 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             dense_threshold: int = DENSE_THRESHOLD,
             act: Callable = jax.nn.silu, valid=None,
             exact=None, renormalize: bool = True,
-            capacity_tokens=None, layer=None,
+            capacity_tokens=None, layer=None, positions: int = 1,
             router_score: str = "softmax", router_bias=None,
             routed_scale: float = 1.0):
     """MoE feed-forward. x [N, h]; router_w [h, E]; gate/up [E, h, i];
-    down [E, i, h]. Returns ([N, h] in x.dtype, the experts whose
-    weights the call read: an int32 scalar).
+    down [E, i, h]. Returns ([N, h] in x.dtype, ``Work``: the experts
+    whose weights the call read and the rows they multiplied, int32
+    scalars).
 
     valid [N] bool marks real tokens: padding rows contribute nothing
     and never consume expert capacity. exact=True forces the all-expert
@@ -469,10 +791,12 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
     one, and where that covers its N tokens (Qwen1.5-MoE, 256 tokens:
     552) it takes the exact path and drops nothing.
     layer (int32 scalar, traced): gate/up/down are the whole stacks
-    [L, E, ...] of which that layer is read in place, by the list path.
-    Where ``list_path`` says so and nowhere else (models/llama.forward
-    asks it, with the mesh, before it hands the stacks over); it is the
-    exact path's sum, so ``exact`` False is refused and the capacity
+    [L, E, ...] of which that layer is read in place, by the list path
+    or the grouped path. Where ``list_path`` or ``grouped_path`` says
+    so and nowhere else (models/llama.forward asks them, with the mesh,
+    before it hands the stacks over; ``positions``, static, is the
+    tokens a row of the N: 1 a decode step); both compute the exact
+    path's sum, so ``exact`` False is refused and the capacity
     arguments do not apply.
     router_score, router_bias, routed_scale: ``route``'s score, bias
     and scale; every path takes the weights it gives unchanged.
@@ -487,14 +811,21 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             top_p = top_p * valid.astype(top_p.dtype)[:, None]
     if layer is not None:
         h, inter = _wshape(gate)[-2:]
-        assert exact is not False and list_path(
-            N, 1, h, inter, stored_dtype(gate), x.dtype), (
-            "moe_mlp was handed whole stacks where list_path says no: "
-            f"{N} rows, experts [{h}, {inter}], exact={exact}")
+        shape = (N // positions, positions, h, inter, stored_dtype(gate),
+                 x.dtype)
+        assert exact is not False and (
+            list_path(*shape) or grouped_path(*shape)), (
+            "moe_mlp was handed whole stacks where neither list_path "
+            f"nor grouped_path says so: {N} tokens, {positions} a row, "
+            f"experts [{h}, {inter}], exact={exact}")
+        if grouped_path(*shape):
+            y, count, multiplied = _moe_grouped(
+                x, top_p, top_i, gate, up, down, act, valid, layer)
+            return y, Work(count, multiplied)
         with jax.named_scope("moe_list"):
             ids, count = experts_hit(top_i, valid, E)
         return _moe_list(x, top_p, top_i, gate, up, down, act, ids,
-                         count, layer), count
+                         count, layer), Work(count, count * N)
     capacity = min(N, capacity_for(capacity_tokens or N, E, top_k,
                                    capacity_factor))
     if exact is None:
@@ -504,4 +835,4 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
     else:
         y = _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
                           valid=valid)
-    return y, jnp.int32(E)
+    return y, Work(jnp.int32(E), jnp.int32(E * (N if exact else capacity)))

@@ -532,6 +532,132 @@ def test_qwen_speculative_window_keeps_the_exact_path(topo,
     assert "moe_list_experts" not in hlo and "moe_experts" in hlo
 
 
+# ---------------------------------------------------------------------
+# the experts of a prefill chunk (ops/moe.py, the grouped path)
+# ---------------------------------------------------------------------
+
+GLM_MOE = dict(E=64, h=2048, i=1536, k=4)     # GLM-4.7-Flash
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 128), (1, 256), (16, 256)])
+@pytest.mark.parametrize("experts", [QWEN_MOE, GLM_MOE],
+                         ids=["qwen15moe", "glm47flash"])
+def test_moe_grouped_kernel_compiles(topo, tpu_branches, experts, rows,
+                                     tokens):
+    """The grouped path's kernel at the MoE cells' widths (int8 stacks
+    of 12 layers, the layer an operand) and their prefill shapes: the
+    one-row chunk of both buckets and the lead-in's 16-row burst. Two
+    slots of three int8 matrices, the converted copy, two passes' rows
+    in and out have to fit the VMEM limit; the rows' copies start at
+    any multiple of 16 rows of a buffer in HBM."""
+    from production_stack_tpu.ops import moe
+    E, h, i, k = (experts[n] for n in "Ehik")
+    N, L = rows * tokens, 12
+    assert moe.grouped_path(rows, tokens, h, i, jnp.int8, jnp.bfloat16)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def stack(dims):
+        return {"w8": shape(dims, jnp.int8),
+                "scale": shape(dims[:2] + dims[3:], jnp.float32)}
+
+    def call(x, top_p, top_i, valid, gate, up, down, layer):
+        return moe._moe_grouped(x, top_p, top_i, gate, up, down,
+                                jax.nn.silu, valid, layer)
+
+    hlo = jax.jit(call).lower(
+        shape((N, h), jnp.bfloat16), shape((N, k), jnp.float32),
+        shape((N, k), jnp.int32), shape((N,), jnp.bool_),
+        stack((L, E, h, i)), stack((L, E, h, i)), stack((L, E, i, h)),
+        shape((), jnp.int32)).compile().as_text()
+    assert "moe_grouped_experts" in hlo and "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("model,layers,experts,rows", [
+    ("qwen1.5-moe-a2.7b", 2, QWEN_MOE, 1),
+    ("glm-4.7-flash", 3, GLM_MOE, 1),
+    ("qwen1.5-moe-a2.7b", 2, QWEN_MOE, 0)],
+    ids=["qwen15moe-1row", "glm47flash-1row", "qwen15moe-16rows"])
+def test_prefill_chunk_multiplies_only_routed_rows(topo, tpu_branches,
+                                                   model, layers,
+                                                   experts, rows):
+    """A prefill chunk of 256 tokens of the runner at the MoE cells'
+    widths, compiled whole: the expert matmuls are the grouped path's
+    custom call on the stacks in place; no instruction yields a product
+    of every expert over every token ([E, N, i] or [E, N, h], the exact
+    path's; [E, C, ...] at any capacity, the dispatch's) and none an
+    array of an expert stack's shape or of one layer's."""
+    import re
+    runner, params, cache, rep = _runner_shapes(
+        topo, 1, layers=layers, kv_blocks=97, model=model)
+    hlo = _compile_prefill_chunk(runner, params, cache, rep, 256,
+                                 rows).as_text()
+    assert "moe_grouped_experts" in hlo
+    assert "moe_list_experts" not in hlo
+    E, h, i = (experts[n] for n in "Ehi")
+    per_expert = [m.group(0) for m in re.finditer(
+        r"\w+\[{},\d+,(?:{}|{})\]".format(E, i, h), hlo)]
+    assert not per_expert, per_expert[:5]
+    stack = r"(?:\d+,)?{},(?:{},{}|{},{})".format(E, h, i, i, h)
+    assert not _stack_makers(hlo, stack)
+
+
+def _program_digest(lowered) -> str:
+    """sha256 of a lowered program's text, each kernel's serialized
+    Mosaic module replaced by its MLIR without debug info (the text
+    carries no source locations; the serialized modules do, and those
+    move with every line added to ops/)."""
+    import base64
+    import hashlib
+    import re
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    def module_digest(m):
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(
+                m.group(1))).operation.get_asm(enable_debug_info=False)
+        return "body: " + hashlib.sha256(asm.encode()).hexdigest()
+
+    text = re.sub(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', module_digest,
+                  lowered.as_text())
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("model,layers,digest", [
+    ("qwen1.5-moe-a2.7b", 2,
+     "159b1ec7338481e961752d3c1eb5691267fe2338ef8f016c98c02c8445611e74"),
+    ("glm-4.7-flash", 3,
+     "1e71969c295a9609fd8f874e28b5a6220e3bb962c87564b61e99e5381d79e1c9")],
+    ids=["qwen15moe", "glm47flash"])
+def test_moe_decode_window_lowers_to_the_pinned_text(topo, tpu_branches,
+                                                     model, layers,
+                                                     digest):
+    """``jit_decode_window`` of both MoE configurations (16 rows, 8
+    steps, the 512 kv bucket, greedy) lowers for the described v5e to
+    the text it lowered to at commit 5d902fa, PR 38 (digests taken on
+    that tree by this test, under pytest: the suite's conftest.py
+    enters the text). PR 39 changed the prefill's experts and nothing
+    a decode step runs, so a chip run before and after differs in the
+    prefill alone. A PR that means to change the decode program pins
+    its own digests here and says so."""
+    runner, params, cache, rep = _runner_shapes(
+        topo, 1, layers=layers, kv_blocks=97, model=model)
+    B = runner.engine_cfg.max_num_seqs
+    a = _step_args(runner, rep, B)
+    fn = jax.jit(partial(runner._decode_impl, steps=8, kv_len=512,
+                         greedy=True), donate_argnums=(1,))
+    lowered = fn.lower(
+        params, cache, a["tables"], rep((B,), jnp.int32),
+        rep((B,), jnp.int32), a["sampling"], a["key"], a["guide_next"],
+        a["guide_id"], a["guide_state"], a["counts"], a["seen"])
+    assert _program_digest(lowered) == digest
+
+
 def test_dense_decode_window_has_no_expert_call(topo, tpu_branches):
     """The dense model's decode window knows nothing of the list path:
     its only custom call is the attention kernel's, and no instruction

@@ -171,7 +171,7 @@ def test_layer_plan_of_one_dense_and_three_expert_layers():
             params, cfg, tokens, jnp.arange(5)[None], cache,
             block_tables=kv_pool.linear_tables(1, 4 * BS, BS))
     assert written == [0, 1, 2, 3]
-    assert int(read) == 3 * 8           # three expert layers, all read
+    assert int(read.experts_read) == 3 * 8           # three expert layers, all read
     # every layer's block 1 holds five written vectors and nothing else
     filled = jnp.any(cache.k[:, 1, 0] != 0, axis=-1)        # [L, Bs]
     assert filled.tolist() == [[True] * 5 + [False] * (BS - 5)] * 4

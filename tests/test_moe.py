@@ -357,7 +357,8 @@ def test_list_path_matches_exact_and_reference(kernels_on, case):
 
     top_p, top_i = moe.route(x, rw, k, renormalize=renorm)
     chosen = np.unique(np.asarray(top_i)[:n_valid])
-    assert int(read) == len(chosen)
+    assert int(read.experts_read) == len(chosen)
+    assert int(read.expert_rows) == len(chosen) * N
     assert len(chosen) == {"same4": 4, "all": 60}.get(routing, len(chosen))
     one = [jax.tree_util.tree_map(lambda a: a[layer], w) for w in stacks]
     exact = moe._moe_exact(x, top_p * valid[:, None], top_i, *one,
@@ -469,11 +470,15 @@ def test_moe_mlp_refuses_stacks_where_the_rule_says_no(kernels_on):
     list nobody chose."""
     x, rw, stacks = _rigged_stacks(jax.random.PRNGKey(3), "random",
                                    N=moe.DENSE_THRESHOLD + 8)
-    with pytest.raises(AssertionError, match="list_path says no"):
+    with pytest.raises(AssertionError, match="handed whole stacks"):
         moe.moe_mlp(x, rw, *stacks, top_k=4, layer=jnp.int32(0))
-    with pytest.raises(AssertionError, match="list_path says no"):
+    with pytest.raises(AssertionError, match="handed whole stacks"):
         moe.moe_mlp(x[:16], rw, *stacks, top_k=4, exact=False,
                     layer=jnp.int32(0))
+    # nor a speculative window's few positions a row (grouped_path)
+    with pytest.raises(AssertionError, match="handed whole stacks"):
+        moe.moe_mlp(x[:16], rw, *stacks, top_k=4, layer=jnp.int32(0),
+                    positions=4)
 
 
 def test_forward_takes_the_list_path_in_place(kernels_on):
@@ -503,6 +508,7 @@ def test_forward_takes_the_list_path_in_place(kernels_on):
     logits, _, read = run()
     pallas_paged.set_flash_enabled(False)
     want, _, read_all = run()
+    read, read_all = read.experts_read, read_all.experts_read
     assert int(read_all) == cfg.num_layers * cfg.num_experts
     assert 2 * 2 <= int(read) <= 2 * B * 2 < int(read_all)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
@@ -511,10 +517,14 @@ def test_forward_takes_the_list_path_in_place(kernels_on):
 
 @pytest.mark.parametrize("B,T", [(1, 16), (4, 4)],
                          ids=["one-row-prefill", "speculative-window"])
-def test_forward_of_several_positions_reads_every_expert(kernels_on, B, T):
+def test_forward_of_several_positions_never_walks_the_list(kernels_on, B,
+                                                           T):
     """A short prefill chunk and a speculative window hold as few
-    tokens as a decode batch does, and keep today's path all the same:
-    the list path is the decode step's (one position a row)."""
+    tokens as a decode batch does, and neither takes the list path,
+    which is the decode step's (one position a row): the chunk runs
+    grouped (ops/moe.grouped_path: more positions a row than the
+    decode attention kernel takes), the window keeps the exact path
+    and reads every expert."""
     cfg = ModelConfig(name="t-moe16", vocab_size=128, hidden_size=128,
                       intermediate_size=128, num_layers=2, num_heads=2,
                       num_kv_heads=2, max_position_embeddings=64,
@@ -527,12 +537,276 @@ def test_forward_of_several_positions_reads_every_expert(kernels_on, B, T):
         dtype=jnp.float32)
     toks = jnp.arange(B * T, dtype=jnp.int32).reshape(B, T)
     pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    jaxpr = jax.make_jaxpr(lambda: llama.forward(
-        params, cfg, toks, pos, cache, block_tables=tables))()
-    assert "moe_list_experts" not in str(jaxpr)
+    jaxpr = str(jax.make_jaxpr(lambda: llama.forward(
+        params, cfg, toks, pos, cache, block_tables=tables))())
+    assert "moe_list_experts" not in jaxpr
+    grouped = T > pallas_paged.DECODE_T_MAX
+    assert ("moe_grouped_experts" in jaxpr) is grouped
     _, _, read = llama.forward(params, cfg, toks, pos, cache,
                                block_tables=tables)
-    assert int(read) == cfg.num_layers * cfg.num_experts
+    every = cfg.num_layers * cfg.num_experts
+    if grouped:     # 16 tokens x top-2: 2 to 16 experts a layer
+        assert cfg.num_layers * 2 <= int(read.experts_read) <= every
+    else:
+        assert int(read.experts_read) == every
+
+
+# ---------------------------------------------------------------------
+# the grouped path (ops/moe.py): a prefill chunk's experts multiply only
+# the rows routed to them, sorted by expert, in place in the stacks.
+# The Pallas kernel runs in interpret mode here.
+# ---------------------------------------------------------------------
+
+def _reference_routed(x, top_p, top_i, g, u, d, valid):
+    """Per-token numpy loop over the experts each token chose, at the
+    weights the router gave: float32, independent of ops/moe.py."""
+    x, top_p, top_i, g, u, d = map(np.asarray, (x, top_p, top_i, g, u, d))
+    out = np.zeros_like(x)
+    for t in np.flatnonzero(valid):
+        for w, e in zip(top_p[t], top_i[t]):
+            hidden = x[t] @ g[e]
+            hidden = hidden / (1 + np.exp(-hidden)) * (x[t] @ u[e])
+            out[t] += w * (hidden @ d[e])
+    return out
+
+
+GROUPED_CASES = {
+    # name: (rows, tokens, E, k, dtype, weights, routing, real tokens
+    #        of each row (None: all), router score)
+    "128-f32-raw-8-top2": (1, 128, 8, 2, "float32", "raw", "random",
+                           None, "softmax"),
+    "128-bf16-int8-8-top2": (1, 128, 8, 2, "bfloat16", "int8", "random",
+                             None, "softmax"),
+    "256-bf16-int8-60-top4": (1, 256, 60, 4, "bfloat16", "int8",
+                              "random", None, "softmax"),
+    "256-bf16-raw-60-top4": (1, 256, 60, 4, "bfloat16", "raw", "random",
+                             None, "softmax"),
+    "256-f32-int8-64-top4": (1, 256, 64, 4, "float32", "int8", "random",
+                             None, "softmax"),
+    "512-bf16-int8-64-top4-sigmoid": (1, 512, 64, 4, "bfloat16", "int8",
+                                      "random", None, "sigmoid"),
+    # right padding: 131 real tokens, group sizes no multiple of a tile
+    "256-right-padded": (1, 256, 60, 4, "bfloat16", "int8", "random",
+                         [131], "softmax"),
+    # the lead-in's burst in small: 16 rows, each padded, four parked
+    "16x64-padded-and-parked": (16, 64, 60, 4, "bfloat16", "int8",
+                                "random",
+                                [64, 1, 33, 17, 64, 50, 9, 40, 64, 64, 2,
+                                 31, 0, 0, 0, 0], "softmax"),
+    # every token on the same two experts: 200 rows each (two passes,
+    # the second half empty), 62 experts with no row
+    "every-token-on-one-pair": (1, 256, 64, 2, "bfloat16", "int8",
+                                "same", [200], "softmax"),
+    # experts 8..63 never chosen
+    "experts-with-no-row": (1, 128, 64, 2, "float32", "raw", "first8",
+                            None, "softmax"),
+    "no-valid-token": (1, 128, 8, 2, "bfloat16", "int8", "random", [0],
+                       "softmax"),
+}
+
+
+def _grouped_case(name, h=128, i=256, L=2):
+    """The operands of one case: x [N, h], the router (and GLM's bias
+    and scale where the score is sigmoid), [L, E, ...] stacks, the
+    tokens' valid mask."""
+    rows, tokens, E, k, dtype, weights, routing, real, score = \
+        GROUPED_CASES[name]
+    N = rows * tokens
+    dtype = jnp.dtype(dtype)
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 6)
+    x = jax.random.normal(ks[0], (N, h), jnp.float32)
+    rw = jax.random.normal(ks[1], (h, E), jnp.float32) * 0.2
+    if routing == "same":        # feature 0 large, pointing at 0..k-1
+        x = x.at[:, 0].set(30.0)
+        rw = rw.at[0, :k].add(5.0)
+    if routing == "first8":      # experts 8.. pushed out of every top-k
+        rw = rw.at[:, 8:].set(0.0)
+        x = x.at[:, 0].set(30.0)
+        rw = rw.at[0, 8:].set(-5.0)
+    stacks = [(jax.random.normal(kk, dims, jnp.float32) * 0.1).astype(dtype)
+              for kk, dims in zip(ks[2:5], ((L, E, h, i), (L, E, h, i),
+                                            (L, E, i, h)))]
+    if weights == "int8":
+        stacks = [quant.quantize_tensor(w) for w in stacks]
+    # every token real: no mask at all, as a caller without padding
+    valid = None if real is None else (
+        jnp.arange(tokens)[None, :]
+        < jnp.asarray(real)[:, None]).reshape(N)
+    router = dict(router_score=score)
+    if score == "sigmoid":
+        router.update(router_bias=0.1 * jax.random.normal(ks[5], (E,)),
+                      routed_scale=1.8)
+    return x.astype(dtype), rw.astype(dtype), stacks, valid, k, router
+
+
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_grouped_path_matches_exact_and_reference(kernels_on, case):
+    """moe_mlp handed the whole stacks, a layer and the tokens a row
+    (the grouped path) against _moe_exact on that layer's slice and
+    against the float32 per-token reference; its work: the experts
+    that had a row, and the rows they multiplied in passes of
+    GROUPED_ROWS."""
+    x, rw, stacks, valid, k, router = _grouped_case(case)
+    rows, tokens, E = GROUPED_CASES[case][:3]
+    layer = 1
+    got, work = jax.jit(lambda x, *w: moe.moe_mlp(
+        x, rw, *w, top_k=k, valid=valid, layer=jnp.int32(layer),
+        positions=tokens, **router))(x, *stacks)
+    assert got.dtype == x.dtype and got.shape == x.shape
+
+    top_p, top_i = moe.route(
+        x, rw, k, score=router["router_score"],
+        bias=router.get("router_bias"),
+        scale=router.get("routed_scale", 1.0))
+    v = np.ones(len(x), bool) if valid is None else np.asarray(valid)
+    per_expert = np.bincount(np.asarray(top_i)[v].reshape(-1),
+                             minlength=E)
+    assert int(work.experts_read) == (per_expert > 0).sum()
+    R = moe.GROUPED_ROWS
+    assert int(work.expert_rows) == (-(-per_expert // R) * R).sum()
+    if case == "every-token-on-one-pair":
+        assert sorted(per_expert[per_expert > 0]) == [200, 200]
+    if case == "experts-with-no-row":
+        assert (per_expert[8:] == 0).all() and per_expert.sum() == 256
+
+    one = [jax.tree_util.tree_map(lambda a: a[layer], w) for w in stacks]
+    exact = moe._moe_exact(x, top_p * v[:, None], top_i, *one,
+                           jax.nn.silu)
+    ref = _reference_routed(x.astype(jnp.float32), top_p, top_i,
+                            *(_float32(w) for w in one), v)
+    got = np.asarray(got.astype(jnp.float32))
+    scale = max(np.abs(ref).max(), 1e-6)
+    loose = x.dtype == jnp.bfloat16
+    assert np.abs(got - np.asarray(exact.astype(jnp.float32))).max() \
+        <= (0.03 if loose else 1e-5) * scale
+    assert np.abs(got - ref).max() <= (0.02 if loose else 1e-5) * scale
+    assert (got[~v] == 0).all()
+
+
+def test_grouped_path_drops_nothing_where_the_dispatch_does(kernels_on):
+    """Every token on the same two experts, 200 rows each: the capacity
+    dispatch at capacity_factor 2.0 keeps 16 rows an expert (2 x 256 x
+    2 / 64) and drops the rest; the grouped path keeps the exact
+    path's sum for every token."""
+    x, rw, stacks, valid, k, router = _grouped_case(
+        "every-token-on-one-pair")
+    one = [jax.tree_util.tree_map(lambda a: a[1], w) for w in stacks]
+    dropped, _ = moe.moe_mlp(x, rw, *one, top_k=k, valid=valid,
+                             capacity_factor=2.0, exact=False)
+    grouped, _ = moe.moe_mlp(x, rw, *stacks, top_k=k, valid=valid,
+                             layer=jnp.int32(1), positions=256)
+    exact, _ = moe.moe_mlp(x, rw, *one, top_k=k, valid=valid, exact=True)
+    v = np.asarray(valid)
+
+    def rows_off(y):
+        return (np.abs(np.asarray(y.astype(jnp.float32))
+                       - np.asarray(exact.astype(jnp.float32))).max(-1)
+                > 0.05 * np.abs(np.asarray(exact, np.float32)).max())[v]
+    assert moe.capacity_for(256, 64, 2, 2.0) == 16
+    assert rows_off(dropped).sum() >= 200 - 16
+    assert rows_off(grouped).sum() == 0
+
+
+@pytest.mark.parametrize("what,rows,positions,widths,mesh,kernels,want", [
+    ("qwen one-row prefill, 256 tokens", 1, 256, QWEN, None, True, True),
+    ("qwen one-row prefill, 128 tokens", 1, 128, QWEN, None, True, True),
+    ("qwen chunk of 512", 1, 512, QWEN, None, True, True),
+    ("qwen 16-row burst", 16, 256, QWEN, None, True, True),
+    ("glm one-row prefill", 1, 256, (2048, 1536), None, True, True),
+    ("eight rows of 16 tokens", 8, 16, QWEN, None, True, True),
+    ("one row of 64 tokens", 1, 64, QWEN, None, True, True),
+    ("the smallest chunk bucket", 1, 16, QWEN, None, True, True),
+    ("speculative window", 4, 4, QWEN, None, True, False),
+    ("the widest speculative window", 16, 8, QWEN, None, True, False),
+    ("decode step, 16 rows", 16, 1, QWEN, None, True, False),
+    ("decode step, 128 rows", 128, 1, QWEN, None, True, False),
+    ("mixtral prefill", 1, 256, MIXTRAL, None, True, False),
+    ("ep mesh", 1, 256, QWEN, dict(dp=1, ep=2, tp=1), True, False),
+    ("tp mesh", 1, 256, QWEN, dict(dp=1, ep=1, tp=2), True, False),
+    ("mesh of one device", 1, 256, QWEN, dict(dp=1, ep=1, tp=1), True,
+     True),
+    ("kernels off (the CPU)", 1, 256, QWEN, None, False, False),
+])
+def test_grouped_path_rule(what, rows, positions, widths, mesh, kernels,
+                           want):
+    """As list_path's: the path follows from the rows and positions,
+    the experts' widths and the mesh; and never both rules at once."""
+    pallas_paged.set_flash_enabled(kernels)
+    try:
+        if mesh is not None:
+            mesh = build_mesh(MeshConfig(**mesh),
+                              devices=jax.devices()[:np.prod(
+                                  list(mesh.values()))])
+        shape = (rows, positions, *widths, jnp.int8, jnp.bfloat16, mesh)
+        assert moe.grouped_path(*shape) is want
+        assert not (want and moe.list_path(*shape))
+    finally:
+        pallas_paged.set_flash_enabled(None)
+
+
+@pytest.mark.parametrize("rows,positions,kernels,widths,want", [
+    (16, 1, True, QWEN, "list"), (1, 256, True, QWEN, "grouped"),
+    (16, 256, True, QWEN, "grouped"), (4, 4, True, QWEN, "exact"),
+    (128, 1, True, QWEN, "exact"), (16, 1, False, QWEN, "exact"),
+    # kernels off: a one-row chunk is covered by the capacity the full
+    # batch reckons (552 >= 256), the 16-row burst is not
+    (1, 256, False, QWEN, "exact"), (16, 256, False, QWEN, "dispatch"),
+    (16, 1, True, MIXTRAL, "exact"), (16, 256, True, MIXTRAL, "dispatch"),
+])
+def test_moe_path_names_the_strategy(rows, positions, kernels, widths,
+                                     want):
+    """moe_path, what engine/runner.py records per executable, for a
+    60-expert top-4 model at the cells' capacity (16 rows x the chunk,
+    factor 2.0)."""
+    pallas_paged.set_flash_enabled(kernels)
+    try:
+        assert moe.moe_path(rows, positions, 60, 4, *widths, jnp.int8,
+                            jnp.bfloat16, None, capacity_factor=2.0,
+                            capacity_tokens=16 * positions) == want
+    finally:
+        pallas_paged.set_flash_enabled(None)
+
+
+def test_forward_takes_the_grouped_path_in_place(kernels_on):
+    """llama.forward over a prefill chunk of a many-expert model (two
+    rows of 64 tokens, one right-padded): the logits of the grouped
+    path against those of the exact path (the kernels off, and a
+    capacity factor of E / k so that capacity covers every token), the
+    kernel's name in the program, and the work it reports against the
+    exact path's layers x experts x tokens."""
+    cfg = ModelConfig(name="t-moe16", vocab_size=128, hidden_size=128,
+                      intermediate_size=128, num_layers=2, num_heads=2,
+                      num_kv_heads=2, max_position_embeddings=64,
+                      num_experts=16, num_experts_per_tok=2,
+                      moe_capacity_factor=8.0, dtype=jnp.float32)
+    from production_stack_tpu.models import make_slot_cache
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    B, T = 2, 64
+    toks = (jnp.arange(B * T, dtype=jnp.int32).reshape(B, T) * 7) % 128
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    valid = jnp.arange(T)[None, :] < jnp.asarray([64, 40])[:, None]
+
+    def run():
+        cache, tables = make_slot_cache(
+            cfg.num_layers, B, 64, cfg.num_kv_heads, cfg.head_dim_,
+            dtype=jnp.float32)
+        return llama.forward(params, cfg, toks, pos, cache,
+                             block_tables=tables, token_valid=valid)
+
+    assert moe.grouped_path(B, T, 128, 128, jnp.float32, jnp.float32)
+    assert "moe_grouped_experts" in str(jax.make_jaxpr(run)())
+    logits, _, work = run()
+    pallas_paged.set_flash_enabled(False)
+    want, _, work_all = run()
+    assert int(work_all.experts_read) == cfg.num_layers * cfg.num_experts
+    assert int(work_all.expert_rows) == (cfg.num_layers * cfg.num_experts
+                                         * B * T)
+    assert int(work.experts_read) <= int(work_all.experts_read)
+    routed = cfg.num_layers * 104 * cfg.num_experts_per_tok
+    assert routed <= int(work.expert_rows) < int(work_all.expert_rows)
+    np.testing.assert_allclose(np.asarray(logits)[np.asarray(valid)],
+                               np.asarray(want)[np.asarray(valid)],
+                               atol=2e-3, rtol=2e-3)
 
 
 # ---------------------------------------------------------------------

@@ -131,7 +131,7 @@ class _Case:
         self.ref = self.dispatch(B)
 
     def _call(self, tokens, starts, lengths, slots):
-        ids, lps, tops = self.runner.prefill(
+        ids, lps, tops, _ = self.runner.prefill(
             tokens, starts, lengths, self.sampling,
             self.runner.engine_cfg.kv_bucket_for(S), topk=TOPK,
             slots=slots, **self.kw)

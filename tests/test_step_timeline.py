@@ -436,14 +436,15 @@ def test_no_second_is_starved_while_the_queue_holds_an_entry(turnover):
     assert end["starved_s"] >= joined["starved_s"]
 
 
-def _moe_totals_after_each(model: str, prompts) -> list:
-    """``totals`` of /debug/perf after each of ``prompts`` has been
-    answered by a fresh engine serving ``model``."""
+def _moe_totals_after_each(model: str, prompts, whole: bool = False,
+                           **geometry) -> list:
+    """``totals`` of /debug/perf (``whole``: all of it) after each of
+    ``prompts`` has been answered by a fresh engine serving ``model``."""
     from production_stack_tpu.engine.async_engine import AsyncLLMEngine
     from production_stack_tpu.engine.config import EngineConfig
-    eng = AsyncLLMEngine(EngineConfig(
+    eng = AsyncLLMEngine(EngineConfig(**{**dict(
         model=model, max_model_len=128, max_num_seqs=2,
-        prefill_chunk=16, prefill_buckets=(16,)))
+        prefill_chunk=16, prefill_buckets=(16,)), **geometry}))
 
     async def body(client):
         seen = []
@@ -453,7 +454,7 @@ def _moe_totals_after_each(model: str, prompts) -> list:
                 "ignore_eos": True, "prompt": prompt})
             assert r.status == 200
             perf = await (await client.get("/debug/perf")).json()
-            seen.append(perf["totals"])
+            seen.append(perf if whole else perf["totals"])
         return seen
     return _with_client(eng, body)
 
@@ -505,6 +506,77 @@ def test_debug_perf_expert_count_follows_the_list_path():
     assert steps_layers > 0
     # one live row (and a parked one): 1 to 2 experts a layer and step
     assert steps_layers <= moe["experts_read"] <= 2 * steps_layers
+
+
+def test_debug_perf_names_the_experts_path_of_every_executable():
+    """``device.moe_paths`` of a MoE engine: one entry an executable,
+    keyed as ``attention_paths`` is; on the CPU the kernels are off and
+    a chunk of 16 tokens is fewer than the dense threshold: exact."""
+    (perf,) = _moe_totals_after_each("debug-moe", ["which path"],
+                                     whole=True)
+    paths = perf["device"]["moe_paths"]
+    assert paths and set(paths) == set(perf["device"]["attention_paths"])
+    assert {k.split("|")[0] for k in paths} >= {"decode", "prefill"}
+    assert set(paths.values()) == {"exact"}
+
+
+def test_debug_perf_names_no_experts_path_for_a_dense_model(served):
+    _, perf, _ = served
+    assert perf["device"]["moe_paths"] == {}
+    assert perf["device"]["attention_paths"]
+    assert not {"expert_rows", "routed_rows"} & set(
+        perf["totals"]["prefill"])
+
+
+def test_debug_perf_counts_the_rows_a_prefills_experts_multiplied():
+    """``totals.prefill`` of a MoE engine: ``routed_rows`` is the real
+    tokens x top-k x layers, ``expert_rows`` what the experts
+    multiplied; on the exact path (the CPU) that is every expert over
+    every position computed, padding and all."""
+    first, second = _moe_totals_after_each(
+        "debug-moe", ["count my rows", "and these rows too"])
+    for totals in (first, second):
+        pre = totals["prefill"]
+        # debug-moe: 2 layers x 4 experts, top-2
+        assert pre["routed_rows"] == pre["real"] * 2 * 2
+        assert pre["expert_rows"] == (pre["real"] + pre["pad"]) * 4 * 2
+    assert second["prefill"]["routed_rows"] > first["prefill"]["routed_rows"]
+
+
+def test_debug_perf_follows_the_grouped_path():
+    """A model wide enough for the kernels (forced on, in interpret
+    mode) and a chunk bucket of 128 tokens: the prefill executable's
+    experts run grouped, the decode executable's walk the list, and
+    the experts multiplied passes of GROUPED_ROWS rows: at most one
+    pass more than the routed rows fill, for each expert and layer."""
+    import jax.numpy as jnp
+    from production_stack_tpu.models import config as model_configs
+    from production_stack_tpu.ops import moe, pallas_paged
+    name = "t-moe16-wide"
+    model_configs.PRESETS[name] = model_configs.ModelConfig(
+        name=name, vocab_size=512, hidden_size=128,
+        intermediate_size=128, num_layers=2, num_heads=2,
+        num_kv_heads=2, max_position_embeddings=256, num_experts=16,
+        num_experts_per_tok=2, dtype=jnp.float32)
+    pallas_paged.set_flash_enabled(True)
+    try:
+        (perf,) = _moe_totals_after_each(
+            name, ["group my rows by expert"], whole=True,
+            max_model_len=256, prefill_chunk=128, prefill_buckets=(128,))
+    finally:
+        pallas_paged.set_flash_enabled(None)
+        del model_configs.PRESETS[name]
+    paths = perf["device"]["moe_paths"]
+    by_kind = {}
+    for key, path in paths.items():
+        by_kind.setdefault(key.split("|")[0], set()).add(path)
+    assert by_kind == {"prefill": {"grouped"}, "decode": {"list"}}
+    pre = perf["totals"]["prefill"]
+    assert pre["routed_rows"] == pre["real"] * 2 * 2 > 0
+    R = moe.GROUPED_ROWS
+    assert pre["expert_rows"] % R == 0
+    assert R * 2 <= pre["expert_rows"] <= (
+        pre["routed_rows"] // R + 2 * 16) * R
 
 
 def test_debug_profile_captures_and_refuses_a_second(engine):
