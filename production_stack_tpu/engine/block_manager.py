@@ -40,7 +40,7 @@ class BlockManager:
     def __init__(self, num_blocks: int, block_size: int,
                  enable_prefix_caching: bool = False,
                  namespace: str = "", bytes_per_token: int = 0,
-                 layout: str = "kv_heads"):
+                 layout: str = "kv_heads", index_bytes_per_token: int = 0):
         if num_blocks < 2:
             raise ValueError("pool needs at least one non-trash block")
         self.num_blocks = num_blocks          # includes trash block 0
@@ -50,6 +50,11 @@ class BlockManager:
         # "kv_heads" | "latent"); 0 where no pool was described
         self.bytes_per_token = bytes_per_token
         self.layout = layout
+        # bytes_per_token's part that a second pool under the same
+        # tables takes ("latent+index": the sparse-attention indexer's
+        # keys): a block id names a block of both pools, so every
+        # count here (free, active, cached, reuse) is of both at once
+        self.index_bytes_per_token = index_bytes_per_token
         self.hasher = (ChunkHasher(block_size, namespace="blk|" + namespace)
                        if enable_prefix_caching else None)
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
@@ -124,6 +129,7 @@ class BlockManager:
         return {
             "num_blocks": self.num_blocks - 1,   # allocatable, no trash
             "bytes_per_token": self.bytes_per_token,
+            "index_bytes_per_token": self.index_bytes_per_token,
             "layout": self.layout,
             "free": self.free_blocks,
             "active": self.active_blocks,

@@ -293,11 +293,25 @@ class EngineConfig:
                 return b
         return self.kv_len_buckets[-1]
 
-    def prefill_rows_for(self, chunks: int) -> int:
+    # the widest chunk bucket a full-batch prefill dispatch is built
+    # for: the default prefill chunk, the widest a deployment ran one
+    # at before chunks of 1024 and more existed. The full batch is for
+    # a burst of short chunks, where it saves dispatches and weight
+    # reads; one row of a wider chunk holds the device for tens of
+    # milliseconds by itself, and max_num_seqs rows of it multiply the
+    # executable's activations, the experts' row buffers and the
+    # logits of every position by the batch for nothing. A rule of the
+    # chunk alone: whatever ran with chunks up to 512 tokens
+    # dispatches as it did, at any number of slots
+    FULL_BATCH_CHUNK_TOKENS = 512
+
+    def prefill_rows_for(self, chunks: int, bucket: int = 0) -> int:
         """Rows of the prefill dispatches that serve ``chunks`` chunks
-        due at once in one chunk bucket: 1 (a dispatch per chunk) up to
-        a quarter of the batch, max_num_seqs (one dispatch for all)
-        above. The ends of the decode batch buckets' range and nothing
+        due at once in one chunk bucket of ``bucket`` tokens: 1 (a
+        dispatch per chunk) up to a quarter of the batch, max_num_seqs
+        (one dispatch for all) above, unless the bucket is wider than
+        FULL_BATCH_CHUNK_TOKENS: then 1 whatever is due.
+        The ends of the decode batch buckets' range and nothing
         between: every row count is one more executable per (chunk
         bucket, kv bucket, variant) to warm or to compile mid-serving,
         and a one-row chunk of 128 tokens already costs a v5e more in
@@ -305,6 +319,7 @@ class EngineConfig:
         chunks due buy nothing and a few weight reads cost less than
         computing four times the rows."""
         return (1 if chunks <= max(1, self.max_num_seqs // 4)
+                or bucket > self.FULL_BATCH_CHUNK_TOKENS
                 else self.max_num_seqs)
 
     def batch_bucket_for(self, rows: int) -> int:

@@ -47,6 +47,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+import numpy as np
+
 # XLA compile durations (seconds): compiles are seconds-scale events,
 # not milliseconds — a distinct bucket ladder from PHASE_BUCKETS
 COMPILE_BUCKETS: Tuple[float, ...] = (
@@ -338,6 +340,11 @@ class EngineEffAccounting:
         # (note_expert_rows); in ``totals.prefill`` of a MoE engine
         self.prefill_expert_rows = 0
         self.prefill_routed_rows = 0
+        # learned sparse attention (note_sparse): over every query a
+        # decode step or a prefill chunk computed, the keys at or
+        # before it, those its indexer scored and those it attended;
+        # ``totals.sparse`` of an engine whose model selects
+        self.sparse: Dict[str, Dict[str, int]] = {}
         # modeled HBM traffic (decode windows only — see module doc)
         self.bytes_total = 0
         self.bytes_effective = 0
@@ -483,6 +490,28 @@ class EngineEffAccounting:
         with self._lock:
             self.prefill_expert_rows += expert_rows
             self.prefill_routed_rows += routed_rows
+
+    def note_sparse(self, kind: str, first, queries: int, topk: int,
+                    selects: bool) -> None:
+        """One dispatch of a model that selects what it attends
+        (ops/dsa.py): ``kind`` "decode" or "prefill"; for each row the
+        position ``first`` of its first query and ``queries``
+        consecutive ones. A query at position p has p + 1 keys in its
+        context; where the executable selects (its kv bucket holds
+        more than ``topk`` positions) all are scored and min(p + 1,
+        topk) attended, else none is scored and all are attended. Per
+        query of ONE layer: every layer does the same."""
+        ctx = (np.asarray(first, np.int64)[:, None] + 1
+               + np.arange(queries, dtype=np.int64)[None, :])
+        in_context = int(ctx.sum())
+        moved = {"queries": int(ctx.size), "keys_in_context": in_context,
+                 "keys_scored": in_context if selects else 0,
+                 "keys_attended": int(np.minimum(ctx, topk).sum())
+                 if selects else in_context}
+        with self._lock:
+            row = self.sparse.setdefault(kind, dict.fromkeys(moved, 0))
+            for key, n in moved.items():
+                row[key] += n
 
     # -- step timeline (engine thread only) ------------------------------
 
@@ -697,6 +726,13 @@ class EngineEffAccounting:
                                 sorted(self.prefill_by_rows.items())},
                             **moe_rows},
                 **moe,
+                **({"sparse": {
+                    **{key: sum(row[key] for row in self.sparse.values())
+                       for key in ("keys_in_context", "keys_scored",
+                                   "keys_attended")},
+                    **{kind: dict(row)
+                       for kind, row in self.sparse.items()}}}
+                   if self.sparse else {}),
                 "bytes_total": self.bytes_total,
                 "bytes_effective": self.bytes_effective,
                 "compiles_total": self.compiles_total,

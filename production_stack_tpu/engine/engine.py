@@ -232,7 +232,8 @@ class LLMEngine:
             namespace=model_fingerprint(self.model_cfg,
                                         engine_cfg.kv_dtype),
             bytes_per_token=self.runner.cache.bytes_per_token,
-            layout=self.runner.cache.layout)
+            layout=self.runner.cache.layout,
+            index_bytes_per_token=self.runner.cache.index_bytes_per_token)
         self._tables = np.zeros((engine_cfg.max_num_seqs,
                                  engine_cfg.max_blocks_per_seq), np.int32)
         self.scheduler.can_admit = self._try_admit
@@ -1156,7 +1157,7 @@ class LLMEngine:
                                  []).append(w)
         dispatches = []
         for bucket, due in sorted(by_bucket.items()):
-            rows = self.cfg.prefill_rows_for(len(due))
+            rows = self.cfg.prefill_rows_for(len(due), bucket)
             dispatches += [(bucket, rows, due[i:i + rows])
                            for i in range(0, len(due), rows)]
         if any(w.seq.options.shaped for w in works if w.is_last):
@@ -1211,6 +1212,13 @@ class LLMEngine:
                 bucket=bucket, batch=rows,
                 real_tokens=sum(len(w.chunk) for w in group),
                 drained=drained)
+            if self.model_cfg.index_topk:
+                # (a chunk's padding past its tokens is not counted)
+                for w in group:
+                    self.eff.note_sparse(
+                        "prefill", [w.start], len(w.chunk),
+                        self.model_cfg.index_topk,
+                        self.runner.selects(kv_len))
             entry = _Prefill(
                 group, devs, [w.seq.admit_time for w in group],
                 joined=[w.seq for w in group
@@ -1608,6 +1616,11 @@ class LLMEngine:
         plain = all(s.options.top_p >= 1.0 and not s.options.top_k
                     and not s.options.min_p
                     for s in decode_seqs)
+        if self.model_cfg.index_topk:
+            self.eff.note_sparse(
+                "decode", [s.next_position + joined.get(s.seq_id, ahead)
+                           for s in decode_seqs], W,
+                self.model_cfg.index_topk, self.runner.selects(kv_len))
         with self._phase("decode_dispatch", dispatches=True) as call:
             (ids_dev, lps_dev, counts_dev, tops_dev,
              experts_read_dev) = self.runner.decode(

@@ -47,7 +47,8 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.sampler import (SamplingParams,
                                                  adjust_logits, sample)
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.models.kv import (LATENT, KVCache, cache_for,
+from production_stack_tpu.models import kv as kv_pool
+from production_stack_tpu.models.kv import (KV_HEADS, KVCache, cache_for,
                                             latent_pool_width)
 from production_stack_tpu.models import llama
 from production_stack_tpu.ops import moe
@@ -134,8 +135,16 @@ class ModelRunner:
                 f"{model_cfg.name}: a latent-attention model (KV pool "
                 f"layout 'latent') runs on one chip only; mesh "
                 f"{dict(mesh.shape)} shards it (tp, ep, dp must be 1)")
-        # K and V per kv head, or the latent pool (models/kv.cache_for;
-        # it refuses an int8 latent pool by name)
+        if model_cfg.index_topk and engine_cfg.speculative_ngram_tokens:
+            raise ValueError(
+                f"{model_cfg.name}: speculative decoding "
+                f"(--speculative-ngram-tokens) is not supported on a "
+                f"model that selects what it attends (index_topk "
+                f"{model_cfg.index_topk}): the selection is built for "
+                f"one query position a row and for prefill chunks")
+        # K and V per kv head, or the latent pool, with the index pool
+        # beside it where the model selects what it attends
+        # (models/kv.cache_for; it refuses an int8 latent pool by name)
         self.cache: KVCache = cache_for(
             model_cfg, n_blocks, engine_cfg.kv_block_size, dtype=kv_dt)
         self._tables = jnp.zeros(
@@ -857,15 +866,26 @@ class ModelRunner:
             self._dec_counts = counts_out
         return ids, lps, None, (tis, tls) if topk else None, read
 
-    def _attention_path(self, positions: int, mesh) -> str:
+    def selects(self, kv_len: Optional[int]) -> bool:
+        """Does an executable of this kv bucket select what it attends
+        (models/kv.selects: the model has an indexer and the bucket
+        holds more positions than it keeps)?"""
+        return kv_pool.selects(kv_len, self.engine_cfg.max_blocks_per_seq,
+                               self.engine_cfg.kv_block_size,
+                               self.model_cfg.index_topk)
+
+    def _attention_path(self, positions: int, mesh,
+                        kv_len: Optional[int] = None) -> str:
         """ops/pallas_paged.attention_path for this model's head
-        geometry and this engine's block size."""
+        geometry, this engine's block size and, where the model selects
+        what it attends, the executable's kv bucket."""
         cfg = self.model_cfg
         if cfg.mla:     # every head on the one cached vector a token
             return attention_path(
                 positions, cfg.num_heads, latent_pool_width(cfg.latent_dim),
                 self.engine_cfg.kv_block_size, mesh,
-                value_dim=cfg.kv_lora_rank)
+                value_dim=cfg.kv_lora_rank,
+                selects=kv_len is not None and self.selects(kv_len))
         return attention_path(
             positions, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
             self.engine_cfg.kv_block_size, mesh)
@@ -877,7 +897,7 @@ class ModelRunner:
         cfg = self.model_cfg
         experts = self.params["layers"]["gate"]
         return moe.moe_path(
-            rows, positions, cfg.num_experts, cfg.num_experts_per_tok,
+            rows, positions, cfg.router_experts_, cfg.num_experts_per_tok,
             cfg.hidden_size,
             cfg.moe_intermediate_size or cfg.intermediate_size,
             moe.stored_dtype(experts), cfg.dtype, self.mesh,
@@ -917,7 +937,7 @@ class ModelRunner:
         fn = cache.get(key)
         if fn is not None:
             return fn
-        path = self._attention_path(positions, self.mesh)
+        path = self._attention_path(positions, self.mesh, kv_len)
         logger.info("%s executable (batch=%d window=%d kv=%d): "
                     "attention path %s", kind, batch, window, kv_len,
                     path)
@@ -963,6 +983,24 @@ class ModelRunner:
         R, Tb = tokens.shape
         guided = guide_table is not None
         B = self.engine_cfg.max_num_seqs
+        if R > 1 and self.engine_cfg.prefill_rows_for(R, Tb) == 1:
+            # more rows than a dispatch of this chunk bucket takes
+            # (cfg.FULL_BATCH_CHUNK_TOKENS; the engine never asks, a
+            # launcher that warms max_num_seqs rows of every shape
+            # does): served a row at a time, by the one-row executable
+            if slots is None:
+                slots = np.arange(R, dtype=np.int32)
+            outs = [self.prefill(
+                tokens[r:r + 1], starts[r:r + 1], lengths[r:r + 1],
+                sampling, kv_len, guide_table=guide_table,
+                guide_ids=guide_ids, guide_states=guide_states,
+                penalized=penalized, topk=topk, slots=slots[r:r + 1])
+                for r in range(R)]
+            ids, lps, tops, rows = zip(*outs)
+            return (jnp.concatenate(ids), jnp.concatenate(lps),
+                    tuple(map(jnp.concatenate, zip(*tops))) if topk
+                    else None,
+                    None if rows[0] is None else sum(rows))
         gshape = guide_table.shape if guided else None
         key = (R, Tb, kv_len, guided, gshape, penalized, topk)
         if R > 1 and key not in self._prefill_fns:
@@ -1101,11 +1139,12 @@ class ModelRunner:
     def _refuse_latent(self, what: str) -> None:
         """KV chunks on the wire are K and V per kv head
         [L, size, Hkv, D]; the latent pool holds neither."""
-        if self.cache.layout == LATENT:
+        if self.cache.layout != KV_HEADS:
             raise ValueError(
                 f"{what}: the KV pool of {self.model_cfg.name} has the "
-                f"layout 'latent' (one [c | k_rope] vector a token), "
-                f"which KV chunks [L, size, Hkv, D] cannot carry")
+                f"layout {self.cache.layout!r} (one [c | k_rope] vector "
+                f"a token), which KV chunks [L, size, Hkv, D] cannot "
+                f"carry")
 
     def extract_chunk(self, slot: int, start: int, size: int):
         """Gather [L, size, Hkv, D] k/v out of a slot's blocks (no

@@ -120,6 +120,22 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # learned sparse attention over the latent pool (GLM-5,
+    # ``glm_moe_dsa``; DeepSeek-V3.2's indexer): index_topk > 0 turns it
+    # on. Per token ONE index key of index_head_dim values is cached in
+    # a pool of its own beside the latent pool (models/kv.py); a query
+    # scores every earlier position with index_n_heads index queries
+    # and attends the index_topk best (ops/dsa.py)
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # the chip's share of a deployment that divides each expert layer
+    # over several chips: the router scores router_experts experts
+    # (0: num_experts, nothing divided) and this chip holds num_experts
+    # of them, from expert_offset on; it adds only what its own experts
+    # give for the tokens routed to them (ops/moe.moe_mlp)
+    router_experts: int = 0
+    expert_offset: int = 0
     dtype: Any = jnp.bfloat16
 
     @property
@@ -143,16 +159,24 @@ class ModelConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
+    def router_experts_(self) -> int:
+        """The router's outputs: every expert of the layer, of which
+        num_experts are held here."""
+        return self.router_experts or self.num_experts
+
+    @property
     def num_params(self) -> int:
+        """Parameters HELD here: the experts of this chip's share, the
+        vocabulary's rows as sliced, the indexer."""
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd, nh = self.head_dim_, self.num_heads
         E = self.num_experts
         dense = 3 * h * i
         if E:
             mi = self.moe_intermediate_size or i
-            mlp = 3 * h * mi * E + h * E
+            mlp = 3 * h * mi * E + h * self.router_experts_
             if self.router_bias:
-                mlp += E
+                mlp += self.router_experts_
             if self.shared_expert_size:
                 mlp += (3 * h * self.shared_expert_size
                         + (h if self.shared_expert_gate else 0))
@@ -167,6 +191,10 @@ class ModelConfig:
                     + kr * nh * (self.qk_nope_head_dim
                                  + self.v_head_dim)          # kv_b
                     + nh * self.v_head_dim * h)              # o
+            if self.index_topk:
+                hi, di = self.index_n_heads, self.index_head_dim
+                attn += (qr * hi * di + h * di + 2 * di      # q, k, norm
+                         + h * hi)                           # head weights
         else:
             attn = (h * (nh * hd)                        # q
                     + 2 * h * (self.num_kv_heads * hd)   # k, v
@@ -185,8 +213,9 @@ class ModelConfig:
         Families: Llama-2/3, TinyLlama, Mistral (the baseline), Qwen2
         (adds q/k/v biases), Gemma (GeGLU via gelu, scaled embeddings,
         unit-offset RMSNorm, tied embeddings), Gemma-2, Mixtral,
-        Qwen2-MoE, and GLM-4.7-Flash (``glm4_moe_lite``:
-        _glm4_moe_lite). Keys the mapping does not know are ignored.
+        Qwen2-MoE, GLM-4.7-Flash (``glm4_moe_lite``) and GLM-5
+        (``glm_moe_dsa``), both through _glm4_moe_lite. Keys the mapping
+        does not know are ignored.
         """
         archs = cfg.get("architectures") or []
         arch = archs[0] if archs else ""
@@ -202,8 +231,9 @@ class ModelConfig:
                       or arch == "MixtralForCausalLM")
         is_qwen2_moe = (model_type == "qwen2_moe"
                         or arch == "Qwen2MoeForCausalLM")
-        is_glm_lite = (model_type == "glm4_moe_lite"
-                       or arch == "Glm4MoeLiteForCausalLM")
+        is_glm_lite = (model_type in ("glm4_moe_lite", "glm_moe_dsa")
+                       or arch in ("Glm4MoeLiteForCausalLM",
+                                   "GlmMoeDsaForCausalLM"))
         is_llama_like = (model_type in ("llama", "mistral") or arch in
                          ("LlamaForCausalLM", "MistralForCausalLM"))
         if not (is_qwen2 or is_gemma or is_gemma2 or is_mixtral
@@ -213,7 +243,7 @@ class ModelConfig:
                 f"unsupported model family (model_type={model_type!r}, "
                 f"architecture={arch!r}); supported: llama, mistral, "
                 f"qwen2, gemma, gemma2, mixtral, qwen2_moe, "
-                f"glm4_moe_lite")
+                f"glm4_moe_lite, glm_moe_dsa")
         if is_glm_lite:
             return _glm4_moe_lite(cfg, name, dtype)
         if is_qwen2_moe:
@@ -282,29 +312,58 @@ class ModelConfig:
 
 def _glm4_moe_lite(cfg: Dict[str, Any], name: str,
                    dtype: Any) -> ModelConfig:
-    """GLM-4.7-Flash (``glm4_moe_lite``): latent attention, leading
-    dense layers, a sigmoid router with a selection bias and a routing
-    scale, ungated shared experts. What the tree does not build is
-    refused by name; the multi-token-prediction block
+    """GLM-4.7-Flash (``glm4_moe_lite``) and GLM-5 (``glm_moe_dsa``):
+    latent attention, leading dense layers, a sigmoid router with a
+    selection bias and a routing scale, ungated shared experts; GLM-5
+    adds the sparse-attention indexer (``index_n_heads``,
+    ``index_head_dim``, ``index_topk``: optional keys). What the tree
+    does not build is refused by name; the multi-token-prediction block
     (``num_nextn_predict_layers``) is not built and not served, as HF's
-    own model class drops those weights on load."""
+    own model class drops those weights on load.
+
+    A file may state the chip's share of a deployment that divides each
+    expert layer over several chips (``deployment``: {"router_experts":
+    the published n_routed_experts, "chips_per_layer", "chip_index"}):
+    ``n_routed_experts`` is then the experts held here, which are those
+    from chip_index x n_routed_experts on, and the router keeps its
+    published width."""
+    family = cfg.get("model_type", "glm4_moe_lite")
     if cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
         raise ValueError(
-            "glm4_moe_lite with grouped routing (n_group / topk_group "
-            "!= 1) is not supported")
+            f"{family} with grouped routing (n_group / topk_group "
+            f"!= 1) is not supported")
     if cfg.get("topk_method", "noaux_tc") != "noaux_tc":
-        raise ValueError(f"glm4_moe_lite topk_method "
+        raise ValueError(f"{family} topk_method "
                          f"{cfg['topk_method']!r} is not supported "
                          f"(supported: noaux_tc)")
     if cfg.get("partial_rotary_factor", 1) != 1:
-        raise ValueError("glm4_moe_lite with partial_rotary_factor != 1 "
-                         "is not supported")
+        raise ValueError(f"{family} with partial_rotary_factor != 1 "
+                         f"is not supported")
     if not cfg.get("q_lora_rank"):
-        raise ValueError("glm4_moe_lite without q_lora_rank (full-rank "
-                         "queries) is not supported")
+        raise ValueError(f"{family} without q_lora_rank (full-rank "
+                         f"queries) is not supported")
     if cfg.get("attention_bias"):
-        raise ValueError("glm4_moe_lite with attention_bias is not "
-                         "supported")
+        raise ValueError(f"{family} with attention_bias is not "
+                         f"supported")
+    held = cfg["n_routed_experts"]
+    deployment = cfg.get("deployment") or {}
+    router_experts = deployment.get("router_experts", held)
+    chips = deployment.get("chips_per_layer", 1)
+    chip = deployment.get("chip_index", 0)
+    if held * chips != router_experts or not 0 <= chip < chips:
+        raise ValueError(
+            f"{family}: a deployment of {chips} chips a layer, each "
+            f"holding {held} experts, does not make the router's "
+            f"{router_experts} (chip_index {chip})")
+    topk = cfg.get("index_topk", 0)
+    if topk and not (cfg.get("index_n_heads") and cfg.get("index_head_dim")):
+        raise ValueError(f"{family}: index_topk without index_n_heads "
+                         f"and index_head_dim")
+    if topk and cfg.get("index_head_dim") < cfg["qk_rope_head_dim"]:
+        raise ValueError(f"{family}: index_head_dim below "
+                         f"qk_rope_head_dim is not supported")
+    # GLM-5 keeps the rotary base inside rope_parameters
+    rope_params = cfg.get("rope_parameters") or {}
     return ModelConfig(
         name=name or cfg.get("_name_or_path", "hf-model"),
         vocab_size=cfg["vocab_size"],
@@ -315,12 +374,20 @@ def _glm4_moe_lite(cfg: Dict[str, Any], name: str,
         # one latent serves every head: the cache has one "kv head"
         num_kv_heads=1,
         head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
-        rope_theta=cfg.get("rope_theta", 10000.0),
+        rope_theta=cfg.get("rope_theta",
+                           rope_params.get("rope_theta", 10000.0)),
         rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
         max_position_embeddings=cfg.get("max_position_embeddings", 4096),
-        rope_scaling=_rope_scaling_spec(cfg.get("rope_scaling")),
+        rope_scaling=_rope_scaling_spec(
+            cfg.get("rope_scaling")
+            or (rope_params if "rope_type" in rope_params else None)),
         tie_word_embeddings=cfg.get("tie_word_embeddings", False),
-        num_experts=cfg["n_routed_experts"],
+        num_experts=held,
+        router_experts=router_experts if chips > 1 else 0,
+        expert_offset=chip * held,
+        index_n_heads=cfg.get("index_n_heads", 0) if topk else 0,
+        index_head_dim=cfg.get("index_head_dim", 0) if topk else 0,
+        index_topk=topk,
         num_experts_per_tok=cfg["num_experts_per_tok"],
         norm_topk_prob=cfg.get("norm_topk_prob", True),
         moe_intermediate_size=cfg["moe_intermediate_size"],
@@ -451,6 +518,23 @@ PRESETS: Dict[str, ModelConfig] = {
         routed_scaling_factor=1.8, shared_expert_gate=False,
         first_dense_layers=1, q_lora_rank=64, kv_lora_rank=128,
         qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+    ),
+    # Tiny GLM-5-style model for CPU tests (``glm_moe_dsa``): debug-mla
+    # with the sparse-attention indexer (4 index heads of 32, the 16
+    # best positions) and one of two chips' share of the experts: 4 of
+    # the router's 8, from expert 4 on
+    "debug-dsa": ModelConfig(
+        name="debug-dsa", vocab_size=512, hidden_size=128,
+        intermediate_size=256, num_layers=3, num_heads=4, num_kv_heads=1,
+        head_dim=48, max_position_embeddings=512, num_experts=4,
+        num_experts_per_tok=2, moe_intermediate_size=128,
+        shared_expert_size=128, moe_naming="glm4_moe_lite",
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=2.5, shared_expert_gate=False,
+        first_dense_layers=1, q_lora_rank=64, kv_lora_rank=128,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+        index_n_heads=4, index_head_dim=32, index_topk=16,
+        router_experts=8, expert_offset=4,
     ),
     # GLM-4.7-Flash (glm4_moe_lite, 30B-A3B): latent attention, one
     # leading dense layer of width 10240, 64 sigmoid-routed experts

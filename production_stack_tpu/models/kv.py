@@ -73,6 +73,18 @@ TPU-first invariants:
   assumes K and V per head (the int8 pool, tp meshes, the KV
   connector's chunks) refuses it by name.
 
+- **The index pool** (learned sparse attention over the latent pool:
+  GLM-5, ops/dsa.py). A second kind of per-token state under the SAME
+  block tables: per token and layer one index key of
+  ``index_head_dim`` values, ``idx [L, N, 1, Bs, Di]``, beside the
+  latents (``KVCache.layout`` "latent+index"). A block id names a block
+  of both pools, so admission, preemption, reuse and prefix sharing
+  count one block and move both; the step programs carry both buffers
+  and append to both in place (``append_chunk``). ``attend_selected``
+  reads it: a query scores every live position against the index keys,
+  keeps the ``index_topk`` best and attends those positions' latents
+  alone.
+
 The reference stack's KV management is configuration around LMCache env
 vars (reference: helm/templates/deployment-vllm-multi.yaml:154-178) and
 its engine's paged KV lives inside vLLM (the stack passes
@@ -82,13 +94,15 @@ is the TPU-native equivalent of that engine layer.
 
 from typing import NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
-from production_stack_tpu.ops import pallas_paged
+from production_stack_tpu.ops import dsa, pallas_paged
 from production_stack_tpu.ops.attention import attention_with_cache
 
 
 LATENT = "latent"        # KVCache.layout: [c | k_rope], no v
+LATENT_INDEX = "latent+index"   # and the indexer's key in its own pool
 KV_HEADS = "kv_heads"     # separate K and V per kv head
 
 
@@ -100,6 +114,9 @@ class KVCache(NamedTuple):
     # scale. None = full-precision cache.
     ks: Optional[jnp.ndarray] = None  # [L, N, Hkv, Bs] f32
     vs: Optional[jnp.ndarray] = None
+    # learned sparse attention only: the index pool [L, N, 1, Bs, Di]
+    # beside the latent pool, under the same block tables
+    idx: Optional[jnp.ndarray] = None
 
     @property
     def num_blocks(self) -> int:
@@ -115,20 +132,40 @@ class KVCache(NamedTuple):
 
     @property
     def layout(self) -> str:
+        if self.idx is not None:
+            return LATENT_INDEX
         return LATENT if self.v is None else KV_HEADS
 
     @property
     def bytes_per_token(self) -> int:
-        """Bytes one token takes in the pool, all layers, as allocated
-        (payload and, int8, scales)."""
+        """Bytes one token takes in the pools, all layers, as allocated
+        (payload and, int8, scales; the index pool's keys too)."""
         tokens = self.num_blocks * self.block_size
         return sum(a.dtype.itemsize * (a.size // tokens)
                    for a in self if a is not None)
 
+    @property
+    def index_bytes_per_token(self) -> int:
+        """bytes_per_token's part that is the index pool's (0: none)."""
+        if self.idx is None:
+            return 0
+        return self.idx.dtype.itemsize * (
+            self.idx.size // (self.num_blocks * self.block_size))
+
+    def carried(self) -> "Pool":
+        """The arrays a step program's layer loop carries: those that
+        are there, in the fields' order."""
+        return tuple(a for a in self if a is not None)
+
+    def carried_back(self, pool: "Pool") -> "KVCache":
+        """``carried`` undone: the same fields, the loop's arrays."""
+        names = [n for n, a in zip(self._fields, self) if a is not None]
+        return KVCache(**dict(zip(names, pool)))
+
 
 # the pool as a step program's layer loop carries it: a KVCache's
-# arrays without the Nones — (k, v), int8 (k, v, ks, vs), or the
-# latent pool's one array (k,)
+# arrays without the Nones — (k, v), int8 (k, v, ks, vs), the latent
+# pool's one array (k,), or the latent and the index pool (k, idx)
 Pool = Tuple[jnp.ndarray, ...]
 
 
@@ -181,10 +218,16 @@ def cache_for(cfg, num_blocks: int, block_size: int,
               dtype=jnp.bfloat16) -> KVCache:
     """The pool a model's layers append to and attend over
     (cfg: models/config.ModelConfig): the latent pool for latent
-    attention, K and V per kv head for everything else."""
+    attention (with the index pool beside it where the model selects
+    what it attends), K and V per kv head for everything else."""
     if cfg.mla:
-        return make_latent_cache(cfg.num_layers, num_blocks, block_size,
-                                 cfg.latent_dim, dtype)
+        cache = make_latent_cache(cfg.num_layers, num_blocks, block_size,
+                                  cfg.latent_dim, dtype)
+        if cfg.index_topk:
+            cache = cache._replace(idx=jnp.zeros(
+                (cfg.num_layers, num_blocks, 1, block_size,
+                 cfg.index_head_dim), dtype))
+        return cache
     return make_cache(cfg.num_layers, num_blocks, block_size,
                       cfg.num_kv_heads, cfg.head_dim_, dtype)
 
@@ -426,7 +469,8 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
            starts: jnp.ndarray, positions: jnp.ndarray,
            kv_len: Optional[int], layer, *, window: Optional[int],
            scale: float, softcap: Optional[float],
-           mesh=None, value_dim: int = 0) -> jnp.ndarray:
+           mesh=None, value_dim: int = 0,
+           select: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """One layer's read: q [B,T,H,D] at ``positions`` [B,T] (contiguous
     from starts [B]) over layer ``layer`` of the pool, which already
     holds the chunk's own K/V (append, then attend) -> [B,T,H,D].
@@ -447,7 +491,12 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
     The latent pool (one array; value_dim = kv_lora_rank, static): q
     [B,T,H,W] are the ABSORBED queries ``[q_lat | q_rope]``, every head
     on the one cached vector a token, and the values are the first
-    value_dim columns of the keys -> [B,T,H,value_dim]."""
+    value_dim columns of the keys -> [B,T,H,value_dim].
+
+    select [B, T, nb*Bs] of 0 / 1 (the latent pool;
+    ``attend_selected``): a query attends only the positions marked
+    for it, a mask inside the kernel (the decode kernel's for one
+    position a row, else the prefill kernel's)."""
     k_cache, v_cache, *scales = pool if len(pool) > 1 else (pool[0], None)
     Bs, MB = k_cache.shape[-2], tables.shape[1]
     nb = MB if kv_len is None else min(-(-kv_len // Bs), MB)
@@ -460,13 +509,20 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
                   softcap=softcap or 0.0, layer=layer)
         if v_cache is None:
             kw.update(value_dim=value_dim)
+        if select is not None:
+            kw.update(select=select)
         if scales:
             kw.update(k_scales=scales[0], v_scales=scales[1])
         if mesh is not None:
             return pallas_paged.paged_attention_sharded(
                 q, k_cache, v_cache, tables, starts, mesh, **kw)
-        paged_fn = (pallas_paged.paged_decode_attention
-                    if path.startswith("pallas_paged_decode")
+        # a mask of selected positions: the decode kernel takes one
+        # query position a row, anything wider is the prefill kernel's
+        decode = (path.startswith("pallas_paged_decode")
+                  and (select is None or T == 1))
+        if decode and select is not None:
+            kw.update(select=select[:, 0])
+        paged_fn = (pallas_paged.paged_decode_attention if decode
                     else pallas_paged.paged_attention)
         return paged_fn(q, k_cache, v_cache, tables, starts, **kw)
     if scales:
@@ -480,4 +536,63 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
                  else gather_view(v_cache, tables, nb, layer=layer))
     return attention_with_cache(q, k_att, v_att, positions, scale=scale,
                                 sliding_window=window,
-                                logit_softcap=softcap)
+                                logit_softcap=softcap, select=select)
+
+
+def selects(kv_len: Optional[int], max_blocks: int, block_size: int,
+            topk: int) -> bool:
+    """Does a forward that reads the first ceil(kv_len/Bs) blocks of a
+    row (all ``max_blocks`` of its table where kv_len is None) select
+    what it attends? Only where those hold more than ``topk``
+    positions: under that every live position is among the topk best,
+    and the layer is plain latent attention (its index keys are written
+    all the same: a longer context will score them). Static, like the
+    kv bucket: engine/runner.py names the executable's attention path
+    by the same rule."""
+    nb = max_blocks if kv_len is None else min(-(-kv_len // block_size),
+                                               max_blocks)
+    return bool(topk) and nb * block_size > topk
+
+
+def attend_selected(q: jnp.ndarray, latents: jnp.ndarray,
+                    index: jnp.ndarray, iq: jnp.ndarray, iw: jnp.ndarray,
+                    tables: jnp.ndarray, starts: jnp.ndarray,
+                    positions: jnp.ndarray, kv_len: Optional[int], layer,
+                    *, topk: int, scale: float, value_dim: int,
+                    mesh=None) -> jnp.ndarray:
+    """``attend`` over the latent pool for a layer that selects what it
+    attends (ops/dsa.py): q [B,T,H,W] the absorbed queries, latents
+    [L,N,1,Bs,W] and index [L,N,1,Bs,Di] the two pools (both already
+    hold the chunk's own tokens), iq [B,T,Hi,Di] the index queries, iw
+    [B,T,Hi] fp32 their weights with the scales folded in.
+
+    Three stages, each under its scope. ``dsa_indexer``: the row's
+    index keys gathered through the tables (a sixth of the latents'
+    bytes) and every query scored against them. ``dsa_select``: the
+    ``topk`` best positions at or before each query, ties to the lower
+    position, as a mask. ``sparse_attention``: the mask goes to the
+    paged kernel of the forward's kind, the decode kernel for one
+    position a row and the prefill kernel for a chunk; either reads
+    every live block and attends the marked positions alone (the same
+    sum as over the selected set; on the v5e fetching 2048 selected
+    latents row by row ran at a ninth of the bandwidth at which the
+    kernel streams live blocks: PERF.md, PR 40). Where the kernels are
+    off, the gathered view and the masked jax.numpy attention."""
+    B, T = q.shape[:2]
+    Bs, MB = latents.shape[-2], tables.shape[1]
+    nb = MB if kv_len is None else min(-(-kv_len // Bs), MB)
+    S = nb * Bs
+    with jax.named_scope("dsa_indexer"):
+        keys = gather_view(index, tables, nb, layer=layer)[:, :, 0, :]
+        scores = dsa.index_scores(iq, iw, keys)              # [B,T,S]
+    with jax.named_scope("dsa_select"):
+        mask = dsa.select(scores.reshape(B * T, S),
+                          positions.reshape(B * T), topk,
+                          dtype=q.dtype).reshape(B, T, S)
+        if dsa.tap is not None:
+            jax.debug.callback(dsa.tap, layer, positions, mask,
+                               ordered=True)
+    with jax.named_scope("sparse_attention"):
+        return attend(q, (latents,), tables, starts, positions, kv_len,
+                      layer, window=None, scale=scale, softcap=None,
+                      mesh=mesh, value_dim=value_dim, select=mask)

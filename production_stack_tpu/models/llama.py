@@ -155,10 +155,27 @@ def _init_params_mla(cfg: ModelConfig, key: jax.Array, w) -> Params:
     E, mi = cfg.num_experts, cfg.moe_intermediate_size
     Ld = cfg.first_dense_layers
     Le = cfg.num_layers - Ld
-    keys = iter(jax.random.split(key, 32))
+    # (a model without the indexer draws from the 32 it always did)
+    keys = iter(jax.random.split(key, 48 if cfg.index_topk else 32))
+
+    def indexer(L, group):
+        # learned sparse attention (ops/dsa.py): index queries from the
+        # query bottleneck, one index key a token from the layer's
+        # input through a LayerNorm, a weight a head from the input
+        if not cfg.index_topk:
+            return {}
+        hi, di = cfg.index_n_heads, cfg.index_head_dim
+        return {
+            "idx_q": w(next(keys), (L, qr, hi * di), group, "idx_q"),
+            "idx_k": w(next(keys), (L, h, di), group, "idx_k"),
+            "idx_k_norm": jnp.ones((L, di), cfg.dtype),
+            "idx_k_norm_bias": jnp.zeros((L, di), cfg.dtype),
+            "idx_w": w(next(keys), (L, h, hi), group, "idx_w"),
+        }
 
     def attention(L, group):
         return {
+            **indexer(L, group),
             "attn_norm": jnp.ones((L, h), cfg.dtype),
             "q_a": w(next(keys), (L, h, qr), group, "q_a"),
             "q_a_norm": jnp.ones((L, qr), cfg.dtype),
@@ -193,11 +210,14 @@ def _init_params_mla(cfg: ModelConfig, key: jax.Array, w) -> Params:
         "up": w(next(keys), (Le, E, h, mi), "layers", "up"),
         "down": w(next(keys), (Le, E, mi, h), "layers", "down",
                   std=cfg.routed_down_init_std or 0.02),
-        "router": w(next(keys), (Le, h, E), "layers", "router"),
+        # the router scores every expert of the layer, whichever are
+        # held here (cfg.router_experts_)
+        "router": w(next(keys), (Le, h, cfg.router_experts_), "layers",
+                    "router"),
     }
     if cfg.router_bias:
         layers["router_bias"] = 0.1 * jax.random.normal(
-            next(keys), (Le, E), jnp.float32)
+            next(keys), (Le, cfg.router_experts_), jnp.float32)
     if cfg.shared_expert_size:
         si = cfg.shared_expert_size
         layers.update({
@@ -229,7 +249,16 @@ def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
     those two products are q_nope's channels and o's: they multiply
     there and nothing is requantised. Without a pool (encode) it is
     the EXPANDED form, [k_nope | v] = c W_kvb per head: the two forms
-    are what tests/test_mla.py compares."""
+    are what tests/test_mla.py compares.
+
+    With an indexer (cfg.index_topk, GLM-5; ops/dsa.py) the pool is two
+    arrays, the latents and the index keys: the layer also makes the
+    index queries qI = c_q W_qI (per index head, the leading rope part
+    rotated), ONE index key kI = LayerNorm(x W_kI) a token (rotated
+    alike) and the heads' weights x W_w, appends kI to the index pool
+    at the token's place, and attends only the index_topk positions
+    the index scores rank best (models/kv.attend_selected) wherever
+    the kv bucket holds more than that (models/kv.selects)."""
     B, T, _ = hidden.shape
     nh, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -248,6 +277,34 @@ def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
         q_rope = apply_rope(q_rope, positions, cos, sin)
         k_rope = apply_rope(k_rope, positions, cos, sin)
     scale = (dn + dr) ** -0.5
+    indexed = bool(cfg.index_topk)
+    if indexed and kv is None and T > cfg.index_topk:
+        raise ValueError(
+            f"{cfg.name}: a forward without a KV pool (encode, "
+            f"forward_train) over {T} positions, more than index_topk "
+            f"{cfg.index_topk}, is not supported: the selection is "
+            f"built on the index pool")
+    # the index key is cached at every kv bucket; the index queries and
+    # the heads' weights are made only where the bucket selects
+    selecting = indexed and kv is not None and kv_pool.selects(
+        kv_len, block_tables.shape[1], kv[0].shape[-2], cfg.index_topk)
+    if indexed and kv is not None:
+        with jax.named_scope("dsa_indexer"):
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            ik = _layer_norm(quant.dequant_matmul(hidden, lp["idx_k"]),
+                             lp["idx_k_norm"], lp["idx_k_norm_bias"])
+            ik = jnp.concatenate(
+                [apply_rope(ik[:, :, None, :dr], positions, cos, sin),
+                 ik[:, :, None, dr:]], axis=-1)           # [B,T,1,di]
+            if selecting:
+                iq = quant.dequant_matmul(c_q, lp["idx_q"]).reshape(
+                    B, T, hi, di)
+                iq = jnp.concatenate(
+                    [apply_rope(iq[..., :dr], positions, cos, sin),
+                     iq[..., dr:]], axis=-1)
+                iw = (jnp.einsum("bth,hj->btj", hidden, lp["idx_w"],
+                                 preferred_element_type=jnp.float32)
+                      * (hi ** -0.5 * di ** -0.5))
     kv_b = lp["kv_b"]
     quantized = quant.is_quantized(kv_b)
     w_kvb = (kv_b["w8"] if quantized else kv_b).reshape(r, nh, dn + dv)
@@ -280,13 +337,23 @@ def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
                                    parts[0].dtype))
         return jnp.concatenate(parts, axis=-1)
     with jax.named_scope("kv_write"):
-        kv = kv_pool.append(kv, padded([c[:, :, None, :], k_rope]), None,
-                            block_tables, starts, token_valid, layer)
-    with jax.named_scope("attention"):
-        ctx = kv_pool.attend(
-            padded([q_lat, q_rope]), kv, block_tables, starts, positions,
-            kv_len, layer, window=None, scale=scale, softcap=None,
-            mesh=mesh, value_dim=r)                       # [B,T,nh,r]
+        latents = kv_pool.append(
+            kv[:1], padded([c[:, :, None, :], k_rope]), None,
+            block_tables, starts, token_valid, layer)
+        kv = latents if not indexed else latents + (
+            kv_pool.append_chunk(kv[1], ik, block_tables, starts,
+                                 token_valid, layer),)
+    if selecting:
+        ctx = kv_pool.attend_selected(
+            padded([q_lat, q_rope]), kv[0], kv[1], iq, iw, block_tables,
+            starts, positions, kv_len, layer, topk=cfg.index_topk,
+            scale=scale, value_dim=r, mesh=mesh)
+    else:
+        with jax.named_scope("attention"):
+            ctx = kv_pool.attend(
+                padded([q_lat, q_rope]), latents, block_tables, starts,
+                positions, kv_len, layer, window=None, scale=scale,
+                softcap=None, mesh=mesh, value_dim=r)     # [B,T,nh,r]
     with jax.named_scope("mla_absorb_o"):
         attn = jnp.einsum("bthr,rhd->bthd", ctx,
                           w_kvb[..., dn:].astype(ctx.dtype))
@@ -457,7 +524,8 @@ def _mlp_block(cfg: ModelConfig, x, lp: Params, kv, token_valid,
             layer=moe_layer, positions=T,
             router_score=cfg.router_score,
             router_bias=lp.get("router_bias"),
-            routed_scale=cfg.routed_scaling_factor)
+            routed_scale=cfg.routed_scaling_factor,
+            expert_offset=cfg.expert_offset)
         if cfg.shared_expert_size:
             # an always-on shared expert: Qwen2-MoE's behind a
             # per-token sigmoid gate, GLM-4.7-Flash's with none
@@ -482,6 +550,16 @@ def _mlp_block(cfg: ModelConfig, x, lp: Params, kv, token_valid,
                                    cfg.rms_norm_eps, offset=offset)
             x = x + mlp_out
     return x, kv, work
+
+
+def _layer_norm(x: jnp.ndarray, weight, bias,
+                eps: float = 1e-6) -> jnp.ndarray:
+    """LayerNorm over the last axis, in float32 (the indexer's key)."""
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
 
 
 def _gelu_tanh(x: jnp.ndarray) -> jnp.ndarray:
@@ -582,7 +660,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     xs = (layer_params, layers, lora_params,
           # Gemma-2 layer pattern: even layers sliding, odd global
           layers % 2 == 0 if cfg.alternating_sliding else None)
-    pool = tuple(a for a in cache if a is not None)
+    pool = cache.carried()
     with jax.named_scope("dense_layers"):
         for i in range(Ld):
             lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
@@ -595,7 +673,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                      offset=1.0 if cfg.rms_norm_offset else 0.0)
     with jax.named_scope("lm_head"):
         logits = _lm_head(params, cfg, x)
-    return (logits, KVCache(*pool),
+    return (logits, cache.carried_back(pool),
             None if work is None else moe.Work(*map(jnp.sum, work)))
 
 

@@ -31,7 +31,9 @@ import jax.numpy as jnp
 # layer-dict entries that stay un-quantized (small or accuracy-critical)
 _SKIP_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_mlp_norm",
                "q_bias", "k_bias", "v_bias", "router", "s_gate_w",
-               "q_a_norm", "kv_a_norm", "router_bias")
+               "q_a_norm", "kv_a_norm", "router_bias",
+               # the sparse-attention indexer's norm and head weights
+               "idx_k_norm", "idx_k_norm_bias", "idx_w")
 # the groups of stacked layers a tree may hold: the scanned layers and
 # a layer plan's leading dense ones (models/llama.py)
 _LAYER_GROUPS = ("layers", "dense_layers")

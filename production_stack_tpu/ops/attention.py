@@ -96,6 +96,7 @@ def attention_with_cache(
     scale: Optional[float] = None,
     sliding_window: Optional[int] = None,
     logit_softcap: Optional[float] = None,
+    select: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Incremental GQA over a preallocated per-slot cache.
 
@@ -107,6 +108,8 @@ def attention_with_cache(
     Query token at position p attends to cache slots s <= p (and
     s > p - sliding_window when windowed). Padding query rows
     (q_positions < 0) produce garbage rows the caller discards.
+    select [B,T,S] of 0 / 1 (learned sparse attention, ops/dsa.py):
+    a query attends only the slots marked for it.
     """
     B, T, H, D = q.shape
     S = k_cache.shape[1]
@@ -122,6 +125,8 @@ def attention_with_cache(
     if sliding_window is not None:
         mask = mask & (s_idx[None, None, :]
                        > q_positions[:, :, None] - sliding_window)
+    if select is not None:
+        mask = mask & (select > 0)
     scores = jnp.where(mask[:, None, None], scores, _NEG_INF)
     probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
     probs = probs / probs.sum(axis=-1, keepdims=True)

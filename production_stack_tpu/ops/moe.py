@@ -75,6 +75,23 @@ helm/templates/deployment-vllm-multi.yaml:57-64; expert parallelism is a
   matmul touches only its resident experts and XLA inserts the
   dispatch/combine collectives from the sharding annotations.
 
+- **The chip's share of the experts.** Where a deployment divides an
+  expert layer over several chips, the router keeps its width and the
+  stacks hold the experts of this chip alone: ``moe_mlp`` is told
+  where they start (``expert_offset``), routes over all of the
+  router's experts, and adds only what its own experts give for the
+  tokens routed to them. An assignment to an expert held elsewhere
+  keeps its place in the renormalised weights and contributes nothing
+  here (what that chip would add is left out, as in the reference:
+  no code stands in for it); the list of experts hit, the grouped rows
+  and ``Work`` count held experts only.
+- **Experts read in tiles.** Where two slots of an expert's matrices
+  miss the kernels' share of VMEM, the list and the grouped kernel
+  take the expert in ``expert_tiles`` tiles of its intermediate width
+  (SwiGLU is elementwise over it and ``down`` sums over it: a tile is
+  a narrower expert with the same routing weight), decided from the
+  shapes by the rule that decides whether the kernels run at all.
+
 Routing follows Mixtral semantics: fp32 softmax over all experts, then
 top-k, then renormalize the selected probabilities to sum to 1; Qwen's
 (raw probabilities) and GLM-4.7-Flash's (sigmoid scores, a bias in the
@@ -186,8 +203,9 @@ def _moe_exact(x, top_p, top_i, gate, up, down, act):
     with jax.named_scope("moe_combine"):
         # combine [N, E]: routing weight where selected, else 0
         combine = jnp.zeros((N, E), jnp.float32)
+        # (an expert held elsewhere is named E: out of range, dropped)
         combine = combine.at[
-            jnp.arange(N)[:, None], top_i].set(top_p)
+            jnp.arange(N)[:, None], top_i].set(top_p, mode="drop")
         return jnp.einsum("enh,ne->nh", y_e,
                           combine.astype(x.dtype))
 
@@ -240,6 +258,33 @@ def list_scratch_bytes(hidden: int, inter: int, weight_dtype,
     return hidden * inter * (2 * 3 * stored.itemsize + converted)
 
 
+# the most tiles an expert is read in: beyond it a tile's copies are
+# too short to hide what starting them costs (not measured; Mixtral's
+# 4096 x 14336 would want 16)
+_MAX_TILES = 8
+
+
+def expert_tiles(hidden: int, inter: int, weight_dtype, act_dtype) -> int:
+    """In how many tiles of its intermediate width the kernels that
+    read experts in place take one expert: the fewest equal tiles, a
+    power of two of them and each a multiple of the 128 lanes wide, of
+    which two slots fit the kernels' share of VMEM
+    (``list_scratch_bytes`` of a tile). 1: the expert whole
+    (Qwen1.5-MoE's 2048 x 1408 in int8, 23 MB; GLM-4.7-Flash's 25 MB);
+    2: GLM-5's 6144 x 2048, 101 MB whole and 50 a half; 0: no such
+    tiling within _MAX_TILES (Mixtral-8x7B's 4096 x 14336, 470 MB)."""
+    if hidden % 128 or inter % 128:
+        return 0
+    tiles = 1
+    while tiles <= _MAX_TILES:
+        if inter % (tiles * 128) == 0 and list_scratch_bytes(
+                hidden, inter // tiles, weight_dtype, act_dtype
+                ) <= _LIST_VMEM_SHARE * pallas_paged.VMEM_LIMIT_BYTES:
+            return tiles
+        tiles *= 2
+    return 0
+
+
 def _experts_in_vmem(hidden: int, inter: int, weight_dtype, act_dtype,
                      mesh) -> bool:
     """What both kernels that read experts in place need (the list
@@ -247,15 +292,12 @@ def _experts_in_vmem(hidden: int, inter: int, weight_dtype, act_dtype,
     (pallas_paged.flash_enabled: compiled on a TPU, off on the CPU,
     interpret mode where a test forces it), no mesh axis shards
     anything, the widths are multiples of the 128 lanes, and two slots
-    of one expert's matrices fit VMEM (list_scratch_bytes:
-    Qwen1.5-MoE's 2048 x 1408 do, 23 MB in int8; Mixtral-8x7B's 4096 x
-    14336 do not, 470 MB)."""
+    of one expert's matrices, or of one of its tiles, fit VMEM
+    (``expert_tiles``)."""
     return (pallas_paged.flash_enabled()
             and (mesh is None
                  or all(size == 1 for size in mesh.shape.values()))
-            and hidden % 128 == 0 and inter % 128 == 0
-            and list_scratch_bytes(hidden, inter, weight_dtype, act_dtype)
-            <= _LIST_VMEM_SHARE * pallas_paged.VMEM_LIMIT_BYTES)
+            and expert_tiles(hidden, inter, weight_dtype, act_dtype) > 0)
 
 
 def list_path(rows: int, positions: int, hidden: int, inter: int,
@@ -298,13 +340,17 @@ def moe_path(rows: int, positions: int, num_experts: int, top_k: int,
              capacity_factor: float = 2.0, capacity_tokens=None) -> str:
     """The strategy the experts of that forward take, as
     models/llama.py calls ``moe_mlp`` (a decode step exact): "list",
-    "grouped", "exact" or "dispatch". engine/runner.py keeps it per
-    executable (``moe_paths``, GET /debug/perf ``device.moe_paths``)."""
+    "grouped", "exact" or "dispatch"; "list_tiled<n>" / "grouped_tiled<n>"
+    where the kernel takes an expert in n tiles (``expert_tiles``).
+    engine/runner.py keeps it per executable (``moe_paths``, GET
+    /debug/perf ``device.moe_paths``)."""
     shape = (rows, positions, hidden, inter, weight_dtype, act_dtype, mesh)
+    tiles = expert_tiles(hidden, inter, weight_dtype, act_dtype)
+    tiled = f"_tiled{tiles}" if tiles > 1 else ""
     if list_path(*shape):
-        return "list"
+        return "list" + tiled
     if grouped_path(*shape):
-        return "grouped"
+        return "grouped" + tiled
     N = rows * positions
     covered = N <= DENSE_THRESHOLD or N <= capacity_for(
         capacity_tokens or N, num_experts, top_k, capacity_factor)
@@ -335,13 +381,17 @@ def experts_hit(top_i: jnp.ndarray, valid, num_experts: int):
             jnp.sum(hit.astype(jnp.int32)))
 
 
-def _rank_in_expert(top_i: jnp.ndarray, valid, num_experts: int):
+def _rank_in_expert(top_i: jnp.ndarray, valid, num_experts: int,
+                    held: bool = False):
     """top_i [N, k], valid [N] bool or None -> (flat_e [N k] int32, the
     assignments' experts, token-major; rank [N k] int32, how many
     earlier assignments of valid tokens chose the same expert (an
     O(N k E) cumsum of integers); rows [E] int32, the valid
     assignments of each expert; keep [N k] bool, the assignment's
-    token is valid: None where valid is)."""
+    token is valid: None where valid is). ``held`` (static): top_i may
+    name num_experts itself, an expert held on another chip
+    (``moe_mlp``): such an assignment is kept out like an invalid
+    token's, and its flat_e reads 0."""
     k = top_i.shape[1]
     flat_e = top_i.reshape(-1)
     onehot = jax.nn.one_hot(flat_e, num_experts, dtype=jnp.int32)
@@ -349,19 +399,33 @@ def _rank_in_expert(top_i: jnp.ndarray, valid, num_experts: int):
     if valid is not None:
         keep = jnp.repeat(valid, k)
         onehot = onehot * keep.astype(jnp.int32)[:, None]
+    if held:
+        here = flat_e < num_experts
+        keep = here if keep is None else keep & here
+        flat_e = jnp.where(here, flat_e, 0)
     prior = jnp.cumsum(onehot, axis=0) - onehot
     rank = jnp.take_along_axis(prior, flat_e[:, None], axis=1)[:, 0]
     return flat_e, rank, jnp.sum(onehot, axis=0), keep
 
 
-def _expert_copies(ids_ref, layer, hbms, gu_buf, d_buf, sems, c, slot):
+def _expert_copies(ids_ref, layer, hbms, gu_buf, d_buf, sems, tiles, c,
+                   slot, tile=0):
     """The copies of listed expert c's gate, up and down out of the
-    stacks in HBM into ``slot``: to start, or to wait for one by one."""
+    stacks in HBM into ``slot``: to start, or to wait for one by one.
+    ``tiles`` > 1 (static): of its tile ``tile`` (static), the columns
+    of gate and up and the rows of down that one tile's width of the
+    intermediate values spans."""
     e = ids_ref[c]
-    return [pltpu.make_async_copy(hbm.at[layer, e], buf, sems.at[slot, o])
-            for o, (hbm, buf) in enumerate(zip(
-                hbms, (gu_buf.at[slot, 0], gu_buf.at[slot, 1],
-                       d_buf.at[slot])))]
+    bufs = (gu_buf.at[slot, 0], gu_buf.at[slot, 1], d_buf.at[slot])
+    if tiles == 1:
+        srcs = [hbm.at[layer, e] for hbm in hbms]
+    else:
+        width = d_buf.shape[1]
+        span = pl.ds(tile * width, width)
+        srcs = [hbms[0].at[layer, e, :, span], hbms[1].at[layer, e, :, span],
+                hbms[2].at[layer, e, span, :]]
+    return [pltpu.make_async_copy(src, buf, sems.at[slot, o])
+            for o, (src, buf) in enumerate(zip(srcs, bufs))]
 
 
 def _scale_row(ref, c):
@@ -384,7 +448,7 @@ def _scaled_dot(a, w, scale):
 
 
 def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
-                     *refs, act: Callable, quant: bool):
+                     *refs, act: Callable, quant: bool, tiles: int):
     """Every listed expert over all N rows.
 
     ids_ref   (SMEM) [M]     the experts hit, compacted
@@ -399,6 +463,13 @@ def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
            out [N, h]; scratch: the gate/up slots [2, 2, h, i], the
            down slots [2, i, h], DMA semaphores [2 slots, 3 matrices],
            acc [N, h] fp32
+
+    tiles > 1 (static): an expert comes in that many tiles of its
+    intermediate width, one after the other through the two slots
+    ([2, 2, h, i/tiles] and [2, i/tiles, h]); gate's and up's scale
+    rows come a row a tile ([M8 x tiles, i/tiles], the expert's tiles
+    in order), and every tile's products are weighed and added like an
+    expert's.
     """
     gate_hbm, up_hbm, down_hbm = refs[:3]
     refs = refs[3:]
@@ -411,7 +482,7 @@ def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
 
     copies = functools.partial(_expert_copies, ids_ref, layer,
                                (gate_hbm, up_hbm, down_hbm), gu_buf,
-                               d_buf, sems)
+                               d_buf, sems, tiles)
 
     @pl.when(count > 0)
     def _first():
@@ -422,35 +493,55 @@ def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
     x = x_ref[...]
 
     def expert(c, carry):
-        slot = jax.lax.rem(c, 2)
+        # an even number of tiles leaves every expert's first in slot 0
+        slot = jax.lax.rem(c, 2) if tiles == 1 else 0
         e = ids_ref[c]
+        for t in range(tiles):
+            if t + 1 < tiles:
+                for cp in copies(c, 1 - slot, t + 1):
+                    cp.start()
+            else:
+                @pl.when(c + 1 < count)
+                def _next():
+                    for cp in copies(c + 1, 1 - slot):
+                        cp.start()
 
-        @pl.when(c + 1 < count)
-        def _next():
-            for cp in copies(c + 1, 1 - slot):
-                cp.start()
-
-        # this expert's combine column [N, 1]: its routing weight on
-        # the rows that chose it, zero on the others
-        comb = jnp.sum(jnp.where(ti_ref[...] == e, tp_ref[...], 0.0),
-                       axis=1, keepdims=True)
-        sg, su, sd = ((_scale_row(ref, c) for ref in scale_refs)
-                      if quant else (None,) * 3)
-        # each matrix is waited for where it is first read: gate's
-        # products run under up's and down's copies
-        gate_copy, up_copy, down_copy = copies(c, slot)
-        gate_copy.wait()
-        g = _scaled_dot(x, gu_buf[slot, 0], sg)
-        up_copy.wait()
-        a = (act(g) * _scaled_dot(x, gu_buf[slot, 1], su)
-             ).astype(cdt)                                      # [N, i]
-        down_copy.wait()
-        y = _scaled_dot(a, d_buf[slot], sd)
-        acc_ref[...] += y * comb
+            if t == 0:
+                # this expert's combine column [N, 1]: its routing
+                # weight on the rows that chose it, zero on the others
+                comb = jnp.sum(
+                    jnp.where(ti_ref[...] == e, tp_ref[...], 0.0),
+                    axis=1, keepdims=True)
+            if quant:
+                part = c if tiles == 1 else c * tiles + t
+                sg, su = (_scale_row(ref, part) for ref in scale_refs[:2])
+                sd = _scale_row(scale_refs[2], c)
+            else:
+                sg = su = sd = None
+            # each matrix is waited for where it is first read: gate's
+            # products run under up's and down's copies
+            gate_copy, up_copy, down_copy = copies(c, slot, t)
+            gate_copy.wait()
+            g = _scaled_dot(x, gu_buf[slot, 0], sg)
+            up_copy.wait()
+            a = (act(g) * _scaled_dot(x, gu_buf[slot, 1], su)
+                 ).astype(cdt)                                  # [N, i]
+            down_copy.wait()
+            y = _scaled_dot(a, d_buf[slot], sd)
+            acc_ref[...] += y * comb
+            if tiles > 1:
+                slot = 1 - slot
         return carry
 
     jax.lax.fori_loop(0, count, expert, 0)
     out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _tile_scales(w, layer, listed, tiles: int):
+    """The listed experts' scale rows of gate or up [M8, i], a row a
+    tile where an expert comes in tiles: [M8 x tiles, i / tiles]."""
+    sc = w["scale"][layer, listed]
+    return sc if tiles == 1 else sc.reshape(sc.shape[0] * tiles, -1)
 
 
 def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
@@ -464,6 +555,8 @@ def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
     k = top_i.shape[1]
     L, E, _, inter = _wshape(gate)
     mats = [w["w8"] if quant else w for w in (gate, up, down)]
+    tiles = expert_tiles(h, inter, mats[0].dtype, x.dtype)
+    inter //= tiles
 
     def whole(*_):
         return (0, 0)
@@ -478,12 +571,14 @@ def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
         # (1.2 MB a layer beside the experts' 8.65 MB each)
         rows = jnp.pad(ids, (0, -ids.shape[0] % 8))
         for w in (gate, up, down):
-            sc = w["scale"][layer, rows]                  # [M8, w]
+            sc = (w["scale"][layer, rows] if w is down      # [M8, w]
+                  else _tile_scales(w, layer, rows, tiles))
             in_specs.append(pl.BlockSpec(sc.shape, whole))
             operands.append(sc)
     with jax.named_scope("moe_experts"):
         return pl.pallas_call(
-            functools.partial(_moe_list_kernel, act=act, quant=quant),
+            functools.partial(_moe_list_kernel, act=act, quant=quant,
+                              tiles=tiles),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(1,),
@@ -539,7 +634,7 @@ def _row_align(dtype) -> int:
 
 
 def _group_rows(top_i: jnp.ndarray, valid, num_experts: int, align: int,
-                slack: int):
+                slack: int, held: bool = False):
     """top_i [N, k], valid [N] bool or None -> (dest [N k] int32: the
     buffer row of each assignment, P where its token is invalid; rows
     [E] int32: the rows of each expert; seg [E] int32: where each
@@ -547,7 +642,7 @@ def _group_rows(top_i: jnp.ndarray, valid, num_experts: int, align: int,
     rows, static)."""
     N, k = top_i.shape
     E = num_experts
-    flat_e, rank, rows, keep = _rank_in_expert(top_i, valid, E)
+    flat_e, rank, rows, keep = _rank_in_expert(top_i, valid, E, held)
     padded = -(-rows // align) * align
     seg = jnp.cumsum(padded) - padded
     P = -(-(N * k + E * (align - 1)) // align) * align + slack
@@ -559,7 +654,7 @@ def _group_rows(top_i: jnp.ndarray, valid, num_experts: int, align: int,
 
 def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
                         xs_hbm, *refs, act: Callable, quant: bool,
-                        align: int):
+                        align: int, tiles: int):
     """Every listed expert over its own rows.
 
     ids_ref    (SMEM) [M]    the experts that have a row, compacted
@@ -574,6 +669,12 @@ def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
            [2, 2, h, i], the down slots [2, i, h], their semaphores
            [2, 3], the rows' slots [2, R, h] in and [2, R, h] out,
            their semaphores [2] and [2]
+
+    tiles > 1 (static): an expert comes in that many tiles of its
+    intermediate width (slots and scale rows as the list kernel's),
+    each walking the expert's segment again and writing its products
+    to a plane of its own, out (HBM) [tiles x P, h]: tile t's at row
+    t P + the segment's; the caller sums the planes.
     """
     gate_hbm, up_hbm, down_hbm = refs[:3]
     refs = refs[3:]
@@ -584,10 +685,11 @@ def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
     count = count_ref[0]
     cdt = x_buf.dtype                              # the dots' operands
     R = x_buf.shape[1]
+    P = xs_hbm.shape[0]
 
     copies = functools.partial(_expert_copies, ids_ref, layer,
                                (gate_hbm, up_hbm, down_hbm), gu_buf,
-                               d_buf, sems)
+                               d_buf, sems, tiles)
 
     def rows_in(row, slot):
         return pltpu.make_async_copy(
@@ -607,55 +709,78 @@ def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
         rows_in(seg_ref[0], 0).start()
 
     def expert(c, done):
-        slot = jax.lax.rem(c, 2)
+        slot = jax.lax.rem(c, 2) if tiles == 1 else 0
         more = c + 1 < count
-
-        @pl.when(more)
-        def _next():
-            for cp in copies(c + 1, 1 - slot):
-                cp.start()
-
-        sg, su, sd = ((_scale_row(ref, c) for ref in scale_refs)
-                      if quant else (None,) * 3)
-        gate_copy, up_copy, down_copy = copies(c, slot)
         first = seg_ref[c]
         passes = passes_ref[c]
         # the segment after this one (the list's last: not read)
         after = seg_ref[jnp.minimum(c + 1, ids_ref.shape[0] - 1)]
+        for t in range(tiles):
+            last_tile = t + 1 == tiles
+            if not last_tile:
+                for cp in copies(c, 1 - slot, t + 1):
+                    cp.start()
+            else:
+                @pl.when(more)
+                def _next():
+                    for cp in copies(c + 1, 1 - slot):
+                        cp.start()
 
-        def one_pass(t, n):
-            """Rows first + t R .. + R of the buffer; n: the passes
-            made so far, whose parity names the rows' slot."""
-            rs = jax.lax.rem(n, 2)
-            row = first + t * R
-            inside = t + 1 < passes
+            if quant:
+                part = c if tiles == 1 else c * tiles + t
+                sg, su = (_scale_row(ref, part) for ref in scale_refs[:2])
+                sd = _scale_row(scale_refs[2], c)
+            else:
+                sg = su = sd = None
+            gate_copy, up_copy, down_copy = copies(c, slot, t)
+            # the rows walked after this tile's: the expert's own again
+            # for its next tile, else the next expert's
+            then = after if last_tile else first
+            plane = t * P
 
-            @pl.when(inside | more)
-            def _rows_ahead():
-                rows_in(jnp.where(inside, row + R, after), 1 - rs).start()
+            def one_pass(p, n, slot=slot, sg=sg, su=su, sd=sd,
+                         gate_copy=gate_copy, up_copy=up_copy,
+                         down_copy=down_copy, then=then, plane=plane,
+                         last_tile=last_tile):
+                """Rows first + p R .. + R of the buffer; n: the passes
+                made so far, whose parity names the rows' slot."""
+                rs = jax.lax.rem(n, 2)
+                row = first + p * R
+                inside = p + 1 < passes
 
-            rows_in(row, rs).wait()
-            x = x_buf[rs]
-            # each matrix is waited for where it is first read
-            pl.when(t == 0)(gate_copy.wait)
-            g = _scaled_dot(x, gu_buf[slot, 0], sg)
-            pl.when(t == 0)(up_copy.wait)
-            a = (act(g) * _scaled_dot(x, gu_buf[slot, 1], su)
-                 ).astype(cdt)
-            pl.when(t == 0)(down_copy.wait)
-            y_buf[rs] = _scaled_dot(a, d_buf[slot], sd
-                                    ).astype(y_buf.dtype)
-            # one write at a time, in the segments' order: what this
-            # pass writes past its segment the next overwrites
+                def _rows_ahead():
+                    rows_in(jnp.where(inside, row + R, then),
+                            1 - rs).start()
 
-            @pl.when(n > 0)
-            def _written():
-                rows_out(row, 1 - rs).wait()
+                if last_tile:
+                    pl.when(inside | more)(_rows_ahead)
+                else:
+                    _rows_ahead()
+                rows_in(row, rs).wait()
+                x = x_buf[rs]
+                # each matrix is waited for where it is first read
+                pl.when(p == 0)(gate_copy.wait)
+                g = _scaled_dot(x, gu_buf[slot, 0], sg)
+                pl.when(p == 0)(up_copy.wait)
+                a = (act(g) * _scaled_dot(x, gu_buf[slot, 1], su)
+                     ).astype(cdt)
+                pl.when(p == 0)(down_copy.wait)
+                y_buf[rs] = _scaled_dot(a, d_buf[slot], sd
+                                        ).astype(y_buf.dtype)
+                # one write at a time, in the segments' order: what
+                # this pass writes past its segment the next overwrites
 
-            rows_out(row, rs).start()
-            return n + 1
+                @pl.when(n > 0)
+                def _written():
+                    rows_out(row, 1 - rs).wait()
 
-        return jax.lax.fori_loop(0, passes, one_pass, done)
+                rows_out(row + plane if plane else row, rs).start()
+                return n + 1
+
+            done = jax.lax.fori_loop(0, passes, one_pass, done)
+            if tiles > 1:
+                slot = 1 - slot
+        return done
 
     done = jax.lax.fori_loop(0, count, expert, 0)
 
@@ -664,11 +789,13 @@ def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
         rows_out(0, jax.lax.rem(done - 1, 2)).wait()
 
 
-def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer):
+def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer,
+                 held: bool = False):
     """Every expert over the rows routed to it, combined by routing
     weight: ``_moe_exact``'s result with nothing multiplied by a
     weight of zero. gate/up [L, E, h, i], down [L, E, i, h] (raw or
-    int8-quantized), layer: int32 scalar, traced. Returns ([N, h], the
+    int8-quantized), layer: int32 scalar, traced; held: top_i may name
+    E, an expert held elsewhere (``moe_mlp``). Returns ([N, h], the
     experts that had a row, the rows the experts multiplied: passes x
     GROUPED_ROWS)."""
     quant = _quant().is_quantized(gate)
@@ -676,10 +803,12 @@ def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer):
     k = top_i.shape[1]
     L, E, _, inter = _wshape(gate)
     mats = [w["w8"] if quant else w for w in (gate, up, down)]
+    tiles = expert_tiles(h, inter, mats[0].dtype, x.dtype)
+    inter //= tiles
     R = GROUPED_ROWS
     align = _row_align(x.dtype)
     with jax.named_scope("moe_group"):
-        dest, rows, seg, P = _group_rows(top_i, valid, E, align, R)
+        dest, rows, seg, P = _group_rows(top_i, valid, E, align, R, held)
         # the buffer's rows by the token each holds (padding: token 0,
         # computed and never read back)
         src = jnp.zeros((P,), jnp.int32).at[dest].set(
@@ -694,13 +823,14 @@ def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer):
     if quant:
         listed = jnp.pad(ids, (0, -ids.shape[0] % 8))
         for w in (gate, up, down):
-            sc = w["scale"][layer, listed]                # [M8, w]
+            sc = (w["scale"][layer, listed] if w is down    # [M8, w]
+                  else _tile_scales(w, layer, listed, tiles))
             in_specs.append(pl.BlockSpec(sc.shape, lambda *_: (0, 0)))
             operands.append(sc)
     with jax.named_scope("moe_experts"):
         ys = pl.pallas_call(
             functools.partial(_moe_grouped_kernel, act=act, quant=quant,
-                              align=align),
+                              align=align, tiles=tiles),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=(1,),
@@ -716,7 +846,7 @@ def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer):
                     pltpu.SemaphoreType.DMA((2,)),
                 ],
             ),
-            out_shape=jax.ShapeDtypeStruct((P, h), x.dtype),
+            out_shape=jax.ShapeDtypeStruct((tiles * P, h), x.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
@@ -728,23 +858,27 @@ def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer):
         # an invalid token's assignments read row 0, which may hold
         # anything, and count as zero
         kept = dest < P
-        y = jnp.where(kept[:, None],
-                      ys[jnp.where(kept, dest, 0)].astype(jnp.float32), 0.0)
+        back = jnp.where(kept, dest, 0)
+        y = ys[back].astype(jnp.float32)
+        for t in range(1, tiles):       # an expert's tiles, a plane each
+            y = y + ys[back + t * P].astype(jnp.float32)
+        y = jnp.where(kept[:, None], y, 0.0)
         y = jnp.sum((y * top_p.reshape(-1)[:, None]).reshape(N, k, h),
                     axis=1)
     return y.astype(x.dtype), count, jnp.sum(passes) * R
 
 
 def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
-                  valid=None):
+                  valid=None, held: bool = False):
     """Scatter-based capacity dispatch (see module docstring)."""
     N, h = x.shape
     E = _wshape(gate)[0]
     k = top_i.shape[1]
 
     # padding tokens must not compete for expert capacity: they are
-    # left out of the rank count and the buffers
-    flat_e, rank, _, real = _rank_in_expert(top_i, valid, E)
+    # left out of the rank count and the buffers (and the assignments
+    # to experts held elsewhere)
+    flat_e, rank, _, real = _rank_in_expert(top_i, valid, E, held)
     keep = rank < capacity
     if real is not None:
         keep = keep & real
@@ -772,11 +906,18 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             exact=None, renormalize: bool = True,
             capacity_tokens=None, layer=None, positions: int = 1,
             router_score: str = "softmax", router_bias=None,
-            routed_scale: float = 1.0):
+            routed_scale: float = 1.0, expert_offset: int = 0):
     """MoE feed-forward. x [N, h]; router_w [h, E]; gate/up [E, h, i];
     down [E, i, h]. Returns ([N, h] in x.dtype, ``Work``: the experts
     whose weights the call read and the rows they multiplied, int32
     scalars).
+
+    The chip's share of the experts: where router_w scores more
+    experts than the stacks hold, the stacks are those from
+    ``expert_offset`` (static) on. The router picks among all of them
+    and weighs as published; an assignment to an expert held elsewhere
+    contributes nothing here (module text), and ``Work`` counts held
+    experts only.
 
     valid [N] bool marks real tokens: padding rows contribute nothing
     and never consume expert capacity. exact=True forces the all-expert
@@ -809,6 +950,13 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
                              scale=routed_scale)
         if valid is not None:
             top_p = top_p * valid.astype(top_p.dtype)[:, None]
+        held = router_w.shape[-1] != E
+        if held:
+            # experts held elsewhere: all named E, one past the stacks,
+            # an id no kernel and no one-hot matches; weight zero
+            here = (top_i >= expert_offset) & (top_i < expert_offset + E)
+            top_i = jnp.where(here, top_i - expert_offset, E)
+            top_p = jnp.where(here, top_p, 0.0)
     if layer is not None:
         h, inter = _wshape(gate)[-2:]
         shape = (N // positions, positions, h, inter, stored_dtype(gate),
@@ -820,13 +968,16 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             f"experts [{h}, {inter}], exact={exact}")
         if grouped_path(*shape):
             y, count, multiplied = _moe_grouped(
-                x, top_p, top_i, gate, up, down, act, valid, layer)
+                x, top_p, top_i, gate, up, down, act, valid, layer, held)
             return y, Work(count, multiplied)
         with jax.named_scope("moe_list"):
             ids, count = experts_hit(top_i, valid, E)
         return _moe_list(x, top_p, top_i, gate, up, down, act, ids,
                          count, layer), Work(count, count * N)
-    capacity = min(N, capacity_for(capacity_tokens or N, E, top_k,
+    # (the balanced load an expert's capacity is reckoned on is that
+    # of all the router's experts)
+    capacity = min(N, capacity_for(capacity_tokens or N,
+                                   router_w.shape[-1], top_k,
                                    capacity_factor))
     if exact is None:
         exact = N <= dense_threshold or capacity >= N
@@ -834,5 +985,5 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
         y = _moe_exact(x, top_p, top_i, gate, up, down, act)
     else:
         y = _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
-                          valid=valid)
+                          valid=valid, held=held)
     return y, Work(jnp.int32(E), jnp.int32(E * (N if exact else capacity)))

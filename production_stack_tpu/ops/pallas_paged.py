@@ -133,6 +133,12 @@ def paged_viable(T: int, groups: int, head_dim: int,
 
 # the smallest q block the prefill kernel cuts a chunk into
 _MIN_BLOCK_Q = 16
+# the sparse case of the prefill kernel (a mask of selected positions
+# over the latent pool): the queries a q block holds and the keys a
+# grid step takes; 32 x 64 heads against 512 keys are 10 MB of scores,
+# probabilities and accumulator (one setting run on the chip: PERF.md)
+_SELECT_BLOCK_Q = 32
+_SELECT_PANEL_TOKENS = 512
 
 
 def _whole_pool(layer, k_pool, v_pool, k_scales, v_scales):
@@ -150,7 +156,8 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
                   block_q: int, groups: int,
                   block_size: int, nb: int, scale: float,
                   quant: bool = False, window: int = 0,
-                  softcap: float = 0.0, value_dim: int = 0):
+                  softcap: float = 0.0, value_dim: int = 0,
+                  select: bool = False, R: int = 1):
     """One (batch row, kv head, q block, pool block) grid step.
 
     tabs_ref   (SMEM) [B, MB]      block tables
@@ -168,9 +175,24 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
     and out [1, BQ*G, value_dim] come with their rows flattened by the
     wrapper (one kv head), and the dots take the operands as stored
     (bf16 to the MXU, float32 products), as the decode kernel's do.
+
+    select (static; the latent pool, learned sparse attention): one
+    more operand after the pools, sel_ref [1, 1, 1, BQ, R*Bs] of 0 / 1:
+    the positions of this step's pool blocks that each query of the
+    block attends (models/kv.attend_selected); the others are masked
+    like the positions past the query. There a grid step takes R pool
+    blocks (static), R operands k_ref .. each [1, 1, 1, Bs, D] side by
+    side as one [R*Bs, D] panel: contexts of thousands of tokens in
+    steps of one 64-token block leave the MXU half empty and pay a
+    grid step's overhead 256 times a q block.
     """
+    k_refs = (k_ref,) + refs[:R - 1]
+    refs = refs[R - 1:]
+    block_size = R * block_size                 # the step's key panel
     if not value_dim:
         v_ref, refs = refs[0], refs[1:]
+    if select:
+        sel_ref, refs = refs[0], refs[1:]
     if quant:
         ks_ref, vs_ref = refs[0], refs[1]
         refs = refs[2:]
@@ -207,7 +229,8 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
             jnp.int32, (rows, 1), 0) // groups
         q_pos = start + qi * block_q + row_ids                # [rows, 1]
         if value_dim:
-            k_blk = k_ref[0, 0, 0]                            # [Bs, D]
+            k_blk = (k_ref[0, 0, 0] if R == 1 else jnp.concatenate(
+                [ref[0, 0, 0] for ref in k_refs], axis=0))    # [Bs, D]
             v_blk = k_blk[:, :value_dim]
             s = jax.lax.dot_general(
                 q_ref[0].astype(k_blk.dtype), k_blk,
@@ -233,6 +256,17 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
         live = k_pos <= q_pos
         if window:
             live = live & (k_pos > q_pos - window)
+        if select:
+            # the query's row of marks for each of its heads' rows
+            # (t*G + g): a product with the 0 / 1 matrix that repeats
+            # it. A row all of whose positions so far are masked
+            # gathers weight 1 on each, which the first marked one's
+            # correction wipes
+            mine = (row_ids == jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_q), 1)).astype(sel_ref.dtype)
+            live = live & (jax.lax.dot_general(
+                mine, sel_ref[0, 0, 0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) > 0.5)
         s = jnp.where(live, s, _NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1,
@@ -263,7 +297,7 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
                     block_q: int = 0, interpret: bool = False,
                     k_scales=None, v_scales=None, window: int = 0,
                     scale: float = None, softcap: float = 0.0,
-                    layer=None, value_dim: int = 0):
+                    layer=None, value_dim: int = 0, select=None):
     """Causal GQA over paged K/V, positions contiguous per row.
 
     q [B, T, H, D]; k/v pool [N, Hkv, Bs, D], or with ``layer`` (an
@@ -284,6 +318,11 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     The latent pool: k_pool [(L,) N, 1, Bs, W], v_pool None and
     value_dim (static) the leading columns of a key that are its value;
     q [B, T, H, W] the absorbed queries -> [B, T, H, value_dim].
+
+    select [B, T, nb*Bs] of 0 / 1 (the latent pool only): the virtual
+    positions each query attends, of those at or before it (learned
+    sparse attention: models/kv.attend_selected). Every live block is
+    still read; the marks mask the scores.
     """
     B, T, H, D = q.shape
     layer, k_pool, v_pool, k_scales, v_scales = _whole_pool(
@@ -291,9 +330,18 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     Hkv, Bs = k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
     MB = tables.shape[1]
+    assert select is None or value_dim, "select: the latent pool only"
     if scale is None:
         scale = D ** -0.5
     quant = k_scales is not None
+    R = 1
+    if select is not None:
+        # the sparse case reads long contexts: _SELECT_BLOCK_Q queries
+        # against as many pool blocks a step as divide the kv bucket,
+        # up to _SELECT_PANEL_TOKENS keys
+        R = next(r for r in (8, 4, 2, 1)
+                 if r * Bs <= _SELECT_PANEL_TOKENS and nb % r == 0)
+        block_q = block_q or _SELECT_BLOCK_Q
     if not block_q:
         # whole chunk per q block while VMEM allows: K/V are streamed
         # once per (batch, head) instead of once per q block
@@ -315,14 +363,14 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     q5 = (q.reshape(B, Tp * G, D) if value_dim
           else q.reshape(B, Tp, Hkv, G, D))
 
-    def kv_index(b, h, qi, j, tabs, sts, lyr):
+    def kv_index(b, h, qi, j, tabs, sts, lyr, i=0):
         # clamp out-of-range blocks (past-causal above, before the
         # sliding window below) onto the nearest visible one: the index
         # stops changing, so Pallas skips the DMA re-fetch and pl.when
-        # skips the compute
+        # skips the compute. (i: which of a step's R blocks)
         jmax = jax.lax.div(sts[b] + qi * block_q + (block_q - 1),
                            Bs)
-        jj = jnp.minimum(jnp.minimum(j, jmax),
+        jj = jnp.minimum(jnp.minimum(j * R + i if R > 1 else j, jmax),
                          jnp.int32(MB - 1))
         if window:
             jmin = jax.lax.div(
@@ -340,11 +388,12 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     def q_index(b, h, qi, j, tabs, sts, lyr):
         return (b, qi, 0) if value_dim else (b, qi, h, 0, 0)
 
-    grid = (B, Hkv, nq, nb)
+    grid = (B, Hkv, nq, nb // R)
     kernel = functools.partial(
         _paged_kernel, block_q=block_q, groups=G, block_size=Bs,
-        nb=nb, scale=scale, quant=quant, window=window,
-        softcap=softcap, value_dim=value_dim)
+        nb=nb // R, scale=scale, quant=quant, window=window,
+        softcap=softcap, value_dim=value_dim, select=select is not None,
+        R=R)
     rows = block_q * G
     q_block, out_block = (
         ((1, rows, D), (1, rows, Dv)) if value_dim
@@ -354,9 +403,23 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
         pl.BlockSpec((1, 1, 1, Bs, D), kv_index),
     ]
     operands = [q5, k_pool]
+    for i in range(1, R):       # the step's further blocks of the pool
+        in_specs.append(pl.BlockSpec((1, 1, 1, Bs, D),
+                                     functools.partial(kv_index, i=i)))
+        operands.append(k_pool)
     if not value_dim:
         in_specs.append(pl.BlockSpec((1, 1, 1, Bs, D), kv_index))
         operands.append(v_pool)
+    if select is not None:
+        # [B, T, nb*Bs] -> a [BQ, Bs] tile per (q block, pool block),
+        # whole in its two minor dimensions
+        if pad_t:
+            select = jnp.pad(select, ((0, 0), (0, pad_t), (0, 0)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, block_q, R * Bs),
+            lambda b, h, qi, j, tabs, sts, lyr: (b, qi, j, 0, 0)))
+        operands.append(select.reshape(B, nq, block_q, nb // R, R * Bs
+                                       ).transpose(0, 1, 3, 2, 4))
     if quant:
         in_specs += [pl.BlockSpec((1, 1, Hkv, Bs), scale_index)] * 2
         operands += [k_scales, v_scales]
@@ -463,7 +526,8 @@ def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
                          T: int, heads_kv: int, groups: int,
                          block_size: int, nb: int, R: int, scale: float,
                          quant: bool = False, window: int = 0,
-                         softcap: float = 0.0, value_dim: int = 0):
+                         softcap: float = 0.0, value_dim: int = 0,
+                         select: bool = False):
     """One batch row: every live block of it, R at a time.
 
     tabs_ref   (SMEM) [B, MB]     block tables
@@ -483,11 +547,17 @@ def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
     The latent pool (value_dim > 0, static): no v_hbm and no V slots —
     a block's one [1, Bs, W] panel is copied once, and its first
     value_dim columns are the values; out, acc and p.V are value_dim
-    wide.
+    wide. select (static; learned sparse attention, one query a row):
+    one more operand after the pools, the row's marks
+    [1, chunks, 1, R*Bs] of 0 / 1, chunk by chunk as the score columns
+    lie: a position not marked is masked like one past the query (its
+    block is copied all the same).
     """
     hbm = (k_hbm,)
     if not value_dim:
         hbm, refs = hbm + refs[:1], refs[1:]
+    if select:
+        sel_ref, refs = refs[0], refs[1:]
     if quant:
         ks_ref, vs_ref = refs[:2]
         refs = refs[2:]
@@ -590,6 +660,8 @@ def _paged_decode_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_hbm,
         live = (k_pos <= row_pos) & (k_pos < (hi + 1) * Bs)
         if window:
             live = live & (k_pos > row_pos - window)
+        if select:
+            live = live & (sel_ref[0, g] > 0.5)
         s = jnp.where(live, s, _NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -646,7 +718,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
                            k_scales=None, v_scales=None,
                            window: int = 0,
                            scale: float = None, softcap: float = 0.0,
-                           layer=None, value_dim: int = 0):
+                           layer=None, value_dim: int = 0, select=None):
     """paged_attention specialized for short query windows (T <=
     DECODE_T_MAX): same contract, same result, work in proportion to
     the rows' live tokens.
@@ -660,13 +732,18 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
     scores and probabilities). The latent pool: k_pool
     [(L,) N, 1, Bs, W], v_pool None, value_dim its value columns, q the
     absorbed queries [B, T, H, W] -> [B, T, H, value_dim]; each live
-    block is copied once.
+    block is copied once. select [B, nb*Bs] of 0 / 1 (the latent pool,
+    T = 1): the positions each row's query attends, of those at or
+    before it (models/kv.attend_selected): every live block is read,
+    the marks mask the scores.
     """
     B, T, H, D = q.shape
     layer, k_pool, v_pool, k_scales, v_scales = _whole_pool(
         layer, k_pool, v_pool, k_scales, v_scales)
     Hkv, Bs = k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
+    assert select is None or (value_dim and T == 1), \
+        "select: the latent pool, one query a row"
     if scale is None:
         scale = D ** -0.5
     quant = k_scales is not None
@@ -688,11 +765,19 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
     kernel = functools.partial(
         _paged_decode_kernel, T=T, heads_kv=Hkv, groups=G,
         block_size=Bs, nb=nb, R=R, scale=scale, quant=quant,
-        window=window, softcap=softcap, value_dim=value_dim)
+        window=window, softcap=softcap, value_dim=value_dim,
+        select=select is not None)
     pools = [k_pool] if value_dim else [k_pool, v_pool]
     in_specs = [pl.BlockSpec((1, Hkv, rows, D), row_index)] + [
         pl.BlockSpec(memory_space=pltpu.HBM) for _ in pools]
     operands = [qh, *pools]
+    if select is not None:
+        # the row's marks chunk by chunk (float32: a [1, R*Bs] row of
+        # it is a whole sublane tile's lanes, as the scales' are)
+        marks = jnp.pad(select.astype(jnp.float32),
+                        ((0, 0), (0, ngrp * R * Bs - nb * Bs)))
+        in_specs.append(pl.BlockSpec((1, ngrp, 1, R * Bs), row_index))
+        operands.append(marks.reshape(B, ngrp, 1, R * Bs))
     if quant:
         in_specs += [pl.BlockSpec((1, ngrp, Hkv, R * Bs), row_index)] * 2
         operands += [_chunked_scales(s, layer, tables, ngrp, R)
@@ -778,7 +863,8 @@ JNP_GATHER = "jnp_gather"
 
 
 def attention_path(T: int, groups: int, head_dim: int, block_size: int,
-                   mesh=None, value_dim: int = 0) -> str:
+                   mesh=None, value_dim: int = 0,
+                   selects: bool = False) -> str:
     """Which cached-attention implementation a forward over T query
     positions per row takes, for ``groups`` query heads per kv head.
     Decided here, by shape, BEFORE anything compiles — a kernel the
@@ -796,6 +882,11 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
     which value_dim columns are the value; no mesh). Twenty heads of
     576 make a 256-token chunk's q panel miss VMEM whole, so what must
     fit is the smallest q block the prefill kernel cuts it into.
+    ``*_latent_sparse``: the same two where the layer selects what it
+    attends (``selects``: models/kv.selects, the kv bucket holds more
+    positions than the indexer keeps): the decode kernel over the
+    selected positions' latents alone, the prefill kernel under a
+    mask of them (models/kv.attend_selected).
     ``jnp_gather``: the kernel is off (PSTPU_FLASH / not a TPU), the
     chunk's working set misses VMEM (paged_viable), or the mesh shards
     the pool's block axis."""
@@ -804,8 +895,9 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
                 min(T, _MIN_BLOCK_Q), groups, head_dim, block_size,
                 value_dim)):
             return JNP_GATHER
-        return ("pallas_paged_decode_latent" if T <= DECODE_T_MAX
-                else "pallas_paged_latent")
+        return (("pallas_paged_decode_latent" if T <= DECODE_T_MAX
+                 else "pallas_paged_latent")
+                + ("_sparse" if selects else ""))
     if not (flash_enabled()
             and paged_viable(T, groups, head_dim, block_size)
             and (mesh is None or mesh_tp_only(mesh))):

@@ -897,3 +897,91 @@ def test_route_refuses_an_unknown_score():
     x, w, _ = _router_inputs()
     with pytest.raises(ValueError, match="router score"):
         moe.route(x, w, 2, score="tanh")
+
+
+# ---------------------------------------------------------------------
+# experts read in tiles (ops/moe.expert_tiles): where two slots of an
+# expert's matrices miss the kernels' share of VMEM, the list and the
+# grouped kernel take the expert in tiles of its intermediate width.
+# ---------------------------------------------------------------------
+
+GLM47 = (2048, 1536)           # GLM-4.7-Flash's experts [h, i]
+GLM5 = (6144, 2048)            # GLM-5's
+
+
+@pytest.mark.parametrize("widths,weights,share,tiles", [
+    (QWEN, jnp.int8, 0.5, 1), (GLM47, jnp.int8, 0.5, 1),
+    (QWEN, jnp.bfloat16, 0.5, 1), (GLM5, jnp.int8, 0.5, 2),
+    (GLM5, jnp.bfloat16, 0.5, 4), (MIXTRAL, jnp.int8, 0.5, 0),
+    # a smaller share (what the tests below set) tiles GLM-4.7-Flash's
+    # 12 lanes of 128; Qwen's 11 split into no power of two
+    (GLM47, jnp.int8, 0.2, 2), (GLM47, jnp.int8, 0.1, 4),
+    (QWEN, jnp.int8, 0.2, 0), ((2048, 1400), jnp.int8, 0.5, 0)])
+def test_expert_tiles_rule(monkeypatch, widths, weights, share, tiles):
+    """The fewest equal tiles, a power of two of them and each whole
+    lanes wide, of which two slots fit the kernels' share of VMEM."""
+    monkeypatch.setattr(moe, "_LIST_VMEM_SHARE", share)
+    assert moe.expert_tiles(*widths, weights, jnp.bfloat16) == tiles
+    if tiles:
+        h, i = widths
+        assert moe.list_scratch_bytes(h, i // tiles, weights, jnp.bfloat16) \
+            <= share * pallas_paged.VMEM_LIMIT_BYTES
+        assert i % (tiles * 128) == 0
+
+
+def test_moe_path_names_the_tiled_kernels(kernels_on):
+    args = (jnp.int8, jnp.bfloat16)
+    assert moe.moe_path(8, 1, 256, 8, *GLM5, *args) == "list_tiled2"
+    assert moe.moe_path(1, 2048, 256, 8, *GLM5, *args) == "grouped_tiled2"
+    assert moe.moe_path(16, 1, 64, 4, *GLM47, *args) == "list"
+    assert moe.moe_path(1, 256, 60, 4, *QWEN, *args) == "grouped"
+    assert moe.moe_path(4, 1, 8, 2, *MIXTRAL, *args) == "exact"
+
+
+@pytest.mark.parametrize("widths", [QWEN, GLM47], ids=["qwen", "glm47"])
+@pytest.mark.parametrize("path", ["list", "grouped"])
+def test_tiled_kernels_equal_the_untiled_ones(kernels_on, monkeypatch,
+                                              path, widths):
+    """Interpret mode at Qwen1.5-MoE's and GLM-4.7-Flash's widths, four
+    int8 experts: the list and the grouped kernel reading an expert in
+    2 and 4 tiles (a smaller share of VMEM makes the rule say so) give
+    what they give reading it whole. Qwen's 1408 = 11 lanes split into
+    no power of two, so the rule never tiles it: its kernels are the
+    untiled ones at any share that admits them."""
+    h, i = widths
+    E, k, L = 4, 2, 1
+    N, positions = (8, 1) if path == "list" else (160, 160)
+    ks = jax.random.split(jax.random.PRNGKey(h + i), 5)
+    x = jax.random.normal(ks[0], (N, h), jnp.float32).astype(jnp.bfloat16)
+    rw = (jax.random.normal(ks[1], (h, E), jnp.float32) * 0.05
+          ).astype(jnp.bfloat16)
+    stacks = [quant.quantize_tensor(
+        (jax.random.normal(kk, dims, jnp.float32) * 0.02
+         ).astype(jnp.bfloat16))
+        for kk, dims in zip(ks[2:], ((L, E, h, i), (L, E, h, i),
+                                     (L, E, i, h)))]
+
+    def run(share):
+        monkeypatch.setattr(moe, "_LIST_VMEM_SHARE", share)
+        tiles = moe.expert_tiles(h, i, jnp.int8, jnp.bfloat16)
+        out, work = jax.jit(lambda x, *w: moe.moe_mlp(
+            x, rw, *w, top_k=k, layer=jnp.int32(0), positions=positions,
+            exact=True if path == "list" else None))(x, *stacks)
+        return tiles, np.asarray(out.astype(jnp.float32)), work
+
+    tiles, whole, work = run(0.5)
+    assert tiles == 1
+    if widths == QWEN:
+        assert moe.expert_tiles(h, i // 2 * 2, jnp.int8, jnp.bfloat16) == 1
+        monkeypatch.setattr(moe, "_LIST_VMEM_SHARE", 0.2)
+        assert moe.expert_tiles(h, i, jnp.int8, jnp.bfloat16) == 0
+        return
+    scale = np.abs(whole).max()
+    for share, want in ((0.2, 2), (0.1, 4)):
+        tiles, tiled, tiled_work = run(share)
+        assert tiles == want
+        # the tiles' sums in float32, rounded to bfloat16 where the
+        # whole expert's are (the grouped kernel: once a tile)
+        assert np.abs(tiled - whole).max() <= 0.02 * scale
+        assert int(tiled_work.experts_read) == int(work.experts_read)
+        assert int(tiled_work.expert_rows) == int(work.expert_rows)
