@@ -323,6 +323,9 @@ class EngineEffAccounting:
         self.prefill_pad = 0
         self.prefill_dispatches = 0
         self.prefill_by_rows: Dict[int, int] = {}
+        # dispatched chunks by the attention path of the executable
+        # that ran them (ops/pallas_paged.attention_path)
+        self.prefill_chunks_by_path: Dict[str, int] = {}
         # prefill dispatches that went behind the windows in flight,
         # and those that drained the queue first, by reason
         self.prefill_behind = 0
@@ -462,13 +465,16 @@ class EngineEffAccounting:
 
     def note_prefill(self, *, bucket: int, batch: int,
                      real_tokens: int,
-                     drained: Optional[str] = None) -> None:
+                     drained: Optional[str] = None,
+                     chunks: int = 0, attention_path: str = "") -> None:
         """One prefill bucket group, dispatched at ``batch`` rows:
         ``batch * bucket`` token positions were computed;
         ``real_tokens`` were actual prompt-chunk tokens, the rest
         bucket right-padding and spare parked rows. ``drained``: why
         the device queue was emptied before it (a name of
-        DRAIN_REASONS); None where it went behind the queue."""
+        DRAIN_REASONS); None where it went behind the queue.
+        ``chunks`` prompt chunks rode in it, on the executable's
+        ``attention_path``."""
         total = batch * bucket
         path = "prefill_behind" if drained is None else "drained_" + drained
         self._cur_prefill[path] = self._cur_prefill.get(path, 0) + 1
@@ -478,6 +484,10 @@ class EngineEffAccounting:
             self.prefill_dispatches += 1
             self.prefill_by_rows[batch] = (
                 self.prefill_by_rows.get(batch, 0) + 1)
+            if chunks:
+                self.prefill_chunks_by_path[attention_path] = (
+                    self.prefill_chunks_by_path.get(attention_path, 0)
+                    + chunks)
             if drained is None:
                 self.prefill_behind += 1
             else:
@@ -724,6 +734,8 @@ class EngineEffAccounting:
                             "by_rows": {
                                 str(r): n for r, n in
                                 sorted(self.prefill_by_rows.items())},
+                            "chunks_by_path": dict(sorted(
+                                self.prefill_chunks_by_path.items())),
                             **moe_rows},
                 **moe,
                 **({"sparse": {

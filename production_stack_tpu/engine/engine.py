@@ -1211,7 +1211,9 @@ class LLMEngine:
             self.eff.note_prefill(
                 bucket=bucket, batch=rows,
                 real_tokens=sum(len(w.chunk) for w in group),
-                drained=drained)
+                drained=drained, chunks=len(group),
+                attention_path=self.runner.prefill_attention_path(
+                    bucket, kv_len))
             if self.model_cfg.index_topk:
                 # (a chunk's padding past its tokens is not counted)
                 for w in group:

@@ -885,10 +885,18 @@ class ModelRunner:
                 positions, cfg.num_heads, latent_pool_width(cfg.latent_dim),
                 self.engine_cfg.kv_block_size, mesh,
                 value_dim=cfg.kv_lora_rank,
-                selects=kv_len is not None and self.selects(kv_len))
+                selects=kv_len is not None and self.selects(kv_len),
+                head_dims=(cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                           cfg.v_head_dim))
         return attention_path(
             positions, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
             self.engine_cfg.kv_block_size, mesh)
+
+    def prefill_attention_path(self, bucket: int, kv_len: int) -> str:
+        """The attention path of the prefill executables of a chunk
+        bucket and kv bucket, whatever their rows (``_compile`` names
+        them by the same call)."""
+        return self._attention_path(bucket, self.mesh, kv_len)
 
     def _moe_path(self, rows: int, positions: int) -> str:
         """ops/moe.moe_path for this model's experts, as a forward of

@@ -470,7 +470,8 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
            kv_len: Optional[int], layer, *, window: Optional[int],
            scale: float, softcap: Optional[float],
            mesh=None, value_dim: int = 0,
-           select: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+           select: Optional[jnp.ndarray] = None,
+           expand=None) -> jnp.ndarray:
     """One layer's read: q [B,T,H,D] at ``positions`` [B,T] (contiguous
     from starts [B]) over layer ``layer`` of the pool, which already
     holds the chunk's own K/V (append, then attend) -> [B,T,H,D].
@@ -496,11 +497,23 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
     select [B, T, nb*Bs] of 0 / 1 (the latent pool;
     ``attend_selected``): a query attends only the positions marked
     for it, a mask inside the kernel (the decode kernel's for one
-    position a row, else the prefill kernel's)."""
+    position a row, else the prefill kernel's).
+
+    expand (the latent pool, where ``expands`` says so): W_kvb's two
+    halves and their scales (pallas_paged.paged_attention ``expand``);
+    q [B,T,H,nope+rope] are then the heads' own queries and the prefill
+    kernel makes each head's keys and values from the cached latents
+    -> [B,T,H,v]."""
     k_cache, v_cache, *scales = pool if len(pool) > 1 else (pool[0], None)
     Bs, MB = k_cache.shape[-2], tables.shape[1]
     nb = MB if kv_len is None else min(-(-kv_len // Bs), MB)
     T, H, D = q.shape[1:]
+    if expand is not None:      # ``expands`` chose the kernel already
+        return pallas_paged.paged_attention(
+            q, k_cache, None, tables, starts, nb=nb,
+            interpret=pallas_paged.needs_interpret(), scale=scale,
+            layer=layer, value_dim=value_dim, select=select,
+            expand=expand)
     path = pallas_paged.attention_path(T, H // k_cache.shape[2], D, Bs,
                                        mesh, value_dim=value_dim)
     if path != pallas_paged.JNP_GATHER:
@@ -539,6 +552,20 @@ def attend(q: jnp.ndarray, pool: Pool, tables: jnp.ndarray,
                                 logit_softcap=softcap, select=select)
 
 
+def expands(T: int, heads: int, latents: jnp.ndarray, value_dim: int,
+            head_dims: Tuple[int, int, int], mesh=None) -> bool:
+    """Does a forward of T positions a row over the latent pool attend
+    EXPANDED (each head's keys and values made from the cached latents
+    inside the prefill kernel) and not absorbed? pallas_paged.
+    attention_path's answer from the shapes (``expanded_cheaper``):
+    static, like the chunk bucket; engine/runner.py names the
+    executable's attention path by the same call. The pool, what is
+    cached and every shorter forward are the same either way."""
+    return "_expanded" in pallas_paged.attention_path(
+        T, heads, latents.shape[-1], latents.shape[-2], mesh,
+        value_dim=value_dim, head_dims=head_dims)
+
+
 def selects(kv_len: Optional[int], max_blocks: int, block_size: int,
             topk: int) -> bool:
     """Does a forward that reads the first ceil(kv_len/Bs) blocks of a
@@ -559,12 +586,14 @@ def attend_selected(q: jnp.ndarray, latents: jnp.ndarray,
                     tables: jnp.ndarray, starts: jnp.ndarray,
                     positions: jnp.ndarray, kv_len: Optional[int], layer,
                     *, topk: int, scale: float, value_dim: int,
-                    mesh=None) -> jnp.ndarray:
+                    mesh=None, expand=None) -> jnp.ndarray:
     """``attend`` over the latent pool for a layer that selects what it
     attends (ops/dsa.py): q [B,T,H,W] the absorbed queries, latents
     [L,N,1,Bs,W] and index [L,N,1,Bs,Di] the two pools (both already
     hold the chunk's own tokens), iq [B,T,Hi,Di] the index queries, iw
-    [B,T,Hi] fp32 their weights with the scales folded in.
+    [B,T,Hi] fp32 their weights with the scales folded in. With
+    ``expand`` (``attend``) q are the heads' own queries and the
+    prefill kernel attends expanded under the same marks.
 
     Three stages, each under its scope. ``dsa_indexer``: the row's
     index keys gathered through the tables (a sixth of the latents'
@@ -595,4 +624,5 @@ def attend_selected(q: jnp.ndarray, latents: jnp.ndarray,
     with jax.named_scope("sparse_attention"):
         return attend(q, (latents,), tables, starts, positions, kv_len,
                       layer, window=None, scale=scale, softcap=None,
-                      mesh=mesh, value_dim=value_dim, select=mask)
+                      mesh=mesh, value_dim=value_dim, select=mask,
+                      expand=expand)

@@ -249,7 +249,12 @@ def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
     those two products are q_nope's channels and o's: they multiply
     there and nothing is requantised. Without a pool (encode) it is
     the EXPANDED form, [k_nope | v] = c W_kvb per head: the two forms
-    are what tests/test_mla.py compares.
+    are what tests/test_mla.py compares. A prefill chunk of enough
+    positions a row that expanded multiplies less (models/kv.expands)
+    attends expanded over the pool too: the prefill kernel makes each
+    key panel's k_nope and v per head from the cached c (ops/
+    pallas_paged.paged_attention ``expand``); what is cached does not
+    change.
 
     With an indexer (cfg.index_topk, GLM-5; ops/dsa.py) the pool is two
     arrays, the latents and the index keys: the layer also makes the
@@ -321,12 +326,20 @@ def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
                 jnp.concatenate([q_nope, q_rope], axis=-1), k,
                 kvh[..., dn:], scale=scale)
         return attn.reshape(B, T, nh * dv), None
-    with jax.named_scope("mla_absorb_q"):
-        if quantized:
-            q_nope = (q_nope.astype(jnp.float32)
-                      * ch_scale[:, :dn]).astype(q_nope.dtype)
-        q_lat = jnp.einsum("bthd,rhd->bthr", q_nope,
-                           w_kvb[..., :dn].astype(q_nope.dtype))
+    # a chunk long enough a row attends expanded, inside the kernel
+    # (ops/pallas_paged.expanded_cheaper): the pool is the same
+    expand = None
+    if kv_pool.expands(T, nh, kv[0], r, (dn, dr, dv), mesh):
+        expand = (w_kvb[..., :dn], w_kvb[..., dn:]) + (
+            (ch_scale[:, :dn], ch_scale[:, dn:]) if quantized
+            else (None, None))
+    else:
+        with jax.named_scope("mla_absorb_q"):
+            if quantized:
+                q_nope = (q_nope.astype(jnp.float32)
+                          * ch_scale[:, :dn]).astype(q_nope.dtype)
+            q_lat = jnp.einsum("bthd,rhd->bthr", q_nope,
+                               w_kvb[..., :dn].astype(q_nope.dtype))
     # the pool's vectors are padded to whole lanes (kv.latent_pool_width):
     # zeros in the cache and in the queries, which add nothing to a score
     pad = kv[0].shape[-1] - (r + dr)
@@ -343,17 +356,21 @@ def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
         kv = latents if not indexed else latents + (
             kv_pool.append_chunk(kv[1], ik, block_tables, starts,
                                  token_valid, layer),)
+    q = (padded([q_lat, q_rope]) if expand is None
+         else jnp.concatenate([q_nope, q_rope], axis=-1))
     if selecting:
         ctx = kv_pool.attend_selected(
-            padded([q_lat, q_rope]), kv[0], kv[1], iq, iw, block_tables,
-            starts, positions, kv_len, layer, topk=cfg.index_topk,
-            scale=scale, value_dim=r, mesh=mesh)
+            q, kv[0], kv[1], iq, iw, block_tables, starts, positions,
+            kv_len, layer, topk=cfg.index_topk, scale=scale, value_dim=r,
+            mesh=mesh, expand=expand)
     else:
         with jax.named_scope("attention"):
             ctx = kv_pool.attend(
-                padded([q_lat, q_rope]), latents, block_tables, starts,
-                positions, kv_len, layer, window=None, scale=scale,
-                softcap=None, mesh=mesh, value_dim=r)     # [B,T,nh,r]
+                q, latents, block_tables, starts, positions, kv_len,
+                layer, window=None, scale=scale, softcap=None, mesh=mesh,
+                value_dim=r, expand=expand)               # [B,T,nh,r]
+    if expand is not None:      # the heads' outputs already [B,T,nh,dv]
+        return ctx.reshape(B, T, nh * dv), kv
     with jax.named_scope("mla_absorb_o"):
         attn = jnp.einsum("bthr,rhd->bthd", ctx,
                           w_kvb[..., dn:].astype(ctx.dtype))

@@ -40,6 +40,17 @@ head and one pool block's [Bs, D] K and V panels live in VMEM. Online
 and emitted at j == nb - 1 — the classic flash accumulation, with GQA
 rows flattened as t*G + g so K/V are never broadcast to query heads.
 
+The latent pool (one cached vector ``[c | k_rope]`` a token, every
+query head on it) has two cases of the prefill kernel. ABSORBED: the
+queries carry W_uk, all heads' rows share a key block, the values are
+the keys' leading columns. EXPANDED, for a chunk of enough positions a
+row that it multiplies less (``expanded_cheaper``): grid ``(B, H, NQ,
+panels)``, one head's whole chunk of queries against a panel of
+several pool blocks whose keys and values of that head are made in
+VMEM from the cached latents with the head's slice of W_kvb, once a
+(head, q block, panel); nothing per head is written to HBM and the
+pool is the same.
+
 Sharded serving: under a tp-only mesh the kernel runs inside
 ``shard_map`` over the head axis (q heads and pool heads both shard by
 tp; tables/starts replicate) — embarrassingly parallel, no collectives.
@@ -139,6 +150,36 @@ _MIN_BLOCK_Q = 16
 # probabilities and accumulator (one setting run on the chip: PERF.md)
 _SELECT_BLOCK_Q = 32
 _SELECT_PANEL_TOKENS = 512
+# the expanded case of the prefill kernel (a long chunk over the latent
+# pool, keys and values made per head from the cached latents): one
+# head's queries a q block, so the whole chunk up to this many, because
+# a key panel is expanded once a (head, q block, panel); the panel the
+# sparse case's
+_EXPAND_BLOCK_Q = 2048
+
+
+def _panel_blocks(nb: int, block_size: int) -> int:
+    """The pool blocks a grid step of the sparse and the expanded case
+    takes: as many as divide the kv bucket, up to _SELECT_PANEL_TOKENS
+    keys."""
+    return next(r for r in (8, 4, 2, 1)
+                if r * block_size <= _SELECT_PANEL_TOKENS and nb % r == 0)
+
+
+def _online_softmax(s, v, m_ref, l_ref, acc_ref) -> None:
+    """One key panel's masked scores s [rows, P] (float32) and values v
+    [P, Dv] folded into the running (max, sum, accumulator) of the
+    prefill kernels: the flash accumulation, float32, the probabilities
+    to the MXU in the values' dtype."""
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)                                # [rows, P]
+    correction = jnp.exp(m_prev - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)               # [rows, Dv]
 
 
 def _whole_pool(layer, k_pool, v_pool, k_scales, v_scales):
@@ -267,18 +308,8 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
             live = live & (jax.lax.dot_general(
                 mine, sel_ref[0, 0, 0], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32) > 0.5)
-        s = jnp.where(live, s, _NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1,
-                                            keepdims=True))
-        p = jnp.exp(s - m_new)                                # [rows, Bs]
-        correction = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_prev * correction + jnp.sum(
-            p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [rows, D]
+        _online_softmax(jnp.where(live, s, _NEG_INF), v_blk,
+                        m_ref, l_ref, acc_ref)
 
     @pl.when(j == nb - 1)
     def _emit():
@@ -289,6 +320,180 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
         out_ref[0] = out.astype(out_ref.dtype)
 
 
+def _expanded_kernel(tabs_ref, starts_ref, layer_ref, q_ref, wk_ref,
+                     wv_ref, *refs, block_q: int, block_size: int,
+                     nb: int, scale: float, value_dim: int,
+                     scaled: bool, select: bool, R: int):
+    """One (batch row, head, q block, key panel) grid step of the
+    EXPANDED case over the latent pool: the panel's keys and values of
+    this head are made from its cached latents, here, and attended per
+    head.
+
+    q_ref   [1, 1, BQ, Dq]         the head's queries [q_nope | q_rope]
+    wk_ref  [1, W, Dq]             the head's key map: a cached vector
+                                   [c | k_rope | 0] -> [k_nope | k_rope]
+                                   (W_uk on c's rows, the identity on
+                                   k_rope's)
+    wv_ref  [1, value_dim, Dv]     the head's value map W_uv
+    refs    R pool blocks [1, 1, 1, Bs, W] side by side as one panel,
+            (scaled: the maps' per-channel scales [1, 1, Dq], [1, 1, Dv]
+            fp32: int8 weights go to the MXU as the pool's dtype and
+            the scales multiply the products,) (select: the queries'
+            marks [1, BQ, R*Bs] of 0 / 1,) out [1, 1, BQ, Dv], scratch
+            m/l/acc.
+
+    Operands go to the MXU as stored (bf16), products are float32: the
+    absorbed case's precision. Per (query, key) a head pays 2 Dq + 2 Dv
+    operations where absorbed pays 2 W + 2 value_dim, and per key the
+    two maps once a q block: ``expanded_cheaper``.
+    """
+    k_refs, refs = refs[:R], refs[R:]
+    if scaled:
+        sk_ref, sv_ref, refs = refs[0], refs[1], refs[2:]
+    if select:
+        sel_ref, refs = refs[0], refs[1:]
+    out_ref, m_ref, l_ref, acc_ref = refs
+    b = pl.program_id(0)
+    qi = pl.program_id(2)
+    j = pl.program_id(3)
+    panel = R * block_size
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    first = starts_ref[b] + qi * block_q        # the block's first query
+    jmax = jax.lax.div(first + (block_q - 1), panel)
+
+    @pl.when(j <= jmax)
+    def _compute():
+        lat = (k_refs[0][0, 0, 0] if R == 1 else jnp.concatenate(
+            [ref[0, 0, 0] for ref in k_refs], axis=0))      # [P, W]
+        k = jax.lax.dot_general(
+            lat, wk_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [P, Dq]
+        v = jax.lax.dot_general(
+            lat[:, :value_dim], wv_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [P, Dv]
+        if scaled:
+            k, v = k * sk_ref[0], v * sv_ref[0]
+        k, v = k.astype(lat.dtype), v.astype(lat.dtype)
+        s = jax.lax.dot_general(
+            q_ref[0, 0].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # [BQ, P]
+        q_pos = first + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        k_pos = j * panel + jax.lax.broadcasted_iota(
+            jnp.int32, (1, panel), 1)
+        live = k_pos <= q_pos
+        if select:
+            # (the v5e compares no bf16: the marks as float32)
+            live = live & (sel_ref[0].astype(jnp.float32) > 0.5)
+        _online_softmax(jnp.where(live, s, _NEG_INF), v,
+                        m_ref, l_ref, acc_ref)
+
+    @pl.when(j == nb - 1)
+    def _emit():
+        out_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                         ).astype(out_ref.dtype)
+
+
+def _expanded_attention(q, pool, tables, starts, layer, expand, *,
+                        nb: int, block_q: int, interpret: bool,
+                        scale: float, value_dim: int, select):
+    """paged_attention's expanded case (its text): q [B, T, H, Dq] the
+    heads' queries [q_nope | q_rope], pool [L, N, 1, Bs, W] the latent
+    pool -> [B, T, H, Dv]."""
+    w_uk, w_uv, s_k, s_v = expand
+    B, T, H, D = q.shape
+    Bs, W = pool.shape[3], pool.shape[4]
+    MB = tables.shape[1]
+    dn, Dv = w_uk.shape[-1], w_uv.shape[-1]
+    dr = D - dn
+    cdt = pool.dtype
+    # the key map [H, W, Dq]: W_uk on c's rows, the identity on k_rope's
+    # (a bf16 value times 1 summed in float32 is itself), the pool's
+    # padding on rows of zeros: keys come out of ONE product, rope part
+    # in place, where a concatenation at column dn would shift lanes
+    wk = jnp.zeros((H, W, D), cdt)
+    wk = wk.at[:, :value_dim, :dn].set(w_uk.transpose(1, 0, 2).astype(cdt))
+    wk = wk.at[:, value_dim:value_dim + dr, dn:].set(jnp.eye(dr, dtype=cdt))
+    wv = w_uv.transpose(1, 0, 2).astype(cdt)                # [H, r, Dv]
+    scaled = s_k is not None
+    R = _panel_blocks(nb, Bs)
+    block_q = min(block_q or _EXPAND_BLOCK_Q, T)
+    pad_t = (-T) % block_q
+    if pad_t:
+        q = jnp.pad(q, ((0, 0), (0, pad_t), (0, 0), (0, 0)))
+    Tp = T + pad_t
+    nq = Tp // block_q
+
+    def head_index(b, h, qi, j, tabs, sts, lyr):
+        return (b, h, qi, 0)
+
+    def weight_index(b, h, qi, j, tabs, sts, lyr):
+        return (h, 0, 0)
+
+    def kv_index(b, h, qi, j, tabs, sts, lyr, i=0):
+        # blocks past the q block's last query clamp onto the last
+        # visible one; pl.when skips their arithmetic
+        jmax = jax.lax.div(sts[b] + qi * block_q + (block_q - 1), Bs)
+        jj = jnp.minimum(jnp.minimum(j * R + i, jmax), jnp.int32(MB - 1))
+        return (lyr[0], tabs[b, jnp.maximum(jj, 0)], 0, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, 1, block_q, D), head_index),
+                pl.BlockSpec((1, W, D), weight_index),
+                pl.BlockSpec((1, value_dim, Dv), weight_index)]
+    operands = [q.transpose(0, 2, 1, 3), wk, wv]
+    for i in range(R):
+        in_specs.append(pl.BlockSpec((1, 1, 1, Bs, W),
+                                     functools.partial(kv_index, i=i)))
+        operands.append(pool)
+    if scaled:
+        in_specs += [pl.BlockSpec((1, 1, D), weight_index),
+                     pl.BlockSpec((1, 1, Dv), weight_index)]
+        operands += [
+            jnp.concatenate([s_k.astype(jnp.float32),
+                             jnp.ones((H, dr), jnp.float32)],
+                            axis=-1)[:, None],
+            s_v.astype(jnp.float32)[:, None]]
+    if select is not None:
+        if pad_t:
+            select = jnp.pad(select, ((0, 0), (0, pad_t), (0, 0)))
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, R * Bs),
+            lambda b, h, qi, j, tabs, sts, lyr: (b, qi, j)))
+        operands.append(select)
+    kernel = functools.partial(
+        _expanded_kernel, block_q=block_q, block_size=Bs, nb=nb // R,
+        scale=scale, value_dim=value_dim, scaled=scaled,
+        select=select is not None, R=R)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H, nq, nb // R),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, block_q, Dv), head_index),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, Tp, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(jnp.asarray(tables, jnp.int32), jnp.asarray(starts, jnp.int32),
+      layer, *operands)
+    return out.transpose(0, 2, 1, 3)[:, :T]
+
+
 @functools.partial(jax.jit,
                    static_argnames=("nb", "block_q", "interpret",
                                     "window", "scale", "softcap",
@@ -297,7 +502,8 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
                     block_q: int = 0, interpret: bool = False,
                     k_scales=None, v_scales=None, window: int = 0,
                     scale: float = None, softcap: float = 0.0,
-                    layer=None, value_dim: int = 0, select=None):
+                    layer=None, value_dim: int = 0, select=None,
+                    expand=None):
     """Causal GQA over paged K/V, positions contiguous per row.
 
     q [B, T, H, D]; k/v pool [N, Hkv, Bs, D], or with ``layer`` (an
@@ -323,10 +529,29 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     positions each query attends, of those at or before it (learned
     sparse attention: models/kv.attend_selected). Every live block is
     still read; the marks mask the scores.
+
+    expand (the latent pool only): ``(w_uk [value_dim, H, nope], w_uv
+    [value_dim, H, Dv], their per-channel scales [H, nope], [H, Dv] or
+    None, None)``, the two halves of W_kvb: the EXPANDED case. q
+    [B, T, H, nope + rope] are then the heads' own queries
+    ``[q_nope | q_rope]``, each key panel's ``k_nope`` and ``v`` are
+    made per head from the cached ``c`` inside the kernel (int8 maps as
+    the pool's dtype, the scales on the products), every head attends
+    ``[k_nope | k_rope]`` -> [B, T, H, Dv]; the same pool, tables,
+    causal clamp and marks (``_expanded_kernel``). Which chunks take
+    it: ``attention_path``.
     """
     B, T, H, D = q.shape
     layer, k_pool, v_pool, k_scales, v_scales = _whole_pool(
         layer, k_pool, v_pool, k_scales, v_scales)
+    if expand is not None:
+        assert value_dim and not (window or softcap), \
+            "expand: the latent pool, full causal"
+        return _expanded_attention(
+            q, k_pool, tables, starts, layer, expand, nb=nb,
+            block_q=block_q, interpret=interpret,
+            scale=D ** -0.5 if scale is None else scale,
+            value_dim=value_dim, select=select)
     Hkv, Bs = k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
     MB = tables.shape[1]
@@ -337,10 +562,8 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     R = 1
     if select is not None:
         # the sparse case reads long contexts: _SELECT_BLOCK_Q queries
-        # against as many pool blocks a step as divide the kv bucket,
-        # up to _SELECT_PANEL_TOKENS keys
-        R = next(r for r in (8, 4, 2, 1)
-                 if r * Bs <= _SELECT_PANEL_TOKENS and nb % r == 0)
+        # against several pool blocks a step
+        R = _panel_blocks(nb, Bs)
         block_q = block_q or _SELECT_BLOCK_Q
     if not block_q:
         # whole chunk per q block while VMEM allows: K/V are streamed
@@ -862,9 +1085,25 @@ def mesh_tp_only(mesh) -> bool:
 JNP_GATHER = "jnp_gather"
 
 
+def expanded_cheaper(T: int, head_dim: int, value_dim: int,
+                     head_dims) -> bool:
+    """Does a forward of T query positions a row over the latent pool
+    (vectors ``head_dim`` wide as cached, ``value_dim`` of them the
+    value) multiply less with the heads' keys and values made from the
+    latents (``head_dims``: a head's nope, rope and value widths) than
+    absorbed? Operations a key and head: absorbed 2 head_dim + 2
+    value_dim a query; expanded 2 (nope + rope) + 2 v a query and
+    making the key and value, 2 value_dim (nope + v), once. At GLM's
+    widths (640 / 512; 192, 64, 256) the cut falls at 358 queries."""
+    nope, rope, v = head_dims
+    return (T * (2 * head_dim + 2 * value_dim)
+            > T * (2 * (nope + rope) + 2 * v)
+            + 2 * value_dim * (nope + v))
+
+
 def attention_path(T: int, groups: int, head_dim: int, block_size: int,
                    mesh=None, value_dim: int = 0,
-                   selects: bool = False) -> str:
+                   selects: bool = False, head_dims=None) -> str:
     """Which cached-attention implementation a forward over T query
     positions per row takes, for ``groups`` query heads per kv head.
     Decided here, by shape, BEFORE anything compiles — a kernel the
@@ -887,6 +1126,12 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
     positions than the indexer keeps): the decode kernel over the
     selected positions' latents alone, the prefill kernel under a
     mask of them (models/kv.attend_selected).
+    ``pallas_paged_latent_expanded`` (``_sparse``): a prefill chunk
+    over the latent pool long enough that making each head's keys and
+    values from the cached latents costs less than attending absorbed
+    (``expanded_cheaper``; ``head_dims``, a head's nope, rope and value
+    widths, from models/llama._mla_attention and engine/runner.py): the
+    prefill kernel's expanded case, same pool, clamp and marks.
     ``jnp_gather``: the kernel is off (PSTPU_FLASH / not a TPU), the
     chunk's working set misses VMEM (paged_viable), or the mesh shards
     the pool's block axis."""
@@ -895,9 +1140,14 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
                 min(T, _MIN_BLOCK_Q), groups, head_dim, block_size,
                 value_dim)):
             return JNP_GATHER
-        return (("pallas_paged_decode_latent" if T <= DECODE_T_MAX
-                 else "pallas_paged_latent")
-                + ("_sparse" if selects else ""))
+        if T <= DECODE_T_MAX:
+            kernel = "pallas_paged_decode_latent"
+        elif head_dims and expanded_cheaper(T, head_dim, value_dim,
+                                            head_dims):
+            kernel = "pallas_paged_latent_expanded"
+        else:
+            kernel = "pallas_paged_latent"
+        return kernel + ("_sparse" if selects else "")
     if not (flash_enabled()
             and paged_viable(T, groups, head_dim, block_size)
             and (mesh is None or mesh_tp_only(mesh))):

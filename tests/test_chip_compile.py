@@ -730,6 +730,55 @@ def test_latent_kernels_compile(topo, B, T, nb):
     assert f"%{name}" in hlo, "the trace readers find the kernel by name"
 
 
+GLM5 = dict(H=64, W=640, R=512, DN=192, DR=64, DV=256)
+
+
+@pytest.mark.parametrize("T,nb,masked", [
+    (2048, 256, True), (2048, 32, False), (512, 32, False)],
+    ids=["chunk2048-kv16384-marks", "chunk2048-kv2048", "chunk512"])
+def test_expanded_latent_prefill_kernel_compiles(topo, T, nb, masked):
+    """The prefill kernel's EXPANDED case at GLM-5's widths as the
+    long-context cell runs it: one row of 2048 positions against the
+    16 384 kv bucket under the selection's marks (and the 2048 bucket,
+    where nothing selects; and the shortest chunk past the rule's cut):
+    64 heads' queries of 256, W_kvb's halves in int8 with their
+    scales, the whole latent pool [7, 2049, 1, 64, 640]. The operation
+    keeps the name the trace readers know the prefill executables by."""
+    import re
+    g = GLM5
+    assert pallas_paged.expanded_cheaper(T, g["W"], g["R"],
+                                         (g["DN"], g["DR"], g["DV"]))
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def call(q, pool, tables, starts, layer, w_uk, w_uv, s_k, s_v, marks):
+        return pallas_paged.paged_attention(
+            q, pool, None, tables, starts, nb=nb, layer=layer,
+            scale=256 ** -0.5, value_dim=g["R"],
+            select=marks if masked else None,
+            expand=(w_uk, w_uv, s_k, s_v))
+
+    compiled = jax.jit(call).lower(
+        shape((1, T, g["H"], g["DN"] + g["DR"]), jnp.bfloat16),
+        shape((7, 2049, 1, BS, g["W"]), jnp.bfloat16),
+        shape((1, 256), jnp.int32), shape((1,), jnp.int32),
+        shape((), jnp.int32),
+        shape((g["R"], g["H"], g["DN"]), jnp.int8),
+        shape((g["R"], g["H"], g["DV"]), jnp.int8),
+        shape((g["H"], g["DN"]), jnp.float32),
+        shape((g["H"], g["DV"]), jnp.float32),
+        shape((1, T, nb * BS), jnp.bfloat16)).compile()
+    hlo = compiled.as_text()
+    calls = re.findall(r"%(paged_attention)[\w.\-]* = (\S+) custom-call\(",
+                       hlo)
+    assert len(calls) == 1, "the trace readers find the kernel by name"
+    # one head's whole chunk a q block: [1, 64, T, 256] head-major
+    assert calls[0][1].startswith(f"bf16[1,{g['H']},{T},{g['DV']}]")
+    _fits(compiled, f"expanded prefill attention T={T} nb={nb}")
+
+
 def test_latent_decode_kernel_copies_a_block_once(topo):
     """The latent decode kernel's module has ONE operand in HBM (the
     pool: no V pool beside it) and one set of VMEM slots, [2, R, 1, Bs,

@@ -224,6 +224,68 @@ def test_prefill_then_decode_agrees_with_the_reference(weights, kernels):
     assert worst(got, want) < TOLERANCE
 
 
+# a chunk of LONG positions a row is past the rule's cut at these
+# widths (the pool 256 wide, 128 of it the value: 768 operations a
+# query absorbed, 160 expanded and 2 x 128 x 64 = 16 384 a key once:
+# 26.9); CHUNK (24) is under it
+LONG = 48
+
+
+@ON
+@pytest.mark.parametrize("padded", [False, True],
+                         ids=["short-tail", "padded-tail"])
+def test_expanded_chunks_select_as_absorbed_and_the_reference(
+        monkeypatch, kernels, padded):
+    """A prompt of 100 tokens prefilled in chunks of LONG through both
+    pools: the chunks attend EXPANDED under the selection's marks (16
+    of up to 100 positions a query), across block boundaries and a
+    chunk boundary, the tail of 4 alone (absorbed) or padded to LONG. The selections (ops/dsa.tap) and the logits against
+    the same prompt with the rule switched off, the logits against the
+    reference's full forward pass."""
+    from tests.test_mla import chunked_logprobs
+    params = llama.init_params(CFG, jax.random.PRNGKey(6),
+                               quantization="int8")
+    tokens = np.random.default_rng(9).integers(0, CFG.vocab_size, (1, 100))
+    dims = (CFG.qk_nope_head_dim, CFG.qk_rope_head_dim, CFG.v_head_dim)
+    assert [pallas_paged.attention_path(
+        t, CFG.num_heads, 256, BS, value_dim=CFG.kv_lora_rank,
+        selects=True, head_dims=dims) for t in (CHUNK, LONG)] == [
+            "pallas_paged_latent_sparse",
+            "pallas_paged_latent_expanded_sparse"]
+    calls = []
+    kernel = pallas_paged.paged_attention
+
+    def counted(*a, **kw):
+        calls.append((a[0].shape[1], kw.get("expand") is not None,
+                      kw.get("select") is not None))
+        return kernel(*a, **kw)
+    monkeypatch.setattr(pallas_paged, "paged_attention", counted)
+    seen = {}
+
+    def tap(layer, positions, mask):
+        for t, row in zip(np.asarray(positions)[0],
+                          np.asarray(mask)[0] > 0):
+            seen.setdefault((int(layer), int(t)), []).append(
+                np.flatnonzero(row).tolist())
+    monkeypatch.setattr(dsa, "tap", tap)
+    expanded = chunked_logprobs(CFG, params, tokens, LONG, padded)
+    # (under marks a tail of 4 is the prefill kernel's too: absorbed)
+    assert {c for c in calls} == {(LONG, True, True)} | (
+        set() if padded else {(4, False, True)})
+    monkeypatch.setattr(pallas_paged, "expanded_cheaper",
+                        lambda *a: False)
+    jax.clear_caches()
+    absorbed = chunked_logprobs(CFG, params, tokens, LONG, padded)
+    jax.effects_barrier()
+    assert worst(expanded, absorbed) < TOLERANCE
+    real = {k: v for k, v in seen.items() if k[1] < 100}
+    assert len(real) == CFG.num_layers * 100
+    assert all(len(v) == 2 and v[0] == v[1] and 0 < len(v[0]) <= 16
+               for v in real.values())
+    want = ref.logprobs(params, hf_of(CFG), tokens[0])
+    assert worst(expanded, want) < TOLERANCE
+
+
 def test_the_served_selection_is_the_references(monkeypatch):
     """Every selection the serving path makes (ops/dsa.tap), in every
     layer, for every query of the last chunk and of the decode steps,
@@ -477,6 +539,29 @@ def test_debug_perf_counts_both_pools_and_the_selection():
                                                 * mc.num_experts) == 0
     paths = eng.runner.attention_paths
     assert paths and all(v == "jnp_gather" for v in paths.values())
+    # two chunks (32 + 18 tokens), on the one path the CPU has
+    assert totals["prefill"]["chunks_by_path"] == {"jnp_gather": 2}
+
+
+@ON
+def test_debug_perf_counts_the_chunks_by_their_attention_path(kernels):
+    """totals.prefill.chunks_by_path: two prompts of 40 tokens in
+    chunks of 32 and 8. The 32 bucket is past the rule's cut at these
+    widths (26.9) and its executables attend expanded, the 16 bucket's
+    absorbed; each chunk counts under the path of the executable that
+    ran it (device.attention_paths), with ``_sparse`` where its kv
+    bucket selects."""
+    eng = _engine(max_num_seqs=2, prefill_buckets=(16, 32))
+    _run_all(eng, [list(range(1, 41)), list(range(3, 43))], max_tokens=2)
+    by_path = eng.eff.report()["prefill"]["chunks_by_path"]
+    prefills = {k: v for k, v in eng.runner.attention_paths.items()
+                if k.startswith("prefill|")}
+    assert set(by_path) <= set(prefills.values())
+    assert {k.split("|")[1]: "_expanded" in v
+            for k, v in prefills.items()} == {"16": False, "32": True}
+    assert sum(n for p, n in by_path.items() if "_expanded" in p) == 2
+    assert sum(n for p, n in by_path.items() if "_expanded" not in p) == 2
+    assert all(p.startswith("pallas_paged_latent") for p in by_path)
 
 
 # ---------------------------------------------------------------------

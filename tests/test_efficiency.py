@@ -134,6 +134,26 @@ def test_prefill_padding_accounting():
     p = acct.report()["prefill"]
     assert p["real"] == 140 and p["pad"] == 412 + 24
     assert p["by_rows"] == {"1": 1, "8": 1}
+    assert p["chunks_by_path"] == {}
+
+
+def test_prefill_chunks_are_counted_by_their_attention_path():
+    """totals.prefill.chunks_by_path: the chunks of every dispatch
+    under the attention path of the executable that ran it."""
+    acct = EngineEffAccounting(now_fn=_Clock(1.0))
+    acct.note_prefill(
+        bucket=2048, batch=1, real_tokens=2048, chunks=1,
+        attention_path="pallas_paged_latent_expanded_sparse")
+    acct.note_prefill(
+        bucket=2048, batch=1, real_tokens=2048, chunks=1,
+        attention_path="pallas_paged_latent_expanded_sparse")
+    acct.note_prefill(bucket=128, batch=4, real_tokens=300, chunks=3,
+                      attention_path="pallas_paged_latent")
+    p = acct.report()["prefill"]
+    assert p["chunks_by_path"] == {
+        "pallas_paged_latent": 3,
+        "pallas_paged_latent_expanded_sparse": 2}
+    assert p["dispatches"] == 3
 
 
 @pytest.mark.parametrize("drained", [None, *DRAIN_REASONS])

@@ -79,6 +79,32 @@ def served_logprobs(cfg, params, tokens, pool_dtype=None) -> jnp.ndarray:
     return jax.nn.log_softmax(jnp.concatenate(out).astype(jnp.float32))
 
 
+def chunked_logprobs(cfg, params, tokens, chunk, padded=False,
+                     tap=None) -> jnp.ndarray:
+    """Log-probabilities after every position [T, V] of a prompt
+    prefilled through the pool in chunks of ``chunk``; ``padded``: the
+    last chunk padded to the chunk's length, its padding marked not
+    valid, as the engine pads a chunk to its bucket. tap(start, logits)
+    sees every chunk's forward."""
+    B, T = tokens.shape
+    MB = -(-(T + chunk) // BS)
+    cache = kv_pool.cache_for(cfg, B * MB + 1, BS, cfg.dtype)
+    tables = kv_pool.linear_tables(B, MB * BS, BS)
+    out = []
+    for start in range(0, T, chunk):
+        real = min(chunk, T - start)
+        n = chunk if padded else real
+        toks = np.zeros((B, n), tokens.dtype)
+        toks[:, :real] = tokens[:, start:start + real]
+        logits, cache, _ = llama.forward(
+            params, cfg, jnp.asarray(toks),
+            jnp.broadcast_to(jnp.arange(start, start + n), (B, n)), cache,
+            block_tables=tables,
+            token_valid=jnp.broadcast_to(jnp.arange(n) < real, (B, n)))
+        out.append(logits[0, :real])
+    return jax.nn.log_softmax(jnp.concatenate(out).astype(jnp.float32))
+
+
 def worst(a, b) -> float:
     return float(jnp.max(jnp.abs(a - b)))
 
@@ -124,6 +150,95 @@ def test_served_precision_agrees_with_the_reference():
     top = jnp.argsort(-want, axis=-1)[:, :20]
     assert worst(jnp.take_along_axis(got, top, -1),
                  jnp.take_along_axis(want, top, -1)) < SERVED_TOLERANCE
+
+
+# a chunk of LONG positions a row is past the rule's cut at these
+# widths (2 x 128 + 2 x 96 = 448 operations a query absorbed, 2 x 48 +
+# 2 x 32 = 160 expanded and 2 x 96 x 64 = 12 288 a key once: 42.7),
+# CHUNK (24) is under it
+LONG = 48
+
+
+def test_the_rule_expands_only_chunks_past_its_cut():
+    """ops/pallas_paged.attention_path at GLM's widths (the pool 640
+    wide, 512 of it the value; heads of 192 + 64 and 256): 256
+    positions a row attend absorbed, 512 and 2048 expanded (the cut is
+    358), with the selection's suffix behind either name; decode
+    windows, a caller that gives no head widths, the kernels off and a
+    pool of K and V never do."""
+    glm = dict(value_dim=512, head_dims=(192, 64, 256))
+    pallas_paged.set_flash_enabled(True)
+    try:
+        path = pallas_paged.attention_path
+        assert [path(t, 64, 640, 64, **glm) for t in
+                (1, 8, 256, 358, 359, 512, 2048)] == [
+            "pallas_paged_decode_latent"] * 2 + [
+            "pallas_paged_latent"] * 2 + [
+            "pallas_paged_latent_expanded"] * 3
+        assert path(2048, 64, 640, 64, selects=True, **glm) \
+            == "pallas_paged_latent_expanded_sparse"
+        assert path(256, 20, 640, 64, selects=True, **glm) \
+            == "pallas_paged_latent_sparse"
+        assert path(2048, 64, 640, 64, value_dim=512) \
+            == "pallas_paged_latent"
+        # K and V per kv head: the same call without a value_dim
+        assert path(512, 4, 128, 64) == "pallas_paged"
+        assert path(512, 4, 128, 64, head_dims=(192, 64, 256)) \
+            == "pallas_paged"
+        assert not pallas_paged.expanded_cheaper(358, 640, 512,
+                                                 (192, 64, 256))
+        assert pallas_paged.expanded_cheaper(359, 640, 512,
+                                             (192, 64, 256))
+        latents = jnp.zeros((1, 3, 1, 64, 640), jnp.bfloat16)
+        assert kv_pool.expands(2048, 64, latents, 512, (192, 64, 256))
+        assert not kv_pool.expands(256, 20, latents, 512, (192, 64, 256))
+        pallas_paged.set_flash_enabled(False)
+        assert path(2048, 64, 640, 64, **glm) == pallas_paged.JNP_GATHER
+        assert not kv_pool.expands(2048, 64, latents, 512, (192, 64, 256))
+    finally:
+        pallas_paged.set_flash_enabled(None)
+
+
+@pytest.mark.parametrize("kernels", [True], indirect=True,
+                         ids=["pallas_interpret"])
+@pytest.mark.parametrize("weights", [None, "int8"], ids=["plain", "int8"])
+@pytest.mark.parametrize("padded", [False, True],
+                         ids=["short-tail", "padded-tail"])
+def test_expanded_chunks_agree_with_absorbed_and_the_reference(
+        monkeypatch, kernels, weights, padded):
+    """A prompt of 100 tokens prefilled in chunks of LONG through the
+    latent pool: the chunks attend EXPANDED (the prefill kernel makes
+    each head's keys and values from the cached latents), across block
+    boundaries (16) and a chunk boundary, the third chunk's 4 tokens
+    alone (the decode kernel, absorbed, over latents that expanded
+    chunks cached) or padded to LONG (expanded, its padding not valid). Against the same
+    prompt with the rule switched off (absorbed throughout) and against
+    the reference's one full forward pass."""
+    params = llama.init_params(CFG, jax.random.PRNGKey(4),
+                               quantization=weights)
+    tokens = np.random.default_rng(7).integers(0, CFG.vocab_size, (1, 100))
+    dims = (CFG.qk_nope_head_dim, CFG.qk_rope_head_dim, CFG.v_head_dim)
+    assert [pallas_paged.attention_path(
+        t, CFG.num_heads, 128, BS, value_dim=CFG.kv_lora_rank,
+        head_dims=dims) for t in (CHUNK, LONG)] == [
+            "pallas_paged_latent", "pallas_paged_latent_expanded"]
+    calls = []
+    kernel = pallas_paged.paged_attention
+
+    def counted(*a, **kw):
+        calls.append(kw.get("expand") is not None)
+        return kernel(*a, **kw)
+    monkeypatch.setattr(pallas_paged, "paged_attention", counted)
+    expanded = chunked_logprobs(CFG, params, tokens, LONG, padded)
+    # (a tail of 4 positions is a decode window's shape: that kernel)
+    assert calls and all(calls)
+    monkeypatch.setattr(pallas_paged, "expanded_cheaper",
+                        lambda *a: False)
+    jax.clear_caches()
+    absorbed = chunked_logprobs(CFG, params, tokens, LONG, padded)
+    assert worst(expanded, absorbed) < TOLERANCE
+    want = ref.logprobs(params, hf_of(CFG), tokens[0])
+    assert worst(expanded, want) < TOLERANCE
 
 
 def test_absorbed_agrees_with_expanded_on_the_same_latents():

@@ -315,6 +315,73 @@ def test_paged_small_block_q_splits():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("dtype,int8,masked,block_q,nb_extra", [
+    (F32, False, False, 0, 0),     # the whole chunk one q block
+    (F32, True, False, 16, 1),     # q blocks of 16: T = 40 padded to 48
+    (F32, True, True, 0, 3),       # under the selection's marks
+    (F32, False, True, 16, 2),
+    (BF16, True, True, 0, 0),      # the serving dtype
+], ids=["plain", "int8-qblocks", "int8-marks", "marks-qblocks", "bf16"])
+def test_paged_expanded_latent_matches_dense(dtype, int8, masked, block_q,
+                                             nb_extra):
+    """The prefill kernel's EXPANDED case over a latent pool (a
+    shuffled pool of two layers, three rows whose chunks of 40 cross
+    block boundaries at different offsets): each head's keys and values
+    made inside the kernel from the cached [c | k_rope | 0], against
+    ops/attention.py on keys and values expanded with plain jax.numpy
+    from the gathered view. nb_extra: dead blocks past the last query
+    (the clamp); with several blocks a step where the kv bucket divides
+    (nb 8: four a step)."""
+    B, T, H, Bs = 3, 40, 4, 16
+    r, dn, dr, dv, W = 96, 32, 16, 24, 128
+    lens = [70, 33, 8]
+    key = jax.random.PRNGKey(11 + block_q)
+    ks = iter(jax.random.split(key, 8))
+    nb = -(-(max(lens) + T) // Bs) + nb_extra
+    pool = jax.random.normal(next(ks), (2, B * nb + 1, 1, Bs, W), dtype)
+    pool = pool.at[..., r + dr:].set(0)
+    perm = np.asarray(jax.random.permutation(next(ks), B * nb)) + 1
+    tables = jnp.asarray(perm.reshape(B, nb).astype(np.int32))
+    starts = jnp.asarray(lens, jnp.int32)
+    positions = starts[:, None] + jnp.arange(T)[None, :]
+    q = jax.random.normal(next(ks), (B, T, H, dn + dr), dtype)
+    if int8:
+        w = jax.random.randint(next(ks), (r, H, dn + dv), -127, 128
+                               ).astype(jnp.int8)
+        ch = jax.random.uniform(next(ks), (H, dn + dv), jnp.float32,
+                                5e-4, 2e-3)
+    else:
+        w = (0.1 * jax.random.normal(next(ks), (r, H, dn + dv))
+             ).astype(dtype)
+        ch = None
+    select = None
+    if masked:
+        marks = np.asarray(jax.random.uniform(next(ks), (B, T, nb * Bs))
+                           ) < 0.4
+        marks[np.arange(B)[:, None], np.arange(T)[None],
+              np.asarray(positions)] = True
+        select = jnp.asarray(marks, dtype)
+    scale = (dn + dr) ** -0.5
+    got = paged_attention(
+        q, pool, None, tables, starts, nb=nb, interpret=True, scale=scale,
+        layer=jnp.int32(1), value_dim=r, select=select, block_q=block_q,
+        expand=(w[..., :dn], w[..., dn:]) + (
+            (ch[:, :dn], ch[:, dn:]) if int8 else (None, None)))
+    assert got.shape == (B, T, H, dv)
+
+    lat = gather_view(pool, tables, nb, layer=1)[:, :, 0].astype(F32)
+    kvh = jnp.einsum("bsr,rhd->bshd", lat[..., :r], w.astype(F32))
+    if int8:
+        kvh = kvh * ch
+    k = jnp.concatenate([kvh[..., :dn], jnp.broadcast_to(
+        lat[:, :, None, r:r + dr], kvh.shape[:3] + (dr,))], -1)
+    want = attention_with_cache(q.astype(F32), k, kvh[..., dn:], positions,
+                                scale=scale, select=select)
+    tol = 3e-2 if dtype == BF16 else 2e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), rtol=tol, atol=tol)
+
+
 @WHOLE
 def test_paged_sharded_tp_parity(layer):
     """shard_map over the head axis on the 8-device CPU mesh matches

@@ -265,7 +265,9 @@ def main(argv=None) -> int:
            "tolerance": args.tolerance, "share": args.share,
            "attention_paths": [pallas_paged.attention_path(
                t, cfg.num_heads, cache.k.shape[-1], bs,
-               value_dim=cfg.kv_lora_rank, selects=True)
+               value_dim=cfg.kv_lora_rank, selects=True,
+               head_dims=(cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim))
                for t in (1, chunk)],
            "served_seconds": served_s, "controls": {}}
     out["served"], true = read(hf, range(R))

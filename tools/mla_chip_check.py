@@ -174,7 +174,9 @@ def main(argv=None) -> int:
            "attention_paths": [
                pallas_paged.attention_path(
                    t, cfg.num_heads, cache.k.shape[-1], BS,
-                   value_dim=cfg.kv_lora_rank) for t in (1, CHUNK)]}
+                   value_dim=cfg.kv_lora_rank,
+                   head_dims=(cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                              cfg.v_head_dim)) for t in (1, CHUNK)]}
 
     if args.prompts:
         rows = []
