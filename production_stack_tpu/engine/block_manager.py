@@ -40,7 +40,8 @@ class BlockManager:
     def __init__(self, num_blocks: int, block_size: int,
                  enable_prefix_caching: bool = False,
                  namespace: str = "", bytes_per_token: int = 0,
-                 layout: str = "kv_heads", index_bytes_per_token: int = 0):
+                 layout: str = "kv_heads", index_bytes_per_token: int = 0,
+                 state_pages: int = 0, state_bytes_per_slot: int = 0):
         if num_blocks < 2:
             raise ValueError("pool needs at least one non-trash block")
         self.num_blocks = num_blocks          # includes trash block 0
@@ -55,6 +56,18 @@ class BlockManager:
         # keys): a block id names a block of both pools, so every
         # count here (free, active, cached, reuse) is of both at once
         self.index_bytes_per_token = index_bytes_per_token
+        # the second KIND of cache (models/kv.py "State pages"): state a
+        # SEQUENCE, not a token. A model with Gated DeltaNet layers
+        # keeps one page a sequence in a pool of ``state_pages`` pages,
+        # page 0 the trash page (never handed out, as block 0); a
+        # sequence takes ONE at admission beside its blocks and gives
+        # it back with them. 0 pages: the model keeps no such state
+        self.state_pages = state_pages
+        self.state_bytes_per_slot = state_bytes_per_slot
+        self._free_pages: List[int] = list(range(state_pages - 1, 0, -1))
+        self.pages_alloc = 0
+        self.pages_freed = 0
+        self.page_alloc_failures = 0
         self.hasher = (ChunkHasher(block_size, namespace="blk|" + namespace)
                        if enable_prefix_caching else None)
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
@@ -137,13 +150,51 @@ class BlockManager:
             "usage": round(self.usage, 4),
             "allocs": self.allocs,
             "blocks_allocated": self.blocks_allocated,
-            "alloc_failures_exhausted": self.alloc_failures_exhausted,
+            # (a missing state page counts as exhaustion: nothing of
+            # that kind was allocatable)
+            "alloc_failures_exhausted": (self.alloc_failures_exhausted
+                                         + self.page_alloc_failures),
             "alloc_failures_fragmented": self.alloc_failures_fragmented,
             "cache_evictions": self.cache_evictions,
             "free_contiguity": round(self.free_contiguity(), 4),
             "defrag_runs": self.defrag_runs,
             "defrag_block_moves": self.defrag_block_moves,
+            # state pages (0 / empty where the model keeps none)
+            "state_bytes_per_slot": self.state_bytes_per_slot,
+            "state_pages": {"total": max(self.state_pages - 1, 0),
+                            "live": self.live_pages},
         }
+
+    # -- state pages -----------------------------------------------------
+
+    @property
+    def live_pages(self) -> int:
+        """State pages held by live sequences."""
+        return max(self.state_pages - 1, 0) - len(self._free_pages)
+
+    def page_counts(self) -> Dict[str, int]:
+        """The pages' counters, for ``totals.state`` of /debug/perf."""
+        return {"pages_alloc": self.pages_alloc,
+                "pages_freed": self.pages_freed,
+                "alloc_failures": self.page_alloc_failures}
+
+    def alloc_page(self) -> Optional[int]:
+        """One state page, or None when every page is held (counted
+        with the blocks' allocation failures: kv_alloc_failures reads
+        both). A model without state pages is never asked."""
+        if not self._free_pages:
+            self.page_alloc_failures += 1
+            return None
+        self.pages_alloc += 1
+        return self._free_pages.pop()
+
+    def free_page(self, page: int) -> None:
+        """Give a page back (0, "none", is ignored). What it holds is
+        left as it is: the next sequence's first chunk starts from a
+        zero state inside the layer (ops/gdn.py)."""
+        if page:
+            self._free_pages.append(page)
+            self.pages_freed += 1
 
     def free_contiguity(self) -> float:
         """Fraction of adjacent free-block-id pairs: 1.0 when the free

@@ -348,6 +348,15 @@ class EngineEffAccounting:
         # before it, those its indexer scored and those it attended;
         # ``totals.sparse`` of an engine whose model selects
         self.sparse: Dict[str, Dict[str, int]] = {}
+        # state pages (note_state; ``totals.state`` of an engine whose
+        # model has Gated DeltaNet layers): real prefill positions
+        # through the chunkwise rule and live row-steps through the
+        # recurrent one, per layer; ``state_pages`` (set by the engine)
+        # reads the block manager's page counters
+        self.scan_tokens = 0
+        self.prefill_keys = 0
+        self.step_rows = 0
+        self.state_pages = None
         # modeled HBM traffic (decode windows only — see module doc)
         self.bytes_total = 0
         self.bytes_effective = 0
@@ -500,6 +509,19 @@ class EngineEffAccounting:
         with self._lock:
             self.prefill_expert_rows += expert_rows
             self.prefill_routed_rows += routed_rows
+
+    def note_state(self, scan_tokens: int = 0, prefill_keys: int = 0,
+                   step_rows: int = 0) -> None:
+        """One dispatch of a model with state pages: the real prefill
+        positions a chunk carried through ops/gdn's chunkwise rule
+        (``prefill_keys``: the keys at or before them, summed, which
+        its attention layers' causal products run over), or the live
+        row-steps a decode window carried through the recurrent one
+        (of ONE layer: every such layer does the same)."""
+        with self._lock:
+            self.scan_tokens += scan_tokens
+            self.prefill_keys += prefill_keys
+            self.step_rows += step_rows
 
     def note_sparse(self, kind: str, first, queries: int, topk: int,
                     selects: bool) -> None:
@@ -745,6 +767,11 @@ class EngineEffAccounting:
                     **{kind: dict(row)
                        for kind, row in self.sparse.items()}}}
                    if self.sparse else {}),
+                **({"state": {**self.state_pages(),
+                              "scan_tokens": self.scan_tokens,
+                              "prefill_keys": self.prefill_keys,
+                              "step_rows": self.step_rows}}
+                   if self.state_pages is not None else {}),
                 "bytes_total": self.bytes_total,
                 "bytes_effective": self.bytes_effective,
                 "compiles_total": self.compiles_total,

@@ -233,9 +233,13 @@ class LLMEngine:
                                         engine_cfg.kv_dtype),
             bytes_per_token=self.runner.cache.bytes_per_token,
             layout=self.runner.cache.layout,
-            index_bytes_per_token=self.runner.cache.index_bytes_per_token)
-        self._tables = np.zeros((engine_cfg.max_num_seqs,
-                                 engine_cfg.max_blocks_per_seq), np.int32)
+            index_bytes_per_token=self.runner.cache.index_bytes_per_token,
+            state_pages=self.runner.cache.state_pages,
+            state_bytes_per_slot=self.runner.cache.state_bytes_per_slot)
+        # a slot's row: its blocks and, where the model keeps state a
+        # sequence, its state page as one more column (models/kv.
+        # split_tables); an empty row names trash block and trash page
+        self._tables = np.zeros(self.runner.table_shape, np.int32)
         self.scheduler.can_admit = self._try_admit
         self.scheduler.on_admit = self._on_admit
         # pool-occupancy-at-allocation histogram: the block manager
@@ -257,11 +261,11 @@ class LLMEngine:
         mc = self.model_cfg
         kv_itemsize = {"bfloat16": 2, "float32": 4,
                        "int8": 1}[engine_cfg.kv_dtype]
-        kv_pos_bytes = (2 * mc.num_layers * mc.num_kv_heads
+        kv_pos_bytes = (2 * mc.attn_layers * mc.num_kv_heads
                         * mc.head_dim_ * kv_itemsize)
         if engine_cfg.kv_dtype == "int8":
             # per-(token, head) f32 scales stream alongside the blocks
-            kv_pos_bytes += 2 * mc.num_layers * mc.num_kv_heads * 4
+            kv_pos_bytes += 2 * mc.attn_layers * mc.num_kv_heads * 4
         from jax import tree_util as _tree_util
         weight_bytes = sum(
             x.size * x.dtype.itemsize
@@ -288,6 +292,8 @@ class LLMEngine:
         # the step timeline (efficiency.STEP_PHASES): every phase of
         # step() runs under `with self._phase(name)`
         self._phase = self.eff.phase
+        if self.block_mgr.state_pages:
+            self.eff.state_pages = self.block_mgr.page_counts
         self.runner.compile_observer = self.eff
         # advertised once: the router's per-endpoint concurrency cap
         # reads this gauge (0 = unbounded admission, nothing to cap on)
@@ -1078,7 +1084,8 @@ class LLMEngine:
         sampling/history dirty; penalty counts and guided ids are
         rebuilt from the sequences at dispatch). KV never moves — a
         slot only indexes a block-table row, so the remap is two table
-        rows per moved sequence, not a cache copy."""
+        rows per moved sequence, not a cache copy (a state page is a
+        column of the row: it does not move either)."""
         running = sorted(self.scheduler.running.values(),
                          key=lambda s: s.slot)
         if not running:
@@ -1114,7 +1121,7 @@ class LLMEngine:
                     self._slot_bias_ids, self._slot_bias_vals,
                     self._slot_stop_ids, self._slot_gstate):
             arr[new] = arr[old]
-        self._set_table_row(new, seq.block_ids)
+        self._set_table_row(new, seq.block_ids, seq.state_page)
         # the next dispatch rebuilds every carry from the mirrors; park
         # AFTER copying (resets old's mirrors). The moved row's
         # sampling differs from the parked defaults park left at
@@ -1214,6 +1221,14 @@ class LLMEngine:
                 drained=drained, chunks=len(group),
                 attention_path=self.runner.prefill_attention_path(
                     bucket, kv_len))
+            if self.model_cfg.gdn_layers:
+                # a query at position p has p + 1 keys in context
+                self.eff.note_state(
+                    scan_tokens=sum(len(w.chunk) for w in group),
+                    prefill_keys=sum(
+                        len(w.chunk) * w.start
+                        + len(w.chunk) * (len(w.chunk) + 1) // 2
+                        for w in group))
             if self.model_cfg.index_topk:
                 # (a chunk's padding past its tokens is not counted)
                 for w in group:
@@ -1618,6 +1633,8 @@ class LLMEngine:
         plain = all(s.options.top_p >= 1.0 and not s.options.top_k
                     and not s.options.min_p
                     for s in decode_seqs)
+        if self.model_cfg.gdn_layers:
+            self.eff.note_state(step_rows=W * len(decode_seqs))
         if self.model_cfg.index_topk:
             self.eff.note_sparse(
                 "decode", [s.next_position + joined.get(s.seq_id, ahead)
@@ -2237,6 +2254,9 @@ class LLMEngine:
             "pallas_attention": pallas_paged.mode(),
             "attention_paths": dict(self.runner.attention_paths),
             "moe_paths": dict(self.runner.moe_paths),
+            # ops/gdn.gdn_path of each executable of a model with Gated
+            # DeltaNet layers (empty on every other)
+            "mixer_paths": dict(self.runner.mixer_paths),
         }
 
     def load_report(self) -> Dict[str, object]:
@@ -2279,6 +2299,8 @@ class LLMEngine:
             # headroom is exactly the stranded capacity it reclaims.
             "kv_pool": self.block_mgr.frag_report(),
         }
+        if self.block_mgr.state_pages:
+            report["state_pages_live"] = self.block_mgr.live_pages
         if self.connector is not None:
             # tier hit/miss/bytes counters (all in-memory totals — no
             # I/O): the cache-aware router scores endpoints on these,
@@ -2312,6 +2334,12 @@ class LLMEngine:
         if fresh is None:
             self.block_mgr.free(shared)   # unpin; retry next iteration
             return False
+        if self.block_mgr.state_pages:
+            # admission counts pages as it counts blocks: all or nothing
+            seq.state_page = self.block_mgr.alloc_page() or 0
+            if not seq.state_page:
+                self.block_mgr.free(shared + fresh)
+                return False
         seq.block_ids = shared + fresh
         seq.num_prefilled = covered       # capped at len-1, full blocks
         return True
@@ -2320,7 +2348,7 @@ class LLMEngine:
         """Scheduler hook (slot now assigned): point the slot's table
         row at the sequence's blocks, then let the KV tiers inject any
         deeper cached prefix (host/disk/remote, kvcache/connector.py)."""
-        self._set_table_row(seq.slot, seq.block_ids)
+        self._set_table_row(seq.slot, seq.block_ids, seq.state_page)
         pf = seq.kv_prefetch
         seq.kv_prefetch = None   # release host buffers either way
         if pf is None:
@@ -2338,8 +2366,11 @@ class LLMEngine:
             # finish
             self.connector.mark_seen(pf.keys)
 
-    def _set_table_row(self, slot: int, block_ids) -> None:
+    def _set_table_row(self, slot: int, block_ids,
+                       state_page: int = 0) -> None:
         self._tables[slot, :] = 0
+        if state_page:      # the row's last column (models/kv.py)
+            self._tables[slot, -1] = state_page
         if block_ids:
             # rolled entries are None placeholders -> trash block 0
             # (never read: every attention path skips blocks behind the
@@ -2350,9 +2381,11 @@ class LLMEngine:
 
     def _free_seq_blocks(self, seq: Sequence) -> None:
         """Release a sequence's live blocks (rolled entries are None
-        placeholders, already freed)."""
+        placeholders, already freed) and, with them, its state page."""
         self.block_mgr.free([b for b in seq.block_ids if b])
         seq.block_ids = []
+        self.block_mgr.free_page(seq.state_page)
+        seq.state_page = 0
 
     def _roll_windows(self, decode_seqs) -> None:
         """Free blocks every future query of a windowed sequence can no
@@ -2376,7 +2409,7 @@ class LLMEngine:
             for i in range(s.rolled_blocks, keep_from):
                 s.block_ids[i] = None
             s.rolled_blocks = keep_from
-            self._set_table_row(s.slot, s.block_ids)
+            self._set_table_row(s.slot, s.block_ids, s.state_page)
 
     def _ensure_blocks(self, seq: Sequence, upto_tokens: int,
                        allow_preempt: bool = True) -> bool:
@@ -2391,7 +2424,8 @@ class LLMEngine:
             fresh = self.block_mgr.alloc(need - len(seq.block_ids))
             if fresh is not None:
                 seq.block_ids.extend(fresh)
-                self._set_table_row(seq.slot, seq.block_ids)
+                self._set_table_row(seq.slot, seq.block_ids,
+                                    seq.state_page)
                 return True
             if not allow_preempt:
                 return False

@@ -52,6 +52,7 @@ from production_stack_tpu.models.kv import (KV_HEADS, KVCache, cache_for,
                                             latent_pool_width)
 from production_stack_tpu.models import llama
 from production_stack_tpu.ops import moe
+from production_stack_tpu.ops.gdn import gdn_path
 from production_stack_tpu.ops.pallas_paged import JNP_GATHER, attention_path
 from production_stack_tpu.ops.rope import rope_table
 from production_stack_tpu.utils import init_logger
@@ -142,17 +143,20 @@ class ModelRunner:
                 f"model that selects what it attends (index_topk "
                 f"{model_cfg.index_topk}): the selection is built for "
                 f"one query position a row and for prefill chunks")
+        if model_cfg.gdn_layers:
+            self._refuse_with_state_pages(lora_stacked)
         # K and V per kv head, or the latent pool, with the index pool
-        # beside it where the model selects what it attends
-        # (models/kv.cache_for; it refuses an int8 latent pool by name)
+        # beside it where the model selects what it attends, or K and V
+        # of the attention layers with a state page a slot (and the
+        # trash page) beside them where the model has Gated DeltaNet
+        # layers (models/kv.cache_for; it refuses an int8 latent pool
+        # and an int8 pool beside state pages by name)
         self.cache: KVCache = cache_for(
-            model_cfg, n_blocks, engine_cfg.kv_block_size, dtype=kv_dt)
-        self._tables = jnp.zeros(
-            (engine_cfg.max_num_seqs, engine_cfg.max_blocks_per_seq),
-            jnp.int32)
-        self._tables_host = np.zeros(
-            (engine_cfg.max_num_seqs, engine_cfg.max_blocks_per_seq),
-            np.int32)
+            model_cfg, n_blocks, engine_cfg.kv_block_size, dtype=kv_dt,
+            state_pages=(engine_cfg.max_num_seqs + 1
+                         if model_cfg.gdn_layers else 0))
+        self._tables = jnp.zeros(self.table_shape, jnp.int32)
+        self._tables_host = np.zeros(self.table_shape, np.int32)
         self._tables_dirty = False
         if mesh is not None:
             # tensor-parallel serving: weights/cache sharded over the
@@ -266,6 +270,9 @@ class ModelRunner:
         # the same key -> the strategy its experts take (ops/moe
         # moe_path); empty on a dense model
         self.moe_paths: Dict[str, str] = {}
+        # the same key -> the implementation its Gated DeltaNet layers
+        # take (ops/gdn.gdn_path); empty on a model without them
+        self.mixer_paths: Dict[str, str] = {}
         # per-batch-bucket sliced views of the sampling params and
         # block tables (invalidated when the source object changes):
         # batch-bucketed dispatches must not pay a 14-array re-slice
@@ -279,6 +286,51 @@ class ModelRunner:
         self._embed_fns = {}
         # prompt-logprobs (echo) path, cached per (batch, padded length)
         self._prompt_lp_fns = {}
+
+    @property
+    def table_shape(self) -> Tuple[int, int]:
+        """[slots, columns] of the table rows: a slot's blocks and,
+        where the model keeps state a sequence, its state page as the
+        last column (models/kv.split_tables)."""
+        return (self.engine_cfg.max_num_seqs,
+                self.engine_cfg.max_blocks_per_seq
+                + bool(self.model_cfg.gdn_layers))
+
+    def _refuse_with_state_pages(self, lora_stacked) -> None:
+        """What a model with state pages (Gated DeltaNet layers: state
+        a sequence, models/kv.py) cannot run with yet, each refused by
+        name at start with its reason."""
+        name, ecfg, mesh = self.model_cfg.name, self.engine_cfg, self.mesh
+        refused = [
+            (ecfg.enable_prefix_caching, "prefix caching "
+             "(--enable-prefix-caching)", "a prefix hit restores a "
+             "sequence's blocks, not the state its layers had reached "
+             "at the prefix's end"),
+            (bool(ecfg.kv_transfer_config), "the KV connector "
+             "(--kv-transfer-config: tiering, extract_chunk / "
+             "inject_chunk, migrate_out, disaggregated handoff)",
+             "a chunk on the wire carries K and V, and no snapshot of "
+             "the state pages exists yet"),
+            (bool(ecfg.speculative_ngram_tokens), "n-gram speculation "
+             "(--speculative-ngram-tokens)", "a rejected draft has "
+             "already advanced the state, and there is no rollback"),
+            (mesh is not None and any(
+                size > 1 for size in mesh.shape.values()),
+             f"a mesh ({dict(mesh.shape) if mesh is not None else {}}: "
+             f"tp, ep, dp must be 1)", "the state pool and the Gated "
+             "DeltaNet kernels run on one chip only"),
+            (lora_stacked is not None or bool(ecfg.lora_adapters),
+             "LoRA adapters", "the adapters' projections are those of "
+             "an attention layer"),
+            (bool(ecfg.checkpoint), "the checkpoint loader "
+             "(--checkpoint)", "the published tensors' names and the "
+             "grouped columns of in_proj_qkvz are not mapped yet"),
+        ]
+        for on, what, why in refused:
+            if on:
+                raise ValueError(
+                    f"{name}: {what} is not supported on a model with "
+                    f"state pages (KV pool layout 'kv+state'): {why}")
 
     def set_lora(self, lora_stacked, lora_scaling: float = None) -> None:
         """Swap the stacked adapter pytree in place (runtime adapter
@@ -962,6 +1014,8 @@ class ModelRunner:
         self.attention_paths[name] = path
         if self.model_cfg.num_experts:
             self.moe_paths[name] = self._moe_path(batch, positions)
+        if self.model_cfg.gdn_layers:
+            self.mixer_paths[name] = gdn_path(positions)
         return fn
 
     def prefill(self, tokens, starts, lengths, sampling: SamplingParams,
@@ -1146,13 +1200,14 @@ class ModelRunner:
 
     def _refuse_latent(self, what: str) -> None:
         """KV chunks on the wire are K and V per kv head
-        [L, size, Hkv, D]; the latent pool holds neither."""
+        [L, size, Hkv, D]; the latent pool holds neither, and a
+        sequence's state pages are no part of them."""
         if self.cache.layout != KV_HEADS:
             raise ValueError(
                 f"{what}: the KV pool of {self.model_cfg.name} has the "
                 f"layout {self.cache.layout!r} (one [c | k_rope] vector "
-                f"a token), which KV chunks [L, size, Hkv, D] cannot "
-                f"carry")
+                f"a token, or state pages beside K and V), which KV "
+                f"chunks [L, size, Hkv, D] cannot carry")
 
     def extract_chunk(self, slot: int, start: int, size: int):
         """Gather [L, size, Hkv, D] k/v out of a slot's blocks (no

@@ -104,6 +104,9 @@ class Sequence:
     # follow. Rolled (sliding-window-freed) entries are None
     # placeholders so virtual indexing stays stable.
     block_ids: List[int] = field(default_factory=list)
+    # the sequence's state page, where the model keeps state a sequence
+    # (engine/block_manager.py); 0: none
+    state_page: int = 0
     # blocks freed behind the sliding window (engine._roll_windows);
     # prefix registration is skipped once any block rolled
     rolled_blocks: int = 0

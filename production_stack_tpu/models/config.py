@@ -10,7 +10,7 @@ architecture is first-class so the engine can build/shard/jit it.
 import dataclasses
 import json
 import os
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -136,6 +136,33 @@ class ModelConfig:
     # give for the tokens routed to them (ops/moe.moe_mlp)
     router_experts: int = 0
     expert_offset: int = 0
+    # the layer pattern: the token mixer of each layer of one PERIOD,
+    # which the layer scan runs as its unit (models/llama.forward).
+    # () is a period of one attention layer: every model but the
+    # hybrid. "gdn" is a Gated DeltaNet layer (ops/gdn.py): per
+    # sequence and layer gdn_value_heads matrices of gdn_key_dim x
+    # gdn_value_dim and the last gdn_conv - 1 inputs of a depthwise
+    # causal convolution over its q, k and v channels, whatever the
+    # context: a state page (models/kv.py), not blocks
+    layer_pattern: Tuple[str, ...] = ()
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
+    # gated attention (Qwen3-Next): q_proj twice as wide, a head's
+    # query and an output gate; RMSNorm on each head's q and k; the
+    # rotary embedding on the leading rotary_dim columns (0: all)
+    attn_gate: bool = False
+    qk_norm: bool = False
+    rotary_dim: int = 0
+    # int8 weights: a projection's float32 sums times its float32
+    # scales, rounded once (models/quant.dequant_matmul exact_scale),
+    # where every other model rounds the sums, the scales and their
+    # product. The hybrid's 24 layers of two projections and a
+    # three-matmul shared expert each read the logit probe at its
+    # limit without it (0.10-0.35 of 0.3 on the chip: PERF.md, PR 42)
+    exact_dequant_scale: bool = False
     dtype: Any = jnp.bfloat16
 
     @property
@@ -150,7 +177,44 @@ class ModelConfig:
     def rope_dim_(self) -> int:
         """Width the rotary embedding turns: the whole head, or the
         rope part of a latent-attention head."""
-        return self.qk_rope_head_dim if self.mla else self.head_dim_
+        if self.mla:
+            return self.qk_rope_head_dim
+        return self.rotary_dim or self.head_dim_
+
+    @property
+    def pattern_(self) -> Tuple[str, ...]:
+        """The mixers of one period of layers, in order."""
+        return self.layer_pattern or ("attn",)
+
+    @property
+    def num_periods(self) -> int:
+        return self.num_layers // len(self.pattern_)
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers that keep K and V (or latents) a token: the KV pool's
+        leading axis."""
+        return self.num_periods * self.pattern_.count("attn")
+
+    @property
+    def gdn_layers(self) -> int:
+        """Layers that keep a state a sequence: the state pool's."""
+        return self.num_periods * self.pattern_.count("gdn")
+
+    @property
+    def gdn_channels(self) -> int:
+        """Channels of a Gated DeltaNet layer's convolution: q, k, v."""
+        return (2 * self.gdn_key_heads * self.gdn_key_dim
+                + self.gdn_value_heads * self.gdn_value_dim)
+
+    @property
+    def state_bytes_per_seq(self) -> int:
+        """Bytes of one state page, all layers: the float32 matrices
+        and the convolution's bfloat16 inputs (0: no such layer)."""
+        return self.gdn_layers * (
+            4 * self.gdn_value_heads * self.gdn_key_dim
+            * self.gdn_value_dim + 2 * (self.gdn_conv - 1)
+            * self.gdn_channels)
 
     @property
     def latent_dim(self) -> int:
@@ -196,14 +260,24 @@ class ModelConfig:
                 attn += (qr * hi * di + h * di + 2 * di      # q, k, norm
                          + h * hi)                           # head weights
         else:
-            attn = (h * (nh * hd)                        # q
+            attn = (h * (nh * hd) * (2 if self.attn_gate else 1)  # q(, gate)
                     + 2 * h * (self.num_kv_heads * hd)   # k, v
-                    + (nh * hd) * h)                     # o
-        per_layer = attn + 2 * h                         # + norms
+                    + (nh * hd) * h                      # o
+                    + (2 * hd if self.qk_norm else 0))
         emb = v * h * (1 if self.tie_word_embeddings else 2)
         Ld = self.first_dense_layers if E else 0
-        return (self.num_layers * per_layer + Ld * dense
-                + (self.num_layers - Ld) * mlp + emb + h)
+        rest = Ld * dense + (self.num_layers - Ld) * mlp + emb + h
+        if self.gdn_layers:
+            hv, dv = self.gdn_value_heads, self.gdn_value_dim
+            gdn = (h * (self.gdn_channels + hv * dv)     # q, k, v, z
+                   + h * 2 * hv                          # b, a
+                   + self.gdn_channels * self.gdn_conv   # convolution
+                   + hv * dv * h                         # out
+                   + 2 * hv + dv)                        # A_log, dt, norm
+            return (self.gdn_layers * gdn + self.attn_layers * attn
+                    + self.num_layers * 2 * h + rest)
+        per_layer = attn + 2 * h                         # + norms
+        return self.num_layers * per_layer + rest
 
     @staticmethod
     def from_hf_config(cfg: Dict[str, Any], name: str = "",
@@ -214,8 +288,9 @@ class ModelConfig:
         (adds q/k/v biases), Gemma (GeGLU via gelu, scaled embeddings,
         unit-offset RMSNorm, tied embeddings), Gemma-2, Mixtral,
         Qwen2-MoE, GLM-4.7-Flash (``glm4_moe_lite``) and GLM-5
-        (``glm_moe_dsa``), both through _glm4_moe_lite. Keys the mapping
-        does not know are ignored.
+        (``glm_moe_dsa``), both through _glm4_moe_lite, and Qwen3-Next
+        (``qwen3_next``, _qwen3_next). Keys the mapping does not know
+        are ignored.
         """
         archs = cfg.get("architectures") or []
         arch = archs[0] if archs else ""
@@ -236,6 +311,8 @@ class ModelConfig:
                                    "GlmMoeDsaForCausalLM"))
         is_llama_like = (model_type in ("llama", "mistral") or arch in
                          ("LlamaForCausalLM", "MistralForCausalLM"))
+        if model_type == "qwen3_next" or arch == "Qwen3NextForCausalLM":
+            return _qwen3_next(cfg, name, dtype)
         if not (is_qwen2 or is_gemma or is_gemma2 or is_mixtral
                 or is_qwen2_moe or is_glm_lite
                 or is_llama_like) and (model_type or arch):
@@ -243,7 +320,7 @@ class ModelConfig:
                 f"unsupported model family (model_type={model_type!r}, "
                 f"architecture={arch!r}); supported: llama, mistral, "
                 f"qwen2, gemma, gemma2, mixtral, qwen2_moe, "
-                f"glm4_moe_lite, glm_moe_dsa")
+                f"glm4_moe_lite, glm_moe_dsa, qwen3_next")
         if is_glm_lite:
             return _glm4_moe_lite(cfg, name, dtype)
         if is_qwen2_moe:
@@ -346,15 +423,7 @@ def _glm4_moe_lite(cfg: Dict[str, Any], name: str,
         raise ValueError(f"{family} with attention_bias is not "
                          f"supported")
     held = cfg["n_routed_experts"]
-    deployment = cfg.get("deployment") or {}
-    router_experts = deployment.get("router_experts", held)
-    chips = deployment.get("chips_per_layer", 1)
-    chip = deployment.get("chip_index", 0)
-    if held * chips != router_experts or not 0 <= chip < chips:
-        raise ValueError(
-            f"{family}: a deployment of {chips} chips a layer, each "
-            f"holding {held} experts, does not make the router's "
-            f"{router_experts} (chip_index {chip})")
+    router_experts, expert_offset = _deployment(cfg, family, held)
     topk = cfg.get("index_topk", 0)
     if topk and not (cfg.get("index_n_heads") and cfg.get("index_head_dim")):
         raise ValueError(f"{family}: index_topk without index_n_heads "
@@ -383,8 +452,7 @@ def _glm4_moe_lite(cfg: Dict[str, Any], name: str,
             or (rope_params if "rope_type" in rope_params else None)),
         tie_word_embeddings=cfg.get("tie_word_embeddings", False),
         num_experts=held,
-        router_experts=router_experts if chips > 1 else 0,
-        expert_offset=chip * held,
+        router_experts=router_experts, expert_offset=expert_offset,
         index_n_heads=cfg.get("index_n_heads", 0) if topk else 0,
         index_head_dim=cfg.get("index_head_dim", 0) if topk else 0,
         index_topk=topk,
@@ -404,6 +472,107 @@ def _glm4_moe_lite(cfg: Dict[str, Any], name: str,
         qk_nope_head_dim=cfg["qk_nope_head_dim"],
         qk_rope_head_dim=cfg["qk_rope_head_dim"],
         v_head_dim=cfg["v_head_dim"],
+        dtype=dtype,
+    )
+
+
+def _deployment(cfg: Dict[str, Any], family: str, held: int):
+    """(router_experts, expert_offset) of a file's ``deployment``
+    ({"router_experts", "chips_per_layer", "chip_index"}): the chip
+    holds ``held`` experts of the router's, from chip_index x held
+    on. No deployment: (0, 0), nothing divided."""
+    deployment = cfg.get("deployment") or {}
+    router_experts = deployment.get("router_experts", held)
+    chips = deployment.get("chips_per_layer", 1)
+    chip = deployment.get("chip_index", 0)
+    if held * chips != router_experts or not 0 <= chip < chips:
+        raise ValueError(
+            f"{family}: a deployment of {chips} chips a layer, each "
+            f"holding {held} experts, does not make the router's "
+            f"{router_experts} (chip_index {chip})")
+    return (router_experts if chips > 1 else 0), chip * held
+
+
+def _qwen3_next(cfg: Dict[str, Any], name: str, dtype: Any) -> ModelConfig:
+    """Qwen3-Next (``qwen3_next``): of every ``full_attention_interval``
+    layers the last is gated softmax attention (a head's query and an
+    output gate from one q_proj, zero-centred RMSNorm on each head's q
+    and k, the rotary embedding on the leading ``partial_rotary_factor``
+    of the head) and the others Gated DeltaNet (``linear_*``); every
+    layer a softmax-routed mixture of experts with one gated shared
+    expert; every RMSNorm zero-centred (``norm(x) * (1 + w)``). What
+    the tree does not build is refused by name; the multi-token-
+    prediction block is not built, as HF's class drops ``mtp.*``. A
+    file may state the chip's share of the experts (``deployment``, as
+    _glm4_moe_lite)."""
+    family = "qwen3_next"
+    interval = cfg.get("full_attention_interval", 4)
+    layers = cfg["num_hidden_layers"]
+    types = cfg.get("layer_types")
+    pattern = ("gdn",) * (interval - 1) + ("attn",)
+    if types is not None and list(types) != [
+            {"gdn": "linear_attention", "attn": "full_attention"}[
+                pattern[i % interval]] for i in range(layers)]:
+        raise ValueError(f"{family}: layer_types that are not "
+                         f"full_attention_interval's pattern are not "
+                         f"supported")
+    if interval < 2 or layers % interval:
+        raise ValueError(
+            f"{family}: num_hidden_layers {layers} is not whole periods "
+            f"of full_attention_interval {interval} (at least 2)")
+    if cfg.get("decoder_sparse_step", 1) != 1 or cfg.get("mlp_only_layers"):
+        raise ValueError(f"{family} with dense interleaving "
+                         f"(decoder_sparse_step != 1 or mlp_only_layers) "
+                         f"is not supported: every layer must be sparse")
+    for key, refused in (("attention_bias", True),
+                         ("use_sliding_window", True),
+                         ("tie_word_embeddings", True)):
+        if cfg.get(key, False) is refused:
+            raise ValueError(f"{family} with {key} is not supported")
+    if cfg.get("rope_scaling"):
+        raise ValueError(f"{family} with rope_scaling is not supported")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"{family} hidden_act "
+                         f"{cfg['hidden_act']!r} is not supported")
+    hk, hv = cfg["linear_num_key_heads"], cfg["linear_num_value_heads"]
+    if hv % hk:
+        raise ValueError(f"{family}: linear_num_value_heads {hv} is "
+                         f"not a multiple of linear_num_key_heads {hk}")
+    head_dim = cfg.get("head_dim") or (cfg["hidden_size"]
+                                       // cfg["num_attention_heads"])
+    rotary = int(head_dim * cfg.get("partial_rotary_factor", 1.0))
+    router_experts, offset = _deployment(cfg, family, cfg["num_experts"])
+    return ModelConfig(
+        name=name or cfg.get("_name_or_path", "hf-model"),
+        vocab_size=cfg["vocab_size"],
+        hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg.get("intermediate_size",
+                                  cfg["moe_intermediate_size"]),
+        num_layers=layers,
+        num_heads=cfg["num_attention_heads"],
+        num_kv_heads=cfg["num_key_value_heads"],
+        head_dim=head_dim,
+        rope_theta=cfg.get("rope_theta", 10000.0),
+        rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        rms_norm_offset=True,
+        num_experts=cfg["num_experts"],
+        router_experts=router_experts, expert_offset=offset,
+        num_experts_per_tok=cfg["num_experts_per_tok"],
+        norm_topk_prob=cfg.get("norm_topk_prob", True),
+        moe_intermediate_size=cfg["moe_intermediate_size"],
+        shared_expert_size=cfg.get("shared_expert_intermediate_size", 0),
+        moe_naming="qwen2",
+        routed_down_init_std=(cfg.get("assumed") or {}).get(
+            "routed_down_init_std"),
+        layer_pattern=pattern,
+        gdn_key_heads=hk, gdn_value_heads=hv,
+        gdn_key_dim=cfg["linear_key_head_dim"],
+        gdn_value_dim=cfg["linear_value_head_dim"],
+        gdn_conv=cfg.get("linear_conv_kernel_dim", 4),
+        attn_gate=True, qk_norm=True,
+        rotary_dim=0 if rotary == head_dim else rotary,
+        exact_dequant_scale=True,
         dtype=dtype,
     )
 
@@ -535,6 +704,22 @@ PRESETS: Dict[str, ModelConfig] = {
         qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
         index_n_heads=4, index_head_dim=32, index_topk=16,
         router_experts=8, expert_offset=4,
+    ),
+    # Tiny Qwen3-Next-style hybrid for CPU tests (``qwen3_next``): two
+    # periods of three Gated DeltaNet layers and one gated attention
+    # layer (a quarter of the head turned), 8 softmax-routed experts
+    # top-2 with a gated shared expert, zero-centred norms. Heads of
+    # 128 where the kernels (interpret mode) want whole lanes
+    "debug-gdn": ModelConfig(
+        name="debug-gdn", vocab_size=512, hidden_size=128,
+        intermediate_size=128, num_layers=8, num_heads=4, num_kv_heads=2,
+        head_dim=128, max_position_embeddings=512, rms_norm_eps=1e-6,
+        rms_norm_offset=True, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=128, shared_expert_size=128,
+        moe_naming="qwen2", layer_pattern=("gdn", "gdn", "gdn", "attn"),
+        gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=128,
+        gdn_value_dim=128, gdn_conv=4, attn_gate=True, qk_norm=True,
+        rotary_dim=32, exact_dequant_scale=True,
     ),
     # GLM-4.7-Flash (glm4_moe_lite, 30B-A3B): latent attention, one
     # leading dense layer of width 10240, 64 sigmoid-routed experts

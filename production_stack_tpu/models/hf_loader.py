@@ -167,4 +167,10 @@ def read_state_dict(path: str) -> Dict[str, Any]:
 
 def load_checkpoint(cfg: ModelConfig, path: str) -> Dict:
     """Load params from an HF checkpoint directory on disk."""
+    if cfg.layer_pattern:
+        raise ValueError(
+            f"{cfg.name}: the checkpoint loader is not supported on a "
+            f"model with two kinds of mixer (state pages): the "
+            f"published tensors' names and the grouped columns of "
+            f"in_proj_qkvz are not mapped yet")
     return params_from_state_dict(cfg, read_state_dict(path))

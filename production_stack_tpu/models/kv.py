@@ -85,6 +85,25 @@ TPU-first invariants:
   keeps the ``index_topk`` best and attends those positions' latents
   alone.
 
+- **State pages** (Gated DeltaNet layers: Qwen3-Next, ops/gdn.py). A
+  second KIND of cache: state a SEQUENCE, not a token. Such a layer
+  keeps, a sequence, ``gdn_value_heads`` float32 matrices ``[Dk, Dv]``
+  and the last ``gdn_conv - 1`` inputs of its convolution, whatever
+  the context: ``state [Lg, P, Hv, Dk, Dv]`` float32 and ``conv [Lg,
+  P, taps - 1, Ch]`` in the pool's dtype, beside the K/V pool of the
+  model's attention layers (``KVCache.layout`` "kv+state"), P =
+  ``max_num_seqs + 1`` pages. engine/block_manager.py hands a sequence
+  ONE page at admission and takes it back with its blocks; the page's
+  id rides as one more column of the sequence's block-table row
+  (``split_tables``), so a slot move rewrites a row and nothing is
+  copied. **Page 0 is the trash page**, as block 0 is the trash block:
+  never allocated, named by every empty table row, written by rows
+  that are not real. The same carried buffers: a step program's layer
+  loop carries ``(state, conv)`` next to the K/V arrays
+  (``state_carried``) and each layer updates its rows' pages in place.
+  A chunk whose first position is 0 starts from a zero state inside
+  the layer: no page is ever cleared.
+
 The reference stack's KV management is configuration around LMCache env
 vars (reference: helm/templates/deployment-vllm-multi.yaml:154-178) and
 its engine's paged KV lives inside vLLM (the stack passes
@@ -104,6 +123,7 @@ from production_stack_tpu.ops.attention import attention_with_cache
 LATENT = "latent"        # KVCache.layout: [c | k_rope], no v
 LATENT_INDEX = "latent+index"   # and the indexer's key in its own pool
 KV_HEADS = "kv_heads"     # separate K and V per kv head
+KV_STATE = "kv+state"     # and a state page a sequence beside them
 
 
 class KVCache(NamedTuple):
@@ -117,6 +137,10 @@ class KVCache(NamedTuple):
     # learned sparse attention only: the index pool [L, N, 1, Bs, Di]
     # beside the latent pool, under the same block tables
     idx: Optional[jnp.ndarray] = None
+    # Gated DeltaNet layers only: the state pages [Lg, P, Hv, Dk, Dv]
+    # float32 and the convolutions' inputs [Lg, P, taps - 1, Ch]
+    state: Optional[jnp.ndarray] = None
+    conv: Optional[jnp.ndarray] = None
 
     @property
     def num_blocks(self) -> int:
@@ -134,15 +158,30 @@ class KVCache(NamedTuple):
     def layout(self) -> str:
         if self.idx is not None:
             return LATENT_INDEX
+        if self.state is not None:
+            return KV_STATE
         return LATENT if self.v is None else KV_HEADS
 
     @property
     def bytes_per_token(self) -> int:
         """Bytes one token takes in the pools, all layers, as allocated
-        (payload and, int8, scales; the index pool's keys too)."""
+        (payload and, int8, scales; the index pool's keys too; not the
+        state pages, which a sequence takes whatever its tokens)."""
         tokens = self.num_blocks * self.block_size
         return sum(a.dtype.itemsize * (a.size // tokens)
-                   for a in self if a is not None)
+                   for a in self.carried())
+
+    @property
+    def state_pages(self) -> int:
+        """Pages of the state pool, the trash page 0 among them (0: the
+        model keeps no state a sequence)."""
+        return 0 if self.state is None else self.state.shape[1]
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes one sequence's state page takes, all layers."""
+        return sum(a.dtype.itemsize * (a.size // a.shape[1])
+                   for a in self.state_carried())
 
     @property
     def index_bytes_per_token(self) -> int:
@@ -152,15 +191,26 @@ class KVCache(NamedTuple):
         return self.idx.dtype.itemsize * (
             self.idx.size // (self.num_blocks * self.block_size))
 
-    def carried(self) -> "Pool":
-        """The arrays a step program's layer loop carries: those that
-        are there, in the fields' order."""
-        return tuple(a for a in self if a is not None)
+    def _per_token(self):
+        """(name, array) of the fields that hold state a TOKEN."""
+        return [(n, getattr(self, n)) for n in ("k", "v", "ks", "vs", "idx")]
 
-    def carried_back(self, pool: "Pool") -> "KVCache":
-        """``carried`` undone: the same fields, the loop's arrays."""
-        names = [n for n, a in zip(self._fields, self) if a is not None]
-        return KVCache(**dict(zip(names, pool)))
+    def carried(self) -> "Pool":
+        """The per-token arrays a step program's layer loop carries:
+        those that are there, in the fields' order."""
+        return tuple(a for _, a in self._per_token() if a is not None)
+
+    def state_carried(self) -> "Pool":
+        """The state pages the loop carries beside them: (state, conv),
+        or () where the model has no such layer."""
+        return () if self.state is None else (self.state, self.conv)
+
+    def carried_back(self, pool: "Pool", state: "Pool" = ()) -> "KVCache":
+        """``carried`` and ``state_carried`` undone: the same fields,
+        the loop's arrays."""
+        names = [n for n, a in self._per_token() if a is not None]
+        return KVCache(**dict(zip(names, pool)),
+                       **dict(zip(("state", "conv"), state)))
 
 
 # the pool as a step program's layer loop carries it: a KVCache's
@@ -215,11 +265,31 @@ def make_latent_cache(num_layers: int, num_blocks: int, block_size: int,
 
 
 def cache_for(cfg, num_blocks: int, block_size: int,
-              dtype=jnp.bfloat16) -> KVCache:
+              dtype=jnp.bfloat16, state_pages: int = 0) -> KVCache:
     """The pool a model's layers append to and attend over
     (cfg: models/config.ModelConfig): the latent pool for latent
     attention (with the index pool beside it where the model selects
-    what it attends), K and V per kv head for everything else."""
+    what it attends), K and V per kv head for everything else: of the
+    model's ATTENTION layers, with ``state_pages`` state pages (the
+    trash page 0 among them) beside them where it has Gated DeltaNet
+    layers."""
+    if cfg.gdn_layers:
+        if dtype == jnp.int8:
+            raise ValueError(
+                f"{cfg.name}: a model with state pages (layout "
+                f"'kv+state') has no int8 KV pool; use --kv-cache-dtype "
+                f"bfloat16 or float32")
+        if state_pages < 2:
+            raise ValueError("a state pool needs the trash page and at "
+                             "least one more (state_pages >= 2)")
+        kv = make_cache(cfg.attn_layers, num_blocks, block_size,
+                        cfg.num_kv_heads, cfg.head_dim_, dtype)
+        return kv._replace(
+            state=jnp.zeros((cfg.gdn_layers, state_pages,
+                             cfg.gdn_value_heads, cfg.gdn_key_dim,
+                             cfg.gdn_value_dim), jnp.float32),
+            conv=jnp.zeros((cfg.gdn_layers, state_pages,
+                            cfg.gdn_conv - 1, cfg.gdn_channels), dtype))
     if cfg.mla:
         cache = make_latent_cache(cfg.num_layers, num_blocks, block_size,
                                   cfg.latent_dim, dtype)
@@ -230,6 +300,16 @@ def cache_for(cfg, num_blocks: int, block_size: int,
         return cache
     return make_cache(cfg.num_layers, num_blocks, block_size,
                       cfg.num_kv_heads, cfg.head_dim_, dtype)
+
+
+def split_tables(tables: jnp.ndarray, has_state: bool):
+    """(block tables [B, MB], state page ids [B] or None) of the table
+    rows as the engine keeps them: where the model has state pages, a
+    row's last column is the sequence's page (0, the trash page, on an
+    empty row)."""
+    if not has_state:
+        return tables, None
+    return tables[:, :-1], tables[:, -1]
 
 
 def linear_tables(num_slots: int, max_len: int,

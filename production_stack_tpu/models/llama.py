@@ -29,7 +29,7 @@ from production_stack_tpu.models import kv as kv_pool
 from production_stack_tpu.models import lora, quant
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.models.kv import KVCache
-from production_stack_tpu.ops import moe
+from production_stack_tpu.ops import gdn, moe
 from production_stack_tpu.ops.attention import causal_attention
 from production_stack_tpu.ops.norms import rms_norm
 from production_stack_tpu.ops.rope import apply_rope, rope_table
@@ -87,6 +87,8 @@ def init_params(cfg: ModelConfig, key: jax.Array,
 
     if cfg.mla:
         return _init_params_mla(cfg, key, w)
+    if cfg.layer_pattern:
+        return _init_params_hybrid(cfg, key, w)
     norm_init = jnp.zeros if cfg.rms_norm_offset else jnp.ones
     E = cfg.num_experts
     params: Params = {
@@ -230,6 +232,137 @@ def _init_params_mla(cfg: ModelConfig, key: jax.Array, w) -> Params:
                                    "s_gate_w")
     params["layers"] = layers
     return params
+
+
+def _init_params_hybrid(cfg: ModelConfig, key: jax.Array, w) -> Params:
+    """init_params for a model whose period holds two kinds of mixer
+    (Qwen3-Next: cfg.layer_pattern). Three groups, each stacked on its
+    own leading axis: ``layers`` holds what every layer has (its two
+    norms, the router, the experts, the gated shared expert) for all
+    num_layers, ``gdn_layers`` the Gated DeltaNet mixers and
+    ``attn_layers`` the gated attention mixers, each in the model's
+    order; the scan takes a period of each (``forward``). ``qkvz`` is
+    [q | k | v | z] (the checkpoint groups the columns by key head, a
+    permutation that is a loader's business), ``ba`` [b | a], ``conv``
+    [taps, channels] with the last tap on the token itself. A_log =
+    log(U(0, 16)), dt_bias one and the norms at their published
+    initialisation (zero-centred norms zero, the gated norm one)."""
+    h, v = cfg.hidden_size, cfg.vocab_size
+    L, Lg, La = cfg.num_layers, cfg.gdn_layers, cfg.attn_layers
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    hv, dv, ch = cfg.gdn_value_heads, cfg.gdn_value_dim, cfg.gdn_channels
+    E, mi, si = cfg.num_experts, cfg.moe_intermediate_size, \
+        cfg.shared_expert_size
+    keys = iter(jax.random.split(key, 32))
+    params: Params = {
+        "embed": w(next(keys), (v, h), "embed"),
+        "final_norm": jnp.zeros((h,), cfg.dtype),
+        "lm_head": w(next(keys), (h, v), "lm_head"),
+        "gdn_layers": {
+            "qkvz": w(next(keys), (Lg, h, ch + hv * dv), "gdn_layers",
+                      "qkvz"),
+            "ba": w(next(keys), (Lg, h, 2 * hv), "gdn_layers", "ba"),
+            "conv": w(next(keys), (Lg, cfg.gdn_conv, ch), "gdn_layers",
+                      "conv"),
+            "A_log": jnp.log(jax.random.uniform(
+                next(keys), (Lg, hv), jnp.float32, 1e-3, 16.0)),
+            "dt_bias": jnp.ones((Lg, hv), jnp.float32),
+            "gdn_norm": jnp.ones((Lg, dv), cfg.dtype),
+            "out": w(next(keys), (Lg, hv * dv, h), "gdn_layers", "out"),
+        },
+        "attn_layers": {
+            "q": w(next(keys), (La, h, 2 * nh * hd), "attn_layers", "q"),
+            "k": w(next(keys), (La, h, nkv * hd), "attn_layers", "k"),
+            "v": w(next(keys), (La, h, nkv * hd), "attn_layers", "v"),
+            "o": w(next(keys), (La, nh * hd, h), "attn_layers", "o"),
+            "q_norm": jnp.zeros((La, hd), cfg.dtype),
+            "k_norm": jnp.zeros((La, hd), cfg.dtype),
+        },
+        "layers": {
+            "attn_norm": jnp.zeros((L, h), cfg.dtype),
+            "mlp_norm": jnp.zeros((L, h), cfg.dtype),
+            "gate": w(next(keys), (L, E, h, mi), "layers", "gate"),
+            "up": w(next(keys), (L, E, h, mi), "layers", "up"),
+            "down": w(next(keys), (L, E, mi, h), "layers", "down",
+                      std=cfg.routed_down_init_std or 0.02),
+            "router": w(next(keys), (L, h, cfg.router_experts_), "layers",
+                        "router"),
+            "s_gate": w(next(keys), (L, h, si), "layers", "s_gate"),
+            "s_up": w(next(keys), (L, h, si), "layers", "s_up"),
+            "s_down": w(next(keys), (L, si, h), "layers", "s_down"),
+            "s_gate_w": w(next(keys), (L, h, 1), "layers", "s_gate_w"),
+        },
+    }
+    return params
+
+
+def _gdn_layer(cfg: ModelConfig, x, lp: Params, state, state_ids, starts,
+               token_valid, layer, state_layer, moe_capacity_tokens,
+               expert_stacks):
+    """One Gated DeltaNet block (ops/gdn.py). x [B,T,H]; state = the
+    WHOLE state pool (matrices [Lg,P,Hv,Dk,Dv] float32, the
+    convolutions' inputs [Lg,P,taps-1,Ch]), of which this block reads
+    and writes the rows' pages ``state_ids`` [B] of layer
+    ``state_layer``, in place. A row none of whose positions is real
+    (token_valid [B,T]; a parked decode row, a spare prefill row)
+    names the trash page 0 whatever its table says; positions that are
+    not real trail the chunk and advance nothing (exp(g) = 1, beta = 0,
+    the convolution keeps its last REAL inputs); a row whose first
+    position is 0 starts from a zero state. Returns (x', the state
+    pool, the experts' work); scopes gdn_proj, gdn_conv, gdn_scan /
+    gdn_step (ops/gdn.mix), gdn_gate_norm, gdn_out_proj."""
+    B, T, _ = x.shape
+    hk, hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    dk, dv, ch = cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_channels
+    mats, conv = state
+    if token_valid is None:
+        token_valid = jnp.ones((B, T), bool)
+    real = jnp.any(token_valid, axis=1)
+    ids = jnp.where(real, state_ids, 0)
+    fresh = starts == 0
+    with jax.named_scope("attn_norm"):
+        hidden = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, offset=1.0)
+    # between the two projections the mixer keeps float32: what enters
+    # the rule's products is rounded to the activations' dtype once
+    f32 = jnp.float32
+    with jax.named_scope("gdn_proj"):
+        qkvz = quant.dequant_matmul(hidden, lp["qkvz"], dtype=f32,
+                                    exact_scale=True)
+        mixed, z = qkvz[..., :ch], qkvz[..., ch:]
+        ba = jnp.einsum("bth,hj->btj", hidden, lp["ba"],
+                        preferred_element_type=jnp.float32)
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(
+            ba[..., hv:] + lp["dt_bias"])
+        beta = jnp.where(token_valid[..., None], beta, 0.0)
+        g = jnp.where(token_valid[..., None], g, 0.0)
+    with jax.named_scope("gdn_conv"):
+        prev = jnp.where(fresh[:, None, None], 0,
+                         conv[state_layer, ids])
+        mixed, new_conv = gdn.causal_conv(
+            mixed, lp["conv"], prev,
+            jnp.sum(token_valid, axis=1, dtype=jnp.int32))
+        conv = conv.at[state_layer, ids].set(new_conv)
+        q = mixed[..., :hk * dk].reshape(B, T, hk, dk)
+        k = mixed[..., hk * dk:2 * hk * dk].reshape(B, T, hk, dk)
+        v = mixed[..., 2 * hk * dk:].reshape(B, T, hv, dv)
+
+        def l2norm(a, scale=1.0):
+            return (a * (jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6) * scale)
+            ).astype(x.dtype)
+        q, k, v = l2norm(q, dk ** -0.5), l2norm(k), v.astype(x.dtype)
+    o, mats = gdn.mix(q, k, v, g, beta, mats, ids, state_layer, fresh)
+    with jax.named_scope("gdn_gate_norm"):
+        o = rms_norm(o, lp["gdn_norm"], cfg.rms_norm_eps)
+        o = (o * jax.nn.silu(z.reshape(B, T, hv, dv))).astype(x.dtype)
+    with jax.named_scope("gdn_out_proj"):
+        x = x + quant.dequant_matmul(o.reshape(B, T, hv * dv), lp["out"],
+                                     exact_scale=True)
+    x, _, work = _mlp_block(cfg, x, lp, None, token_valid,
+                            moe_capacity_tokens, expert_stacks, layer,
+                            None)
+    return x, (mats, conv), work
 
 
 def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
@@ -390,11 +523,13 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
                 block_tables: Optional[jnp.ndarray] = None,
                 mesh=None, layer_local=None, layer=None,
                 moe_capacity_tokens: Optional[int] = None,
-                expert_stacks: Optional[Params] = None):
+                expert_stacks: Optional[Params] = None, kv_layer=None):
     """One transformer block. x [B,T,H]; kv = the WHOLE paged pool
     (k, v) [L,N,Hkv,Bs,D] — with (ks, vs) [L,N,Hkv,Bs] behind them for
     the int8 pool — of which this block appends to and reads
-    ``layer`` (its index, traced), addressed through block_tables
+    ``layer`` (its index, traced; ``kv_layer`` where the pool holds
+    the attention layers alone and the experts' stacks every layer:
+    a hybrid model), addressed through block_tables
     [B,MB]. The pool comes back as the second result, the same buffer
     with this layer's chunk written (models/kv.py: carried, never
     stacked); what is done to it is done behind models/kv.py
@@ -428,9 +563,12 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     B, T, _ = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     cos, sin = rope
+    if kv_layer is None:
+        kv_layer = layer
 
     def proj(h, name):
-        out = quant.dequant_matmul(h, lp[name])
+        out = quant.dequant_matmul(h, lp[name],
+                                   exact_scale=cfg.exact_dequant_scale)
         bias = lp.get(f"{name}_bias")
         if bias is not None:
             out = out + bias
@@ -452,12 +590,28 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
         return _mlp_block(cfg, x, lp, kv, token_valid,
                           moe_capacity_tokens, expert_stacks, layer, proj)
     with jax.named_scope("qkv_proj"):
-        q = proj(hidden, "q").reshape(B, T, nh, hd)
+        if cfg.attn_gate:
+            # a head's query and its output gate, side by side
+            qg = proj(hidden, "q").reshape(B, T, nh, 2 * hd)
+            q, out_gate = qg[..., :hd], qg[..., hd:]
+        else:
+            q = proj(hidden, "q").reshape(B, T, nh, hd)
         k = proj(hidden, "k").reshape(B, T, nkv, hd)
         v = proj(hidden, "v").reshape(B, T, nkv, hd)
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, offset=offset)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, offset=offset)
     with jax.named_scope("rope"):
-        q = apply_rope(q, positions, cos, sin)
-        k = apply_rope(k, positions, cos, sin)
+        if cfg.rotary_dim:
+            # the leading rotary_dim columns turn, the rest pass
+            rd = cfg.rotary_dim
+            q = jnp.concatenate([apply_rope(q[..., :rd], positions, cos,
+                                            sin), q[..., rd:]], axis=-1)
+            k = jnp.concatenate([apply_rope(k[..., :rd], positions, cos,
+                                            sin), k[..., rd:]], axis=-1)
+        else:
+            q = apply_rope(q, positions, cos, sin)
+            k = apply_rope(k, positions, cos, sin)
 
     # Gemma-2 deviations from the Llama baseline: attention scale from
     # query_pre_attn_scalar, tanh score softcap, and (alternating)
@@ -484,11 +638,15 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     else:
         with jax.named_scope("kv_write"):
             kv = kv_pool.append(kv, k, v, block_tables, starts,
-                                token_valid, layer)
+                                token_valid, kv_layer)
         with jax.named_scope("attention"):
             attn = _windowed(lambda w: kv_pool.attend(
-                q, kv, block_tables, starts, positions, kv_len, layer,
+                q, kv, block_tables, starts, positions, kv_len, kv_layer,
                 window=w, scale=scale_val, softcap=cap, mesh=mesh))
+    if cfg.attn_gate:
+        with jax.named_scope("attn_gate"):
+            attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                out_gate.astype(jnp.float32))).astype(x.dtype)
     with jax.named_scope("o_proj"):
         o_out = proj(attn.reshape(B, T, nh * hd), "o")
         if cfg.sandwich_norms:
@@ -547,10 +705,11 @@ def _mlp_block(cfg: ModelConfig, x, lp: Params, kv, token_valid,
             # an always-on shared expert: Qwen2-MoE's behind a
             # per-token sigmoid gate, GLM-4.7-Flash's with none
             with jax.named_scope("shared_expert"):
-                shared = quant.dequant_matmul(
-                    act(quant.dequant_matmul(hidden, lp["s_gate"]))
-                    * quant.dequant_matmul(hidden, lp["s_up"]),
-                    lp["s_down"])
+                mm = functools.partial(
+                    quant.dequant_matmul,
+                    exact_scale=cfg.exact_dequant_scale)
+                shared = mm(act(mm(hidden, lp["s_gate"]))
+                            * mm(hidden, lp["s_up"]), lp["s_down"])
                 if cfg.shared_expert_gate:
                     shared = jax.nn.sigmoid(
                         hidden @ lp["s_gate_w"]) * shared
@@ -624,35 +783,82 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if rope is None:
         rope = rope_table(cfg.max_position_embeddings, cfg.rope_dim_,
                           cfg.rope_theta, scaling=cfg.rope_scaling)
-    if lora_params is not None and (cfg.mla or cfg.first_dense_layers):
+    if lora_params is not None and (cfg.mla or cfg.first_dense_layers
+                                    or cfg.layer_pattern):
         raise ValueError(
             "LoRA adapters are not supported on a latent-attention "
-            "model or one with leading dense layers")
+            "model, one with leading dense layers or one with two kinds "
+            "of mixer")
     if block_tables is None:
         B = tokens.shape[0]
         Bs = cache.block_size
         n_per = (cache.k.shape[1] - 1) // B
         block_tables = kv_pool.linear_tables(B, n_per * Bs, Bs)
+        if cfg.gdn_layers:      # row b's state page: 1 + b
+            block_tables = jnp.concatenate(
+                [block_tables, 1 + jnp.arange(B, dtype=jnp.int32)[:, None]],
+                axis=1)
     starts = positions[:, 0]
+    # the last column of a table row is the sequence's state page,
+    # where the model has such pages (models/kv.split_tables)
+    block_tables, state_ids = kv_pool.split_tables(
+        block_tables, bool(cfg.gdn_layers))
     with jax.named_scope("embed"):
         x = _embed(params, cfg, tokens)
+    # the scan's unit is one PERIOD of the layer pattern (cfg.pattern_):
+    # one attention layer for every model but the hybrid, whose period
+    # runs its Gated DeltaNet layers and then its attention layer
+    pattern = cfg.pattern_
+    period = len(pattern)
 
     def scan_body(carry, xs):
-        # the pool rides in the CARRY, one buffer from the executable's
-        # donated argument to its result: as the scan's xs -> ys it was
-        # sliced, rewritten and stacked, a layer's pool per layer and
-        # the whole pool per step (models/kv.py)
-        h, pool = carry
+        # the pools ride in the CARRY, one buffer each from the
+        # executable's donated argument to its result: as the scan's
+        # xs -> ys the K/V pool was sliced, rewritten and stacked, a
+        # layer's pool per layer and the whole pool per step
+        # (models/kv.py); the state pages beside it alike
+        h, pool, spool = carry
         lp, layer, ll, local = xs
-        h, pool, work = _layer_body(
-            cfg, rope, positions, starts, h, lp, pool,
-            kv_len=kv_len, lora_layer=ll, adapter_ids=adapter_ids,
-            lora_scaling=lora_scaling, token_valid=token_valid,
-            block_tables=block_tables, mesh=mesh,
-            layer_local=local, layer=layer,
-            moe_capacity_tokens=moe_capacity_tokens,
-            expert_stacks=expert_stacks)
-        return (h, pool), work
+        if period == 1:
+            h, pool, work = _layer_body(
+                cfg, rope, positions, starts, h, lp, pool,
+                kv_len=kv_len, lora_layer=ll, adapter_ids=adapter_ids,
+                lora_scaling=lora_scaling, token_valid=token_valid,
+                block_tables=block_tables, mesh=mesh,
+                layer_local=local, layer=layer,
+                moe_capacity_tokens=moe_capacity_tokens,
+                expert_stacks=expert_stacks)
+            return (h, pool, spool), work
+        # ``layer`` is the period's index; its sub-layers in a static
+        # loop, each reading its own row of the groups' stacks in place
+        # (closed over, like the experts' stacks: as the scan's xs a
+        # period's slice [layers a period, ...] was copied out whole
+        # before a sub-layer's row was taken, 75 MB of the fused input
+        # projections a period and step on the chip: PERF.md, PR 42)
+        works = []
+        for j, kind in enumerate(pattern):
+            # the sub-layer's place among its kind's, in the period
+            i, per = pattern[:j].count(kind), pattern.count(kind)
+            sub = jax.tree.map(lambda a: a[layer * period + j],
+                               layer_params)
+            sub.update(jax.tree.map(lambda a: a[layer * per + i],
+                                    params[kind + "_layers"]))
+            if kind == "gdn":
+                h, spool, work = _gdn_layer(
+                    cfg, h, sub, spool, state_ids, starts, token_valid,
+                    layer * period + j, layer * per + i,
+                    moe_capacity_tokens, expert_stacks)
+            else:
+                h, pool, work = _layer_body(
+                    cfg, rope, positions, starts, h, sub, pool,
+                    kv_len=kv_len, token_valid=token_valid,
+                    block_tables=block_tables, mesh=mesh,
+                    layer=layer * period + j,
+                    moe_capacity_tokens=moe_capacity_tokens,
+                    expert_stacks=expert_stacks,
+                    kv_layer=layer * per + i)
+            works.append(work)
+        return (h, pool, spool), moe.Work(*map(sum, zip(*works)))
 
     layer_params = params["layers"]
     expert_stacks = None
@@ -673,24 +879,28 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     # scan runs the rest (the expert layers) at the pool's layer index,
     # so the pool stays the one carried buffer
     Ld = cfg.first_dense_layers
-    layers = jnp.arange(Ld, cfg.num_layers)
-    xs = (layer_params, layers, lora_params,
+    if period == 1:
+        layers = jnp.arange(Ld, cfg.num_layers)
+    else:       # the scan counts periods; the body reads the stacks
+        layers = jnp.arange(cfg.num_periods)
+    xs = (layer_params if period == 1 else None, layers, lora_params,
           # Gemma-2 layer pattern: even layers sliding, odd global
           layers % 2 == 0 if cfg.alternating_sliding else None)
-    pool = cache.carried()
+    pool, spool = cache.carried(), cache.state_carried()
     with jax.named_scope("dense_layers"):
         for i in range(Ld):
             lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
-            (x, pool), _ = scan_body((x, pool),
-                                     (lp, jnp.int32(i), None, None))
+            (x, pool, spool), _ = scan_body(
+                (x, pool, spool), (lp, jnp.int32(i), None, None))
     with jax.named_scope("layers"):
-        (x, pool), work = jax.lax.scan(scan_body, (x, pool), xs)
+        (x, pool, spool), work = jax.lax.scan(scan_body, (x, pool, spool),
+                                              xs)
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                      offset=1.0 if cfg.rms_norm_offset else 0.0)
     with jax.named_scope("lm_head"):
         logits = _lm_head(params, cfg, x)
-    return (logits, cache.carried_back(pool),
+    return (logits, cache.carried_back(pool, spool),
             None if work is None else moe.Work(*map(jnp.sum, work)))
 
 
@@ -703,6 +913,12 @@ def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     token_valid [B,T] marks real tokens in right-padded batches — on
     MoE models padding must not compete for expert capacity.
     """
+    if cfg.layer_pattern:
+        raise ValueError(
+            f"{cfg.name}: a forward without caches (encode, "
+            f"forward_train: embeddings, echoed prompt log-"
+            f"probabilities) is not built for a model with state pages; "
+            f"its reference is chipbench/references/qwen3_next.py")
     if rope is None:
         rope = rope_table(cfg.max_position_embeddings, cfg.rope_dim_,
                           cfg.rope_theta, scaling=cfg.rope_scaling)

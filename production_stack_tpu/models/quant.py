@@ -33,10 +33,14 @@ _SKIP_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_mlp_norm",
                "q_bias", "k_bias", "v_bias", "router", "s_gate_w",
                "q_a_norm", "kv_a_norm", "router_bias",
                # the sparse-attention indexer's norm and head weights
-               "idx_k_norm", "idx_k_norm_bias", "idx_w")
+               "idx_k_norm", "idx_k_norm_bias", "idx_w",
+               # a Gated DeltaNet mixer's small leaves, and the gated
+               # attention's per-head norms
+               "ba", "conv", "A_log", "dt_bias", "gdn_norm", "q_norm",
+               "k_norm")
 # the groups of stacked layers a tree may hold: the scanned layers and
 # a layer plan's leading dense ones (models/llama.py)
-_LAYER_GROUPS = ("layers", "dense_layers")
+_LAYER_GROUPS = ("layers", "dense_layers", "gdn_layers", "attn_layers")
 
 
 def quantize_tensor(w: jnp.ndarray) -> Dict[str, jnp.ndarray]:
@@ -69,19 +73,30 @@ def is_quantized(leaf: Any) -> bool:
     return isinstance(leaf, dict) and "w8" in leaf
 
 
-def dequant_matmul(x: jnp.ndarray, w: Any, dtype=None) -> jnp.ndarray:
-    """x @ w for raw or quantized w, in x.dtype (or `dtype`)."""
+def dequant_matmul(x: jnp.ndarray, w: Any, dtype=None,
+                   exact_scale: bool = False) -> jnp.ndarray:
+    """x @ w for raw or quantized w, in x.dtype (or `dtype`).
+    ``exact_scale``: the float32 sums times the float32 scales, rounded
+    to ``dtype`` ONCE; without it the sums are rounded to ``dtype``,
+    the scales are, and their product is (three roundings a matmul,
+    the scales' the same for every token). A model whose depth brings
+    the logit probe to its limit asks for it
+    (ModelConfig.exact_dequant_scale)."""
     if not is_quantized(w):
-        return x @ w
+        return x @ w if dtype is None else (x @ w).astype(dtype)
     dtype = dtype or x.dtype
+    if exact_scale:
+        y = jnp.matmul(x, w["w8"].astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+        return (y * w["scale"]).astype(dtype)
     y = x @ w["w8"].astype(dtype)
     return y * w["scale"].astype(dtype)
 
 
 def leaf_quantizer(path):
     """The quantizer the standard int8 recipe applies to the leaf at
-    ``path`` — ("embed",), ("lm_head",) or (group, name), group
-    "layers" or "dense_layers", in the models/llama.py layout — or None where the leaf stays in the model
+    ``path`` — ("embed",), ("lm_head",) or (group, name), group one
+    of _LAYER_GROUPS, in the models/llama.py layout — or None where the leaf stays in the model
     dtype. Embed quantizes per row so the gather and tied-lm_head roles
     share one scale axis."""
     if path == ("embed",):

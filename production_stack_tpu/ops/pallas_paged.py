@@ -133,9 +133,12 @@ _VMEM_WORK_BYTES = 8 * 1024 * 1024
 def paged_viable(T: int, groups: int, head_dim: int,
                  block_size: int, value_dim: int = 0) -> bool:
     """Can a [T*G, D] q panel + accumulator + one [T*G, Bs] score
-    block hold in VMEM? (Decode windows always can; only very long
-    prefill chunks on wide-GQA models cannot.) value_dim: the
-    accumulator's width where it is not the keys' (the latent pool)."""
+    block hold in VMEM? T is what ONE grid step holds: a decode
+    window's positions, or one q block of a prefill chunk (the prefill
+    kernel halves a chunk's q block until this says yes, down to
+    _MIN_BLOCK_Q positions, so a path is viable where the smallest q
+    block is: ``attention_path``). value_dim: the accumulator's width
+    where it is not the keys' (the latent pool)."""
     rows = max(T * groups, 8)
     work = rows * (head_dim + (value_dim or head_dim)) * 4 \
         + rows * block_size * 4 * 2 + rows * head_dim * 2
@@ -1114,7 +1117,12 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
     ``pallas_paged_decode``: short windows (decode / speculative
     verify) on the decode kernel — one grid step a row, all kv heads
     and several live pool blocks a chunk, copied in by the kernel.
-    ``pallas_paged``: prefill chunks on the general paged kernel.
+    ``pallas_paged``: prefill chunks on the general paged kernel, cut
+    into q blocks where the whole chunk's q panel misses VMEM (2048
+    positions x 8 query heads a kv head x 256 are 50 MB; a q block of
+    256 is 6.3): what must fit is the smallest q block the kernel cuts
+    a chunk into, so wide-GQA long chunks stay on the kernel at every
+    kv bucket and pay only K and V streamed once a q block.
     ``*_sharded``: either, shard-local per head under a tp-only mesh.
     ``*_latent``: either, over the latent pool (value_dim > 0: every
     query head on the one cached vector a token, head_dim wide, of
@@ -1132,9 +1140,9 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
     (``expanded_cheaper``; ``head_dims``, a head's nope, rope and value
     widths, from models/llama._mla_attention and engine/runner.py): the
     prefill kernel's expanded case, same pool, clamp and marks.
-    ``jnp_gather``: the kernel is off (PSTPU_FLASH / not a TPU), the
-    chunk's working set misses VMEM (paged_viable), or the mesh shards
-    the pool's block axis."""
+    ``jnp_gather``: the kernel is off (PSTPU_FLASH / not a TPU), even
+    the smallest q block's working set misses VMEM (paged_viable), or
+    the mesh shards the pool's block axis."""
     if value_dim:
         if not (flash_enabled() and mesh is None and paged_viable(
                 min(T, _MIN_BLOCK_Q), groups, head_dim, block_size,
@@ -1149,7 +1157,8 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
             kernel = "pallas_paged_latent"
         return kernel + ("_sparse" if selects else "")
     if not (flash_enabled()
-            and paged_viable(T, groups, head_dim, block_size)
+            and paged_viable(min(T, _MIN_BLOCK_Q), groups, head_dim,
+                             block_size)
             and (mesh is None or mesh_tp_only(mesh))):
         return JNP_GATHER
     kernel = "pallas_paged_decode" if T <= DECODE_T_MAX else "pallas_paged"

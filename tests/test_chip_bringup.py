@@ -194,13 +194,14 @@ def test_refused_compile_raises_naming_the_executable():
     (9, True, None, "pallas_paged"),              # DECODE_T_MAX + 1
     (512, True, None, "pallas_paged"),
     (512, False, None, "jnp_gather"),             # the gate off
-    # a chunk whose working set misses VMEM (paged_viable)
-    (1 << 16, True, None, "jnp_gather"),
+    # a chunk whose whole q panel misses VMEM stays on the kernel, cut
+    # into q blocks (paged_viable asks of the smallest)
+    (1 << 16, True, None, "pallas_paged"),
     (1, True, dict(dp=1, tp=2), "pallas_paged_decode_sharded"),
     (1, True, dict(dp=2, tp=2), "jnp_gather"),    # the pool's blocks
     #                                               sharded: the dp cliff
 ], ids=["decode", "decode_t_max", "past_decode_t_max", "prefill_chunk",
-        "gate_off", "misses_vmem", "tp_only_mesh", "dp_mesh"])
+        "gate_off", "cut_into_q_blocks", "tp_only_mesh", "dp_mesh"])
 def test_attention_path_is_chosen_by_shape_and_recorded(
         monkeypatch, T, gate, mesh_dims, want):
     from production_stack_tpu.models.config import get_config

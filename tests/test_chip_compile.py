@@ -292,7 +292,8 @@ def _runner_shapes(topo, tp: int, layers=None, kv_blocks=None,
         jax.random.PRNGKey(0))
     cache = jax.eval_shape(partial(
         cache_for, mcfg, kv_blocks or ecfg.num_kv_blocks,
-        ecfg.kv_block_size))
+        ecfg.kv_block_size,
+        state_pages=ecfg.max_num_seqs + 1 if mcfg.gdn_layers else 0))
     if mesh is None:
         p_sh = jax.tree.map(lambda _: rep_sh, params)
         c_sh = jax.tree.map(lambda _: rep_sh, cache)
@@ -968,3 +969,130 @@ def test_prefill_step_compiles_at_mistral_7b(topo, tpu_branches, tp,
                                       rows)
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled, f"prefill step tp={tp} rows={rows or 'all'}")
+
+
+# ---------------------------------------------------------------------
+# two kinds of mixer in one model, state pages beside the K/V pool
+# (Qwen3-Next's share at its published widths: 32 state matrices of
+# 128 x 128 a sequence and Gated DeltaNet layer, gated attention of 16
+# query / 2 kv heads of 256, 64 of 512 experts of 2048 x 512 held)
+# ---------------------------------------------------------------------
+
+def _qwen3next_runner(topo, monkeypatch, periods=1):
+    """The runner skeleton at the benchmark's Qwen3-Next file, cut to
+    ``periods`` periods of four layers, 8 slots of 16384 tokens over
+    the K/V pool and 9 state pages (the cell's geometry)."""
+    import dataclasses
+    import json
+    from chipbench.engine_child import model_config
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.models import config as model_configs
+    from production_stack_tpu.models.kv import cache_for
+    from production_stack_tpu.ops.rope import rope_table
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "qwen3-next-80b-a3b-int8-l24-e64.json")) as f:
+        conf = json.load(f)
+    mcfg = dataclasses.replace(model_config(conf, "qwen3-next-share"),
+                               num_layers=4 * periods)
+    monkeypatch.setitem(model_configs.PRESETS, "qwen3-next-share", mcfg)
+    runner, params, _, rep = _runner_shapes(
+        topo, 1, kv_blocks=2049, model="qwen3-next-share")
+    runner.engine_cfg = EngineConfig(
+        model="qwen3-next-share", quantization="int8", max_num_seqs=8,
+        max_model_len=16384, kv_pool_tokens=131072, prefill_chunk=2048)
+    runner.rope = rope_table(16384, mcfg.rope_dim_, mcfg.rope_theta)
+    cache = jax.tree.map(
+        lambda x: rep(x.shape, x.dtype),
+        jax.eval_shape(partial(cache_for, mcfg, 2049, 64, state_pages=9)))
+    return runner, params, cache, rep
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk"])
+def test_hybrid_step_program_compiles_at_qwen3next_widths(
+        topo, tpu_branches, monkeypatch, program):
+    """One decode window of 8 rows and one 2048-token prefill chunk of
+    one row at the longest kv bucket, compiled whole for the described
+    v5e: the delta rule is ops/gdn.py's kernel of the forward's kind,
+    the attention the paged kernels' K/V case at 8 query heads a kv
+    head of 256 (the chunk cut into q blocks), the experts the list and
+    the grouped kernel; neither the K/V pool nor the state pool is
+    copied or sliced (both aliased to the result); no period's slice
+    of a parameter group is copied out before a sub-layer reads its
+    row (as the scan's xs the three fused input projections of a
+    period, 75 MB, were: PERF.md, PR 42); the program fits the
+    chip."""
+    import re
+    P, N = 2, 2049
+    runner, params, cache, rep = _qwen3next_runner(topo, monkeypatch, P)
+    B = runner.engine_cfg.max_num_seqs
+    a = _step_args(runner, rep, B)
+    tables = rep(runner.table_shape, jnp.int32)
+    assert runner.table_shape == (8, 257)
+    small = (a["sampling"], a["key"], a["guide_next"], a["guide_id"],
+             a["guide_state"], a["counts"], a["seen"])
+    if program == "decode_window":
+        fn = jax.jit(partial(runner._decode_impl, steps=8, kv_len=16384,
+                             greedy=True), donate_argnums=(1,))
+        compiled = fn.lower(params, cache, tables, rep((B,), jnp.int32),
+                            rep((B,), jnp.int32), *small).compile()
+        want = {"paged_decode_attention", "moe_list_experts",
+                "gdn_recurrent_step"}
+        path = "pallas_paged_decode"
+    else:
+        fn = jax.jit(partial(runner._prefill_impl, kv_len=16384),
+                     donate_argnums=(1,))
+        compiled = fn.lower(params, cache, tables,
+                            rep((1,), jnp.int32), rep((1, 2048), jnp.int32),
+                            rep((1,), jnp.int32), rep((1,), jnp.int32),
+                            *small).compile()
+        want = {"paged_attention", "moe_grouped_experts", "gdn_chunk_scan"}
+        path = "pallas_paged"
+    hlo = compiled.as_text()
+    calls = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]+)[\w.\-]* = [^=]*? custom-call\(", hlo)}
+    assert {c for c in calls if c.startswith(("paged", "moe", "gdn"))} \
+        == want
+    assert runner._attention_path(
+        1 if program == "decode_window" else 2048, None, 16384) == path
+    pools = re.compile(
+        r"([\w.\-]+) = \(?\w+\[(?:{p},{n},2,{bs},256|{g},9,32,128,128)\]\S* "
+        r"([\w\-]+)\(".format(p=P, n=N, bs=BS, g=3 * P))
+    moved = [m.group(1) + ": " + m.group(2)
+             for m in map(pools.search, hlo.splitlines()) if m
+             and re.search(r"copy|dynamic.slice|dynamic.update.slice",
+                           m.group(1) + " " + m.group(2))]
+    assert not moved, moved
+    slices = re.findall(r"= s8\[3,2048,\d+\]\S* [\w\-]+\(", hlo)
+    assert not slices, slices[:3]
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= 2 * P * N * 2 * BS * 256 * 2 + 3 * P * 9 * 32 * 128 * 128 * 4)
+    _fits(compiled, f"qwen3-next share {program}")
+
+
+@pytest.mark.parametrize("T,nb", [(2048, 256), (2048, 32), (512, 8)])
+def test_kv_prefill_kernel_in_q_blocks_compiles_at_8_groups_of_256(
+        topo, T, nb):
+    """The prefill kernel's K/V case at 16 query / 2 kv heads of 256:
+    a chunk whose whole q panel misses VMEM (paged_viable(2048, 8,
+    256, 64) is false) is cut into q blocks and compiles at the
+    longest and a short kv bucket; attention_path keeps it on the
+    kernel."""
+    assert not pallas_paged.paged_viable(2048, 8, 256, 64)
+    assert pallas_paged.paged_viable(256, 8, 256, 64)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    pool = s((6, 2049, 2, 64, 256), jnp.bfloat16)
+    was = pallas_paged._override
+    pallas_paged._override = True
+    try:
+        assert pallas_paged.attention_path(T, 8, 256, 64) == "pallas_paged"
+    finally:
+        pallas_paged._override = was
+    jax.jit(lambda q, k, v, t, st, lyr: pallas_paged.paged_attention(
+        q, k, v, t, st, nb=nb, layer=lyr)).lower(
+        s((1, T, 16, 256), jnp.bfloat16), pool, pool,
+        s((1, 256), jnp.int32), s((1,), jnp.int32),
+        s((), jnp.int32)).compile()

@@ -387,15 +387,22 @@ def test_the_chip_check_sees_what_the_held_experts_add(
 # the chip's share of the experts
 # ---------------------------------------------------------------------
 
-def test_sixteen_shares_add_up_to_the_uncut_layer():
-    """A layer of 16 routed experts top-4 behind one router, cut over
-    16 chips of one expert each: the routed parts that the sixteen
-    shares give, with the shared expert counted once, add up to what
-    the uncut layer gives; each share reads at most its one expert."""
-    E, k, N, h = 16, 4, 40, 128
-    whole = dataclasses.replace(CFG, num_experts=E, router_experts=0,
-                                expert_offset=0, num_experts_per_tok=k,
-                                num_layers=2)
+@pytest.mark.parametrize("preset,E,k,held", [
+    ("debug-dsa", 16, 4, 1), ("debug-gdn", 64, 4, 8)],
+    ids=["sigmoid_16_chips_of_1", "softmax_8_chips_of_8"])
+def test_sixteen_shares_add_up_to_the_uncut_layer(preset, E, k, held):
+    """A layer of E routed experts top-4 behind one router, cut over
+    E / held chips of ``held`` experts each (16 sigmoid-routed experts
+    over 16 chips with an ungated shared expert; 64 softmax-routed,
+    renormalised experts over 8 chips with a gated one): the routed
+    parts that the shares give, with the shared expert counted once,
+    add up to what the uncut layer gives; each share reads at most its
+    own experts."""
+    N, h = 40, 128
+    whole = dataclasses.replace(
+        get_config(preset), dtype=jnp.float32, num_experts=E,
+        router_experts=0, expert_offset=0, num_experts_per_tok=k,
+        num_layers=4 if preset == "debug-gdn" else 2)
     params = llama.init_params(whole, jax.random.PRNGKey(4))
     lp = jax.tree.map(lambda a: a[0], params["layers"])
     x = jax.random.normal(jax.random.PRNGKey(5), (1, N, h), jnp.float32)
@@ -410,14 +417,16 @@ def test_sixteen_shares_add_up_to_the_uncut_layer():
     shared = block(dataclasses.replace(whole, routed_scaling_factor=0.0),
                    lp)[0]
     assert float(jnp.abs(shared).max()) > 0
+    assert float(jnp.abs(uncut - shared).max()) > 0
     total = shared
-    for chip in range(E):
-        cfg = dataclasses.replace(whole, num_experts=1, router_experts=E,
-                                  expert_offset=chip)
-        mine = {**lp, **{n: lp[n][chip:chip + 1]
+    for chip in range(E // held):
+        cfg = dataclasses.replace(whole, num_experts=held,
+                                  router_experts=E,
+                                  expert_offset=chip * held)
+        mine = {**lp, **{n: lp[n][chip * held:(chip + 1) * held]
                          for n in ("gate", "up", "down")}}
         part, work = block(cfg, mine)
-        assert int(work.experts_read) == 1
+        assert int(work.experts_read) <= held
         total = total + part - shared
     assert worst(total, uncut) < 1e-4 * float(jnp.abs(uncut).max())
 
