@@ -14,17 +14,18 @@ Two kernels, one contract:
 K/V pool blocks ``[N, Hkv, Bs, D]`` (models/kv.py, head-major: the
 per-(block, head) panel is a contiguous [Bs, D] tile) are streamed
 straight from HBM through *scalar-prefetched* block tables, and each
-KV byte a row needs is read exactly once. In the prefill kernel the
-grid's innermost dimension walks a row's blocks and the BlockSpec
-index map reads ``tables[b, j]`` to point the next DMA at the right
-block; blocks past a row's last query position clamp to the last live
-one and their grid steps are `pl.when`-masked away. Pallas' pipeline
-skips a copy only when an operand's block index is the one it had the
-grid step before: true of ONE operand that walks the blocks one a
-step, as here; not of R operands that take R blocks a step, where
-each clamped operand has a buffer of its own and fetches the clamped
-block again. That is why the decode kernel issues its own copies, for
-live blocks alone.
+KV byte a row needs is read exactly once a q block. In the prefill
+kernel the grid's innermost dimension walks a row's blocks, a PANEL of
+R of them a step (``prefill_tiles``), and the BlockSpec index maps read
+``tables[b, j*R + i]`` to point the step's DMAs at the right blocks;
+blocks past a q block's last query position clamp to the last live
+one and grid steps wholly past it are `pl.when`-masked away. Pallas'
+pipeline skips a copy only when an operand's block index is the one
+it had the grid step before, so each of the R operands fetches the
+clamped block once more where a row's dead blocks begin: nothing to a
+prefill chunk, whose q blocks see most of the bucket, and why the
+decode kernel (one position a row, rows of every length) issues its
+own copies, for live blocks alone.
 
 The serving path hands in the WHOLE pool ``[L, N, Hkv, Bs, D]`` and a
 ``layer`` index, a third scalar-prefetched operand that the index maps
@@ -33,12 +34,14 @@ sliced out of the buffer the step program carries (models/kv.py
 "carried, never stacked"). A bare 4-D layer is the same call on a pool
 of one layer.
 
-Prefill grid ``(B, Hkv, NQ, nb)``; per step the q block [BQ, G, D] for one kv
-head and one pool block's [Bs, D] K and V panels live in VMEM. Online
-(max, sum, acc) statistics persist in VMEM scratch across the
-``nb``-axis (sequential "arbitrary" dimension), initialized at j == 0
-and emitted at j == nb - 1 — the classic flash accumulation, with GQA
-rows flattened as t*G + g so K/V are never broadcast to query heads.
+Prefill grid ``(B, Hkv, NQ, nb / R)``; per step the q block [BQ, G, D]
+for one kv head and a panel of R pool blocks' [R*Bs, D] K and V live in
+VMEM, and go to the MXU as stored (bf16 in every cell; float32
+products, float32 softmax). Online (max, sum, acc) statistics persist
+in VMEM scratch across the last axis (sequential "arbitrary"
+dimension), initialized at j == 0 and emitted at the last step — the
+classic flash accumulation, with GQA rows flattened as t*G + g so K/V
+are never broadcast to query heads.
 
 The latent pool (one cached vector ``[c | k_rope]`` a token, every
 query head on it) has two cases of the prefill kernel. ABSORBED: the
@@ -126,23 +129,40 @@ def mode() -> str:
 
 # VMEM ceiling for the per-grid-step working set (q + acc + scores,
 # fp32): conservative slice of the ~16 MB/core budget, leaving room
-# for Pallas' double-buffered K/V panels and the output block.
+# for Pallas' double-buffered K/V panels and the output block. What a
+# path must fit to be viable at all, the latent case's q block and the
+# decode kernel's chunk are reckoned against it.
 _VMEM_WORK_BYTES = 8 * 1024 * 1024
+# and the same reckoning's ceiling for a q block of the K/V prefill
+# kernel against a PANEL of pool blocks, within VMEM_LIMIT_BYTES: set
+# from tools/kv_prefill_table.py on the v5e (PERF.md, PR 43). At 8
+# groups of 256 against 512 keys a q block of 512 positions (4096
+# rows: 27 MB by this reckoning) ran a 16 384-key call in 3.46 ms, one
+# of 256 in 3.70, one of 128 in 4.32; 1024 was not measured.
+_PANEL_WORK_BYTES = 28 * 1024 * 1024
+
+
+def _work_bytes(T: int, groups: int, head_dim: int, panel: int,
+                value_dim: int = 0) -> int:
+    """What one grid step's own arrays take: a [T*G, D] q panel (as
+    float32 and as stored), the float32 accumulator and one
+    [T*G, panel] block of float32 scores and of probabilities."""
+    rows = max(T * groups, 8)
+    return (rows * (head_dim + (value_dim or head_dim)) * 4
+            + rows * panel * 4 * 2 + rows * head_dim * 2)
 
 
 def paged_viable(T: int, groups: int, head_dim: int,
                  block_size: int, value_dim: int = 0) -> bool:
     """Can a [T*G, D] q panel + accumulator + one [T*G, Bs] score
     block hold in VMEM? T is what ONE grid step holds: a decode
-    window's positions, or one q block of a prefill chunk (the prefill
-    kernel halves a chunk's q block until this says yes, down to
-    _MIN_BLOCK_Q positions, so a path is viable where the smallest q
-    block is: ``attention_path``). value_dim: the accumulator's width
-    where it is not the keys' (the latent pool)."""
-    rows = max(T * groups, 8)
-    work = rows * (head_dim + (value_dim or head_dim)) * 4 \
-        + rows * block_size * 4 * 2 + rows * head_dim * 2
-    return work <= _VMEM_WORK_BYTES
+    window's positions, or the smallest q block of a prefill chunk
+    against ONE pool block (_MIN_BLOCK_Q positions: a path is viable
+    where that is, ``attention_path``; ``prefill_tiles`` then widens
+    both as far as the shapes allow). value_dim: the accumulator's
+    width where it is not the keys' (the latent pool)."""
+    return _work_bytes(T, groups, head_dim, block_size,
+                       value_dim) <= _VMEM_WORK_BYTES
 
 
 # the smallest q block the prefill kernel cuts a chunk into
@@ -162,24 +182,55 @@ _EXPAND_BLOCK_Q = 2048
 
 
 def _panel_blocks(nb: int, block_size: int) -> int:
-    """The pool blocks a grid step of the sparse and the expanded case
-    takes: as many as divide the kv bucket, up to _SELECT_PANEL_TOKENS
-    keys."""
+    """The most pool blocks a grid step of the prefill kernel takes: as
+    many as divide the kv bucket, up to _SELECT_PANEL_TOKENS keys."""
     return next(r for r in (8, 4, 2, 1)
                 if r * block_size <= _SELECT_PANEL_TOKENS and nb % r == 0)
 
 
-def _online_softmax(s, v, m_ref, l_ref, acc_ref) -> None:
+def prefill_tiles(T: int, groups: int, head_dim: int, nb: int,
+                  block_size: int, value_dim: int = 0) -> tuple:
+    """(q block, R): how the prefill kernel cuts a chunk of T positions
+    a row against a kv bucket of ``nb`` pool blocks, read off the
+    shapes alone. The K/V pool: R blocks a grid step, the widest panel
+    up to _SELECT_PANEL_TOKENS keys that divides the bucket, and the
+    largest q block (the chunk, halved) whose working set holds beside
+    it (_PANEL_WORK_BYTES): K and V stream once a q block, a step's
+    overhead is paid once a panel. The panel narrows before the q
+    block falls under _MIN_BLOCK_Q, so whatever is viable at one block
+    a step (``attention_path``) has its tiles. The latent pool's
+    absorbed case without a mask: one block a step and the q block
+    ``paged_viable`` allows, as it was sized (PERF.md section 7)."""
+    R = 1 if value_dim else _panel_blocks(nb, block_size)
+    limit = _VMEM_WORK_BYTES if value_dim else _PANEL_WORK_BYTES
+
+    def holds(block_q: int) -> bool:
+        return _work_bytes(block_q, groups, head_dim, R * block_size,
+                           value_dim) <= limit
+
+    while True:
+        block_q = T
+        while block_q > _MIN_BLOCK_Q and not holds(block_q):
+            block_q //= 2
+        if R == 1 or holds(block_q):
+            return block_q, R
+        R //= 2
+
+
+def _online_softmax(s, v, m_ref, l_ref, acc_ref, v_scale=None) -> None:
     """One key panel's masked scores s [rows, P] (float32) and values v
     [P, Dv] folded into the running (max, sum, accumulator) of the
     prefill kernels: the flash accumulation, float32, the probabilities
-    to the MXU in the values' dtype."""
+    to the MXU in the values' dtype. v_scale [1, P]: the int8 pool's
+    per-token dequantization, on the probabilities."""
     m_prev, l_prev = m_ref[...], l_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)                                # [rows, P]
     correction = jnp.exp(m_prev - m_new)
     m_ref[...] = m_new
     l_ref[...] = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale
     acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)               # [rows, Dv]
@@ -202,39 +253,51 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
                   quant: bool = False, window: int = 0,
                   softcap: float = 0.0, value_dim: int = 0,
                   select: bool = False, R: int = 1):
-    """One (batch row, kv head, q block, pool block) grid step.
+    """One (batch row, kv head, q block, key panel) grid step.
 
     tabs_ref   (SMEM) [B, MB]      block tables
     starts_ref (SMEM) [B]          absolute position of q[:, 0]
     layer_ref  (SMEM) [1]          the pool's layer (index maps only)
     q_ref   [1, BQ, 1, G, D]       this kv-head's query block
-    k_ref   [1, 1, 1, Bs, D]       pool block tabs[b, min(j, jmax)]
-    refs    v_ref [1, 1, 1, Bs, D], (quant only: ks/vs dequant scales [1, 1, Hkv, Bs] fp32 —
-            every kv head of the block, this step reads row h,)
+    k_ref.. R of [1, 1, 1, Bs, D]  pool blocks tabs[b, min(j*R + i, jmax)]
+                                   side by side as one [R*Bs, D] panel
+    refs    R of v_ref [1, 1, 1, Bs, D] likewise, (quant only: the
+            panel's k and v dequant scales [1, 1, Hkv, R*Bs] fp32,
+            gathered through the tables by the wrapper — every kv head
+            of the panel, this step reads row h,)
             out [1, BQ, 1, G, D], scratch m/l/acc (online softmax
             state across j)
 
+    A grid step takes R pool blocks (static; ``prefill_tiles``):
+    contexts of thousands of tokens in steps of one 64-token block
+    leave the MXU half empty and pay a grid step's overhead 256 times
+    a q block. Both dots take their operands as stored (bf16 to the
+    MXU in every cell, float32 products; the int8 pool converted to
+    q's dtype, which is exact, its per-token scales on the score
+    columns and the probabilities), as the decode kernel's do; scale,
+    soft cap, masks and the online softmax are float32, and the
+    probabilities meet V in V's dtype, as in ops/attention.py.
+
     The latent pool (value_dim > 0, static): no v_ref — the values are
-    the first value_dim columns of the K block — q_ref [1, BQ*G, D]
+    the first value_dim columns of the K panel — q_ref [1, BQ*G, D]
     and out [1, BQ*G, value_dim] come with their rows flattened by the
-    wrapper (one kv head), and the dots take the operands as stored
-    (bf16 to the MXU, float32 products), as the decode kernel's do.
+    wrapper (one kv head).
 
     select (static; the latent pool, learned sparse attention): one
     more operand after the pools, sel_ref [1, 1, 1, BQ, R*Bs] of 0 / 1:
     the positions of this step's pool blocks that each query of the
     block attends (models/kv.attend_selected); the others are masked
-    like the positions past the query. There a grid step takes R pool
-    blocks (static), R operands k_ref .. each [1, 1, 1, Bs, D] side by
-    side as one [R*Bs, D] panel: contexts of thousands of tokens in
-    steps of one 64-token block leave the MXU half empty and pay a
-    grid step's overhead 256 times a q block.
+    like the positions past the query.
     """
+    def panel(block_refs):
+        return (block_refs[0][0, 0, 0] if R == 1 else jnp.concatenate(
+            [ref[0, 0, 0] for ref in block_refs], axis=0))  # [R*Bs, D]
+
     k_refs = (k_ref,) + refs[:R - 1]
     refs = refs[R - 1:]
     block_size = R * block_size                 # the step's key panel
     if not value_dim:
-        v_ref, refs = refs[0], refs[1:]
+        v_refs, refs = refs[:R], refs[R:]
     if select:
         sel_ref, refs = refs[0], refs[1:]
     if quant:
@@ -255,12 +318,12 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     start = starts_ref[b]
-    # last block this q block can see (same formula as the index map's
-    # clamp): beyond it the DMA re-targets a resident block and the
-    # step is skipped entirely
+    # last panel this q block can see: beyond it (and, inside it, past
+    # the index map's clamp per block) the DMA re-targets a resident
+    # block and the step is skipped entirely
     max_pos = start + qi * block_q + (block_q - 1)
     jmax = jax.lax.div(max_pos, block_size)
-    # sliding window: blocks wholly before the EARLIEST query row's
+    # sliding window: panels wholly before the EARLIEST query row's
     # window are skipped the same way (window == 0 means full causal)
     jmin = (jax.lax.div(
         jnp.maximum(start + qi * block_q - (window - 1), 0), block_size)
@@ -272,26 +335,22 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
         row_ids = jax.lax.broadcasted_iota(
             jnp.int32, (rows, 1), 0) // groups
         q_pos = start + qi * block_q + row_ids                # [rows, 1]
+        k_blk = panel(k_refs)
+        v_scale = None
         if value_dim:
-            k_blk = (k_ref[0, 0, 0] if R == 1 else jnp.concatenate(
-                [ref[0, 0, 0] for ref in k_refs], axis=0))    # [Bs, D]
             v_blk = k_blk[:, :value_dim]
-            s = jax.lax.dot_general(
-                q_ref[0].astype(k_blk.dtype), k_blk,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [rows, Bs]
+            q = q_ref[0]
         else:
-            q = q_ref[0].reshape(rows, D).astype(jnp.float32) * scale
-            k_blk = k_ref[0, 0, 0].astype(jnp.float32)        # [Bs, D]
-            v_blk = v_ref[0, 0, 0].astype(jnp.float32)
+            q = q_ref[0].reshape(rows, D)
+            v_blk = panel(v_refs)
             if quant:
-                # int8 pool: dequantize the panel in VMEM (per-token
-                # scale)
-                k_blk = k_blk * ks_ref[0, 0, h][:, None]
-                v_blk = v_blk * vs_ref[0, 0, h][:, None]
-            s = jax.lax.dot_general(
-                q, k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)           # [rows, Bs]
+                k_blk, v_blk = k_blk.astype(q.dtype), v_blk.astype(q.dtype)
+                v_scale = vs_ref[0, 0, pl.ds(h, 1), :]        # [1, R*Bs]
+        s = jax.lax.dot_general(
+            q.astype(k_blk.dtype), k_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [rows, R*Bs]
+        if quant:
+            s = s * ks_ref[0, 0, pl.ds(h, 1), :]
         if softcap:
             # Gemma-2 tanh cap on RAW scores, before -inf masking
             s = softcap * jnp.tanh(s / softcap)
@@ -312,7 +371,7 @@ def _paged_kernel(tabs_ref, starts_ref, layer_ref, q_ref, k_ref, *refs,
                 mine, sel_ref[0, 0, 0], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32) > 0.5)
         _online_softmax(jnp.where(live, s, _NEG_INF), v_blk,
-                        m_ref, l_ref, acc_ref)
+                        m_ref, l_ref, acc_ref, v_scale)
 
     @pl.when(j == nb - 1)
     def _emit():
@@ -498,11 +557,12 @@ def _expanded_attention(q, pool, tables, starts, layer, expand, *,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("nb", "block_q", "interpret",
-                                    "window", "scale", "softcap",
-                                    "value_dim"))
+                   static_argnames=("nb", "block_q", "panel_blocks",
+                                    "interpret", "window", "scale",
+                                    "softcap", "value_dim"))
 def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
-                    block_q: int = 0, interpret: bool = False,
+                    block_q: int = 0, panel_blocks: int = 0,
+                    interpret: bool = False,
                     k_scales=None, v_scales=None, window: int = 0,
                     scale: float = None, softcap: float = 0.0,
                     layer=None, value_dim: int = 0, select=None,
@@ -521,8 +581,14 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     the caller discards, exactly like the jnp path.
 
     k_scales/v_scales [(L,) N, Hkv, Bs] fp32 activate the int8-pool
-    mode: panels stream from HBM as int8 (half the bytes) and
-    dequantize in VMEM next to the dot.
+    mode: panels stream from HBM as int8 (half the bytes) and go to the
+    dots as q's dtype (exact); the per-token scales multiply the score
+    columns and the probabilities.
+
+    block_q, panel_blocks (static; 0: by ``prefill_tiles``): the
+    positions a q block holds and the pool blocks a grid step takes,
+    for tests and tools/kv_prefill_table.py; the serving path gives
+    neither.
 
     The latent pool: k_pool [(L,) N, 1, Bs, W], v_pool None and
     value_dim (static) the leading columns of a key that are its value;
@@ -557,24 +623,20 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
             value_dim=value_dim, select=select)
     Hkv, Bs = k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
+    tables = jnp.asarray(tables, jnp.int32)
     MB = tables.shape[1]
     assert select is None or value_dim, "select: the latent pool only"
     if scale is None:
         scale = D ** -0.5
     quant = k_scales is not None
-    R = 1
     if select is not None:
         # the sparse case reads long contexts: _SELECT_BLOCK_Q queries
-        # against several pool blocks a step
-        R = _panel_blocks(nb, Bs)
-        block_q = block_q or _SELECT_BLOCK_Q
-    if not block_q:
-        # whole chunk per q block while VMEM allows: K/V are streamed
-        # once per (batch, head) instead of once per q block
-        block_q = T
-        while block_q > _MIN_BLOCK_Q and not paged_viable(
-                block_q, G, D, Bs, value_dim):
-            block_q //= 2
+        # against the widest panel a step
+        tiles = (_SELECT_BLOCK_Q, _panel_blocks(nb, Bs))
+    else:
+        tiles = prefill_tiles(T, G, D, nb, Bs, value_dim)
+    block_q, R = block_q or tiles[0], panel_blocks or tiles[1]
+    assert nb % R == 0, (nb, R)
     block_q = min(block_q, T)
     pad_t = (-T) % block_q
     if pad_t:
@@ -606,10 +668,10 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
         return (lyr[0], tabs[b, jj], h, 0, 0)
 
     def scale_index(b, h, qi, j, tabs, sts, lyr):
-        # the whole head axis rides in the block: the TPU lowering
-        # wants a block's second-minor dim to be a multiple of 8 or the
-        # full axis, and one head's [1, Bs] row is neither
-        return kv_index(b, h, qi, j, tabs, sts, lyr)[:2] + (0, 0)
+        # a panel's scales, the whole head axis in the block: the TPU
+        # lowering wants a block's second-minor dim to be a multiple of
+        # 8 or the full axis, and one head's [1, R*Bs] row is neither
+        return (b, j, 0, 0)
 
     def q_index(b, h, qi, j, tabs, sts, lyr):
         return (b, qi, 0) if value_dim else (b, qi, h, 0, 0)
@@ -624,18 +686,13 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
     q_block, out_block = (
         ((1, rows, D), (1, rows, Dv)) if value_dim
         else ((1, block_q, 1, G, D),) * 2)
-    in_specs = [
-        pl.BlockSpec(q_block, q_index),
-        pl.BlockSpec((1, 1, 1, Bs, D), kv_index),
-    ]
-    operands = [q5, k_pool]
-    for i in range(1, R):       # the step's further blocks of the pool
-        in_specs.append(pl.BlockSpec((1, 1, 1, Bs, D),
-                                     functools.partial(kv_index, i=i)))
-        operands.append(k_pool)
-    if not value_dim:
-        in_specs.append(pl.BlockSpec((1, 1, 1, Bs, D), kv_index))
-        operands.append(v_pool)
+    in_specs = [pl.BlockSpec(q_block, q_index)]
+    operands = [q5]
+    for pool in (k_pool,) if value_dim else (k_pool, v_pool):
+        for i in range(R):                  # the step's blocks of it
+            in_specs.append(pl.BlockSpec((1, 1, 1, Bs, D),
+                                         functools.partial(kv_index, i=i)))
+            operands.append(pool)
     if select is not None:
         # [B, T, nb*Bs] -> a [BQ, Bs] tile per (q block, pool block),
         # whole in its two minor dimensions
@@ -647,8 +704,11 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
         operands.append(select.reshape(B, nq, block_q, nb // R, R * Bs
                                        ).transpose(0, 1, 3, 2, 4))
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, Hkv, Bs), scale_index)] * 2
-        operands += [k_scales, v_scales]
+        # the rows' scales panel by panel as the score columns lie (the
+        # decode kernel's operand: its text says why gathered here)
+        in_specs += [pl.BlockSpec((1, 1, Hkv, R * Bs), scale_index)] * 2
+        operands += [_chunked_scales(sc, layer, tables, nb // R, R)
+                     for sc in (k_scales, v_scales)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -669,8 +729,7 @@ def paged_attention(q, k_pool, v_pool, tables, starts, *, nb: int,
                                  "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(jnp.asarray(tables, jnp.int32), jnp.asarray(starts, jnp.int32),
-      layer, *operands)
+    )(tables, jnp.asarray(starts, jnp.int32), layer, *operands)
 
     return out.reshape(B, Tp, H, Dv)[:, :T]
 
@@ -1118,11 +1177,12 @@ def attention_path(T: int, groups: int, head_dim: int, block_size: int,
     verify) on the decode kernel — one grid step a row, all kv heads
     and several live pool blocks a chunk, copied in by the kernel.
     ``pallas_paged``: prefill chunks on the general paged kernel, cut
-    into q blocks where the whole chunk's q panel misses VMEM (2048
-    positions x 8 query heads a kv head x 256 are 50 MB; a q block of
-    256 is 6.3): what must fit is the smallest q block the kernel cuts
-    a chunk into, so wide-GQA long chunks stay on the kernel at every
-    kv bucket and pay only K and V streamed once a q block.
+    into q blocks against panels of pool blocks (``prefill_tiles``)
+    where the whole chunk's working set misses VMEM (2048 positions x
+    8 query heads a kv head x 256 against 512 keys are 109 MB; a q
+    block of 512 is 27): what must fit is the smallest q block against
+    one pool block, so wide-GQA long chunks stay on the kernel at
+    every kv bucket and pay only K and V streamed once a q block.
     ``*_sharded``: either, shard-local per head under a tp-only mesh.
     ``*_latent``: either, over the latent pool (value_dim > 0: every
     query head on the one cached vector a token, head_dim wide, of

@@ -207,6 +207,42 @@ def test_decode_kernel_feeds_the_mxu_native_panels(topo, heads, int8):
     assert not widened, widened
 
 
+@KV
+@pytest.mark.parametrize("T,heads,nb", [
+    (256, (32, 8), 8),      # M's one-row prefill in the 512 bucket
+    (128, (16, 16), 8),     # Q's
+    (512, (32, 8), 32),     # a 512-token chunk in the 2048 bucket
+], ids=["m_256", "q_128", "m_512_kv2048"])
+def test_prefill_kernel_feeds_the_mxu_native_panels(topo, T, heads, nb,
+                                                    int8):
+    """In the K/V prefill kernel's module for the described v5e no
+    matmul takes a float32 operand, each takes a PANEL of pool blocks
+    (``prefill_tiles``: 512 keys here, where the kernel this replaced
+    took one 64-token block a grid step), and nothing converts a K or
+    V panel to float32: the pool's bf16 goes to the MXU as it lies
+    (int8: converted to bf16, which is exact), products accumulate in
+    float32 (PERF.md, PR 43)."""
+    import re
+    H, Hkv = heads
+    text = _kernel_module(_lower_attention(
+        topo, B=1, T=T, H=H, Hkv=Hkv, int8=int8, layers=4, nb=nb))
+    matmuls = re.findall(
+        r'tpu\.matmul"?\(.*?: \(vector<([\dx]+)x(\w+)>, '
+        r'vector<([\dx]+)x(\w+)>, vector<[\dx]+xf32>\)', text)
+    assert len(matmuls) == 2, matmuls
+    assert {(a, b) for _, a, _, b in matmuls} == {("bf16", "bf16")}, \
+        matmuls
+    block_q, R = pallas_paged.prefill_tiles(T, H // Hkv, D, nb, BS)
+    assert (block_q, R) == (T, 8)
+    rows = block_q * H // Hkv
+    assert {(a, b) for a, _, b, _ in matmuls} == {
+        (f"{rows}x{D}", f"{R * BS}x{D}"), (f"{rows}x{R * BS}", f"{R * BS}x{D}")}
+    widened = [m for m in re.findall(
+        r'arith\.(?:extf|sitofp|uitofp)"?\(.*?-> vector<([\dx]+)xf32>',
+        text) if int(m.split("x")[-1]) == D]
+    assert not widened, widened
+
+
 @POOL
 @KV
 @pytest.mark.parametrize("window", [0, 4096])
@@ -1075,11 +1111,12 @@ def test_kv_prefill_kernel_in_q_blocks_compiles_at_8_groups_of_256(
         topo, T, nb):
     """The prefill kernel's K/V case at 16 query / 2 kv heads of 256:
     a chunk whose whole q panel misses VMEM (paged_viable(2048, 8,
-    256, 64) is false) is cut into q blocks and compiles at the
-    longest and a short kv bucket; attention_path keeps it on the
-    kernel."""
+    256, 64) is false) is cut into q blocks of 512 against panels of
+    512 keys (``prefill_tiles``) and compiles at the longest and a
+    short kv bucket; attention_path keeps it on the kernel."""
     assert not pallas_paged.paged_viable(2048, 8, 256, 64)
     assert pallas_paged.paged_viable(256, 8, 256, 64)
+    assert pallas_paged.prefill_tiles(T, 8, 256, nb, 64) == (512, 8)
     one = SingleDeviceSharding(topo.devices[0])
 
     def s(shape, dtype):

@@ -75,33 +75,18 @@ def _paged_case(kernel, seed, T, G, Bs, D, Hkv, dtype, layer):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("T,G,Bs,D,Hkv,dtype", [
-    (1, 4, 16, 32, 2, F32),      # decode window step, GQA
-    (1, 1, 16, 32, 2, F32),      # decode, MHA (G == 1)
-    (5, 4, 16, 32, 2, F32),      # speculative window (draft + 1)
-    (48, 2, 16, 64, 2, F32),     # prefill chunk, ragged block boundary
-    (40, 4, 16, 32, 1, F32),     # one kv head: row // G over every head
-    (24, 2, 16, 128, 2, F32),    # head dim 128, as both benchmark cells
-    (32, 2, 16, 64, 2, BF16),    # the serving dtype
-])
-@WHOLE
-def test_paged_matches_dense(T, G, Bs, D, Hkv, dtype, layer):
-    _paged_case(paged_attention, T * 1000 + G, T, G, Bs, D, Hkv, dtype,
-                layer)
-
-
 PARKED = -1     # a row parked at start = MB * Bs
 
 
-def _decode_case(seed, T, G, Bs, D, Hkv, dtype, layer, *, starts, nb,
-                 window=0, softcap=0.0, int8=False):
-    """The decode kernel against the dense path on a shuffled pool, one
-    row per entry of ``starts`` (PARKED: a row that holds no request),
-    every row's table as wide as the kv bucket ``nb`` and one block
-    more, every block of the pool random: a row's dead blocks hold
-    other rows' values, and nothing may read them. The int8 pool is
-    the float pool quantized per token; both sides read the same
-    dequantized values."""
+def _pool_case(kernel, seed, T, G, Bs, D, Hkv, dtype, layer, *, starts,
+               nb, window=0, softcap=0.0, int8=False, **kernel_kw):
+    """A kernel against the dense path on a shuffled pool, one row per
+    entry of ``starts`` (PARKED: a row that holds no request), every
+    row's table as wide as the kv bucket ``nb`` and one block more,
+    every block of the pool random: a row's dead blocks hold other
+    rows' values, and nothing may read them. The int8 pool is the
+    float pool quantized per token; both sides read the same
+    dequantized values. Returns (got, want, live rows)."""
     B, H, MB = len(starts), Hkv * G, nb + 1
     key = jax.random.PRNGKey(seed)
     kk, kv, kt, kq = jax.random.split(key, 4)
@@ -114,7 +99,7 @@ def _decode_case(seed, T, G, Bs, D, Hkv, dtype, layer, *, starts, nb,
     starts = jnp.asarray([MB * Bs if s == PARKED else s for s in starts],
                          jnp.int32)
     q = jax.random.normal(kq, (B, T, H, D), dtype)
-    kw = dict(window=window, softcap=softcap)
+    kw = dict(window=window, softcap=softcap, **kernel_kw)
     if int8:
         # [N, Hkv, Bs, D]: quantize_chunk takes the amax over D
         k_pool, ks = quantize_chunk(k_pool.astype(F32))
@@ -125,14 +110,126 @@ def _decode_case(seed, T, G, Bs, D, Hkv, dtype, layer, *, starts, nb,
     else:
         k_att = gather_view(k_pool, tables, nb)
         v_att = gather_view(v_pool, tables, nb)
-    got = np.asarray(_call(paged_decode_attention, q, k_pool, v_pool,
-                           tables, starts, nb=nb, interpret=True,
-                           layer=layer, **kw), np.float32)
+    got = np.asarray(_call(kernel, q, k_pool, v_pool, tables, starts,
+                           nb=nb, interpret=True, layer=layer, **kw),
+                     np.float32)
     positions = starts[:, None] + jnp.arange(T)[None, :]
     want = np.asarray(attention_with_cache(
         q, k_att, v_att, positions, sliding_window=window or None,
         logit_softcap=softcap or None), np.float32)
-    live = np.asarray(starts) < MB * Bs
+    return got, want, np.asarray(starts) < MB * Bs
+
+
+@pytest.mark.parametrize("T,G,Bs,D,Hkv,dtype,more", [
+    (1, 4, 16, 32, 2, F32, {}),      # decode window step, GQA
+    (1, 1, 16, 32, 2, F32, {}),      # decode, MHA (G == 1)
+    (5, 4, 16, 32, 2, F32, {}),      # speculative window (draft + 1)
+    (48, 2, 16, 64, 2, F32, {}),     # prefill chunk, ragged block boundary
+    (40, 4, 16, 32, 1, F32, {}),     # one kv head: row // G over every head
+    (24, 2, 16, 128, 2, F32, {}),    # head dim 128, as both benchmark cells
+    (32, 2, 16, 64, 2, BF16, {}),    # the serving dtype
+    # a grid step takes a PANEL of pool blocks (prefill_tiles): at
+    # blocks of 16 a bucket of 16 or 24 blocks goes in panels of 8 (128
+    # keys), one of 12 in panels of 4. Chunks in q blocks of 32 whose
+    # first q block sees less than one panel (a start inside it) and
+    # whose last sees two or three; a short row beside them
+    (160, 2, 16, 32, 2, F32, dict(starts=[70, 200, 3], nb=24, block_q=32)),
+    (160, 2, 16, 32, 2, BF16, dict(starts=[70, 200, 3], nb=24,
+                                   block_q=32)),
+    (48, 4, 16, 32, 2, F32, dict(starts=[70, 33, 140], nb=12)),
+    (48, 4, 16, 64, 1, BF16, dict(starts=[70, 33, 140], nb=12)),
+    # the cells' block of 64 and heads of 128, a panel of 8 blocks of
+    # which a row fills two and a half
+    (32, 4, 64, 128, 2, BF16, dict(starts=[130, 40], nb=8)),
+    # a sliding window whose lower edge falls inside a panel (and, on
+    # the last row, holds the whole row), whole chunk and in q blocks
+    (48, 2, 16, 32, 2, F32, dict(starts=[200, 100, 20], nb=16,
+                                 window=40)),
+    (96, 2, 16, 32, 2, F32, dict(starts=[150, 260, 20], nb=24, window=72,
+                                 block_q=32)),
+    # Gemma-2's cap on the raw scores, alone and under a window
+    (48, 2, 16, 32, 2, F32, dict(starts=[200, 100, 20], nb=16,
+                                 softcap=3.0)),
+    (48, 2, 16, 32, 2, BF16, dict(starts=[200, 100, 20], nb=16,
+                                  softcap=3.0, window=40)),
+    # the int8 pool: its per-token scales on the score columns and the
+    # probabilities, panel by panel
+    (48, 4, 16, 32, 2, F32, dict(starts=[200, 100, 20], nb=16,
+                                 int8=True)),
+    (48, 2, 16, 32, 2, F32, dict(starts=[70, 33, 140], nb=12, int8=True,
+                                 window=40, softcap=3.0)),
+    (32, 4, 64, 128, 2, BF16, dict(starts=[130, 40], nb=8, int8=True)),
+    # one block a step (what the kernel took before the panels) and a
+    # forced panel narrower than the rule's: the same result
+    (48, 2, 16, 32, 2, F32, dict(starts=[200, 100, 20], nb=16,
+                                 panel_blocks=1)),
+    (48, 2, 16, 32, 2, F32, dict(starts=[200, 100, 20], nb=16,
+                                 panel_blocks=2, block_q=16)),
+])
+@WHOLE
+def test_paged_matches_dense(T, G, Bs, D, Hkv, dtype, more, layer):
+    """The prefill kernel (and, for the short windows, what it answers
+    where the decode kernel would run) matches the dense jnp path on
+    shuffled pools. bf16 inputs (the serving dtype) are held to a bf16
+    tolerance: both sides accumulate in float32 and hand the
+    probabilities to the second product in the values' dtype."""
+    if not more:
+        return _paged_case(paged_attention, T * 1000 + G, T, G, Bs, D,
+                           Hkv, dtype, layer)
+    got, want, _ = _pool_case(paged_attention, T * 1000 + G, T, G, Bs, D,
+                              Hkv, dtype, layer, **more)
+    tol = 2e-2 if dtype == BF16 else 2e-5
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("T,G,D,nb,want", [
+    # N's chunk of 2048 at 8 groups of 256, its four kv buckets: q
+    # blocks of 512 against panels of 512 keys
+    (2048, 8, 256, 256, (512, 8)), (2048, 8, 256, 128, (512, 8)),
+    (2048, 8, 256, 64, (512, 8)), (2048, 8, 256, 32, (512, 8)),
+    # M's and Q's one-row prefills in the 512 bucket: the whole chunk
+    # one q block, the whole bucket one panel; M's full-batch chunk
+    (128, 4, 128, 8, (128, 8)), (256, 4, 128, 8, (256, 8)),
+    (128, 1, 128, 8, (128, 8)), (256, 1, 128, 8, (256, 8)),
+    (512, 4, 128, 32, (512, 8)),
+    # Gemma-2's 256-wide heads (2 groups), a chunk of 512
+    (512, 2, 256, 16, (512, 8)),
+    # a bucket no panel of 8 divides; one of a single block
+    (256, 4, 128, 12, (256, 4)), (64, 4, 128, 1, (64, 1)),
+    # heads so many a kv head that 16 positions against 512 keys miss
+    # the working set: the panel narrows, the q block stays
+    (2048, 448, 64, 256, (16, 4)),
+])
+def test_prefill_tiles_follow_the_shapes(monkeypatch, T, G, D, nb, want):
+    """(q block, pool blocks a step) of the K/V prefill kernel are a
+    function of the trace-time shapes: the panel divides the kv bucket
+    and holds at most _SELECT_PANEL_TOKENS keys, the q block's working
+    set holds against it, and every shape ``attention_path`` calls
+    viable at the smallest q block and one block a step has its tiles,
+    so that what is ``pallas_paged`` by that answer stays so."""
+    from production_stack_tpu.ops import pallas_paged
+    Bs = 64
+    monkeypatch.setattr(pallas_paged, "_override", True)
+    assert pallas_paged.attention_path(T, G, D, Bs) == "pallas_paged"
+    block_q, R = pallas_paged.prefill_tiles(T, G, D, nb, Bs)
+    if want is not None:
+        assert (block_q, R) == want
+    assert nb % R == 0 and (R == 1 or R * Bs
+                            <= pallas_paged._SELECT_PANEL_TOKENS)
+    assert min(T, pallas_paged._MIN_BLOCK_Q) <= block_q <= T
+    assert (pallas_paged._work_bytes(block_q, G, D, R * Bs)
+            <= pallas_paged._PANEL_WORK_BYTES)
+    # the latent pool's absorbed case keeps one block a step and the q
+    # block it had (G's 20 heads: 64 positions, GLM-5's 64 heads: 16)
+    assert pallas_paged.prefill_tiles(T, 20, 640, nb, Bs, 512) == (
+        min(T, 64), 1)
+    assert pallas_paged.prefill_tiles(T, 64, 640, nb, Bs, 512) == (16, 1)
+
+
+def _decode_case(seed, T, G, Bs, D, Hkv, dtype, layer, **more):
+    """The decode kernel against the dense path (``_pool_case``)."""
+    got, want, live = _pool_case(paged_decode_attention, seed, T, G, Bs,
+                                 D, Hkv, dtype, layer, **more)
     tol = 2e-2 if dtype == BF16 else 2e-5
     np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
     # a parked row costs nothing and says nothing: finite zeros
