@@ -339,10 +339,15 @@ class EngineEffAccounting:
         self.experts_read = 0
         self.experts_resident = 0
         # MoE prefill dispatches: the rows their experts multiplied and
-        # the rows routed, real tokens x top-k x layers
-        # (note_expert_rows); in ``totals.prefill`` of a MoE engine
+        # the rows routed, real tokens x top-k x layers; where the
+        # layers hold a share of their router's experts, the
+        # assignments that landed here and the rounds that worked
+        # through them (note_expert_rows); in ``totals.prefill`` of a
+        # MoE engine
         self.prefill_expert_rows = 0
         self.prefill_routed_rows = 0
+        self.prefill_held_rows = 0
+        self.prefill_expert_rounds = 0
         # learned sparse attention (note_sparse): over every query a
         # decode step or a prefill chunk computed, the keys at or
         # before it, those its indexer scored and those it attended;
@@ -502,13 +507,19 @@ class EngineEffAccounting:
             else:
                 self.prefill_drained[drained] += 1
 
-    def note_expert_rows(self, expert_rows: int, routed_rows: int) -> None:
+    def note_expert_rows(self, expert_rows: int, held_rows: int,
+                         rounds: int, routed_rows: int) -> None:
         """One prefill dispatch of a MoE engine: its experts multiplied
         ``expert_rows`` rows, summed over the layers, where
-        ``routed_rows`` were routed (real tokens x top-k x layers)."""
+        ``routed_rows`` were routed (real tokens x top-k x layers);
+        ``held_rows`` of those named an expert held on this chip and
+        were worked through in ``rounds`` rounds (ops/moe.Work; both 0
+        where the layers hold every expert their routers score)."""
         with self._lock:
             self.prefill_expert_rows += expert_rows
             self.prefill_routed_rows += routed_rows
+            self.prefill_held_rows += held_rows
+            self.prefill_expert_rounds += rounds
 
     def note_state(self, scan_tokens: int = 0, prefill_keys: int = 0,
                    step_rows: int = 0) -> None:
@@ -735,7 +746,9 @@ class EngineEffAccounting:
                            "experts_resident": self.experts_resident}
                    } if self.expert_bytes else {}
             moe_rows = {"expert_rows": self.prefill_expert_rows,
-                        "routed_rows": self.prefill_routed_rows
+                        "routed_rows": self.prefill_routed_rows,
+                        "held_rows": self.prefill_held_rows,
+                        "expert_rounds": self.prefill_expert_rounds
                         } if self.expert_bytes else {}
             # rounded before the subtraction, so that what is reported
             # adds up: cpu_s + offcpu_s == phase_s, phase by phase

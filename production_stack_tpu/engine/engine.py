@@ -1344,7 +1344,8 @@ class LLMEngine:
                             (np.asarray(tops_dev[0]),
                              np.asarray(tops_dev[1])))
                     for rows_dev, routed in self._expert_rows_due:
-                        self.eff.note_expert_rows(int(rows_dev), routed)
+                        self.eff.note_expert_rows(
+                            *np.asarray(rows_dev).tolist(), routed)
                     self._expert_rows_due.clear()
                 if not self._inflight:
                     # nothing was queued behind the chunk

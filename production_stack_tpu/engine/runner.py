@@ -628,8 +628,11 @@ class ModelRunner:
         whatever R is (ops/moe.moe_mlp ``capacity_tokens``): fewer rows
         never hold less per expert than the full dispatch does.
         Returns (sampled id of each row's last real token [R], its
-        logprob [R], top ids and logprobs [R, K], cache', the rows the
-        experts multiplied, summed over the layers: None on a dense
+        logprob [R], top ids and logprobs [R, K], cache', the experts'
+        counts summed over the layers, int32 [3]: the rows they
+        multiplied, the assignments kept and the rounds run where the
+        layers hold a share of their experts (ops/moe.Work
+        ``expert_rows``, ``held_rows``, ``rounds``); None on a dense
         model).
         """
         Tb = tokens.shape[1]
@@ -651,7 +654,8 @@ class ModelRunner:
             lora_params=self._lora, adapter_ids=sampling.adapter,
             lora_scaling=self._lora_scaling, token_valid=token_valid,
             moe_capacity_tokens=self.engine_cfg.max_num_seqs * Tb)
-        expert_rows = None if work is None else work.expert_rows
+        expert_rows = None if work is None else jnp.stack(
+            [work.expert_rows, work.held_rows, work.rounds])
         with jax.named_scope("sample"):
             last = jnp.take_along_axis(
                 logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
@@ -1030,8 +1034,9 @@ class ModelRunner:
         ([max_num_seqs]), as the engine keeps them. Returns device
         (ids, logprobs, tops) — ids/logprobs [R], by row; tops None
         unless topk > 0, then ([R, K] ids, [R, K] logprobs)
-        alternatives — and the rows the experts multiplied (a device
-        int32 scalar; None on a dense model).
+        alternatives — and the experts' counts (a device int32 [3]:
+        the rows they multiplied, the assignments kept, the rounds;
+        ``_prefill_impl``; None on a dense model).
 
         Prefill executables compile lazily per (rows, chunk, kv
         bucket), each on the attention path its shape selects

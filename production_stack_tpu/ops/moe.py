@@ -52,7 +52,11 @@ helm/templates/deployment-vllm-multi.yaml:57-64; expert parallelism is a
   attention kernel takes), and what the list path needs of the mesh,
   the widths, VMEM and the kernels. Nothing is dropped at any routing: the exact path's
   sum with the zeros left out, at 1/15 (Qwen1.5-MoE) to 1/16
-  (GLM-4.7-Flash) of its arithmetic.
+  (GLM-4.7-Flash) of its arithmetic. Where the layer holds a SHARE of
+  its router's experts (below) only the assignments that name a held
+  expert are laid out at all: their list is compacted, and the
+  grouping, the kernel and the sum run on a static block of it at a
+  time, in as many rounds as the routing filled.
 
   **Capacity dispatch (large N where the kernels are off or the mesh
   shards the experts).** The GShard/Switch
@@ -84,7 +88,10 @@ helm/templates/deployment-vllm-multi.yaml:57-64; expert parallelism is a
   keeps its place in the renormalised weights and contributes nothing
   here (what that chip would add is left out, as in the reference:
   no code stands in for it); the list of experts hit, the grouped rows
-  and ``Work`` count held experts only.
+  and ``Work`` count held experts only. The grouped path sizes its
+  buffer, its gathers and its sum by the assignments that landed here
+  (``held_block``, ``_moe_grouped``), an eighth or a sixteenth of a
+  chunk's in the deployments measured, not by all N k.
 - **Experts read in tiles.** Where two slots of an expert's matrices
   miss the kernels' share of VMEM, the list and the grouped kernel
   take the expert in ``expert_tiles`` tiles of its intermediate width
@@ -111,9 +118,18 @@ from production_stack_tpu.ops import pallas_paged
 
 class Work(NamedTuple):
     """What a call's experts did (int32 scalars; models/llama.forward
-    sums them over the layers)."""
+    sums them over the layers). The last two count the rounds of the
+    grouped path where the layer holds a share of its router's experts
+    (``_moe_grouped``), and read 0 on every other path."""
     experts_read: jnp.ndarray       # experts whose weights were read
     expert_rows: jnp.ndarray        # rows the experts multiplied
+    held_rows: jnp.ndarray          # assignments the rounds worked through
+    rounds: jnp.ndarray             # rounds (blocks of assignments) run
+
+
+def _work(experts_read, expert_rows, held_rows=0, rounds=0) -> Work:
+    return Work(*(jnp.asarray(n, jnp.int32) for n in
+                  (experts_read, expert_rows, held_rows, rounds)))
 
 
 def capacity_for(n_tokens: int, num_experts: int, top_k: int,
@@ -241,6 +257,18 @@ DENSE_THRESHOLD = 64
 # MXU's 128 rows: a pass over fewer costs the same weight loads)
 GROUPED_ROWS = 128
 
+# the grouped path where the layer holds a share of its router's
+# experts: the block of assignments one round takes, in even shares
+# (the assignments of a chunk that land here when the routing is even:
+# N k E / the router's experts); rounded up to GROUPED_ROWS. Two: one
+# round at any routing near even, and the smallest block whose worst
+# case (every assignment here, N k / block rounds that each read the
+# hit experts again) stays under twice the time of the path sized by
+# N k: 1.6 x at Qwen3-Next's share, 1.8 x at GLM-5's, where one share
+# reads 2.2 x and 2.5 x and four cost 0.5-0.9 ms a layer more at even
+# routing (tools/moe_prefill_table.py --held; PERF.md, PR 44)
+HELD_BLOCK_SHARES = 2
+
 # the share of pallas_paged.VMEM_LIMIT_BYTES the kernel's scratch may
 # take; the rest is the compiler's (the float32 products, the rows).
 # The largest compiled: Qwen1.5-MoE's experts in bfloat16, 0.33
@@ -329,7 +357,11 @@ def grouped_path(rows: int, positions: int, hidden: int, inter: int,
     clause: the grouped kernel holds the same two slots). No token
     count enters: at every chunk bucket from 16 tokens up it was
     level with the exact path or faster (PERF.md, PR 39). Nothing is
-    dropped at any routing, so no capacity enters."""
+    dropped at any routing, so no capacity enters. Whether the layer
+    holds all of its router's experts or a share does not enter
+    either: the path then sizes its work by the assignments that land
+    on the share, in rounds of ``held_block`` of them
+    (``_moe_grouped``; PERF.md, PR 44), and is the same path."""
     return (positions > pallas_paged.DECODE_T_MAX
             and _experts_in_vmem(hidden, inter, weight_dtype, act_dtype,
                                  mesh))
@@ -625,6 +657,26 @@ def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
 # order and a pass's write is awaited before the next starts). Outside,
 # each assignment's row is gathered back, weighed and the k terms
 # summed in float32: ``_moe_exact``'s sum with the zeros left out.
+#
+# A share of the router's experts (``router_experts`` wider than the
+# stacks). Most assignments then name an expert on another chip, and
+# all of the above sized by N k would sort, gather and sum them to
+# multiply them by nothing. So the assignments that name a held expert
+# of a valid token are COMPACTED first, by 1-D integer work alone (a
+# prefix count over N k and one scatter of their indices), into a list
+# in token order; ``held_block`` reads a block size B off the shapes
+# (HELD_BLOCK_SHARES even shares of N k); and a loop with a traced trip
+# count, ceil(kept / B), takes the list a block a round: the block's B
+# (token, expert, weight) entries are grouped as B tokens of one choice
+# each (rank and segments from a one-hot [B, E], a buffer of B + E
+# (align - 1) + slack rows), the same Pallas call multiplies them, B
+# rows are gathered back and weighed in float32, and each is added to
+# its token's row of the [N, h] float32 sum (a scatter-add whose
+# indices are sorted: the list is in token order). No capacity and no
+# second path: with every assignment here the loop runs N k / B rounds,
+# each reading the hit experts again (about 1.6-1.8 x the time of the
+# path sized by N k at that routing: tools/moe_prefill_table.py
+# --held); with none, no round, and zeros.
 # ---------------------------------------------------------------------
 
 def _row_align(dtype) -> int:
@@ -789,18 +841,19 @@ def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
         rows_out(0, jax.lax.rem(done - 1, 2)).wait()
 
 
-def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer,
-                 held: bool = False):
-    """Every expert over the rows routed to it, combined by routing
-    weight: ``_moe_exact``'s result with nothing multiplied by a
-    weight of zero. gate/up [L, E, h, i], down [L, E, i, h] (raw or
-    int8-quantized), layer: int32 scalar, traced; held: top_i may name
-    E, an expert held elsewhere (``moe_mlp``). Returns ([N, h], the
-    experts that had a row, the rows the experts multiplied: passes x
-    GROUPED_ROWS)."""
+def _grouped_products(x, top_i, tokens, gate, up, down, act, valid, layer,
+                      held: bool):
+    """What the experts give for each assignment, unweighed: top_i
+    [M, c] names each assignment's expert, tokens [M c] the row of x
+    it multiplies (assignment-major, as top_i.reshape(-1)). The
+    assignments are laid out by expert (``_group_rows``), the rows
+    gathered into the buffer, one Pallas call multiplies each expert's
+    segment, and each assignment's row is gathered back: ([M c, h]
+    float32, zeros where the assignment is left out: an invalid row of
+    top_i or, ``held``, an expert named E; the experts that had a row;
+    the rows the experts multiplied: passes x GROUPED_ROWS)."""
     quant = _quant().is_quantized(gate)
-    N, h = x.shape
-    k = top_i.shape[1]
+    h = x.shape[1]
     L, E, _, inter = _wshape(gate)
     mats = [w["w8"] if quant else w for w in (gate, up, down)]
     tiles = expert_tiles(h, inter, mats[0].dtype, x.dtype)
@@ -811,8 +864,7 @@ def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer,
         dest, rows, seg, P = _group_rows(top_i, valid, E, align, R, held)
         # the buffer's rows by the token each holds (padding: token 0,
         # computed and never read back)
-        src = jnp.zeros((P,), jnp.int32).at[dest].set(
-            jnp.arange(N * k, dtype=jnp.int32) // k, mode="drop")
+        src = jnp.zeros((P,), jnp.int32).at[dest].set(tokens, mode="drop")
         xs = x[src]                                       # [P, h]
         ids, count = experts_hit(top_i, valid, E)
         passes = -(-rows // R)
@@ -855,17 +907,101 @@ def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer,
         )(ids, count.reshape(1), jnp.asarray(layer, jnp.int32).reshape(1),
           seg[ids], passes[ids], *operands)
     with jax.named_scope("moe_combine"):
-        # an invalid token's assignments read row 0, which may hold
-        # anything, and count as zero
+        # an assignment left out reads row 0, which may hold anything,
+        # and counts as zero
         kept = dest < P
         back = jnp.where(kept, dest, 0)
         y = ys[back].astype(jnp.float32)
         for t in range(1, tiles):       # an expert's tiles, a plane each
             y = y + ys[back + t * P].astype(jnp.float32)
         y = jnp.where(kept[:, None], y, 0.0)
-        y = jnp.sum((y * top_p.reshape(-1)[:, None]).reshape(N, k, h),
-                    axis=1)
-    return y.astype(x.dtype), count, jnp.sum(passes) * R
+    return y, count, jnp.sum(passes) * R
+
+
+def held_block(tokens: int, top_k: int, num_experts: int,
+               router_experts: int) -> int:
+    """The assignments one round of the grouped path takes where the
+    layer holds ``num_experts`` of its router's ``router_experts``:
+    HELD_BLOCK_SHARES even shares of the chunk's ``tokens`` x top_k,
+    rounded up to GROUPED_ROWS (Qwen3-Next's share, 64 of 512 top-10 at
+    2048 tokens: 5120 of 20 480; GLM-5's, 16 of 256 top-8: 2048 of
+    16 384), and no more than all of them. Read off shapes alone."""
+    R = GROUPED_ROWS
+    assignments = tokens * top_k
+    even = -(-assignments * num_experts // router_experts)
+    return min(-(-HELD_BLOCK_SHARES * even // R), -(-assignments // R)) * R
+
+
+def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer,
+                 router_experts: int = 0):
+    """Every expert over the rows routed to it, combined by routing
+    weight: ``_moe_exact``'s result with nothing multiplied by a
+    weight of zero. gate/up [L, E, h, i], down [L, E, i, h] (raw or
+    int8-quantized), layer: int32 scalar, traced. Returns ([N, h],
+    ``Work``).
+
+    router_experts (static; 0: the stacks' E): the experts the router
+    scores where the stacks hold a share of them (``moe_mlp``); top_i
+    then names E for an expert held elsewhere, and the path works on
+    the COMPACTED list of the assignments that name a held expert of a
+    valid token, ``held_block`` of them a round, in as many rounds as
+    the routing filled (a traced trip count: one at any routing near
+    even, N k / block with every assignment here, none and zeros out
+    with none). A round runs the grouping and the kernel on its block
+    as that many tokens of one choice each, weighs what comes back and
+    adds it to its token's row of the [N, h] float32 sum (the block is
+    in token order). Nothing of N k rows by E or by h is built."""
+    N, h = x.shape
+    k = top_i.shape[1]
+    E = _wshape(gate)[1]
+    A = N * k
+    if not router_experts or router_experts == E:
+        y, count, multiplied = _grouped_products(
+            x, top_i, jnp.arange(A, dtype=jnp.int32) // k, gate, up, down,
+            act, valid, layer, False)
+        with jax.named_scope("moe_combine"):
+            y = jnp.sum((y * top_p.reshape(-1)[:, None]).reshape(N, k, h),
+                        axis=1)
+        return y.astype(x.dtype), _work(count, multiplied)
+
+    B = held_block(N, k, E, router_experts)
+    with jax.named_scope("moe_group"):
+        flat_e = top_i.reshape(-1)
+        flat_p = top_p.reshape(-1)
+        keep = flat_e < E
+        if valid is not None:
+            keep = keep & jnp.repeat(valid, k)
+        # the kept assignments' places in the compacted list, which is
+        # in token order: a 1-D prefix count and a 1-D scatter
+        upto = jnp.cumsum(keep.astype(jnp.int32))
+        total = upto[-1]
+        cap = -(-A // B) * B
+        kept = jnp.zeros((cap,), jnp.int32).at[
+            jnp.where(keep, upto - 1, cap)].set(
+                jnp.arange(A, dtype=jnp.int32), mode="drop")
+        rounds = -(-total // B)
+
+    def one_round(r, carry):
+        acc, read, multiplied = carry
+        with jax.named_scope("moe_group"):
+            a = jax.lax.dynamic_slice(kept, (r * B,), (B,))
+            live = r * B + jnp.arange(B, dtype=jnp.int32) < total
+            # past the list's end: no expert (E), and a token past the
+            # last, so that the tokens stay in order for the sum
+            tok = jnp.where(live, a // k, N)
+            expert = jnp.where(live, flat_e[a], E)
+            weight = jnp.where(live, flat_p[a], 0.0)
+        y, count, rows = _grouped_products(
+            x, expert[:, None], tok, gate, up, down, act, None, layer, True)
+        with jax.named_scope("moe_combine"):
+            acc = acc.at[tok].add(y * weight[:, None], mode="drop",
+                                  indices_are_sorted=True)
+        return acc, read + count, multiplied + rows
+
+    acc, read, multiplied = jax.lax.fori_loop(
+        0, rounds, one_round,
+        (jnp.zeros((N, h), jnp.float32), jnp.int32(0), jnp.int32(0)))
+    return acc.astype(x.dtype), _work(read, multiplied, total, rounds)
 
 
 def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
@@ -967,13 +1103,12 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             f"nor grouped_path says so: {N} tokens, {positions} a row, "
             f"experts [{h}, {inter}], exact={exact}")
         if grouped_path(*shape):
-            y, count, multiplied = _moe_grouped(
-                x, top_p, top_i, gate, up, down, act, valid, layer, held)
-            return y, Work(count, multiplied)
+            return _moe_grouped(x, top_p, top_i, gate, up, down, act,
+                                valid, layer, router_w.shape[-1])
         with jax.named_scope("moe_list"):
             ids, count = experts_hit(top_i, valid, E)
         return _moe_list(x, top_p, top_i, gate, up, down, act, ids,
-                         count, layer), Work(count, count * N)
+                         count, layer), _work(count, count * N)
     # (the balanced load an expert's capacity is reckoned on is that
     # of all the router's experts)
     capacity = min(N, capacity_for(capacity_tokens or N,
@@ -986,4 +1121,4 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
     else:
         y = _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
                           valid=valid, held=held)
-    return y, Work(jnp.int32(E), jnp.int32(E * (N if exact else capacity)))
+    return y, _work(E, E * (N if exact else capacity))

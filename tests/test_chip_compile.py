@@ -972,6 +972,17 @@ def test_sparse_step_program_compiles_at_glm5_widths(
     assert (runner._attention_path(1 if program == "decode_window"
                                    else 2048, None, kv_len)
             .endswith("_sparse")) is runner.selects(kv_len)
+    if program == "prefill_chunk":
+        # the held experts' rounds (ops/moe._moe_grouped): nothing of a
+        # chunk's 2048 x 8 assignments by the hidden width is built,
+        # nor the buffer of 16 752 rows they were sorted into; a
+        # round's buffer is 2048 + 16 x 15 + 128 rows
+        from production_stack_tpu.ops import moe
+        assert moe.held_block(2048, 8, 16, 256) == 2048
+        big = re.findall(            # (int8 [16384, 6144]: o_proj)
+            r"(?:bf16|f32)\[(?:\d+,)?(?:16384|16752|33504),6144\]", hlo)
+        assert not big, big[:3]
+        assert re.search(r"bf16\[4832,6144\]", hlo)   # two tiles' planes
     runner.params = params      # (_moe_path reads the stacks' dtype)
     assert runner._moe_path(8, 1) == "list_tiled2"
     assert runner._moe_path(1, 2048) == "grouped_tiled2"
@@ -1101,6 +1112,17 @@ def test_hybrid_step_program_compiles_at_qwen3next_widths(
     assert not moved, moved
     slices = re.findall(r"= s8\[3,2048,\d+\]\S* [\w\-]+\(", hlo)
     assert not slices, slices[:3]
+    if program == "prefill_chunk":
+        # the held experts' rounds (ops/moe._moe_grouped): nothing of a
+        # chunk's 2048 x 10 assignments by the hidden width is built,
+        # nor the buffer of 21 568 rows they were sorted into; a
+        # round's buffer is 5120 + 64 x 15 + 128 rows
+        from production_stack_tpu.ops import moe
+        assert moe.held_block(2048, 10, 64, 512) == 5120
+        big = re.findall(
+            r"(?:bf16|f32)\[(?:\d+,)?(?:20480|21568),2048\]", hlo)
+        assert not big, big[:3]
+        assert re.search(r"bf16\[6208,2048\]", hlo)
     assert (compiled.memory_analysis().alias_size_in_bytes
             >= 2 * P * N * 2 * BS * 256 * 2 + 3 * P * 9 * 32 * 128 * 128 * 4)
     _fits(compiled, f"qwen3-next share {program}")

@@ -431,6 +431,45 @@ def test_sixteen_shares_add_up_to_the_uncut_layer(preset, E, k, held):
     assert worst(total, uncut) < 1e-4 * float(jnp.abs(uncut).max())
 
 
+@pytest.mark.parametrize("chips", [4, 8], ids=["4_chips_of_4",
+                                                "8_chips_of_2"])
+def test_shares_on_the_grouped_path_add_up_to_the_uncut_layer(chips):
+    """A prefill chunk of 128 tokens through 16 sigmoid-routed experts
+    top-4 on the grouped path (the kernels in interpret mode): the
+    layer whole (every expert held: today's path, no rounds) against
+    the sum of what ``chips`` shares of it give, each working through
+    the assignments that landed on it in rounds of its block; the
+    shares' ``held_rows`` add up to every assignment."""
+    E, k, N, h, i = 16, 4, 128, 128, 128
+    held = E // chips
+    ks = jax.random.split(jax.random.PRNGKey(chips), 6)
+    x = jax.random.normal(ks[0], (N, h), jnp.float32)
+    rw = jax.random.normal(ks[1], (h, E), jnp.float32) * 0.3
+    bias = 0.1 * jax.random.normal(ks[2], (E,), jnp.float32)
+    stacks = [jax.random.normal(kk, dims, jnp.float32) * 0.1
+              for kk, dims in zip(ks[3:], ((1, E, h, i), (1, E, h, i),
+                                           (1, E, i, h)))]
+    kw = dict(top_k=k, layer=jnp.int32(0), positions=N,
+              router_score="sigmoid", router_bias=bias, routed_scale=2.5)
+    pallas_paged.set_flash_enabled(True)
+    try:
+        uncut, work = moe.moe_mlp(x, rw, *stacks, **kw)
+        assert int(work.rounds) == 0 and int(work.held_rows) == 0
+        total, landed = jnp.zeros_like(uncut), 0
+        for chip in range(chips):
+            mine = [w[:, chip * held:(chip + 1) * held] for w in stacks]
+            part, work = moe.moe_mlp(x, rw, *mine,
+                                     expert_offset=chip * held, **kw)
+            block = moe.held_block(N, k, held, E)
+            assert int(work.rounds) == -(-int(work.held_rows) // block)
+            assert int(work.experts_read) <= held * int(work.rounds)
+            total, landed = total + part, landed + int(work.held_rows)
+    finally:
+        pallas_paged.set_flash_enabled(None)
+    assert landed == N * k
+    assert worst(total, uncut) < 1e-5 * float(jnp.abs(uncut).max())
+
+
 @pytest.mark.parametrize("path", ["exact", "dispatch", "list", "grouped"])
 def test_every_expert_path_adds_only_the_held_experts_part(path):
     """Experts 4-7 of a router of 8 held here (debug-dsa's share): each
@@ -689,7 +728,9 @@ def test_a_launcher_that_warms_the_full_batch_runs_wide_chunks_by_row():
         np.full((4,), 20, np.int32), SamplingParams.filled(4), 64)
     assert ids.shape == (4,) and lps.shape == (4,) and tops is None
     assert {k[0] for k in runner._prefill_fns} == {1}
-    assert int(rows) > 0
+    # the experts' counts, the four dispatches' summed: rows
+    # multiplied, assignments kept, rounds (kernels off here: none)
+    assert rows.shape == (3,) and int(rows[0]) > 0
 
 
 def test_rehearsal_of_the_cell_at_a_tiny_file(tmp_path):

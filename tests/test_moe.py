@@ -707,6 +707,157 @@ def test_grouped_path_drops_nothing_where_the_dispatch_does(kernels_on):
     assert rows_off(grouped).sum() == 0
 
 
+# the chip's share of the experts on the grouped path: the compacted
+# list of the assignments that land here, a block of them a round
+HELD_CASES = {
+    # name: (held choices of each token: "even" draws them at the even
+    #        share, a number gives every token that many, a list the
+    #        leading tokens theirs and the others none; real tokens
+    #        (None: all); dtype; weights; tiles)
+    # 128 tokens top-4, 8 of a router's 64 experts held: 512
+    # assignments, a block of 2 x 64 -> 128
+    "near-even-one-round": ("even", None, "float32", "raw", 1),
+    "every-assignment-here-4-rounds": (4, None, "float32", "raw", 1),
+    "none-here-no-round": (0, None, "float32", "raw", 1),
+    "total-exactly-a-block": ([4] * 32, None, "float32", "raw", 1),
+    "total-a-block-and-one": ([4] * 32 + [1], None, "float32", "raw", 1),
+    # tokens 100.. are padding whose choices are all held here
+    "padding-that-would-land-here": ([2] * 100 + [4] * 28, 100, "float32",
+                                     "raw", 1),
+    "bf16-int8-two-rounds": ([3] * 70, None, "bfloat16", "int8", 1),
+    "tiled-two-rounds": ([4] * 40, None, "bfloat16", "int8", 2),
+}
+
+
+def _held_case(name, N=128, k=4, E=8, h=128, i=256, L=2):
+    """x [N, h], the routing as moe_mlp hands it over (an expert held
+    elsewhere named E, its weight zero; an invalid token's weights
+    zero), [L, E, ...] stacks, the valid mask."""
+    here, real, dtype, weights, _ = HELD_CASES[name]
+    rng = np.random.default_rng(len(name))
+    if isinstance(here, str):       # 8 of 64: an eighth of the choices
+        here = rng.binomial(k, E / 64, N)
+    elif isinstance(here, int):
+        here = [here] * N
+    here = np.concatenate([here, np.zeros(N - len(here), int)]).astype(int)
+    top_i = np.full((N, k), E, np.int32)
+    for t in range(N):
+        top_i[t, rng.permutation(k)[:here[t]]] = rng.permutation(E)[:here[t]]
+    valid = None if real is None else np.arange(N) < real
+    top_p = rng.uniform(0.05, 0.5, (N, k)).astype(np.float32)
+    top_p[top_i == E] = 0.0
+    if valid is not None:
+        top_p[~valid] = 0.0
+    dtype = jnp.dtype(dtype)
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 4)
+    x = jax.random.normal(ks[0], (N, h), jnp.float32).astype(dtype)
+    stacks = [(jax.random.normal(kk, dims, jnp.float32) * 0.1).astype(dtype)
+              for kk, dims in zip(ks[1:], ((L, E, h, i), (L, E, h, i),
+                                           (L, E, i, h)))]
+    if weights == "int8":
+        stacks = [quant.quantize_tensor(w) for w in stacks]
+    return x, jnp.asarray(top_p), jnp.asarray(top_i), stacks, valid
+
+
+@pytest.mark.parametrize("case", HELD_CASES)
+def test_held_grouped_path_matches_exact_and_reference(
+        kernels_on, monkeypatch, case):
+    """``_moe_grouped`` told that the router scores 64 experts where
+    the stacks hold 8: the sum over the held experts alone, against
+    _moe_exact on the layer's slice and the float32 per-token
+    reference, at every filling of the rounds; ``Work`` counts the
+    assignments kept, the rounds, and over the rounds the experts
+    that had a row and the passes' rows."""
+    x, top_p, top_i, stacks, valid = _held_case(case)
+    N, k = top_i.shape
+    E, tiles = 8, HELD_CASES[case][4]
+    if tiles > 1:       # two slots of a HALF of an expert fit, no more
+        monkeypatch.setattr(
+            moe, "_LIST_VMEM_SHARE",
+            1.5 * moe.list_scratch_bytes(128, 128, jnp.int8, jnp.bfloat16)
+            / pallas_paged.VMEM_LIMIT_BYTES)
+    assert moe.expert_tiles(128, 256, moe.stored_dtype(stacks[0]),
+                            x.dtype) == tiles
+    B = moe.held_block(N, k, E, 64)
+    assert B == 128
+    layer = 1
+    got, work = jax.jit(lambda x, *w: moe._moe_grouped(
+        x, top_p, top_i, *w, jax.nn.silu,
+        None if valid is None else jnp.asarray(valid), jnp.int32(layer),
+        64))(x, *stacks)
+    assert got.dtype == x.dtype and got.shape == x.shape
+
+    v = np.ones(N, bool) if valid is None else valid
+    flat = np.asarray(top_i).reshape(-1)
+    kept = flat[(flat < E) & np.repeat(v, k)]     # in token order
+    assert int(work.held_rows) == len(kept)
+    assert int(work.rounds) == -(-len(kept) // B)
+    blocks = [np.bincount(kept[r:r + B], minlength=E)
+              for r in range(0, len(kept), B)]
+    assert int(work.experts_read) == sum((b > 0).sum() for b in blocks)
+    R = moe.GROUPED_ROWS
+    assert int(work.expert_rows) == sum((-(-b // R) * R).sum()
+                                        for b in blocks)
+    want_rounds = {"near-even-one-round": 1, "none-here-no-round": 0,
+                   "every-assignment-here-4-rounds": 4,
+                   "total-exactly-a-block": 1, "total-a-block-and-one": 2,
+                   "padding-that-would-land-here": 2,
+                   "bf16-int8-two-rounds": 2, "tiled-two-rounds": 2}
+    assert int(work.rounds) == want_rounds[case]
+
+    one = [jax.tree_util.tree_map(lambda a: a[layer], w) for w in stacks]
+    exact = moe._moe_exact(x, top_p, top_i, *one, jax.nn.silu)
+    ref = _reference_routed(
+        x.astype(jnp.float32), top_p, np.minimum(np.asarray(top_i), E - 1),
+        *(_float32(w) for w in one), v)           # (weight 0 elsewhere)
+    got = np.asarray(got.astype(jnp.float32))
+    scale = max(np.abs(ref).max(), 1e-6)
+    loose = x.dtype == jnp.bfloat16
+    assert np.abs(got - np.asarray(exact.astype(jnp.float32))).max() \
+        <= (0.03 if loose else 1e-5) * scale
+    assert np.abs(got - ref).max() <= (0.02 if loose else 1e-5) * scale
+    assert (got[~v] == 0).all()
+    if not len(kept):
+        assert (got == 0).all()
+
+
+def _primitives(jaxpr):
+    """The primitives of a traced program by name, those of the loops'
+    and calls' bodies too, a Pallas kernel's own left out."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _primitives(sub)
+    return names
+
+
+def test_grouped_path_runs_no_rounds_where_every_expert_is_held(
+        kernels_on):
+    """The router scores exactly the stacks' experts: no loop over
+    rounds, no sum by token, and ``Work`` counts none; told of a wider
+    router the same call traces both."""
+    x, top_p, top_i, stacks, _ = _held_case("near-even-one-round")
+    top_i = jnp.minimum(top_i, 7)
+
+    def traced(router_experts):
+        return _primitives(jax.make_jaxpr(lambda x, *w: moe._moe_grouped(
+            x, top_p, top_i, *w, jax.nn.silu, None, jnp.int32(0),
+            router_experts))(x, *stacks).jaxpr)
+
+    for own in (0, 8):
+        names = traced(own)
+        assert names.count("pallas_call") == 1
+        assert not {"while", "scatter-add"} & set(names)
+    assert {"while", "scatter-add", "pallas_call"} <= set(traced(64))
+    _, work = moe._moe_grouped(x, top_p, top_i, *stacks, jax.nn.silu, None,
+                               jnp.int32(0))
+    assert int(work.held_rows) == 0 and int(work.rounds) == 0
+    assert int(work.expert_rows) >= 128
+
+
 @pytest.mark.parametrize("what,rows,positions,widths,mesh,kernels,want", [
     ("qwen one-row prefill, 256 tokens", 1, 256, QWEN, None, True, True),
     ("qwen one-row prefill, 128 tokens", 1, 128, QWEN, None, True, True),
@@ -985,3 +1136,31 @@ def test_tiled_kernels_equal_the_untiled_ones(kernels_on, monkeypatch,
         assert np.abs(tiled - whole).max() <= 0.02 * scale
         assert int(tiled_work.experts_read) == int(work.experts_read)
         assert int(tiled_work.expert_rows) == int(work.expert_rows)
+
+
+def test_the_held_table_tool_rehearses_on_the_cpu():
+    """tools/moe_prefill_table.py --held at tiny widths, the kernels
+    in interpret mode (a process of its own: the tool sets the block's
+    shares and the kernels' switch for itself): a row for each block
+    size and routing, whose rounds are what its kept assignments fill,
+    and with the selection forced here more land here than as routed."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "tools", "moe_prefill_table.py"),
+         "--held", "--allow-cpu", "--repeat", "1", "--layers", "1",
+         "--stack", "1", "--held-models", "glm5-share"],
+        capture_output=True, text=True, timeout=600,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    rows = json.loads(proc.stdout.strip().splitlines()[-1])["rows"]
+    assert [(r["routing"], r["shares"]) for r in rows] == [
+        (routing, shares) for routing in ("as_routed", "all_here")
+        for shares in (1, 2, 4)]
+    for r in rows:
+        _, _, kept, rounds = r["work_first_layer"]
+        assert rounds == -(-kept // r["block"]) and r["us"] > 0
+    assert rows[3]["work_first_layer"][2] > rows[0]["work_first_layer"][2]

@@ -524,8 +524,8 @@ def test_debug_perf_names_no_experts_path_for_a_dense_model(served):
     _, perf, _ = served
     assert perf["device"]["moe_paths"] == {}
     assert perf["device"]["attention_paths"]
-    assert not {"expert_rows", "routed_rows"} & set(
-        perf["totals"]["prefill"])
+    assert not {"expert_rows", "routed_rows", "held_rows",
+                "expert_rounds"} & set(perf["totals"]["prefill"])
 
 
 def test_debug_perf_counts_the_rows_a_prefills_experts_multiplied():
@@ -540,6 +540,8 @@ def test_debug_perf_counts_the_rows_a_prefills_experts_multiplied():
         # debug-moe: 2 layers x 4 experts, top-2
         assert pre["routed_rows"] == pre["real"] * 2 * 2
         assert pre["expert_rows"] == (pre["real"] + pre["pad"]) * 4 * 2
+        # no rounds outside the grouped path of a held share
+        assert pre["held_rows"] == 0 and pre["expert_rounds"] == 0
     assert second["prefill"]["routed_rows"] > first["prefill"]["routed_rows"]
 
 
@@ -577,6 +579,35 @@ def test_debug_perf_follows_the_grouped_path():
     assert pre["expert_rows"] % R == 0
     assert R * 2 <= pre["expert_rows"] <= (
         pre["routed_rows"] // R + 2 * 16) * R
+    # every expert the router scores is held: nothing is compacted
+    assert pre["held_rows"] == 0 and pre["expert_rounds"] == 0
+
+
+def test_debug_perf_counts_the_rounds_of_a_held_share():
+    """debug-dsa holds experts 4-7 of a router of 8 (two expert layers,
+    top-2), the kernels forced on in interpret mode, a chunk bucket of
+    128 tokens: the prefill's experts run grouped over the assignments
+    that landed here, ``held_rows`` of the ``routed_rows``, in one
+    round a layer and dispatch (a block is all 256 of the chunk's
+    assignments: twice the even share)."""
+    from production_stack_tpu.ops import moe, pallas_paged
+    pallas_paged.set_flash_enabled(True)
+    try:
+        (perf,) = _moe_totals_after_each(
+            "debug-dsa", ["which of my rows land on this chip"], whole=True,
+            max_model_len=256, prefill_chunk=128, prefill_buckets=(128,))
+    finally:
+        pallas_paged.set_flash_enabled(None)
+    assert {path for key, path in perf["device"]["moe_paths"].items()
+            if key.startswith("prefill")} == {"grouped"}
+    pre = perf["totals"]["prefill"]
+    assert moe.held_block(128, 2, 4, 8) == 256
+    assert pre["routed_rows"] == pre["real"] * 2 * 2 > 0
+    assert 0 < pre["held_rows"] < pre["routed_rows"]
+    assert pre["expert_rounds"] == 2 * pre["dispatches"]
+    R = moe.GROUPED_ROWS
+    assert pre["expert_rows"] % R == 0
+    assert pre["held_rows"] <= pre["expert_rows"] <= 2 * 4 * R
 
 
 def test_debug_profile_captures_and_refuses_a_second(engine):

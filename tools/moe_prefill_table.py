@@ -24,6 +24,21 @@ layer of
 Each is the median of ``--repeat`` calls of a jitted chain of
 ``--layers`` layers (the output of one the input of the next), over
 the chain's length. One JSON line last.
+
+``--held`` (PERF.md, PR 44; the table behind
+``ops/moe.HELD_BLOCK_SHARES``): a chip's SHARE of an expert layer as
+the model, Qwen3-Next's (64 of 512 softmax-routed experts top-10 of
+2048 x 512) and GLM-5's (16 of 256 sigmoid-routed top-8 of 6144 x
+2048, read in two tiles), one chunk of 2048 tokens: microseconds a
+layer of ``moe_mlp``'s grouped path with blocks of 1, 2 and 4 even
+shares of the chunk's assignments a round, at the routing the weights
+give (an eighth, a sixteenth of the assignments land here) and with
+every assignment forced onto the held experts (a selection bias: the
+worst case, N k / block rounds), with what ``Work`` counted. Copied
+into a tree whose grouped path has no rounds it times that tree's one
+path (``"shares": 0``: the "before"). ``--outputs DIR``: the first
+layer's sum of each row is kept there, and compared with what a run
+before this one left (the other tree's, in the same call).
 """
 
 import argparse
@@ -47,6 +62,116 @@ SHAPES = ((1, 16, 1.0), (1, 64, 1.0), (1, 128, 1.0), (1, 256, 1.0),
           (1, 256, 0.75), (1, 512, 1.0), (16, 128, 1.0), (16, 256, 1.0),
           (16, 256, 0.625))
 HBM_BYTES_PER_S = 819e9
+HELD = {
+    # name: (router's experts, held, hidden, inter, top-k, router score,
+    #        routed scale)
+    "qwen3next-share": (512, 64, 2048, 512, 10, "softmax", 1.0),
+    "glm5-share": (256, 16, 6144, 2048, 8, "sigmoid", 2.5),
+}
+HELD_TOKENS = 2048
+
+
+def held_table(args, timed, platform):
+    """The rows of ``--held`` (module text)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from production_stack_tpu.models import quant
+    from production_stack_tpu.ops import moe
+
+    L = args.stack
+    layers = jnp.arange(args.layers, dtype=jnp.int32) % L
+    rounds_built = hasattr(moe, "HELD_BLOCK_SHARES")
+    table = []
+    for name in args.held_models.split(","):
+        E_all, E, h, inter, k, score, scale = HELD[name]
+        N = HELD_TOKENS
+        if platform == "cpu":
+            E_all, E, h, inter, N = E_all // 8, E // 8, 128, 256, 256
+        keys = iter(jax.random.split(jax.random.PRNGKey(len(name)), 8))
+        stacks = [quant.quantize_tensor(
+            (0.02 * jax.random.normal(next(keys), dims, jnp.float32)
+             ).astype(jnp.bfloat16))
+            for dims in ((L, E, h, inter), (L, E, h, inter),
+                         (L, E, inter, h))]
+        router = (0.02 * jax.random.normal(next(keys), (h, E_all))
+                  ).astype(jnp.bfloat16)
+        x = jax.random.normal(next(keys), (N, h)).astype(jnp.bfloat16)
+        # the selection alone moves: every choice a held expert
+        forced = jnp.where(jnp.arange(E_all) < E, 10.0, 0.0)
+        for routing, bias in (("as_routed", None), ("all_here", forced)):
+
+            def chain(shares, bias=bias):
+                def run(x, *stacks):
+                    if shares:
+                        moe.HELD_BLOCK_SHARES = shares
+
+                    def body(x, layer):
+                        y, work = moe.moe_mlp(
+                            x, router, *stacks, top_k=k, layer=layer,
+                            positions=N, router_score=score,
+                            router_bias=bias, routed_scale=scale)
+                        # the next layer's input: the residual stream
+                        # at the input's scale (a token with nothing
+                        # here keeps its row: the share's output alone
+                        # is zeros there, which every router sends to
+                        # the same experts)
+                        r32 = x.astype(jnp.float32) + y.astype(jnp.float32)
+                        nxt = (r32 * jax.lax.rsqrt(jnp.mean(
+                            r32 * r32, axis=-1, keepdims=True) + 1e-6)
+                            ).astype(jnp.bfloat16)
+                        return nxt, (y, jnp.stack(work))
+                    return jax.lax.scan(body, x, layers)[1]
+                return jax.jit(run)
+
+            for shares in ((1, 2, 4) if rounds_built else (0,)):
+                (got, work), us = timed(chain(shares), x, *stacks)
+                row = {"model": name, "tokens": N, "routing": routing,
+                       "shares": shares, "us": round(us, 1),
+                       "work_first_layer": np.asarray(work[0]).tolist(),
+                       "work_all_layers": np.asarray(work).sum(0).tolist()}
+                if shares:
+                    row["block"] = moe.held_block(N, k, E, E_all)
+                if args.outputs and shares in (0, 2):
+                    first = np.asarray(got[0].astype(jnp.float32))
+                    path = os.path.join(args.outputs,
+                                        f"{name}.{routing}.npy")
+                    if os.path.exists(path):
+                        before = np.load(path)
+                        row["largest_difference_from_before"] = float(
+                            np.abs(first - before).max())
+                        row["largest_before"] = float(np.abs(before).max())
+                    else:
+                        os.makedirs(args.outputs, exist_ok=True)
+                        np.save(path, first)
+                if args.profile and shares in (0, 2):
+                    row["top_ops_us_a_layer"] = top_ops(
+                        os.path.join(args.profile, f"{name}.{routing}"),
+                        chain(shares), (x, *stacks), args.layers)
+                table.append(row)
+                print(json.dumps(row), file=sys.stderr, flush=True)
+        del stacks
+    return table
+
+
+def top_ops(trace_dir, fn, operands, layers, calls=3):
+    """The device operations of ``calls`` runs of fn that took most of
+    their own time, in microseconds a layer (chipbench/xplane.py reads
+    the capture)."""
+    import jax
+
+    from chipbench import xplane
+    jax.block_until_ready(fn(*operands))
+    with jax.profiler.trace(trace_dir):
+        for _ in range(calls):
+            jax.block_until_ready(fn(*operands))
+    try:
+        top = xplane.reduce_file(xplane.find_xplane(trace_dir))["top_ops"]
+    except ValueError:      # a rehearsal on the CPU: no device plane
+        return []
+    return [[name, round(1e6 * sec / (calls * layers), 1)]
+            for name, sec in top]
 
 
 def main(argv=None) -> int:
@@ -59,6 +184,16 @@ def main(argv=None) -> int:
     ap.add_argument("--models", default=",".join(MODELS))
     ap.add_argument("--pass-rows", default="16,64,128,256")
     ap.add_argument("--out", default="")
+    ap.add_argument("--held", action="store_true",
+                    help="the table of a chip's share of the experts "
+                    "(module text) instead of the whole-layer one")
+    ap.add_argument("--held-models", default=",".join(HELD))
+    ap.add_argument("--profile", default="",
+                    help="--held: capture three calls of each row here "
+                    "and list the operations that took most of a layer")
+    ap.add_argument("--outputs", default="",
+                    help="--held: keep the first layer's sums here, "
+                    "compare with what is there")
     ap.add_argument("--allow-cpu", action="store_true",
                     help="rehearse on the CPU: widths 128 x 256, the "
                     "kernels in interpret mode, the first four shapes")
@@ -93,8 +228,8 @@ def main(argv=None) -> int:
             times.append(time.perf_counter() - t0)
         return out, 1e6 * float(np.median(times)) / args.layers
 
-    table = []
-    for name in args.models.split(","):
+    table = held_table(args, timed, dev.platform) if args.held else []
+    for name in [] if args.held else args.models.split(","):
         E, h, inter, score, bias_sd, renorm, scale = MODELS[name]
         if dev.platform == "cpu":
             h, inter = 128, 256
@@ -156,9 +291,9 @@ def main(argv=None) -> int:
 
                     def body(x, layer):
                         top_p, top_i = routed(x)
-                        y, _, multiplied = moe._moe_grouped(
+                        y, work = moe._moe_grouped(
                             x, top_p, top_i, *stacks, act, valid, layer)
-                        return renormed(y), (y, multiplied)
+                        return renormed(y), (y, work.expert_rows)
                     return jax.lax.scan(body, x, layers)[1]
                 return jax.jit(run)
 
