@@ -25,7 +25,7 @@ to make before spending chip time on a change to the step programs.
 """
 
 import os
-from functools import partial
+from functools import cached_property, partial
 
 import numpy as np
 import pytest
@@ -64,6 +64,18 @@ def topo():
     yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def compiler_defaults():
+    """tests/conftest.py builds the suite's CPU executables with most
+    optimisations off. These tests read the TPU compiler's OPTIMISED HLO
+    and what it fits into the chip, so this module compiles at the
+    compiler's defaults."""
+    was = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
+    yield
+    jax.config.update("jax_disable_most_optimizations", was)
 
 
 def _placements(topo, tp: int, layers: int = 0):
@@ -376,7 +388,7 @@ def _fits(compiled, what: str) -> None:
     assert need < HBM_BYTES, what
 
 
-def _compile_decode_window(runner, params, cache, rep, rows: int = 0):
+def _lower_decode_window(runner, params, cache, rep, rows: int = 0):
     """A greedy decode window of 8 steps at the first kv bucket, the
     pool donated, as the runner jits it, at the batch bucket ``rows``
     (0: all max_num_seqs)."""
@@ -387,12 +399,11 @@ def _compile_decode_window(runner, params, cache, rep, rows: int = 0):
     return fn.lower(
         params, cache, a["tables"], rep((B,), jnp.int32),
         rep((B,), jnp.int32), a["sampling"], a["key"], a["guide_next"],
-        a["guide_id"], a["guide_state"], a["counts"], a["seen"]
-    ).compile()
+        a["guide_id"], a["guide_state"], a["counts"], a["seen"])
 
 
-def _compile_prefill_chunk(runner, params, cache, rep, Tb: int,
-                           rows: int = 0):
+def _lower_prefill_chunk(runner, params, cache, rep, Tb: int,
+                         rows: int = 0):
     """A prefill chunk of ``rows`` rows (0: all max_num_seqs); what is
     kept per slot keeps max_num_seqs rows and is gathered by slots."""
     B = runner.engine_cfg.max_num_seqs
@@ -405,13 +416,52 @@ def _compile_prefill_chunk(runner, params, cache, rep, Tb: int,
         rep((R, Tb), jnp.int32),
         rep((R,), jnp.int32), rep((R,), jnp.int32), a["sampling"],
         a["key"], a["guide_next"], a["guide_id"], a["guide_state"],
-        a["counts"], a["seen"]).compile()
+        a["counts"], a["seen"])
+
+
+class _StepProgram:
+    """One step program lowered for the described chip, compiled when
+    first asked for; every test that asserts on it reads this one."""
+
+    def __init__(self, lowered):
+        self.lowered = lowered
+
+    @cached_property
+    def compiled(self):
+        return self.lowered.compile()
+
+    @cached_property
+    def hlo(self) -> str:
+        return self.compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def step_program(topo):
+    """(model, layers, tokens, rows) -> the _StepProgram of a runner's
+    decode window (``tokens`` 0) or prefill chunk of ``tokens`` positions
+    a row over a pool of 97 blocks, at the batch bucket ``rows`` (0: all
+    max_num_seqs) and the 512 kv bucket, on one described chip: lowered
+    and compiled once a worker, whichever test asks first
+    (docs/testing.md; the persistent cache cannot keep a compile for a
+    described device). Ask with ``tpu_branches`` in force."""
+    made = {}
+
+    def get(model: str, layers: int, *, tokens: int = 0, rows: int = 0):
+        key = (model, layers, tokens, rows)
+        if key not in made:
+            shapes = _runner_shapes(topo, 1, layers=layers, kv_blocks=97,
+                                    model=model)
+            made[key] = _StepProgram(
+                _lower_prefill_chunk(*shapes, tokens, rows) if tokens
+                else _lower_decode_window(*shapes, rows))
+        return made[key]
+    return get
 
 
 @pytest.mark.parametrize("program", ["decode_window", "prefill_chunk",
                                      "prefill_chunk_1row",
                                      "prefill_chunk_2rows"])
-def test_step_program_never_copies_the_pool(topo, tpu_branches,
+def test_step_program_never_copies_the_pool(tpu_branches, step_program,
                                             program):
     """Mistral head geometry, two layers, a pool of 97 blocks: in the
     optimised HLO no copy, dynamic-slice or dynamic-update-slice (nor
@@ -424,16 +474,13 @@ def test_step_program_never_copies_the_pool(topo, tpu_branches,
     back, per layer (models/kv.py: appends rewrite whole blocks)."""
     import re
     L, N = 2, 97
-    runner, params, cache, rep = _runner_shapes(topo, 1, layers=L,
-                                                kv_blocks=N)
     if program == "decode_window":
-        compiled = _compile_decode_window(runner, params, cache, rep)
+        made = step_program("mistral-7b", L)
     else:
         rows = {"prefill_chunk": 0, "prefill_chunk_1row": 1,
                 "prefill_chunk_2rows": 2}[program]
-        compiled = _compile_prefill_chunk(runner, params, cache, rep,
-                                          128, rows)
-    hlo = compiled.as_text()
+        made = step_program("mistral-7b", L, tokens=128, rows=rows)
+    compiled, hlo = made.compiled, made.hlo
     assert "tpu_custom_call" in hlo
     # "%name = bf16[2,97,8,64,128]{layout} opcode(": a pool-shaped result
     pool_result = re.compile(
@@ -512,7 +559,8 @@ def _stack_makers(hlo: str, dims: str) -> list:
                                    "bitcast")]
 
 
-def test_decode_window_reads_the_experts_in_place(topo, tpu_branches):
+def test_decode_window_reads_the_experts_in_place(tpu_branches,
+                                                  step_program):
     """One decode window of the runner at the Qwen1.5-MoE geometry with
     two layers, compiled whole: the expert matmuls are the list path's
     custom call, and nothing in the optimised HLO yields an array of
@@ -523,9 +571,7 @@ def test_decode_window_reads_the_experts_in_place(topo, tpu_branches):
     ``constant_dynamic-slice_fusion.6 s8[1,4096,4096]`` is that kind
     of copy), so models/llama.forward closes over the stacks."""
     L = 2
-    runner, params, cache, rep = _runner_shapes(
-        topo, 1, layers=L, kv_blocks=97, model="qwen1.5-moe-a2.7b")
-    hlo = _compile_decode_window(runner, params, cache, rep).as_text()
+    hlo = step_program("qwen1.5-moe-a2.7b", L).hlo
     assert "moe_list_experts" in hlo
     E, h, i = (QWEN_MOE[n] for n in "Ehi")
     stack = r"(?:(?:{}|1),)?{},(?:{},{}|{},{})".format(L, E, h, i, i, h)
@@ -534,17 +580,14 @@ def test_decode_window_reads_the_experts_in_place(topo, tpu_branches):
 
 
 @pytest.mark.parametrize("rows", [1, 4])
-def test_mixtral_decode_window_keeps_the_exact_path(topo, tpu_branches,
-                                                    rows):
+def test_mixtral_decode_window_keeps_the_exact_path(tpu_branches,
+                                                    step_program, rows):
     """Mixtral-8x7B unsharded, two layers: one expert matrix is 58.7 MB
     in int8, two slots of three miss VMEM (moe.list_path), so a decode
     window of a small batch bucket (4 rows x top-2 hit 5.3 of 8 experts
     under even routing, one row 2: shares a list would pay for)
     compiles the exact path, as it did."""
-    runner, params, cache, rep = _runner_shapes(
-        topo, 1, layers=2, kv_blocks=97, model="mixtral-8x7b")
-    hlo = _compile_decode_window(runner, params, cache, rep,
-                                 rows=rows).as_text()
+    hlo = step_program("mixtral-8x7b", 2, rows=rows).hlo
     assert "moe_list_experts" not in hlo and "moe_experts" in hlo
 
 
@@ -617,9 +660,9 @@ def test_moe_grouped_kernel_compiles(topo, tpu_branches, experts, rows,
     ("glm-4.7-flash", 3, GLM_MOE, 1),
     ("qwen1.5-moe-a2.7b", 2, QWEN_MOE, 0)],
     ids=["qwen15moe-1row", "glm47flash-1row", "qwen15moe-16rows"])
-def test_prefill_chunk_multiplies_only_routed_rows(topo, tpu_branches,
-                                                   model, layers,
-                                                   experts, rows):
+def test_prefill_chunk_multiplies_only_routed_rows(tpu_branches,
+                                                   step_program, model,
+                                                   layers, experts, rows):
     """A prefill chunk of 256 tokens of the runner at the MoE cells'
     widths, compiled whole: the expert matmuls are the grouped path's
     custom call on the stacks in place; no instruction yields a product
@@ -627,10 +670,7 @@ def test_prefill_chunk_multiplies_only_routed_rows(topo, tpu_branches,
     path's; [E, C, ...] at any capacity, the dispatch's) and none an
     array of an expert stack's shape or of one layer's."""
     import re
-    runner, params, cache, rep = _runner_shapes(
-        topo, 1, layers=layers, kv_blocks=97, model=model)
-    hlo = _compile_prefill_chunk(runner, params, cache, rep, 256,
-                                 rows).as_text()
+    hlo = step_program(model, layers, tokens=256, rows=rows).hlo
     assert "moe_grouped_experts" in hlo
     assert "moe_list_experts" not in hlo
     E, h, i = (experts[n] for n in "Ehi")
@@ -671,9 +711,9 @@ def _program_digest(lowered) -> str:
     ("glm-4.7-flash", 3,
      "1e71969c295a9609fd8f874e28b5a6220e3bb962c87564b61e99e5381d79e1c9")],
     ids=["qwen15moe", "glm47flash"])
-def test_moe_decode_window_lowers_to_the_pinned_text(topo, tpu_branches,
-                                                     model, layers,
-                                                     digest):
+def test_moe_decode_window_lowers_to_the_pinned_text(tpu_branches,
+                                                     step_program, model,
+                                                     layers, digest):
     """``jit_decode_window`` of both MoE configurations (16 rows, 8
     steps, the 512 kv bucket, greedy) lowers for the described v5e to
     the text it lowered to at commit 5d902fa, PR 38 (digests taken on
@@ -682,29 +722,18 @@ def test_moe_decode_window_lowers_to_the_pinned_text(topo, tpu_branches,
     a decode step runs, so a chip run before and after differs in the
     prefill alone. A PR that means to change the decode program pins
     its own digests here and says so."""
-    runner, params, cache, rep = _runner_shapes(
-        topo, 1, layers=layers, kv_blocks=97, model=model)
-    B = runner.engine_cfg.max_num_seqs
-    a = _step_args(runner, rep, B)
-    fn = jax.jit(partial(runner._decode_impl, steps=8, kv_len=512,
-                         greedy=True), donate_argnums=(1,))
-    lowered = fn.lower(
-        params, cache, a["tables"], rep((B,), jnp.int32),
-        rep((B,), jnp.int32), a["sampling"], a["key"], a["guide_next"],
-        a["guide_id"], a["guide_state"], a["counts"], a["seen"])
-    assert _program_digest(lowered) == digest
+    assert _program_digest(step_program(model, layers).lowered) == digest
 
 
-def test_dense_decode_window_has_no_expert_call(topo, tpu_branches):
+def test_dense_decode_window_has_no_expert_call(tpu_branches,
+                                                step_program):
     """The dense model's decode window knows nothing of the list path:
     its only custom call is the attention kernel's, and no instruction
     carries a ``moe_`` scope. (That its optimised HLO is the parent
     commit's, instruction for instruction, was read off both trees'
     compiles for PR 34: PERF.md.)"""
     import re
-    runner, params, cache, rep = _runner_shapes(topo, 1, layers=2,
-                                                kv_blocks=97)
-    hlo = _compile_decode_window(runner, params, cache, rep).as_text()
+    hlo = step_program("mistral-7b", 2).hlo
     calls = {m.group(1) for m in re.finditer(
         r"%([A-Za-z_]+)[\w.\-]* = \S+ custom-call\(", hlo)
         if "tpu_custom_call" in hlo}
@@ -833,30 +862,22 @@ def test_latent_decode_kernel_copies_a_block_once(topo):
         ("bf16", "bf16")}, matmuls
 
 
-def _glm_runner(topo, layers=3):
-    """The runner skeleton at GLM-4.7-Flash's widths: one dense layer
-    and ``layers - 1`` expert layers, a latent pool of 97 blocks."""
-    return _runner_shapes(topo, 1, layers=layers, kv_blocks=97,
-                          model="glm-4.7-flash")
-
-
 @pytest.mark.parametrize("program", ["decode_window", "prefill_chunk",
                                      "prefill_chunk_1row"])
-def test_latent_step_program_never_copies_the_pool(topo, tpu_branches,
-                                                   program):
+def test_latent_step_program_never_copies_the_pool(tpu_branches,
+                                                   step_program, program):
     """never_copies_the_pool for the latent pool [3, 97, 1, 64, 576]:
     the leading dense layer outside the scan and the scanned expert
     layers append to and read ONE carried buffer."""
     import re
     L, N = 3, 97
-    runner, params, cache, rep = _glm_runner(topo, L)
     if program == "decode_window":
-        compiled = _compile_decode_window(runner, params, cache, rep)
+        made = step_program("glm-4.7-flash", L)
     else:
-        compiled = _compile_prefill_chunk(
-            runner, params, cache, rep, 256,
-            {"prefill_chunk": 0, "prefill_chunk_1row": 1}[program])
-    hlo = compiled.as_text()
+        made = step_program(
+            "glm-4.7-flash", L, tokens=256,
+            rows={"prefill_chunk": 0, "prefill_chunk_1row": 1}[program])
+    compiled, hlo = made.compiled, made.hlo
     assert "tpu_custom_call" in hlo
     pool_result = re.compile(
         r"([\w.\-]+) = \(?\w+\[(?:{},)?{},1,{},{}\]\S* ([\w\-]+)\("
@@ -871,7 +892,7 @@ def test_latent_step_program_never_copies_the_pool(topo, tpu_branches,
 
 
 def test_latent_decode_window_makes_no_key_or_value_per_head(
-        topo, tpu_branches):
+        tpu_branches, step_program):
     """The decode executable of the latent model is absorbed: its
     attention is the paged decode kernel on the pool, the experts are
     the list path's call on the expert layers' stacks in place, and no
@@ -879,8 +900,7 @@ def test_latent_decode_window_makes_no_key_or_value_per_head(
     dimensions are [20, 256] / [20, 192] / [20, 448] over the batch's
     16 rows and a context axis (>= one block of 64 tokens)."""
     import re
-    runner, params, cache, rep = _glm_runner(topo)
-    hlo = _compile_decode_window(runner, params, cache, rep).as_text()
+    hlo = step_program("glm-4.7-flash", 3).hlo
     assert "%paged_decode_attention" in hlo
     assert "moe_list_experts" in hlo
     def leading(m):
@@ -1002,7 +1022,7 @@ def test_sparse_step_program_compiles_at_glm5_widths(
 @pytest.mark.slow
 @pytest.mark.parametrize("tp", [1, 4])
 def test_decode_window_compiles_at_mistral_7b(topo, tpu_branches, tp):
-    compiled = _compile_decode_window(*_runner_shapes(topo, tp))
+    compiled = _lower_decode_window(*_runner_shapes(topo, tp)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled, f"decode window tp={tp}")
 
@@ -1012,8 +1032,8 @@ def test_decode_window_compiles_at_mistral_7b(topo, tpu_branches, tp):
 @pytest.mark.parametrize("tp", [1, 4])
 def test_prefill_step_compiles_at_mistral_7b(topo, tpu_branches, tp,
                                              rows):
-    compiled = _compile_prefill_chunk(*_runner_shapes(topo, tp), 512,
-                                      rows)
+    compiled = _lower_prefill_chunk(*_runner_shapes(topo, tp), 512,
+                                    rows).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled, f"prefill step tp={tp} rows={rows or 'all'}")
 

@@ -224,7 +224,13 @@ def test_rolling_kv_frees_behind_window():
                            max_num_seqs=2, prefill_chunk=32,
                            prefill_buckets=(32,), decode_window=4,
                            kv_block_size=16,
-                           kv_pool_tokens=pool_tokens)
+                           kv_pool_tokens=pool_tokens,
+                           # float32: the stream's closest greedy
+                           # choice stands 1.6e-4 apart, a rounding
+                           # 1e-6. In bfloat16 the two are level and
+                           # one run in eight under load takes another
+                           # token, big pool or small (PR 45)
+                           dtype="float32", kv_dtype="float32")
         eng = LLMEngine(cfg)
         opts = SamplingOptions(temperature=0.0, max_tokens=300,
                                ignore_eos=True)
