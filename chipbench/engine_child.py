@@ -110,14 +110,19 @@ def add_routes(app, engine, conf: dict, trace_dir: str) -> None:
     async def probe(request: web.Request) -> web.Response:
         """Body: {"prompts": [[ids]], "ids": [[ids]]}: for each prompt
         the reference's log-probabilities of the next token at ``ids``
-        and its own top-20 ids."""
+        and its own top-20 ids. With ``"control": {key: value}`` the
+        reference is run with those keys laid over the configuration's
+        (``chipbench/probe_seeds.py``: the reference in a lower
+        precision, the limit's upper reading; no benchmark run sends
+        it)."""
         body = await request.json()
         ref = importlib.import_module(
             "chipbench.references." + conf["reference"])
+        hf = {**conf, **(body.get("control") or {})}
 
         def compute():
             return ref.next_token_logprobs(
-                engine.engine.runner.params, conf,
+                engine.engine.runner.params, hf,
                 body["prompts"], body["ids"])
         t0 = time.monotonic()
         out = await asyncio.to_thread(compute)
@@ -213,7 +218,10 @@ def main(argv=None) -> int:
     def engine_then_warm(cfg, *a, **kw):
         engine = inner_engine(cfg, *a, **kw)
         runner = engine.engine.runner
-        shapes = shapes_reached(runner.engine_cfg, reach)
+        # ``shapes``: the list itself (chipbench/probe_seeds.py warms
+        # nothing: the probe's prompts compile on first use, as in a run)
+        shapes = reach.get("shapes") or shapes_reached(runner.engine_cfg,
+                                                       reach)
         print(READY_TAG + json.dumps({**warm(runner, shapes), **shapes}),
               flush=True)
         return engine

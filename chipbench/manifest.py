@@ -3,7 +3,8 @@
 Whatever belongs to one configuration, one traffic mix, one cell or one
 per-layer metric sits in a file of its own:
 
-    configuration  its ``file`` in the manifest
+    configuration  its ``file`` in the manifest (with the optional
+                   ``harness`` key: chipbench/harness_key.py)
     traffic        chipbench/traffic/<traffic>.json
     cell           chipbench/cells/<workload>.json   (optional: rate,
                    restrictions of the warm-up)
@@ -19,6 +20,8 @@ import json
 import os
 import re
 from typing import Dict, List, Optional
+
+from chipbench import harness_key
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -73,6 +76,11 @@ class Cell:
         self.config_entry = configs[row["config"]]
         self.config_file = os.path.join(root, self.config_entry["file"])
         self.config = load(self.config_file)
+        try:    # a ``harness`` key the harness cannot read fails here
+            harness_key.of(self.config)
+        except ValueError as e:
+            raise ManifestError(f"{self.config_entry['file']}: {e}") \
+                from None
         self.traffic_name = row["traffic"]
         self.traffic = load(find("traffic", row["traffic"], data_dirs))
         cell_file = find("cells", name, data_dirs, required=False)

@@ -27,6 +27,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
+from chipbench.run import run_dir  # noqa: E402
 from chipbench.stats import median, spread  # noqa: E402
 
 
@@ -67,7 +68,8 @@ def main() -> int:
             with open(out_path, "a") as f:
                 f.write(json.dumps(row) + "\n")
             if proc.returncode or not line.get("correct"):
-                log = os.path.join(ROOT, ".chipbench", "logs", "engine.log")
+                log = os.path.join(run_dir(workload, seed, trace), "logs",
+                                   "engine.log")
                 if os.path.exists(log):
                     shutil.copy(log, os.path.join(
                         out_dir, f"{args.tag}-{workload}-{seed}.engine.log"))

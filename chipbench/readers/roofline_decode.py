@@ -5,7 +5,7 @@ time one step took (trace_module, ``per: step``)."""
 
 import json
 
-from trace_module import read as module_ms
+from trace_module import module_ms
 
 from chipbench import roofline
 

@@ -13,7 +13,7 @@ import json
 
 from perf_delta import read as read_share
 from roofline_decode import live_rows_and_context
-from trace_module import read as module_ms
+from trace_module import module_ms
 
 from chipbench import roofline, roofline_latent
 

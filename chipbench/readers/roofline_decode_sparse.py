@@ -7,7 +7,7 @@ took (trace_module, ``per: step``). No trace, no counter or a file
 without an indexer: None."""
 
 from roofline_sparse_common import config, experts_touched, live_contexts
-from trace_module import read as module_ms
+from trace_module import module_ms
 
 from chipbench import roofline, roofline_sparse
 

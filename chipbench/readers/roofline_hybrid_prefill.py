@@ -7,7 +7,7 @@ time of the prefill executable that ran most while traced (of those
 that run ``kernel``). No trace, no counter: None."""
 
 from roofline_hybrid_common import bytes_per_param, config, is_hybrid, moved
-from trace_module import read as module_ms
+from trace_module import module_ms
 
 from chipbench import roofline, roofline_hybrid
 

@@ -8,7 +8,7 @@ executables that run ``kernel``). No trace, no counter: None."""
 
 from _common import dig
 from roofline_sparse_common import config
-from trace_module import read as module_ms
+from trace_module import module_ms
 
 from chipbench import roofline, roofline_sparse
 

@@ -11,7 +11,9 @@ the largest logit changes on rounding.
 """
 
 import re
-from typing import Dict, List
+from typing import Dict, List, Optional
+
+from chipbench.harness_key import PROBE_GAP_LIMIT
 
 TOP = 20
 # Largest allowed |served - reference| over the served top-20 tokens of
@@ -25,7 +27,12 @@ TOP = 20
 # comparison measures under 0.02 and a causal mask off by one position
 # measures over 0.04 (tests/chipbench). What it cannot see is in
 # PERF.md section 7.
-TOLERANCE = 0.3
+# PR 46: a configuration whose own readings lie elsewhere, or do not
+# stand three times apart on this number, states its limits in its file
+# (``harness.probe``, chipbench/harness_key.py: this widest gap a
+# prompt, and the mean gap over every served log-probability of the
+# run); this is the default.
+TOLERANCE = PROBE_GAP_LIMIT
 # of the served top-20, how many the reference's own top-20 must name:
 # near-ties at the tail of the list swap freely, a wrong distribution
 # shares few
@@ -52,20 +59,32 @@ def token_id(entry: Dict) -> int:
 
 
 def compare(served: List[Dict], reference: List[Dict],
-            tolerance: float = TOLERANCE) -> Dict:
+            tolerance: Optional[float] = TOLERANCE,
+            mean_limit: Optional[float] = None) -> Dict:
     """``served``: per prompt {"prompt_tokens", "ids", "logprobs"} (the
     API's top-20); ``reference``: per prompt {"prompt_tokens",
     "logprobs" (at the served ids), "top_ids"}. ok only if every prompt
-    is inside the tolerance and shares enough of its top list."""
+    is inside the tolerance (the widest gap of its twenty; None: not
+    held to one) and shares enough of its top list, and the mean gap
+    over every served log-probability of every prompt is inside
+    ``mean_limit`` (None: not held to one)."""
     rows, ok = [], len(served) == len(reference) and bool(served)
+    every = []
     for s, r in zip(served, reference):
-        diff = max(abs(a - b) for a, b in
-                   zip(s["logprobs"], r["logprobs"], strict=True))
+        gaps = [abs(a - b) for a, b in
+                zip(s["logprobs"], r["logprobs"], strict=True)]
+        every += gaps
         shared = len(set(s["ids"]) & set(r["top_ids"]))
         good = (s["prompt_tokens"] == r["prompt_tokens"]
-                and diff <= tolerance and shared >= MIN_SHARED)
+                and (tolerance is None or max(gaps) <= tolerance)
+                and shared >= MIN_SHARED)
         ok = ok and good
         rows.append({"prompt_tokens": s["prompt_tokens"],
-                     "max_abs_logprob_diff": diff,
+                     "max_abs_logprob_diff": max(gaps),
+                     "mean_abs_logprob_diff": sum(gaps) / len(gaps),
                      "shared_top": shared, "ok": good})
-    return {"ok": ok, "tolerance": tolerance, "rows": rows}
+    mean = sum(every) / len(every) if every else None
+    if mean_limit is not None:
+        ok = ok and mean is not None and mean <= mean_limit
+    return {"ok": ok, "tolerance": tolerance, "mean_limit": mean_limit,
+            "mean_abs_logprob_diff": mean, "rows": rows}

@@ -26,6 +26,7 @@ import json
 import os
 import random
 import re
+import shutil
 import signal
 import socket
 import subprocess
@@ -42,12 +43,16 @@ if ROOT not in sys.path:
 
 import aiohttp  # noqa: E402
 
+from chipbench import harness_key, reference, traffic, window  # noqa: E402
 from chipbench import manifest as mf  # noqa: E402
-from chipbench import reference, traffic, window  # noqa: E402
 from chipbench.engine_child import DEVICE_TAG  # noqa: E402
 from chipbench.stats import median, percentile  # noqa: E402
 
-# what the run leaves behind, all inside the checkout and ignored by git
+# what a run leaves behind, all inside the checkout and ignored by git:
+# the warm list, and under runs/<workload>.<seed>.<trace>/ (run_dir) the
+# children's logs and the profiler's capture of that run alone, so that
+# two runs side by side (rehearsals under several test workers) never
+# read each other's engine log
 STATE_DIR = os.path.join(ROOT, ".chipbench")
 START_S = 1100        # engine spawn to /health, first (compiling) run
 STOP_S = 90           # per signal, for a child to be gone (PERF.md s6:
@@ -72,11 +77,11 @@ def free_port() -> int:
 # ---------------------------------------------------------------------
 
 class Child:
-    def __init__(self, name: str, cmd: List[str], url: str,
+    def __init__(self, name: str, cmd: List[str], url: str, where: str,
                  env: Optional[Dict[str, str]] = None):
-        os.makedirs(os.path.join(STATE_DIR, "logs"), exist_ok=True)
+        os.makedirs(os.path.join(where, "logs"), exist_ok=True)
         self.name, self.url = name, url
-        self.log_path = os.path.join(STATE_DIR, "logs", name + ".log")
+        self.log_path = os.path.join(where, "logs", name + ".log")
         with open(self.log_path, "wb") as log:
             self.popen = subprocess.Popen(
                 cmd, stdout=log, stderr=subprocess.STDOUT, cwd=ROOT,
@@ -103,7 +108,11 @@ class Child:
         return time.monotonic() - t0
 
 
-def start_engine(cell: mf.Cell, seed: int, reach: Dict, trace_dir: str,
+def run_dir(workload: str, seed: int, trace: int) -> str:
+    return os.path.join(STATE_DIR, "runs", f"{workload}.{seed}.{trace}")
+
+
+def start_engine(cell: mf.Cell, seed: int, reach: Dict, where: str,
                  rehearse: bool) -> Child:
     os.makedirs(STATE_DIR, exist_ok=True)
     warm_file = os.path.join(STATE_DIR, "warm.json")
@@ -113,15 +122,16 @@ def start_engine(cell: mf.Cell, seed: int, reach: Dict, trace_dir: str,
     cmd = [sys.executable, os.path.join(HERE, "engine_child.py"),
            "--config-file", cell.config_file, "--port", str(port),
            "--seed", str(seed), "--chips", str(cell.chips),
-           "--warm", warm_file, "--trace-dir", trace_dir]
+           "--warm", warm_file,
+           "--trace-dir", os.path.join(where, "trace")]
     env = {}
     if rehearse:
         cmd.append("--allow-cpu")
         env["JAX_PLATFORMS"] = "cpu"
-    return Child("engine", cmd, f"http://127.0.0.1:{port}", env)
+    return Child("engine", cmd, f"http://127.0.0.1:{port}", where, env)
 
 
-def start_router(engine_url: str, model: str) -> Child:
+def start_router(engine_url: str, model: str, where: str) -> Child:
     port = free_port()
     cmd = [sys.executable, "-m", "production_stack_tpu.router.app",
            "--host", "127.0.0.1", "--port", str(port),
@@ -129,7 +139,7 @@ def start_router(engine_url: str, model: str) -> Child:
            "--static-backends", engine_url, "--static-models", model,
            "--routing-logic", "roundrobin",
            "--engine-stats-interval", "5"]
-    return Child("router", cmd, f"http://127.0.0.1:{port}")
+    return Child("router", cmd, f"http://127.0.0.1:{port}", where)
 
 
 async def get_json(session, url: str, **kw) -> Dict:
@@ -208,13 +218,29 @@ def probe_contents(seed: int, block: int, chunk: int) -> List[str]:
             for n in (block // 2, block + 11, chunk + 48)]
 
 
-async def run_probe(session, engine_url: str, cell: mf.Cell,
-                    seed: int) -> Dict:
+async def run_probe(session, engine_url: str, cell: mf.Cell, seed: int,
+                    controls: Optional[Dict[str, Dict]] = None) -> Dict:
+    """The three prompts served, the reference over them, the verdict
+    within the configuration's limits. ``controls`` (name -> keys laid
+    over the configuration's; ``chipbench/probe_seeds.py`` alone gives
+    them, a run never) adds ``detail``: what was served and what the
+    reference said, and for each control the reference run with those
+    keys IN THE PROGRAM'S PLACE: its own top-20 held against the plain
+    reference by the same comparison."""
     t0 = time.monotonic()
     args = cell.config["engine_args"]
+    key = harness_key.of(cell.config)["probe"]
+    limits = (key["logprob_gap_limit"], key["mean_logprob_gap_limit"])
 
     def arg(flag, default):
         return int(args[args.index(flag) + 1]) if flag in args else default
+
+    async def reference_at(rows, control=None):
+        """The reference (or a control) at what ``rows`` served."""
+        return await post_json(
+            session, engine_url + "/chipbench/probe",
+            {"prompts": prompts, "ids": [r["ids"] for r in rows],
+             "control": control})
     served, prompts = [], []
     for content in probe_contents(seed, arg("--kv-block-size", 64),
                                   arg("--prefill-chunk", 512)):
@@ -231,12 +257,22 @@ async def run_probe(session, engine_url: str, cell: mf.Cell,
         served.append({"prompt_tokens": data["usage"]["prompt_tokens"],
                        "ids": [reference.token_id(t) for t in top],
                        "logprobs": [t["logprob"] for t in top]})
-    ref = await post_json(session, engine_url + "/chipbench/probe",
-                          {"prompts": prompts,
-                           "ids": [s["ids"] for s in served]})
-    out = reference.compare(served, ref["rows"])
+    ref = await reference_at(served)
+    out = reference.compare(served, ref["rows"], *limits)
     out["seconds"] = time.monotonic() - t0
     out["reference_seconds"] = ref["seconds"]
+    if controls is not None:
+        out["detail"] = {"served": served, "reference": ref["rows"],
+                         "controls": {}}
+        for name, keys in controls.items():
+            own = (await reference_at(served, keys))["rows"]
+            stand_in = [{"prompt_tokens": r["prompt_tokens"],
+                         "ids": r["top_ids"],
+                         "logprobs": r["top_logprobs"]} for r in own]
+            plain = (await reference_at(stand_in))["rows"]
+            out["detail"]["controls"][name] = {
+                "keys": keys, "served": stand_in, "reference": plain,
+                **reference.compare(stand_in, plain, *limits)}
     return out
 
 
@@ -442,9 +478,9 @@ async def stack(cell: mf.Cell, seed: int, plan: traffic.Plan,
                 rehearse: bool, run: Dict):
     """Router and engine up, healthy and probed; both stopped and gone
     on the way out, whatever happened inside."""
-    engine = start_engine(cell, seed, reach_of(cell, plan),
-                          os.path.join(STATE_DIR, "trace"), rehearse)
-    router = start_router(engine.url, cell.config["name"])
+    engine = start_engine(cell, seed, reach_of(cell, plan), run["dir"],
+                          rehearse)
+    router = start_router(engine.url, cell.config["name"], run["dir"])
     timeout = aiohttp.ClientTimeout(total=None, sock_connect=10,
                                     sock_read=300)
     try:
@@ -462,11 +498,11 @@ async def stack(cell: mf.Cell, seed: int, plan: traffic.Plan,
 
 
 async def run_cell(cell: mf.Cell, seed: int, seconds: float, trace: bool,
-                   rehearse: bool) -> Dict:
+                   rehearse: bool, where: str) -> Dict:
     plan = traffic.make_plan(cell.traffic, seed, seconds,
                              cell.params.get("rate_rps"))
     run: Dict = {"cell": cell.name, "seed": seed, "seconds": seconds,
-                 "config_file": cell.config_file}
+                 "config_file": cell.config_file, "dir": where}
     async with stack(cell, seed, plan, rehearse, run) as (
             session, engine, router):
         load = Load(session, router.url, cell.config["name"], plan)
@@ -525,7 +561,7 @@ async def run_cell(cell: mf.Cell, seed: int, seconds: float, trace: bool,
 
 
 async def run_sweep(cell: mf.Cell, seed: int, seconds: float,
-                    rates: List[float], rehearse: bool) -> None:
+                    rates: List[float], rehearse: bool, where: str) -> None:
     """One engine start, the open-loop rate stepped inside it: for each
     rate one line with what decides whether the cell sustains it (the
     generator's lag and the requests in flight must not grow over the
@@ -533,7 +569,7 @@ async def run_sweep(cell: mf.Cell, seed: int, seconds: float,
     0.8 of it into chipbench/cells/<cell>.json; no run searches."""
     plans = [traffic.make_plan(cell.traffic, seed, seconds, r)
              for r in rates]
-    run: Dict = {}
+    run: Dict = {"dir": where}
     async with stack(cell, seed, plans[-1], rehearse, run) as (
             session, engine, router):
         for rate, plan in zip(rates, plans):
@@ -575,27 +611,48 @@ async def run_sweep(cell: mf.Cell, seed: int, seconds: float,
 # ---------------------------------------------------------------------
 
 def verdict(run: Dict) -> Dict:
-    """``correct``, ``attempted``, ``failed`` and why."""
+    """``correct``, ``attempted``, ``failed``, why, and each number
+    compared beside its limit (``compared``: name -> [number, limit];
+    a logit gap may reach its limit, the top lists must share at least
+    theirs, the counts must be 0). Of the probe's two gaps those are
+    compared for which the configuration's file states a limit."""
     records = run["records"]
     sent = [r for r in records if r["sent"] is not None]
     failed = [r for r in sent
               if r["ended"] and not r["done"] and not r["cut"]]
-    paths = run["perf_close"]["device"]["attention_paths"]
-    why = []
+    why, compared = [], {"requests_failed": [len(failed), 0]}
     if failed:
         why.append(f"{len(failed)} requests failed, first: "
                    f"{failed[0]['status']} {failed[0]['error']}")
     if not run["probe"]["ok"]:
-        why.append(f"logit probe: {run['probe']['rows']}")
+        why.append(f"logit probe: mean gap "
+                   f"{run['probe'].get('mean_abs_logprob_diff')} of "
+                   f"{run['probe'].get('mean_limit')}; "
+                   f"{run['probe']['rows']}")
+    probe = run["probe"]
+    if probe.get("mean_limit") is not None:
+        compared["probe_mean_logprob_gap"] = [
+            probe["mean_abs_logprob_diff"], probe["mean_limit"]]
+    for i, row in enumerate(probe["rows"]):
+        if probe["tolerance"] is not None:
+            compared[f"probe{i}_logprob_gap"] = [
+                row["max_abs_logprob_diff"], probe["tolerance"]]
+        compared[f"probe{i}_shared_top"] = [row["shared_top"],
+                                            reference.MIN_SHARED]
     if not run.get("rehearsal"):
-        off = {k: v for k, v in paths.items()
-               if not v.startswith("pallas_paged")}
-        if off or not paths:
-            why.append(f"attention paths off the kernel: {off or paths}")
-    why += reconcile(records, run["engine_traces"], run["router_traces"],
-                     run["moved"])
+        off = harness_key.kernels_off(
+            run["perf_close"]["device"],
+            harness_key.read(run["config_file"]))
+        compared["executables_off_kernels"] = [len(off), 0]
+        if off:
+            why.append(f"executables off the kernels their "
+                       f"configuration names, table[executable]: {off}")
+    unreconciled = reconcile(records, run["engine_traces"],
+                             run["router_traces"], run["moved"])
+    compared["counts_unreconciled"] = [len(unreconciled), 0]
+    why += unreconciled
     return {"correct": not why, "attempted": len(sent),
-            "failed": len(failed), "why": why}
+            "failed": len(failed), "why": why, "compared": compared}
 
 
 def device_block(run: Dict) -> Dict:
@@ -649,8 +706,9 @@ def result_line(cell: mf.Cell, run: Dict, trace: bool,
                 "idle_gaps": run["trace"]["idle_gaps"][:10]}
     else:
         line["metrics"] = end_to_end
-    line["probe"] = {k: run["probe"][k] for k in
-                     ("ok", "rows", "seconds", "reference_seconds")}
+    line["probe"] = {k: run["probe"].get(k) for k in
+                     ("ok", "mean_abs_logprob_diff", "rows", "seconds",
+                      "reference_seconds")}
     line["notes"] = {
         "stop_s": run["stop_s"], "engine_ready_s": run["engine_ready_s"],
         "in_flight_open": run["in_flight_open"],
@@ -680,6 +738,7 @@ def result_line(cell: mf.Cell, run: Dict, trace: bool,
         line["notes"]["trace_modules"] = run["trace"].get("modules")
     if run.get("rehearsal"):
         line["rehearsal"] = True
+    line["compared"] = line.pop("compared")     # the line's last key
     return line
 
 
@@ -720,14 +779,16 @@ def main(argv: Optional[List[str]] = None) -> int:
     # weights and traffic both come from the seed; the engine's is an
     # int32, the driver's seeds are wider
     seed = args.seed % 0x7FFFFFFF
+    where = run_dir(args.workload, args.seed, args.trace)
     try:
         if args.sweep:
             asyncio.run(run_sweep(
                 cell, seed, args.seconds,
-                [float(r) for r in args.sweep.split(",")], args.rehearse))
+                [float(r) for r in args.sweep.split(",")], args.rehearse,
+                where))
             return 0
         run = asyncio.run(run_cell(cell, seed, args.seconds,
-                                   bool(args.trace), args.rehearse))
+                                   bool(args.trace), args.rehearse, where))
         run["rehearsal"] = args.rehearse
         line = result_line(cell, run, bool(args.trace), args.data)
     except RunFailure as e:
@@ -737,6 +798,11 @@ def main(argv: Optional[List[str]] = None) -> int:
         with open(args.dump, "w") as f:
             json.dump(run, f)
     print(json.dumps(line), flush=True)
+    for name, (value, limit) in line["compared"].items():
+        print(f"chipbench: compared {name} = {value}, limit {limit}",
+              file=sys.stderr)
+    if line["correct"]:     # a run at fault keeps its children's logs
+        shutil.rmtree(where, ignore_errors=True)
     return 0
 
 

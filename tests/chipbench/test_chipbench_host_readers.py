@@ -9,10 +9,9 @@ commit, which the driver measures with these same files).
 Its EXPECTED joins ``test_chipbench_readers.EXPECTED`` at import, as
 ``test_chipbench_latent.py``'s does: every worker imports every test
 file while it collects, so the completeness check there sees these
-nine covered. This file also carries, for the manifest as it is now,
-the two assertions of ``test_chipbench_latent.py`` that hold the
-manifest's size (31 metrics, 30 in the GLM cell) and fail once it
-gains again; only a ``benchmark`` PR may edit them there.
+nine covered. Where the nine stand in the manifest, and that every
+cell reports them, is ``test_chipbench_manifest.py``'s
+(``manifest_history/pr38.json``).
 """
 
 import copy
@@ -229,44 +228,9 @@ def test_manifest_entry_matches_the_metric_file(name):
                                 "layer", "moves", "reader", "args"}
 
 
-def test_manifest_only_gained_at_its_end():
-    """What test_chipbench_latent asserted of PR 35's four metrics, for
-    a manifest that has gained again: nothing moved, PR 35's four are
-    directly before these nine, which are last; configurations and
-    cells are as they were."""
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-9:] == list(NEW)
-    assert names[-13:-9] == [
-        "latent_decode_step_roofline", "latent_attention_kernel_roofline",
-        "moe_read_share", "kv_bytes_per_token"]
-    assert names[-21:-13] == [
-        "step_host_work_share", "device_starved_share",
-        "prefill_loop_share", "decode_host_ms_per_step",
-        "engine_lock_wait_p50_ms", "engine_prefill_wait_p50_ms",
-        "engine_first_token_emit_p50_ms", "prefill_device_share"]
-    assert len(names) == 40 and names[0] == "loadgen_lag_p95_ms"
-    assert [c["name"] for c in MANIFEST["configs"]] == [
-        "mistral-7b-int8", "qwen15-moe-a2.7b-int8-l12",
-        "glm-4.7-flash-int8-l13"]
-    assert [w["name"] for w in MANIFEST["workloads"]] == [
-        "mistral7b-decode-closed", "qwen15moe-decode-closed",
-        "glm47flash-decode-closed"]
-    assert [m["name"] for m in MANIFEST["end_to_end"]] == [
-        "tpot_p50_ms", "out_tokens_per_s", "setup_s"]
-
-
-@pytest.mark.parametrize("cell, count", [
-    ("mistral7b-decode-closed", 36), ("qwen15moe-decode-closed", 36),
-    ("glm47flash-decode-closed", 39)])
-def test_manifest_resolves_with_the_new_metrics(cell, count):
-    assert mf.problems(MANIFEST, []) == []
-    names = [m["name"] for m in mf.Cell(MANIFEST, cell, []).per_layer]
-    assert len(names) == count and set(NEW) <= set(names)
-    assert ("decode_step_roofline" in names) == (count == 36)
-
-
 def test_layers_and_sources_are_the_manifests_own():
-    layers = {m["layer"] for m in MANIFEST["per_layer"][:-9]}
+    layers = {m["layer"] for m in MANIFEST["per_layer"]
+              if m["name"] not in NEW}
     for name in NEW:
         assert SPECS[name]["layer"] in layers
         assert SPECS[name]["source"] in ("program_counter", "program_span")

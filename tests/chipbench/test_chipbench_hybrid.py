@@ -6,16 +6,13 @@ published keys, the plain reference (chipbench/references/qwen3_next)
 against the program at a tiny size. The CPU rehearsal of the cell at a
 tiny ``qwen3_next`` file (``rehearsal/BENCHMARK.hybrid.json``,
 ``rehearsal/configs/tiny-gdn.json``) is run by tests/test_gdn.py, away
-from the rehearsals of this directory (they share ``.chipbench/``).
+from the rehearsals of this directory (it was put there while they
+shared ``.chipbench/``'s one engine log; since PR 46 a run has a
+directory of its own).
 
 Its EXPECTED joins ``test_chipbench_readers.EXPECTED`` at import, as
-test_chipbench_sparse's does (only a ``benchmark`` PR may edit
-conftest.py's LATER_TABLES). The tests that are there are not edited,
-so ``test_chipbench_sparse.py``'s ``test_manifest_only_gained_at_its_
-end``, ``test_manifest_resolves_with_the_new_cell`` and ``test_layers_
-are_the_manifests_own_or_named_in_perf_md`` join those that already
-fail because the manifest gained again (CHANGES.md, PR 42); this file
-carries their assertions for the manifest as it is now.
+test_chipbench_sparse's does. Where PR 42's entries stand in the
+manifest is ``manifest_history/pr42.json``'s (test_chipbench_manifest).
 """
 
 import json
@@ -152,17 +149,17 @@ def test_the_step_note_names_the_hybrid_yardstick():
     assert note["experts_touched"] == pytest.approx(0.14 * 64)
 
 
-def test_the_listless_step_metric_reads_four_steps_here():
-    """``decode_step_device_ms`` (trace_module, ``per: step``) divides
-    the attention kernel's calls by ``num_hidden_layers`` (24), and a
-    step of this model calls it 6 times: it reads FOUR TIMES the
-    step's milliseconds in this cell (PERF.md sections 3 and 7), which
-    is why the cell has ``hybrid_decode_step_device_ms``."""
+def test_the_listless_step_metric_reads_one_step_here():
+    """``decode_step_device_ms`` (trace_module, ``kind: decode_step``,
+    ``per: step``) divides the attention kernel's calls by what this
+    configuration's file says a step makes, 6 (``harness.decode_step.
+    calls_per_step``), and reads what ``hybrid_decode_step_device_ms``
+    reads; until PR 46 it divided by ``num_hidden_layers`` (24) and
+    read four steps."""
     spec = mf.load(os.path.join(mf.HERE, "metrics",
                                 "decode_step_device_ms.json"))
-    four = runner.read_metric(spec, record(), [])
-    assert four == pytest.approx(
-        4 * EXPECTED["hybrid_decode_step_device_ms"], rel=1e-6)
+    assert runner.read_metric(spec, record(), []) == pytest.approx(
+        EXPECTED["hybrid_decode_step_device_ms"], rel=1e-12)
 
 
 def test_the_yardstick_counts_the_issue_arithmetic():
@@ -274,8 +271,8 @@ def test_the_traffic_and_the_cell_are_the_issues():
 
 
 # ---------------------------------------------------------------------
-# the manifest as it is now (what test_chipbench_sparse's failing tests
-# asserted of it before it gained again)
+# the manifest's entries for this cell (where they stand in it, and what
+# each cell reports: test_chipbench_manifest.py, manifest_history/)
 # ---------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", NEW)
@@ -286,27 +283,6 @@ def test_manifest_entry_matches_the_metric_file(name):
     assert entry["workloads"] == [CELL]
     assert set(SPECS[name]) == {"name", "unit", "better", "source",
                                 "layer", "moves", "reader", "args"}
-
-
-def test_manifest_only_gained_at_its_end():
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-6:] == list(NEW)
-    assert names[-12:-6] == [
-        "sparse_decode_step_roofline", "sparse_prefill_chunk_roofline",
-        "indexer_kernel_roofline", "sparse_attention_kernel_roofline",
-        "sparse_attended_share", "index_bytes_per_token"]
-    assert len(names) == 52 and names[0] == "loadgen_lag_p95_ms"
-    assert [c["name"] for c in MANIFEST["configs"]] == [
-        "mistral-7b-int8", "qwen15-moe-a2.7b-int8-l12",
-        "glm-4.7-flash-int8-l13", "glm-5-int8-l7-e16",
-        "qwen3-next-80b-a3b-int8-l24-e64"]
-    assert [w["name"] for w in MANIFEST["workloads"]] == [
-        "mistral7b-decode-closed", "qwen15moe-decode-closed",
-        "glm47flash-decode-closed", "glm5-longctx-closed", CELL]
-    assert [m["name"] for m in MANIFEST["end_to_end"]] == [
-        "tpot_p50_ms", "out_tokens_per_s", "setup_s"]
-    assert MANIFEST["run_seconds"] == 50
-    assert all(w["chips"] == 1 for w in MANIFEST["workloads"])
 
 
 def _strings(obj):
@@ -324,24 +300,9 @@ def test_every_manifest_string_is_at_most_200_characters():
     assert max(map(len, _strings(MANIFEST))) <= 200
 
 
-@pytest.mark.parametrize("cell, count", [
-    ("mistral7b-decode-closed", 36), ("qwen15moe-decode-closed", 36),
-    ("glm47flash-decode-closed", 39), ("glm5-longctx-closed", 41),
-    (CELL, 41)])
-def test_manifest_resolves_with_the_new_cell(cell, count):
-    """The new cell reports every metric that lists no workloads (35)
-    and its own six; the four accepted cells report what they did."""
-    assert mf.problems(MANIFEST, []) == []
-    names = [m["name"] for m in mf.Cell(MANIFEST, cell, []).per_layer]
-    assert len(names) == count
-    assert (set(NEW) <= set(names)) == (cell == CELL)
-    listless = [m["name"] for m in MANIFEST["per_layer"]
-                if "workloads" not in m]
-    assert len(listless) == 35 and set(listless) <= set(names)
-
-
 def test_layers_are_the_manifests_own_or_named_in_perf_md():
-    layers = {m["layer"] for m in MANIFEST["per_layer"][:-6]}
+    layers = {m["layer"] for m in MANIFEST["per_layer"]
+              if m["name"] not in NEW}
     new_layers = {SPECS[n]["layer"] for n in NEW} - layers
     assert new_layers == {
         "kernels (ops/gdn.py delta rule)",
@@ -406,3 +367,49 @@ def test_the_probe_tolerance_sees_a_wrong_block(breakage):
         params, {**conf, **breakage}, prompts, [s["ids"] for s in served])
     assert not reference.compare(served, rows,
                                  tolerance=TINY_TOLERANCE)["ok"]
+
+
+# the mean gap over the sixty served log-probabilities at this size: the
+# served path and the bfloat16 reference read under 0.005, float8 over
+# 0.1
+TINY_MEAN_LIMIT = 0.02
+
+
+@pytest.mark.parametrize("limits", [(TINY_TOLERANCE, None),
+                                    (None, TINY_MEAN_LIMIT)],
+                         ids=["widest_gap", "mean_gap"])
+@pytest.mark.parametrize("dtype, fails", [("float8_e4m3fn", True),
+                                          ("bfloat16", False)])
+def test_the_lower_precision_control_fails_and_the_served_one_passes(
+        dtype, fails, limits):
+    """The control a probe's limits are set under (chipbench/
+    probe_seeds.py reads it on the chip at the cell's own size): the
+    reference with its residual stream rounded to float8, the precision
+    below the served bfloat16, PUT IN THE PROGRAM'S PLACE (its own
+    top-20 held against the plain reference's) comes out not correct,
+    by one prompt's widest gap and by the run's mean gap alike; rounded
+    to the served precision it passes both, as the program does."""
+    conf, params, prompts, served = _tiny()
+    own = qwen3_next.next_token_logprobs(
+        params, {**conf, "round_to": dtype}, prompts,
+        [[0]] * len(prompts))
+    stand_in = [{"prompt_tokens": r["prompt_tokens"], "ids": r["top_ids"],
+                 "logprobs": r["top_logprobs"]} for r in own]
+    plain = qwen3_next.next_token_logprobs(
+        params, conf, prompts, [r["top_ids"] for r in own])
+    out = reference.compare(stand_in, plain, *limits)
+    assert out["ok"] is not fails, out
+    rows = qwen3_next.next_token_logprobs(
+        params, conf, prompts, [s["ids"] for s in served])
+    assert reference.compare(served, rows, *limits)["ok"]
+
+
+def test_the_cells_file_holds_the_probe_to_its_mean_gap():
+    """N's readings on the chip (PERF.md section 2) separate on the
+    mean gap and not on one prompt's widest, so its file states the one
+    and not the other; the file's words give the readings."""
+    from chipbench import harness_key
+    assert harness_key.of(QWEN)["probe"] == {
+        "logprob_gap_limit": None, "mean_logprob_gap_limit": 0.3}
+    for reading in ("0.110", "0.617", "0.5435", "1.258"):
+        assert reading in QWEN["harness_why"]

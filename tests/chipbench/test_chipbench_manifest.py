@@ -1,6 +1,11 @@
-"""BENCHMARK.json: it loads, every name in it finds its file, and names
-and units hold only what the contract allows."""
+"""BENCHMARK.json: it loads, every name in it finds its file, names and
+units hold only what the contract allows, and it has grown only at its
+end: ``manifest_history/pr<n>.json`` holds the names of its four lists
+as PR n left them, and each record is a prefix of today's. A PR that
+gains a metric, a configuration or a cell ADDS its record there (a new
+file; no file that is there is edited) and pins no length anywhere."""
 
+import glob
 import json
 import os
 import re
@@ -13,6 +18,15 @@ ROOT = mf.ROOT
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     MANIFEST = json.load(f)
 METRICS = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+LISTS = ("per_layer", "configs", "workloads", "end_to_end")
+RECORDS = []        # oldest first
+for _path in glob.glob(os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "manifest_history", "pr*.json")):
+    with open(_path) as f:
+        RECORDS.append(json.load(f))
+    assert os.path.basename(_path) == f"pr{RECORDS[-1]['pr']}.json"
+RECORDS.sort(key=lambda r: r["pr"])
 
 
 def test_manifest_has_no_problem():
@@ -22,11 +36,59 @@ def test_manifest_has_no_problem():
 def test_command_and_paths():
     assert MANIFEST["command"] == ["python3", "-m", "chipbench"]
     assert "chipbench" in MANIFEST["paths"]
-    assert 1 <= MANIFEST["run_seconds"] <= 51
+    assert MANIFEST["run_seconds"] == 50    # no later PR may change it
     assert len(json.dumps(MANIFEST)) < 64 * 1024
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
+@pytest.mark.parametrize("record", RECORDS, ids=lambda r: f"pr{r['pr']}")
+def test_manifest_grew_only_at_its_end(record):
+    """Nothing an accepted PR left was moved, renamed or taken away:
+    its record is how today's lists begin. (No count is pinned: the
+    next PR that gains only appends, here and in the manifest.)"""
+    for key in LISTS:
+        today = [m["name"] for m in MANIFEST[key]]
+        assert today[:len(record[key])] == record[key], key
+
+
+def test_the_history_is_a_chain_and_reaches_today():
+    """Each record starts with the one before it, and the newest is
+    the whole of today's manifest: a PR that gained without adding its
+    record fails here."""
+    for before, after in zip(RECORDS, RECORDS[1:]):
+        for key in LISTS:
+            assert after[key][:len(before[key])] == before[key]
+    for key in LISTS:
+        assert RECORDS[-1][key] == [m["name"] for m in MANIFEST[key]], key
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cell_reports_every_listless_metric_and_those_that_list_it(cell):
+    """What the tests of PR 35, 38, 40 and 42 each asserted of the
+    cells they knew, without their counts: a cell's traced line holds
+    every metric that lists no workloads and exactly those others that
+    list it (so G, L and N have no ``decode_step_roofline``, whose
+    yardstick cannot read their families' files), and what the PR that
+    brought the cell added with it."""
+    names = [m["name"] for m in mf.Cell(MANIFEST, cell, []).per_layer]
+    assert len(names) == len(set(names))
+    for m in MANIFEST["per_layer"]:
+        assert (m["name"] in names) == (cell in m.get("workloads", [cell]))
+    for before, after in zip(RECORDS, RECORDS[1:]):
+        if cell in after["workloads"] and cell not in before["workloads"]:
+            own = set(after["per_layer"]) - set(before["per_layer"])
+            assert own <= set(names)
+
+
+@pytest.mark.parametrize("layer", sorted({m["layer"]
+                                          for m in MANIFEST["per_layer"]}))
+def test_every_layer_is_named_in_perf_md(layer):
+    """``layer`` is the name PERF.md's list of layers has, letter for
+    letter (the contract); a metric of a new layer brings its row."""
+    with open(os.path.join(ROOT, "PERF.md")) as f:
+        assert layer in f.read()
+
+
+@pytest.mark.parametrize("cell", CELLS)
 def test_every_name_of_a_cell_resolves_to_a_file(cell):
     c = mf.Cell(MANIFEST, cell, [])
     assert c.chips == 1, "no cell takes four chips yet (PERF.md s7)"

@@ -123,3 +123,29 @@ def test_roofline_of_a_decode_step():
     per_expert = 3 * 2048 * 1408
     assert (full["bytes"] - one["bytes"]) / (12 * per_expert) == \
         pytest.approx(39.6 - 3.9, abs=0.5)
+
+
+@pytest.mark.parametrize("limits, ok", [
+    ((0.3, None), False),       # one prompt's widest gap: 0.5 is over
+    ((None, 0.3), True),        # the run's mean gap: 0.0725 is under
+    ((None, 0.05), False), ((0.6, 0.3), True), ((0.6, 0.05), False)],
+    ids=["widest", "mean", "mean_over", "both", "both_mean_over"])
+def test_compare_holds_a_run_to_the_limits_it_is_given(limits, ok):
+    """Two prompts of twenty served log-probabilities: one token 0.5
+    off in the first, every token 0.12 off in the second; the widest
+    gap a prompt is 0.5 and 0.12, the mean over all forty 0.0725. A
+    limit of None is not held; both numbers are always returned."""
+    ids = list(range(20))
+    served = [{"prompt_tokens": 9, "ids": ids, "logprobs": [-3.0] * 20},
+              {"prompt_tokens": 40, "ids": ids, "logprobs": [-3.0] * 20}]
+    rows = [{"prompt_tokens": 9, "top_ids": ids,
+             "logprobs": [-3.5] + [-3.0] * 19},
+            {"prompt_tokens": 40, "top_ids": ids, "logprobs": [-3.12] * 20}]
+    out = reference.compare(served, rows, *limits)
+    assert out["ok"] is ok
+    assert (out["tolerance"], out["mean_limit"]) == limits
+    assert out["mean_abs_logprob_diff"] == pytest.approx(0.0725)
+    assert [r["max_abs_logprob_diff"] for r in out["rows"]] == \
+        pytest.approx([0.5, 0.12])
+    assert [r["mean_abs_logprob_diff"] for r in out["rows"]] == \
+        pytest.approx([0.025, 0.12])

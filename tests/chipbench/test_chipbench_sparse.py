@@ -6,18 +6,16 @@ published keys, the plain reference (chipbench/references/glm_moe_dsa)
 against the program at a tiny size. The CPU rehearsal of the cell at
 a tiny ``glm_moe_dsa`` file (``rehearsal/BENCHMARK.sparse.json``,
 ``rehearsal/configs/tiny-dsa.json``) is run by tests/test_dsa.py, away
-from the rehearsals of this directory: they share ``.chipbench/`` and
-its engine log, and ``test_without_a_chip_there_is_no_result`` reads
-that log's tail.
+from the rehearsals of this directory (it was put there while they
+shared ``.chipbench/``'s one engine log, whose tail
+``test_without_a_chip_there_is_no_result`` reads; since PR 46 a run
+has a directory of its own).
 
 Its EXPECTED joins ``test_chipbench_readers.EXPECTED`` at import, as
-test_chipbench_latent's and test_chipbench_host_readers' do (only a
-``benchmark`` PR may edit conftest.py's LATER_TABLES): the completeness
-check there sees these six metrics covered. The tests that are there
-are not edited, so ``test_chipbench_host_readers.py::
-test_manifest_only_gained_at_its_end`` joins the three that already
-fail because the manifest gained again (CHANGES.md, PR 40); this file
-carries their assertions for the manifest as it is now.
+test_chipbench_latent's and test_chipbench_host_readers' do: the
+completeness check there sees these six metrics covered. Where PR 40's
+entries stand in the manifest is ``manifest_history/pr40.json``'s
+(test_chipbench_manifest).
 """
 
 import json
@@ -240,8 +238,8 @@ def _engine_config():
 
 
 # ---------------------------------------------------------------------
-# the manifest as it is now (what the four failing tests asserted of it
-# before it gained again)
+# the manifest's entries for this cell (where they stand in it, and what
+# each cell reports: test_chipbench_manifest.py, manifest_history/)
 # ---------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", NEW)
@@ -254,47 +252,9 @@ def test_manifest_entry_matches_the_metric_file(name):
                                 "layer", "moves", "reader", "args"}
 
 
-def test_manifest_only_gained_at_its_end():
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-6:] == list(NEW)
-    assert names[-15:-6] == [
-        "dispatch_dry_share", "step_host_offcpu_share", "loop_busy_share",
-        "loop_stream_share", "loop_lag_p95_ms", "engine_preprocess_p50_ms",
-        "engine_first_token_write_p50_ms", "prefill_behind_share",
-        "prefill_real_share"]
-    assert names[-19:-15] == [
-        "latent_decode_step_roofline", "latent_attention_kernel_roofline",
-        "moe_read_share", "kv_bytes_per_token"]
-    assert len(names) == 46 and names[0] == "loadgen_lag_p95_ms"
-    assert [c["name"] for c in MANIFEST["configs"]] == [
-        "mistral-7b-int8", "qwen15-moe-a2.7b-int8-l12",
-        "glm-4.7-flash-int8-l13", "glm-5-int8-l7-e16"]
-    assert [w["name"] for w in MANIFEST["workloads"]] == [
-        "mistral7b-decode-closed", "qwen15moe-decode-closed",
-        "glm47flash-decode-closed", CELL]
-    assert [m["name"] for m in MANIFEST["end_to_end"]] == [
-        "tpot_p50_ms", "out_tokens_per_s", "setup_s"]
-    assert MANIFEST["run_seconds"] == 50
-    assert all(w["chips"] == 1 for w in MANIFEST["workloads"])
-
-
-@pytest.mark.parametrize("cell, count", [
-    ("mistral7b-decode-closed", 36), ("qwen15moe-decode-closed", 36),
-    ("glm47flash-decode-closed", 39), (CELL, 41)])
-def test_manifest_resolves_with_the_new_cell(cell, count):
-    """The new cell reports every metric that lists no workloads (35)
-    and its own six; the three accepted cells report what they did."""
-    assert mf.problems(MANIFEST, []) == []
-    names = [m["name"] for m in mf.Cell(MANIFEST, cell, []).per_layer]
-    assert len(names) == count
-    assert (set(NEW) <= set(names)) == (cell == CELL)
-    listless = [m["name"] for m in MANIFEST["per_layer"]
-                if "workloads" not in m]
-    assert len(listless) == 35 and set(listless) <= set(names)
-
-
 def test_layers_are_the_manifests_own_or_named_in_perf_md():
-    layers = {m["layer"] for m in MANIFEST["per_layer"][:-6]}
+    layers = {m["layer"] for m in MANIFEST["per_layer"]
+              if m["name"] not in NEW}
     new_layers = {SPECS[n]["layer"] for n in NEW} - layers
     assert new_layers == {"kernels (ops/dsa.py indexer and selection)"}
     with open(os.path.join(mf.ROOT, "PERF.md")) as f:
