@@ -62,6 +62,15 @@ class BlockManager:
         # page 0 the trash page (never handed out, as block 0); a
         # sequence takes ONE at admission beside its blocks and gives
         # it back with them. 0 pages: the model keeps no such state
+        # A model whose ONLY cache is state pages (layout "state":
+        # power retention layers) has no second pool: its page IS its
+        # block, of max_model_len tokens, so every count here is of
+        # pages, a sequence holds one, and the pages' own list stays
+        # empty (``pages_are_blocks``; the caller's ``state_pages`` is
+        # then the pool's size again and is not kept)
+        self.pages_are_blocks = layout == "state"
+        if self.pages_are_blocks:
+            state_pages = 0
         self.state_pages = state_pages
         self.state_bytes_per_slot = state_bytes_per_slot
         self._free_pages: List[int] = list(range(state_pages - 1, 0, -1))
@@ -161,19 +170,40 @@ class BlockManager:
             "defrag_block_moves": self.defrag_block_moves,
             # state pages (0 / empty where the model keeps none)
             "state_bytes_per_slot": self.state_bytes_per_slot,
-            "state_pages": {"total": max(self.state_pages - 1, 0),
+            "state_pages": {"total": self.total_pages,
                             "live": self.live_pages},
         }
 
     # -- state pages -----------------------------------------------------
 
     @property
+    def keeps_pages(self) -> bool:
+        """Does the model keep state a sequence, beside a K/V pool or
+        alone?"""
+        return bool(self.state_pages) or self.pages_are_blocks
+
+    @property
+    def total_pages(self) -> int:
+        """State pages a sequence can be given (the trash page is not
+        one)."""
+        if self.pages_are_blocks:
+            return self.num_blocks - 1
+        return max(self.state_pages - 1, 0)
+
+    @property
     def live_pages(self) -> int:
         """State pages held by live sequences."""
-        return max(self.state_pages - 1, 0) - len(self._free_pages)
+        if self.pages_are_blocks:
+            return self.active_blocks
+        return self.total_pages - len(self._free_pages)
 
     def page_counts(self) -> Dict[str, int]:
         """The pages' counters, for ``totals.state`` of /debug/perf."""
+        if self.pages_are_blocks:
+            return {"pages_alloc": self.blocks_allocated,
+                    "pages_freed": self.pages_freed,
+                    "alloc_failures": (self.alloc_failures_exhausted
+                                       + self.alloc_failures_fragmented)}
         return {"pages_alloc": self.pages_alloc,
                 "pages_freed": self.pages_freed,
                 "alloc_failures": self.page_alloc_failures}
@@ -272,6 +302,7 @@ class BlockManager:
                 self._ref[blk] = r
                 continue
             self._ref.pop(blk, None)
+            self.pages_freed += self.pages_are_blocks
             if blk in self._key_of:
                 self._evictable[blk] = None    # MRU end
             else:

@@ -354,13 +354,18 @@ class EngineEffAccounting:
         # ``totals.sparse`` of an engine whose model selects
         self.sparse: Dict[str, Dict[str, int]] = {}
         # state pages (note_state; ``totals.state`` of an engine whose
-        # model has Gated DeltaNet layers): real prefill positions
-        # through the chunkwise rule and live row-steps through the
-        # recurrent one, per layer; ``state_pages`` (set by the engine)
-        # reads the block manager's page counters
+        # model has Gated DeltaNet or power retention layers): real
+        # prefill positions through the chunked rule and live row-steps
+        # through the recurrent one, per layer; the decode steps
+        # dispatched and the bytes of state pages the dispatches of
+        # either kind read and wrote, all layers; ``state_pages`` (set
+        # by the engine) reads the block manager's page counters
         self.scan_tokens = 0
         self.prefill_keys = 0
         self.step_rows = 0
+        self.state_steps = 0
+        self.state_step_bytes = 0
+        self.state_scan_bytes = 0
         self.state_pages = None
         # modeled HBM traffic (decode windows only — see module doc)
         self.bytes_total = 0
@@ -522,17 +527,25 @@ class EngineEffAccounting:
             self.prefill_expert_rounds += rounds
 
     def note_state(self, scan_tokens: int = 0, prefill_keys: int = 0,
-                   step_rows: int = 0) -> None:
+                   step_rows: int = 0, steps: int = 0,
+                   step_bytes: int = 0, scan_bytes: int = 0) -> None:
         """One dispatch of a model with state pages: the real prefill
-        positions a chunk carried through ops/gdn's chunkwise rule
+        positions a chunk carried through the chunked rule
         (``prefill_keys``: the keys at or before them, summed, which
         its attention layers' causal products run over), or the live
         row-steps a decode window carried through the recurrent one
-        (of ONE layer: every such layer does the same)."""
+        (of ONE layer: every such layer does the same); ``steps`` the
+        window's decode steps, ``step_bytes`` / ``scan_bytes`` the
+        bytes of state pages the dispatch read and wrote, every layer
+        and every row of its batch (a row that is not real moves the
+        trash page)."""
         with self._lock:
             self.scan_tokens += scan_tokens
             self.prefill_keys += prefill_keys
             self.step_rows += step_rows
+            self.state_steps += steps
+            self.state_step_bytes += step_bytes
+            self.state_scan_bytes += scan_bytes
 
     def note_sparse(self, kind: str, first, queries: int, topk: int,
                     selects: bool) -> None:
@@ -783,7 +796,10 @@ class EngineEffAccounting:
                 **({"state": {**self.state_pages(),
                               "scan_tokens": self.scan_tokens,
                               "prefill_keys": self.prefill_keys,
-                              "step_rows": self.step_rows}}
+                              "step_rows": self.step_rows,
+                              "steps": self.state_steps,
+                              "step_bytes": self.state_step_bytes,
+                              "scan_bytes": self.state_scan_bytes}}
                    if self.state_pages is not None else {}),
                 "bytes_total": self.bytes_total,
                 "bytes_effective": self.bytes_effective,

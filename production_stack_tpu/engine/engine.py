@@ -292,8 +292,10 @@ class LLMEngine:
         # the step timeline (efficiency.STEP_PHASES): every phase of
         # step() runs under `with self._phase(name)`
         self._phase = self.eff.phase
-        if self.block_mgr.state_pages:
+        if self.block_mgr.keeps_pages:
             self.eff.state_pages = self.block_mgr.page_counts
+        # bytes of one sequence's state page, all layers (0: none)
+        self._state_page_bytes = self.runner.cache.state_bytes_per_slot
         self.runner.compile_observer = self.eff
         # advertised once: the router's per-endpoint concurrency cap
         # reads this gauge (0 = unbounded admission, nothing to cap on)
@@ -1221,14 +1223,16 @@ class LLMEngine:
                 drained=drained, chunks=len(group),
                 attention_path=self.runner.prefill_attention_path(
                     bucket, kv_len))
-            if self.model_cfg.gdn_layers:
-                # a query at position p has p + 1 keys in context
+            if self.model_cfg.state_layers:
+                # a query at position p has p + 1 keys in context; a
+                # chunk reads and writes its row's page once a layer
                 self.eff.note_state(
                     scan_tokens=sum(len(w.chunk) for w in group),
                     prefill_keys=sum(
                         len(w.chunk) * w.start
                         + len(w.chunk) * (len(w.chunk) + 1) // 2
-                        for w in group))
+                        for w in group),
+                    scan_bytes=2 * rows * self._state_page_bytes)
             if self.model_cfg.index_topk:
                 # (a chunk's padding past its tokens is not counted)
                 for w in group:
@@ -1634,8 +1638,12 @@ class LLMEngine:
         plain = all(s.options.top_p >= 1.0 and not s.options.top_k
                     and not s.options.min_p
                     for s in decode_seqs)
-        if self.model_cfg.gdn_layers:
-            self.eff.note_state(step_rows=W * len(decode_seqs))
+        if self.model_cfg.state_layers:
+            # a step reads and writes the page of every row of the
+            # batch bucket, a parked row's trash page among them
+            self.eff.note_state(
+                step_rows=W * len(decode_seqs), steps=W,
+                step_bytes=2 * W * batch * self._state_page_bytes)
         if self.model_cfg.index_topk:
             self.eff.note_sparse(
                 "decode", [s.next_position + joined.get(s.seq_id, ahead)
@@ -2255,8 +2263,9 @@ class LLMEngine:
             "pallas_attention": pallas_paged.mode(),
             "attention_paths": dict(self.runner.attention_paths),
             "moe_paths": dict(self.runner.moe_paths),
-            # ops/gdn.gdn_path of each executable of a model with Gated
-            # DeltaNet layers (empty on every other)
+            # ops/gdn.gdn_path or ops/retention.retention_path of each
+            # executable of a model whose layers keep state pages
+            # (empty on every other)
             "mixer_paths": dict(self.runner.mixer_paths),
         }
 
@@ -2300,7 +2309,7 @@ class LLMEngine:
             # headroom is exactly the stranded capacity it reclaims.
             "kv_pool": self.block_mgr.frag_report(),
         }
-        if self.block_mgr.state_pages:
+        if self.block_mgr.keeps_pages:
             report["state_pages_live"] = self.block_mgr.live_pages
         if self.connector is not None:
             # tier hit/miss/bytes counters (all in-memory totals — no

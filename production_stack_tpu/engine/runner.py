@@ -53,6 +53,7 @@ from production_stack_tpu.models.kv import (KV_HEADS, KVCache, cache_for,
 from production_stack_tpu.models import llama
 from production_stack_tpu.ops import moe
 from production_stack_tpu.ops.gdn import gdn_path
+from production_stack_tpu.ops.retention import retention_path
 from production_stack_tpu.ops.pallas_paged import JNP_GATHER, attention_path
 from production_stack_tpu.ops.rope import rope_table
 from production_stack_tpu.utils import init_logger
@@ -89,6 +90,17 @@ class ModelRunner:
         self.model_cfg = model_cfg
         self.engine_cfg = engine_cfg
         self.mesh = mesh
+        if model_cfg.state_layers and not model_cfg.attn_layers:
+            # state pages are the model's ONLY cache (models/kv.py
+            # "State pages alone"): the page is the sequence's one
+            # block and holds any context, so the block is
+            # max_model_len tokens, a table row one column, the pool
+            # max_num_seqs pages and the trash page, and there is one
+            # kv bucket: no executable differs by context. The engine
+            # and the scheduler read the same object
+            engine_cfg.kv_block_size = engine_cfg.max_model_len
+            engine_cfg.kv_pool_tokens = None
+            engine_cfg.kv_len_buckets = (engine_cfg.max_model_len,)
         # stacked multi-LoRA adapters, layer axis leading for lax.scan
         # (models/lora.py); row selection comes in via sampling.adapter
         from production_stack_tpu.models import lora as lora_mod
@@ -143,14 +155,16 @@ class ModelRunner:
                 f"model that selects what it attends (index_topk "
                 f"{model_cfg.index_topk}): the selection is built for "
                 f"one query position a row and for prefill chunks")
-        if model_cfg.gdn_layers:
+        if model_cfg.state_layers:
             self._refuse_with_state_pages(lora_stacked)
         # K and V per kv head, or the latent pool, with the index pool
         # beside it where the model selects what it attends, or K and V
         # of the attention layers with a state page a slot (and the
         # trash page) beside them where the model has Gated DeltaNet
-        # layers (models/kv.cache_for; it refuses an int8 latent pool
-        # and an int8 pool beside state pages by name)
+        # layers, or state pages ALONE, a page a block, where every
+        # layer is a power retention layer (models/kv.cache_for; it
+        # refuses an int8 latent pool and an int8 pool beside state
+        # pages by name)
         self.cache: KVCache = cache_for(
             model_cfg, n_blocks, engine_cfg.kv_block_size, dtype=kv_dt,
             state_pages=(engine_cfg.max_num_seqs + 1
@@ -271,7 +285,8 @@ class ModelRunner:
         # moe_path); empty on a dense model
         self.moe_paths: Dict[str, str] = {}
         # the same key -> the implementation its Gated DeltaNet layers
-        # take (ops/gdn.gdn_path); empty on a model without them
+        # (ops/gdn.gdn_path) or its power retention layers take
+        # (ops/retention.retention_path); empty on a model without them
         self.mixer_paths: Dict[str, str] = {}
         # per-batch-bucket sliced views of the sampling params and
         # block tables (invalidated when the source object changes):
@@ -290,18 +305,24 @@ class ModelRunner:
     @property
     def table_shape(self) -> Tuple[int, int]:
         """[slots, columns] of the table rows: a slot's blocks and,
-        where the model keeps state a sequence, its state page as the
-        last column (models/kv.split_tables)."""
+        where the model keeps state a sequence BESIDE a K/V pool, its
+        state page as the last column (models/kv.split_tables). A
+        model with state pages alone has the one column: its block is
+        its page."""
         return (self.engine_cfg.max_num_seqs,
                 self.engine_cfg.max_blocks_per_seq
                 + bool(self.model_cfg.gdn_layers))
 
     def _refuse_with_state_pages(self, lora_stacked) -> None:
-        """What a model with state pages (Gated DeltaNet layers: state
-        a sequence, models/kv.py) cannot run with yet, each refused by
-        name at start with its reason."""
+        """What a model with state pages (Gated DeltaNet or power
+        retention layers: state a sequence, models/kv.py) cannot run
+        with yet, each refused by name at start with its reason."""
         name, ecfg, mesh = self.model_cfg.name, self.engine_cfg, self.mesh
+        alone = not self.model_cfg.attn_layers
         refused = [
+            (alone and ecfg.kv_dtype == "int8", "an int8 cache "
+             "(--kv-cache-dtype int8)", "the state is float32 and no "
+             "K or V is cached"),
             (ecfg.enable_prefix_caching, "prefix caching "
              "(--enable-prefix-caching)", "a prefix hit restores a "
              "sequence's blocks, not the state its layers had reached "
@@ -317,20 +338,21 @@ class ModelRunner:
             (mesh is not None and any(
                 size > 1 for size in mesh.shape.values()),
              f"a mesh ({dict(mesh.shape) if mesh is not None else {}}: "
-             f"tp, ep, dp must be 1)", "the state pool and the Gated "
-             "DeltaNet kernels run on one chip only"),
+             f"tp, ep, dp must be 1)", "the state pool and its "
+             "kernels run on one chip only"),
             (lora_stacked is not None or bool(ecfg.lora_adapters),
              "LoRA adapters", "the adapters' projections are those of "
              "an attention layer"),
             (bool(ecfg.checkpoint), "the checkpoint loader "
-             "(--checkpoint)", "the published tensors' names and the "
-             "grouped columns of in_proj_qkvz are not mapped yet"),
+             "(--checkpoint)", "the published tensors' names (and the "
+             "grouped columns of in_proj_qkvz) are not mapped yet"),
         ]
+        layout = "state" if alone else "kv+state"
         for on, what, why in refused:
             if on:
                 raise ValueError(
                     f"{name}: {what} is not supported on a model with "
-                    f"state pages (KV pool layout 'kv+state'): {why}")
+                    f"state pages (KV pool layout {layout!r}): {why}")
 
     def set_lora(self, lora_stacked, lora_scaling: float = None) -> None:
         """Swap the stacked adapter pytree in place (runtime adapter
@@ -948,10 +970,23 @@ class ModelRunner:
             positions, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
             self.engine_cfg.kv_block_size, mesh)
 
+    def _mixer_path(self, positions: int) -> Optional[str]:
+        """The implementation the layers that keep state pages take in
+        an executable of ``positions`` query positions a row (None: the
+        model has no such layer)."""
+        cfg = self.model_cfg
+        if cfg.ret_layers:
+            return retention_path(positions, cfg.head_dim_,
+                                  cfg.num_kv_heads)
+        return gdn_path(positions) if cfg.gdn_layers else None
+
     def prefill_attention_path(self, bucket: int, kv_len: int) -> str:
         """The attention path of the prefill executables of a chunk
         bucket and kv bucket, whatever their rows (``_compile`` names
-        them by the same call)."""
+        them by the same call); of a model with no attention layer,
+        its mixers' path."""
+        if not self.model_cfg.attn_layers:
+            return self._mixer_path(bucket)
         return self._attention_path(bucket, self.mesh, kv_len)
 
     def _moe_path(self, rows: int, positions: int) -> str:
@@ -1001,10 +1036,14 @@ class ModelRunner:
         fn = cache.get(key)
         if fn is not None:
             return fn
-        path = self._attention_path(positions, self.mesh, kv_len)
+        # a model with no attention layer has no attention path: its
+        # executables stand in ``mixer_paths`` alone
+        attends = bool(self.model_cfg.attn_layers)
+        path = (self._attention_path(positions, self.mesh, kv_len)
+                if attends else self._mixer_path(positions))
         logger.info("%s executable (batch=%d window=%d kv=%d): "
-                    "attention path %s", kind, batch, window, kv_len,
-                    path)
+                    "%s path %s", kind, batch, window, kv_len,
+                    "attention" if attends else "mixer", path)
         with self._observed(kind, window, kv_len, batch):
             try:
                 fn = make_fn()
@@ -1012,14 +1051,16 @@ class ModelRunner:
             except Exception as e:
                 raise RuntimeError(
                     f"{kind} executable {key!r} failed to compile on the "
-                    f"{path} attention path: {e}") from e
+                    f"{path} {'attention' if attends else 'mixer'} "
+                    f"path: {e}") from e
         cache[key] = fn
         name = f"{kind}|{window}|{kv_len}|{batch}"
-        self.attention_paths[name] = path
+        if attends:
+            self.attention_paths[name] = path
         if self.model_cfg.num_experts:
             self.moe_paths[name] = self._moe_path(batch, positions)
-        if self.model_cfg.gdn_layers:
-            self.mixer_paths[name] = gdn_path(positions)
+        if self.model_cfg.state_layers:
+            self.mixer_paths[name] = self._mixer_path(positions)
         return fn
 
     def prefill(self, tokens, starts, lengths, sampling: SamplingParams,
@@ -1354,7 +1395,7 @@ class ModelRunner:
                          np.full((B,), S, np.int32),
                          np.ones((B,), np.int32), sampling,
                          cfg.kv_bucket_for(bucket))
-        jax.block_until_ready(self.cache.k)
+        jax.block_until_ready(self.cache)
         dt = time.time() - t0
         logger.info(
             "warmup compiled decode grid (batch %s x window %s, kv %d) "

@@ -143,7 +143,13 @@ class ModelConfig:
     # sequence and layer gdn_value_heads matrices of gdn_key_dim x
     # gdn_value_dim and the last gdn_conv - 1 inputs of a depthwise
     # causal convolution over its q, k and v channels, whatever the
-    # context: a state page (models/kv.py), not blocks
+    # context: a state page (models/kv.py), not blocks. "ret" is a power
+    # retention layer of degree 2 (ops/retention.py; Brumby): the
+    # attention layer's own projections (q and k normed a head and
+    # turned), a gate a key-value head, and per sequence, layer and
+    # key-value head a float32 state over the ret_features monomials of
+    # the key's symmetric square, the model's ONLY cache where every
+    # layer is one
     layer_pattern: Tuple[str, ...] = ()
     gdn_key_heads: int = 0
     gdn_value_heads: int = 0
@@ -202,6 +208,21 @@ class ModelConfig:
         return self.num_periods * self.pattern_.count("gdn")
 
     @property
+    def ret_layers(self) -> int:
+        """Power retention layers: state a sequence, no K or V."""
+        return self.num_periods * self.pattern_.count("ret")
+
+    @property
+    def ret_features(self) -> int:
+        """Monomials of the symmetric square of a head's key."""
+        return self.head_dim_ * (self.head_dim_ + 1) // 2
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep state pages, of either kind."""
+        return self.gdn_layers + self.ret_layers
+
+    @property
     def gdn_channels(self) -> int:
         """Channels of a Gated DeltaNet layer's convolution: q, k, v."""
         return (2 * self.gdn_key_heads * self.gdn_key_dim
@@ -210,11 +231,15 @@ class ModelConfig:
     @property
     def state_bytes_per_seq(self) -> int:
         """Bytes of one state page, all layers: the float32 matrices
-        and the convolution's bfloat16 inputs (0: no such layer)."""
-        return self.gdn_layers * (
+        and the convolution's bfloat16 inputs of a Gated DeltaNet
+        layer; a power retention layer's float32 ``S`` and ``z`` a
+        key-value head (0: no such layer)."""
+        return (self.gdn_layers * (
             4 * self.gdn_value_heads * self.gdn_key_dim
             * self.gdn_value_dim + 2 * (self.gdn_conv - 1)
             * self.gdn_channels)
+            + self.ret_layers * 4 * self.num_kv_heads
+            * self.ret_features * (self.head_dim_ + 1))
 
     @property
     def latent_dim(self) -> int:
@@ -276,6 +301,8 @@ class ModelConfig:
                    + 2 * hv + dv)                        # A_log, dt, norm
             return (self.gdn_layers * gdn + self.attn_layers * attn
                     + self.num_layers * 2 * h + rest)
+        if self.ret_layers:      # the gate a key-value head, its bias
+            attn += h * self.num_kv_heads + self.num_kv_heads
         per_layer = attn + 2 * h                         # + norms
         return self.num_layers * per_layer + rest
 
@@ -288,9 +315,9 @@ class ModelConfig:
         (adds q/k/v biases), Gemma (GeGLU via gelu, scaled embeddings,
         unit-offset RMSNorm, tied embeddings), Gemma-2, Mixtral,
         Qwen2-MoE, GLM-4.7-Flash (``glm4_moe_lite``) and GLM-5
-        (``glm_moe_dsa``), both through _glm4_moe_lite, and Qwen3-Next
-        (``qwen3_next``, _qwen3_next). Keys the mapping does not know
-        are ignored.
+        (``glm_moe_dsa``), both through _glm4_moe_lite, Qwen3-Next
+        (``qwen3_next``, _qwen3_next) and Brumby (``brumby``, _brumby).
+        Keys the mapping does not know are ignored.
         """
         archs = cfg.get("architectures") or []
         arch = archs[0] if archs else ""
@@ -313,6 +340,8 @@ class ModelConfig:
                          ("LlamaForCausalLM", "MistralForCausalLM"))
         if model_type == "qwen3_next" or arch == "Qwen3NextForCausalLM":
             return _qwen3_next(cfg, name, dtype)
+        if model_type == "brumby" or arch == "BrumbyForCausalLM":
+            return _brumby(cfg, name, dtype)
         if not (is_qwen2 or is_gemma or is_gemma2 or is_mixtral
                 or is_qwen2_moe or is_glm_lite
                 or is_llama_like) and (model_type or arch):
@@ -320,7 +349,7 @@ class ModelConfig:
                 f"unsupported model family (model_type={model_type!r}, "
                 f"architecture={arch!r}); supported: llama, mistral, "
                 f"qwen2, gemma, gemma2, mixtral, qwen2_moe, "
-                f"glm4_moe_lite, glm_moe_dsa, qwen3_next")
+                f"glm4_moe_lite, glm_moe_dsa, qwen3_next, brumby")
         if is_glm_lite:
             return _glm4_moe_lite(cfg, name, dtype)
         if is_qwen2_moe:
@@ -577,6 +606,53 @@ def _qwen3_next(cfg: Dict[str, Any], name: str, dtype: Any) -> ModelConfig:
     )
 
 
+def _brumby(cfg: Dict[str, Any], name: str, dtype: Any) -> ModelConfig:
+    """Brumby (``brumby``; Manifest AI's retraining of Qwen3-14B):
+    Qwen3's pre-norm block with SwiGLU, and in EVERY layer a power
+    retention mixer of degree 2 in the attention's place
+    (ops/retention.py): q, k, v, o as published, RMSNorm on each head's
+    q and k and the rotary embedding on both (kept from Qwen3), a gate
+    a key-value head. ``config.json`` has no key for the degree, the
+    gate, the normaliser's eps or the scale of q . k: the release's and
+    the ``retention`` package's as known here, listed under ``assumed``
+    in the benchmark's file. What the tree does not build is refused by
+    name."""
+    family = "brumby"
+    for key, refused in (("attention_bias", True),
+                         ("use_sliding_window", True),
+                         ("tie_word_embeddings", True)):
+        if cfg.get(key, False) is refused:
+            raise ValueError(f"{family} with {key} is not supported")
+    if cfg.get("sliding_window"):
+        raise ValueError(f"{family} with sliding_window is not supported")
+    if cfg.get("rope_scaling"):
+        raise ValueError(f"{family} with rope_scaling is not supported")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"{family} hidden_act "
+                         f"{cfg['hidden_act']!r} is not supported")
+    heads = cfg["num_attention_heads"]
+    kv_heads = cfg.get("num_key_value_heads", heads)
+    head_dim = cfg.get("head_dim") or cfg["hidden_size"] // heads
+    if heads % kv_heads or kv_heads % 2 or head_dim % 2:
+        raise ValueError(
+            f"{family}: {heads} query heads over {kv_heads} key-value "
+            f"heads of {head_dim} is not supported (whole groups; the "
+            f"state's layout wants both of the latter even)")
+    return ModelConfig(
+        name=name or cfg.get("_name_or_path", "hf-model"),
+        vocab_size=cfg["vocab_size"],
+        hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg["intermediate_size"],
+        num_layers=cfg["num_hidden_layers"],
+        num_heads=heads, num_kv_heads=kv_heads, head_dim=head_dim,
+        rope_theta=cfg.get("rope_theta", 10000.0),
+        rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        layer_pattern=("ret",), qk_norm=True,
+        dtype=dtype,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Presets. Dimensions are the publicly documented architecture shapes.
 # ---------------------------------------------------------------------------
@@ -720,6 +796,15 @@ PRESETS: Dict[str, ModelConfig] = {
         gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=128,
         gdn_value_dim=128, gdn_conv=4, attn_gate=True, qk_norm=True,
         rotary_dim=32, exact_dequant_scale=True,
+    ),
+    # Tiny Brumby-style model for CPU tests (``brumby``): every mixer a
+    # power retention layer (2 key-value heads of 16, so a head keeps
+    # 136 monomials), no K/V pool at all
+    "debug-brumby": ModelConfig(
+        name="debug-brumby", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
+        head_dim=16, max_position_embeddings=512, rms_norm_eps=1e-6,
+        layer_pattern=("ret",), qk_norm=True,
     ),
     # GLM-4.7-Flash (glm4_moe_lite, 30B-A3B): latent attention, one
     # leading dense layer of width 10240, 64 sigmoid-routed experts

@@ -104,6 +104,20 @@ TPU-first invariants:
   A chunk whose first position is 0 starts from a zero state inside
   the layer: no page is ever cleared.
 
+- **State pages alone** (power retention layers: Brumby,
+  ops/retention.py). A model whose EVERY mixer keeps state a sequence
+  has no K/V pool at all: ``KVCache.k`` is None, ``layout`` "state",
+  ``bytes_per_token`` 0. A layer keeps, a sequence and key-value head,
+  a float32 ``S`` over the ``F = D (D + 1) / 2`` monomials of the key's
+  symmetric square and the normaliser ``z``: ``state [L, P, Hkv, F,
+  D]`` and ``norm [L, P, F Hkv / D, D]`` (the layout inside a page is
+  ops/retention.py's), 34 MB a layer and sequence at 8 heads of 128,
+  whatever the context. The page IS the sequence's one block: the
+  engine runs such a model at a block of ``max_model_len`` tokens, so
+  a table row is one column, the page's id, and the block manager's
+  counts (free, active, admission, preemption) are counts of pages;
+  page 0 is the trash block and the trash page at once.
+
 The reference stack's KV management is configuration around LMCache env
 vars (reference: helm/templates/deployment-vllm-multi.yaml:154-178) and
 its engine's paged KV lives inside vLLM (the stack passes
@@ -124,10 +138,13 @@ LATENT = "latent"        # KVCache.layout: [c | k_rope], no v
 LATENT_INDEX = "latent+index"   # and the indexer's key in its own pool
 KV_HEADS = "kv_heads"     # separate K and V per kv head
 KV_STATE = "kv+state"     # and a state page a sequence beside them
+STATE = "state"           # state pages alone: no K, no V
 
 
 class KVCache(NamedTuple):
-    k: jnp.ndarray  # [L, N, Hkv, Bs, D]; latent pool [L, N, 1, Bs, W]
+    # [L, N, Hkv, Bs, D]; latent pool [L, N, 1, Bs, W]; None where
+    # state pages are the model's only cache
+    k: Optional[jnp.ndarray] = None
     v: Optional[jnp.ndarray] = None  # [L, N, Hkv, Bs, D]; latent: None
     # int8 KV mode only: symmetric per-(token, head) dequant scales
     # (models/quant.py recipe applied to the cache): value = int8 *
@@ -141,14 +158,20 @@ class KVCache(NamedTuple):
     # float32 and the convolutions' inputs [Lg, P, taps - 1, Ch]
     state: Optional[jnp.ndarray] = None
     conv: Optional[jnp.ndarray] = None
+    # power retention layers only: the state pages [L, P, Hkv, F, D]
+    # float32 are ``state``, and their normalisers [L, P, F Hkv / D, D]
+    norm: Optional[jnp.ndarray] = None
 
     @property
     def num_blocks(self) -> int:
-        return self.k.shape[1]
+        """Blocks of the pool, the trash block among them; of a model
+        with state pages alone, its pages (a page is its block)."""
+        return self.state_pages if self.k is None else self.k.shape[1]
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[3]
+        """Tokens a block holds (0: state pages alone, any number)."""
+        return 0 if self.k is None else self.k.shape[3]
 
     @property
     def quantized(self) -> bool:
@@ -158,6 +181,8 @@ class KVCache(NamedTuple):
     def layout(self) -> str:
         if self.idx is not None:
             return LATENT_INDEX
+        if self.k is None:
+            return STATE
         if self.state is not None:
             return KV_STATE
         return LATENT if self.v is None else KV_HEADS
@@ -167,6 +192,8 @@ class KVCache(NamedTuple):
         """Bytes one token takes in the pools, all layers, as allocated
         (payload and, int8, scales; the index pool's keys too; not the
         state pages, which a sequence takes whatever its tokens)."""
+        if self.k is None:
+            return 0
         tokens = self.num_blocks * self.block_size
         return sum(a.dtype.itemsize * (a.size // tokens)
                    for a in self.carried())
@@ -200,17 +227,22 @@ class KVCache(NamedTuple):
         those that are there, in the fields' order."""
         return tuple(a for _, a in self._per_token() if a is not None)
 
+    def _per_sequence(self):
+        """(name, array) of the fields that hold state a SEQUENCE."""
+        return [(n, getattr(self, n)) for n in ("state", "conv", "norm")]
+
     def state_carried(self) -> "Pool":
-        """The state pages the loop carries beside them: (state, conv),
-        or () where the model has no such layer."""
-        return () if self.state is None else (self.state, self.conv)
+        """The state pages the loop carries beside them: (state, conv)
+        of Gated DeltaNet layers, (state, norm) of power retention
+        layers, or () where the model has no such layer."""
+        return tuple(a for _, a in self._per_sequence() if a is not None)
 
     def carried_back(self, pool: "Pool", state: "Pool" = ()) -> "KVCache":
         """``carried`` and ``state_carried`` undone: the same fields,
         the loop's arrays."""
         names = [n for n, a in self._per_token() if a is not None]
-        return KVCache(**dict(zip(names, pool)),
-                       **dict(zip(("state", "conv"), state)))
+        kinds = [n for n, a in self._per_sequence() if a is not None]
+        return KVCache(**dict(zip(names, pool)), **dict(zip(kinds, state)))
 
 
 # the pool as a step program's layer loop carries it: a KVCache's
@@ -272,7 +304,23 @@ def cache_for(cfg, num_blocks: int, block_size: int,
     what it attends), K and V per kv head for everything else: of the
     model's ATTENTION layers, with ``state_pages`` state pages (the
     trash page 0 among them) beside them where it has Gated DeltaNet
-    layers."""
+    layers; state pages ALONE, ``num_blocks`` of them, where every
+    layer is a power retention layer (module text)."""
+    if cfg.ret_layers:
+        if cfg.attn_layers or cfg.gdn_layers:
+            raise ValueError(
+                f"{cfg.name}: power retention layers beside another "
+                f"kind of mixer are not built (layer_pattern "
+                f"{cfg.layer_pattern})")
+        if num_blocks < 2:
+            raise ValueError("a state pool needs the trash page and at "
+                             "least one more")
+        D, F, H = cfg.head_dim_, cfg.ret_features, cfg.num_kv_heads
+        return KVCache(
+            state=jnp.zeros((cfg.ret_layers, num_blocks, H, F, D),
+                            jnp.float32),
+            norm=jnp.zeros((cfg.ret_layers, num_blocks, F * H // D, D),
+                           jnp.float32))
     if cfg.gdn_layers:
         if dtype == jnp.int8:
             raise ValueError(

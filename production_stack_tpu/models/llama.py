@@ -29,7 +29,7 @@ from production_stack_tpu.models import kv as kv_pool
 from production_stack_tpu.models import lora, quant
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.models.kv import KVCache
-from production_stack_tpu.ops import gdn, moe
+from production_stack_tpu.ops import gdn, moe, retention
 from production_stack_tpu.ops.attention import causal_attention
 from production_stack_tpu.ops.norms import rms_norm
 from production_stack_tpu.ops.rope import apply_rope, rope_table
@@ -87,7 +87,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
 
     if cfg.mla:
         return _init_params_mla(cfg, key, w)
-    if cfg.layer_pattern:
+    if cfg.gdn_layers:
         return _init_params_hybrid(cfg, key, w)
     norm_init = jnp.zeros if cfg.rms_norm_offset else jnp.ones
     E = cfg.num_experts
@@ -138,6 +138,20 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         params["layers"]["v_bias"] = jnp.zeros((L, nkv * hd), cfg.dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), (h, v), "lm_head")
+    if cfg.qk_norm:
+        params["layers"]["q_norm"] = norm_init((L, hd), cfg.dtype)
+        params["layers"]["k_norm"] = norm_init((L, hd), cfg.dtype)
+    if cfg.ret_layers:
+        # a power retention mixer's gate, one number a key-value head:
+        # log g = logsigmoid(x W_g + b_g). The bias is drawn U(2, 8), so
+        # that the heads' memories run from about eight tokens
+        # (sigmoid(2) = 0.88) to about three thousand (sigmoid(8)); at
+        # zero every head would forget all but its last few tokens and
+        # a carried state would hold nothing to compare
+        params["layers"]["ret_gate"] = w(next(keys), (L, h, nkv), "layers",
+                                         "ret_gate")
+        params["layers"]["ret_gate_bias"] = jax.random.uniform(
+            next(keys), (L, nkv), jnp.float32, 2.0, 8.0)
     return params
 
 
@@ -363,6 +377,55 @@ def _gdn_layer(cfg: ModelConfig, x, lp: Params, state, state_ids, starts,
                             moe_capacity_tokens, expert_stacks, layer,
                             None)
     return x, (mats, conv), work
+
+
+def _ret_layer(cfg: ModelConfig, rope, positions, starts, x, lp: Params,
+               state, state_ids, token_valid, layer):
+    """One power retention block (ops/retention.py; Brumby). x [B,T,H];
+    state = the WHOLE state pools (``S`` [L,P,Hkv,F,D] and the
+    normalisers [L,P,F Hkv/D,D], float32), of which this block reads
+    and writes the rows' pages ``state_ids`` [B] of layer ``layer``, in
+    place: the model's only cache. q = RoPE(RMSNorm_head(x W_q)) times
+    head_dim ** -0.5, k = RoPE(RMSNorm_head(x W_k)), v = x W_v, log g =
+    logsigmoid(x W_g + b_g) a key-value head; then W_o and the dense
+    MLP. As _gdn_layer: a row none of whose positions is real names the
+    trash page 0 whatever its table says; positions that are not real
+    trail the chunk and advance nothing (log g = 0, k = 0); a row whose
+    first position is 0 starts from a zero state. Returns (x', the
+    pools, None); scopes ret_proj, rope, ret_scan / ret_step
+    (ops/retention.retain), ret_out_proj."""
+    B, T, _ = x.shape
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    cos, sin = rope
+    if token_valid is None:
+        token_valid = jnp.ones((B, T), bool)
+    ids = jnp.where(jnp.any(token_valid, axis=1), state_ids, 0)
+    with jax.named_scope("attn_norm"):
+        hidden = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("ret_proj"):
+        q = quant.dequant_matmul(hidden, lp["q"]).reshape(B, T, nh, hd)
+        k = quant.dequant_matmul(hidden, lp["k"]).reshape(B, T, nkv, hd)
+        v = quant.dequant_matmul(hidden, lp["v"]).reshape(B, T, nkv, hd)
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+        logg = jax.nn.log_sigmoid(
+            jnp.einsum("bth,hj->btj", hidden, lp["ret_gate"],
+                       preferred_element_type=jnp.float32)
+            + lp["ret_gate_bias"])
+        logg = jnp.where(token_valid[..., None], logg, 0.0)
+    with jax.named_scope("rope"):
+        q = apply_rope(q, positions, cos, sin)
+        k = apply_rope(k, positions, cos, sin)
+        q = (q.astype(jnp.float32) * hd ** -0.5).astype(x.dtype)
+        k = jnp.where(token_valid[..., None, None], k, 0)
+    y, *state = retention.retain(q, k, v, logg, *state, ids, layer,
+                                 starts == 0)
+    with jax.named_scope("ret_out_proj"):
+        x = x + quant.dequant_matmul(
+            y.astype(x.dtype).reshape(B, T, nh * hd), lp["o"])
+    x, _, _ = _mlp_block(cfg, x, lp, None, token_valid, None, None, layer,
+                         lambda h, name: quant.dequant_matmul(h, lp[name]))
+    return x, tuple(state), None
 
 
 def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
@@ -791,18 +854,21 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             "of mixer")
     if block_tables is None:
         B = tokens.shape[0]
-        Bs = cache.block_size
-        n_per = (cache.k.shape[1] - 1) // B
-        block_tables = kv_pool.linear_tables(B, n_per * Bs, Bs)
-        if cfg.gdn_layers:      # row b's state page: 1 + b
-            block_tables = jnp.concatenate(
-                [block_tables, 1 + jnp.arange(B, dtype=jnp.int32)[:, None]],
-                axis=1)
+        pages = 1 + jnp.arange(B, dtype=jnp.int32)[:, None]
+        if cache.k is None:     # state pages alone: row b's page, 1 + b
+            block_tables = pages
+        else:
+            Bs = cache.block_size
+            n_per = (cache.k.shape[1] - 1) // B
+            block_tables = kv_pool.linear_tables(B, n_per * Bs, Bs)
+            if cfg.state_layers:
+                block_tables = jnp.concatenate([block_tables, pages],
+                                               axis=1)
     starts = positions[:, 0]
     # the last column of a table row is the sequence's state page,
     # where the model has such pages (models/kv.split_tables)
     block_tables, state_ids = kv_pool.split_tables(
-        block_tables, bool(cfg.gdn_layers))
+        block_tables, bool(cfg.state_layers))
     with jax.named_scope("embed"):
         x = _embed(params, cfg, tokens)
     # the scan's unit is one PERIOD of the layer pattern (cfg.pattern_):
@@ -819,6 +885,11 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         # (models/kv.py); the state pages beside it alike
         h, pool, spool = carry
         lp, layer, ll, local = xs
+        if pattern == ("ret",):
+            h, spool, work = _ret_layer(
+                cfg, rope, positions, starts, h, lp, spool, state_ids,
+                token_valid, layer)
+            return (h, pool, spool), work
         if period == 1:
             h, pool, work = _layer_body(
                 cfg, rope, positions, starts, h, lp, pool,
@@ -918,7 +989,7 @@ def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             f"{cfg.name}: a forward without caches (encode, "
             f"forward_train: embeddings, echoed prompt log-"
             f"probabilities) is not built for a model with state pages; "
-            f"its reference is chipbench/references/qwen3_next.py")
+            f"its reference is under chipbench/references/")
     if rope is None:
         rope = rope_table(cfg.max_position_embeddings, cfg.rope_dim_,
                           cfg.rope_theta, scaling=cfg.rope_scaling)

@@ -37,7 +37,9 @@ _SKIP_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_mlp_norm",
                # a Gated DeltaNet mixer's small leaves, and the gated
                # attention's per-head norms
                "ba", "conv", "A_log", "dt_bias", "gdn_norm", "q_norm",
-               "k_norm")
+               "k_norm",
+               # a power retention mixer's gate a key-value head
+               "ret_gate", "ret_gate_bias")
 # the groups of stacked layers a tree may hold: the scanned layers and
 # a layer plan's leading dense ones (models/llama.py)
 _LAYER_GROUPS = ("layers", "dense_layers", "gdn_layers", "attn_layers")

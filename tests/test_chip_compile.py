@@ -1175,3 +1175,112 @@ def test_kv_prefill_kernel_in_q_blocks_compiles_at_8_groups_of_256(
         s((1, T, 16, 256), jnp.bfloat16), pool, pool,
         s((1, 256), jnp.int32), s((1,), jnp.int32),
         s((), jnp.int32)).compile()
+
+
+# ---------------------------------------------------------------------
+# state pages alone (Brumby's cut at its published widths: 8 key-value
+# heads of 128, five queries a head, a float32 state of 8256 monomials
+# a head; no K/V pool)
+# ---------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,T,kernel", [
+    (16, 1, "retention_recurrent_step"), (1, 256, "retention_chunk_scan"),
+    (16, 256, "retention_chunk_scan"), (1, 2048, "retention_chunk_scan")])
+def test_retention_kernels_compile_at_brumby_widths(topo, tpu_branches,
+                                                    B, T, kernel):
+    """ops/retention.py's two kernels at the cell's shapes (a decode
+    step of 16 rows; a 256-token chunk of 1 and of 16 rows; the 2048
+    tools/retention_chip_check.py runs) over the cell's pool of 17
+    pages and 10 layers, compiled for the described v5e: the kernel of
+    the forward's kind is in the program, both pools are aliased to the
+    result (nothing of 5.8 GB is copied) and nothing of the monomials'
+    size (``[tokens, 8256]``) is made in HBM."""
+    import re
+    from production_stack_tpu.ops import retention
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    L, P, H, G, D = 10, 17, 8, 5, 128
+    F = retention.features(D)
+    assert retention.retention_path(T) == kernel.rsplit("_", 1)[0]
+    compiled = jax.jit(
+        lambda q, k, v, g, state, norm, ids, fresh: retention.retain(
+            q, k, v, g, state, norm, ids, 3, fresh),
+        donate_argnums=(4, 5)).lower(
+        s((B, T, H * G, D), jnp.bfloat16), s((B, T, H, D), jnp.bfloat16),
+        s((B, T, H, D), jnp.bfloat16), s((B, T, H)),
+        s((L, P, H, F, D)), s((L, P, F * H // D, D)),
+        s((B,), jnp.int32), s((B,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    calls = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]+)[\w.\-]* = [^=]*? custom-call\(", hlo)}
+    assert {c for c in calls if c.startswith("retention")} == {kernel}
+    pools = L * P * H * F * (D + 1) * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+    assert not re.findall(r"\[(?:\d+,)*(?:8256|8320)(?:,\d+)*\]\S* "
+                          r"(?:fusion|multiply|broadcast)\(", hlo)
+
+
+def test_brumby_decode_window_compiles_with_state_pages_alone(
+        topo, tpu_branches, monkeypatch):
+    """One decode window of 16 rows at the benchmark's Brumby file cut
+    to two layers, compiled whole for the described v5e as the runner
+    lays such a model out: ONE table column (the page), one kv bucket,
+    no K or V array among the program's arguments, the step kernel
+    once a layer and step, the state pools aliased to the result."""
+    import dataclasses
+    import json
+    import re
+    from chipbench.engine_child import model_config
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.engine.runner import ModelRunner
+    from production_stack_tpu.models import llama
+    from production_stack_tpu.models.kv import cache_for
+    from production_stack_tpu.ops.rope import rope_table
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "brumby-14b-int8-l10.json")) as f:
+        conf = json.load(f)
+    mcfg = dataclasses.replace(model_config(conf, "brumby-cut"),
+                               num_layers=2)
+    ecfg = EngineConfig(model="brumby-cut", quantization="int8",
+                        max_num_seqs=16, max_model_len=32768,
+                        prefill_chunk=256)
+    # (ModelRunner.__init__ sets the page geometry; a skeleton has to)
+    ecfg.kv_block_size, ecfg.kv_len_buckets = 32768, (32768,)
+    runner = ModelRunner.__new__(ModelRunner)
+    runner.model_cfg, runner.engine_cfg, runner.mesh = mcfg, ecfg, None
+    runner._lora, runner._lora_scaling = None, 1.0
+    runner.rope = rope_table(32768, mcfg.rope_dim_, mcfg.rope_theta)
+    assert runner.table_shape == (16, 1) and ecfg.num_kv_blocks == 17
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def rep(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def placed(tree):
+        return jax.tree.map(lambda x: rep(x.shape, x.dtype), tree)
+    params = placed(jax.eval_shape(
+        partial(llama.init_params, mcfg, quantization="int8"),
+        jax.random.PRNGKey(0)))
+    cache = placed(jax.eval_shape(partial(cache_for, mcfg, 17, 32768)))
+    assert cache.k is None and cache.v is None
+    a = _step_args(runner, rep, 16)
+    fn = jax.jit(partial(runner._decode_impl, steps=8, kv_len=32768,
+                         greedy=True), donate_argnums=(1,))
+    compiled = fn.lower(
+        params, cache, rep((16, 1), jnp.int32), rep((16,), jnp.int32),
+        rep((16,), jnp.int32), a["sampling"], a["key"], a["guide_next"],
+        a["guide_id"], a["guide_state"], a["counts"],
+        a["seen"]).compile()
+    hlo = compiled.as_text()
+    calls = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]+)[\w.\-]* = [^=]*? custom-call\(", hlo)}
+    assert {c for c in calls if c.startswith(("paged", "retention"))} \
+        == {"retention_recurrent_step"}
+    assert runner._mixer_path(1) == "retention_recurrent"
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= 2 * 17 * 8 * 8256 * 129 * 4)
+    _fits(compiled, "brumby cut decode window")
