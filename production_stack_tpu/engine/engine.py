@@ -1639,11 +1639,13 @@ class LLMEngine:
                     and not s.options.min_p
                     for s in decode_seqs)
         if self.model_cfg.state_layers:
-            # a step reads and writes the page of every row of the
-            # batch bucket, a parked row's trash page among them
+            # a window moves pages of every row of the batch bucket, a
+            # parked row's trash page among them: 2 W a row, or W + 2
+            # where the window form runs (runner.state_pages_moved)
             self.eff.note_state(
                 step_rows=W * len(decode_seqs), steps=W,
-                step_bytes=2 * W * batch * self._state_page_bytes)
+                step_bytes=self.runner.state_pages_moved(W) * batch
+                * self._state_page_bytes)
         if self.model_cfg.index_topk:
             self.eff.note_sparse(
                 "decode", [s.next_position + joined.get(s.seq_id, ahead)

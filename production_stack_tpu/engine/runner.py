@@ -51,9 +51,8 @@ from production_stack_tpu.models import kv as kv_pool
 from production_stack_tpu.models.kv import (KV_HEADS, KVCache, cache_for,
                                             latent_pool_width)
 from production_stack_tpu.models import llama
-from production_stack_tpu.ops import moe
+from production_stack_tpu.ops import moe, retention
 from production_stack_tpu.ops.gdn import gdn_path
-from production_stack_tpu.ops.retention import retention_path
 from production_stack_tpu.ops.pallas_paged import JNP_GATHER, attention_path
 from production_stack_tpu.ops.rope import rope_table
 from production_stack_tpu.utils import init_logger
@@ -477,12 +476,19 @@ class ModelRunner:
         than forking the executable cache.
         """
         S = self.engine_cfg.max_model_len
+        # where the model's power retention layers take the window form
+        # at this step count, the window's own keys ride beside the
+        # cache: the steps read the pages and the window's end writes
+        # them, once (None for every other model and step count: the
+        # steps are ``llama.forward``'s)
+        window = llama.open_window(self.model_cfg, tables, positions,
+                                   steps, positions < S)
 
         def body(carry, i):
-            cache, toks, pos, gstate, counts = carry
-            logits, cache, work = llama.forward(
+            cache, toks, pos, gstate, counts, window = carry
+            logits, cache, work, window = llama.forward_in_window(
                 params, self.model_cfg, toks[:, None], pos[:, None],
-                cache, block_tables=tables,
+                cache, window, block_tables=tables,
                 rope=self.rope, kv_len=kv_len, mesh=self.mesh,
                 lora_params=self._lora, adapter_ids=sampling.adapter,
                 lora_scaling=self._lora_scaling,
@@ -493,14 +499,15 @@ class ModelRunner:
                 jax.random.fold_in(key, i), greedy=greedy,
                 seeded=seeded, plain=plain, guided=guided,
                 penalized=penalized, eos_id=eos_id, topk=topk)
-            return ((cache, ids, pos + 1, gstate, counts),
+            return ((cache, ids, pos + 1, gstate, counts, window),
                     (ids, lp, ti, tl,
                      None if work is None else work.experts_read))
 
-        (cache, toks, pos, gstate, counts), (ids, lps, tis, tls, read) = \
-            jax.lax.scan(
-                body, (cache, tokens, positions, guide_state, out_counts),
-                jnp.arange(steps))
+        ((cache, toks, pos, gstate, counts, window),
+         (ids, lps, tis, tls, read)) = jax.lax.scan(
+            body, (cache, tokens, positions, guide_state, out_counts,
+                   window), jnp.arange(steps))
+        cache = llama.close_window(cache, window)
         # ids/lps [B, steps]; tis/tls [B, steps, K]
         return (ids.T, lps.T, tis.transpose(1, 0, 2),
                 tls.transpose(1, 0, 2), toks, pos, gstate, counts,
@@ -970,15 +977,25 @@ class ModelRunner:
             positions, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
             self.engine_cfg.kv_block_size, mesh)
 
-    def _mixer_path(self, positions: int) -> Optional[str]:
+    def _mixer_path(self, positions: int, steps: int = 1) -> Optional[str]:
         """The implementation the layers that keep state pages take in
-        an executable of ``positions`` query positions a row (None: the
-        model has no such layer)."""
+        an executable of ``positions`` query positions a row, ``steps``
+        of them fused (a decode window's; 1: a prefill chunk) (None:
+        the model has no such layer)."""
         cfg = self.model_cfg
         if cfg.ret_layers:
-            return retention_path(positions, cfg.head_dim_,
-                                  cfg.num_kv_heads)
+            return retention.retention_path(
+                positions, cfg.head_dim_, cfg.num_kv_heads, steps)
         return gdn_path(positions) if cfg.gdn_layers else None
+
+    def state_pages_moved(self, steps: int) -> int:
+        """State pages a row's decode window of ``steps`` steps reads
+        and writes, a layer: a read and a write a step, but where power
+        retention layers take the window form (ops/retention.windowed)
+        a read a step and the fold's read and write."""
+        return retention.pages_moved(
+            steps, bool(self.model_cfg.ret_layers)
+            and retention.windowed(1, steps))
 
     def prefill_attention_path(self, bucket: int, kv_len: int) -> str:
         """The attention path of the prefill executables of a chunk
@@ -1039,8 +1056,9 @@ class ModelRunner:
         # a model with no attention layer has no attention path: its
         # executables stand in ``mixer_paths`` alone
         attends = bool(self.model_cfg.attn_layers)
+        mixer = self._mixer_path(positions, window if kind == "decode" else 1)
         path = (self._attention_path(positions, self.mesh, kv_len)
-                if attends else self._mixer_path(positions))
+                if attends else mixer)
         logger.info("%s executable (batch=%d window=%d kv=%d): "
                     "%s path %s", kind, batch, window, kv_len,
                     "attention" if attends else "mixer", path)
@@ -1060,7 +1078,7 @@ class ModelRunner:
         if self.model_cfg.num_experts:
             self.moe_paths[name] = self._moe_path(batch, positions)
         if self.model_cfg.state_layers:
-            self.mixer_paths[name] = self._mixer_path(positions)
+            self.mixer_paths[name] = mixer
         return fn
 
     def prefill(self, tokens, starts, lengths, sampling: SamplingParams,

@@ -380,7 +380,8 @@ def _gdn_layer(cfg: ModelConfig, x, lp: Params, state, state_ids, starts,
 
 
 def _ret_layer(cfg: ModelConfig, rope, positions, starts, x, lp: Params,
-               state, state_ids, token_valid, layer):
+               state, state_ids, token_valid, layer, window=None,
+               taken=None):
     """One power retention block (ops/retention.py; Brumby). x [B,T,H];
     state = the WHOLE state pools (``S`` [L,P,Hkv,F,D] and the
     normalisers [L,P,F Hkv/D,D], float32), of which this block reads
@@ -391,9 +392,13 @@ def _ret_layer(cfg: ModelConfig, rope, positions, starts, x, lp: Params,
     MLP. As _gdn_layer: a row none of whose positions is real names the
     trash page 0 whatever its table says; positions that are not real
     trail the chunk and advance nothing (log g = 0, k = 0); a row whose
-    first position is 0 starts from a zero state. Returns (x', the
-    pools, None); scopes ret_proj, rope, ret_scan / ret_step
-    (ops/retention.retain), ret_out_proj."""
+    first position is 0 starts from a zero state. Inside a decode
+    window that takes the window form (``window``: ops/retention.Window;
+    ``taken``: this layer's slices of its keys, values and gates) the
+    pages are only read, the rows' pages are the window's, and the
+    step's k, v and summed log-gate join the slices. Returns (x', the
+    pools, the slices or None); scopes ret_proj, rope, ret_scan /
+    ret_step (ops/retention.retain), ret_out_proj."""
     B, T, _ = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     cos, sin = rope
@@ -418,14 +423,19 @@ def _ret_layer(cfg: ModelConfig, rope, positions, starts, x, lp: Params,
         k = apply_rope(k, positions, cos, sin)
         q = (q.astype(jnp.float32) * hd ** -0.5).astype(x.dtype)
         k = jnp.where(token_valid[..., None, None], k, 0)
-    y, *state = retention.retain(q, k, v, logg, *state, ids, layer,
-                                 starts == 0)
+    if window is None:
+        y, *state = retention.retain(q, k, v, logg, *state, ids, layer,
+                                     starts == 0)
+    else:
+        with jax.named_scope("ret_step"):
+            y, *taken = retention.retain_in_window(
+                q, k, v, logg, *taken, window, *state, layer)
     with jax.named_scope("ret_out_proj"):
         x = x + quant.dequant_matmul(
             y.astype(x.dtype).reshape(B, T, nh * hd), lp["o"])
     x, _, _ = _mlp_block(cfg, x, lp, None, token_valid, None, None, layer,
                          lambda h, name: quant.dequant_matmul(h, lp[name]))
-    return x, tuple(state), None
+    return x, tuple(state), None if window is None else tuple(taken)
 
 
 def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
@@ -806,6 +816,33 @@ def _gelu_tanh(x: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.gelu(x, approximate=True)
 
 
+def open_window(cfg: ModelConfig, block_tables: jnp.ndarray,
+                positions: jnp.ndarray, steps: int, valid: jnp.ndarray):
+    """What a decode window of ``steps`` steps (one position a row and
+    step, the rows' first at ``positions`` [B]) carries beside the
+    cache from its first ``forward_in_window`` to ``close_window``: an
+    ops/retention.Window where the model's power retention layers take
+    the window form at that step count (ops/retention.windowed: the
+    pages are read a step and written once, by the fold), else None,
+    and the steps run as ``forward`` runs them. valid [B]: the row is
+    real at the first step (else it names the trash page)."""
+    if not (cfg.ret_layers and retention.windowed(1, steps)):
+        return None
+    _, ids = kv_pool.split_tables(block_tables, True)
+    return retention.open_window(cfg.num_layers, steps, cfg.num_kv_heads,
+                                 cfg.head_dim_, jnp.where(valid, ids, 0),
+                                 positions == 0)
+
+
+def close_window(cache: KVCache, window) -> KVCache:
+    """The window's end: its keys folded into the rows' pages (``cache``
+    as the last step returned it; None: nothing was deferred)."""
+    if window is None:
+        return cache
+    return cache.carried_back(cache.carried(), retention.fold_window(
+        window, *cache.state_carried()))
+
+
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             positions: jnp.ndarray, cache: KVCache,
             block_tables: Optional[jnp.ndarray] = None,
@@ -843,6 +880,30 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     list_path, decode steps, and grouped_path, prefill chunks), and
     ``expert_rows``, the rows those experts multiplied.
     """
+    return forward_in_window(
+        params, cfg, tokens, positions, cache, None,
+        block_tables=block_tables, rope=rope, kv_len=kv_len,
+        lora_params=lora_params, adapter_ids=adapter_ids,
+        lora_scaling=lora_scaling, token_valid=token_valid, mesh=mesh,
+        moe_capacity_tokens=moe_capacity_tokens)[:3]
+
+
+def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+                      positions: jnp.ndarray, cache: KVCache, window,
+                      block_tables: Optional[jnp.ndarray] = None,
+                      rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+                      kv_len: Optional[int] = None,
+                      lora_params=None,
+                      adapter_ids: Optional[jnp.ndarray] = None,
+                      lora_scaling: float = 1.0,
+                      token_valid: Optional[jnp.ndarray] = None,
+                      mesh=None, moe_capacity_tokens: Optional[int] = None):
+    """``forward`` as one step of a decode window: ``window`` is what
+    ``open_window`` gave or the step before returned. -> (logits,
+    cache', the experts' work, window'). With a window the power
+    retention layers read their pages and write nothing (the cache's
+    pools come back as they went in) and the step's keys join the
+    window; with None this IS ``forward``."""
     if rope is None:
         rope = rope_table(cfg.max_position_embeddings, cfg.rope_dim_,
                           cfg.rope_theta, scaling=cfg.rope_scaling)
@@ -884,12 +945,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         # layer's pool per layer and the whole pool per step
         # (models/kv.py); the state pages beside it alike
         h, pool, spool = carry
-        lp, layer, ll, local = xs
+        lp, layer, ll, local, taken = xs
         if pattern == ("ret",):
-            h, spool, work = _ret_layer(
+            h, spool, taken = _ret_layer(
                 cfg, rope, positions, starts, h, lp, spool, state_ids,
-                token_valid, layer)
-            return (h, pool, spool), work
+                token_valid, layer, window, taken)
+            return (h, pool, spool), (None, taken)
         if period == 1:
             h, pool, work = _layer_body(
                 cfg, rope, positions, starts, h, lp, pool,
@@ -899,7 +960,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 layer_local=local, layer=layer,
                 moe_capacity_tokens=moe_capacity_tokens,
                 expert_stacks=expert_stacks)
-            return (h, pool, spool), work
+            return (h, pool, spool), (work, None)
         # ``layer`` is the period's index; its sub-layers in a static
         # loop, each reading its own row of the groups' stacks in place
         # (closed over, like the experts' stacks: as the scan's xs a
@@ -929,7 +990,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                     expert_stacks=expert_stacks,
                     kv_layer=layer * per + i)
             works.append(work)
-        return (h, pool, spool), moe.Work(*map(sum, zip(*works)))
+        return (h, pool, spool), (moe.Work(*map(sum, zip(*works))), None)
 
     layer_params = params["layers"]
     expert_stacks = None
@@ -956,23 +1017,28 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         layers = jnp.arange(cfg.num_periods)
     xs = (layer_params if period == 1 else None, layers, lora_params,
           # Gemma-2 layer pattern: even layers sliding, odd global
-          layers % 2 == 0 if cfg.alternating_sliding else None)
+          layers % 2 == 0 if cfg.alternating_sliding else None,
+          # a decode window's keys, values and gates, a layer's slices
+          None if window is None else (window.k, window.v, window.G))
     pool, spool = cache.carried(), cache.state_carried()
     with jax.named_scope("dense_layers"):
         for i in range(Ld):
             lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
             (x, pool, spool), _ = scan_body(
-                (x, pool, spool), (lp, jnp.int32(i), None, None))
+                (x, pool, spool), (lp, jnp.int32(i), None, None, None))
     with jax.named_scope("layers"):
-        (x, pool, spool), work = jax.lax.scan(scan_body, (x, pool, spool),
-                                              xs)
+        (x, pool, spool), (work, taken) = jax.lax.scan(
+            scan_body, (x, pool, spool), xs)
+    if window is not None:
+        k, v, G = taken
+        window = window._replace(k=k, v=v, G=G, step=window.step + 1)
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                      offset=1.0 if cfg.rms_norm_offset else 0.0)
     with jax.named_scope("lm_head"):
         logits = _lm_head(params, cfg, x)
     return (logits, cache.carried_back(pool, spool),
-            None if work is None else moe.Work(*map(jnp.sum, work)))
+            None if work is None else moe.Work(*map(jnp.sum, work)), window)
 
 
 def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,

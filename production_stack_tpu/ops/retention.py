@@ -25,13 +25,39 @@ D]``, row ``d Hkv + j`` head j's ``z[d, :]`` and the last ``Hkv / 2``
 rows the half rows of heads ``r`` and ``r + Hkv/2`` side by side. Both
 are exactly the ``F (D + 1)`` numbers a head keeps.
 
-Two implementations, chosen by shape alone (``retention_path``), both
+Three implementations, chosen by shape alone (``retention_path``), all
 taking the state from and leaving it in its page, which a step program
 carries as it carries a K/V pool:
 
-``retention_recurrent``  T <= DECODE_T_MAX positions a row (a decode
-    step): the rule as written. On the TPU the kernel
-    ``retention_recurrent_step``, a grid step a (row, key-value head):
+``retention_window``  one position a row in a decode window that fuses
+    W >= 4 steps (``windowed``): the rule re-associated over the
+    window. With ``S0``, ``z0`` the page as the window found it,
+    ``G_t`` the log-gates summed from the window's first step (all <=
+    0) and ``(k_j, v_j)`` the window's own keys and values,
+
+        num_t = exp(G_t) phi(q_t)^T S0 + sum_{j<=t} exp(G_t - G_j) (q_t . k_j)^2 v_j
+        den_t = exp(G_t) phi(q_t)^T z0 + sum_{j<=t} exp(G_t - G_j) (q_t . k_j)^2
+        y_t = num_t / (den_t + eps)
+
+    and after the last step ``S = exp(G_W) S0 + sum_j exp(G_W - G_j)
+    phi(k_j) v_j^T``, ``z`` alike: the state is READ W times and
+    written once (W + 2 pages a row for the recurrent form's 2 W). A
+    step (``retain_in_window``) is the kernel ``retention_recurrent_
+    step`` in a form that copies a head's ``S`` in, multiplies it by
+    the group's queries and copies nothing back, plus the two sums over
+    the window's keys in ``jax.numpy`` (W x G dot products a head, at
+    full precision); the window's k, v and ``G`` (``Window``, float32,
+    10.5 MB at Brumby's cut) ride the step scan's carry and never
+    leave the executable; ``fold_window`` after the scan is the kernel
+    ``retention_window_fold``, a grid step a (layer, row, head): ``S``
+    copied in, decayed, the W outer products added on the VPU, copied
+    back to the SAME page. At every executable boundary a page holds
+    ``S`` and ``z`` of the recurrent rule after the row's last token.
+``retention_recurrent``  else up to DECODE_T_MAX positions a row (a
+    decode window of 1 or 2 steps, a short forward): the rule as
+    written, and what the tests hold the window form to. On the TPU
+    the kernel ``retention_recurrent_step`` in the form that writes, a
+    grid step a (row, key-value head):
     the head's 4.2 MB of ``S`` are copied in, decayed, updated and
     multiplied by the group's queries on the VPU, eight values of ``v``
     against the 128 lanes of ``i`` a register (so that a monomial row
@@ -56,7 +82,7 @@ are not real advance nothing: the caller hands them ``log g = 0`` and
 
 Where the kernels are off (``pallas_paged.flash_enabled``: the CPU) or
 the shapes are not theirs (heads of 128, key-value heads in eights) the
-same two forms run in ``jax.numpy`` under names that end in ``_jnp``;
+same three forms run in ``jax.numpy`` under names that end in ``_jnp``;
 tests/test_retention.py holds each to the attention form of
 chipbench/references/brumby.py and, in interpret mode, the kernels to
 the ``jax.numpy`` forms.
@@ -64,6 +90,7 @@ the ``jax.numpy`` forms.
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -81,7 +108,16 @@ EPS = 1e-6
 
 RECURRENT = "retention_recurrent"
 CHUNKED = "retention_chunk"
+WINDOWED = "retention_window"
 _SQRT2 = math.sqrt(2.0)
+# registers of a head's tile (8 values of v against the lanes of i) that
+# the window form's two kernels take through one trip of their loops,
+# under one load of the monomial rows: independent chains for the
+# vector unit. On the v5e, 16 rows of a layer (PERF.md, PR 48): the
+# step that reads 1.78 / 1.05 / 0.86 / 0.86 ms at 1 / 2 / 4 / 8
+# registers, the fold 2.43 / 1.77 / 1.71 ms at 1 / 2 / 4
+READ_SUBS = 4
+FOLD_SUBS = 4
 
 
 def features(head_dim: int) -> int:
@@ -95,11 +131,32 @@ def kernels_fit(head_dim: int, kv_heads: int) -> bool:
     return head_dim == 128 and kv_heads % 8 == 0
 
 
-def retention_path(T: int, head_dim: int = 128, kv_heads: int = 8) -> str:
-    """Which implementation a forward of T positions a row runs:
-    decided by shape, before anything compiles (a kernel the compiler
-    then refuses is an error, not a reason to take the other)."""
-    path = RECURRENT if T <= DECODE_T_MAX else CHUNKED
+def pages_moved(steps: int, windowed: bool) -> int:
+    """State pages a row's decode window of ``steps`` steps moves
+    between HBM and the kernels, a layer: the recurrent form reads and
+    writes the page every step; the window form reads it every step and
+    its fold reads and writes it once."""
+    return steps + 2 if windowed else 2 * steps
+
+
+def windowed(T: int, steps: int) -> bool:
+    """Does a decode window of ``steps`` steps of T positions a row
+    take the window form: where that moves at most three quarters of
+    the recurrent form's bytes (4 steps: 6 pages for 8; at 2 the two
+    are level, and the fold is one more call)."""
+    return T == 1 and 4 * pages_moved(steps, True) \
+        <= 3 * pages_moved(steps, False)
+
+
+def retention_path(T: int, head_dim: int = 128, kv_heads: int = 8,
+                   steps: int = 1) -> str:
+    """Which implementation a forward of T positions a row runs, in an
+    executable that fuses ``steps`` of them (a decode window; 1: a
+    prefill chunk, a forward alone): decided by shape, before anything
+    compiles (a kernel the compiler then refuses is an error, not a
+    reason to take the other)."""
+    path = (WINDOWED if windowed(T, steps)
+            else RECURRENT if T <= DECODE_T_MAX else CHUNKED)
     on = pallas_paged.flash_enabled() and kernels_fit(head_dim, kv_heads)
     return path if on else path + "_jnp"
 
@@ -386,6 +443,358 @@ def _recurrent(q, k, v, logg, state, norm, ids, layer, fresh):
       jnp.broadcast_to(jnp.exp(logg)[..., None], k.shape), state, norm)
     den = jnp.sum(den, axis=-1).transpose(0, 1, 3, 2)        # [B,T,H,G]
     return num[:, :, :, :G] / (den[..., None] + EPS), state, norm
+
+
+# ---------------------------------------------------------------------
+# the window form
+# ---------------------------------------------------------------------
+
+class Window(NamedTuple):
+    """What a decode window of W steps keeps beside the pages, from its
+    first step to its fold: it is made inside the executable and gone
+    at its end, so that a page holds ``S`` and ``z`` alone wherever the
+    host can see one."""
+    k: jnp.ndarray      # [L, B, W, Hkv, D] float32; 0: not real, not yet
+    v: jnp.ndarray      # [L, B, W, Hkv, D] float32
+    G: jnp.ndarray      # [L, B, W, Hkv]: log-gates summed from step 0
+    ids: jnp.ndarray    # [B] the rows' pages, as of the first step
+    keep: jnp.ndarray   # [B] float32: 0.0 where the row started at 0
+    step: jnp.ndarray   # int32: steps taken
+
+
+def open_window(layers: int, steps: int, kv_heads: int, head_dim: int,
+                ids: jnp.ndarray, fresh: jnp.ndarray) -> Window:
+    """ids [B]: the rows' pages (the trash page for a row that is not
+    real at the window's first step); fresh [B] bool: the row's first
+    position is 0 (positions advance by one a step, so no later one
+    is), and it starts from a zero state."""
+    B = ids.shape[0]
+    kv = jnp.zeros((layers, B, steps, kv_heads, head_dim), jnp.float32)
+    return Window(kv, kv, jnp.zeros(kv.shape[:-1], jnp.float32),
+                  ids.astype(jnp.int32), jnp.where(fresh, 0.0, 1.0),
+                  jnp.int32(0))
+
+
+def _read_jnp(q, state, norm, ids, layer):
+    S, z = unpack_state(state[layer, ids], norm[layer, ids])
+    pq = phi_rows(q)                                     # [B,Hkv,G,d,i]
+    return (jnp.einsum("bhgdi,bhdvi->bhgv", pq, S),
+            jnp.einsum("bhgdi,bhdi->bhg", pq, z))
+
+
+def _read_kernel(ids_ref, layer_ref, q_ref, s_ref, z_ref, num_ref,
+                 den_ref, pq_ref, acc_ref, *, G: int, H: int, D: int):
+    """One (row, key-value head) of a step that only READS: the head's
+    ``S`` against the group's queries' monomial rows, and at the row's
+    first head every head's ``z`` against them. Nothing decays, nothing
+    is added, no tile is stored.
+
+    q_ref [1, G, H, D]: a register holds one query of every head (as
+    ``_recurrent_kernel``); pq_ref [G, (D/2 + 1) H, D] the monomial
+    rows, row ``d H + j`` head j's; acc_ref [G, D, D] a query's
+    products before the lanes are summed."""
+    j = pl.program_id(1)
+    h = D // 2
+    lo = _tail_mask((1, D), D)
+
+    @pl.when(j == 0)
+    def _rows_and_norm():
+        row = jax.lax.broadcasted_iota(jnp.int32, (H, D), 0)
+        Q = [q_ref[0, r] for r in range(G)]
+
+        def distance(d, den):
+            w = jnp.where(d == 0, 1.0, _SQRT2)
+            at = pl.ds(pl.multiple_of(d * H, H), H)
+            zs = z_ref[0, 0, at, :]
+            out = []
+            for r in range(G):
+                pq = Q[r] * pltpu.roll(Q[r], d, axis=1) * w
+                pq_ref[r, at, :] = pq
+                out.append(den[r] + pq * zs)
+            return tuple(out)
+        den = list(jax.lax.fori_loop(
+            0, h, distance,
+            tuple(jnp.zeros((H, D), jnp.float32) for _ in range(G))))
+        # the half row: heads s and s + H/2 share a stored row
+        pqs = [Q[r] * pltpu.roll(Q[r], h, axis=1) * (_SQRT2 * lo)
+               for r in range(G)]
+        for r in range(G):
+            pq_ref[r, h * H:, :] = pqs[r]
+        for s in range(H // 2):
+            stored = z_ref[0, 0, h * H + s:h * H + s + 1, :]
+            for head, zs in ((s, stored * lo),
+                             (s + H // 2,
+                              pltpu.roll(stored * (1.0 - lo), h, axis=1))):
+                for r in range(G):
+                    den[r] = den[r] + jnp.where(
+                        row == head, pqs[r][head:head + 1] * zs, 0.0)
+        for r in range(G):
+            den_ref[0, r] = den[r]
+
+    # READ_SUBS registers of the head's tile a load of the G monomial
+    # rows (which broadcast along the sublanes in their loads)
+    group = 8 * READ_SUBS
+    for sg in range(D // group):
+        def distance(d, flat):
+            rows = [pq_ref[r, pl.ds(d * H + j, 1), :] for r in range(G)]
+            flat = list(flat)
+            for s in range(READ_SUBS):
+                here = pl.ds(
+                    pl.multiple_of(d * D + sg * group + s * 8, 8), 8)
+                tile = s_ref[0, 0, 0, here, :]
+                for r in range(G):
+                    flat[r * READ_SUBS + s] += tile * rows[r]
+            return tuple(flat)
+        flat = jax.lax.fori_loop(
+            0, h, distance,
+            tuple(jnp.zeros((8, D), jnp.float32)
+                  for _ in range(G * READ_SUBS)))
+        for r in range(G):
+            for s in range(READ_SUBS):
+                at_v = sg * group + s * 8
+                acc_ref[r, at_v:at_v + 8, :] = flat[r * READ_SUBS + s]
+    # the half tile: value v in the lanes below D/2, v + D/2 above
+    tile = _whole_tile(s_ref[0, 0, 0, h * D:, :])
+    # the lanes summed, a query's values back along the lanes: ones
+    # against the accumulator's transpose, on the MXU at full precision
+    ones = jnp.ones((8, D), jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (8, D), 0)
+    out = jnp.zeros((8, D), jnp.float32)
+    for r in range(G):
+        half = tile * pq_ref[r, pl.ds(h * H + j, 1), :]
+        y = jax.lax.dot_general(
+            ones, acc_ref[r] + half, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)              # [8, D (v)]
+        out = jnp.where(row == r, y, out)
+    num_ref[0, 0] = out
+
+
+def _read(q, state, norm, ids, layer):
+    """q [B, Hkv, G, D] float32 against the rows' pages as they stand:
+    -> (phi(q)^T S [B, Hkv, G, D], phi(q)^T z [B, Hkv, G])."""
+    B, H, G, D = q.shape
+    if retention_path(1, D, H).endswith("_jnp"):
+        return _read_jnp(q, state, norm, ids, layer)
+    if G > 8:
+        raise ValueError(f"retention_recurrent_step serves at most 8 "
+                         f"query heads a key-value head, not {G}")
+    F, rows = state.shape[-2], (D // 2 + 1) * H
+    den_spec = pl.BlockSpec((1, G, H, D), lambda b, j, ids, lyr: (b, 0, 0, 0))
+    num, den = pl.pallas_call(
+        functools.partial(_read_kernel, G=G, H=H, D=D),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, H),
+            in_specs=[den_spec,
+                      pl.BlockSpec((1, 1, 1, F, D),
+                                   lambda b, j, ids, lyr:
+                                   (lyr[0], ids[b], j, 0, 0)),
+                      pl.BlockSpec((1, 1, norm.shape[-2], D),
+                                   lambda b, j, ids, lyr:
+                                   (lyr[0], ids[b], 0, 0))],
+            out_specs=[pl.BlockSpec((1, 1, 8, D),
+                                    lambda b, j, ids, lyr: (b, j, 0, 0)),
+                       den_spec],
+            scratch_shapes=[pltpu.VMEM((G, rows, D), jnp.float32),
+                            pltpu.VMEM((G, D, D), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, 8, D), jnp.float32),
+                   jax.ShapeDtypeStruct((B, G, H, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
+        interpret=pallas_paged.needs_interpret(),
+        # the name a decode executable is told by, one call a layer and
+        # step (chipbench: the configuration's harness.decode_step)
+        name="retention_recurrent_step",
+    )(ids.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q.transpose(0, 2, 1, 3), state, norm)
+    return num[:, :, :G], jnp.sum(den, axis=-1).transpose(0, 2, 1)
+
+
+def retain_in_window(q, k, v, logg, keys, values, gates, window: Window,
+                     state, norm, layer):
+    """One step of a decode window, the pages only READ: q [B, 1, H, D]
+    (scaled), k (ZERO where the position is not real), v [B, 1, Hkv, D],
+    logg [B, 1, Hkv] float32 (0 where it is not real); keys, values [B,
+    W, Hkv, D] and gates [B, W, Hkv]: this layer's slices of ``window``
+    as the steps before left them. With ``S0``, ``z0`` the page and
+    ``G_t`` the log-gates summed from the window's first step to this
+    one,
+
+        num = exp(G_t) phi(q)^T S0 + sum_{j<=t} exp(G_t - G_j) (q . k_j)^2 v_j
+        den = exp(G_t) phi(q)^T z0 + sum_{j<=t} exp(G_t - G_j) (q . k_j)^2
+
+    which is the recurrent rule's quotient, re-associated; everything
+    float32, the dot products at full precision. -> (y [B, 1, H, D]
+    float32, the three slices with this step's k, v and G_t in)."""
+    B, _, H, D = q.shape
+    Hkv, W = k.shape[2], keys.shape[1]
+    f32, exact = jnp.float32, jax.lax.Precision.HIGHEST
+    step = window.step
+    q = q[:, 0].reshape(B, Hkv, H // Hkv, D).astype(f32)
+    before = jax.lax.dynamic_index_in_dim(
+        gates, jnp.maximum(step - 1, 0), axis=1, keepdims=False)
+    Gt = jnp.where(step > 0, before, 0.0) + logg[:, 0]       # [B, Hkv]
+    keys, values, gates = (
+        jax.lax.dynamic_update_slice_in_dim(a, x.astype(f32), step, axis=1)
+        for a, x in ((keys, k), (values, v), (gates, Gt[:, None])))
+    # exp of differences only, and only where they are <= 0
+    taken = (jnp.arange(W) <= step)[None, :, None]
+    decay = jnp.where(taken, jnp.exp(jnp.where(
+        taken, Gt[:, None] - gates, 0.0)), 0.0)              # [B, W, Hkv]
+    scores = jnp.einsum("bhgd,bwhd->bhgw", q, keys, precision=exact)
+    a = scores * scores * decay.transpose(0, 2, 1)[:, :, None, :]
+    num = jnp.einsum("bhgw,bwhd->bhgd", a, values, precision=exact)
+    den = jnp.sum(a, axis=-1)
+    num0, den0 = _read(q, state, norm, window.ids, layer)
+    base = window.keep[:, None] * jnp.exp(Gt)                # [B, Hkv]
+    y = ((base[..., None, None] * num0 + num)
+         / ((base[..., None] * den0 + den)[..., None] + EPS))
+    return y.reshape(B, 1, H, D), keys, values, gates
+
+
+def _fold_operands(window: Window):
+    """-> (ku [L, B, W, Hkv, D]: k_j times exp((G_W - G_j) / 2), the
+    monomials are quadratic, so they carry exp(G_W - G_j); decay [L, B,
+    Hkv]: keep exp(G_W), what is left of the page's own state)."""
+    last = window.G[:, :, -1]
+    ku = window.k * jnp.exp(0.5 * (last[:, :, None] - window.G))[..., None]
+    return ku, window.keep[None, :, None] * jnp.exp(last)
+
+
+def _fold_jnp(ku, v, decay, state, norm, ids):
+    L, B, W, H, D = ku.shape
+    S, z = unpack_state(state[:, ids].reshape((L * B,) + state.shape[2:]),
+                        norm[:, ids].reshape((L * B,) + norm.shape[2:]))
+    pk = phi_rows(ku.reshape(L * B, W, H, D))            # [n,W,Hkv,d,i]
+    decay = decay.reshape(L * B, H)
+    S = (S * decay[..., None, None, None]
+         + jnp.einsum("nwhv,nwhdi->nhdvi", v.reshape(L * B, W, H, D), pk))
+    z = z * decay[..., None, None] + jnp.sum(pk, axis=1)
+    S, z = pack_state(S, z)
+    return (state.at[:, ids].set(S.reshape((L, B) + S.shape[1:])),
+            norm.at[:, ids].set(z.reshape((L, B) + z.shape[1:])))
+
+
+def _fold_kernel(ids_ref, ku_ref, v_ref, dec_ref, s_ref, z_ref, so_ref,
+                 zo_ref, pk_ref, *, W: int, H: int, D: int):
+    """One (layer, row, key-value head): the head's ``S`` decayed by
+    the window's gates and the window's W outer products added, copied
+    back to the SAME page; at the row's first head every head's ``z``
+    alike.
+
+    ku_ref [1, 1, W, H, D] and dec_ref [1, 1, H, D] (the decay, along
+    the lanes): a register holds one vector of every head; v_ref [1, 1,
+    1, W, D] the head's values, a step a row; pk_ref [W, (D/2 + 1) H,
+    D] the keys' monomial rows, row ``d H + j`` head j's."""
+    j = pl.program_id(2)
+    h = D // 2
+    lo = _tail_mask((1, D), D)
+
+    @pl.when(j == 0)
+    def _rows_and_norm():
+        a = dec_ref[0, 0]
+        K = [ku_ref[0, 0, t] for t in range(W)]
+
+        def distance(d, _):
+            w = jnp.where(d == 0, 1.0, _SQRT2)
+            at = pl.ds(pl.multiple_of(d * H, H), H)
+            zs = a * z_ref[0, 0, at, :]
+            for t in range(W):
+                pk = K[t] * pltpu.roll(K[t], d, axis=1) * w
+                pk_ref[t, at, :] = pk
+                zs = zs + pk
+            zo_ref[0, 0, at, :] = zs
+            return 0
+        jax.lax.fori_loop(0, h, distance, 0)
+        # the half row: heads s and s + H/2 share a stored row
+        total = jnp.zeros((H, D), jnp.float32)
+        for t in range(W):
+            pk = K[t] * pltpu.roll(K[t], h, axis=1) * (_SQRT2 * lo)
+            pk_ref[t, h * H:, :] = pk
+            total = total + pk
+        for s in range(H // 2):
+            u = s + H // 2
+            stored = z_ref[0, 0, h * H + s:h * H + s + 1, :]
+            lower = a[s:s + 1] * stored + total[s:s + 1]
+            upper = (a[u:u + 1] * stored
+                     + pltpu.roll(total[u:u + 1], h, axis=1))
+            zo_ref[0, 0, h * H + s:h * H + s + 1, :] = (
+                lower * lo + upper * (1.0 - lo))
+
+    g = dec_ref[0, 0, pl.ds(j, 1), :]                        # [1, D]
+    # a step's values down the sublanes, the same in every lane: its
+    # row broadcast along the sublanes, transposed (as an operand [D,
+    # 1] a value would take a whole tile of HBM)
+    vs = [jnp.broadcast_to(v_ref[0, 0, 0, t:t + 1, :], (D, D)).T
+          for t in range(W)]
+    group = 8 * FOLD_SUBS
+    for sg in range(D // group):
+        mine = [[vs[t][sg * group + s * 8:sg * group + s * 8 + 8]
+                 for s in range(FOLD_SUBS)] for t in range(W)]
+
+        def distance(d, _):
+            rows = [pk_ref[t, pl.ds(d * H + j, 1), :] for t in range(W)]
+            for s in range(FOLD_SUBS):
+                here = pl.ds(
+                    pl.multiple_of(d * D + sg * group + s * 8, 8), 8)
+                tile = s_ref[0, 0, 0, here, :] * g
+                for t in range(W):
+                    tile = tile + mine[t][s] * rows[t]
+                so_ref[0, 0, 0, here, :] = tile
+            return 0
+        jax.lax.fori_loop(0, h, distance, 0)
+    # the half tile: value v in the lanes below D/2, v + D/2 above
+    tile = _whole_tile(s_ref[0, 0, 0, h * D:, :]) * g
+    for t in range(W):
+        tile = tile + vs[t] * pk_ref[t, pl.ds(h * H + j, 1), :]
+    so_ref[0, 0, 0, h * D:, :] = _half_tile(tile)
+
+
+def fold_window(window: Window, state: jnp.ndarray, norm: jnp.ndarray):
+    """The window's end: every layer's pages of the window's rows take
+    the rank-W update, ``S = keep exp(G_W) S0 + sum_j exp(G_W - G_j)
+    phi(k_j) v_j^T`` and ``z`` alike, in place: what W steps of the
+    recurrent rule would have left there. -> the two pools."""
+    L, B, W, H, D = window.k.shape
+    with jax.named_scope("ret_fold"):
+        ku, decay = _fold_operands(window)
+        if retention_path(1, D, H).endswith("_jnp"):
+            return _fold_jnp(ku, window.v, decay, state, norm, window.ids)
+        F = state.shape[-2]
+        page = pl.BlockSpec((1, 1, 1, F, D),
+                            lambda l, b, j, ids: (l, ids[b], j, 0, 0))
+        zpage = pl.BlockSpec((1, 1, norm.shape[-2], D),
+                             lambda l, b, j, ids: (l, ids[b], 0, 0))
+        return pl.pallas_call(
+            functools.partial(_fold_kernel, W=W, H=H, D=D),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(L, B, H),
+                in_specs=[pl.BlockSpec((1, 1, W, H, D),
+                                       lambda l, b, j, ids: (l, b, 0, 0, 0)),
+                          pl.BlockSpec((1, 1, 1, W, D),
+                                       lambda l, b, j, ids:
+                                       (l, b, j, 0, 0)),
+                          pl.BlockSpec((1, 1, H, D),
+                                       lambda l, b, j, ids: (l, b, 0, 0)),
+                          page, zpage],
+                out_specs=[page, zpage],
+                scratch_shapes=[
+                    pltpu.VMEM((W, (D // 2 + 1) * H, D), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                       jax.ShapeDtypeStruct(norm.shape, norm.dtype)],
+            # operands count the scalar-prefetch argument: the pools are
+            # the fifth and sixth
+            input_output_aliases={4: 0, 5: 1},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary",
+                                     "arbitrary"),
+                vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
+            interpret=pallas_paged.needs_interpret(),
+            name="retention_window_fold",
+        )(window.ids, ku, window.v.transpose(0, 1, 3, 2, 4),
+          jnp.broadcast_to(decay[..., None], decay.shape + (D,)), state,
+          norm)
 
 
 # ---------------------------------------------------------------------

@@ -58,6 +58,32 @@ if "PYTEST_XDIST_WORKER" not in os.environ:
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+def _write_cache_entries_whole():
+    """JAX writes an entry of the persistent cache in place
+    (``LRUCache.put``: ``write_bytes``) and ``get`` reads whatever is
+    there, so a worker that looks a key up while another writes it
+    deserialises half an executable: a segmentation fault in
+    ``compilation_cache.get_executable_and_time`` took a worker down in
+    a whole run (PR 48; six workers build the same engines at the same
+    time). Written beside and renamed, an entry is there whole or not at
+    all. (A process a test starts still writes in place.)"""
+    from jax._src import lru_cache
+    in_place = lru_cache.LRUCache.put
+
+    def put(self, key, val):
+        if not key or self.eviction_enabled:
+            return in_place(self, key, val)
+        path = self.path / f"{key}{lru_cache._CACHE_SUFFIX}"
+        if not path.exists():
+            beside = self.path / f"{key}.{os.getpid()}.tmp"
+            beside.write_bytes(val)
+            os.replace(beside, path)
+    lru_cache.LRUCache.put = put
+
+
+_write_cache_entries_whole()
+
+
 def pytest_unconfigure(config):
     if _RUN_DIR is not None:
         shutil.rmtree(_RUN_DIR, ignore_errors=True)

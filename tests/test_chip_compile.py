@@ -1223,13 +1223,61 @@ def test_retention_kernels_compile_at_brumby_widths(topo, tpu_branches,
                           r"(?:fusion|multiply|broadcast)\(", hlo)
 
 
+@pytest.mark.parametrize("W", [4, 8])
+def test_the_window_form_compiles_at_brumby_widths(topo, tpu_branches, W):
+    """A decode window's form at the cell's shapes (16 rows, windows of
+    4 and 8 steps, the pool of 17 pages and 10 layers), compiled for
+    the described v5e: a step of one layer that only reads (under the
+    recurrent step's name, no pool among its results) and the fold of
+    every layer, the pools aliased to the result; nothing of the
+    monomials' size is made in HBM, and the window's operands take
+    what they hold (as ``[..., 1]`` a value took a tile: 671 MB)."""
+    import re
+    from production_stack_tpu.ops import retention
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    L, P, H, G, D, B = 10, 17, 8, 5, 128, 16
+    F = retention.features(D)
+    assert retention.retention_path(1, steps=W) == "retention_window"
+
+    def window(q, k, v, g, state, norm, ids, fresh):
+        win = retention.open_window(L, W, H, D, ids, fresh)
+        y, *taken = retention.retain_in_window(
+            q, k, v, g, win.k[3], win.v[3], win.G[3], win, state, norm, 3)
+        win = win._replace(
+            **{n: getattr(win, n).at[3].set(a) for n, a in zip("kvG", taken)},
+            step=win.step + 1)
+        return y, retention.fold_window(win, state, norm)
+    compiled = jax.jit(window, donate_argnums=(4, 5)).lower(
+        s((B, 1, H * G, D), jnp.bfloat16), s((B, 1, H, D), jnp.bfloat16),
+        s((B, 1, H, D), jnp.bfloat16), s((B, 1, H)),
+        s((L, P, H, F, D)), s((L, P, F * H // D, D)),
+        s((B,), jnp.int32), s((B,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    calls = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]+)[\w.\-]* = [^=]*? custom-call\(", hlo)}
+    assert {c for c in calls if c.startswith("retention")} \
+        == {"retention_recurrent_step", "retention_window_fold"}
+    pools = L * P * H * F * (D + 1) * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26
+    assert not re.findall(r"\[(?:\d+,)*(?:8256|8320)(?:,\d+)*\]\S* "
+                          r"(?:fusion|multiply|broadcast|copy)\(", hlo)
+
+
+@pytest.mark.parametrize("W", [8, 2])
 def test_brumby_decode_window_compiles_with_state_pages_alone(
-        topo, tpu_branches, monkeypatch):
+        topo, tpu_branches, monkeypatch, W):
     """One decode window of 16 rows at the benchmark's Brumby file cut
     to two layers, compiled whole for the described v5e as the runner
     lays such a model out: ONE table column (the page), one kv bucket,
     no K or V array among the program's arguments, the step kernel
-    once a layer and step, the state pools aliased to the result."""
+    once a layer and step, the state pools aliased to the result and
+    never copied. A window of 8 steps takes the window form (the steps
+    read, ``retention_window_fold`` writes, once, after the scan); one
+    of 2 the recurrent rule a step, as before."""
     import dataclasses
     import json
     import re
@@ -1268,7 +1316,7 @@ def test_brumby_decode_window_compiles_with_state_pages_alone(
     cache = placed(jax.eval_shape(partial(cache_for, mcfg, 17, 32768)))
     assert cache.k is None and cache.v is None
     a = _step_args(runner, rep, 16)
-    fn = jax.jit(partial(runner._decode_impl, steps=8, kv_len=32768,
+    fn = jax.jit(partial(runner._decode_impl, steps=W, kv_len=32768,
                          greedy=True), donate_argnums=(1,))
     compiled = fn.lower(
         params, cache, rep((16, 1), jnp.int32), rep((16,), jnp.int32),
@@ -1279,8 +1327,15 @@ def test_brumby_decode_window_compiles_with_state_pages_alone(
     calls = {m.group(1) for m in re.finditer(
         r"%([A-Za-z_]+)[\w.\-]* = [^=]*? custom-call\(", hlo)}
     assert {c for c in calls if c.startswith(("paged", "retention"))} \
-        == {"retention_recurrent_step"}
+        == ({"retention_recurrent_step", "retention_window_fold"}
+            if W == 8 else {"retention_recurrent_step"})
+    assert runner._mixer_path(1, W) == (
+        "retention_window" if W == 8 else "retention_recurrent")
     assert runner._mixer_path(1) == "retention_recurrent"
+    assert runner.state_pages_moved(W) == (10 if W == 8 else 4)
     assert (compiled.memory_analysis().alias_size_in_bytes
             >= 2 * 17 * 8 * 8256 * 129 * 4)
+    # the pool goes from the argument to the result through the scan of
+    # read-only steps and the fold: no copy of its shape anywhere
+    assert not re.findall(r"f32\[2,17,8,8256,128\]\S* copy\(", hlo)
     _fits(compiled, "brumby cut decode window")

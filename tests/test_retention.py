@@ -8,7 +8,8 @@ with seeded weights.
   chipbench/references/brumby.py (``power_attention``), at lengths that
   are not a multiple of the chunk, with padded tails and a carried
   state; the chunked rule against the recurrent one across a chunk
-  boundary;
+  boundary; a decode window's form (the pages read a step, written once
+  by the fold) against the recurrent rule a position at a time;
 - the model through its pages (prefill in several chunks with a padded
   last one, then decode steps beside a parked row) against the
   reference's full forward pass, float32, to 1e-4 on the
@@ -153,6 +154,110 @@ def test_a_padded_tail_advances_nothing(T, real):
     assert worst(s_pad, s_real) < 1e-5 and worst(n_pad, n_real) < 1e-5
 
 
+def _window_inputs(W, windows, Hkv, G, D, seed=5):
+    """Three rows through ``windows`` windows of W steps, one layer's
+    pool of five pages: row 0 from a page that 6 tokens built, with a
+    position that is not real in mid-window; row 1 parked (nothing
+    real: the trash page); row 2 ``fresh`` (its first position is 0)
+    over a page that holds something. Gates drawn as the
+    configuration's: ``b_g`` from U(2, 8), heads that forget in eight
+    tokens and in three thousand."""
+    T = W * windows
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    q = jax.random.normal(ks[0], (3, T, Hkv * G, D)) * D ** -0.5
+    k = jax.random.normal(ks[1], (3, T, Hkv, D))
+    v = jax.random.normal(ks[2], (3, T, Hkv, D))
+    b_g = jax.random.uniform(ks[3], (Hkv,), minval=2.0, maxval=8.0)
+    logg = jax.nn.log_sigmoid(
+        jax.random.normal(ks[4], (3, T, Hkv)) + b_g)
+    real = jnp.ones((3, T), bool).at[1].set(False).at[0, W // 2].set(False)
+    k = jnp.where(real[..., None, None], k, 0)
+    logg = jnp.where(real[..., None], logg, 0)
+    F = retention.features(D)
+    state = jax.random.normal(ks[5], (1, 5, Hkv, F, D))
+    norm = jax.random.normal(ks[5], (1, 5, F * Hkv // D, D))
+    warm = _inputs(6, Hkv, G, D, seed=7)
+    was = pallas_paged._override
+    pallas_paged.set_flash_enabled(False)
+    _, state, norm = _through(*warm[:4], state, norm, [6], layer=0)
+    pallas_paged.set_flash_enabled(was)
+    return q, k, v, logg, state, norm, jnp.array([2, 0, 4], jnp.int32)
+
+
+def _by_windows(q, k, v, logg, state, norm, ids, W):
+    """Every W positions a window of ops/retention's window form: the
+    steps read the pages, the fold writes them. -> (y [3, T, H, D], the
+    pools after each fold)."""
+    out, pools = [], []
+    for first in range(0, q.shape[1], W):
+        win = retention.open_window(
+            1, W, k.shape[2], k.shape[3], ids,
+            jnp.array([False, False, first == 0]))
+        for t in range(first, first + W):
+            at = slice(t, t + 1)
+            y, *taken = retention.retain_in_window(
+                q[:, at], k[:, at], v[:, at], logg[:, at], win.k[0],
+                win.v[0], win.G[0], win, state, norm, 0)
+            win = win._replace(
+                **{n: a[None] for n, a in zip("kvG", taken)},
+                step=win.step + 1)
+            out.append(y)
+        state, norm = retention.fold_window(win, state, norm)
+        pools.append((state, norm))
+    return jnp.concatenate(out, axis=1), pools
+
+
+def _by_positions(q, k, v, logg, state, norm, ids, W):
+    """The same through ``_recurrent_jnp`` a position at a time."""
+    B, T, Hkv, D = k.shape
+    out, pools = [], []
+    for t in range(T):
+        at = slice(t, t + 1)
+        y, state, norm = retention._recurrent_jnp(
+            q[:, at].reshape(B, 1, Hkv, -1, D), k[:, at], v[:, at],
+            logg[:, at], state, norm, ids, 0,
+            jnp.array([False, False, t == 0]))
+        out.append(y.reshape(B, 1, -1, D))
+        if (t + 1) % W == 0:
+            pools.append((state, norm))
+    return jnp.concatenate(out, axis=1), pools
+
+
+def _same_window(got, want, ids):
+    """Every step's y of the rows that are real, and their pages after
+    each fold, to the kernel tests' tolerances (3e-6 and 3e-7 of the
+    largest value seen: float32 sums in another order)."""
+    (y, pools), (y_want, pools_want) = got, want
+    for b in (0, 2):
+        assert worst(y[b], y_want[b]) \
+            < 1e-4 * float(jnp.max(jnp.abs(y_want[b])))
+        for (s, n), (s_want, n_want) in zip(pools, pools_want):
+            big = float(jnp.max(jnp.abs(s_want[0, ids[b]])))
+            assert worst(s[0, ids[b]], s_want[0, ids[b]]) < 1e-5 * big
+            assert worst(n[0, ids[b]], n_want[0, ids[b]]) < 1e-5 * big
+    # pages nobody named stay as they were
+    assert worst(pools[-1][0][0, (1, 3), ], pools_want[-1][0][0, (1, 3), ]) \
+        == 0
+
+
+@pytest.mark.parametrize("W", [4, 8])
+def test_the_window_form_is_the_recurrent_form(W):
+    """``jax.numpy``, heads of 16, two windows in a row: the steps of a
+    window answer from the page as the window found it plus the
+    window's own keys, and its fold leaves what W steps of the
+    recurrent rule leave. The first token of the fresh row is a
+    one-term quotient, which the window form computes as ``(q . k)^2``
+    itself."""
+    *x, ids = _window_inputs(W, 2, 2, 2, 16)
+    got, want = _by_windows(*x, ids, W), _by_positions(*x, ids, W)
+    _same_window(got, want, ids)
+    q, k, v = (a[2, 0] for a in x[:3])
+    qk2 = jnp.einsum("hgd,hd->hg", q.reshape(2, 2, 16), k) ** 2
+    first = (qk2 / (qk2 + retention.EPS))[..., None] * v[:, None]
+    assert worst(got[0][2, 0], first.reshape(4, 16)) \
+        < 1e-6 * float(jnp.max(jnp.abs(first)))
+
+
 def test_the_layout_packs_exactly_the_monomials():
     """``phi(a) . phi(b) = (a . b)^2``; a page unpacks and packs to
     itself; a head keeps F (D + 1) numbers and not one more."""
@@ -176,17 +281,27 @@ def test_the_layout_packs_exactly_the_monomials():
     assert cache.norm.shape == (10, 2, 516, 128)
 
 
-@pytest.mark.parametrize("splits", [[3, 1, 2], [130, 1, 9]],
-                         ids=["steps", "chunks-and-a-step"])
+@pytest.mark.parametrize("splits", [[3, 1, 2], [130, 1, 9], "window"],
+                         ids=["steps", "chunks-and-a-step", "window"])
 def test_the_kernels_are_the_jnp_forms(splits, kernels):
     """Interpret mode, at the kernels' own shapes (heads of 128, 8
     key-value heads, two queries a group): ``retention_recurrent_step``
     at 1, 2 and 3 positions a row from a carried page, and
     ``retention_chunk_scan`` over two chunks with a padded tail and a
-    nine-position call. About 15 s each: the interpreter walks 65 tiles
-    of 128 x 128 a head."""
+    nine-position call; the window form's two (the step that only
+    reads, under the recurrent step's name, and
+    ``retention_window_fold``) over a window of 4 steps and three rows.
+    About 15 s each: the interpreter walks 65 tiles of 128 x 128 a
+    head."""
     assert retention.retention_path(1) == "retention_recurrent"
     assert retention.retention_path(9) == "retention_chunk"
+    assert retention.retention_path(1, steps=4) == "retention_window"
+    if splits == "window":
+        *x, ids = _window_inputs(4, 1, 8, 2, 128)
+        got = _by_windows(*x, ids, 4)
+        pallas_paged.set_flash_enabled(False)
+        _same_window(got, _by_windows(*x, ids, 4), ids)
+        return
     T = sum(splits)
     q, k, v, logg, state, norm = _inputs(T, 8, 2, 128, seed=1)
     # a page that is NOT fresh: the kernels start from what it holds
@@ -221,8 +336,20 @@ def test_the_rule_by_shape_names_what_runs():
     assert retention.retention_path(1) == "retention_recurrent_jnp"
     assert retention.retention_path(8) == "retention_recurrent_jnp"
     assert retention.retention_path(9) == "retention_chunk_jnp"
+    # a decode window of 4 steps and more reads the pages a step and
+    # writes them once; shorter ones, and any T > 1, as before
+    assert [retention.retention_path(1, steps=w) for w in (1, 2, 3, 4, 8)] \
+        == ["retention_recurrent_jnp"] * 3 + ["retention_window_jnp"] * 2
+    assert retention.retention_path(2, steps=8) == "retention_recurrent_jnp"
+    assert retention.retention_path(9, steps=8) == "retention_chunk_jnp"
+    assert [retention.pages_moved(w, retention.windowed(1, w))
+            for w in (1, 2, 4, 8)] == [2, 4, 6, 10]
     pallas_paged.set_flash_enabled(True)
     try:
+        assert retention.retention_path(1, steps=8) == "retention_window"
+        assert retention.retention_path(1, steps=2) == "retention_recurrent"
+        assert retention.retention_path(1, 16, 2, steps=8) \
+            == "retention_window_jnp"
         assert retention.retention_path(256) == "retention_chunk"
         assert retention.retention_path(1, 16, 2) \
             == "retention_recurrent_jnp"
@@ -378,20 +505,52 @@ def test_turnover_and_page_reuse_leave_every_request_as_alone(alone):
     assert state["pages_alloc"] == state["pages_freed"] == 6
     assert state["scan_tokens"] == sum(map(len, PROMPTS))
     assert state["step_rows"] > 0 and state["steps"] > 0
-    # a step moves the page of every row of its batch bucket, in and out
+    # a window moves pages of every row of its batch bucket: in and out
+    # a step, or in a step and its fold's in and out
     assert state["step_bytes"] % (2 * CFG.state_bytes_per_seq) == 0
-    assert (2 * CFG.state_bytes_per_seq
+    assert (CFG.state_bytes_per_seq
             <= state["step_bytes"] / state["steps"]
             <= 2 * 4 * CFG.state_bytes_per_seq)
     assert state["scan_bytes"] > 0
     device = eng.device_report()
     assert device["attention_paths"] == {}
     paths = device["mixer_paths"]
-    assert {v for k, v in paths.items() if k.startswith("decode")} \
-        == {"retention_recurrent_jnp"}
+    decode = {int(k.split("|")[1]): v for k, v in paths.items()
+              if k.startswith("decode")}
+    assert 8 in decode and decode == {
+        w: "retention_window_jnp" if w >= 4 else "retention_recurrent_jnp"
+        for w in decode}
     assert {v for k, v in paths.items() if k.startswith("prefill")} \
         == {"retention_chunk_jnp"}
     assert {k.split("|")[2] for k in paths} == {"256"}   # ONE kv bucket
+
+
+def test_a_window_of_eight_serves_what_a_window_of_one_serves():
+    """Float32, six requests through four slots and four pages (two
+    take a freed page): with ``decode_window`` 8 the decode steps run
+    the window form (W = 4 and 8), with 1 the recurrent rule a step;
+    the same greedy ids and, to 1e-5, the same log-probabilities. The
+    counter of state bytes says which form ran: a window moves ``W +
+    2`` pages a row where it is the window form, ``2 W`` where not.
+    (Two engines' warm-ups: about 20 s.)"""
+    seen = {}
+    for window in (8, 1):
+        eng = _engine(decode_window=window)
+        ids = [eng.add_request(p, GREEDY) for p in PROMPTS]
+        _run(eng)
+        seen[window] = [(list(eng.seqs[s].output_tokens),
+                         list(eng.seqs[s].output_logprobs)) for s in ids]
+        windows = eng.eff.recent_windows(1000)
+        steps = {e["steps"] for e in windows}
+        assert steps == {1} if window == 1 else 8 in steps
+        pages = sum((e["steps"] + 2 if e["steps"] >= 4 else 2 * e["steps"])
+                    * e["batch"] for e in windows)
+        state = eng.eff.report()["state"]
+        assert state["step_bytes"] == pages * CFG.state_bytes_per_seq
+        assert state["pages_alloc"] == state["pages_freed"] == 6
+    for (ids8, lps8), (ids1, lps1) in zip(seen[8], seen[1]):
+        assert ids8 == ids1 and len(ids8) == 12
+        assert np.allclose(lps8, lps1, atol=1e-5)
 
 
 def test_a_slot_move_a_preemption_and_an_abort_change_nothing(alone):

@@ -13,8 +13,11 @@ state that thousands of tokens built. This script compares, for
 of ``--chunks`` tokens (2048 and 512 by turns of the rows: the chunked
 rule carrying ``S`` and ``z`` across 4-31 chunk boundaries and a padded
 last chunk), then ``--decode-steps`` teacher-forced decode steps of all
-rows in one batch of 16 beside parked rows (the recurrent kernel, in
-place on the same pages), against the reference's ONE full forward pass
+rows in one batch of 16 beside parked rows, in windows of 8 as the
+engine fuses them (the window form: the step kernel only reads the
+pages, the window's own keys answer beside them, and
+``retention_window_fold`` writes the same pages once between windows),
+against the reference's ONE full forward pass
 over each row's whole sequence (the attention form: a ``[T, T]`` matrix
 a head in blocks of 1024 queries, no state at all):
 
@@ -27,17 +30,18 @@ a head in blocks of 1024 queries, no state at all):
 ``lean``: the first row served once more
 into a spare page with the STATE KEPT IN BFLOAT16 (``S`` and ``z``
 rounded after every chunk of ``--lean-chunk`` 256 tokens and every
-decode step: what a bfloat16 state page would hold at the cell's
-chunk; the products stay float32). At the cell's bfloat16 activations
-it is a REPORT: the activations' own rounding reads 0.13-0.16 here and
+decode window: what a bfloat16 state page would hold at the cell's
+chunk and window; the products stay float32). At the cell's bfloat16
+activations it is a REPORT: the activations' own rounding reads 0.13-0.16 here and
 a bfloat16 state 0.15 (my chip runs, PR 47: the errors of 8256
 monomials are independent and average out), so no tolerance on the
 logits tells the two apart. ``--dtype float32`` serves the same path
 with float32 activations and every product at full precision (the
 kernels' float32 case, int8 weights as they are), where the state's precision is the only thing under
-float32: there ``--tolerance`` defaults to 0.02 (40 x the 0.0005 the
-served path reads at 8k and 32 steps, under a third of the 0.07 a
-bfloat16 state reads: my chip run, PR 47) and lean is a CONTROL
+float32: there ``--tolerance`` defaults to 0.02 (36 x the 0.00055 the
+served path reads at 8k and 32 steps; a bfloat16 state reads 0.027
+rounded once a window of 8, my chip run, PR 48, and 0.07 rounded every
+step, PR 47: 48 and 133 x the served path) and lean is a CONTROL
 that must fail ``logits`` or stand ``FARTHER`` (3) times as far from
 the reference as the served path does on that row. ``--control
 NAME:KEY=JSON`` (repeatable) reads the same served numbers against the
@@ -73,6 +77,7 @@ CONFIG = os.path.join(ROOT, "chipbench", "configs",
                       "brumby-14b-int8-l10.json")
 TOP = 20
 BATCH = 16
+WINDOW = 8
 FARTHER = 3.0
 
 
@@ -83,7 +88,8 @@ def main(argv=None) -> int:
     ap.add_argument("--contexts", type=int, nargs=2, default=(8192, 16000),
                     metavar=("LO", "HI"))
     ap.add_argument("--chunks", type=int, nargs="+", default=(2048, 512))
-    ap.add_argument("--decode-steps", type=int, default=32)
+    ap.add_argument("--decode-steps", type=int, default=32,
+                    help=f"in windows of {WINDOW}")
     ap.add_argument("--lean-chunk", type=int, default=256,
                     help="the lean pass's prefill chunk: the state is "
                          "rounded once a chunk, as a bfloat16 page would "
@@ -137,6 +143,8 @@ def main(argv=None) -> int:
         args.tolerance = 0.3 if args.dtype == "bfloat16" else 0.02
     lo, hi = args.contexts
     R, N = args.rows, args.decode_steps
+    if N % WINDOW or not retention.windowed(1, WINDOW):
+        ap.error(f"--decode-steps: whole windows of {WINDOW} steps")
     t0 = time.monotonic()
     params = llama.init_params(cfg, jax.random.PRNGKey(args.seed),
                                quantization=hf["quantization"])
@@ -160,9 +168,28 @@ def main(argv=None) -> int:
             logits, jnp.clip(lengths - 1, 0, T - 1)[:, None, None], axis=1)
         return jax.nn.log_softmax(last[:, 0], axis=-1), cache
 
+    def window(cache, params, tables, tokens, starts):
+        """One decode window as engine/runner.py fuses it, its tokens
+        [BATCH, WINDOW] given: -> ([WINDOW, BATCH, V] log-probabilities,
+        the cache after the window's fold)."""
+        win = llama.open_window(cfg, tables, starts, WINDOW,
+                                starts < parked)
+
+        def body(carry, toks):
+            cache, win, pos = carry
+            logits, cache, _, win = llama.forward_in_window(
+                params, cfg, toks[:, None], pos[:, None], cache, win,
+                block_tables=tables, token_valid=(pos < parked)[:, None])
+            return ((cache, win, pos + 1),
+                    jax.nn.log_softmax(logits[:, 0], axis=-1))
+        (cache, win, _), lps = jax.lax.scan(body, (cache, win, starts),
+                                            tokens.T)
+        return lps, llama.close_window(cache, win)
+
     # the parameters are an ARGUMENT: closed over, 4.9 GB of weights
     # become constants of the lowering (tools/dsa_chip_check.py)
     step = jax.jit(forward, donate_argnums=0)
+    steps = jax.jit(window, donate_argnums=0)
     if args.dtype == "float32":
         # float32 activations multiply in bfloat16 passes on the TPU
         # unless told otherwise (0.07 against the reference at 8k, my
@@ -199,18 +226,17 @@ def main(argv=None) -> int:
         """N teacher-forced steps of ``rows`` {batch row: sequence row}
         beside parked rows -> [N] arrays [BATCH, V]."""
         out = []
-        for t in range(N):
-            tokens = np.zeros((BATCH, 1), np.int32)
+        for t in range(0, N, WINDOW):
+            tokens = np.zeros((BATCH, WINDOW), np.int32)
             starts = np.full((BATCH,), parked, np.int32)
-            lengths = np.zeros((BATCH,), np.int32)
             for b, r in rows.items():
-                tokens[b, 0] = seqs[r][lens[r] + t]
-                starts[b], lengths[b] = lens[r] + t, 1
-            lps, cache = step(cache, params, tables, jnp.asarray(tokens),
-                              jnp.asarray(starts), jnp.asarray(lengths))
+                tokens[b] = seqs[r][lens[r] + t:lens[r] + t + WINDOW]
+                starts[b] = lens[r] + t
+            lps, cache = steps(cache, params, tables, jnp.asarray(tokens),
+                               jnp.asarray(starts))
             if after is not None:
                 cache = after(cache)
-            out.append(np.asarray(lps))
+            out += list(np.asarray(lps))
         return out, cache
 
     lens = np.linspace(lo, hi, R).astype(int)
@@ -250,7 +276,8 @@ def main(argv=None) -> int:
            "chunks": used, "dtype": args.dtype,
            "tolerance": args.tolerance,
            "mixer_paths": [retention.retention_path(
-               t, cfg.head_dim_, cfg.num_kv_heads) for t in (1, used[0])],
+               t, cfg.head_dim_, cfg.num_kv_heads, steps=w)
+               for t, w in ((1, WINDOW), (used[0], 1))],
            "retention_chunk": retention.CHUNK, "served_seconds": served_s,
            "controls": {}, "reports": {}}
     out["served"], true = read(hf, range(R))
