@@ -33,10 +33,15 @@ carries as it carries the K/V pool:
     (the WY / UT transform: ``T = (I + L)^-1`` by forward substitution
     in blocks, ``u = T (beta V)``, ``w = T (beta exp(G) K)``,
     ``D = u - w S_0``), made for every chunk at once under the scope
-    ``gdn_chunk_prep``. Between chunks the state is carried: on the TPU
-    the kernel ``gdn_chunk_scan``, a grid step a (row, head, chunk)
-    with the head's matrix in VMEM from the page's copy-in at the first
-    chunk to its copy-back at the last:
+    ``gdn_chunk_prep``: on the TPU the substitution of the diagonal
+    blocks is the kernel ``gdn_chunk_solve`` (a lane tile of blocks a
+    grid step, read and written once, its fifteen row updates on the
+    tile in VMEM); the products around it (L itself, the
+    merges of neighbouring blocks, ``u``, ``w``) are XLA's. Between
+    chunks the state is carried: on the TPU the kernel
+    ``gdn_chunk_scan``, a grid step a (row, head, chunk) with the head's
+    matrix in VMEM from the page's copy-in at the first chunk to its
+    copy-back at the last:
 
         D = u - w S;  o = (q exp(G)) S + (Q K^T . decay) D
         S = exp(G_C) S + (k exp(G_C - G))^T D
@@ -50,9 +55,10 @@ are not real advance nothing: the caller hands them ``g = 0`` and
 ``beta = 0``, and a row that is not real names the trash page.
 
 Where the kernels are off (``pallas_paged.flash_enabled``: the CPU) the
-same two forms run in ``jax.numpy``; tests/test_gdn.py holds each to
-the sequential rule of chipbench/references/qwen3_next.py and, in
-interpret mode, the kernels to the ``jax.numpy`` forms.
+same two forms, the substitution among them, run in ``jax.numpy``;
+tests/test_gdn.py holds each to the sequential rule of
+chipbench/references/qwen3_next.py and, in interpret mode, the kernels
+to the ``jax.numpy`` forms.
 """
 
 import functools
@@ -73,6 +79,9 @@ CHUNK = 64
 # forward substitution, row by row; above it blocks are merged by
 # products
 _SOLVE_BLOCK = 16
+# diagonal blocks a grid step of the substitution kernel holds, one a
+# lane: [16, 16, 512] float32 is 512 KB in and as much out
+_SOLVE_LANES = 512
 
 RECURRENT = "gdn_recurrent"
 CHUNKED = "gdn_chunk"
@@ -174,6 +183,58 @@ def _recurrent(q, k, v, g, beta, state, ids, layer, fresh):
 # the chunkwise form
 # ---------------------------------------------------------------------
 
+def _solve_rows_jnp(At: jnp.ndarray) -> jnp.ndarray:
+    """The substitution as XLA operations: the CPU's path, and what
+    tests/test_gdn.py holds the kernel to."""
+    for i in range(1, At.shape[0]):
+        row = At[i]                                     # [b, blocks]
+        At = At.at[i].set(row + jnp.sum(row[:, None, :] * At, axis=0))
+    return At
+
+
+def _solve_kernel(a_ref, o_ref):
+    """One lane tile of blocks, [b, b, lanes] float32 in VMEM: row i
+    from the finished rows j < i of o_ref (the entries at j >= i are
+    zeros of a strictly lower triangle and are left out). No product
+    goes to the MXU: every multiply-add is the vector unit's, float32,
+    so no matmul precision of the caller's reaches in here."""
+    o_ref[0] = a_ref[0]
+    for i in range(1, a_ref.shape[0]):
+        acc = a_ref[i]                                  # [b, lanes]
+        for j in range(i):
+            acc = acc + a_ref[i, j:j + 1, :] * o_ref[j]
+        o_ref[i] = acc
+
+
+def _solve_rows(At: jnp.ndarray) -> jnp.ndarray:
+    """At [b, b, blocks] float32, the NEGATED strictly lower diagonal
+    blocks with the blocks on the minor axis (whole lanes) -> the same
+    of their ``(I + L)^-1 - I``. Row i of a block's inverse = its own
+    entries plus, for every j < i, entry j times the finished row j:
+    forward substitution, the same recurrence in the same order in
+    both forms. The kernel's operand is row-major by the call's own
+    constraint; for the loop inside ``_chunk_prep`` XLA's layout
+    assignment puts the blocks on the major axis, a sixteenth of the
+    lanes, and neither ``optimization_barrier`` nor
+    ``with_layout_constraint`` around the loop moves it (PERF.md,
+    PR 49)."""
+    if not pallas_paged.flash_enabled():
+        return _solve_rows_jnp(At)
+    b, _, blocks = At.shape
+    lanes = min(_SOLVE_LANES, blocks)
+    tile = pl.BlockSpec((b, b, lanes), lambda t: (0, 0, t))
+    return pl.pallas_call(
+        _solve_kernel, grid=(pl.cdiv(blocks, lanes),),
+        in_specs=[tile], out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(At.shape, At.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
+        interpret=pallas_paged.needs_interpret(),
+        name="gdn_chunk_solve",
+    )(At)
+
+
 def _unit_lower_inverse(L: jnp.ndarray) -> jnp.ndarray:
     """(I + L)^-1 for L [..., n, n] STRICTLY lower triangular, float32,
     n a power-of-two multiple of _SOLVE_BLOCK. The diagonal blocks of
@@ -186,19 +247,11 @@ def _unit_lower_inverse(L: jnp.ndarray) -> jnp.ndarray:
     hi = jax.lax.Precision.HIGHEST
     A = -jnp.stack([L[..., i:i + b, i:i + b] for i in range(0, n, b)],
                    axis=-3)                             # [..., n/b, b, b]
-    # row i of a block's inverse = its own entries plus, for every
-    # j < i, entry j times the finished row j. With the blocks on the
-    # MINOR axis ([row, column, blocks]: whole lanes, and a row's update
-    # rewrites that row's [column, blocks] plane alone); as [..., b, b]
-    # every one of the 15 updates rewrote the whole array at a sixth of
-    # its lanes (31 ms of a 2048-token chunk on the chip, and written
-    # out entry by entry the compiler recomputed earlier rows inside
-    # later ones, 88 ms: PERF.md, PR 42)
+    # the blocks go on the MINOR axis ([row, column, blocks]: whole
+    # lanes); as [..., b, b] the substitution ran at a sixth of its
+    # lanes (PERF.md, PR 42)
     lead = A.shape[:-2]
-    At = jnp.moveaxis(A.reshape((-1, b, b)), 0, -1)     # [b, b, blocks]
-    for i in range(1, b):
-        row = At[i]                                     # [b, blocks]
-        At = At.at[i].set(row + jnp.sum(row[:, None, :] * At, axis=0))
+    At = _solve_rows(jnp.moveaxis(A.reshape((-1, b, b)), 0, -1))
     A = (jnp.moveaxis(At, -1, 0).reshape(lead + (b, b))
          + jnp.eye(b, dtype=L.dtype))
     blocks, size = [A[..., i, :, :] for i in range(n // b)], b

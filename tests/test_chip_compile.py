@@ -1113,7 +1113,8 @@ def test_hybrid_step_program_compiles_at_qwen3next_widths(
                             rep((1,), jnp.int32), rep((1, 2048), jnp.int32),
                             rep((1,), jnp.int32), rep((1,), jnp.int32),
                             *small).compile()
-        want = {"paged_attention", "moe_grouped_experts", "gdn_chunk_scan"}
+        want = {"paged_attention", "moe_grouped_experts", "gdn_chunk_scan",
+                "gdn_chunk_solve"}
         path = "pallas_paged"
     hlo = compiled.as_text()
     calls = {m.group(1) for m in re.finditer(
@@ -1133,6 +1134,13 @@ def test_hybrid_step_program_compiles_at_qwen3next_widths(
     slices = re.findall(r"= s8\[3,2048,\d+\]\S* [\w\-]+\(", hlo)
     assert not slices, slices[:3]
     if program == "prefill_chunk":
+        # the rule's substitution is the kernel's (ops/gdn._solve_rows):
+        # no XLA operation rewrites a layer's 4096 diagonal blocks a row
+        # at a time (fifteen dynamic-update-slice a layer: PERF.md,
+        # PR 49)
+        rows = re.findall(
+            r"= f32\[16,16,4096\]\S* dynamic-update-slice\(", hlo)
+        assert not rows, rows[:3]
         # the held experts' rounds (ops/moe._moe_grouped): nothing of a
         # chunk's 2048 x 10 assignments by the hidden width is built,
         # nor the buffer of 21 568 rows they were sorted into; a
@@ -1175,6 +1183,36 @@ def test_kv_prefill_kernel_in_q_blocks_compiles_at_8_groups_of_256(
         s((1, T, 16, 256), jnp.bfloat16), pool, pool,
         s((1, 256), jnp.int32), s((1,), jnp.int32),
         s((), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("T,heads", [(512, 32), (320, 32), (64, 4),
+                                     (192, 4)])
+def test_gdn_chunk_prep_compiles_with_the_solve_kernel(topo, tpu_branches,
+                                                      T, heads):
+    """The chunkwise rule's per-chunk transform of one row at
+    Qwen3-Next's 32 value heads of 128 (512 tokens: 1024 diagonal
+    blocks in two lane tiles; 320: 640, the last tile a quarter full)
+    and at 4 heads (16 and 48 blocks: less than a vector register's
+    lanes), compiled for the described v5e: the substitution is
+    ``gdn_chunk_solve`` and nothing else writes the blocks a row at a
+    time. A chunk's 4096 blocks are the whole prefill executable's
+    (test_hybrid_step_program_compiles_at_qwen3next_widths)."""
+    import re
+    from production_stack_tpu.ops import gdn
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    blocks = heads * T // 16
+    hlo = jax.jit(gdn._chunk_prep).lower(
+        s((1, T, heads, 128)), s((1, T, heads, 128)),
+        s((1, T, heads, 128)), s((1, T, heads), jnp.float32),
+        s((1, T, heads), jnp.float32)).compile().as_text()
+    calls = [m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]+)[\w.\-]* = [^=]*? custom-call\(", hlo)]
+    assert [c for c in calls if c.startswith("gdn")] == ["gdn_chunk_solve"]
+    assert not re.findall(rf"= f32\[16,16,{blocks}\]\S* "
+                          r"dynamic-update-slice\(", hlo)
 
 
 # ---------------------------------------------------------------------

@@ -6,7 +6,8 @@ pool, on the CPU at tiny sizes with seeded weights.
   ``jax.numpy`` and as the kernels in interpret mode, against the
   SEQUENTIAL rule of chipbench/references/qwen3_next.py
   (``delta_rule``), at lengths that are not a multiple of the chunk,
-  with padded tails and a carried state;
+  with padded tails and a carried state; the substitution kernel
+  (``gdn_chunk_solve``) against the ``jax.numpy`` loop;
 - the model through both caches (prefill in several chunks with a
   padded last one, then decode steps beside a parked row) against the
   reference's full forward pass, float32, to 1e-4 on the
@@ -141,12 +142,76 @@ def test_both_forms_are_the_sequential_rule(T, how, request):
     assert worst(new[0], state[0]) == 0 and worst(new[2], state[2]) == 0
 
 
+def _diagonal_blocks(T, B, hv, seed, monkeypatch):
+    """What ``_chunk_prep`` hands the substitution for seeded inputs of
+    T positions, padded to whole chunks as ``_chunked`` pads them
+    (g = 0, beta = 0): [16, 16, blocks], negated and strictly lower."""
+    q, k, v, g, beta, _ = _rule_inputs(T, B=B, hk=hv, hv=hv, seed=seed)
+    pad = (-T) % gdn.CHUNK
+    q, k, v, g, beta = (
+        jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        for x in (q, k, v, g, beta))
+    seen = []
+    with monkeypatch.context() as patch:
+        patch.setattr(gdn, "_solve_rows", lambda At: (
+            seen.append(At), gdn._solve_rows_jnp(At))[1])
+        gdn._chunk_prep(q, k, v, g, beta)
+    return seen[0]
+
+
+@pytest.mark.parametrize("T,B,hv", [(64, 2, 4), (100, 2, 4), (192, 2, 4),
+                                    (2048, 1, 2)])
+def test_the_solve_kernel_is_the_jnp_substitution(T, B, hv, kernels,
+                                                  monkeypatch):
+    """``gdn_chunk_solve`` in interpret mode against the ``jax.numpy``
+    loop on the diagonal blocks of real chunks, to 1e-6 of the largest
+    entry: 32, 64, 96 and 256 blocks, none of which fills the
+    kernel's lane tile. A padded position is a row of zeros in L and
+    stays one (the identity's row, once I is added)."""
+    At = _diagonal_blocks(T, B, hv, 4, monkeypatch)
+    blocks = B * hv * (-(-T // gdn.CHUNK)) * (gdn.CHUNK // 16)
+    assert At.shape == (16, 16, blocks) and blocks < gdn._SOLVE_LANES
+    want = gdn._solve_rows_jnp(At)
+    got = jax.jit(gdn._solve_rows)(At)
+    assert worst(got, want) <= 1e-6 * float(jnp.abs(want).max())
+    upper = jnp.arange(16)[:, None] <= jnp.arange(16)[None, :]
+    assert float(jnp.abs(jnp.where(upper[..., None], got, 0.0)).max()) == 0
+    if T == 100:        # positions 100-127 of each row's second chunk
+        rows = np.asarray(got).reshape(16, 16, B, hv, 2, 4)
+        assert np.abs(rows[..., 1, 2:][4:]).max() == 0      # 100-111
+        assert np.abs(rows[..., 1, 3]).max() == 0           # 112-127
+        assert np.abs(rows[..., 0, :]).max() > 0
+
+
+@pytest.mark.parametrize("blocks", [512, 640, 1536])
+def test_the_solve_kernel_by_lane_tiles(blocks, kernels):
+    """One whole lane tile, a tile and a quarter (the last grid step
+    holds 128 real lanes of 512) and three: every block is solved and
+    none leaks into its neighbour's lane. Entries up to 1.5: sixteen
+    rows compound them to hundreds."""
+    low = jnp.arange(16)[:, None] > jnp.arange(16)[None, :]
+    At = jnp.where(low[..., None], 0.5 * jax.random.normal(
+        jax.random.PRNGKey(blocks), (16, 16, blocks)), 0.0)
+    want = gdn._solve_rows_jnp(At)
+    got = jax.jit(gdn._solve_rows)(At)
+    assert worst(got, want) <= 1e-6 * float(jnp.abs(want).max())
+    # the inverse it is: (I + L) (I + X) = I, block by block
+    eye = jnp.eye(16)[..., None]
+    prod = jnp.einsum("ijn,jkn->ikn", eye - At, eye + got,
+                      precision=jax.lax.Precision.HIGHEST)
+    assert worst(prod, jnp.broadcast_to(eye, prod.shape)) \
+        <= 1e-5 * float(jnp.abs(want).max())
+
+
 @pytest.mark.parametrize("T,real", [(64, 40), (128, 70), (192, 1)])
-def test_a_padded_tail_advances_nothing(T, real):
+@pytest.mark.parametrize("how", ["jnp", "kernel"])
+def test_a_padded_tail_advances_nothing(T, real, how, request):
     """Positions past ``real`` carry g = 0 and beta = 0 (what the layer
     hands the rule for positions that are not real): the state after
     the chunk is the state after its real positions, and carrying it
     into a second call gives what one call over both gives."""
+    if how == "kernel":
+        request.getfixturevalue("kernels")
     q, k, v, g, beta, state = _rule_inputs(T, B=1, seed=1)
     live = (jnp.arange(T) < real)[None, :, None]
     g, beta = jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0)
@@ -170,10 +235,14 @@ def test_a_padded_tail_advances_nothing(T, real):
     assert worst(o2[0], both[real:]) < 2e-5
 
 
-def test_keys_that_repeat_do_not_break_the_solve():
+@pytest.mark.parametrize("how", ["jnp", "kernel"])
+def test_keys_that_repeat_do_not_break_the_solve(how, request):
     """Identical keys and beta near one make I + L the matrix whose
     inverse the series I - L + L^2 - ... reaches only through terms of
-    1e17: forward substitution in blocks stays exact."""
+    1e17: forward substitution in blocks stays exact, as XLA's loop and
+    as the kernel."""
+    if how == "kernel":
+        request.getfixturevalue("kernels")
     T = 128
     q, k, v, g, beta, state = _rule_inputs(T, B=1, seed=3)
     k = jnp.broadcast_to(k[:, :1], k.shape)
@@ -184,6 +253,29 @@ def test_keys_that_repeat_do_not_break_the_solve():
                              v[0], g[0], beta[0],
                              jnp.zeros_like(state[0, 0]))
     assert worst(o[0], want) < 1e-4 * max(1.0, float(jnp.abs(want).max()))
+
+
+def test_the_transform_table_tool_rehearses_on_the_cpu():
+    """tools/gdn_prep_table.py at 4 heads and 64 tokens, the kernel in
+    interpret mode (a process of its own: the tool switches the kernels
+    for itself): a row a form, the kernel's with what it differs by
+    from the loop's."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tools", "gdn_prep_table.py"),
+         "--tiny", "--allow-cpu", "--tokens", "64", "--repeat", "1"],
+        capture_output=True, text=True, timeout=600,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    xla, kernel = line["rows"]
+    assert line["kernel"] and (xla["form"], kernel["form"]) == (
+        "xla", "kernel")
+    assert xla["blocks"] == kernel["blocks"] == 16
+    for row in (xla, kernel):       # no device plane here
+        assert row["clock"] == "host" and row["ops_us"] == {}
+        assert row["prep_ms"] > 0 and row["solve_alone_ms"] > 0
+    for name, entry in kernel["largest_entry"].items():
+        assert kernel["largest_difference"][name] <= 1e-6 * max(entry, 1.0)
 
 
 def test_the_convolution_keeps_its_last_real_inputs():

@@ -216,7 +216,11 @@ def test_a_held_loop_reads_a_lag_sample(served):
     assert idle and max(idle) < 0.04
     assert all(set(e) == {"at", "at_unix", "lag_s", "cpu_s"}
                for e in perf["loop"])
-    assert len(few["loop"]) == 2 and few["loop"] == perf["loop"][-2:]
+    # the ring's last two, in order; where one probe sample fell between
+    # the two reads, the last and that one
+    assert len(few["loop"]) == 2
+    assert (few["loop"] == perf["loop"][-2:]
+            or few["loop"][0] == perf["loop"][-1])
 
 
 def test_first_token_write_lies_inside_decode(served):
