@@ -41,7 +41,8 @@ class BlockManager:
                  enable_prefix_caching: bool = False,
                  namespace: str = "", bytes_per_token: int = 0,
                  layout: str = "kv_heads", index_bytes_per_token: int = 0,
-                 state_pages: int = 0, state_bytes_per_slot: int = 0):
+                 state_pages: int = 0, state_bytes_per_slot: int = 0,
+                 pool_layers: int = 0, reader_layers: int = 0):
         if num_blocks < 2:
             raise ValueError("pool needs at least one non-trash block")
         self.num_blocks = num_blocks          # includes trash block 0
@@ -51,6 +52,11 @@ class BlockManager:
         # "kv_heads" | "latent"); 0 where no pool was described
         self.bytes_per_token = bytes_per_token
         self.layout = layout
+        # the pool's layers, and the model's layers that read one: more
+        # where some read another layer's K/V and append nothing (a
+        # decoder-hybrid-decoder's cross layers); reported, not used
+        self.pool_layers = pool_layers
+        self.reader_layers = reader_layers
         # bytes_per_token's part that a second pool under the same
         # tables takes ("latent+index": the sparse-attention indexer's
         # keys): a block id names a block of both pools, so every
@@ -153,6 +159,8 @@ class BlockManager:
             "bytes_per_token": self.bytes_per_token,
             "index_bytes_per_token": self.index_bytes_per_token,
             "layout": self.layout,
+            "pool_layers": self.pool_layers,
+            "reader_layers": self.reader_layers,
             "free": self.free_blocks,
             "active": self.active_blocks,
             "cached": self.cached_blocks,

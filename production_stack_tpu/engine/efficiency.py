@@ -367,6 +367,15 @@ class EngineEffAccounting:
         self.state_step_bytes = 0
         self.state_scan_bytes = 0
         self.state_pages = None
+        # a model whose prefill runs in two depths (note_depths;
+        # ``self_positions`` / ``cross_positions`` of ``totals.prefill``):
+        # the positions its first layers and its later layers ran, a
+        # dispatch's rows x chunk bucket against one a finishing row;
+        # and ``totals.shared_kv`` (note_shared_kv): the paged calls
+        # that read a pool layer they did not append to, and the keys
+        # at or before their queries. None: every layer owns its K/V
+        self.depth_positions = None
+        self.shared_kv = None
         # modeled HBM traffic (decode windows only — see module doc)
         self.bytes_total = 0
         self.bytes_effective = 0
@@ -546,6 +555,27 @@ class EngineEffAccounting:
             self.state_steps += steps
             self.state_step_bytes += step_bytes
             self.state_scan_bytes += scan_bytes
+
+    def note_depths(self, self_positions: int,
+                    cross_positions: int) -> None:
+        """One prefill dispatch of a model whose plan has two depths:
+        the positions its first layers ran (rows x chunk bucket) and
+        those its later layers ran (one a row whose prompt ended in
+        the chunk, where any did; else none)."""
+        with self._lock:
+            at = self.depth_positions or [0, 0]
+            self.depth_positions = [at[0] + self_positions,
+                                    at[1] + cross_positions]
+
+    def note_shared_kv(self, reads: int, keys_read: int) -> None:
+        """One dispatch of a model with layers that read ANOTHER
+        layer's K/V: ``reads`` paged calls of such layers (a call a
+        layer and step), ``keys_read`` the keys at or before their
+        queries, summed over calls, rows and positions (reckoned here,
+        from the positions, as ``note_sparse`` reckons)."""
+        with self._lock:
+            at = self.shared_kv or [0, 0]
+            self.shared_kv = [at[0] + reads, at[1] + keys_read]
 
     def note_sparse(self, kind: str, first, queries: int, topk: int,
                     selects: bool) -> None:
@@ -784,8 +814,15 @@ class EngineEffAccounting:
                                 sorted(self.prefill_by_rows.items())},
                             "chunks_by_path": dict(sorted(
                                 self.prefill_chunks_by_path.items())),
+                            **({"self_positions": self.depth_positions[0],
+                                "cross_positions":
+                                    self.depth_positions[1]}
+                               if self.depth_positions else {}),
                             **moe_rows},
                 **moe,
+                **({"shared_kv": {"reads": self.shared_kv[0],
+                                  "keys_read": self.shared_kv[1]}}
+                   if self.shared_kv else {}),
                 **({"sparse": {
                     **{key: sum(row[key] for row in self.sparse.values())
                        for key in ("keys_in_context", "keys_scored",

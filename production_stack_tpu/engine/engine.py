@@ -235,7 +235,11 @@ class LLMEngine:
             layout=self.runner.cache.layout,
             index_bytes_per_token=self.runner.cache.index_bytes_per_token,
             state_pages=self.runner.cache.state_pages,
-            state_bytes_per_slot=self.runner.cache.state_bytes_per_slot)
+            state_bytes_per_slot=self.runner.cache.state_bytes_per_slot,
+            pool_layers=(0 if self.runner.cache.k is None
+                         else self.runner.cache.k.shape[0]),
+            reader_layers=(self.model_cfg.reader_layers
+                           if self.runner.cache.k is not None else 0))
         # a slot's row: its blocks and, where the model keeps state a
         # sequence, its state page as one more column (models/kv.
         # split_tables); an empty row names trash block and trash page
@@ -296,6 +300,8 @@ class LLMEngine:
             self.eff.state_pages = self.block_mgr.page_counts
         # bytes of one sequence's state page, all layers (0: none)
         self._state_page_bytes = self.runner.cache.state_bytes_per_slot
+        # layers that read another layer's K/V (0: every layer its own)
+        self._cross_layers = mc.reader_layers - mc.attn_layers
         self.runner.compile_observer = self.eff
         # advertised once: the router's per-endpoint concurrency cap
         # reads this gauge (0 = unbounded admission, nothing to cap on)
@@ -320,15 +326,12 @@ class LLMEngine:
         # rolling KV: models whose EVERY layer is windowed (Mistral
         # v0.1-style) never attend positions behind the window again, so
         # their blocks are freed as generation advances — live-context
-        # HBM bounded by W instead of total length. Off for alternating
-        # (Gemma-2: global layers need the full prefix) and under KV
+        # HBM bounded by W instead of total length. Off where some layer
+        # sees every key (ModelConfig.window_everywhere: Gemma-2's
+        # global layers, a decoder-hybrid-decoder's full layer) and under KV
         # tiering (tier extraction reads from position 0).
-        self._roll_window = (
-            self.model_cfg.sliding_window
-            if (self.model_cfg.sliding_window
-                and not self.model_cfg.alternating_sliding
-                and self.connector is None)
-            else None)
+        self._roll_window = (self.model_cfg.window_everywhere
+                             if self.connector is None else None)
         self.seqs: Dict[str, Sequence] = {}
         self._finished_order: List[str] = []
         self._id_counter = itertools.count()
@@ -1208,12 +1211,15 @@ class LLMEngine:
                         if w.is_last), default=0)
             if topk:
                 topk = 1 << (topk - 1).bit_length()
+            # some row's prompt ends in this dispatch: what a model
+            # whose prefill has two depths runs its later layers for
+            finishing = any(w.is_last for w in group)
             with self._phase("prefill_dispatch", dispatches=True) as call:
                 devs = self.runner.prefill(
                     tokens, starts, lengths, self._dev_sampling, kv_len,
                     guide_table=gtable, guide_ids=gids,
                     guide_states=gstates, penalized=penalized, topk=topk,
-                    slots=slots)
+                    slots=slots, finishing=finishing)
             # bucket-padding accounting: the dispatch computed
             # rows*bucket positions; only the scheduled chunks' tokens
             # were real
@@ -1233,6 +1239,16 @@ class LLMEngine:
                         + len(w.chunk) * (len(w.chunk) + 1) // 2
                         for w in group),
                     scan_bytes=2 * rows * self._state_page_bytes)
+            if self._cross_layers:
+                # the later layers run on a finishing row's last
+                # position alone, where any row finishes, and read the
+                # shared layer's keys at or before it
+                self.eff.note_depths(rows * bucket,
+                                     len(group) if finishing else 0)
+                if finishing:
+                    self.eff.note_shared_kv(
+                        self._cross_layers, self._cross_layers * sum(
+                            w.start + len(w.chunk) for w in group))
             if self.model_cfg.index_topk:
                 # (a chunk's padding past its tokens is not counted)
                 for w in group:
@@ -1646,6 +1662,15 @@ class LLMEngine:
                 step_rows=W * len(decode_seqs), steps=W,
                 step_bytes=self.runner.state_pages_moved(W) * batch
                 * self._state_page_bytes)
+        if self._cross_layers:
+            # a query at position p reads p + 1 keys of the shared
+            # layer, in every cross layer, every step of the window
+            first = sum(s.next_position + joined.get(s.seq_id, ahead)
+                        for s in decode_seqs)
+            self.eff.note_shared_kv(
+                W * self._cross_layers,
+                self._cross_layers * (W * first + len(decode_seqs)
+                                      * W * (W + 1) // 2))
         if self.model_cfg.index_topk:
             self.eff.note_sparse(
                 "decode", [s.next_position + joined.get(s.seq_id, ahead)

@@ -53,6 +53,7 @@ from production_stack_tpu.models.kv import (KV_HEADS, KVCache, cache_for,
 from production_stack_tpu.models import llama
 from production_stack_tpu.ops import moe, retention
 from production_stack_tpu.ops.gdn import gdn_path
+from production_stack_tpu.ops.mamba import mamba_path
 from production_stack_tpu.ops.pallas_paged import JNP_GATHER, attention_path
 from production_stack_tpu.ops.rope import rope_table
 from production_stack_tpu.utils import init_logger
@@ -160,14 +161,14 @@ class ModelRunner:
         # beside it where the model selects what it attends, or K and V
         # of the attention layers with a state page a slot (and the
         # trash page) beside them where the model has Gated DeltaNet
-        # layers, or state pages ALONE, a page a block, where every
+        # or Mamba layers, or state pages ALONE, a page a block, where every
         # layer is a power retention layer (models/kv.cache_for; it
         # refuses an int8 latent pool and an int8 pool beside state
         # pages by name)
         self.cache: KVCache = cache_for(
             model_cfg, n_blocks, engine_cfg.kv_block_size, dtype=kv_dt,
             state_pages=(engine_cfg.max_num_seqs + 1
-                         if model_cfg.gdn_layers else 0))
+                         if self._pages_beside_kv else 0))
         self._tables = jnp.zeros(self.table_shape, jnp.int32)
         self._tables_host = np.zeros(self.table_shape, np.int32)
         self._tables_dirty = False
@@ -310,7 +311,14 @@ class ModelRunner:
         its page."""
         return (self.engine_cfg.max_num_seqs,
                 self.engine_cfg.max_blocks_per_seq
-                + bool(self.model_cfg.gdn_layers))
+                + self._pages_beside_kv)
+
+    @property
+    def _pages_beside_kv(self) -> bool:
+        """Does the model keep state pages BESIDE a K/V pool (Gated
+        DeltaNet or Mamba layers among attention layers)?"""
+        cfg = self.model_cfg
+        return bool(cfg.state_layers and cfg.attn_layers)
 
     def _refuse_with_state_pages(self, lora_stacked) -> None:
         """What a model with state pages (Gated DeltaNet or power
@@ -637,6 +645,7 @@ class ModelRunner:
                       guide_next: jnp.ndarray, guide_id: jnp.ndarray,
                       guide_state: jnp.ndarray,
                       out_counts: jnp.ndarray, prompt_seen: jnp.ndarray,
+                      finishing: Optional[jnp.ndarray] = None,
                       *, kv_len: int, guided: bool = False,
                       penalized: bool = False, eos_id: int = 0,
                       topk: int = 0):
@@ -656,6 +665,12 @@ class ModelRunner:
         models the experts' capacity is reckoned on max_num_seqs rows
         whatever R is (ops/moe.moe_mlp ``capacity_tokens``): fewer rows
         never hold less per expert than the full dispatch does.
+        A model whose plan has two depths (cfg.self_layers <
+        num_layers: a decoder-hybrid-decoder) runs its later layers,
+        the final norm and the head on each row's LAST real position
+        alone, and not at all unless ``finishing`` (a bool scalar: some
+        row's prompt ends in this chunk; llama.forward ``last``): no
+        chunk makes logits for more than one position a row.
         Returns (sampled id of each row's last real token [R], its
         logprob [R], top ids and logprobs [R, K], cache', the experts'
         counts summed over the layers, int32 [3]: the rows they
@@ -676,19 +691,22 @@ class ModelRunner:
         # write K/V, route in MoE layers, or steal expert capacity
         token_valid = ((jnp.arange(Tb)[None, :] < lengths[:, None])
                        & (starts < S)[:, None])
+        two_depths = self.model_cfg.self_layers < self.model_cfg.num_layers
+        at_last = jnp.maximum(lengths - 1, 0)
         logits, cache, work = llama.forward(
             params, self.model_cfg, tokens, positions, cache,
             block_tables=tables,
             rope=self.rope, kv_len=kv_len, mesh=self.mesh,
             lora_params=self._lora, adapter_ids=sampling.adapter,
             lora_scaling=self._lora_scaling, token_valid=token_valid,
-            moe_capacity_tokens=self.engine_cfg.max_num_seqs * Tb)
+            moe_capacity_tokens=self.engine_cfg.max_num_seqs * Tb,
+            **(dict(last=at_last, finishing=finishing) if two_depths
+               else {}))
         expert_rows = None if work is None else jnp.stack(
             [work.expert_rows, work.held_rows, work.rounds])
         with jax.named_scope("sample"):
-            last = jnp.take_along_axis(
-                logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
-            )[:, 0, :]
+            last = (logits if two_depths else jnp.take_along_axis(
+                logits, at_last[:, None, None], axis=1))[:, 0, :]
             if penalized:
                 # first sampled token: counts cover any already-emitted
                 # output (preemption-resume rows), prompt_seen the prompt
@@ -973,9 +991,11 @@ class ModelRunner:
                 selects=kv_len is not None and self.selects(kv_len),
                 head_dims=(cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                            cfg.v_head_dim))
+        # (the pool's own heads: differential attention pairs them,
+        # cfg.pool_kv_heads of cfg.pool_head_dim)
         return attention_path(
-            positions, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
-            self.engine_cfg.kv_block_size, mesh)
+            positions, cfg.num_heads // cfg.pool_kv_heads,
+            cfg.pool_head_dim, self.engine_cfg.kv_block_size, mesh)
 
     def _mixer_path(self, positions: int, steps: int = 1) -> Optional[str]:
         """The implementation the layers that keep state pages take in
@@ -986,6 +1006,8 @@ class ModelRunner:
         if cfg.ret_layers:
             return retention.retention_path(
                 positions, cfg.head_dim_, cfg.num_kv_heads, steps)
+        if cfg.mamba_layers:
+            return mamba_path(positions)
         return gdn_path(positions) if cfg.gdn_layers else None
 
     def state_pages_moved(self, steps: int) -> int:
@@ -1084,9 +1106,11 @@ class ModelRunner:
     def prefill(self, tokens, starts, lengths, sampling: SamplingParams,
                 kv_len: int, guide_table=None, guide_ids=None,
                 guide_states=None, penalized: bool = False,
-                topk: int = 0, slots=None):
+                topk: int = 0, slots=None, finishing: bool = True):
         """Chunk prefill of ``tokens.shape[0]`` rows (see
-        _prefill_impl). tokens [R, Tb] int32 np; starts/lengths [R];
+        _prefill_impl; ``finishing``: some row's prompt ends in this
+        chunk, which only a model whose plan has two depths reads).
+        tokens [R, Tb] int32 np; starts/lengths [R];
         slots [R] the slot each row serves (None: rows are slots
         0..R-1, which at R == max_num_seqs is the full-batch dispatch).
         sampling, guide_ids and guide_states stay per slot
@@ -1120,7 +1144,8 @@ class ModelRunner:
                 tokens[r:r + 1], starts[r:r + 1], lengths[r:r + 1],
                 sampling, kv_len, guide_table=guide_table,
                 guide_ids=guide_ids, guide_states=guide_states,
-                penalized=penalized, topk=topk, slots=slots[r:r + 1])
+                penalized=penalized, topk=topk, slots=slots[r:r + 1],
+                finishing=finishing)
                 for r in range(R)]
             ids, lps, tops, rows = zip(*outs)
             return (jnp.concatenate(ids), jnp.concatenate(lps),
@@ -1154,7 +1179,8 @@ class ModelRunner:
                 jnp.asarray(starts, jnp.int32),
                 jnp.asarray(lengths, jnp.int32), sampling, self._next_key(),
                 guide_table, jnp.asarray(guide_ids, jnp.int32),
-                jnp.asarray(guide_states, jnp.int32), counts, seen)
+                jnp.asarray(guide_states, jnp.int32), counts, seen,
+                jnp.asarray(finishing, bool))
 
         def make_prefill():
             logger.info("compiling prefill (rows=%d chunk=%d kv=%d%s%s)",

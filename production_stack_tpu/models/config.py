@@ -169,6 +169,27 @@ class ModelConfig:
     # three-matmul shared expert each read the logit probe at its
     # limit without it (0.10-0.35 of 0.3 on the chip: PERF.md, PR 42)
     exact_dequant_scale: bool = False
+    # the layer plan in full: RUNS of (period, repeats), each run a scan
+    # over its periods (models/llama.forward). () is the one run every
+    # other model is: (pattern_, num_periods). A decoder-hybrid-decoder
+    # (Phi-4-mini-flash, ``phi4flash``; SambaY) is three: ("mamba",
+    # "swa") x 8, ("mamba_mem", "full") x 1, ("gmu", "cross") x 7.
+    # "mamba" is a selective-scan layer (ops/mamba.py): per sequence and
+    # layer a float32 state [mamba_d_state, mamba_d_inner] and the last
+    # mamba_d_conv - 1 inputs of its convolution, a state page;
+    # "mamba_mem" the same layer that also leaves its pre-gate output as
+    # the MEMORY the later "gmu" layers (gated memory units: no cache,
+    # no state) multiply into; "swa" / "full" differential attention
+    # with K/V of its own (a window of sliding_window / every key);
+    # "cross" differential attention whose K and V are the last "full"
+    # layer's, read from that layer's pool layer, nothing appended. Such
+    # a block is LayerNorm (weight and bias) -> mixer -> LayerNorm -> a
+    # fused gate-up MLP, with no rotary embedding anywhere
+    layer_plan: Tuple[Tuple[Tuple[str, ...], int], ...] = ()
+    mamba_d_inner: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_dt_rank: int = 0
     dtype: Any = jnp.bfloat16
 
     @property
@@ -197,20 +218,80 @@ class ModelConfig:
         return self.num_layers // len(self.pattern_)
 
     @property
+    def plan_(self) -> Tuple[Tuple[Tuple[str, ...], int], ...]:
+        """The runs of (period, repeats) the layer loop scans, in
+        order: one run for every model but a decoder-hybrid-decoder."""
+        return self.layer_plan or ((self.pattern_, self.num_periods),)
+
+    def kind_layers(self, *kinds: str) -> int:
+        """Layers of the plan whose mixer is one of ``kinds``."""
+        return sum(period.count(k) * repeats
+                   for period, repeats in self.plan_ for k in kinds)
+
+    @property
     def attn_layers(self) -> int:
         """Layers that keep K and V (or latents) a token: the KV pool's
         leading axis."""
-        return self.num_periods * self.pattern_.count("attn")
+        return self.kind_layers("attn", "swa", "full")
+
+    @property
+    def reader_layers(self) -> int:
+        """Layers that READ a pool layer: those that keep K and V and
+        the cross layers, which read another layer's."""
+        return self.attn_layers + self.kind_layers("cross")
+
+    @property
+    def mamba_layers(self) -> int:
+        """Selective-scan layers: a state page a sequence."""
+        return self.kind_layers("mamba", "mamba_mem")
+
+    @property
+    def self_layers(self) -> int:
+        """Layers a prefill chunk runs on EVERY position: those before
+        the first run that reads a memory or another layer's K/V; the
+        rest run on a row's last prompt position alone
+        (models/llama.forward ``last``). num_layers: no such run."""
+        done = 0
+        for period, repeats in self.plan_:
+            if "gmu" in period or "cross" in period:
+                return done
+            done += len(period) * repeats
+        return self.num_layers
+
+    @property
+    def window_everywhere(self) -> Optional[int]:
+        """sliding_window where EVERY layer that attends is windowed
+        (Mistral v0.1): a block wholly behind the window is of no use
+        to any layer and the engine gives it back (engine.
+        _roll_windows). None where some layer sees every key: Gemma-2's
+        global layers, a decoder-hybrid-decoder's full and cross
+        layers, which share the window layers' block table."""
+        if (self.alternating_sliding
+                or self.kind_layers("full", "cross")):
+            return None
+        return self.sliding_window or None
+
+    @property
+    def pool_kv_heads(self) -> int:
+        """Heads of the K/V pool. Differential attention pairs its
+        key heads ([k1 | k2], one value of twice the width): half as
+        many heads, twice as wide, the same bytes a token."""
+        return self.num_kv_heads // 2 if self.layer_plan \
+            else self.num_kv_heads
+
+    @property
+    def pool_head_dim(self) -> int:
+        return 2 * self.head_dim_ if self.layer_plan else self.head_dim_
 
     @property
     def gdn_layers(self) -> int:
         """Layers that keep a state a sequence: the state pool's."""
-        return self.num_periods * self.pattern_.count("gdn")
+        return self.kind_layers("gdn")
 
     @property
     def ret_layers(self) -> int:
         """Power retention layers: state a sequence, no K or V."""
-        return self.num_periods * self.pattern_.count("ret")
+        return self.kind_layers("ret")
 
     @property
     def ret_features(self) -> int:
@@ -219,8 +300,8 @@ class ModelConfig:
 
     @property
     def state_layers(self) -> int:
-        """Layers that keep state pages, of either kind."""
-        return self.gdn_layers + self.ret_layers
+        """Layers that keep state pages, of any kind."""
+        return self.gdn_layers + self.ret_layers + self.mamba_layers
 
     @property
     def gdn_channels(self) -> int:
@@ -233,8 +314,12 @@ class ModelConfig:
         """Bytes of one state page, all layers: the float32 matrices
         and the convolution's bfloat16 inputs of a Gated DeltaNet
         layer; a power retention layer's float32 ``S`` and ``z`` a
-        key-value head (0: no such layer)."""
-        return (self.gdn_layers * (
+        key-value head; a selective-scan layer's float32 state and its
+        convolution's inputs (0: no such layer)."""
+        return (self.mamba_layers * (
+            4 * self.mamba_d_state * self.mamba_d_inner
+            + 2 * (self.mamba_d_conv - 1) * self.mamba_d_inner)
+            + self.gdn_layers * (
             4 * self.gdn_value_heads * self.gdn_key_dim
             * self.gdn_value_dim + 2 * (self.gdn_conv - 1)
             * self.gdn_channels)
@@ -259,6 +344,19 @@ class ModelConfig:
         vocabulary's rows as sliced, the indexer."""
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd, nh = self.head_dim_, self.num_heads
+        if self.layer_plan:
+            di, ds, r = (self.mamba_d_inner, self.mamba_d_state,
+                         self.mamba_dt_rank)
+            kv, lam = 2 * self.num_kv_heads * hd, 4 * hd + 2 * hd
+            mamba = (h * 2 * di + di * h + di * (r + 2 * ds) + r * di
+                     + di + (self.mamba_d_conv + 1) * di + di * ds + di)
+            own = h * (nh * hd + kv) + nh * hd + kv + nh * hd * h + h + lam
+            cross = 2 * (h * nh * hd) + nh * hd + h + lam
+            block = 4 * h + 3 * h * i           # two LayerNorms, fc1, fc2
+            return (self.mamba_layers * mamba + self.attn_layers * own
+                    + self.kind_layers("gmu") * 2 * h * di
+                    + self.kind_layers("cross") * cross
+                    + self.num_layers * block + v * h + 2 * h)
         E = self.num_experts
         dense = 3 * h * i
         if E:
@@ -316,7 +414,8 @@ class ModelConfig:
         unit-offset RMSNorm, tied embeddings), Gemma-2, Mixtral,
         Qwen2-MoE, GLM-4.7-Flash (``glm4_moe_lite``) and GLM-5
         (``glm_moe_dsa``), both through _glm4_moe_lite, Qwen3-Next
-        (``qwen3_next``, _qwen3_next) and Brumby (``brumby``, _brumby).
+        (``qwen3_next``, _qwen3_next), Brumby (``brumby``, _brumby) and
+        Phi-4-mini-flash (``phi4flash``, _phi4flash).
         Keys the mapping does not know are ignored.
         """
         archs = cfg.get("architectures") or []
@@ -342,6 +441,8 @@ class ModelConfig:
             return _qwen3_next(cfg, name, dtype)
         if model_type == "brumby" or arch == "BrumbyForCausalLM":
             return _brumby(cfg, name, dtype)
+        if model_type == "phi4flash" or arch == "Phi4FlashForCausalLM":
+            return _phi4flash(cfg, name, dtype)
         if not (is_qwen2 or is_gemma or is_gemma2 or is_mixtral
                 or is_qwen2_moe or is_glm_lite
                 or is_llama_like) and (model_type or arch):
@@ -349,7 +450,7 @@ class ModelConfig:
                 f"unsupported model family (model_type={model_type!r}, "
                 f"architecture={arch!r}); supported: llama, mistral, "
                 f"qwen2, gemma, gemma2, mixtral, qwen2_moe, "
-                f"glm4_moe_lite, glm_moe_dsa, qwen3_next, brumby")
+                f"glm4_moe_lite, glm_moe_dsa, qwen3_next, brumby, phi4flash")
         if is_glm_lite:
             return _glm4_moe_lite(cfg, name, dtype)
         if is_qwen2_moe:
@@ -653,6 +754,75 @@ def _brumby(cfg: Dict[str, Any], name: str, dtype: Any) -> ModelConfig:
     )
 
 
+def _phi4flash(cfg: Dict[str, Any], name: str, dtype: Any) -> ModelConfig:
+    """Phi-4-mini-flash (``phi4flash``): SambaY, a decoder-hybrid-
+    decoder (Ren et al., arXiv 2507.06607) with differential attention.
+    The first half of the layers is the SELF-decoder, periods of
+    ``mb_per_layer`` layers of which the first is a Mamba layer and
+    the last attention with its own K/V, windowed
+    (``sliding_window``); the second half, the CROSS-decoder, opens
+    with one more such period whose attention sees every key, and its
+    other periods are a gated memory unit on that period's Mamba
+    layer's pre-gate output and a cross-attention layer on that
+    period's attention layer's K and V. Every
+    block LayerNorm (weight and bias), a fused gate-up MLP, no rotary
+    embedding. ``config.json`` has no key for the Mamba sizes
+    (``d_state`` 16, ``d_conv`` 4, ``expand`` 2, ``dt_rank`` =
+    ceil(hidden / 16): Mamba's defaults) nor for the differential
+    attention's constants: a benchmark's file lists them under
+    ``assumed``, and its ``assumed`` numbers (``mamba_d_state``,
+    ``mamba_d_conv``, ``mamba_expand``, ``mamba_dt_rank``) are read
+    from there. What the tree does not build is refused by name."""
+    family = "phi4flash"
+    layers, per = cfg["num_hidden_layers"], cfg.get("mb_per_layer", 2)
+    if per != 2 or layers % 4 or layers < 8:
+        raise ValueError(
+            f"{family}: mb_per_layer {per} with {layers} layers is not "
+            f"supported (periods of 2: a Mamba layer, an attention "
+            f"layer; two halves of whole periods, two periods at least "
+            f"in the first)")
+    for key, refused in (("tie_word_embeddings", False),
+                         ("mlp_bias", True), ("lm_head_bias", True)):
+        if cfg.get(key, not refused) is refused:
+            raise ValueError(f"{family} with {key} = {refused} is not "
+                             f"supported")
+    if not cfg.get("sliding_window"):
+        raise ValueError(f"{family} without sliding_window is not "
+                         f"supported")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"{family} hidden_act "
+                         f"{cfg['hidden_act']!r} is not supported")
+    heads = cfg["num_attention_heads"]
+    kv_heads = cfg.get("num_key_value_heads", heads)
+    hidden = cfg["hidden_size"]
+    if heads % 2 or kv_heads % 2 or heads % kv_heads or hidden % heads:
+        raise ValueError(
+            f"{family}: {heads} query heads over {kv_heads} key-value "
+            f"heads is not supported (differential attention pairs the "
+            f"heads of both)")
+    assumed = cfg.get("assumed") or {}
+    return ModelConfig(
+        name=name or cfg.get("_name_or_path", "hf-model"),
+        vocab_size=cfg["vocab_size"], hidden_size=hidden,
+        intermediate_size=cfg["intermediate_size"], num_layers=layers,
+        num_heads=heads, num_kv_heads=kv_heads,
+        head_dim=hidden // heads,
+        rms_norm_eps=cfg.get("layer_norm_eps", 1e-5),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        sliding_window=cfg["sliding_window"], tie_word_embeddings=True,
+        attention_bias=True,
+        layer_plan=((("mamba", "swa"), layers // 4),
+                    (("mamba_mem", "full"), 1),
+                    (("gmu", "cross"), layers // 4 - 1)),
+        mamba_d_inner=assumed.get("mamba_expand", 2) * hidden,
+        mamba_d_state=assumed.get("mamba_d_state", 16),
+        mamba_d_conv=assumed.get("mamba_d_conv", 4),
+        mamba_dt_rank=assumed.get("mamba_dt_rank", -(-hidden // 16)),
+        exact_dequant_scale=True,
+        dtype=dtype,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Presets. Dimensions are the publicly documented architecture shapes.
 # ---------------------------------------------------------------------------
@@ -805,6 +975,21 @@ PRESETS: Dict[str, ModelConfig] = {
         intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
         head_dim=16, max_position_embeddings=512, rms_norm_eps=1e-6,
         layer_pattern=("ret",), qk_norm=True,
+    ),
+    # Tiny Phi-4-mini-flash-style decoder-hybrid-decoder for CPU tests
+    # (``phi4flash``): 8 layers = (mamba, window 16) x 2, (mamba that
+    # leaves the memory, full attention) x 1, (gated memory unit,
+    # cross-attention) x 1; 8 / 4 heads of 8 in differential pairs,
+    # a state of 4 a channel, every ratio of the published model
+    "debug-yoco": ModelConfig(
+        name="debug-yoco", vocab_size=512, hidden_size=64,
+        intermediate_size=256, num_layers=8, num_heads=8, num_kv_heads=4,
+        head_dim=8, max_position_embeddings=512, sliding_window=16,
+        tie_word_embeddings=True, attention_bias=True,
+        layer_plan=((("mamba", "swa"), 2), (("mamba_mem", "full"), 1),
+                    (("gmu", "cross"), 1)),
+        mamba_d_inner=128, mamba_d_state=4, mamba_d_conv=4,
+        mamba_dt_rank=4, exact_dequant_scale=True,
     ),
     # GLM-4.7-Flash (glm4_moe_lite, 30B-A3B): latent attention, one
     # leading dense layer of width 10240, 64 sigmoid-routed experts

@@ -321,7 +321,7 @@ def cache_for(cfg, num_blocks: int, block_size: int,
                             jnp.float32),
             norm=jnp.zeros((cfg.ret_layers, num_blocks, F * H // D, D),
                            jnp.float32))
-    if cfg.gdn_layers:
+    if cfg.gdn_layers or cfg.mamba_layers:
         if dtype == jnp.int8:
             raise ValueError(
                 f"{cfg.name}: a model with state pages (layout "
@@ -330,8 +330,18 @@ def cache_for(cfg, num_blocks: int, block_size: int,
         if state_pages < 2:
             raise ValueError("a state pool needs the trash page and at "
                              "least one more (state_pages >= 2)")
+        # the layers that OWN K and V alone: a cross layer reads
+        # another layer's pool layer (cfg.reader_layers read these)
         kv = make_cache(cfg.attn_layers, num_blocks, block_size,
-                        cfg.num_kv_heads, cfg.head_dim_, dtype)
+                        cfg.pool_kv_heads, cfg.pool_head_dim, dtype)
+        if cfg.mamba_layers:
+            return kv._replace(
+                state=jnp.zeros((cfg.mamba_layers, state_pages,
+                                 cfg.mamba_d_state, cfg.mamba_d_inner),
+                                jnp.float32),
+                conv=jnp.zeros((cfg.mamba_layers, state_pages,
+                                cfg.mamba_d_conv - 1, cfg.mamba_d_inner),
+                               dtype))
         return kv._replace(
             state=jnp.zeros((cfg.gdn_layers, state_pages,
                              cfg.gdn_value_heads, cfg.gdn_key_dim,

@@ -29,7 +29,7 @@ from production_stack_tpu.models import kv as kv_pool
 from production_stack_tpu.models import lora, quant
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.models.kv import KVCache
-from production_stack_tpu.ops import gdn, moe, retention
+from production_stack_tpu.ops import gdn, mamba, moe, retention
 from production_stack_tpu.ops.attention import causal_attention
 from production_stack_tpu.ops.norms import rms_norm
 from production_stack_tpu.ops.rope import apply_rope, rope_table
@@ -89,6 +89,8 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         return _init_params_mla(cfg, key, w)
     if cfg.gdn_layers:
         return _init_params_hybrid(cfg, key, w)
+    if cfg.layer_plan:
+        return _init_params_plan(cfg, key, w)
     norm_init = jnp.zeros if cfg.rms_norm_offset else jnp.ones
     E = cfg.num_experts
     params: Params = {
@@ -308,6 +310,380 @@ def _init_params_hybrid(cfg: ModelConfig, key: jax.Array, w) -> Params:
         },
     }
     return params
+
+
+# the group of stacked parameters each mixer of a layer plan reads its
+# own from (cfg.layer_plan), beside ``layers`` (what every block has)
+PLAN_GROUPS = {"mamba": "mamba_layers", "mamba_mem": "mamba_layers",
+               "swa": "diff_layers", "full": "diff_layers",
+               "gmu": "gmu_layers", "cross": "cross_layers"}
+
+
+def _init_params_plan(cfg: ModelConfig, key: jax.Array, w) -> Params:
+    """init_params for a decoder-hybrid-decoder (Phi-4-mini-flash:
+    cfg.layer_plan). ``layers`` holds what every block has (two
+    LayerNorms with biases, the fused gate-up ``fc1`` and ``fc2``) for
+    all num_layers; ``mamba_layers``, ``diff_layers`` (differential
+    attention with its own K/V: the window layers and the full one),
+    ``gmu_layers`` and ``cross_layers`` the mixers, each in the model's
+    order. ``conv`` is [taps, channels] with the last tap on the token
+    itself; ``A_log`` [state, channels] = log(1..state) a channel and
+    ``D`` one (Mamba's S4D-real initialisation); ``dt_bias`` the
+    inverse softplus of dt log-uniform in [0.001, 0.1]; the lambda
+    vectors normal(0, 0.1); norms one, biases zero; the head is the
+    embedding."""
+    h, v, i, L = (cfg.hidden_size, cfg.vocab_size, cfg.intermediate_size,
+                  cfg.num_layers)
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    di, ds, r = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+    Lm, La = cfg.mamba_layers, cfg.attn_layers
+    Lg, Lc = cfg.kind_layers("gmu"), cfg.kind_layers("cross")
+    keys = iter(jax.random.split(key, 32))
+    f32 = jnp.float32
+
+    def lambdas(n):
+        return {name: 0.1 * jax.random.normal(next(keys), (n, hd), f32)
+                for name in ("lambda_q1", "lambda_k1", "lambda_q2",
+                             "lambda_k2")}
+
+    dt = jnp.exp(jax.random.uniform(next(keys), (Lm, di), f32)
+                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    return {
+        "embed": w(next(keys), (v, h), "embed"),
+        "final_norm": jnp.ones((h,), cfg.dtype),
+        "final_norm_bias": jnp.zeros((h,), cfg.dtype),
+        "layers": {
+            "attn_norm": jnp.ones((L, h), cfg.dtype),
+            "attn_norm_bias": jnp.zeros((L, h), cfg.dtype),
+            "mlp_norm": jnp.ones((L, h), cfg.dtype),
+            "mlp_norm_bias": jnp.zeros((L, h), cfg.dtype),
+            "fc1": w(next(keys), (L, h, 2 * i), "layers", "fc1"),
+            "fc2": w(next(keys), (L, i, h), "layers", "fc2"),
+        },
+        "mamba_layers": {
+            "in_proj": w(next(keys), (Lm, h, 2 * di), "mamba_layers",
+                         "in_proj"),
+            "conv": w(next(keys), (Lm, cfg.mamba_d_conv, di),
+                      "mamba_layers", "conv"),
+            "conv_bias": jnp.zeros((Lm, di), cfg.dtype),
+            "x_proj": w(next(keys), (Lm, di, r + 2 * ds), "mamba_layers",
+                        "x_proj"),
+            "dt_proj": w(next(keys), (Lm, r, di), "mamba_layers",
+                         "dt_proj"),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                1, ds + 1, dtype=f32))[None, :, None], (Lm, ds, di)),
+            "D": jnp.ones((Lm, di), f32),
+            "out_proj": w(next(keys), (Lm, di, h), "mamba_layers",
+                          "out_proj"),
+        },
+        "diff_layers": {
+            "qkv": w(next(keys), (La, h, (nh + 2 * nkv) * hd),
+                     "diff_layers", "qkv"),
+            "qkv_bias": jnp.zeros((La, (nh + 2 * nkv) * hd), cfg.dtype),
+            "o": w(next(keys), (La, nh * hd, h), "diff_layers", "o"),
+            "o_bias": jnp.zeros((La, h), cfg.dtype),
+            "subln": jnp.ones((La, 2 * hd), cfg.dtype),
+            **lambdas(La),
+        },
+        "gmu_layers": {
+            "in_proj": w(next(keys), (Lg, h, di), "gmu_layers", "in_proj"),
+            "out_proj": w(next(keys), (Lg, di, h), "gmu_layers",
+                          "out_proj"),
+        },
+        "cross_layers": {
+            "q": w(next(keys), (Lc, h, nh * hd), "cross_layers", "q"),
+            "q_bias": jnp.zeros((Lc, nh * hd), cfg.dtype),
+            "o": w(next(keys), (Lc, nh * hd, h), "cross_layers", "o"),
+            "o_bias": jnp.zeros((Lc, h), cfg.dtype),
+            "subln": jnp.ones((Lc, 2 * hd), cfg.dtype),
+            **lambdas(Lc),
+        },
+    }
+
+
+def _mamba_mixer(cfg: ModelConfig, hidden, lp: Params, state, state_ids,
+                 starts, token_valid, state_layer):
+    """A Mamba mixer (ops/mamba.py) on the normed input ``hidden``
+    [B,T,H] -> (the mixer's output [B,T,H], its pre-gate ``y`` [B,T,Di]
+    with the skip term, the state pools). state = the WHOLE pools (the
+    float32 states [Lm,P,N,Di], the convolutions' inputs
+    [Lm,P,taps-1,Di]), of which the rows' pages ``state_ids`` [B] of
+    layer ``state_layer`` are read and written in place, as
+    _gdn_layer's: a row none of whose positions is real names the trash
+    page 0; positions that are not real trail the chunk and advance
+    nothing (dt = 0; the convolution keeps its last REAL inputs); a row
+    whose first position is 0 starts from a zero state. Scopes
+    mamba_proj, mamba_conv, mamba_chunk_scan / mamba_recurrent_step
+    (ops/mamba.selective_scan), mamba_gate, mamba_out_proj."""
+    B, T, _ = hidden.shape
+    di, ds, r = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+    hs, conv = state
+    ids = jnp.where(jnp.any(token_valid, axis=1), state_ids, 0)
+    fresh = starts == 0
+    f32 = jnp.float32
+    with jax.named_scope("mamba_proj"):
+        xz = quant.dequant_matmul(hidden, lp["in_proj"], dtype=f32,
+                                  exact_scale=True)
+        xs, z = xz[..., :di], xz[..., di:]
+    with jax.named_scope("mamba_conv"):
+        prev = jnp.where(fresh[:, None, None], 0, conv[state_layer, ids])
+        xs, new_conv = gdn.causal_conv(
+            xs, lp["conv"], prev,
+            jnp.sum(token_valid, axis=1, dtype=jnp.int32),
+            bias=lp["conv_bias"])
+        conv = conv.at[state_layer, ids].set(new_conv)
+    with jax.named_scope("mamba_proj"):
+        dbc = jnp.einsum("btd,dj->btj", xs.astype(hidden.dtype),
+                         lp["x_proj"], preferred_element_type=f32)
+        dt = jax.nn.softplus(jnp.einsum(
+            "btr,rd->btd", dbc[..., :r].astype(hidden.dtype), lp["dt_proj"],
+            preferred_element_type=f32) + lp["dt_bias"])
+        dt = jnp.where(token_valid[..., None], dt, 0.0)
+    y, hs = mamba.selective_scan(
+        xs, dt, dbc[..., r:r + ds], dbc[..., r + ds:],
+        -jnp.exp(lp["A_log"]), hs, ids, state_layer, fresh)
+    with jax.named_scope("mamba_gate"):
+        y = y + lp["D"] * xs
+        gated = (y * jax.nn.silu(z)).astype(hidden.dtype)
+    with jax.named_scope("mamba_out_proj"):
+        out = quant.dequant_matmul(gated, lp["out_proj"], exact_scale=True)
+    return out, y.astype(hidden.dtype), (hs, conv)
+
+
+def _diff_attention(cfg: ModelConfig, hidden, lp: Params, kv, kv_len,
+                    token_valid, block_tables, starts, positions, mesh,
+                    layer, kv_layer, window, appends: bool):
+    """Differential attention (Ye et al., arXiv 2410.05258) on the
+    normed input ``hidden`` [B,T,H] -> (the heads' outputs [B,T,nh*hd],
+    the pool). Query heads (2a, 2a+1) are q1_a, q2_a; key heads (2c,
+    2c+1) are k1_c, k2_c and value heads (2c, 2c+1) ONE value V_c of
+    twice the width; pair a reads c = a // 2:
+
+        o_a = attn(q1_a, k1_c, V_c) - lambda attn(q2_a, k2_c, V_c)
+
+    then RMSNorm over the pair's 2 hd values times (1 - lambda_init).
+    The pool holds a token's K as [k1_c | k2_c] and its V as V_c, heads
+    of 2 hd, exactly as the projection's columns lie (cfg.
+    pool_kv_heads, cfg.pool_head_dim): with q1 padded to [q1 | 0] and
+    q2 to [0 | q2] both softmaxes are PLAIN grouped-query calls of one
+    paged kernel (four query heads a pool head), and the difference is
+    taken after the call. ``appends``: the layer has K/V of its own
+    (``qkv``) and writes them to pool layer ``kv_layer`` before it
+    reads; else (a cross layer, ``q`` alone) it reads that pool layer,
+    another layer's, and appends nothing. No rotary embedding. Scope
+    diff_attention (with qkv_proj, kv_write, attention inside)."""
+    B, T, _ = hidden.shape
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    f32 = jnp.float32
+
+    def proj(h, name):
+        return quant.dequant_matmul(h, lp[name], exact_scale=True) \
+            + lp[name + "_bias"]
+    with jax.named_scope("qkv_proj"):
+        if appends:
+            qkv = proj(hidden, "qkv")
+            q = qkv[..., :nh * hd]
+            k = qkv[..., nh * hd:(nh + nkv) * hd].reshape(
+                B, T, nkv // 2, 2 * hd)
+            v = qkv[..., (nh + nkv) * hd:].reshape(B, T, nkv // 2, 2 * hd)
+        else:
+            q = proj(hidden, "q")
+        q = q.reshape(B, T, nh // 2, 2, hd)
+        zero = jnp.zeros_like(q[..., 0, :])
+        q = jnp.stack([jnp.concatenate([q[..., 0, :], zero], -1),
+                       jnp.concatenate([zero, q[..., 1, :]], -1)],
+                      axis=3).reshape(B, T, nh, 2 * hd)
+    if appends:
+        with jax.named_scope("kv_write"):
+            kv = kv_pool.append(kv, k, v, block_tables, starts,
+                                token_valid, kv_layer)
+    with jax.named_scope("attention"):
+        tables, first, at = block_tables, starts, positions
+        if window:
+            # a window layer reads the blocks its window and its chunk
+            # touch and no other: with no rotary embedding only the
+            # DISTANCE of a key matters, so the row's table is cut to
+            # those blocks and its positions counted from the first of
+            # them. The kernels skip a block outside the window anyway
+            # (ops/pallas_paged.py), but walk a grid step for it: 256
+            # blocks of a 16k bucket against 41
+            Bs, MB = kv[0].shape[-2], block_tables.shape[1]
+            few = -(-(window - 1 + T) // Bs) + 1
+            # whole panels of the prefill kernel (512 keys a grid step:
+            # ops/pallas_paged.prefill_tiles takes the widest panel that
+            # DIVIDES the bucket, and 41 blocks divide by nothing: a
+            # block a step, 5.5 ms a layer where 0.7 would do; PERF.md,
+            # PR 50)
+            panel = max(1, 512 // Bs)
+            few = -(-few // panel) * panel
+            if few < (MB if kv_len is None else min(-(-kv_len // Bs), MB)):
+                lo = jnp.maximum(starts - (window - 1), 0) // Bs
+                tables = jnp.take_along_axis(
+                    block_tables,
+                    jnp.clip(lo[:, None] + jnp.arange(few), 0, MB - 1),
+                    axis=1)
+                first, at = starts - lo * Bs, positions - (lo * Bs)[:, None]
+                kv_len = few * Bs
+        o = kv_pool.attend(q, kv, tables, first, at, kv_len, kv_layer,
+                           window=window, scale=hd ** -0.5, softcap=None,
+                           mesh=mesh)
+    with jax.named_scope("diff_lambda"):
+        init = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, f32))
+        lam = (jnp.exp(jnp.sum(lp["lambda_q1"] * lp["lambda_k1"]))
+               - jnp.exp(jnp.sum(lp["lambda_q2"] * lp["lambda_k2"])) + init)
+        o = o.astype(f32).reshape(B, T, nh // 2, 2, 2 * hd)
+        o = o[..., 0, :] - lam * o[..., 1, :]
+        o = rms_norm(o, lp["subln"], cfg.rms_norm_eps) * (1.0 - init)
+    return o.astype(hidden.dtype).reshape(B, T, nh * hd), kv
+
+
+def _plan_layer(cfg: ModelConfig, kind: str, x, lp: Params, pool, spool,
+                mem, *, positions, starts, state_ids, token_valid,
+                block_tables, kv_len, mesh, layer, group_layer,
+                shared_kv_layer):
+    """One block of a decoder-hybrid-decoder (cfg.layer_plan): x +=
+    mixer(LN1(x)); x += fc2(up * silu(gate)), gate, up = split(fc1(
+    LN2(x))). ``kind`` (static) names the mixer, ``lp`` the block's own
+    parameters and its mixer's, ``layer`` its index in the model and
+    ``group_layer`` among its kind's: a Mamba layer's in the state
+    pool, an attention layer's in the K/V pool; a cross layer reads
+    pool layer ``shared_kv_layer`` (static: the last "full" layer's).
+    ``mem`` is the memory a "mamba_mem" layer leaves and a "gmu" layer
+    reads. -> (x', pool, spool, mem)."""
+    eps = cfg.rms_norm_eps
+    with jax.named_scope("attn_norm"):
+        hidden = _layer_norm(x, lp["attn_norm"], lp["attn_norm_bias"], eps)
+    if kind in ("mamba", "mamba_mem"):
+        out, y, spool = _mamba_mixer(cfg, hidden, lp, spool, state_ids,
+                                     starts, token_valid, group_layer)
+        if kind == "mamba_mem":
+            mem = y
+    elif kind == "gmu":
+        with jax.named_scope("gmu"):
+            gate = quant.dequant_matmul(hidden, lp["in_proj"],
+                                        exact_scale=True)
+            out = quant.dequant_matmul(
+                (jax.nn.silu(gate.astype(jnp.float32))
+                 * mem.astype(jnp.float32)).astype(x.dtype),
+                lp["out_proj"], exact_scale=True)
+    else:
+        own = kind != "cross"
+        with jax.named_scope("diff_attention"):
+            attn, pool = _diff_attention(
+                cfg, hidden, lp, pool, kv_len, token_valid, block_tables,
+                starts, positions, mesh, layer,
+                group_layer if own else shared_kv_layer,
+                cfg.sliding_window if kind == "swa" else None, own)
+            with jax.named_scope("o_proj"):
+                out = quant.dequant_matmul(
+                    attn, lp["o"], exact_scale=True) + lp["o_bias"]
+    x = x + out
+    with jax.named_scope("mlp_norm"):
+        hidden = _layer_norm(x, lp["mlp_norm"], lp["mlp_norm_bias"], eps)
+    with jax.named_scope("mlp"):
+        gu = quant.dequant_matmul(hidden, lp["fc1"], exact_scale=True)
+        half = gu.shape[-1] // 2
+        x = x + quant.dequant_matmul(
+            gu[..., half:] * jax.nn.silu(gu[..., :half]), lp["fc2"],
+            exact_scale=True)
+    return x, pool, spool, mem
+
+
+def _run_plan(params: Params, cfg: ModelConfig, x, positions, cache,
+              block_tables, state_ids, token_valid, kv_len, mesh, last,
+              finishing):
+    """The layer loop of a decoder-hybrid-decoder: cfg.layer_plan's
+    runs in order, each a scan over its periods (a run of one period is
+    called as it stands), the pools in the carry as in ``forward``.
+    ``last`` [B] (a prefill chunk): the runs from the first that reads
+    the memory or another layer's K/V on (cfg.self_layers) see ONE
+    position a row, ``last[b]``, of the residual stream and the memory,
+    and, with ``finishing`` (a traced bool: some row's prompt ends in
+    this chunk), do not run at all where it is False (they write no
+    cache: what they would have returned is read by nobody); None:
+    every run sees every position. -> (x [B,T or 1,H], cache')."""
+    B, T, _ = x.shape
+    if token_valid is None:
+        token_valid = jnp.ones((B, T), bool)
+    # a cross layer reads the pool layer of the LAST layer that sees
+    # every key, among the layers that own their K/V
+    own = [k for period, reps in cfg.plan_ for _ in range(reps)
+           for k in period if k in ("swa", "full")]
+    shared = len(own) - 1 - own[::-1].index("full") if "full" in own else 0
+
+    def go(runs, at, x, pool, spool, mem, positions, token_valid):
+        base, seen = at
+        starts = positions[:, 0]
+        for period, repeats in runs:
+            if "mamba_mem" in period and mem is None and repeats > 1:
+                mem = jnp.zeros(x.shape[:2] + (cfg.mamba_d_inner,),
+                                x.dtype)
+            per = {g: sum(PLAN_GROUPS[k] == g for k in period)
+                   for g in seen}
+
+            def body(carry, p, period=period, base=base, seen=seen,
+                     per=per):
+                h, pool, spool, mem = carry
+                for j, kind in enumerate(period):
+                    group = PLAN_GROUPS[kind]
+                    layer = base + p * len(period) + j
+                    among = (seen[group] + p * per[group] + sum(
+                        PLAN_GROUPS[k] == group for k in period[:j]))
+                    # each reads its own row of the stacks in place
+                    # (closed over: ``forward``'s note on the hybrid)
+                    lp = jax.tree.map(lambda a: a[layer],
+                                      params["layers"])
+                    lp.update(jax.tree.map(lambda a: a[among],
+                                           params[group]))
+                    h, pool, spool, mem = _plan_layer(
+                        cfg, kind, h, lp, pool, spool, mem,
+                        positions=positions, starts=starts,
+                        state_ids=state_ids, token_valid=token_valid,
+                        block_tables=block_tables, kv_len=kv_len,
+                        mesh=mesh, layer=layer, group_layer=among,
+                        shared_kv_layer=shared)
+                return (h, pool, spool, mem), None
+
+            carry = (x, pool, spool, mem)
+            if repeats == 1:
+                carry, _ = body(carry, 0)
+            else:
+                carry, _ = jax.lax.scan(body, carry, jnp.arange(repeats))
+            x, pool, spool, mem = carry
+            base += len(period) * repeats
+            seen = {g: seen[g] + per[g] * repeats for g in seen}
+        return x, pool, spool, mem, (base, seen)
+
+    plan = cfg.plan_
+    cut = next((n for n, (period, _) in enumerate(plan)
+                if "gmu" in period or "cross" in period), len(plan))
+    if last is None:
+        cut = len(plan)
+    x, pool, spool, mem, at = go(
+        plan[:cut], (0, {g: 0 for g in PLAN_GROUPS.values()}), x,
+        cache.carried(), cache.state_carried(), None, positions,
+        token_valid)
+    if cut < len(plan):
+        # the second depth: the row's last prompt position alone
+        def at_last(a):
+            return jnp.take_along_axis(
+                a, last.reshape((B,) + (1,) * (a.ndim - 1)), axis=1)
+        x, positions = at_last(x), at_last(positions)
+        mem = None if mem is None else at_last(mem)
+        one = jnp.ones((B, 1), bool)
+        reads_only = all(k in ("gmu", "cross") for period, _ in plan[cut:]
+                         for k in period)
+        if finishing is not None and reads_only:
+            x = jax.lax.cond(
+                finishing,
+                lambda x, mem: go(plan[cut:], at, x, pool, spool, mem,
+                                  positions, one)[0],
+                lambda x, mem: x, x, mem)
+        else:
+            x, pool, spool, mem, _ = go(plan[cut:], at, x, pool, spool,
+                                        mem, positions, one)
+    return x, cache.carried_back(pool, spool)
 
 
 def _gdn_layer(cfg: ModelConfig, x, lp: Params, state, state_ids, starts,
@@ -852,6 +1228,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             lora_scaling: float = 1.0,
             token_valid: Optional[jnp.ndarray] = None,
             mesh=None, moe_capacity_tokens: Optional[int] = None,
+            last: Optional[jnp.ndarray] = None,
+            finishing: Optional[jnp.ndarray] = None,
             ) -> Tuple[jnp.ndarray, KVCache, Optional[jnp.ndarray]]:
     """Incremental forward. tokens/positions [B,T] -> (logits fp32
     [B,T,V], cache', the experts' work).
@@ -879,13 +1257,22 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     routed to where the expert matmuls walk that list: ops/moe
     list_path, decode steps, and grouped_path, prefill chunks), and
     ``expert_rows``, the rows those experts multiplied.
+    last [B] (a prefill chunk of a model whose plan has two depths,
+    cfg.self_layers < num_layers: a decoder-hybrid-decoder): the index
+    in T of each row's last real position. The layers from
+    cfg.self_layers on, the final norm and the head then run on that
+    position alone and the logits are [B,1,V]; with ``finishing`` (a
+    traced bool: some row's prompt ends in this chunk) those layers do
+    not run at all where it is False, and the logits mean nothing.
+    None: every layer on every position.
     """
     return forward_in_window(
         params, cfg, tokens, positions, cache, None,
         block_tables=block_tables, rope=rope, kv_len=kv_len,
         lora_params=lora_params, adapter_ids=adapter_ids,
         lora_scaling=lora_scaling, token_valid=token_valid, mesh=mesh,
-        moe_capacity_tokens=moe_capacity_tokens)[:3]
+        moe_capacity_tokens=moe_capacity_tokens, last=last,
+        finishing=finishing)[:3]
 
 
 def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -897,7 +1284,9 @@ def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                       adapter_ids: Optional[jnp.ndarray] = None,
                       lora_scaling: float = 1.0,
                       token_valid: Optional[jnp.ndarray] = None,
-                      mesh=None, moe_capacity_tokens: Optional[int] = None):
+                      mesh=None, moe_capacity_tokens: Optional[int] = None,
+                      last: Optional[jnp.ndarray] = None,
+                      finishing: Optional[jnp.ndarray] = None):
     """``forward`` as one step of a decode window: ``window`` is what
     ``open_window`` gave or the step before returned. -> (logits,
     cache', the experts' work, window'). With a window the power
@@ -908,7 +1297,8 @@ def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         rope = rope_table(cfg.max_position_embeddings, cfg.rope_dim_,
                           cfg.rope_theta, scaling=cfg.rope_scaling)
     if lora_params is not None and (cfg.mla or cfg.first_dense_layers
-                                    or cfg.layer_pattern):
+                                    or cfg.layer_pattern
+                                    or cfg.layer_plan):
         raise ValueError(
             "LoRA adapters are not supported on a latent-attention "
             "model, one with leading dense layers or one with two kinds "
@@ -932,6 +1322,17 @@ def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         block_tables, bool(cfg.state_layers))
     with jax.named_scope("embed"):
         x = _embed(params, cfg, tokens)
+    if cfg.layer_plan:
+        # several runs of periods, LayerNorms, no rotary embedding
+        with jax.named_scope("layers"):
+            x, cache = _run_plan(params, cfg, x, positions, cache,
+                                 block_tables, state_ids, token_valid,
+                                 kv_len, mesh, last, finishing)
+        with jax.named_scope("final_norm"):
+            x = _layer_norm(x, params["final_norm"],
+                            params["final_norm_bias"], cfg.rms_norm_eps)
+        with jax.named_scope("lm_head"):
+            return _lm_head(params, cfg, x), cache, None, window
     # the scan's unit is one PERIOD of the layer pattern (cfg.pattern_):
     # one attention layer for every model but the hybrid, whose period
     # runs its Gated DeltaNet layers and then its attention layer
@@ -1050,7 +1451,7 @@ def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     token_valid [B,T] marks real tokens in right-padded batches — on
     MoE models padding must not compete for expert capacity.
     """
-    if cfg.layer_pattern:
+    if cfg.layer_pattern or cfg.layer_plan:
         raise ValueError(
             f"{cfg.name}: a forward without caches (encode, "
             f"forward_train: embeddings, echoed prompt log-"

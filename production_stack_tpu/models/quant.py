@@ -39,10 +39,18 @@ _SKIP_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_mlp_norm",
                "ba", "conv", "A_log", "dt_bias", "gdn_norm", "q_norm",
                "k_norm",
                # a power retention mixer's gate a key-value head
-               "ret_gate", "ret_gate_bias")
+               "ret_gate", "ret_gate_bias",
+               # a decoder-hybrid-decoder's LayerNorm biases; a Mamba
+               # mixer's small projections, skip term and bias; the
+               # differential attention's biases, lambdas and norm
+               "attn_norm_bias", "mlp_norm_bias", "conv_bias", "x_proj",
+               "dt_proj", "D", "qkv_bias", "o_bias", "subln", "lambda_q1",
+               "lambda_k1", "lambda_q2", "lambda_k2")
 # the groups of stacked layers a tree may hold: the scanned layers and
 # a layer plan's leading dense ones (models/llama.py)
-_LAYER_GROUPS = ("layers", "dense_layers", "gdn_layers", "attn_layers")
+_LAYER_GROUPS = ("layers", "dense_layers", "gdn_layers", "attn_layers",
+                 "mamba_layers", "diff_layers", "gmu_layers",
+                 "cross_layers")
 
 
 def quantize_tensor(w: jnp.ndarray) -> Dict[str, jnp.ndarray]:

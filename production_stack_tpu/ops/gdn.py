@@ -442,9 +442,10 @@ def mix(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
 
 
 def causal_conv(x: jnp.ndarray, weight: jnp.ndarray, prev: jnp.ndarray,
-                valid_len: jnp.ndarray):
-    """The depthwise causal convolution of a Gated DeltaNet layer, then
-    SiLU. x [B, T, Ch] the chunk's inputs, weight [taps, Ch] (the last
+                valid_len: jnp.ndarray, bias=None):
+    """The depthwise causal convolution of a Gated DeltaNet layer (or,
+    with ``bias`` [Ch], of a Mamba layer), then SiLU. x [B, T, Ch] the
+    chunk's inputs, weight [taps, Ch] (the last
     tap multiplies the token itself), prev [B, taps - 1, Ch] the inputs
     before the chunk (the convolution's state), valid_len [B] how many
     of the T positions are real (they lead the chunk) -> (y [B, T, Ch],
@@ -454,6 +455,8 @@ def causal_conv(x: jnp.ndarray, weight: jnp.ndarray, prev: jnp.ndarray,
     full = jnp.concatenate([prev.astype(x.dtype), x], axis=1)
     y = sum(full[:, j:j + T].astype(jnp.float32)
             * weight[j].astype(jnp.float32) for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     new = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(
         f, n, taps - 1, axis=0))(full, valid_len)
     return jax.nn.silu(y).astype(x.dtype), new.astype(prev.dtype)

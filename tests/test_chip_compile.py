@@ -1377,3 +1377,95 @@ def test_brumby_decode_window_compiles_with_state_pages_alone(
     # read-only steps and the fold: no copy of its shape anywhere
     assert not re.findall(r"f32\[2,17,8,8256,128\]\S* copy\(", hlo)
     _fits(compiled, "brumby cut decode window")
+
+
+def _phi4flash_runner(topo, monkeypatch):
+    """The runner skeleton at the benchmark's Phi-4-mini-flash file,
+    whole (three runs of periods: the scans make depth free), 8 slots
+    of 16384 tokens over the nine-layer K/V pool and 9 state pages
+    (the cell's geometry)."""
+    import json
+    from chipbench.engine_child import model_config
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.models import config as model_configs
+    from production_stack_tpu.models.kv import cache_for
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "phi4-mini-flash-int8.json")) as f:
+        conf = json.load(f)
+    mcfg = model_config(conf, "phi4flash-whole")
+    monkeypatch.setitem(model_configs.PRESETS, "phi4flash-whole", mcfg)
+    monkeypatch.setattr(
+        "production_stack_tpu.models.kv.cache_for",
+        lambda cfg, n, bs, state_pages=0, **kw: cache_for(
+            cfg, n, bs, state_pages=9, **kw))
+    runner, params, _, rep = _runner_shapes(
+        topo, 1, kv_blocks=2049, model="phi4flash-whole")
+    runner.engine_cfg = EngineConfig(
+        model="phi4flash-whole", quantization="int8", max_num_seqs=8,
+        max_model_len=16384, kv_pool_tokens=131072, prefill_chunk=2048)
+    cache = jax.tree.map(
+        lambda x: rep(x.shape, x.dtype),
+        jax.eval_shape(partial(cache_for, mcfg, 2049, 64, state_pages=9)))
+    return runner, params, cache, rep
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk"])
+def test_plan_step_program_compiles_at_phi4flash_widths(
+        topo, tpu_branches, monkeypatch, program):
+    """One decode window of 8 rows and one 2048-token prefill chunk of
+    one row at the longest kv bucket of the WHOLE model (32 layers in
+    three runs of periods), compiled for the described v5e: the scan is
+    ops/mamba.py's kernel of the forward's kind, the attention the
+    paged kernels' plain K/V case at 4 query heads a pool head of 128
+    (differential attention's pairs: models/llama._diff_attention), a
+    window of 512 in eight layers; a prefill chunk calls the DECODE
+    kernel too, in its seven cross layers at one position a row;
+    neither the nine-layer K/V pool nor the state pool is copied or
+    sliced (both aliased to the result, through the ``finishing``
+    conditional too); the program fits the chip beside 3.85 GB of
+    weights and the 6 GB pool. ~40 s each: 32 layers' worth of three
+    scan bodies at 2560 wide."""
+    import re
+    N = 2049
+    runner, params, cache, rep = _phi4flash_runner(topo, monkeypatch)
+    B = runner.engine_cfg.max_num_seqs
+    a = _step_args(runner, rep, B)
+    tables = rep(runner.table_shape, jnp.int32)
+    assert runner.table_shape == (8, 257)
+    small = (a["sampling"], a["key"], a["guide_next"], a["guide_id"],
+             a["guide_state"], a["counts"], a["seen"])
+    if program == "decode_window":
+        fn = jax.jit(partial(runner._decode_impl, steps=8, kv_len=16384,
+                             greedy=True), donate_argnums=(1,))
+        compiled = fn.lower(params, cache, tables, rep((B,), jnp.int32),
+                            rep((B,), jnp.int32), *small).compile()
+        want = {"paged_decode_attention", "mamba_recurrent_step"}
+    else:
+        fn = jax.jit(partial(runner._prefill_impl, kv_len=16384),
+                     donate_argnums=(1,))
+        compiled = fn.lower(params, cache, tables,
+                            rep((1,), jnp.int32), rep((1, 2048), jnp.int32),
+                            rep((1,), jnp.int32), rep((1,), jnp.int32),
+                            *small, rep((), jnp.bool_)).compile()
+        want = {"paged_attention", "paged_decode_attention",
+                "mamba_chunk_scan"}
+    hlo = compiled.as_text()
+    calls = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]+)[\w.\-]* = [^=]*? custom-call\(", hlo)}
+    assert {c for c in calls if c.startswith(("paged", "mamba"))} == want
+    assert runner._attention_path(
+        1 if program == "decode_window" else 2048, None, 16384) \
+        == ("pallas_paged_decode" if program == "decode_window"
+            else "pallas_paged")
+    pools = re.compile(
+        r"([\w.\-]+) = \(?\w+\[(?:9,{n},10,{bs},128|9,9,16,5120)\]\S* "
+        r"([\w\-]+)\(".format(n=N, bs=BS))
+    moved = [m.group(1) + ": " + m.group(2)
+             for m in map(pools.search, hlo.splitlines()) if m
+             and re.search(r"copy|dynamic.slice|dynamic.update.slice",
+                           m.group(1) + " " + m.group(2))]
+    assert not moved, moved
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= 2 * 9 * N * 10 * BS * 128 * 2 + 9 * 9 * 16 * 5120 * 4)
+    _fits(compiled, f"phi4flash whole {program}")

@@ -70,16 +70,27 @@ def test_hf_config_parses_sliding_window():
     assert cfg.sliding_window is None
 
 
-@pytest.mark.parametrize("T", [1, 5, 48])
+@pytest.mark.parametrize("T,pairs", [
+    pytest.param(1, False, id="1"), pytest.param(5, False, id="5"),
+    pytest.param(48, False, id="48"), pytest.param(1, True, id="1-pairs"),
+    pytest.param(5, True, id="5-pairs"),
+    pytest.param(48, True, id="48-pairs")])
 @WHOLE
-def test_paged_kernels_windowed_parity(T, layer):
+def test_paged_kernels_windowed_parity(T, pairs, layer):
     """Both pallas kernels with a window (interpret, CPU) match the
-    windowed jnp reference through shuffled tables."""
+    windowed jnp reference through shuffled tables. ``pairs``: the
+    shapes differential attention serves them at (Phi-4-mini-flash's,
+    scaled down: models/llama._diff_attention): a pool head holds
+    [k1 | k2], keys of hd side by side, and ONE value of 2 hd; four
+    query heads a pool head, q1 padded to [q1 | 0] and q2 to [0 | q2];
+    the reference attends each query head over ITS key of hd and the
+    value of 2 hd, two query groups a key."""
     from production_stack_tpu.ops.attention import attention_with_cache
     from production_stack_tpu.ops.pallas_paged import (
         paged_attention, paged_decode_attention)
 
-    B, Hkv, G, Bs, D, W = 2, 2, 2, 16, 32, 24
+    B, Hkv, G, Bs, D, W = (2, 2, 4, 16, 64, 24) if pairs \
+        else (2, 2, 2, 16, 32, 24)
     H = Hkv * G
     lens = [70, 40]
     key = jax.random.PRNGKey(T)
@@ -105,11 +116,25 @@ def test_paged_kernels_windowed_parity(T, layer):
 
     k_att = gather_view(k_pool, tables, nb)
     v_att = gather_view(v_pool, tables, nb)
-    want = attention_with_cache(q, k_att, v_att, positions,
-                                sliding_window=W)
+    scale = D ** -0.5
+    if pairs:
+        hd, scale = D // 2, (D // 2) ** -0.5
+        small = q[..., :hd]             # the heads' own queries of hd
+        half = (jnp.arange(H) % 2)[:, None] == jnp.arange(2)[None, :]
+        q = (small[..., None, :] * half[:, :, None]).reshape(B, T, H, D)
+        # query head h reads key (h % 2) of pool head h // G
+        k_own = jnp.stack(
+            [k_att[:, :, h // G, (h % 2) * hd:(h % 2 + 1) * hd]
+             for h in range(H)], axis=2)
+        want = attention_with_cache(
+            small, k_own, jnp.repeat(v_att, G, axis=2), positions,
+            scale=scale, sliding_window=W)
+    else:
+        want = attention_with_cache(q, k_att, v_att, positions,
+                                    sliding_window=W)
     fn = paged_decode_attention if T <= 8 else paged_attention
     got = _call(fn, q, k_pool, v_pool, tables, starts, nb=nb, window=W,
-                interpret=True, layer=layer)
+                interpret=True, layer=layer, scale=scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
