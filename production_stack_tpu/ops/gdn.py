@@ -31,23 +31,30 @@ carries as it carries the K/V pool:
         L[i, j] = beta_i exp(G_i - G_j) (k_i . k_j),  j < i
 
     (the WY / UT transform: ``T = (I + L)^-1`` by forward substitution
-    in blocks, ``u = T (beta V)``, ``w = T (beta exp(G) K)``,
-    ``D = u - w S_0``), made for every chunk at once under the scope
-    ``gdn_chunk_prep``: on the TPU the substitution of the diagonal
-    blocks is the kernel ``gdn_chunk_solve`` (a lane tile of blocks a
-    grid step, read and written once, its fifteen row updates on the
-    tile in VMEM); the products around it (L itself, the
-    merges of neighbouring blocks, ``u``, ``w``) are XLA's. Between
-    chunks the state is carried: on the TPU the kernel
-    ``gdn_chunk_scan``, a grid step a (row, head, chunk) with the head's
-    matrix in VMEM from the page's copy-in at the first chunk to its
-    copy-back at the last:
+    in blocks of 16, neighbours merged level by level, ``u = T (beta
+    V)``, ``w = T (beta exp(G) K)``, ``D = u - w S_0``), and between
+    chunks the state is carried:
 
         D = u - w S;  o = (q exp(G)) S + (Q K^T . decay) D
         S = exp(G_C) S + (k exp(G_C - G))^T D
 
-    bfloat16 operands (as the inputs come), float32 products, the state
-    float32 throughout.
+    operands in the activations' dtype (as the inputs come; ``w``,
+    ``attn = Q K^T . decay``, ``qg``, ``kd`` and D rounded to it once),
+    float32 products, ``G``, the decays, L, T and ``u`` float32, the
+    products that make T's merges, ``u`` and ``w`` at float32 accuracy,
+    the state float32 throughout. On the TPU ONE kernel,
+    ``gdn_chunk_scan``, a grid step a (row, value head, up to eight
+    PAIRS of chunks): it reads q, k, v where the projections left them
+    (``[B, T, heads x D]``, the key head ``h // (Hv // Hk)`` by the
+    block's index map) and writes ``o`` the same way, and everything
+    between is made in VMEM and never written to HBM. Two chunks share
+    each 128 x 128 matrix of the transform, block-diagonal under a
+    mask; the diagonal blocks of a step's pairs are substituted at
+    once, row i of every block one tile (``_solve_stacked``: the
+    vector unit's multiply-adds, the same recurrence in the same order
+    as the ``jax.numpy`` loop); the head's matrix stays in VMEM from
+    the page's copy-in at the row's first step to its copy-back at the
+    last. PERF.md (PR 51) has what each part costs.
 
 A chunk whose first position is 0 (``fresh``) starts from a zero state
 inside the kernel: no page is ever cleared by the host. Positions that
@@ -55,10 +62,11 @@ are not real advance nothing: the caller hands them ``g = 0`` and
 ``beta = 0``, and a row that is not real names the trash page.
 
 Where the kernels are off (``pallas_paged.flash_enabled``: the CPU) the
-same two forms, the substitution among them, run in ``jax.numpy``;
-tests/test_gdn.py holds each to the sequential rule of
-chipbench/references/qwen3_next.py and, in interpret mode, the kernels
-to the ``jax.numpy`` forms.
+same two forms run in ``jax.numpy`` (the chunkwise one as
+``_chunk_prep``, every chunk's operands at once under the scope
+``gdn_chunk_prep``, then ``_scan_jnp``); tests/test_gdn.py holds each
+to the sequential rule of chipbench/references/qwen3_next.py and, in
+interpret mode, the kernels to the ``jax.numpy`` forms.
 """
 
 import functools
@@ -79,9 +87,12 @@ CHUNK = 64
 # forward substitution, row by row; above it blocks are merged by
 # products
 _SOLVE_BLOCK = 16
-# diagonal blocks a grid step of the substitution kernel holds, one a
-# lane: [16, 16, 512] float32 is 512 KB in and as much out
-_SOLVE_LANES = 512
+# tokens whose matrices of the transform the kernel makes at once: two
+# chunks, block-diagonal under a mask (the MXU's 128 rows)
+_PAIR = 2 * CHUNK
+# pairs a grid step of the kernel takes at most: row i of each pair's
+# folded diagonal blocks is a sublane of one tile
+_GROUP = 8
 
 RECURRENT = "gdn_recurrent"
 CHUNKED = "gdn_chunk"
@@ -192,49 +203,6 @@ def _solve_rows_jnp(At: jnp.ndarray) -> jnp.ndarray:
     return At
 
 
-def _solve_kernel(a_ref, o_ref):
-    """One lane tile of blocks, [b, b, lanes] float32 in VMEM: row i
-    from the finished rows j < i of o_ref (the entries at j >= i are
-    zeros of a strictly lower triangle and are left out). No product
-    goes to the MXU: every multiply-add is the vector unit's, float32,
-    so no matmul precision of the caller's reaches in here."""
-    o_ref[0] = a_ref[0]
-    for i in range(1, a_ref.shape[0]):
-        acc = a_ref[i]                                  # [b, lanes]
-        for j in range(i):
-            acc = acc + a_ref[i, j:j + 1, :] * o_ref[j]
-        o_ref[i] = acc
-
-
-def _solve_rows(At: jnp.ndarray) -> jnp.ndarray:
-    """At [b, b, blocks] float32, the NEGATED strictly lower diagonal
-    blocks with the blocks on the minor axis (whole lanes) -> the same
-    of their ``(I + L)^-1 - I``. Row i of a block's inverse = its own
-    entries plus, for every j < i, entry j times the finished row j:
-    forward substitution, the same recurrence in the same order in
-    both forms. The kernel's operand is row-major by the call's own
-    constraint; for the loop inside ``_chunk_prep`` XLA's layout
-    assignment puts the blocks on the major axis, a sixteenth of the
-    lanes, and neither ``optimization_barrier`` nor
-    ``with_layout_constraint`` around the loop moves it (PERF.md,
-    PR 49)."""
-    if not pallas_paged.flash_enabled():
-        return _solve_rows_jnp(At)
-    b, _, blocks = At.shape
-    lanes = min(_SOLVE_LANES, blocks)
-    tile = pl.BlockSpec((b, b, lanes), lambda t: (0, 0, t))
-    return pl.pallas_call(
-        _solve_kernel, grid=(pl.cdiv(blocks, lanes),),
-        in_specs=[tile], out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct(At.shape, At.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
-        interpret=pallas_paged.needs_interpret(),
-        name="gdn_chunk_solve",
-    )(At)
-
-
 def _unit_lower_inverse(L: jnp.ndarray) -> jnp.ndarray:
     """(I + L)^-1 for L [..., n, n] STRICTLY lower triangular, float32,
     n a power-of-two multiple of _SOLVE_BLOCK. The diagonal blocks of
@@ -251,7 +219,7 @@ def _unit_lower_inverse(L: jnp.ndarray) -> jnp.ndarray:
     # lanes); as [..., b, b] the substitution ran at a sixth of its
     # lanes (PERF.md, PR 42)
     lead = A.shape[:-2]
-    At = _solve_rows(jnp.moveaxis(A.reshape((-1, b, b)), 0, -1))
+    At = _solve_rows_jnp(jnp.moveaxis(A.reshape((-1, b, b)), 0, -1))
     A = (jnp.moveaxis(At, -1, 0).reshape(lead + (b, b))
          + jnp.eye(b, dtype=L.dtype))
     blocks, size = [A[..., i, :, :] for i in range(n // b)], b
@@ -308,35 +276,7 @@ def _chunk_prep(q, k, v, g, beta):
     return qg, w, kd.swapaxes(-1, -2), u, attn, jnp.exp(G[..., -1])
 
 
-def _scan_kernel(ids_ref, layer_ref, fresh_ref, qg_ref, w_ref, kdt_ref,
-                 u_ref, attn_ref, dec_ref, s_ref, o_ref, so_ref, acc_ref,
-                 *, chunks: int):
-    """One (row, head, chunk): the head's matrix stays in acc_ref from
-    the page's copy-in at the first chunk to its copy-back at the
-    last."""
-    b, n = pl.program_id(0), pl.program_id(2)
 
-    @pl.when(n == 0)
-    def _load():
-        acc_ref[...] = s_ref[0, 0, 0] * (
-            1.0 - fresh_ref[b].astype(jnp.float32))
-
-    S = acc_ref[...]                                         # [Dk, Dv]
-    dt = qg_ref.dtype
-    Sb = S.astype(dt)
-
-    def dot(a, c):
-        return jax.lax.dot_general(a, c, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-    d = u_ref[0, 0, 0] - dot(w_ref[0, 0, 0], Sb)
-    db = d.astype(dt)
-    o_ref[0, 0, 0] = dot(qg_ref[0, 0, 0], Sb) + dot(attn_ref[0, 0, 0], db)
-    S = S * dec_ref[0, 0, pl.ds(n, 1), :] + dot(kdt_ref[0, 0, 0], db)
-    acc_ref[...] = S
-
-    @pl.when(n == chunks - 1)
-    def _store():
-        so_ref[0, 0, 0] = S
 
 
 def _scan_jnp(qg, w, kdT, u, attn, dec, state, ids, layer, fresh):
@@ -363,59 +303,257 @@ def _scan_jnp(qg, w, kdT, u, attn, dec, state, ids, layer, fresh):
     return jnp.moveaxis(o, 0, 2), state.at[layer, ids].set(S)
 
 
+def _solve_stacked(a_ref, e_ref, x_ref):
+    """The forward substitution of every diagonal block of a grid step
+    at once. a_ref [16 * _GROUP, _PAIR] float32: row ``i * _GROUP + p``
+    holds row i of the eight NEGATED strictly lower diagonal blocks of
+    pair p, a block after the other on the lanes (block, column); so
+    row i of every block of the step is ONE [_GROUP, _PAIR] tile.
+    x_ref, the same layout, receives ``(I + L)^-1 - I``: row i = its
+    own entries plus, for every j < i, entry (i, j) times the finished
+    row j, the recurrence of ``_solve_rows_jnp`` in its order. Entry
+    (i, j) of a block has to multiply that block's sixteen lanes of row
+    j: e_ref[j] holds it spread over them, made on the MXU against a
+    0 / 1 matrix from the entries split into three bfloat16 terms, which
+    reproduces a float32 exactly; every multiply-add of the recurrence
+    itself is the vector unit's, float32."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    n = a_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    to = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    by = _SOLVE_BLOCK.bit_length() - 1
+    same = (lane >> by) == (to >> by)
+    a = a_ref[...]
+    terms = []
+    for _ in range(3):
+        terms.append(a.astype(bf16))
+        a = a - terms[-1].astype(f32)
+    for j in range(_SOLVE_BLOCK - 1):
+        spread = (same & ((lane & (_SOLVE_BLOCK - 1)) == j)).astype(bf16)
+        e_ref[j] = sum(jnp.dot(t, spread, preferred_element_type=f32)
+                       for t in terms)
+    x_ref[0:_GROUP] = a_ref[0:_GROUP]
+    for i in range(1, _SOLVE_BLOCK):
+        rows = slice(i * _GROUP, (i + 1) * _GROUP)
+        acc = a_ref[rows]
+        for j in range(i):
+            acc = acc + e_ref[j, rows] * x_ref[j * _GROUP:(j + 1) * _GROUP]
+        x_ref[rows] = acc
+
+
+def _rule_kernel(ids_ref, layer_ref, fresh_ref, q_ref, k_ref, v_ref, g_ref,
+                 beta_ref, s_ref, o_ref, so_ref, acc_ref, l_ref, decay_ref,
+                 a_ref, e_ref, x_ref, *, pairs: int, groups: int):
+    """One (row, value head, group of ``pairs`` pairs of chunks): the
+    whole rule over ``pairs * _PAIR`` positions, nothing of it written
+    to HBM but ``o`` and, at the row's last group, the page.
+
+    q_ref, k_ref [1, pairs * _PAIR, Dk] (the key head the value head
+    reads) and v_ref [.., Dv] in the activations' dtype, read where the
+    projections left them; g_ref, beta_ref [1, pairs * _PAIR, Hv]
+    float32 (every head's: this one's is picked by lane); s_ref /
+    so_ref [1, 1, 1, Dk, Dv] the head's matrix of the page. Two chunks
+    share every [_PAIR, _PAIR] matrix of the transform (L, the decays,
+    the inverse, ``attn``), block-diagonal by chunk under a mask: the
+    MXU takes 128 rows for the time of 64. Three passes over the pairs:
+    L and its folded diagonal blocks; their substitution, every pair's
+    at once; the merges, ``u``, ``w``, ``attn``, ``qg``, ``kd`` and the
+    recurrence, the head's matrix in acc_ref from the page's copy-in
+    at the first group to its copy-back at the last."""
+    b, h, n = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    f32, dt = jnp.float32, q_ref.dtype
+    P, C, hi = _PAIR, CHUNK, jax.lax.Precision.HIGHEST
+    # float32 operands (tests, tools) multiply at full precision: the
+    # caller's default_matmul_precision does not reach into the kernel
+    exact = hi if dt == f32 else None
+    row = jax.lax.broadcasted_iota(jnp.int32, (P, P), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (P, P), 1)
+
+    def same(size):     # in one block of ``size`` (a power of two)
+        by = size.bit_length() - 1
+        return (row >> by) == (col >> by)
+    chunk, eye = same(C), row == col
+    same16, same32 = same(_SOLVE_BLOCK), same(2 * _SOLVE_BLOCK)
+    mine = jax.lax.broadcasted_iota(
+        jnp.int32, (P, g_ref.shape[-1]), 1) == h
+
+    def dot(x, y, dims=((1,), (0,)), precision=exact):
+        return jax.lax.dot_general(x, y, (dims, ((), ())),
+                                   precision=precision,
+                                   preferred_element_type=f32)
+
+    def second(x, size):
+        """The rows of the second block of each two blocks of size."""
+        return jnp.concatenate(
+            [x[i:i + size] for i in range(size, P, 2 * size)], axis=0)
+
+    def spaced(x, size):
+        """``second``'s rows back in their places, zeros between."""
+        gap = jnp.zeros((size, P), f32)
+        return jnp.concatenate([y for i in range(0, P // 2, size)
+                                for y in (gap, x[i:i + size])], axis=0)
+
+    def columns(at):
+        """The pair's G (log-decays summed from each chunk's first
+        token) as a column and as a row, and beta as a column."""
+        g = jnp.sum(jnp.where(mine, g_ref[0, at, :], 0.0), axis=1,
+                    keepdims=True)
+        beta = jnp.sum(jnp.where(mine, beta_ref[0, at, :], 0.0), axis=1,
+                       keepdims=True)
+        Grow = jnp.sum(jnp.where(chunk & (row <= col), g, 0.0), axis=0,
+                       keepdims=True)
+        Gcol = jnp.sum(jnp.where(eye, Grow, 0.0), axis=1, keepdims=True)
+        return Gcol, Grow, beta
+
+    @pl.when(n == 0)
+    def _load():
+        acc_ref[...] = s_ref[0, 0, 0] * (
+            1.0 - fresh_ref[b].astype(f32))
+
+    if pairs < _GROUP:
+        a_ref[...] = jnp.zeros_like(a_ref)
+    for p in range(pairs):
+        at = slice(p * P, (p + 1) * P)
+        Gcol, Grow, beta = columns(at)
+        # exp of differences only, and only where they are <= 0
+        keep = chunk & (row >= col)
+        decay = jnp.where(keep, jnp.exp(jnp.where(keep, Gcol - Grow, 0.0)),
+                          0.0)
+        k = k_ref[0, at, :]
+        L = jnp.where(chunk & (row > col),
+                      beta * decay * dot(k, k, ((1,), (1,))), 0.0)
+        l_ref[p], decay_ref[p] = L, decay
+        # row i of the pair's eight diagonal blocks, negated
+        blocks = jnp.where(same16, -L, 0.0)
+        a_ref[pl.ds(p, _SOLVE_BLOCK, stride=_GROUP)] = sum(
+            blocks[i:i + _SOLVE_BLOCK]
+            for i in range(0, P, _SOLVE_BLOCK))
+
+    _solve_stacked(a_ref, e_ref, x_ref)
+
+    for p in range(pairs):
+        at = slice(p * P, (p + 1) * P)
+        Gcol, _, beta = columns(at)
+        L, decay = l_ref[p], decay_ref[p]
+        q, k, v = q_ref[0, at, :], k_ref[0, at, :], v_ref[0, at, :]
+        solved = x_ref[pl.ds(p, _SOLVE_BLOCK, stride=_GROUP)]
+        Tm = jnp.where(same16, jnp.concatenate(
+            [solved] * (P // _SOLVE_BLOCK), axis=0), 0.0) + eye.astype(f32)
+        # neighbours merge, level by level: with T the inverses of the
+        # diagonal blocks and B what L holds beside them, T - T B T;
+        # only the second block of each two has rows in B and in T B T,
+        # and the products take those rows alone
+        for size, beside in ((_SOLVE_BLOCK, same32 & ~same16),
+                             (2 * _SOLVE_BLOCK, ~same32)):
+            BT = dot(second(jnp.where(beside, L, 0.0), size), Tm,
+                     precision=hi)
+            Tm = Tm - spaced(dot(second(Tm, size), spaced(BT, size),
+                                 precision=hi), size)
+        eG = jnp.exp(Gcol)
+        uw = dot(Tm, jnp.concatenate(
+            [beta * v.astype(f32), (beta * eG) * k.astype(f32)], axis=1),
+            precision=hi)
+        Dv = v.shape[-1]
+        u, w = uw[:, :Dv], uw[:, Dv:].astype(dt)
+        attn = (dot(q, k, ((1,), (1,))) * decay).astype(dt)
+        qg = (q.astype(f32) * eG).astype(dt)
+        last = jnp.where(row[:, :1] < C, Gcol[C - 1:C], Gcol[P - 1:P])
+        kdT = (k.astype(f32) * jnp.exp(last - Gcol)).T.astype(dt)
+        # the recurrence, a chunk after the other; the products against
+        # a whole pair's rows take the other chunk's as zeros
+        S = acc_ref[...]
+        none, ds, outs = jnp.zeros((C, Dv), dt), [], []
+        for c in range(2):
+            rows = slice(c * C, (c + 1) * C)
+            Sb = S.astype(dt)
+            outs.append(dot(qg[rows], Sb))
+            ds.append((u[rows] - dot(w[rows], Sb)).astype(dt))
+            both = [none, none]
+            both[c] = ds[-1]
+            S = (S * jnp.exp(Gcol[(c + 1) * C - 1:(c + 1) * C])
+                 + dot(kdT, jnp.concatenate(both, axis=0)))
+        o_ref[0, at, :] = (jnp.concatenate(outs, axis=0)
+                           + dot(attn, jnp.concatenate(ds, axis=0)))
+        acc_ref[...] = S
+
+    @pl.when(n == groups - 1)
+    def _store():
+        so_ref[0, 0, 0] = acc_ref[...]
+
+
+def _group_pairs(pairs: int) -> int:
+    """Pairs of chunks a grid step takes: the most, up to _GROUP, that
+    divide the row's (a block past the end of the row would be read as
+    it lies, and its g and beta would advance the state)."""
+    return max(d for d in range(1, _GROUP + 1) if pairs % d == 0)
+
+
 def _chunked(q, k, v, g, beta, state, ids, layer, fresh):
-    """q, k, v [B, T, Hv, D] in the activations' dtype, g, beta
-    [B, T, Hv] float32 -> (o [B, T, Hv, Dv] float32, the pool)."""
-    B, T, Hv, Dk = q.shape
-    Dv = v.shape[-1]
-    pad = (-T) % CHUNK
+    """q, k [B, T, Hk, Dk], v [B, T, Hv, Dv] in the activations' dtype,
+    g, beta [B, T, Hv] float32 -> (o [B, T, Hv, Dv] float32, the
+    pool)."""
+    B, T, Hk, Dk = q.shape
+    Hv, Dv = v.shape[2:]
+    kernels = pallas_paged.flash_enabled()
+    pad = (-T) % (_PAIR if kernels else CHUNK)
     if pad:     # g = 0, beta = 0: the padding advances nothing
         q, k, v, g, beta = (
             jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta))
-    N, C = (T + pad) // CHUNK, CHUNK
-    with jax.named_scope("gdn_chunk_prep"):
-        qg, w, kdT, u, attn, dec = _chunk_prep(q, k, v, g, beta)
-    if not pallas_paged.flash_enabled():
-        o, state = _scan_jnp(qg, w, kdT, u, attn, dec, state, ids, layer,
-                             fresh)
-    else:
-        def at(b, h, n, ids, lyr, fr):
-            return (b, h, n, 0, 0)
-        page = pl.BlockSpec(
-            (1, 1, 1, Dk, Dv),
-            lambda b, h, n, ids, lyr, fr: (lyr[0], ids[b], h, 0, 0))
-        o, state = pl.pallas_call(
-            functools.partial(_scan_kernel, chunks=N),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3, grid=(B, Hv, N),
-                in_specs=[pl.BlockSpec((1, 1, 1, C, Dk), at),
-                          pl.BlockSpec((1, 1, 1, C, Dk), at),
-                          pl.BlockSpec((1, 1, 1, Dk, C), at),
-                          pl.BlockSpec((1, 1, 1, C, Dv), at),
-                          pl.BlockSpec((1, 1, 1, C, C), at),
-                          # a head's decays of every chunk, as rows
-                          pl.BlockSpec(
-                              (1, 1, N, Dv),
-                              lambda b, h, n, ids, lyr, fr: (b, h, 0, 0)),
-                          page],
-                out_specs=[pl.BlockSpec((1, 1, 1, C, Dv), at), page],
-                scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)]),
-            out_shape=[jax.ShapeDtypeStruct((B, Hv, N, C, Dv), jnp.float32),
-                       jax.ShapeDtypeStruct(state.shape, state.dtype)],
-            input_output_aliases={9: 1},
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary",
-                                     "arbitrary"),
-                vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
-            interpret=pallas_paged.needs_interpret(),
-            name="gdn_chunk_scan",
-        )(ids.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-          fresh.astype(jnp.int32), qg, w, kdT, u, attn,
-          jnp.broadcast_to(dec[..., None], dec.shape + (Dv,)), state)
-    # [B, Hv, N, C, Dv] -> [B, T, Hv, Dv]
-    o = jnp.moveaxis(o, 1, 3).reshape(B, N * C, Hv, Dv)
-    return o[:, :T], state
+    Tp = T + pad
+    if not kernels:
+        with jax.named_scope("gdn_chunk_prep"):
+            operands = _chunk_prep(_expand_heads(q, Hv),
+                                   _expand_heads(k, Hv), v, g, beta)
+        o, state = _scan_jnp(*operands, state, ids, layer, fresh)
+        # [B, Hv, N, C, Dv] -> [B, T, Hv, Dv]
+        o = jnp.moveaxis(o, 1, 3).reshape(B, Tp, Hv, Dv)
+        return o[:, :T], state
+    pairs = _group_pairs(Tp // _PAIR)
+    rows, rep = pairs * _PAIR, Hv // Hk
+    groups = Tp // rows
+
+    def head(width, of):
+        return pl.BlockSpec(
+            (1, rows, width),
+            lambda b, h, n, ids, lyr, fr: (b, n, of(h)))
+    page = pl.BlockSpec(
+        (1, 1, 1, Dk, Dv),
+        lambda b, h, n, ids, lyr, fr: (lyr[0], ids[b], h, 0, 0))
+    f32 = jnp.float32
+    o, state = pl.pallas_call(
+        functools.partial(_rule_kernel, pairs=pairs, groups=groups),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, Hv, groups),
+            # q, k, v and o as the projections lay them, [B, T, H * D]:
+            # a block is (row, group, head), the key head h // rep
+            in_specs=[head(Dk, lambda h: h // rep),
+                      head(Dk, lambda h: h // rep),
+                      head(Dv, lambda h: h),
+                      head(Hv, lambda h: 0), head(Hv, lambda h: 0), page],
+            out_specs=[head(Dv, lambda h: h), page],
+            scratch_shapes=[
+                pltpu.VMEM((Dk, Dv), f32),
+                pltpu.VMEM((pairs, _PAIR, _PAIR), f32),
+                pltpu.VMEM((pairs, _PAIR, _PAIR), f32),
+                pltpu.VMEM((_SOLVE_BLOCK * _GROUP, _PAIR), f32),
+                pltpu.VMEM((_SOLVE_BLOCK - 1, _SOLVE_BLOCK * _GROUP, _PAIR),
+                           f32),
+                pltpu.VMEM((_SOLVE_BLOCK * _GROUP, _PAIR), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, Tp, Hv * Dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # operands count the scalar-prefetch arguments: the pool is the
+        # ninth, and the second result
+        input_output_aliases={8: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
+        interpret=pallas_paged.needs_interpret(),
+        name="gdn_chunk_scan",
+    )(ids.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      fresh.astype(jnp.int32), q.reshape(B, Tp, Hk * Dk),
+      k.reshape(B, Tp, Hk * Dk), v.reshape(B, Tp, Hv * Dv), g, beta, state)
+    return o.reshape(B, Tp, Hv, Dv)[:, :T], state
 
 
 def mix(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
@@ -430,13 +568,13 @@ def mix(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
     [B] the rows' pages (the trash page for a row that is not real);
     fresh [B] bool: the row starts at position 0, from a zero state.
     -> (o [B, T, Hv, Dv] float32, the pool, updated in place)."""
-    Hv = v.shape[2]
-    q, k = _expand_heads(q, Hv), _expand_heads(k, Hv)
     if gdn_path(q.shape[1]) == RECURRENT:
         with jax.named_scope("gdn_step"):
-            f32 = jnp.float32
-            return _recurrent(q.astype(f32), k.astype(f32), v.astype(f32),
-                              g, beta, state, ids, layer, fresh)
+            f32, Hv = jnp.float32, v.shape[2]
+            return _recurrent(_expand_heads(q, Hv).astype(f32),
+                              _expand_heads(k, Hv).astype(f32),
+                              v.astype(f32), g, beta, state, ids, layer,
+                              fresh)
     with jax.named_scope("gdn_scan"):
         return _chunked(q, k, v, g, beta, state, ids, layer, fresh)
 

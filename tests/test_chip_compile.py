@@ -1113,8 +1113,7 @@ def test_hybrid_step_program_compiles_at_qwen3next_widths(
                             rep((1,), jnp.int32), rep((1, 2048), jnp.int32),
                             rep((1,), jnp.int32), rep((1,), jnp.int32),
                             *small).compile()
-        want = {"paged_attention", "moe_grouped_experts", "gdn_chunk_scan",
-                "gdn_chunk_solve"}
+        want = {"paged_attention", "moe_grouped_experts", "gdn_chunk_scan"}
         path = "pallas_paged"
     hlo = compiled.as_text()
     calls = {m.group(1) for m in re.finditer(
@@ -1134,13 +1133,18 @@ def test_hybrid_step_program_compiles_at_qwen3next_widths(
     slices = re.findall(r"= s8\[3,2048,\d+\]\S* [\w\-]+\(", hlo)
     assert not slices, slices[:3]
     if program == "prefill_chunk":
-        # the rule's substitution is the kernel's (ops/gdn._solve_rows):
+        # the rule's substitution is the kernel's (ops/gdn._solve_stacked):
         # no XLA operation rewrites a layer's 4096 diagonal blocks a row
         # at a time (fifteen dynamic-update-slice a layer: PERF.md,
         # PR 49)
         rows = re.findall(
             r"= f32\[16,16,4096\]\S* dynamic-update-slice\(", hlo)
         assert not rows, rows[:3]
+        # nor is any operand of the recurrence an array of the program
+        # (PR 51: ``qg``, ``w``, ``kdT``, ``u``, ``attn`` [1, 32 heads,
+        # 32 chunks, 64, .] are made in the kernel's VMEM)
+        made = re.findall(r"(?:bf16|f32)\[1,32,32,(?:64|128),\d+\]", hlo)
+        assert not made, made[:3]
         # the held experts' rounds (ops/moe._moe_grouped): nothing of a
         # chunk's 2048 x 10 assignments by the hidden width is built,
         # nor the buffer of 21 568 rows they were sorted into; a
@@ -1185,34 +1189,51 @@ def test_kv_prefill_kernel_in_q_blocks_compiles_at_8_groups_of_256(
         s((), jnp.int32)).compile()
 
 
-@pytest.mark.parametrize("T,heads", [(512, 32), (320, 32), (64, 4),
-                                     (192, 4)])
-def test_gdn_chunk_prep_compiles_with_the_solve_kernel(topo, tpu_branches,
-                                                      T, heads):
-    """The chunkwise rule's per-chunk transform of one row at
-    Qwen3-Next's 32 value heads of 128 (512 tokens: 1024 diagonal
-    blocks in two lane tiles; 320: 640, the last tile a quarter full)
-    and at 4 heads (16 and 48 blocks: less than a vector register's
-    lanes), compiled for the described v5e: the substitution is
-    ``gdn_chunk_solve`` and nothing else writes the blocks a row at a
-    time. A chunk's 4096 blocks are the whole prefill executable's
-    (test_hybrid_step_program_compiles_at_qwen3next_widths)."""
+@pytest.mark.parametrize("T,hk,hv", [(2048, 16, 32), (512, 16, 32),
+                                     (256, 16, 32), (320, 16, 32),
+                                     (64, 2, 4), (640, 4, 4)])
+def test_gdn_chunk_kernel_compiles_and_reads_in_place(topo, tpu_branches,
+                                                      T, hk, hv):
+    """The chunkwise rule of one row at Qwen3-Next's 16 key / 32 value
+    heads of 128 (a 2048-token chunk: two grid steps of eight pairs of
+    chunks a head; 512 and the probe's 256: four and two pairs; 320:
+    padded to three) and at 4 heads (one pair; five with a key head a
+    value head), compiled for the described v5e: ONE kernel,
+    ``gdn_chunk_scan``, and around it neither a copy nor a transpose of
+    q, k, v or o, which go in and come out as [1, T, heads x 128], the
+    layout the projections leave and read: nothing of the transform
+    (``qg``, ``w``, ``kdT``, ``u``, ``attn``, L, the decays) is an
+    array of the program. The whole prefill executable:
+    test_hybrid_step_program_compiles_at_qwen3next_widths."""
     import re
     from production_stack_tpu.ops import gdn
     one = SingleDeviceSharding(topo.devices[0])
 
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-    blocks = heads * T // 16
-    hlo = jax.jit(gdn._chunk_prep).lower(
-        s((1, T, heads, 128)), s((1, T, heads, 128)),
-        s((1, T, heads, 128)), s((1, T, heads), jnp.float32),
-        s((1, T, heads), jnp.float32)).compile().as_text()
+
+    def rule(q, k, v, g, beta, pool, ids, fresh):
+        o, pool = gdn.mix(
+            q.reshape(1, T, hk, 128), k.reshape(1, T, hk, 128),
+            v.reshape(1, T, hv, 128), g, beta, pool, ids, jnp.int32(1),
+            fresh)
+        return o.reshape(1, T, hv * 128), pool
+    hlo = jax.jit(rule, donate_argnums=5).lower(
+        s((1, T, hk * 128)), s((1, T, hk * 128)), s((1, T, hv * 128)),
+        s((1, T, hv), jnp.float32), s((1, T, hv), jnp.float32),
+        s((2, 3, hv, 128, 128), jnp.float32), s((1,), jnp.int32),
+        s((1,), jnp.bool_)).compile().as_text()
     calls = [m.group(1) for m in re.finditer(
         r"%([A-Za-z_]+)[\w.\-]* = [^=]*? custom-call\(", hlo)]
-    assert [c for c in calls if c.startswith("gdn")] == ["gdn_chunk_solve"]
-    assert not re.findall(rf"= f32\[16,16,{blocks}\]\S* "
-                          r"dynamic-update-slice\(", hlo)
+    assert calls == ["gdn_chunk_scan"]
+    # g and beta [1, T, hv] may be fetched ahead (a copy between
+    # memory spaces); a row that is no whole number of pairs is padded
+    # (one fusion an operand), and nothing else is moved
+    big = [m for m in re.findall(
+        r"= (?:bf16|f32)\[1,\d+,\d+(?:,\d+)*\]\S* "
+        r"(?:copy|transpose|fusion)\(", hlo)
+        if not m.startswith(f"= f32[1,{T},{hv}]")]
+    assert len(big) <= (5 if T % gdn._PAIR else 0), big
 
 
 # ---------------------------------------------------------------------

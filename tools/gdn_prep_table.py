@@ -1,35 +1,41 @@
 #!/usr/bin/env python3
-"""The delta rule's per-chunk transform alone, on the chip: the table
-behind the substitution kernel ``gdn_chunk_solve`` (ops/gdn.py;
-PERF.md, PR 49).
+"""The delta rule's chunkwise form alone, on the chip: one layer of
+``ops/gdn.mix`` over one row, the WHOLE rule beside its per-chunk
+transform (ops/gdn.py; PERF.md, PR 49 and PR 51).
 
-One layer of ``ops/gdn._chunk_prep`` (L, the unit lower-triangular
-inverse, ``u`` and ``w``, the scan's other operands) over one row at
-the shape ``qwen3next-longctx-closed`` serves (2048 tokens, 32 value
-heads of 128, bfloat16 inputs with the keys and queries normalised,
-log-decays and write strengths as the layer draws them) and at 512
-tokens, in both forms of the substitution of its diagonal blocks
-(``[16, 16, blocks]`` float32: 4096 and 1024 blocks): ``xla`` (the
-``jax.numpy`` loop, the CPU's path) and ``kernel`` (the tile in VMEM).
+The shapes ``qwen3next-longctx-closed`` serves: 16 key / 32 value heads
+of 128, bfloat16 inputs with the keys and queries normalised, log-decays
+and write strengths as the layer draws them, one row of 2048 tokens (a
+prefill chunk), of 512, and of 256 (the probe's one-row bucket), from
+and to a page of a float32 state pool. q, k, v go in and ``o`` comes
+out as ``[1, T, heads x 128]``, the layout the projections leave and
+read, so a relayout the rule makes of them is the rule's own. Two
+forms a shape:
+
+- ``jnp``: the kernels off: ``_chunk_prep`` + ``_scan_jnp`` as XLA
+  operations, the CPU's path and what tests/test_gdn.py holds the
+  kernels to;
+- ``kernel``: the tree's kernels on. Since PR 51 ONE kernel,
+  ``gdn_chunk_scan``, which reads q, k, v in place and makes the
+  transform in VMEM; copied into a tree from before it, ``_chunk_prep``
+  with the substitution kernel ``gdn_chunk_solve``, then the scan
+  kernel: the "before".
+
 Every time is the DEVICE's, from a profiler capture of ``--repeat``
 calls (chipbench/xplane.py reads it), so neither the host's dispatch
 nor a timing loop's own copies are in it:
 
-- ``prep_ms``: the median run of the jitted ``_chunk_prep``;
-- ``solve_ms``: of that, the substitution's own operations a run: the
-  kernel, or XLA's ``dynamic-update-slice``, ``multiply_reduce_fusion``
-  and ``slice_add_fusion`` (fifteen of each);
-- ``solve_alone_ms``: ``_solve_rows`` jitted by itself on the blocks
-  (XLA then lays the array out row-major and updates it in place, which
-  inside ``_chunk_prep`` it does not: PERF.md, PR 49);
-- ``ops_us``: the transform's operations by base name, [calls a run,
-  microseconds a run], the 24 that took most.
+- ``rule_ms``: the median run of the jitted ``mix`` (transform,
+  substitution, scan and every copy between them);
+- ``ops_us``: its operations by base name, [calls a run, microseconds
+  a run], the 24 that took most;
+- ``prep_ms``: where the form runs ``_chunk_prep``, the median run of
+  that alone (the transform without the scan).
 
-``largest_difference``: the kernel's against the loop's, of the solved
-blocks and of ``u`` and ``w``, beside the largest entry of each.
-Copied into a tree without the kernel it times that tree's one form:
-the "before". A rehearsal on the CPU has no device plane: its times
-are the host's clock around a call (``"clock": "host"``).
+``largest_difference``: the kernels' ``o`` and page against the
+``jnp`` form's, beside the largest entry of each. A rehearsal on the
+CPU has no device plane: its times are the host's clock around a call
+(``"clock": "host"``).
 
 One JSON line last.
 """
@@ -45,29 +51,26 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-HEADS, HEAD_DIM = 32, 128
-TOKENS = (2048, 512)
-# XLA's operations of the substitution, by base name (chipbench/xplane)
-XLA_SOLVE_OPS = ("dynamic-update-slice", "multiply_reduce_fusion",
-                 "slice_add_fusion")
+KEY_HEADS, VALUE_HEADS, HEAD_DIM = 16, 32, 128
+TOKENS = (2048, 512, 256)
 
 
-def device_times(fn, operands, calls):
-    """``calls`` runs of the jitted fn under the profiler -> (median
-    device ms a run, {base name: [calls a run, us a run]}, "device");
-    where the capture holds no device plane (the CPU) the host's clock
-    around a run, no operations, "host"."""
+def device_times(step, carry, calls):
+    """``calls`` runs of ``carry = step(carry)`` under the profiler ->
+    (median device ms a run, {base name: [calls a run, us a run]},
+    "device"); where the capture holds no device plane
+    (the CPU) the host's clock around a run, no operations, "host"."""
     import jax
     import numpy as np
 
     from chipbench import xplane
-    jax.block_until_ready(fn(*operands))
+    carry = jax.block_until_ready(step(carry))
     times = []
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
             for _ in range(calls):
                 t0 = time.perf_counter()
-                jax.block_until_ready(fn(*operands))
+                carry = jax.block_until_ready(step(carry))
                 times.append(time.perf_counter() - t0)
         try:
             modules = xplane.reduce_file(
@@ -75,8 +78,9 @@ def device_times(fn, operands, calls):
         except ValueError:
             return round(1e3 * float(np.median(times)), 4), {}, "host"
     module = max(modules.values(), key=lambda m: m["total_s"])
+    ops = sorted(module["ops"].items(), key=lambda kv: -kv[1][1])[:24]
     ops = {name: [n / calls, round(1e6 * sec / calls, 1)]
-           for name, (n, sec) in module["ops"].items()}
+           for name, (n, sec) in ops}
     return round(1e3 * module["median_s"], 4), ops, "device"
 
 
@@ -85,10 +89,11 @@ def main(argv=None) -> int:
     ap.add_argument("--repeat", type=int, default=5,
                     help="calls in a capture")
     ap.add_argument("--tokens", type=int, nargs="*", default=[],
-                    help="tokens of the row (default: 2048 and 512)")
+                    help="tokens of the row (default: 2048, 512, 256)")
     ap.add_argument("--allow-cpu", action="store_true")
     ap.add_argument("--tiny", action="store_true",
-                    help="4 heads, 128 and 64 tokens (a CPU rehearsal)")
+                    help="2 key / 4 value heads, 256 and 128 tokens (a "
+                         "CPU rehearsal)")
     args = ap.parse_args(argv)
 
     import jax
@@ -100,81 +105,86 @@ def main(argv=None) -> int:
     if dev.platform == "cpu" and not args.allow_cpu:
         print("gdn_prep_table: JAX found no accelerator", file=sys.stderr)
         return 3
-    has_kernel = hasattr(gdn, "_solve_rows")
-    heads, tokens = (4, (128, 64)) if args.tiny else (HEADS, TOKENS)
+    # the tree's kernels: one since PR 51, two before it
+    one_kernel = not hasattr(gdn, "_solve_rows")
+    hk, hv, tokens = (2, 4, (256, 128)) if args.tiny \
+        else (KEY_HEADS, VALUE_HEADS, TOKENS)
     tokens = args.tokens or tokens
+    bf16, f32, D = jnp.bfloat16, jnp.float32, HEAD_DIM
 
     def worst(a, b):
-        return float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                     - b.astype(jnp.float32))))
+        return float(jnp.max(jnp.abs(a.astype(f32) - b.astype(f32))))
 
     rows = []
     for T in tokens:
-        ks = jax.random.split(jax.random.PRNGKey(T), 5)
-        q, k = (jax.random.normal(key, (1, T, heads, HEAD_DIM))
-                for key in ks[:2])
-        q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * HEAD_DIM ** -0.5
+        ks = jax.random.split(jax.random.PRNGKey(T), 6)
+        q, k = (jax.random.normal(key, (1, T, hk, D)) for key in ks[:2])
+        q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
         k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-        v = jax.random.normal(ks[2], (1, T, heads, HEAD_DIM))
+        v = jax.random.normal(ks[2], (1, T, hv, D))
         # as models/llama's layer makes them: A = exp(A_log) in (0, 16),
         # g = -A softplus(a + dt_bias), beta = sigmoid(b)
-        A = jax.random.uniform(ks[3], (heads,), minval=0.0, maxval=16.0)
-        g = -A * jax.nn.softplus(
-            jax.random.normal(ks[3], (1, T, heads)) + 1.0)
-        beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, T, heads)))
-        prep_in = tuple(x.astype(jnp.bfloat16) for x in (q, k, v)) \
-            + (g, beta)
-        At = None
-        if has_kernel:      # what _chunk_prep hands the substitution
-            seen, was = [], gdn._solve_rows
-            gdn._solve_rows = lambda a: (seen.append(a),
-                                         gdn._solve_rows_jnp(a))[1]
-            try:
-                gdn._chunk_prep(*prep_in)
-            finally:
-                gdn._solve_rows = was
-            At = seen[0]
+        A = jax.random.uniform(ks[3], (hv,), minval=0.0, maxval=16.0)
+        g = -A * jax.nn.softplus(jax.random.normal(ks[3], (1, T, hv)) + 1.0)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, T, hv)))
+        flat = tuple(x.astype(bf16).reshape(1, T, -1) for x in (q, k, v))
+        pool = jax.random.normal(ks[5], (1, 2, hv, D, D))
+        ids, fresh = jnp.array([1]), jnp.array([False])
+
+        def rule(pool, q, k, v, g, beta):
+            o, pool = gdn.mix(
+                q.reshape(1, T, hk, D), k.reshape(1, T, hk, D),
+                v.reshape(1, T, hv, D), g, beta, pool, ids, jnp.int32(0),
+                fresh)
+            return o.reshape(1, T, hv * D), pool
+
+        def prep(q, k, v, g, beta):
+            return gdn._chunk_prep(
+                gdn._expand_heads(q.reshape(1, T, hk, D), hv),
+                gdn._expand_heads(k.reshape(1, T, hk, D), hv),
+                v.reshape(1, T, hv, D), g, beta)
+
         want = {}
-        for form in ("xla", "kernel") if has_kernel else ("tree",):
-            row = {"tokens": T, "heads": heads,
-                   "blocks": heads * T // gdn._SOLVE_BLOCK,
-                   "form": form}
-            if form != "tree":
-                pallas_paged.set_flash_enabled(form == "kernel")
+        for form in ("jnp", "kernel"):
+            row = {"tokens": T, "heads": [hk, hv], "form": form,
+                   "kernels": [] if form == "jnp" else
+                   ["gdn_chunk_scan"] if one_kernel else
+                   ["gdn_chunk_solve", "gdn_chunk_scan"]}
+            pallas_paged.set_flash_enabled(form == "kernel")
             got = {}
             try:
-                prep = jax.jit(lambda *a: gdn._chunk_prep(*a))
-                _, got["w"], _, got["u"], _, _ = prep(*prep_in)
-                row["prep_ms"], ops, row["clock"] = device_times(
-                    prep, prep_in, args.repeat)
-                mine = ("gdn_chunk_solve",) if form == "kernel" \
-                    else XLA_SOLVE_OPS
-                row["solve_ms"] = round(sum(
-                    ops.get(n, [0, 0.0])[1] for n in mine) / 1e3, 4)
-                row["ops_us"] = ops
-                if At is not None:
-                    solve = jax.jit(lambda a: gdn._solve_rows(a))
-                    got["blocks"] = solve(At)
-                    row["solve_alone_ms"] = device_times(
-                        solve, (At,), args.repeat)[0]
+                # a function of its own a form: jit keys its cache on
+                # the function, not on the kernels' switch
+                run = jax.jit(lambda *a: rule(*a), donate_argnums=0)
+                got["o"], page = run(pool + 0.0, *flat, g, beta)
+                got["state"] = page[0, 1]
+                # a run takes the page the run before it left
+                row["rule_ms"], row["ops_us"], row["clock"] = device_times(
+                    lambda page: run(page, *flat, g, beta)[1], page,
+                    args.repeat)
+                if form == "jnp" or not one_kernel:
+                    alone = jax.jit(lambda *a: prep(*a))
+                    row["prep_ms"] = device_times(
+                        lambda _: alone(*flat, g, beta), None,
+                        args.repeat)[0]
             except Exception as e:      # what the compiler refuses
                 row["refused"] = str(e).splitlines()[0][:200]
                 got = {}
             finally:
                 pallas_paged.set_flash_enabled(None)
-            if form == "xla":
+            if form == "jnp":
                 want = got
             elif want and got:
                 row["largest_difference"] = {
                     n: worst(got[n], want[n]) for n in got}
                 row["largest_entry"] = {
-                    n: float(jnp.max(jnp.abs(
-                        want[n].astype(jnp.float32)))) for n in got}
+                    n: float(jnp.max(jnp.abs(want[n].astype(f32))))
+                    for n in got}
             rows.append(row)
             print(json.dumps(row), file=sys.stderr, flush=True)
     print(json.dumps({"platform": dev.platform,
                       "device_kind": dev.device_kind,
-                      "kernel": has_kernel, "rows": rows}), flush=True)
+                      "one_kernel": one_kernel, "rows": rows}), flush=True)
     return 0
 
 
