@@ -17,6 +17,13 @@ A from-scratch rebuild of the capabilities of vLLM Production Stack
 - ``utils/``    — logging, singletons, misc helpers.
 """
 
-from production_stack_tpu.version import __version__
+import time
+
+# the package's first import: what a start is counted from where the
+# platform does not say when the process began (engine/efficiency.py
+# ``process_start``)
+IMPORTED_UNIX = time.time()
+
+from production_stack_tpu.version import __version__  # noqa: E402
 
 __all__ = ["__version__"]

@@ -43,11 +43,15 @@ chip's bytes/s are never divided by another device's peak. The
 """
 
 import collections
+import os
 import threading
 import time
+import weakref
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+
+from production_stack_tpu import IMPORTED_UNIX as _IMPORTED_UNIX
 
 # XLA compile durations (seconds): compiles are seconds-scale events,
 # not milliseconds — a distinct bucket ladder from PHASE_BUCKETS
@@ -105,6 +109,214 @@ DEPTH_KEYS: Tuple[str, ...] = ("0", "1", "2", "3_or_more")
 # in-flight queue"): each names state that only the host holds.
 DRAIN_REASONS: Tuple[str, ...] = (
     "guided", "shaped", "resume", "speculation", "reshape", "pressure")
+
+# What a build is made of (docs/observability.md "Start-up and builds"):
+# the ``jax.monitoring`` events JAX emits while it traces, lowers and
+# compiles OR loads an executable, by the key their seconds are booked
+# under. ``backend_s`` is the compile, or on a hit of the persistent
+# cache the key, the read and the deserialisation.
+BUILD_PARTS: Dict[str, str] = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s"}
+# the persistent cache was asked for an executable, and held it. (JAX's
+# ``cache_misses`` says an entry was WRITTEN, which the cache's
+# thresholds of seconds and bytes decide: asked and not held is a miss.)
+CACHE_EVENTS: Dict[str, str] = {
+    "/jax/compilation_cache/compile_requests_use_cache": "asked",
+    "/jax/compilation_cache/cache_hits": "hits"}
+CACHE_SECONDS: Dict[str, str] = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s"}
+# the seconds of ``totals.builds``
+BUILD_SECONDS: Tuple[str, ...] = (
+    "wall_s", "trace_s", "lower_s", "backend_miss_s", "backend_hit_s",
+    "cache_load_s", "saved_s", "other_s")
+# the marks of a start, in the order a server reaches them
+STARTUP_MARKS: Tuple[str, ...] = (
+    "main", "engine_built", "serving", "first_request")
+
+
+def process_start(imported_unix: float) -> Tuple[float, str]:
+    """When the operating system started this process, as a unix time,
+    and where that was read: ``proc_stat`` (``/proc/self/stat``'s start
+    time in clock ticks since boot, against CLOCK_BOOTTIME now, so no
+    whole-second ``btime`` is in it). A launcher may import JAX and
+    ask for the devices before the server's ``main`` runs, so no stamp
+    taken by Python code can stand for it. Where the platform gives no
+    start time, or one after ``imported_unix`` (the package's first
+    import, which the process can only have reached later), the answer
+    is that import and ``package_import``."""
+    try:
+        with open("/proc/self/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+        ticks = int(fields[19])         # field 22: starttime
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+        started = time.time() - age
+        if ticks > 0 and age >= 0 and started <= imported_unix + 0.5:
+            return started, "proc_stat"
+    except (OSError, ValueError, IndexError, AttributeError):
+        pass
+    return imported_unix, "package_import"
+
+
+class BuildEvents:
+    """Where ``jax.monitoring``'s events go: the three functions the
+    engine registers with it once a process (this module stays off
+    JAX), and which accounting each event is booked to. While a build
+    is open on the calling thread (EngineEffAccounting.compile_started
+    to compile_finished, which ModelRunner._observed calls) what
+    arrives belongs to that build; with none open it goes to
+    ``unattributed`` of every accounting alive (a server has one), and
+    while none is alive it is kept for the next (an engine draws its
+    weights before its accounting exists).
+
+    JAX's durations nest (tracing ``f`` traces the jitted functions
+    ``f`` calls; lowering traces again), and a sum of them would count
+    a second twice or more. JAX says when each begins (a scalar of the
+    same name) before it says how long it took, so a stack a thread
+    keeps what lay inside each: a part is booked its OWN seconds, and
+    the parts of a build add up to no more than its wall.
+
+    ``calls`` counts the calls JAX made into the three functions (by
+    a plain add: near enough where two threads build at once, and
+    exact where nothing builds): a step served from the runner's table
+    makes none."""
+
+    def __init__(self):
+        self.calls = 0
+        self._here = threading.local()
+        self._live: "weakref.WeakSet[EngineEffAccounting]" = \
+            weakref.WeakSet()
+        # what arrived while no accounting was alive (an engine makes
+        # its weights before its accounting): the next one's
+        self._early = _new_unattributed()
+        self._lock = threading.Lock()
+
+    def follow(self, acct: "EngineEffAccounting") -> None:
+        """``acct`` takes the unattributed events from now on, and
+        those that arrived while nobody did."""
+        with self._lock:
+            early, self._early = self._early, _new_unattributed()
+            self._live.add(acct)
+        for key, amount in early.items():
+            acct.unattributed[key] += amount
+
+    def open(self, acct: "EngineEffAccounting") -> None:
+        self._here.build = (acct, _new_build())
+        self._here.stack = []
+
+    def close(self, acct: "EngineEffAccounting") -> Dict[str, float]:
+        """The parts of the build ``acct`` opened on this thread (all
+        zero where it opened none)."""
+        acct_open, build = getattr(self._here, "build", None) or (None, None)
+        if acct_open is not acct:
+            return _new_build()
+        self._here.build = None
+        return build
+
+    def _book(self, key: str, amount: float) -> None:
+        open_build = getattr(self._here, "build", None)
+        if open_build is not None:
+            open_build[1][key] += amount
+            return
+        with self._lock:
+            takers = list(self._live)
+            if not takers:
+                _book_unattributed(self._early, key, amount)
+        for acct in takers:
+            acct._unattributed(key, amount)
+
+    # -- jax.monitoring's listeners --------------------------------------
+
+    def began(self, event: str, value=None, **_) -> None:
+        """Scalar listener: a timed part begins on this thread."""
+        self.calls += 1
+        if event in BUILD_PARTS:
+            stack = self._here.__dict__.setdefault("stack", [])
+            stack.append([event, 0.0])
+
+    def lasted(self, event: str, seconds: float, **_) -> None:
+        """Duration listener: a timed part ends, or the cache says what
+        a load took and saved."""
+        self.calls += 1
+        part = BUILD_PARTS.get(event)
+        if part is None:
+            key = CACHE_SECONDS.get(event)
+            if key is not None:
+                self._book(key, seconds)
+            return
+        stack = getattr(self._here, "stack", None)
+        inside = 0.0
+        if stack and stack[-1][0] == event:
+            inside = stack.pop()[1]
+        if stack:
+            stack[-1][1] += seconds
+        self._book(part, max(0.0, seconds - inside))
+
+    def happened(self, event: str, **_) -> None:
+        """Event listener: the persistent cache was asked, or held
+        what it was asked for."""
+        self.calls += 1
+        key = CACHE_EVENTS.get(event)
+        if key is not None:
+            self._book(key, 1)
+
+
+def _new_build() -> Dict[str, float]:
+    return {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+            "cache_load_s": 0.0, "saved_s": 0.0, "asked": 0, "hits": 0}
+
+
+def _new_unattributed() -> Dict[str, float]:
+    return {"events": 0, "seconds": 0.0, "trace_s": 0.0, "lower_s": 0.0,
+            "backend_s": 0.0, "hits": 0, "misses": 0}
+
+
+def _book_unattributed(row: Dict[str, float], key: str,
+                       amount: float) -> None:
+    if key == "hits":
+        row["hits"] += 1
+        row["misses"] -= 1
+    elif key == "asked":
+        row["misses"] += 1      # until the cache says it held it
+    elif key in ("trace_s", "lower_s", "backend_s"):
+        row["events"] += 1
+        row["seconds"] += amount
+        row[key] += amount
+
+
+def _build_row(build: Dict[str, float], wall_s: float) -> Dict[str, object]:
+    """What a row of ``totals.compiles`` and an entry of the
+    ``compiles`` ring say of one build beside its wall seconds.
+    ``cache_hit``: the persistent cache held the executable (true), did
+    not (false: a miss among several decides), or was not asked (None:
+    no cache). ``other_s`` is the wall less the three parts: the
+    runner's ``make_fn``, its choice of path, its log line."""
+    asked = build["asked"]
+    return {"trace_s": build["trace_s"], "lower_s": build["lower_s"],
+            "backend_s": build["backend_s"],
+            "cache_hit": build["hits"] == asked if asked else None,
+            "cache_load_s": build["cache_load_s"],
+            "saved_s": build["saved_s"],
+            "other_s": wall_s - build["trace_s"] - build["lower_s"]
+            - build["backend_s"]}
+
+
+def _rounded(row: Dict[str, object]) -> Dict[str, object]:
+    return {k: round(v, 6) if isinstance(v, float) else v
+            for k, v in row.items()}
+
+
+def _less(now: Dict, before: Dict) -> Dict:
+    """``now`` less ``before``, two reports of ``totals.builds``."""
+    return {k: _less(v, before[k]) if isinstance(v, dict)
+            else round(v - before[k], 6) for k, v in now.items()}
+
+
+# the process's: jax.monitoring's registry is the process's too
+BUILD_EVENTS = BuildEvents()
 
 
 class _Span:
@@ -280,6 +492,12 @@ class EngineEffAccounting:
     close of every phase and the open of every step (the starved
     seconds). ``cpu_fn`` is the calling
     thread's processor clock, injectable as ``now_fn`` is.
+
+    ``process_start_unix`` is ``(unix time, source)`` of the process's
+    start (``process_start``; a test injects one): the zero of the
+    ``startup`` block's marks. What JAX says of a build while one is
+    open (BuildEvents) lands in that build's row; the engine registers
+    BUILD_EVENTS' three functions with ``jax.monitoring``.
     """
 
     def __init__(self, *, weight_bytes: int = 0,
@@ -291,7 +509,8 @@ class EngineEffAccounting:
                  wall_fn: Callable[[], float] = time.time,
                  annotate: Optional[Callable[[str], object]] = None,
                  queue_depth: Optional[Callable[[], int]] = None,
-                 cpu_fn: Callable[[], float] = time.thread_time):
+                 cpu_fn: Callable[[], float] = time.thread_time,
+                 process_start_unix: Optional[Tuple[float, str]] = None):
         self.weight_bytes = int(weight_bytes)
         self.kv_position_bytes = int(kv_position_bytes)
         # None = no known peak for this device: MBU is not reported
@@ -380,18 +599,37 @@ class EngineEffAccounting:
         self.bytes_total = 0
         self.bytes_effective = 0
         # XLA compile tracking:
-        # (kind, window, kv, batch) -> [count, total_s]
+        # (kind, window, kv, batch) -> [count, total_s, parts], parts
+        # the build's seconds by what JAX spent them on (_build_row)
         self.compiles: Dict[Tuple[str, int, int, int], List] = {}
         self.compiles_total = 0
         self.compile_s_total = 0.0
         self.compile_in_flight = 0
         self.last_compile_at: Optional[float] = None
+        # ``totals.builds``: the same builds summed by part, the builds
+        # by whether the persistent cache held them, and what JAX
+        # traced, lowered or compiled with no build open
+        self.builds: Dict[str, float] = {
+            "count": 0, "hits": 0, "misses": 0,
+            **dict.fromkeys(BUILD_SECONDS, 0.0)}
+        self.unattributed: Dict[str, float] = _new_unattributed()
+        # the ``startup`` block: marks in unix time (None: not reached),
+        # the runner's spans, ``totals.builds`` as it stood at
+        # ``serving``
+        self.process_start_unix, self.process_start_source = (
+            process_start_unix or process_start(_IMPORTED_UNIX))
+        self.startup_marks: Dict[str, Optional[float]] = dict.fromkeys(
+            STARTUP_MARKS)
+        self.startup_spans: Dict[str, Optional[float]] = {
+            "weights_s": None, "cache_alloc_s": None}
+        self._builds_before_serving: Optional[Dict] = None
         self._windows: "collections.deque[dict]" = collections.deque(
             maxlen=max(1, ring_entries))
-        # (start_mono, dur_s, kind, window, kv, batch, start_unix)
+        # (start_mono, dur_s, kind, window, kv, batch, start_unix, parts)
         self._compile_events: "collections.deque[tuple]" = \
             collections.deque(maxlen=128)
         self._lock = threading.Lock()
+        BUILD_EVENTS.follow(self)
         # step timeline (phase/step below). Totals and the ring are
         # read under the micro-lock; everything with a leading
         # underscore below is the engine thread's alone and is folded
@@ -737,6 +975,7 @@ class EngineEffAccounting:
     def compile_started(self, kind: str, window: int, kv_len: int,
                         batch: int = 0) -> None:
         self._compile_c0 = self._cpu()
+        BUILD_EVENTS.open(self)
         with self._lock:
             self.compile_in_flight += 1
 
@@ -744,6 +983,7 @@ class EngineEffAccounting:
                          started_at: float, dur_s: float,
                          batch: int = 0) -> None:
         key = (kind, int(window), int(kv_len), int(batch))
+        parts = _build_row(BUILD_EVENTS.close(self), dur_s)
         if self._stack:
             # a compile inside a phase of the step loop (they happen
             # on the engine thread, in the dispatch phases) is taken
@@ -757,20 +997,105 @@ class EngineEffAccounting:
             self._starve("compile", started_at + dur_s)
         with self._lock:
             self.compile_in_flight = max(0, self.compile_in_flight - 1)
-            slot = self.compiles.setdefault(key, [0, 0.0])
+            slot = self.compiles.setdefault(key, [0, 0.0, None])
             slot[0] += 1
             slot[1] += dur_s
+            slot[2] = parts if slot[2] is None else {
+                k: v if k == "cache_hit" else slot[2][k] + v
+                for k, v in parts.items()}
             self.compiles_total += 1
             self.compile_s_total += dur_s
             self.last_compile_at = started_at + dur_s
+            builds, hit = self.builds, parts["cache_hit"]
+            builds["count"] += 1
+            if hit is not None:
+                builds["hits" if hit else "misses"] += 1
+            builds["wall_s"] += dur_s
+            builds["backend_hit_s" if hit else "backend_miss_s"] += \
+                parts["backend_s"]
+            for k in ("trace_s", "lower_s", "cache_load_s", "saved_s",
+                      "other_s"):
+                builds[k] += parts[k]
             # wall-clock stamp of the compile START (this call runs at
             # compile END, so subtract the duration)
             self._compile_events.append(
                 (started_at, dur_s, kind, int(window), int(kv_len),
-                 int(batch), round(self._wall() - dur_s, 4)))
+                 int(batch), round(self._wall() - dur_s, 4), parts))
         if self.compile_hist is not None:
             self.compile_hist.observe(kind, str(window), str(kv_len),
                                       dur_s)
+
+    def _unattributed(self, key: str, amount: float) -> None:
+        """BuildEvents: JAX traced, lowered, compiled or asked its
+        cache with no build open (the weights' init and quantise jits,
+        the embeddings and prompt-logprobs functions, the KV extract
+        and inject functions, a compile an executable makes at its
+        first call, another thread's jit)."""
+        with self._lock:
+            _book_unattributed(self.unattributed, key, amount)
+
+    # -- the start, by its marks -----------------------------------------
+
+    def mark(self, name: str, at_unix: Optional[float] = None) -> None:
+        """The start reached ``name`` (of STARTUP_MARKS) now, or at
+        ``at_unix`` (``main`` is stamped before this object exists). A
+        mark is set once and never moves. At ``serving`` the builds so
+        far are frozen as ``before_serving``."""
+        with self._lock:
+            if self.startup_marks[name] is not None:
+                return
+            self.startup_marks[name] = (self._wall() if at_unix is None
+                                        else at_unix)
+            if name == "serving":
+                self._builds_before_serving = self._builds_report()
+
+    def _builds_report(self) -> Dict[str, object]:
+        """``totals.builds`` (under the lock). Rounded before the
+        subtraction, so that what is reported adds up: ``wall_s`` is
+        the four parts and ``other_s``."""
+        b = _rounded(self.builds)
+        b["other_s"] = round(
+            b["wall_s"] - b["trace_s"] - b["lower_s"]
+            - b["backend_miss_s"] - b["backend_hit_s"], 6)
+        b["unattributed"] = _rounded(self.unattributed)
+        return b
+
+    def startup_report(self) -> Dict[str, object]:
+        """The ``startup`` block of GET /debug/perf
+        (docs/observability.md "Start-up and builds"): the process's
+        start, the marks in seconds since it (None until reached), the
+        runner's spans, ``totals.builds`` as frozen at ``serving`` and
+        what has been built since (both None before it)."""
+        with self._lock:
+            now, before = self._builds_report(), self._builds_before_serving
+            marks = {k: None if v is None
+                     else round(v - self.process_start_unix, 4)
+                     for k, v in self.startup_marks.items()}
+            spans = dict(self.startup_spans)
+        return {
+            "process_start_unix": round(self.process_start_unix, 4),
+            "process_start_source": self.process_start_source,
+            "marks": marks, "spans": spans,
+            "before_serving": before,
+            "after_serving": None if before is None
+            else _less(now, before)}
+
+    def startup_sentence(self) -> str:
+        """The ``startup`` block in one log line, for the server to
+        say once ``serving`` is marked."""
+        r = self.startup_report()
+        b, m, sp = r["before_serving"], r["marks"], r["spans"]
+        return (
+            f"start: serving {m['serving']} s after the process began "
+            f"({r['process_start_source']}; main at {m['main']} s, engine "
+            f"built at {m['engine_built']} s); weights "
+            f"{sp['weights_s']} s, cache {sp['cache_alloc_s']} s; "
+            f"{b['count']} builds in {b['wall_s']} s "
+            f"({b['hits']} loaded, {b['misses']} compiled): trace "
+            f"{b['trace_s']} s, lower {b['lower_s']} s, compile "
+            f"{b['backend_miss_s']} s, load {b['backend_hit_s']} s, "
+            f"other {b['other_s']} s; outside any build "
+            f"{b['unattributed']['seconds']} s")
 
     # -- reads (off the hot path) ----------------------------------------
 
@@ -783,7 +1108,9 @@ class EngineEffAccounting:
         seconds), ``starved_s`` and ``starved_by_phase``,
         ``dispatch_depth`` (all DEPTH_KEYS), ``prefill_behind`` and
         ``prefill_drained`` (all DRAIN_REASONS). ``loop`` is the event
-        loop's account (LoopAccounting.report)."""
+        loop's account (LoopAccounting.report). ``builds`` sums the
+        builds by their parts, and a row of ``compiles`` holds its
+        own (docs/observability.md "Start-up and builds")."""
         with self._lock:
             moe = {"moe": {"experts_read": self.experts_read,
                            "experts_resident": self.experts_resident}
@@ -845,9 +1172,11 @@ class EngineEffAccounting:
                 "compile_in_flight": self.compile_in_flight,
                 "compiles": {f"{k}|{w}|{kv}|{b}":
                              {"count": c[0],
-                              "seconds": round(c[1], 4)}
+                              "seconds": round(c[1], 4),
+                              **_rounded(c[2])}
                              for (k, w, kv, b), c in
                              self.compiles.items()},
+                "builds": self._builds_report(),
                 "weight_bytes": self.weight_bytes,
                 "kv_position_bytes": self.kv_position_bytes,
                 "hbm_peak_bytes_per_s": self.hbm_peak_bytes_per_s,
@@ -945,8 +1274,9 @@ class EngineEffAccounting:
             events = list(self._compile_events)[-max(1, limit):]
         return [{"at": round(t, 4), "at_unix": wall,
                  "duration_s": round(d, 4),
-                 "kind": k, "window": w, "kv_bucket": kv, "batch": b}
-                for t, d, k, w, kv, b, wall in events]
+                 "kind": k, "window": w, "kv_bucket": kv, "batch": b,
+                 **_rounded(parts)}
+                for t, d, k, w, kv, b, wall, parts in events]
 
     def compile_events_between(self, t0: float, t1: float
                                ) -> List[Tuple[float, float, str, int,

@@ -121,6 +121,27 @@ class DeadlineExceeded(Exception):
     was still WAITING; the scheduler dropped it before prefill. The
     server maps this to 504 with an x-deadline-expired marker."""
 
+
+_LISTENING = False      # this process's jax.monitoring has the listeners
+
+
+def _listen_to_builds() -> None:
+    """Register efficiency.BUILD_EVENTS with ``jax.monitoring``, once a
+    process (its registry is the process's, and only grows): the
+    scalar that opens a timed part, the duration that closes it, the
+    cache's events. They fire only when JAX traces, lowers, compiles
+    or loads; a step served from the runner's table reaches none."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    _LISTENING = True
+    from production_stack_tpu.engine.efficiency import BUILD_EVENTS
+    jax.monitoring.register_scalar_listener(BUILD_EVENTS.began)
+    jax.monitoring.register_event_duration_secs_listener(
+        BUILD_EVENTS.lasted)
+    jax.monitoring.register_event_listener(BUILD_EVENTS.happened)
+
+
 class LLMEngine:
     def __init__(self, engine_cfg: EngineConfig, params=None, mesh=None):
         self.cfg = engine_cfg
@@ -144,8 +165,10 @@ class LLMEngine:
         self.tokenizer = load_tokenizer(engine_cfg.model,
                                         engine_cfg.tokenizer,
                                         engine_cfg.chat_template)
+        load_t0 = time.monotonic()
         if params is None and engine_cfg.checkpoint:
             params = load_checkpoint(self.model_cfg, engine_cfg.checkpoint)
+        weights_loaded_s = time.monotonic() - load_t0
         # multi-LoRA: every adapter is served as its own model id; the
         # stacked adapter pytree rides in the runner, rows select their
         # adapter per request (reference surface: --enable-lora +
@@ -198,6 +221,9 @@ class LLMEngine:
         # entry for this device kind, else none — never another chip's
         from production_stack_tpu.engine.efficiency import (
             HBM_PEAK_GBPS, EngineEffAccounting)
+        # before the first jit (the weights' init): what JAX traces,
+        # lowers, compiles or loads is told to the accounting
+        _listen_to_builds()
         from production_stack_tpu.ops import pallas_paged
         self.devices = (list(mesh.devices.flat) if mesh is not None
                         else jax.devices()[:1])
@@ -213,7 +239,8 @@ class LLMEngine:
             jax.device_count(), peak_gbps, pallas_paged.mode())
         self.runner = ModelRunner(self.model_cfg, engine_cfg, params=params,
                                   mesh=mesh, lora_stacked=lora_stacked,
-                                  lora_scaling=lora_scaling)
+                                  lora_scaling=lora_scaling,
+                                  weights_loaded_s=weights_loaded_s)
         self.scheduler = Scheduler(engine_cfg.max_num_seqs,
                                    engine_cfg.max_model_len,
                                    engine_cfg.prefill_chunk)
@@ -303,6 +330,9 @@ class LLMEngine:
         # layers that read another layer's K/V (0: every layer its own)
         self._cross_layers = mc.reader_layers - mc.attn_layers
         self.runner.compile_observer = self.eff
+        # the ``startup`` block's spans are the runner's own dict: its
+        # ``weights_s`` arrives when the device has the parameters
+        self.eff.startup_spans = self.runner.startup_spans
         # advertised once: the router's per-endpoint concurrency cap
         # reads this gauge (0 = unbounded admission, nothing to cap on)
         self.metrics.capacity.set(
@@ -524,6 +554,8 @@ class LLMEngine:
                     seq_id: Optional[str] = None,
                     model: Optional[str] = None,
                     deadline: Optional[float] = None) -> str:
+        if self.eff.startup_marks["first_request"] is None:
+            self.eff.mark("first_request")
         seq_id = seq_id or f"seq-{next(self._id_counter)}"
         options = options or SamplingOptions()
         if options.logit_bias:

@@ -34,6 +34,7 @@ is the TPU-native core the stack serves from.
 """
 
 import contextlib
+import threading
 import time
 from functools import partial
 
@@ -86,7 +87,11 @@ def _carry_edit_impl(tokens, positions, gstate, slots, new_tokens,
 class ModelRunner:
     def __init__(self, model_cfg: ModelConfig, engine_cfg: EngineConfig,
                  params=None, mesh=None, lora_stacked=None,
-                 lora_scaling: float = 1.0):
+                 lora_scaling: float = 1.0,
+                 weights_loaded_s: float = 0.0):
+        """``weights_loaded_s``: the seconds the caller spent loading
+        ``params`` (a checkpoint's read), which the ``startup`` block's
+        ``weights_s`` counts with what is spent on them here."""
         self.model_cfg = model_cfg
         self.engine_cfg = engine_cfg
         self.mesh = mesh
@@ -111,8 +116,8 @@ class ModelRunner:
         self.rope = rope_table(engine_cfg.max_model_len, model_cfg.rope_dim_,
                                model_cfg.rope_theta,
                                scaling=model_cfg.rope_scaling)
+        t0 = time.monotonic() - weights_loaded_s
         if params is None:
-            t0 = time.time()
             # quantized leaf by leaf as it is made (llama.init_params):
             # the full-precision tree of a model that needs
             # --quantization to fit never exists
@@ -120,7 +125,7 @@ class ModelRunner:
                 model_cfg, jax.random.PRNGKey(engine_cfg.seed),
                 quantization=engine_cfg.quantization)
             logger.info("random-initialized %s (%.2fs)", model_cfg.name,
-                        time.time() - t0)
+                        time.monotonic() - t0)
         elif engine_cfg.quantization == "int8":
             from production_stack_tpu.models import quant
             # loaded checkpoint: donate, so XLA may free each fp buffer
@@ -165,6 +170,7 @@ class ModelRunner:
         # layer is a power retention layer (models/kv.cache_for; it
         # refuses an int8 latent pool and an int8 pool beside state
         # pages by name)
+        cache_t0 = time.monotonic()
         self.cache: KVCache = cache_for(
             model_cfg, n_blocks, engine_cfg.kv_block_size, dtype=kv_dt,
             state_pages=(engine_cfg.max_num_seqs + 1
@@ -172,6 +178,13 @@ class ModelRunner:
         self._tables = jnp.zeros(self.table_shape, jnp.int32)
         self._tables_host = np.zeros(self.table_shape, np.int32)
         self._tables_dirty = False
+        # the ``startup`` block's spans (GET /debug/perf): the pool and
+        # the tables as the host saw them made, and the weights until
+        # they are READY ON THE DEVICE, which a thread waits for (below)
+        # so that the start itself never does
+        self.startup_spans = {
+            "weights_s": None,
+            "cache_alloc_s": round(time.monotonic() - cache_t0, 4)}
         if mesh is not None:
             # tensor-parallel serving: weights/cache sharded over the
             # slice's chips; XLA derives all ICI collectives from here
@@ -245,6 +258,13 @@ class ModelRunner:
                     self._lora, NamedSharding(mesh, PartitionSpec()))
         else:
             self._tables_sharding = None
+
+        def stamp_weights(ready=self.params):
+            jax.block_until_ready(ready)
+            self.startup_spans["weights_s"] = round(
+                time.monotonic() - t0, 4)
+        threading.Thread(target=stamp_weights, name="pstpu-weights-ready",
+                         daemon=True).start()
         self._key = jax.random.PRNGKey(engine_cfg.seed ^ 0x5EED)
         # device-carried decode inputs: (tokens [B], positions [B]);
         # refreshed from host mirrors only when the engine marks them stale
@@ -1044,7 +1064,10 @@ class ModelRunner:
 
     @contextlib.contextmanager
     def _observed(self, kind: str, window: int, kv_len: int, batch: int):
-        """Stamp the compile made inside through ``compile_observer``."""
+        """Stamp the build made inside through ``compile_observer``:
+        while it is open on this thread, what JAX says it traced,
+        lowered, compiled or loaded is booked to it
+        (efficiency.BuildEvents)."""
         obs = self.compile_observer
         t0 = time.monotonic()
         if obs is not None:
@@ -1058,20 +1081,24 @@ class ModelRunner:
 
     def _compile(self, cache: dict, key, make_fn, args, *, kind: str,
                  window: int, kv_len: int, batch: int, positions: int):
-        """Fetch-or-compile an executable. ``positions`` is its query
+        """Fetch-or-build an executable. ``positions`` is its query
         positions per row (1 decode, draft+1 speculative, the chunk
         bucket for prefill): with the static config that fixes its
         attention path (``_attention_path``), which is logged once and
         kept for the ``device`` block of GET /debug/perf. The path is
         chosen by shape before compiling and never changed after: a
         kernel the compiler refuses raises, naming the executable.
-        Compilation is an explicit lower+compile BEFORE any buffers are
+        The build is an explicit lower+compile BEFORE any buffers are
         donated, so the error leaves the cache buffer alive.
 
-        Every cache miss is stamped through ``compile_observer`` (kind,
-        window, kv bucket, wall duration): compiles block the engine
-        loop for seconds, so they must be countable and visible in
-        /debug/traces, not just log lines."""
+        Every miss of ``cache`` (the runner's own table) is a build,
+        stamped through ``compile_observer`` (kind, window, kv bucket,
+        wall duration, and by its parts: traced, lowered, then compiled
+        OR loaded from JAX's persistent cache, which only the
+        accounting's ``cache_hit`` tells apart): builds block the
+        engine loop for seconds, so they must be countable and visible
+        in /debug/traces, not just log lines. A hit of the table makes
+        no call into the accounting."""
         fn = cache.get(key)
         if fn is not None:
             return fn

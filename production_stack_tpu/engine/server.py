@@ -1203,7 +1203,9 @@ async def debug_perf(request: web.Request) -> web.Response:
     """``GET /debug/perf``: the engine-efficiency ring — recent
     window-level real/pad/dead breakdowns, the step timeline's recent
     steps (seconds per phase), the loop timeline's recent lag samples
-    (``loop``), recent XLA compile events,
+    (``loop``), recent XLA compile events (each build by its parts),
+    the ``startup`` block (the start by its marks, the builds before
+    and after ``serving``),
     cumulative totals + rates, the KV block pool's fragmentation
     census, and the ``device`` block (platform, device kind and count,
     bytes in use per device, the attention path of every compiled
@@ -1219,6 +1221,7 @@ async def debug_perf(request: web.Request) -> web.Response:
         limit = 50
     return web.json_response({
         "device": eng.device_report(),
+        "startup": eng.eff.startup_report(),
         "totals": eng.eff.report(),
         "rates": eng.eff.rates(),
         "windows": eng.eff.recent_windows(limit),
@@ -1649,6 +1652,7 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 
 def main(argv=None) -> None:
+    main_unix = time.time()     # the ``startup`` block's first mark
     args = parse_args(argv)
     logger.info("compile cache: %s", place_compile_cache())
     set_ulimit()
@@ -1697,6 +1701,9 @@ def main(argv=None) -> None:
     engine = AsyncLLMEngine(cfg)
     if not args.no_warmup:
         engine.engine.runner.warmup()
+    eff = engine.engine.eff
+    eff.mark("main", main_unix)
+    eff.mark("engine_built")
 
     async def _serve():
         app = build_app(engine,
@@ -1714,8 +1721,10 @@ def main(argv=None) -> None:
         await runner.setup()
         site = web.TCPSite(runner, args.host, args.port)
         await site.start()
+        eff.mark("serving")
         logger.info("engine serving %s on %s:%d", cfg.model, args.host,
                     args.port)
+        logger.info(eff.startup_sentence())
         while True:
             await asyncio.sleep(3600)
 
