@@ -56,7 +56,9 @@ helm/templates/deployment-vllm-multi.yaml:57-64; expert parallelism is a
   its router's experts (below) only the assignments that name a held
   expert are laid out at all: their list is compacted, and the
   grouping, the kernel and the sum run on a static block of it at a
-  time, in as many rounds as the routing filled.
+  time, in as many rounds as the routing filled; a round's sum by
+  token is a second Pallas call, one pass over the block
+  (``_held_sum``).
 
   **Capacity dispatch (large N where the kernels are off or the mesh
   shards the experts).** The GShard/Switch
@@ -670,9 +672,15 @@ def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
 # (token, expert, weight) entries are grouped as B tokens of one choice
 # each (rank and segments from a one-hot [B, E], a buffer of B + E
 # (align - 1) + slack rows), the same Pallas call multiplies them, B
-# rows are gathered back and weighed in float32, and each is added to
-# its token's row of the [N, h] float32 sum (a scatter-add whose
-# indices are sorted: the list is in token order). No capacity and no
+# rows are gathered back as they are, and ``_held_sum`` adds each,
+# weighed in float32, to its token's row of the [N, h] float32 sum.
+# The block is in token order, so the entries of a tile of 128 tokens
+# are one contiguous window of it: a second Pallas call a round,
+# ``moe_held_sum``, walks the block ONCE, slab of 128 entries after
+# slab, and adds a slab to a tile's rows as the product of a [128
+# tokens, 128 entries] matrix of the weights with it (no gather, no
+# scatter; nothing past the live entries is read; to PR 52 a row
+# scatter-add, 88 ns a row whatever was live). No capacity and no
 # second path: with every assignment here the loop runs N k / B rounds,
 # each reading the hit experts again (about 1.6-1.8 x the time of the
 # path sized by N k at that routing: tools/moe_prefill_table.py
@@ -851,7 +859,14 @@ def _grouped_products(x, top_i, tokens, gate, up, down, act, valid, layer,
     segment, and each assignment's row is gathered back: ([M c, h]
     float32, zeros where the assignment is left out: an invalid row of
     top_i or, ``held``, an expert named E; the experts that had a row;
-    the rows the experts multiplied: passes x GROUPED_ROWS)."""
+    the rows the experts multiplied: passes x GROUPED_ROWS). ``held``
+    (a round of the held experts, whose sum by token is a kernel's,
+    ``_held_sum``): the rows come back as the kernel wrote them, a
+    list of [M c, h] in x's dtype, one plane a tile of an expert
+    (``expert_tiles``), whose float32 sum is the assignment's row; an
+    assignment left out holds the buffer's row 0, some expert's
+    product, which its weight of zero takes out of the sum (a select
+    over the block here was a pass of its own, 35 us a layer in N)."""
     quant = _quant().is_quantized(gate)
     h = x.shape[1]
     L, E, _, inter = _wshape(gate)
@@ -911,10 +926,13 @@ def _grouped_products(x, top_i, tokens, gate, up, down, act, valid, layer,
         # and counts as zero
         kept = dest < P
         back = jnp.where(kept, dest, 0)
-        y = ys[back].astype(jnp.float32)
-        for t in range(1, tiles):       # an expert's tiles, a plane each
-            y = y + ys[back + t * P].astype(jnp.float32)
-        y = jnp.where(kept[:, None], y, 0.0)
+        if held:        # an expert's tiles, a plane each, as they are
+            y = [ys[back + t * P] for t in range(tiles)]
+        else:
+            y = ys[back].astype(jnp.float32)
+            for t in range(1, tiles):
+                y = y + ys[back + t * P].astype(jnp.float32)
+            y = jnp.where(kept[:, None], y, 0.0)
     return y, count, jnp.sum(passes) * R
 
 
@@ -930,6 +948,147 @@ def held_block(tokens: int, top_k: int, num_experts: int,
     assignments = tokens * top_k
     even = -(-assignments * num_experts // router_experts)
     return min(-(-HELD_BLOCK_SHARES * even // R), -(-assignments // R)) * R
+
+
+def _held_sum_tile(tokens: int) -> int:
+    """The tokens a grid step of ``_held_sum`` takes: GROUPED_ROWS (the
+    MXU's rows), or all of a shorter chunk in whole sublanes."""
+    return min(GROUPED_ROWS, -(-tokens // 8) * 8)
+
+
+def _held_sum_kernel(first_ref, acc_ref, tok_ref, weight_ref, *refs):
+    """One tile of T tokens: its rows of the sum plus what the tile's
+    window of the block gives them.
+
+    first_ref  (SMEM) [tiles + 1]  where each tile's entries start in
+                                   the block; the last: the live entries
+    acc_ref    [T, h] fp32         the tile's rows of the sum so far
+    tok_ref    [B / R, 1, R] int32 each entry's token, a slab a row
+    weight_ref [B / R, 1, R] fp32  each entry's routing weight
+    refs   the planes (HBM) [B, h] each; out [T, h] fp32 (acc's own
+           buffer); scratch: the slabs' slots [2, planes, R, h], their
+           semaphores [2, planes], slabs arrived (SMEM) [1]
+
+    The block is walked ONCE over the whole grid: its slabs of R
+    entries are copied in one after the other, slab s into slot s % 2
+    while slab s - 1 is multiplied, and a slab that two tiles' windows
+    share stays where it is for the second. A slab is added to the
+    tile's rows as W @ slab with W[t, e] = the entry's weight where its
+    token is the tile's row t, else 0: W enters the MXU as its three
+    bfloat16 terms (a float32 exactly) against a bfloat16 slab, every
+    product exact and summed in float32; float32 slabs (tests) multiply
+    at full precision."""
+    planes = len(refs) - 4
+    hbm, (out_ref, buf, sems, arrived) = refs[:planes], refs[planes:]
+    i = pl.program_id(0)
+    T = acc_ref.shape[0]
+    R = buf.shape[2]
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    slabs = pl.cdiv(first_ref[pl.num_programs(0)], R)
+
+    def copies(s):
+        slot = jax.lax.rem(s, 2)
+        return [pltpu.make_async_copy(
+            hbm[p].at[pl.ds(pl.multiple_of(s * R, R), R)],
+            buf.at[slot, p], sems.at[slot, p]) for p in range(planes)]
+
+    @pl.when(i == 0)
+    def _first():
+        arrived[0] = 0
+
+        @pl.when(slabs > 0)
+        def _():
+            for cp in copies(0):
+                cp.start()
+
+    out_ref[...] = acc_ref[...]
+    lo, hi = first_ref[i], first_ref[i + 1]
+    row = i * T + jax.lax.broadcasted_iota(jnp.int32, (T, R), 0)
+
+    def one_slab(s, carry):
+        @pl.when(s == arrived[0])
+        def _arrive():
+            for cp in copies(s):
+                cp.wait()
+            arrived[0] = s + 1
+
+            @pl.when(s + 1 < slabs)
+            def _ahead():
+                for cp in copies(s + 1):
+                    cp.start()
+
+        slot = jax.lax.rem(s, 2)
+        w = jnp.where(tok_ref[s] == row, weight_ref[s], 0.0)      # [T, R]
+        terms, exact = [w], jax.lax.Precision.HIGHEST
+        if buf.dtype == bf16:
+            terms, exact = [], None
+            for _ in range(3):
+                terms.append(w.astype(bf16))
+                w = w - terms[-1].astype(f32)
+        for p in range(planes):
+            slab = buf[slot, p]
+            out_ref[...] += sum(
+                jnp.dot(t, slab, precision=exact, preferred_element_type=f32)
+                for t in terms)
+        return carry
+
+    jax.lax.fori_loop(lo // R, jnp.where(hi > lo, pl.cdiv(hi, R), lo // R),
+                      one_slab, 0)
+
+
+def _held_sum(acc, planes, tok, weight, tokens: int):
+    """acc [Np, h] float32 (Np: ``tokens`` rounded up to whole tiles)
+    plus a round's block by token: acc[tok[e]] += weight[e] x the sum
+    of the planes' rows e ([B, h] each, in the activations' dtype, B a
+    multiple of GROUPED_ROWS), for the entries whose tok is under
+    ``tokens``. tok [B] is non-decreasing (the block is the compacted
+    list in token order; the entries past its end name ``tokens``,
+    weigh zero and hold finite rows: those of the last live slab are
+    multiplied by their zero), so
+    the entries of a tile of tokens are one contiguous window of the
+    block: a prefix count at the tiles' boundaries gives the windows,
+    and one Pallas call, ``moe_held_sum``, walks the block once
+    (``_held_sum_kernel``): no gather, no scatter, nothing past the
+    live entries read, acc updated in place."""
+    Np, h = acc.shape
+    B = tok.shape[0]
+    R = GROUPED_ROWS
+    T = _held_sum_tile(tokens)
+    tiles = Np // T
+    bounds = jnp.minimum(jnp.arange(tiles + 1, dtype=jnp.int32) * T, tokens)
+    first = jnp.sum(tok[None, :] < bounds[:, None], axis=1, dtype=jnp.int32)
+
+    def whole(i, first):
+        return (0, 0, 0)
+
+    def tile(i, first):
+        return (i, 0)
+
+    return pl.pallas_call(
+        _held_sum_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(tiles,),
+            in_specs=[pl.BlockSpec((T, h), tile),
+                      pl.BlockSpec((B // R, 1, R), whole),
+                      pl.BlockSpec((B // R, 1, R), whole)]
+            + [pl.BlockSpec(memory_space=pltpu.HBM)] * len(planes),
+            out_specs=pl.BlockSpec((T, h), tile),
+            scratch_shapes=[
+                pltpu.VMEM((2, len(planes), R, h), planes[0].dtype),
+                pltpu.SemaphoreType.DMA((2, len(planes))),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=pallas_paged.VMEM_LIMIT_BYTES),
+        interpret=pallas_paged.needs_interpret(),
+        name="moe_held_sum",
+    )(first, acc, tok.reshape(B // R, 1, R),
+      weight.astype(jnp.float32).reshape(B // R, 1, R), *planes)
 
 
 def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer,
@@ -948,9 +1107,12 @@ def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer,
     the routing filled (a traced trip count: one at any routing near
     even, N k / block with every assignment here, none and zeros out
     with none). A round runs the grouping and the kernel on its block
-    as that many tokens of one choice each, weighs what comes back and
-    adds it to its token's row of the [N, h] float32 sum (the block is
-    in token order). Nothing of N k rows by E or by h is built."""
+    as that many tokens of one choice each, and ``_held_sum`` weighs
+    what comes back and adds it to its token's row of the [N, h]
+    float32 sum in one pass over the block, which is in token order
+    (the sum is the loop's carry, updated in place; what differs from
+    a scatter-add of the weighed rows is the order of a token's terms,
+    nothing else). Nothing of N k rows by E or by h is built."""
     N, h = x.shape
     k = top_i.shape[1]
     E = _wshape(gate)[1]
@@ -991,17 +1153,18 @@ def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer,
             tok = jnp.where(live, a // k, N)
             expert = jnp.where(live, flat_e[a], E)
             weight = jnp.where(live, flat_p[a], 0.0)
-        y, count, rows = _grouped_products(
+        planes, count, rows = _grouped_products(
             x, expert[:, None], tok, gate, up, down, act, None, layer, True)
         with jax.named_scope("moe_combine"):
-            acc = acc.at[tok].add(y * weight[:, None], mode="drop",
-                                  indices_are_sorted=True)
+            acc = _held_sum(acc, planes, tok, weight, N)
         return acc, read + count, multiplied + rows
 
+    T = _held_sum_tile(N)
     acc, read, multiplied = jax.lax.fori_loop(
         0, rounds, one_round,
-        (jnp.zeros((N, h), jnp.float32), jnp.int32(0), jnp.int32(0)))
-    return acc.astype(x.dtype), _work(read, multiplied, total, rounds)
+        (jnp.zeros((-(-N // T) * T, h), jnp.float32), jnp.int32(0),
+         jnp.int32(0)))
+    return acc[:N].astype(x.dtype), _work(read, multiplied, total, rounds)
 
 
 def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,

@@ -673,6 +673,8 @@ def test_prefill_chunk_multiplies_only_routed_rows(tpu_branches,
     hlo = step_program(model, layers, tokens=256, rows=rows).hlo
     assert "moe_grouped_experts" in hlo
     assert "moe_list_experts" not in hlo
+    # (every expert is held: no rounds, so no sum by token, PR 53)
+    assert "moe_held_sum" not in hlo
     E, h, i = (experts[n] for n in "Ehi")
     per_expert = [m.group(0) for m in re.finditer(
         r"\w+\[{},\d+,(?:{}|{})\]".format(E, i, h), hlo)]
@@ -981,7 +983,7 @@ def test_sparse_step_program_compiles_at_glm5_widths(
                             rep((1,), jnp.int32), rep((1, 2048), jnp.int32),
                             rep((1,), jnp.int32), rep((1,), jnp.int32),
                             *small).compile()
-        want = {"paged_attention", "moe_grouped_experts"}
+        want = {"paged_attention", "moe_grouped_experts", "moe_held_sum"}
     if runner.selects(kv_len):
         want |= {"dsa_index_scores", "dsa_select"}
     hlo = compiled.as_text()
@@ -1003,6 +1005,10 @@ def test_sparse_step_program_compiles_at_glm5_widths(
             r"(?:bf16|f32)\[(?:\d+,)?(?:16384|16752|33504),6144\]", hlo)
         assert not big, big[:3]
         assert re.search(r"bf16\[4832,6144\]", hlo)   # two tiles' planes
+        # and a round's sum by token is the kernel's one pass over the
+        # block (``moe_held_sum``), no scatter into the float32 sum
+        scattered = re.findall(r"= f32\[2048,6144\]\S* scatter\(", hlo)
+        assert not scattered, scattered[:3]
     runner.params = params      # (_moe_path reads the stacks' dtype)
     assert runner._moe_path(8, 1) == "list_tiled2"
     assert runner._moe_path(1, 2048) == "grouped_tiled2"
@@ -1113,7 +1119,8 @@ def test_hybrid_step_program_compiles_at_qwen3next_widths(
                             rep((1,), jnp.int32), rep((1, 2048), jnp.int32),
                             rep((1,), jnp.int32), rep((1,), jnp.int32),
                             *small).compile()
-        want = {"paged_attention", "moe_grouped_experts", "gdn_chunk_scan"}
+        want = {"paged_attention", "moe_grouped_experts", "moe_held_sum",
+                "gdn_chunk_scan"}
         path = "pallas_paged"
     hlo = compiled.as_text()
     calls = {m.group(1) for m in re.finditer(
@@ -1155,6 +1162,10 @@ def test_hybrid_step_program_compiles_at_qwen3next_widths(
             r"(?:bf16|f32)\[(?:\d+,)?(?:20480|21568),2048\]", hlo)
         assert not big, big[:3]
         assert re.search(r"bf16\[6208,2048\]", hlo)
+        # and a round's sum by token is the kernel's one pass over the
+        # block (``moe_held_sum``), no scatter into the float32 sum
+        scattered = re.findall(r"= f32\[2048,2048\]\S* scatter\(", hlo)
+        assert not scattered, scattered[:3]
     assert (compiled.memory_analysis().alias_size_in_bytes
             >= 2 * P * N * 2 * BS * 256 * 2 + 3 * P * 9 * 32 * 128 * 128 * 4)
     _fits(compiled, f"qwen3-next share {program}")

@@ -821,6 +821,98 @@ def test_held_grouped_path_matches_exact_and_reference(
         assert (got == 0).all()
 
 
+# the rounds' sum by token alone (``_held_sum``), against the row
+# scatter-add it took the place of
+HELD_SUM_CASES = {
+    # name: (tokens, block, entries of each token in order (the tokens
+    #        after them have none), dtype, planes)
+    # 384 tokens are three tiles of 128, a block of 512 four slabs
+    "near-even": (384, 512, "even", "bfloat16", 1),
+    "a-token-with-no-entry": (384, 512, [1, 0, 2, 0, 0, 3] * 64, "bfloat16",
+                              1),
+    # token 40's ten entries are the block's 120..129
+    "a-token-straddles-two-slabs": (384, 512, [3] * 40 + [10] + [1] * 300,
+                                    "bfloat16", 1),
+    "a-tile-with-an-empty-window": (384, 512, [2] * 128 + [0] * 128
+                                    + [1] * 128, "bfloat16", 1),
+    "a-leading-tile-with-an-empty-window": (384, 512, [0] * 200 + [2] * 184,
+                                            "bfloat16", 1),
+    "total-0": (384, 512, [], "bfloat16", 1),
+    "total-the-block": (384, 512, [4] * 128, "bfloat16", 1),
+    "total-a-whole-slab": (384, 512, [1] * 256, "bfloat16", 1),
+    "two-planes": (384, 512, "even", "bfloat16", 2),
+    "float32-rows": (384, 512, "even", "float32", 1),
+    "a-chunk-shorter-than-a-tile": (72, 128, [1, 2] * 36, "bfloat16", 1),
+    "a-chunk-of-one-tile-and-a-part": (200, 256, [1] * 200, "bfloat16", 2),
+}
+
+
+@pytest.mark.parametrize("case", HELD_SUM_CASES)
+def test_held_sum_kernel_matches_the_scatter_add(kernels_on, case):
+    """``_held_sum`` (one pass over the round's block) against the row
+    scatter-add of the weighed rows: equal to float32 reassociation,
+    1e-6 of the largest entry, with weights whose low mantissa bits
+    matter (rounded to bfloat16 they miss that by a hundredfold), on
+    top of a sum that already holds something; rows of tokens with no
+    entry come back as they went in, and what lies past the live
+    entries adds nothing."""
+    N, B, counts, dtype, planes = HELD_SUM_CASES[case]
+    h = 256
+    rng = np.random.default_rng(len(case))
+    if isinstance(counts, str):
+        counts = rng.binomial(10, 0.125, N)
+    counts = np.concatenate([counts, np.zeros(N - len(counts), int)]
+                            ).astype(int)
+    live = int(counts.sum())
+    assert live <= B and B % moe.GROUPED_ROWS == 0
+    tok = np.full(B, N, np.int32)
+    tok[:live] = np.repeat(np.arange(N), counts)
+    weight = rng.uniform(0.01, 1.0, B).astype(np.float32)
+    weight[live:] = 0.0
+    ys = [jnp.asarray(rng.normal(size=(B, h)), jnp.float32).astype(dtype)
+          for _ in range(planes)]
+    T = moe._held_sum_tile(N)
+    Np = -(-N // T) * T
+    acc = rng.normal(size=(Np, h)).astype(np.float32)
+
+    def scattered(weight):
+        y = sum(p.astype(jnp.float32) for p in ys)
+        return np.asarray(jnp.asarray(acc).at[jnp.asarray(tok)].add(
+            y * jnp.asarray(weight)[:, None], mode="drop",
+            indices_are_sorted=True))
+
+    got = np.asarray(jax.jit(lambda acc, *ys: moe._held_sum(
+        acc, ys, jnp.asarray(tok), jnp.asarray(weight), N))(acc, *ys))
+    want = scattered(weight)
+    assert got.shape == want.shape and got.dtype == np.float32
+    scale = np.abs(want).max()
+    assert np.abs(got - want).max() <= 1e-6 * scale
+    none = np.flatnonzero(counts == 0)
+    assert (got[none] == acc[none]).all() and (got[N:] == acc[N:]).all()
+    if live and dtype == "bfloat16":
+        rounded = np.asarray(jnp.asarray(weight).astype(jnp.bfloat16)
+                             .astype(jnp.float32))
+        assert np.abs(scattered(rounded) - want).max() > 1e-4 * scale
+
+
+def test_held_sum_is_one_named_call_in_place():
+    """The rounds' sum is the Pallas call ``moe_held_sum`` (the name a
+    capture's breakdown and tests/test_chip_compile.py tell it by), a
+    grid step a tile of 128 tokens, and the sum goes in and out through
+    one buffer, as the rounds' carry does."""
+    acc = jnp.zeros((256, 256), jnp.float32)
+    ys = jnp.zeros((256, 256), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda acc, ys: moe._held_sum(
+        acc, [ys], jnp.zeros((256,), jnp.int32),
+        jnp.zeros((256,), jnp.float32), 256))(acc, ys).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "moe_held_sum"
+    assert tuple(call.params["input_output_aliases"]) == ((1, 0),)
+    assert call.params["grid_mapping"].grid == (2,)
+    assert not {"scatter-add", "gather", "while"} & {
+        e.primitive.name for e in jaxpr.eqns}
+
+
 def _primitives(jaxpr):
     """The primitives of a traced program by name, those of the loops'
     and calls' bodies too, a Pallas kernel's own left out."""
@@ -838,7 +930,8 @@ def test_grouped_path_runs_no_rounds_where_every_expert_is_held(
         kernels_on):
     """The router scores exactly the stacks' experts: no loop over
     rounds, no sum by token, and ``Work`` counts none; told of a wider
-    router the same call traces both."""
+    router the same call traces the loop, whose sum by token is a
+    second Pallas call and no scatter."""
     x, top_p, top_i, stacks, _ = _held_case("near-even-one-round")
     top_i = jnp.minimum(top_i, 7)
 
@@ -851,7 +944,9 @@ def test_grouped_path_runs_no_rounds_where_every_expert_is_held(
         names = traced(own)
         assert names.count("pallas_call") == 1
         assert not {"while", "scatter-add"} & set(names)
-    assert {"while", "scatter-add", "pallas_call"} <= set(traced(64))
+    held = traced(64)
+    assert {"while", "pallas_call"} <= set(held)
+    assert "scatter-add" not in held and held.count("pallas_call") == 2
     _, work = moe._moe_grouped(x, top_p, top_i, *stacks, jax.nn.silu, None,
                                jnp.int32(0))
     assert int(work.held_rows) == 0 and int(work.rounds) == 0
@@ -1143,7 +1238,10 @@ def test_the_held_table_tool_rehearses_on_the_cpu():
     in interpret mode (a process of its own: the tool sets the block's
     shares and the kernels' switch for itself): a row for each block
     size and routing, whose rounds are what its kept assignments fill,
-    and with the selection forced here more land here than as routed."""
+    and with the selection forced here more land here than as routed;
+    the rows of the program's own block time a round's sum by token
+    alone, the scatter-add beside the kernel, on the host's clock here
+    and saying so, and the two agree to float32 reassociation."""
     import json
     import os
     import subprocess
@@ -1152,7 +1250,7 @@ def test_the_held_table_tool_rehearses_on_the_cpu():
     proc = subprocess.run(
         [sys.executable, os.path.join(root, "tools", "moe_prefill_table.py"),
          "--held", "--allow-cpu", "--repeat", "1", "--layers", "1",
-         "--stack", "1", "--held-models", "glm5-share"],
+         "--stack", "1", "--held-models", "glm5-share", "--sum-rounds", "2"],
         capture_output=True, text=True, timeout=600,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -1164,3 +1262,10 @@ def test_the_held_table_tool_rehearses_on_the_cpu():
         _, _, kept, rounds = r["work_first_layer"]
         assert rounds == -(-kept // r["block"]) and r["us"] > 0
     assert rows[3]["work_first_layer"][2] > rows[0]["work_first_layer"][2]
+    for r in rows:
+        assert ("sum_kernel_us" in r) is (r["shares"] == 2)
+        if r["shares"] == 2:
+            assert r["sum_clock"] == "host"
+            assert r["sum_live"] == min(r["work_first_layer"][2], r["block"])
+            assert r["sum_scatter_us"] > 0 and r["sum_kernel_us"] > 0
+            assert r["sum_largest_difference"] <= 1e-6 * r["sum_largest"]

@@ -38,7 +38,17 @@ worst case, N k / block rounds), with what ``Work`` counted. Copied
 into a tree whose grouped path has no rounds it times that tree's one
 path (``"shares": 0``: the "before"). ``--outputs DIR``: the first
 layer's sum of each row is kept there, and compared with what a run
-before this one left (the other tree's, in the same call).
+before this one left (the other tree's, in the same call). The rows of
+the program's own block (two shares) also time a round's SUM BY TOKEN
+alone on the first round's block of that routing (PERF.md, PR 53):
+``sum_scatter_us`` the row scatter-add of the weighed rows (the form
+to PR 52), ``sum_kernel_us`` the kernel ``moe_held_sum``
+(``ops/moe._held_sum``; left out in a tree that has none), device
+microseconds a round from a profiler capture (``sum_clock``
+``device``; a rehearsal on the CPU reads the host's clock and says
+``host``), ``sum_live`` the block's live entries and
+``sum_largest_difference`` between the two after ``--sum-rounds``
+rounds, beside ``sum_largest``.
 """
 
 import argparse
@@ -133,6 +143,11 @@ def held_table(args, timed, platform):
                        "work_all_layers": np.asarray(work).sum(0).tolist()}
                 if shares:
                     row["block"] = moe.held_block(N, k, E, E_all)
+                if shares == 2:
+                    row.update(sum_alone(
+                        args, timed, platform, x, router, k, E, score, bias,
+                        scale, row["block"],
+                        moe.expert_tiles(h, inter, jnp.int8, jnp.bfloat16)))
                 if args.outputs and shares in (0, 2):
                     first = np.asarray(got[0].astype(jnp.float32))
                     path = os.path.join(args.outputs,
@@ -155,10 +170,74 @@ def held_table(args, timed, platform):
     return table
 
 
-def top_ops(trace_dir, fn, operands, layers, calls=3):
-    """The device operations of ``calls`` runs of fn that took most of
-    their own time, in microseconds a layer (chipbench/xplane.py reads
-    the capture)."""
+def sum_alone(args, timed, platform, x, router, k, E, score, bias, scale,
+              B, planes):
+    """The columns of a round's sum by token alone (module text): the
+    first round's block of this routing, ``planes`` planes of random
+    rows, ``--sum-rounds`` rounds a call each adding the block to the
+    sum it carries."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from production_stack_tpu.ops import moe
+
+    N, h = x.shape
+    top_p, top_i = moe.route(x, router, k, score=score, bias=bias,
+                             scale=scale)
+    here = np.flatnonzero(np.asarray(top_i).reshape(-1) < E)[:B]
+    tok = np.full(B, N, np.int32)
+    tok[:len(here)] = here // k
+    weight = np.zeros(B, np.float32)
+    weight[:len(here)] = np.asarray(top_p).reshape(-1)[here]
+    keys = jax.random.split(jax.random.PRNGKey(B), planes)
+    ys = [jax.random.normal(key, (B, h)).astype(jnp.bfloat16)
+          for key in keys]
+    tok, weight = jnp.asarray(tok), jnp.asarray(weight)
+    rounds = args.sum_rounds
+
+    def scatter(acc, *ys):
+        y = ys[0].astype(jnp.float32)
+        for plane in ys[1:]:
+            y = y + plane.astype(jnp.float32)
+        return acc.at[tok].add(y * weight[:, None], mode="drop",
+                               indices_are_sorted=True)
+
+    def kernel(acc, *ys):
+        return moe._held_sum(acc, ys, tok, weight, N)
+
+    def chain(one_round):
+        return jax.jit(lambda *ys: jax.lax.fori_loop(
+            0, rounds, lambda _, acc: one_round(acc, *ys),
+            jnp.zeros((N, h), jnp.float32)))
+
+    def device_us(fn, calls=3):
+        """Device microseconds a round: the busy time of a capture of
+        ``calls`` calls (the chip), else the host's clock."""
+        got, us = timed(fn, *ys)
+        us *= args.layers / rounds
+        if platform != "cpu":
+            busy = captured(tempfile.mkdtemp(prefix="moe_sum_"), fn, ys,
+                            calls)["busy_s"]
+            us = 1e6 * busy / (calls * rounds)
+        return np.asarray(got), round(us, 1)
+
+    before, scatter_us = device_us(chain(scatter))
+    cols = {"sum_live": len(here), "sum_scatter_us": scatter_us,
+            "sum_clock": "host" if platform == "cpu" else "device",
+            "sum_largest": float(np.abs(before).max())}
+    if hasattr(moe, "_held_sum"):
+        after, cols["sum_kernel_us"] = device_us(chain(kernel))
+        cols["sum_largest_difference"] = float(np.abs(after - before).max())
+    return cols
+
+
+def captured(trace_dir, fn, operands, calls):
+    """What chipbench/xplane.py reads off a capture of ``calls`` runs
+    of fn (compiled before it); ValueError where the capture holds no
+    device plane (the CPU)."""
     import jax
 
     from chipbench import xplane
@@ -166,8 +245,14 @@ def top_ops(trace_dir, fn, operands, layers, calls=3):
     with jax.profiler.trace(trace_dir):
         for _ in range(calls):
             jax.block_until_ready(fn(*operands))
+    return xplane.reduce_file(xplane.find_xplane(trace_dir))
+
+
+def top_ops(trace_dir, fn, operands, layers, calls=3):
+    """The device operations of ``calls`` runs of fn that took most of
+    their own time, in microseconds a layer."""
     try:
-        top = xplane.reduce_file(xplane.find_xplane(trace_dir))["top_ops"]
+        top = captured(trace_dir, fn, operands, calls)["top_ops"]
     except ValueError:      # a rehearsal on the CPU: no device plane
         return []
     return [[name, round(1e6 * sec / (calls * layers), 1)]
@@ -194,6 +279,8 @@ def main(argv=None) -> int:
     ap.add_argument("--outputs", default="",
                     help="--held: keep the first layer's sums here, "
                     "compare with what is there")
+    ap.add_argument("--sum-rounds", type=int, default=24,
+                    help="--held: rounds a call of the sum by token alone")
     ap.add_argument("--allow-cpu", action="store_true",
                     help="rehearse on the CPU: widths 128 x 256, the "
                     "kernels in interpret mode, the first four shapes")
