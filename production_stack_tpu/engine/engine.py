@@ -305,9 +305,9 @@ class LLMEngine:
         # a decode step leaves unread of an expert off its list
         expert_bytes = sum(
             x.size * x.dtype.itemsize
-            for n in ("gate", "up", "down")
-            for x in _tree_util.tree_leaves(self.runner.params["layers"][n])
-        ) // (mc.num_layers * mc.num_experts) if mc.num_experts else 0
+            for x in _tree_util.tree_leaves(self.runner.expert_stacks())
+        ) // (self._resident_layers() * mc.num_experts
+              ) if mc.num_experts else 0
         self.eff = EngineEffAccounting(
             weight_bytes=weight_bytes,
             kv_position_bytes=kv_pos_bytes,
@@ -1352,8 +1352,7 @@ class LLMEngine:
             self._expert_rows_due.append((
                 expert_rows_dev,
                 sum(len(w.chunk) for w in entry.group)
-                * mc.num_experts_per_tok
-                * (mc.num_layers - mc.first_dense_layers)))
+                * mc.num_experts_per_tok * mc.expert_layers))
         for row, (w, admitted) in enumerate(zip(entry.group,
                                                 entry.admitted)):
             seq = w.seq
@@ -1764,13 +1763,21 @@ class LLMEngine:
         if win.experts_read is not None:
             counted.update(
                 experts_read=int(win.experts_read.sum()),
-                experts_resident=win.steps * self.model_cfg.num_layers
+                experts_resident=win.steps * self._resident_layers()
                 * self.model_cfg.num_experts)
         self.eff.note_window(**counted, window_s=window_s,
                              host_s=win.host_s + walk.self_s,
                              sync_s=sync.self_s)
         outputs.extend(self._land_prefills(top_up))
         return outputs
+
+    def _resident_layers(self) -> int:
+        """The layers ``experts_resident`` counts a step: a layer
+        plan's expert blocks; of every other model ALL its layers, the
+        leading dense ones too (what the counter has counted since it
+        was read: PERF.md, ``moe_read_share``)."""
+        mc = self.model_cfg
+        return mc.expert_layers if mc.layer_plan else mc.num_layers
 
     def _sync_inflight(self) -> _Window:
         """Device->host sync of the OLDEST in-flight window's arrays (no

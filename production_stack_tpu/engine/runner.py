@@ -39,7 +39,7 @@ import time
 from functools import partial
 
 import numpy as np
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +55,7 @@ from production_stack_tpu.models import llama
 from production_stack_tpu.ops import moe, retention
 from production_stack_tpu.ops.gdn import gdn_path
 from production_stack_tpu.ops.mamba import mamba_path
+from production_stack_tpu.ops.mamba2 import mamba2_path
 from production_stack_tpu.ops.pallas_paged import JNP_GATHER, attention_path
 from production_stack_tpu.ops.rope import rope_table
 from production_stack_tpu.utils import init_logger
@@ -1026,7 +1027,11 @@ class ModelRunner:
         if cfg.ret_layers:
             return retention.retention_path(
                 positions, cfg.head_dim_, cfg.num_kv_heads, steps)
-        if cfg.mamba_layers:
+        if cfg.kind_layers("mamba2"):
+            return mamba2_path(positions, cfg.mamba_d_inner,
+                               cfg.mamba_heads, cfg.mamba_groups,
+                               cfg.mamba_d_state)
+        if cfg.kind_layers("mamba", "mamba_mem"):
             return mamba_path(positions)
         return gdn_path(positions) if cfg.gdn_layers else None
 
@@ -1053,14 +1058,19 @@ class ModelRunner:
         ``rows`` x ``positions`` tokens calls them (a prefill reckons
         its capacity on max_num_seqs rows: _prefill_impl)."""
         cfg = self.model_cfg
-        experts = self.params["layers"]["gate"]
         return moe.moe_path(
             rows, positions, cfg.router_experts_, cfg.num_experts_per_tok,
-            cfg.hidden_size,
-            cfg.moe_intermediate_size or cfg.intermediate_size,
-            moe.stored_dtype(experts), cfg.dtype, self.mesh,
-            capacity_factor=cfg.moe_capacity_factor,
-            capacity_tokens=self.engine_cfg.max_num_seqs * positions)
+            cfg.hidden_size, cfg.moe_stored_size,
+            moe.stored_dtype(self.expert_stacks()["up"]), cfg.dtype,
+            self.mesh, capacity_factor=cfg.moe_capacity_factor,
+            capacity_tokens=self.engine_cfg.max_num_seqs * positions,
+            gated=cfg.expert_gate)
+
+    def expert_stacks(self) -> Dict[str, Any]:
+        """The routed experts' stacks by name (gate, up, down; up and
+        down where the experts have no gate), every expert layer's."""
+        group = self.params.get("moe_layers") or self.params["layers"]
+        return {n: group[n] for n in ("gate", "up", "down") if n in group}
 
     @contextlib.contextmanager
     def _observed(self, kind: str, window: int, kv_len: int, batch: int):

@@ -36,6 +36,12 @@ def _rope_scaling_spec(rs: Optional[dict]) -> Optional[tuple]:
         f"llama3)")
 
 
+# the kinds of a layer plan whose block is ONE sublayer, x +=
+# f(RMSNorm(x)) (Nemotron-H; models/llama.SUBLAYERS has what each
+# runs); every other kind's block is a mixer and a feed-forward part
+SUBLAYER_KINDS = ("mamba2", "attn", "moe")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "debug-llama"
@@ -75,7 +81,8 @@ class ModelConfig:
     # Llama-3.1/3.2 checkpoints REQUIRE the llama3 warp.
     rope_scaling: Optional[tuple] = None
     attention_bias: bool = False    # Qwen2: biases on q/k/v projections
-    activation: str = "silu"        # "silu" | "gelu_tanh" (Gemma GeGLU)
+    # "silu" | "gelu_tanh" (Gemma GeGLU) | "relu2" (Nemotron-H)
+    activation: str = "silu"
     rms_norm_offset: bool = False   # Gemma: y *= (1 + w), not w
     embed_scale: bool = False       # Gemma: embeddings *= sqrt(hidden)
     # MoE (Mixtral / Qwen2-MoE): 0 experts = dense MLP. capacity_factor
@@ -190,7 +197,33 @@ class ModelConfig:
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
     mamba_dt_rank: int = 0
+    # Nemotron-H (``nemotron_h``): a plan whose blocks are ONE sublayer
+    # each, x += f(RMSNorm(x)): "mamba2" a Mamba-2 mixer (ops/mamba2.py:
+    # mamba_heads heads of mamba_d_inner / mamba_heads channels, ONE
+    # decay a head, B and C by mamba_groups groups of mamba_d_state, a
+    # gated RMSNorm a group; the convolution runs over x, B and C:
+    # mamba_conv_channels; per sequence and layer a float32 state
+    # [mamba_d_state, mamba_d_inner] and the convolution's inputs), "moe"
+    # the expert layer alone, "attn" grouped-query attention with no
+    # rotary embedding through the ordinary paged path (SUBLAYER_KINDS).
+    # mamba_heads and mamba_groups are the Mamba-2 mixers' geometry and
+    # say nothing else of the model
+    mamba_heads: int = 0
+    mamba_groups: int = 1
+    # experts WITHOUT a gate matrix: down(act(up(x))) (ops/moe.py
+    # ``gate`` None), with activation "relu2" Nemotron-H's
+    expert_gate: bool = True
     dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        kinds = {k for period, _ in self.layer_plan for k in period}
+        if kinds & set(SUBLAYER_KINDS) and kinds - set(SUBLAYER_KINDS):
+            raise ValueError(
+                f"layer_plan mixes blocks that are one sublayer "
+                f"({sorted(kinds & set(SUBLAYER_KINDS))}) with two-part "
+                f"blocks ({sorted(kinds - set(SUBLAYER_KINDS))}): the "
+                f"parameter tree and num_params are built for one or the "
+                f"other")
 
     @property
     def head_dim_(self) -> int:
@@ -243,7 +276,50 @@ class ModelConfig:
     @property
     def mamba_layers(self) -> int:
         """Selective-scan layers: a state page a sequence."""
-        return self.kind_layers("mamba", "mamba_mem")
+        return self.kind_layers("mamba", "mamba_mem", "mamba2")
+
+    @property
+    def mamba_conv_channels(self) -> int:
+        """Channels of a Mamba layer's convolution: x, and for Mamba-2
+        every group's B and C beside it."""
+        return self.mamba_d_inner + (
+            2 * self.mamba_groups * self.mamba_d_state
+            if self.kind_layers("mamba2") else 0)
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers that hold experts: a plan's "moe" blocks, else every
+        layer after the leading dense ones."""
+        if not self.num_experts:
+            return 0
+        return (self.kind_layers("moe") if self.layer_plan
+                else self.num_layers - self.first_dense_layers)
+
+    @property
+    def sublayer_plan(self) -> bool:
+        """Is every block of the layer plan ONE sublayer (SUBLAYER_KINDS;
+        __post_init__ refuses a plan that mixes them with two-part
+        blocks)?"""
+        return any(k in SUBLAYER_KINDS for period, _ in self.layer_plan
+                   for k in period)
+
+    @property
+    def moe_stored_size(self) -> int:
+        """The width the expert stacks hold, which the kernels read:
+        the published one, but in a one-sublayer plan the next multiple
+        of the 128 lanes (models/llama._init_params_sublayers: zero
+        columns of ``up`` and zero rows of ``down``, relu(0)^2 = 0, so
+        the mathematics, and num_params, are those of the published
+        width). The kernels that read experts in place copy whole
+        vectors of lanes."""
+        mi = self.moe_intermediate_size or self.intermediate_size
+        return -(-mi // 128) * 128 if self.sublayer_plan else mi
+
+    @property
+    def differential(self) -> bool:
+        """Does the plan hold differential-attention layers (a
+        decoder-hybrid-decoder's)?"""
+        return bool(self.kind_layers("swa", "full", "cross"))
 
     @property
     def self_layers(self) -> int:
@@ -276,12 +352,12 @@ class ModelConfig:
         """Heads of the K/V pool. Differential attention pairs its
         key heads ([k1 | k2], one value of twice the width): half as
         many heads, twice as wide, the same bytes a token."""
-        return self.num_kv_heads // 2 if self.layer_plan \
+        return self.num_kv_heads // 2 if self.differential \
             else self.num_kv_heads
 
     @property
     def pool_head_dim(self) -> int:
-        return 2 * self.head_dim_ if self.layer_plan else self.head_dim_
+        return 2 * self.head_dim_ if self.differential else self.head_dim_
 
     @property
     def gdn_layers(self) -> int:
@@ -315,10 +391,11 @@ class ModelConfig:
         and the convolution's bfloat16 inputs of a Gated DeltaNet
         layer; a power retention layer's float32 ``S`` and ``z`` a
         key-value head; a selective-scan layer's float32 state and its
-        convolution's inputs (0: no such layer)."""
+        convolution's inputs, by kind (a Mamba-2 layer's convolution
+        holds B and C too) (0: no such layer)."""
         return (self.mamba_layers * (
             4 * self.mamba_d_state * self.mamba_d_inner
-            + 2 * (self.mamba_d_conv - 1) * self.mamba_d_inner)
+            + 2 * (self.mamba_d_conv - 1) * self.mamba_conv_channels)
             + self.gdn_layers * (
             4 * self.gdn_value_heads * self.gdn_key_dim
             * self.gdn_value_dim + 2 * (self.gdn_conv - 1)
@@ -344,7 +421,26 @@ class ModelConfig:
         vocabulary's rows as sliced, the indexer."""
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd, nh = self.head_dim_, self.num_heads
-        if self.layer_plan:
+        if self.sublayer_plan:
+            # one sublayer a block: its norm and (Mamba-2) in_proj, the
+            # convolution and its bias, A_log, dt_bias, D, the gated
+            # norm, out_proj; (attention) q, k, v, o; (experts) router
+            # and bias, the shared expert, the held experts at the
+            # PUBLISHED width; embedding, head, final norm
+            di, ch = self.mamba_d_inner, self.mamba_conv_channels
+            mi = self.moe_intermediate_size
+            mamba2 = (h * (di + ch + self.mamba_heads) + di * h
+                      + ch * (self.mamba_d_conv + 1)
+                      + 3 * self.mamba_heads + di)
+            attn = 2 * h * nh * hd + 2 * h * self.num_kv_heads * hd
+            moe = (h * self.router_experts_ + self.router_experts_
+                   + 2 * h * self.shared_expert_size
+                   + self.num_experts * 2 * h * mi)
+            return (self.kind_layers("mamba2") * mamba2
+                    + self.kind_layers("attn") * attn
+                    + self.kind_layers("moe") * moe
+                    + self.num_layers * h + 2 * v * h + h)
+        if self.layer_plan:     # a decoder-hybrid-decoder's two-part blocks
             di, ds, r = (self.mamba_d_inner, self.mamba_d_state,
                          self.mamba_dt_rank)
             kv, lam = 2 * self.num_kv_heads * hd, 4 * hd + 2 * hd
@@ -414,8 +510,9 @@ class ModelConfig:
         unit-offset RMSNorm, tied embeddings), Gemma-2, Mixtral,
         Qwen2-MoE, GLM-4.7-Flash (``glm4_moe_lite``) and GLM-5
         (``glm_moe_dsa``), both through _glm4_moe_lite, Qwen3-Next
-        (``qwen3_next``, _qwen3_next), Brumby (``brumby``, _brumby) and
-        Phi-4-mini-flash (``phi4flash``, _phi4flash).
+        (``qwen3_next``, _qwen3_next), Brumby (``brumby``, _brumby),
+        Phi-4-mini-flash (``phi4flash``, _phi4flash) and Nemotron-H
+        (``nemotron_h``, _nemotron_h).
         Keys the mapping does not know are ignored.
         """
         archs = cfg.get("architectures") or []
@@ -443,6 +540,8 @@ class ModelConfig:
             return _brumby(cfg, name, dtype)
         if model_type == "phi4flash" or arch == "Phi4FlashForCausalLM":
             return _phi4flash(cfg, name, dtype)
+        if model_type == "nemotron_h" or arch == "NemotronHForCausalLM":
+            return _nemotron_h(cfg, name, dtype)
         if not (is_qwen2 or is_gemma or is_gemma2 or is_mixtral
                 or is_qwen2_moe or is_glm_lite
                 or is_llama_like) and (model_type or arch):
@@ -450,7 +549,8 @@ class ModelConfig:
                 f"unsupported model family (model_type={model_type!r}, "
                 f"architecture={arch!r}); supported: llama, mistral, "
                 f"qwen2, gemma, gemma2, mixtral, qwen2_moe, "
-                f"glm4_moe_lite, glm_moe_dsa, qwen3_next, brumby, phi4flash")
+                f"glm4_moe_lite, glm_moe_dsa, qwen3_next, brumby, "
+                f"phi4flash, nemotron_h")
         if is_glm_lite:
             return _glm4_moe_lite(cfg, name, dtype)
         if is_qwen2_moe:
@@ -823,6 +923,112 @@ def _phi4flash(cfg: Dict[str, Any], name: str, dtype: Any) -> ModelConfig:
     )
 
 
+def plan_runs(kinds) -> Tuple[Tuple[Tuple[str, ...], int], ...]:
+    """The runs of (period, repeats) that spell ``kinds`` (a block's
+    kind each, in order) with the FEWEST traced sublayers (the periods'
+    lengths summed; among equals the fewest runs): the layer loop
+    traces a period once and scans its repeats. Nemotron-3-Nano's 52
+    letters come out as (M E M E M * E) x 5, (M E) x 3, (M * E) x 1,
+    (M E) x 4: 14 traced sublayers."""
+    kinds = tuple(kinds)
+    n = len(kinds)
+    # best[i]: (traced sublayers, runs, the runs) of the blocks from i on
+    best = [None] * n + [(0, 0, ())]
+    for i in range(n - 1, -1, -1):
+        for p in range(1, n - i + 1):
+            period, most = kinds[i:i + p], 1
+            while kinds[i + p * most:i + p * (most + 1)] == period:
+                most += 1
+            for reps in range(1, most + 1):
+                cost, runs, tail = best[i + p * reps]
+                cand = (cost + p, runs + 1, ((period, reps),) + tail)
+                if best[i] is None or cand[:2] < best[i][:2]:
+                    best[i] = cand
+    return best[0][2]
+
+
+def _nemotron_h(cfg: Dict[str, Any], name: str, dtype: Any) -> ModelConfig:
+    """Nemotron-H (``nemotron_h``; NVIDIA-Nemotron-3-Nano-30B-A3B,
+    arXiv 2504.03624): ``hybrid_override_pattern`` spells the blocks, M
+    a Mamba-2 mixer (arXiv 2405.21060), E the expert layer, * grouped-
+    query attention; EACH BLOCK IS ONE SUBLAYER, x += f(RMSNorm(x)).
+    The router is DeepSeek-V3's (sigmoid scores, a selection bias,
+    renormalised, times ``routed_scaling_factor``); an expert is
+    ``down(relu(up(x))^2)``, no gate, and one shared expert of the same
+    form is added with no gate in front; the attention has no rotary
+    embedding (``rope_theta`` stands in the published file unused); the
+    head is untied. ``chunk_size`` is the chunked scan's own 128
+    (ops/mamba2.CHUNK); the result does not depend on it. A file may
+    state the chip's share of the experts (``deployment``, as
+    _glm4_moe_lite). What the tree does not build is refused by
+    name."""
+    family = "nemotron_h"
+    letters = cfg["hybrid_override_pattern"]
+    layers = cfg["num_hidden_layers"]
+    if len(letters) != layers or set(letters) - set("ME*"):
+        raise ValueError(
+            f"{family}: hybrid_override_pattern {letters!r} is not "
+            f"{layers} blocks of M, E and * (a dense MLP block, '-', is "
+            f"not supported)")
+    for key in ("attention_bias", "mlp_bias", "use_bias",
+                "mamba_proj_bias", "tie_word_embeddings"):
+        if cfg.get(key, False):
+            raise ValueError(f"{family} with {key} is not supported")
+    if cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
+        raise ValueError(f"{family} with grouped routing (n_group / "
+                         f"topk_group != 1) is not supported")
+    if cfg.get("mlp_hidden_act", "relu2") != "relu2":
+        raise ValueError(f"{family} mlp_hidden_act "
+                         f"{cfg['mlp_hidden_act']!r} is not supported")
+    if cfg.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError(f"{family} mamba_hidden_act "
+                         f"{cfg['mamba_hidden_act']!r} is not supported")
+    if cfg.get("sliding_window"):
+        raise ValueError(f"{family} with sliding_window is not supported")
+    if not cfg.get("use_conv_bias", True):
+        raise ValueError(f"{family} without use_conv_bias is not "
+                         f"supported")
+    heads, groups = cfg["mamba_num_heads"], cfg["n_groups"]
+    if heads % groups:
+        raise ValueError(f"{family}: mamba_num_heads {heads} is not a "
+                         f"multiple of n_groups {groups}")
+    held = cfg["n_routed_experts"]
+    router_experts, offset = _deployment(cfg, family, held)
+    assumed = cfg.get("assumed") or {}
+    mi = cfg["moe_intermediate_size"]
+    return ModelConfig(
+        name=name or cfg.get("_name_or_path", "hf-model"),
+        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg.get("intermediate_size", mi),
+        num_layers=layers, num_heads=cfg["num_attention_heads"],
+        num_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg.get("head_dim"),
+        rms_norm_eps=cfg.get("layer_norm_epsilon",
+                             cfg.get("norm_eps", 1e-5)),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        activation="relu2", expert_gate=False,
+        num_experts=held, router_experts=router_experts,
+        expert_offset=offset,
+        num_experts_per_tok=cfg["num_experts_per_tok"],
+        norm_topk_prob=cfg.get("norm_topk_prob", True),
+        moe_intermediate_size=mi,
+        shared_expert_size=(cfg.get("n_shared_experts", 0) * cfg.get(
+            "moe_shared_expert_intermediate_size", mi)),
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+        shared_expert_gate=False,
+        routed_down_init_std=assumed.get("routed_down_init_std"),
+        layer_plan=plan_runs(
+            {"M": "mamba2", "E": "moe", "*": "attn"}[c] for c in letters),
+        mamba_d_inner=heads * cfg["mamba_head_dim"],
+        mamba_d_state=cfg["ssm_state_size"],
+        mamba_d_conv=cfg.get("conv_kernel", 4),
+        mamba_heads=heads, mamba_groups=groups,
+        exact_dequant_scale=True,
+        dtype=dtype,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Presets. Dimensions are the publicly documented architecture shapes.
 # ---------------------------------------------------------------------------
@@ -990,6 +1196,26 @@ PRESETS: Dict[str, ModelConfig] = {
                     (("gmu", "cross"), 1)),
         mamba_d_inner=128, mamba_d_state=4, mamba_d_conv=4,
         mamba_dt_rank=4, exact_dequant_scale=True,
+    ),
+    # Tiny Nemotron-H-style model for CPU tests (``nemotron_h``): 12
+    # blocks M E M * E M E M * E M E = (M E M * E) x 2, (M E) x 1, each
+    # ONE sublayer; Mamba-2 of 8 heads of 32 in 2 groups, a state of 16;
+    # 4 / 2 attention heads of 32, no rotary embedding; 8 sigmoid-routed
+    # ungated relu^2 experts top-3 of width 48, STORED at 128, and a
+    # shared one of 96
+    "debug-nemotron": ModelConfig(
+        name="debug-nemotron", vocab_size=512, hidden_size=128,
+        intermediate_size=48, num_layers=12, num_heads=4, num_kv_heads=2,
+        head_dim=32, max_position_embeddings=512, activation="relu2",
+        expert_gate=False, num_experts=8, num_experts_per_tok=3,
+        moe_intermediate_size=48,
+        shared_expert_size=96, router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=2.5, shared_expert_gate=False,
+        moe_capacity_factor=8 / 3,
+        layer_plan=((("mamba2", "moe", "mamba2", "attn", "moe"), 2),
+                    (("mamba2", "moe"), 1)),
+        mamba_d_inner=256, mamba_d_state=16, mamba_d_conv=4,
+        mamba_heads=8, mamba_groups=2, exact_dequant_scale=True,
     ),
     # GLM-4.7-Flash (glm4_moe_lite, 30B-A3B): latent attention, one
     # leading dense layer of width 10240, 64 sigmoid-routed experts

@@ -340,8 +340,8 @@ def cache_for(cfg, num_blocks: int, block_size: int,
                                  cfg.mamba_d_state, cfg.mamba_d_inner),
                                 jnp.float32),
                 conv=jnp.zeros((cfg.mamba_layers, state_pages,
-                                cfg.mamba_d_conv - 1, cfg.mamba_d_inner),
-                               dtype))
+                                cfg.mamba_d_conv - 1,
+                                cfg.mamba_conv_channels), dtype))
         return kv._replace(
             state=jnp.zeros((cfg.gdn_layers, state_pages,
                              cfg.gdn_value_heads, cfg.gdn_key_dim,

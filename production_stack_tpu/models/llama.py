@@ -27,9 +27,9 @@ import jax.numpy as jnp
 
 from production_stack_tpu.models import kv as kv_pool
 from production_stack_tpu.models import lora, quant
-from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.models.config import SUBLAYER_KINDS, ModelConfig
 from production_stack_tpu.models.kv import KVCache
-from production_stack_tpu.ops import gdn, mamba, moe, retention
+from production_stack_tpu.ops import gdn, mamba, mamba2, moe, retention
 from production_stack_tpu.ops.attention import causal_attention
 from production_stack_tpu.ops.norms import rms_norm
 from production_stack_tpu.ops.rope import apply_rope, rope_table
@@ -90,7 +90,8 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     if cfg.gdn_layers:
         return _init_params_hybrid(cfg, key, w)
     if cfg.layer_plan:
-        return _init_params_plan(cfg, key, w)
+        return (_init_params_sublayers if cfg.sublayer_plan
+                else _init_params_plan)(cfg, key, w)
     norm_init = jnp.zeros if cfg.rms_norm_offset else jnp.ones
     E = cfg.num_experts
     params: Params = {
@@ -316,7 +317,10 @@ def _init_params_hybrid(cfg: ModelConfig, key: jax.Array, w) -> Params:
 # own from (cfg.layer_plan), beside ``layers`` (what every block has)
 PLAN_GROUPS = {"mamba": "mamba_layers", "mamba_mem": "mamba_layers",
                "swa": "diff_layers", "full": "diff_layers",
-               "gmu": "gmu_layers", "cross": "cross_layers"}
+               "gmu": "gmu_layers", "cross": "cross_layers",
+               # blocks that are ONE sublayer (Nemotron-H)
+               "mamba2": "mamba2_layers", "attn": "gqa_layers",
+               "moe": "moe_layers"}
 
 
 def _init_params_plan(cfg: ModelConfig, key: jax.Array, w) -> Params:
@@ -402,6 +406,111 @@ def _init_params_plan(cfg: ModelConfig, key: jax.Array, w) -> Params:
     }
 
 
+def _stack_by_layer(make, layers: int):
+    """A stack [layers, ...] whose every layer ``make(l)`` gives (an
+    array, or its {"w8", "scale"} leaf), written a layer at a time into
+    one buffer that is donated from write to write: the transient is
+    ONE layer's, where the stack made whole holds its float copy beside
+    its int8 one (3.7 G parameters of experts: 11 GB beside the
+    finished leaves)."""
+    def put(buf, leaf, at):
+        return jax.tree.map(
+            lambda b, a: jax.lax.dynamic_update_index_in_dim(b, a, at, 0),
+            buf, leaf)
+    put = jax.jit(put, donate_argnums=0)
+    buf = jax.tree.map(lambda a: jnp.zeros((layers,) + a.shape, a.dtype),
+                       jax.eval_shape(make, 0))
+    for at in range(layers):
+        buf = put(buf, make(at), jnp.int32(at))
+    return buf
+
+
+def _init_params_sublayers(cfg: ModelConfig, key: jax.Array, w) -> Params:
+    """init_params for a model whose blocks are ONE sublayer each
+    (Nemotron-H: cfg.sublayer_plan). ``layers`` holds every block's norm;
+    ``mamba2_layers``, ``gqa_layers`` and ``moe_layers`` the sublayers,
+    each stacked in the model's order. A Mamba-2 mixer as published
+    initialises it: ``A_log`` = log(1..heads), ``D`` one, ``dt_bias``
+    the inverse softplus of dt log-uniform in [0.001, 0.1] floored at
+    1e-4, the depthwise convolution and its bias uniform in +-
+    taps ** -0.5 (PyTorch's Conv1d); ``conv`` [taps, channels] with the
+    last tap on the token itself, the channels x, then every group's B,
+    then every group's C; ``in_proj``'s columns z, x B C, dt. The
+    experts ``up`` [Le, E, h, s] and ``down`` [Le, E, s, h] are STORED
+    s = cfg.moe_stored_size wide, zero beyond the published
+    moe_intermediate_size, and made a layer at a time."""
+    h, v, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    di, H, ch = cfg.mamba_d_inner, cfg.mamba_heads, cfg.mamba_conv_channels
+    taps, E = cfg.mamba_d_conv, cfg.num_experts
+    mi, ms, si = (cfg.moe_intermediate_size, cfg.moe_stored_size,
+                  cfg.shared_expert_size)
+    Lm, La, Le = (cfg.kind_layers("mamba2"), cfg.kind_layers("attn"),
+                  cfg.kind_layers("moe"))
+    keys = iter(jax.random.split(key, 24))
+    f32 = jnp.float32
+    dt = jnp.maximum(jnp.exp(
+        jax.random.uniform(next(keys), (Lm, H), f32)
+        * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001)), 1e-4)
+    bound = taps ** -0.5
+
+    def widen_up(a):    # the last axis: w8 [E, h, mi], scale [E, mi]
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, ms - mi)])
+
+    def widen_down(a):  # the rows of [E, mi, h]; a scale [E, h] as it is
+        return jnp.pad(a, [(0, 0), (0, ms - mi), (0, 0)]) \
+            if a.ndim == 3 else a
+
+    def experts(k, name, shape, widen, std):
+        """An expert stack a layer at a time, zero beyond ``mi``."""
+        layer_keys = jax.random.split(k, Le)
+
+        return _stack_by_layer(
+            lambda at: jax.tree.map(widen, w(
+                layer_keys[at], shape, "moe_layers", name, std=std)), Le)
+
+    return {
+        "embed": w(next(keys), (v, h), "embed"),
+        "lm_head": w(next(keys), (h, v), "lm_head"),
+        "final_norm": jnp.ones((h,), cfg.dtype),
+        "layers": {"norm": jnp.ones((L, h), cfg.dtype)},
+        "mamba2_layers": {
+            "in_proj": w(next(keys), (Lm, h, di + ch + H), "mamba2_layers",
+                         "in_proj"),
+            "conv": jax.random.uniform(next(keys), (Lm, taps, ch), f32,
+                                       -bound, bound).astype(cfg.dtype),
+            "conv_bias": jax.random.uniform(
+                next(keys), (Lm, ch), f32, -bound, bound).astype(cfg.dtype),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, H + 1, dtype=f32)), (Lm, H)),
+            "D": jnp.ones((Lm, H), f32),
+            "gate_norm": jnp.ones((Lm, di), cfg.dtype),
+            "out_proj": w(next(keys), (Lm, di, h), "mamba2_layers",
+                          "out_proj"),
+        },
+        "gqa_layers": {
+            "q": w(next(keys), (La, h, nh * hd), "gqa_layers", "q"),
+            "k": w(next(keys), (La, h, nkv * hd), "gqa_layers", "k"),
+            "v": w(next(keys), (La, h, nkv * hd), "gqa_layers", "v"),
+            "o": w(next(keys), (La, nh * hd, h), "gqa_layers", "o"),
+        },
+        "moe_layers": {
+            "router": w(next(keys), (Le, h, cfg.router_experts_),
+                        "moe_layers", "router"),
+            # a trained selection bias is not zero; sd 0.1 moves the
+            # selection (sigmoid scores lie around 0.5)
+            "router_bias": 0.1 * jax.random.normal(
+                next(keys), (Le, cfg.router_experts_), f32),
+            "up": experts(next(keys), "up", (E, h, mi), widen_up, 0.02),
+            "down": experts(next(keys), "down", (E, mi, h), widen_down,
+                            cfg.routed_down_init_std or 0.02),
+            "s_up": w(next(keys), (Le, h, si), "moe_layers", "s_up"),
+            "s_down": w(next(keys), (Le, si, h), "moe_layers", "s_down"),
+        },
+    }
+
+
 def _mamba_mixer(cfg: ModelConfig, hidden, lp: Params, state, state_ids,
                  starts, token_valid, state_layer):
     """A Mamba mixer (ops/mamba.py) on the normed input ``hidden``
@@ -449,6 +558,57 @@ def _mamba_mixer(cfg: ModelConfig, hidden, lp: Params, state, state_ids,
     with jax.named_scope("mamba_out_proj"):
         out = quant.dequant_matmul(gated, lp["out_proj"], exact_scale=True)
     return out, y.astype(hidden.dtype), (hs, conv)
+
+
+def _mamba2_mixer(cfg: ModelConfig, hidden, lp: Params, state, state_ids,
+                  starts, token_valid, state_layer):
+    """A Mamba-2 mixer (ops/mamba2.py) on the normed input ``hidden``
+    [B,T,H] -> (the mixer's output [B,T,H], the state pools). state =
+    the WHOLE pools (the float32 states [Lm,P,N,Di], the convolutions'
+    inputs [Lm,P,taps-1,Ch]), of which the rows' pages ``state_ids``
+    [B] of layer ``state_layer`` are read and written in place, by
+    _mamba_mixer's conventions (the trash page for a row that is not
+    real, dt = 0 where a position is not, a zero state at position 0).
+    ``in_proj``'s columns are z, (x, B, C), dt; the convolution runs
+    over x, B and C together; the output is the GROUP RMSNorm of y
+    silu(z) (cfg.mamba_groups groups, one weight a channel). Scopes
+    mamba2_proj, mamba2_conv, mamba2_chunk_scan / mamba2_recurrent_step
+    (ops/mamba2.ssd_scan), mamba2_gate_norm, mamba2_out_proj."""
+    B, T, _ = hidden.shape
+    di, H, G, N = (cfg.mamba_d_inner, cfg.mamba_heads, cfg.mamba_groups,
+                   cfg.mamba_d_state)
+    ch = cfg.mamba_conv_channels
+    hs, conv = state
+    ids = jnp.where(jnp.any(token_valid, axis=1), state_ids, 0)
+    fresh = starts == 0
+    f32 = jnp.float32
+    with jax.named_scope("mamba2_proj"):
+        zxd = quant.dequant_matmul(hidden, lp["in_proj"], dtype=f32,
+                                   exact_scale=True)
+        z, xbc, dt = zxd[..., :di], zxd[..., di:di + ch], zxd[..., di + ch:]
+        dt = jnp.where(token_valid[..., None],
+                       jax.nn.softplus(dt + lp["dt_bias"]), 0.0)
+    with jax.named_scope("mamba2_conv"):
+        prev = jnp.where(fresh[:, None, None], 0, conv[state_layer, ids])
+        xbc, new_conv = gdn.causal_conv(
+            xbc, lp["conv"], prev,
+            jnp.sum(token_valid, axis=1, dtype=jnp.int32),
+            bias=lp["conv_bias"])
+        conv = conv.at[state_layer, ids].set(new_conv)
+        xs = xbc[..., :di]
+        Bm, Cm = (xbc[..., di + j * G * N:di + (j + 1) * G * N].reshape(
+            B, T, G, N).astype(hidden.dtype) for j in (0, 1))
+    y, hs = mamba2.ssd_scan(xs.astype(hidden.dtype), dt, Bm, Cm,
+                            -jnp.exp(lp["A_log"]), hs, ids, state_layer,
+                            fresh)
+    with jax.named_scope("mamba2_gate_norm"):
+        y = (y + jnp.repeat(lp["D"], di // H) * xs) * jax.nn.silu(z)
+        y = rms_norm(y.reshape(B, T, G, di // G),
+                     lp["gate_norm"].reshape(G, di // G), cfg.rms_norm_eps)
+        y = y.reshape(B, T, di).astype(hidden.dtype)
+    with jax.named_scope("mamba2_out_proj"):
+        out = quant.dequant_matmul(y, lp["out_proj"], exact_scale=True)
+    return out, (hs, conv)
 
 
 def _diff_attention(cfg: ModelConfig, hidden, lp: Params, kv, kv_len,
@@ -538,25 +698,111 @@ def _diff_attention(cfg: ModelConfig, hidden, lp: Params, kv, kv_len,
     return o.astype(hidden.dtype).reshape(B, T, nh * hd), kv
 
 
+def _mamba2_sublayer(cfg: ModelConfig, hidden, lp: Params, pool, spool, at):
+    out, spool = _mamba2_mixer(cfg, hidden, lp, spool, at["state_ids"],
+                               at["starts"], at["token_valid"],
+                               at["group_layer"])
+    return out, pool, spool, None
+
+
+def _gqa_sublayer(cfg: ModelConfig, hidden, lp: Params, pool, spool, at):
+    """Grouped-query attention with NO rotary embedding (Nemotron-H:
+    the Mamba blocks carry position) through the ordinary paged path:
+    pool layer ``group_layer``. Scopes qkv_proj, kv_write, attention,
+    o_proj."""
+    B, T, _ = hidden.shape
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    mm = functools.partial(quant.dequant_matmul, exact_scale=True)
+    with jax.named_scope("qkv_proj"):
+        q = mm(hidden, lp["q"]).reshape(B, T, nh, hd)
+        k = mm(hidden, lp["k"]).reshape(B, T, nkv, hd)
+        v = mm(hidden, lp["v"]).reshape(B, T, nkv, hd)
+    with jax.named_scope("kv_write"):
+        pool = kv_pool.append(pool, k, v, at["block_tables"], at["starts"],
+                              at["token_valid"], at["group_layer"])
+    with jax.named_scope("attention"):
+        attn = kv_pool.attend(q, pool, at["block_tables"], at["starts"],
+                              at["positions"], at["kv_len"],
+                              at["group_layer"], window=None,
+                              scale=hd ** -0.5, softcap=None,
+                              mesh=at["mesh"])
+    with jax.named_scope("o_proj"):
+        return mm(attn.reshape(B, T, nh * hd), lp["o"]), pool, spool, None
+
+
+def _expert_sublayer(cfg: ModelConfig, hidden, lp: Params, pool, spool, at):
+    """The expert layer as a block's ONLY part: the held experts
+    without a gate (ops/moe.moe_mlp ``gate`` None; their stacks read in
+    place from ``expert_stacks`` at ``group_layer`` where the list or
+    the grouped path runs) and the shared expert of the same form,
+    added with no gate in front. Scopes moe_router / moe_experts /
+    moe_combine (ops/moe.py), shared_expert."""
+    B, T, H = hidden.shape
+    stacks, valid = at["expert_stacks"], at["token_valid"]
+    up, down = (lp[n] if stacks is None else stacks[n]
+                for n in ("up", "down"))
+    act = _activation(cfg)
+    y, work = moe.moe_mlp(
+        hidden.reshape(B * T, H), lp["router"], None, up, down,
+        top_k=cfg.num_experts_per_tok,
+        capacity_factor=cfg.moe_capacity_factor,
+        capacity_tokens=at["moe_capacity_tokens"], act=act,
+        valid=valid.reshape(B * T), renormalize=cfg.norm_topk_prob,
+        exact=True if T == 1 else None,     # a decode step drops nothing
+        layer=None if stacks is None else at["group_layer"], positions=T,
+        router_score=cfg.router_score, router_bias=lp["router_bias"],
+        routed_scale=cfg.routed_scaling_factor,
+        expert_offset=cfg.expert_offset)
+    with jax.named_scope("shared_expert"):
+        inner = act(quant.dequant_matmul(hidden, lp["s_up"],
+                                         dtype=jnp.float32,
+                                         exact_scale=True))
+        shared = quant.dequant_matmul(inner.astype(hidden.dtype),
+                                      lp["s_down"], exact_scale=True)
+    return y.reshape(B, T, H) + shared, pool, spool, work
+
+
+# a one-sublayer block's part by kind: (cfg, the normed input, the
+# block's parameters, the K/V pool, the state pools, the step's
+# arguments) -> (its output, the pools, the experts' work or None)
+SUBLAYERS = dict(zip(SUBLAYER_KINDS, (_mamba2_sublayer, _gqa_sublayer,
+                                      _expert_sublayer)))
+
+
+def _sublayer_block(cfg: ModelConfig, kind: str, x, lp: Params, pool,
+                    spool, mem, at):
+    """One block that is ONE sublayer (Nemotron-H): x += f(RMSNorm(x)),
+    f by ``kind`` (SUBLAYERS). The memory (a decoder-hybrid-decoder's,
+    part of the layer loop's carry) passes through. -> _plan_layer's
+    results."""
+    with jax.named_scope("norm"):
+        hidden = rms_norm(x, lp["norm"], cfg.rms_norm_eps)
+    out, pool, spool, work = SUBLAYERS[kind](cfg, hidden, lp, pool, spool,
+                                             at)
+    return x + out, pool, spool, mem, work
+
+
 def _plan_layer(cfg: ModelConfig, kind: str, x, lp: Params, pool, spool,
-                mem, *, positions, starts, state_ids, token_valid,
-                block_tables, kv_len, mesh, layer, group_layer,
-                shared_kv_layer):
+                mem, at):
     """One block of a decoder-hybrid-decoder (cfg.layer_plan): x +=
     mixer(LN1(x)); x += fc2(up * silu(gate)), gate, up = split(fc1(
     LN2(x))). ``kind`` (static) names the mixer, ``lp`` the block's own
-    parameters and its mixer's, ``layer`` its index in the model and
-    ``group_layer`` among its kind's: a Mamba layer's in the state
+    parameters and its mixer's; ``at`` holds the step's arguments
+    (_run_plan) and the block's place: ``layer`` its index in the model
+    and ``group_layer`` among its kind's: a Mamba layer's in the state
     pool, an attention layer's in the K/V pool; a cross layer reads
     pool layer ``shared_kv_layer`` (static: the last "full" layer's).
     ``mem`` is the memory a "mamba_mem" layer leaves and a "gmu" layer
-    reads. -> (x', pool, spool, mem)."""
+    reads. -> (x', pool, spool, mem, the experts' work: None)."""
+    starts, token_valid, group_layer = (at["starts"], at["token_valid"],
+                                        at["group_layer"])
     eps = cfg.rms_norm_eps
     with jax.named_scope("attn_norm"):
         hidden = _layer_norm(x, lp["attn_norm"], lp["attn_norm_bias"], eps)
     if kind in ("mamba", "mamba_mem"):
-        out, y, spool = _mamba_mixer(cfg, hidden, lp, spool, state_ids,
-                                     starts, token_valid, group_layer)
+        out, y, spool = _mamba_mixer(cfg, hidden, lp, spool,
+                                     at["state_ids"], starts, token_valid,
+                                     group_layer)
         if kind == "mamba_mem":
             mem = y
     elif kind == "gmu":
@@ -571,9 +817,10 @@ def _plan_layer(cfg: ModelConfig, kind: str, x, lp: Params, pool, spool,
         own = kind != "cross"
         with jax.named_scope("diff_attention"):
             attn, pool = _diff_attention(
-                cfg, hidden, lp, pool, kv_len, token_valid, block_tables,
-                starts, positions, mesh, layer,
-                group_layer if own else shared_kv_layer,
+                cfg, hidden, lp, pool, at["kv_len"], token_valid,
+                at["block_tables"], starts, at["positions"], at["mesh"],
+                at["layer"],
+                group_layer if own else at["shared_kv_layer"],
                 cfg.sliding_window if kind == "swa" else None, own)
             with jax.named_scope("o_proj"):
                 out = quant.dequant_matmul(
@@ -587,22 +834,43 @@ def _plan_layer(cfg: ModelConfig, kind: str, x, lp: Params, pool, spool,
         x = x + quant.dequant_matmul(
             gu[..., half:] * jax.nn.silu(gu[..., :half]), lp["fc2"],
             exact_scale=True)
-    return x, pool, spool, mem
+    return x, pool, spool, mem, None
+
+
+# the block each kind of a layer plan is: a decoder-hybrid-decoder's
+# two-part block, or ONE sublayer. Both are called (cfg, kind, x, the
+# block's parameters, the K/V pool, the state pools, the memory, the
+# step's arguments and the block's place) -> (x', the pools, the
+# memory, the experts' work or None)
+PLAN_BLOCKS = {**{k: _plan_layer for k in ("mamba", "mamba_mem", "swa",
+                                           "full", "gmu", "cross")},
+               **{k: _sublayer_block for k in SUBLAYERS}}
+
+
+def _sum_work(works):
+    """The sum of the experts' work over blocks (None: no experts)."""
+    works = [w for w in works if w is not None]
+    return moe.Work(*map(sum, zip(*works))) if works else None
 
 
 def _run_plan(params: Params, cfg: ModelConfig, x, positions, cache,
               block_tables, state_ids, token_valid, kv_len, mesh, last,
-              finishing):
-    """The layer loop of a decoder-hybrid-decoder: cfg.layer_plan's
+              finishing, moe_capacity_tokens=None):
+    """The layer loop of a model with a layer plan: cfg.layer_plan's
     runs in order, each a scan over its periods (a run of one period is
-    called as it stands), the pools in the carry as in ``forward``.
+    called as it stands), the pools in the carry as in ``forward``; a
+    block is what PLAN_BLOCKS says of its kind. Where the list or the
+    grouped path reads the experts in place (ops/moe.py) their stacks
+    are closed over whole and a block is handed its index among the
+    expert layers, as ``forward``'s one-run loop does.
     ``last`` [B] (a prefill chunk): the runs from the first that reads
     the memory or another layer's K/V on (cfg.self_layers) see ONE
     position a row, ``last[b]``, of the residual stream and the memory,
     and, with ``finishing`` (a traced bool: some row's prompt ends in
     this chunk), do not run at all where it is False (they write no
     cache: what they would have returned is read by nobody); None:
-    every run sees every position. -> (x [B,T or 1,H], cache')."""
+    every run sees every position. -> (x [B,T or 1,H], cache', the
+    experts' work summed over the blocks, ops/moe.Work, or None)."""
     B, T, _ = x.shape
     if token_valid is None:
         token_valid = jnp.ones((B, T), bool)
@@ -611,10 +879,28 @@ def _run_plan(params: Params, cfg: ModelConfig, x, positions, cache,
     own = [k for period, reps in cfg.plan_ for _ in range(reps)
            for k in period if k in ("swa", "full")]
     shared = len(own) - 1 - own[::-1].index("full") if "full" in own else 0
+    groups = {g: params[g] for g in set(PLAN_GROUPS.values()) if g in params}
+    expert_stacks = None
+    if cfg.kind_layers("moe") and any(in_place(
+            B, T, cfg.hidden_size, cfg.moe_stored_size,
+            moe.stored_dtype(groups["moe_layers"]["up"]), x.dtype, mesh,
+            cfg.expert_gate) for in_place in (moe.list_path,
+                                              moe.grouped_path)):
+        # read in place, whole: ``forward``'s note on the expert stacks
+        expert_stacks = {n: groups["moe_layers"][n] for n in ("up", "down")}
+        groups["moe_layers"] = {n: a for n, a in groups["moe_layers"].items()
+                                if n not in expert_stacks}
 
     def go(runs, at, x, pool, spool, mem, positions, token_valid):
         base, seen = at
-        starts = positions[:, 0]
+        # what every block of these runs is handed, beside its place
+        step = dict(positions=positions, starts=positions[:, 0],
+                    state_ids=state_ids, token_valid=token_valid,
+                    block_tables=block_tables, kv_len=kv_len, mesh=mesh,
+                    shared_kv_layer=shared,
+                    moe_capacity_tokens=moe_capacity_tokens,
+                    expert_stacks=expert_stacks)
+        works = []
         for period, repeats in runs:
             if "mamba_mem" in period and mem is None and repeats > 1:
                 mem = jnp.zeros(x.shape[:2] + (cfg.mamba_d_inner,),
@@ -625,6 +911,7 @@ def _run_plan(params: Params, cfg: ModelConfig, x, positions, cache,
             def body(carry, p, period=period, base=base, seen=seen,
                      per=per):
                 h, pool, spool, mem = carry
+                did = []
                 for j, kind in enumerate(period):
                     group = PLAN_GROUPS[kind]
                     layer = base + p * len(period) + j
@@ -635,32 +922,32 @@ def _run_plan(params: Params, cfg: ModelConfig, x, positions, cache,
                     lp = jax.tree.map(lambda a: a[layer],
                                       params["layers"])
                     lp.update(jax.tree.map(lambda a: a[among],
-                                           params[group]))
-                    h, pool, spool, mem = _plan_layer(
+                                           groups[group]))
+                    h, pool, spool, mem, work = PLAN_BLOCKS[kind](
                         cfg, kind, h, lp, pool, spool, mem,
-                        positions=positions, starts=starts,
-                        state_ids=state_ids, token_valid=token_valid,
-                        block_tables=block_tables, kv_len=kv_len,
-                        mesh=mesh, layer=layer, group_layer=among,
-                        shared_kv_layer=shared)
-                return (h, pool, spool, mem), None
+                        dict(step, layer=layer, group_layer=among))
+                    did.append(work)
+                return (h, pool, spool, mem), _sum_work(did)
 
             carry = (x, pool, spool, mem)
             if repeats == 1:
-                carry, _ = body(carry, 0)
+                carry, work = body(carry, 0)
             else:
-                carry, _ = jax.lax.scan(body, carry, jnp.arange(repeats))
+                carry, work = jax.lax.scan(body, carry, jnp.arange(repeats))
+                if work is not None:
+                    work = moe.Work(*map(jnp.sum, work))
+            works.append(work)
             x, pool, spool, mem = carry
             base += len(period) * repeats
             seen = {g: seen[g] + per[g] * repeats for g in seen}
-        return x, pool, spool, mem, (base, seen)
+        return x, pool, spool, mem, (base, seen), _sum_work(works)
 
     plan = cfg.plan_
     cut = next((n for n, (period, _) in enumerate(plan)
                 if "gmu" in period or "cross" in period), len(plan))
     if last is None:
         cut = len(plan)
-    x, pool, spool, mem, at = go(
+    x, pool, spool, mem, at, work = go(
         plan[:cut], (0, {g: 0 for g in PLAN_GROUPS.values()}), x,
         cache.carried(), cache.state_carried(), None, positions,
         token_valid)
@@ -681,9 +968,9 @@ def _run_plan(params: Params, cfg: ModelConfig, x, positions, cache,
                                   positions, one)[0],
                 lambda x, mem: x, x, mem)
         else:
-            x, pool, spool, mem, _ = go(plan[cut:], at, x, pool, spool,
-                                        mem, positions, one)
-    return x, cache.carried_back(pool, spool)
+            x, pool, spool, mem, _, _ = go(plan[cut:], at, x, pool, spool,
+                                           mem, positions, one)
+    return x, cache.carried_back(pool, spool), work
 
 
 def _gdn_layer(cfg: ModelConfig, x, lp: Params, state, state_ids, starts,
@@ -1118,7 +1405,7 @@ def _mlp_block(cfg: ModelConfig, x, lp: Params, kv, token_valid,
     with jax.named_scope("mlp_norm"):
         hidden = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps,
                           offset=offset)
-    act = jax.nn.silu if cfg.activation == "silu" else _gelu_tanh
+    act = _activation(cfg)
     work = None
     if "router" in lp:
         H = hidden.shape[-1]
@@ -1190,6 +1477,11 @@ def _layer_norm(x: jnp.ndarray, weight, bias,
 def _gelu_tanh(x: jnp.ndarray) -> jnp.ndarray:
     """Gemma's gelu_pytorch_tanh (jax.nn.gelu's approximate form)."""
     return jax.nn.gelu(x, approximate=True)
+
+
+def _activation(cfg: ModelConfig):
+    return {"silu": jax.nn.silu, "relu2": moe.relu2}.get(
+        cfg.activation, _gelu_tanh)
 
 
 def open_window(cfg: ModelConfig, block_tables: jnp.ndarray,
@@ -1323,16 +1615,20 @@ def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     with jax.named_scope("embed"):
         x = _embed(params, cfg, tokens)
     if cfg.layer_plan:
-        # several runs of periods, LayerNorms, no rotary embedding
+        # several runs of periods, no rotary embedding
         with jax.named_scope("layers"):
-            x, cache = _run_plan(params, cfg, x, positions, cache,
-                                 block_tables, state_ids, token_valid,
-                                 kv_len, mesh, last, finishing)
+            x, cache, work = _run_plan(
+                params, cfg, x, positions, cache, block_tables, state_ids,
+                token_valid, kv_len, mesh, last, finishing,
+                moe_capacity_tokens)
         with jax.named_scope("final_norm"):
-            x = _layer_norm(x, params["final_norm"],
-                            params["final_norm_bias"], cfg.rms_norm_eps)
+            if "final_norm_bias" in params:
+                x = _layer_norm(x, params["final_norm"],
+                                params["final_norm_bias"], cfg.rms_norm_eps)
+            else:
+                x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         with jax.named_scope("lm_head"):
-            return _lm_head(params, cfg, x), cache, None, window
+            return _lm_head(params, cfg, x), cache, work, window
     # the scan's unit is one PERIOD of the layer pattern (cfg.pattern_):
     # one attention layer for every model but the hybrid, whose period
     # runs its Gated DeltaNet layers and then its attention layer

@@ -45,12 +45,16 @@ _SKIP_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_mlp_norm",
                # differential attention's biases, lambdas and norm
                "attn_norm_bias", "mlp_norm_bias", "conv_bias", "x_proj",
                "dt_proj", "D", "qkv_bias", "o_bias", "subln", "lambda_q1",
-               "lambda_k1", "lambda_q2", "lambda_k2")
+               "lambda_k1", "lambda_q2", "lambda_k2",
+               # a one-sublayer block's norm; a Mamba-2 mixer's gated
+               # group norm
+               "norm", "gate_norm")
 # the groups of stacked layers a tree may hold: the scanned layers and
 # a layer plan's leading dense ones (models/llama.py)
 _LAYER_GROUPS = ("layers", "dense_layers", "gdn_layers", "attn_layers",
                  "mamba_layers", "diff_layers", "gmu_layers",
-                 "cross_layers")
+                 "cross_layers", "mamba2_layers", "gqa_layers",
+                 "moe_layers")
 
 
 def quantize_tensor(w: jnp.ndarray) -> Dict[str, jnp.ndarray]:

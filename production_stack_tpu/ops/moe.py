@@ -101,6 +101,12 @@ helm/templates/deployment-vllm-multi.yaml:57-64; expert parallelism is a
   a narrower expert with the same routing weight), decided from the
   shapes by the rule that decides whether the kernels run at all.
 
+- **Experts without a gate.** ``gate`` None (static: decided by the
+  parameters, before anything compiles): an expert is ``down(act(up(
+  x)))``, two matrices (Nemotron-H's, with ``relu2``); every path, the
+  scratch rule and the tiling count two matrices a slot then, and what
+  they trace for an expert with a gate is as it was.
+
 Routing follows Mixtral semantics: fp32 softmax over all experts, then
 top-k, then renormalize the selected probabilities to sum to 1; Qwen's
 (raw probabilities) and GLM-4.7-Flash's (sigmoid scores, a bias in the
@@ -203,9 +209,18 @@ def _edot(xb: jnp.ndarray, w) -> jnp.ndarray:
     return jnp.einsum("eci,eio->eco", xb, w)
 
 
+def relu2(x: jnp.ndarray) -> jnp.ndarray:
+    """relu(x) squared: the activation of an expert without a gate
+    (Nemotron-H's ``mlp_hidden_act`` "relu2")."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def _expert_ffn(xb: jnp.ndarray, gate, up, down,
                 act: Callable) -> jnp.ndarray:
-    """Batched per-expert FFN. xb [E, C, h] -> [E, C, h]."""
+    """Batched per-expert FFN. xb [E, C, h] -> [E, C, h]; ``gate``
+    None: ``down(act(up(x)))``."""
+    if gate is None:
+        return _edot(act(_edot(xb, up)), down)
     g = _edot(xb, gate)
     u = _edot(xb, up)
     return _edot(act(g) * u, down)
@@ -214,7 +229,7 @@ def _expert_ffn(xb: jnp.ndarray, gate, up, down,
 def _moe_exact(x, top_p, top_i, gate, up, down, act):
     """All experts over all tokens, combined by routing weight."""
     N = x.shape[0]
-    E = _wshape(gate)[0]
+    E = _wshape(up)[0]
     with jax.named_scope("moe_experts"):
         xb = jnp.broadcast_to(x, (E,) + x.shape)        # [E, N, h]
         y_e = _expert_ffn(xb, gate, up, down, act)      # [E, N, h]
@@ -278,14 +293,16 @@ _LIST_VMEM_SHARE = 0.5
 
 
 def list_scratch_bytes(hidden: int, inter: int, weight_dtype,
-                       act_dtype) -> int:
+                       act_dtype, gated: bool = True) -> int:
     """VMEM the list kernel holds for experts of [hidden, inter]: two
-    slots of gate, up and down as stored, and one matrix converted to
-    the activation dtype (none where the weights already are)."""
+    slots of gate, up and down as stored (``gated`` False: of up and
+    down), and one matrix converted to the activation dtype (none where
+    the weights already are)."""
     stored = jnp.dtype(weight_dtype)
     act = jnp.dtype(act_dtype)
     converted = act.itemsize if stored != act else 0
-    return hidden * inter * (2 * 3 * stored.itemsize + converted)
+    return hidden * inter * (2 * (3 if gated else 2) * stored.itemsize
+                             + converted)
 
 
 # the most tiles an expert is read in: beyond it a tile's copies are
@@ -294,7 +311,8 @@ def list_scratch_bytes(hidden: int, inter: int, weight_dtype,
 _MAX_TILES = 8
 
 
-def expert_tiles(hidden: int, inter: int, weight_dtype, act_dtype) -> int:
+def expert_tiles(hidden: int, inter: int, weight_dtype, act_dtype,
+                 gated: bool = True) -> int:
     """In how many tiles of its intermediate width the kernels that
     read experts in place take one expert: the fewest equal tiles, a
     power of two of them and each a multiple of the 128 lanes wide, of
@@ -308,7 +326,7 @@ def expert_tiles(hidden: int, inter: int, weight_dtype, act_dtype) -> int:
     tiles = 1
     while tiles <= _MAX_TILES:
         if inter % (tiles * 128) == 0 and list_scratch_bytes(
-                hidden, inter // tiles, weight_dtype, act_dtype
+                hidden, inter // tiles, weight_dtype, act_dtype, gated
                 ) <= _LIST_VMEM_SHARE * pallas_paged.VMEM_LIMIT_BYTES:
             return tiles
         tiles *= 2
@@ -316,7 +334,7 @@ def expert_tiles(hidden: int, inter: int, weight_dtype, act_dtype) -> int:
 
 
 def _experts_in_vmem(hidden: int, inter: int, weight_dtype, act_dtype,
-                     mesh) -> bool:
+                     mesh, gated: bool = True) -> bool:
     """What both kernels that read experts in place need (the list
     path's and the grouped path's): the Pallas kernels run at all
     (pallas_paged.flash_enabled: compiled on a TPU, off on the CPU,
@@ -327,11 +345,13 @@ def _experts_in_vmem(hidden: int, inter: int, weight_dtype, act_dtype,
     return (pallas_paged.flash_enabled()
             and (mesh is None
                  or all(size == 1 for size in mesh.shape.values()))
-            and expert_tiles(hidden, inter, weight_dtype, act_dtype) > 0)
+            and expert_tiles(hidden, inter, weight_dtype, act_dtype,
+                             gated) > 0)
 
 
 def list_path(rows: int, positions: int, hidden: int, inter: int,
-              weight_dtype, act_dtype, mesh=None) -> bool:
+              weight_dtype, act_dtype, mesh=None,
+              gated: bool = True) -> bool:
     """Do the expert matmuls of a forward over ``positions`` tokens of
     each of ``rows`` rows walk the list of experts hit (``_moe_list``)?
     Decided here, from the shapes and the mesh, at trace time: a decode
@@ -344,11 +364,12 @@ def list_path(rows: int, positions: int, hidden: int, inter: int,
     asks both rules to know whether to hand the stacks over whole."""
     return (positions == 1 and rows <= DENSE_THRESHOLD
             and _experts_in_vmem(hidden, inter, weight_dtype, act_dtype,
-                                 mesh))
+                                 mesh, gated))
 
 
 def grouped_path(rows: int, positions: int, hidden: int, inter: int,
-                 weight_dtype, act_dtype, mesh=None) -> bool:
+                 weight_dtype, act_dtype, mesh=None,
+                 gated: bool = True) -> bool:
     """Do the expert matmuls of that forward run grouped: every expert
     over the rows routed to it and no others (``_moe_grouped``)?
     Decided as ``list_path`` is: a prefill chunk, which is more
@@ -366,20 +387,22 @@ def grouped_path(rows: int, positions: int, hidden: int, inter: int,
     (``_moe_grouped``; PERF.md, PR 44), and is the same path."""
     return (positions > pallas_paged.DECODE_T_MAX
             and _experts_in_vmem(hidden, inter, weight_dtype, act_dtype,
-                                 mesh))
+                                 mesh, gated))
 
 
 def moe_path(rows: int, positions: int, num_experts: int, top_k: int,
              hidden: int, inter: int, weight_dtype, act_dtype, mesh=None,
-             capacity_factor: float = 2.0, capacity_tokens=None) -> str:
+             capacity_factor: float = 2.0, capacity_tokens=None,
+             gated: bool = True) -> str:
     """The strategy the experts of that forward take, as
     models/llama.py calls ``moe_mlp`` (a decode step exact): "list",
     "grouped", "exact" or "dispatch"; "list_tiled<n>" / "grouped_tiled<n>"
     where the kernel takes an expert in n tiles (``expert_tiles``).
     engine/runner.py keeps it per executable (``moe_paths``, GET
     /debug/perf ``device.moe_paths``)."""
-    shape = (rows, positions, hidden, inter, weight_dtype, act_dtype, mesh)
-    tiles = expert_tiles(hidden, inter, weight_dtype, act_dtype)
+    shape = (rows, positions, hidden, inter, weight_dtype, act_dtype, mesh,
+             gated)
+    tiles = expert_tiles(hidden, inter, weight_dtype, act_dtype, gated)
     tiled = f"_tiled{tiles}" if tiles > 1 else ""
     if list_path(*shape):
         return "list" + tiled
@@ -445,19 +468,21 @@ def _rank_in_expert(top_i: jnp.ndarray, valid, num_experts: int,
 def _expert_copies(ids_ref, layer, hbms, gu_buf, d_buf, sems, tiles, c,
                    slot, tile=0):
     """The copies of listed expert c's gate, up and down out of the
-    stacks in HBM into ``slot``: to start, or to wait for one by one.
+    stacks in HBM into ``slot``: to start, or to wait for one by one
+    (``hbms`` (up, down): an expert without a gate, two copies).
     ``tiles`` > 1 (static): of its tile ``tile`` (static), the columns
     of gate and up and the rows of down that one tile's width of the
     intermediate values spans."""
     e = ids_ref[c]
-    bufs = (gu_buf.at[slot, 0], gu_buf.at[slot, 1], d_buf.at[slot])
+    bufs = [gu_buf.at[slot, o] for o in range(len(hbms) - 1)] \
+        + [d_buf.at[slot]]
     if tiles == 1:
         srcs = [hbm.at[layer, e] for hbm in hbms]
     else:
         width = d_buf.shape[1]
         span = pl.ds(tile * width, width)
-        srcs = [hbms[0].at[layer, e, :, span], hbms[1].at[layer, e, :, span],
-                hbms[2].at[layer, e, span, :]]
+        srcs = [hbm.at[layer, e, :, span] for hbm in hbms[:-1]] \
+            + [hbms[-1].at[layer, e, span, :]]
     return [pltpu.make_async_copy(src, buf, sems.at[slot, o])
             for o, (src, buf) in enumerate(zip(srcs, bufs))]
 
@@ -481,8 +506,22 @@ def _scaled_dot(a, w, scale):
     return y if scale is None else y * scale
 
 
+def _act_products(x, in_copies, gu_buf, slot, scales, act, wait=None):
+    """act(x @ gate) * (x @ up), or act(x @ up) where the expert has no
+    gate (one copy in ``in_copies``), float32; each matrix waited for
+    where it is first read (``wait``: how, default at once)."""
+    wait = wait or (lambda cp: cp.wait())
+    wait(in_copies[0])
+    first = _scaled_dot(x, gu_buf[slot, 0], scales[0])
+    if len(in_copies) == 1:
+        return act(first)
+    wait(in_copies[1])
+    return act(first) * _scaled_dot(x, gu_buf[slot, 1], scales[1])
+
+
 def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
-                     *refs, act: Callable, quant: bool, tiles: int):
+                     *refs, act: Callable, quant: bool, tiles: int,
+                     gated: bool = True):
     """Every listed expert over all N rows.
 
     ids_ref   (SMEM) [M]     the experts hit, compacted
@@ -504,19 +543,21 @@ def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
     rows come a row a tile ([M8 x tiles, i/tiles], the expert's tiles
     in order), and every tile's products are weighed and added like an
     expert's.
+
+    gated False (static): no gate among the refs, the scale rows or
+    the slots ([2, 1, h, i]): the expert is ``act(x @ up) @ down``.
     """
-    gate_hbm, up_hbm, down_hbm = refs[:3]
-    refs = refs[3:]
+    mats = 3 if gated else 2
+    hbms, refs = refs[:mats], refs[mats:]
     if quant:
-        scale_refs, refs = refs[:3], refs[3:]
+        scale_refs, refs = refs[:mats], refs[mats:]
     out_ref, gu_buf, d_buf, sems, acc_ref = refs
     layer = layer_ref[0]
     count = count_ref[0]
     cdt = x_ref.dtype                              # the dots' operands
 
-    copies = functools.partial(_expert_copies, ids_ref, layer,
-                               (gate_hbm, up_hbm, down_hbm), gu_buf,
-                               d_buf, sems, tiles)
+    copies = functools.partial(_expert_copies, ids_ref, layer, hbms,
+                               gu_buf, d_buf, sems, tiles)
 
     @pl.when(count > 0)
     def _first():
@@ -548,18 +589,16 @@ def _moe_list_kernel(ids_ref, count_ref, layer_ref, x_ref, ti_ref, tp_ref,
                     axis=1, keepdims=True)
             if quant:
                 part = c if tiles == 1 else c * tiles + t
-                sg, su = (_scale_row(ref, part) for ref in scale_refs[:2])
-                sd = _scale_row(scale_refs[2], c)
+                *s_in, sd = ([_scale_row(ref, part)
+                              for ref in scale_refs[:-1]]
+                             + [_scale_row(scale_refs[-1], c)])
             else:
-                sg = su = sd = None
+                *s_in, sd = [None] * mats
             # each matrix is waited for where it is first read: gate's
             # products run under up's and down's copies
-            gate_copy, up_copy, down_copy = copies(c, slot, t)
-            gate_copy.wait()
-            g = _scaled_dot(x, gu_buf[slot, 0], sg)
-            up_copy.wait()
-            a = (act(g) * _scaled_dot(x, gu_buf[slot, 1], su)
-                 ).astype(cdt)                                  # [N, i]
+            *in_copies, down_copy = copies(c, slot, t)
+            a = _act_products(x, in_copies, gu_buf, slot, s_in, act
+                              ).astype(cdt)                     # [N, i]
             down_copy.wait()
             y = _scaled_dot(a, d_buf[slot], sd)
             acc_ref[...] += y * comb
@@ -584,12 +623,13 @@ def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
     contributes exactly zero there), reading ``count`` experts' weights
     where that reads all E. gate/up [L, E, h, i], down [L, E, i, h]
     (raw or int8-quantized), layer: int32 scalar, traced."""
-    quant = _quant().is_quantized(gate)
+    quant = _quant().is_quantized(up)
     N, h = x.shape
     k = top_i.shape[1]
-    L, E, _, inter = _wshape(gate)
-    mats = [w["w8"] if quant else w for w in (gate, up, down)]
-    tiles = expert_tiles(h, inter, mats[0].dtype, x.dtype)
+    L, E, _, inter = _wshape(up)
+    stacks = (up, down) if gate is None else (gate, up, down)
+    mats = [w["w8"] if quant else w for w in stacks]
+    tiles = expert_tiles(h, inter, mats[0].dtype, x.dtype, gate is not None)
     inter //= tiles
 
     def whole(*_):
@@ -598,13 +638,13 @@ def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
     in_specs = [pl.BlockSpec((N, h), whole),
                 pl.BlockSpec((N, k), whole),
                 pl.BlockSpec((N, k), whole)]
-    in_specs += [pl.BlockSpec(memory_space=pltpu.HBM)] * 3
+    in_specs += [pl.BlockSpec(memory_space=pltpu.HBM)] * len(mats)
     operands = [x, top_i, top_p.astype(jnp.float32)] + mats
     if quant:
         # the listed experts' scale rows, gathered out of the stacks
         # (1.2 MB a layer beside the experts' 8.65 MB each)
         rows = jnp.pad(ids, (0, -ids.shape[0] % 8))
-        for w in (gate, up, down):
+        for w in stacks:
             sc = (w["scale"][layer, rows] if w is down      # [M8, w]
                   else _tile_scales(w, layer, rows, tiles))
             in_specs.append(pl.BlockSpec(sc.shape, whole))
@@ -612,16 +652,17 @@ def _moe_list(x, top_p, top_i, gate, up, down, act, ids, count, layer):
     with jax.named_scope("moe_experts"):
         return pl.pallas_call(
             functools.partial(_moe_list_kernel, act=act, quant=quant,
-                              tiles=tiles),
+                              tiles=tiles, gated=gate is not None),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(1,),
                 in_specs=in_specs,
                 out_specs=pl.BlockSpec((N, h), whole),
                 scratch_shapes=[
-                    pltpu.VMEM((2, 2, h, inter), mats[0].dtype),
-                    pltpu.VMEM((2, inter, h), mats[2].dtype),
-                    pltpu.SemaphoreType.DMA((2, 3)),
+                    pltpu.VMEM((2, len(mats) - 1, h, inter),
+                               mats[0].dtype),
+                    pltpu.VMEM((2, inter, h), mats[-1].dtype),
+                    pltpu.SemaphoreType.DMA((2, len(mats))),
                     pltpu.VMEM((N, h), jnp.float32),
                 ],
             ),
@@ -714,7 +755,7 @@ def _group_rows(top_i: jnp.ndarray, valid, num_experts: int, align: int,
 
 def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
                         xs_hbm, *refs, act: Callable, quant: bool,
-                        align: int, tiles: int):
+                        align: int, tiles: int, gated: bool = True):
     """Every listed expert over its own rows.
 
     ids_ref    (SMEM) [M]    the experts that have a row, compacted
@@ -735,11 +776,13 @@ def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
     each walking the expert's segment again and writing its products
     to a plane of its own, out (HBM) [tiles x P, h]: tile t's at row
     t P + the segment's; the caller sums the planes.
+
+    gated False (static): as the list kernel's.
     """
-    gate_hbm, up_hbm, down_hbm = refs[:3]
-    refs = refs[3:]
+    mats = 3 if gated else 2
+    hbms, refs = refs[:mats], refs[mats:]
     if quant:
-        scale_refs, refs = refs[:3], refs[3:]
+        scale_refs, refs = refs[:mats], refs[mats:]
     out_hbm, gu_buf, d_buf, sems, x_buf, y_buf, x_sems, y_sems = refs
     layer = layer_ref[0]
     count = count_ref[0]
@@ -747,9 +790,8 @@ def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
     R = x_buf.shape[1]
     P = xs_hbm.shape[0]
 
-    copies = functools.partial(_expert_copies, ids_ref, layer,
-                               (gate_hbm, up_hbm, down_hbm), gu_buf,
-                               d_buf, sems, tiles)
+    copies = functools.partial(_expert_copies, ids_ref, layer, hbms,
+                               gu_buf, d_buf, sems, tiles)
 
     def rows_in(row, slot):
         return pltpu.make_async_copy(
@@ -788,18 +830,19 @@ def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
 
             if quant:
                 part = c if tiles == 1 else c * tiles + t
-                sg, su = (_scale_row(ref, part) for ref in scale_refs[:2])
-                sd = _scale_row(scale_refs[2], c)
+                *s_in, sd = ([_scale_row(ref, part)
+                              for ref in scale_refs[:-1]]
+                             + [_scale_row(scale_refs[-1], c)])
             else:
-                sg = su = sd = None
-            gate_copy, up_copy, down_copy = copies(c, slot, t)
+                *s_in, sd = [None] * mats
+            *in_copies, down_copy = copies(c, slot, t)
             # the rows walked after this tile's: the expert's own again
             # for its next tile, else the next expert's
             then = after if last_tile else first
             plane = t * P
 
-            def one_pass(p, n, slot=slot, sg=sg, su=su, sd=sd,
-                         gate_copy=gate_copy, up_copy=up_copy,
+            def one_pass(p, n, slot=slot, s_in=s_in, sd=sd,
+                         in_copies=in_copies,
                          down_copy=down_copy, then=then, plane=plane,
                          last_tile=last_tile):
                 """Rows first + p R .. + R of the buffer; n: the passes
@@ -819,11 +862,9 @@ def _moe_grouped_kernel(ids_ref, count_ref, layer_ref, seg_ref, passes_ref,
                 rows_in(row, rs).wait()
                 x = x_buf[rs]
                 # each matrix is waited for where it is first read
-                pl.when(p == 0)(gate_copy.wait)
-                g = _scaled_dot(x, gu_buf[slot, 0], sg)
-                pl.when(p == 0)(up_copy.wait)
-                a = (act(g) * _scaled_dot(x, gu_buf[slot, 1], su)
-                     ).astype(cdt)
+                a = _act_products(
+                    x, in_copies, gu_buf, slot, s_in, act,
+                    lambda cp: pl.when(p == 0)(cp.wait)).astype(cdt)
                 pl.when(p == 0)(down_copy.wait)
                 y_buf[rs] = _scaled_dot(a, d_buf[slot], sd
                                         ).astype(y_buf.dtype)
@@ -867,11 +908,12 @@ def _grouped_products(x, top_i, tokens, gate, up, down, act, valid, layer,
     assignment left out holds the buffer's row 0, some expert's
     product, which its weight of zero takes out of the sum (a select
     over the block here was a pass of its own, 35 us a layer in N)."""
-    quant = _quant().is_quantized(gate)
+    quant = _quant().is_quantized(up)
     h = x.shape[1]
-    L, E, _, inter = _wshape(gate)
-    mats = [w["w8"] if quant else w for w in (gate, up, down)]
-    tiles = expert_tiles(h, inter, mats[0].dtype, x.dtype)
+    L, E, _, inter = _wshape(up)
+    stacks = (up, down) if gate is None else (gate, up, down)
+    mats = [w["w8"] if quant else w for w in stacks]
+    tiles = expert_tiles(h, inter, mats[0].dtype, x.dtype, gate is not None)
     inter //= tiles
     R = GROUPED_ROWS
     align = _row_align(x.dtype)
@@ -885,11 +927,11 @@ def _grouped_products(x, top_i, tokens, gate, up, down, act, valid, layer,
         passes = -(-rows // R)
 
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
-    in_specs = [hbm] * 4
+    in_specs = [hbm] * (1 + len(mats))
     operands = [xs] + mats
     if quant:
         listed = jnp.pad(ids, (0, -ids.shape[0] % 8))
-        for w in (gate, up, down):
+        for w in stacks:
             sc = (w["scale"][layer, listed] if w is down    # [M8, w]
                   else _tile_scales(w, layer, listed, tiles))
             in_specs.append(pl.BlockSpec(sc.shape, lambda *_: (0, 0)))
@@ -897,16 +939,18 @@ def _grouped_products(x, top_i, tokens, gate, up, down, act, valid, layer,
     with jax.named_scope("moe_experts"):
         ys = pl.pallas_call(
             functools.partial(_moe_grouped_kernel, act=act, quant=quant,
-                              align=align, tiles=tiles),
+                              align=align, tiles=tiles,
+                              gated=gate is not None),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=(1,),
                 in_specs=in_specs,
                 out_specs=hbm,
                 scratch_shapes=[
-                    pltpu.VMEM((2, 2, h, inter), mats[0].dtype),
-                    pltpu.VMEM((2, inter, h), mats[2].dtype),
-                    pltpu.SemaphoreType.DMA((2, 3)),
+                    pltpu.VMEM((2, len(mats) - 1, h, inter),
+                               mats[0].dtype),
+                    pltpu.VMEM((2, inter, h), mats[-1].dtype),
+                    pltpu.SemaphoreType.DMA((2, len(mats))),
                     pltpu.VMEM((2, R, h), x.dtype),
                     pltpu.VMEM((2, R, h), x.dtype),
                     pltpu.SemaphoreType.DMA((2,)),
@@ -1115,7 +1159,7 @@ def _moe_grouped(x, top_p, top_i, gate, up, down, act, valid, layer,
     nothing else). Nothing of N k rows by E or by h is built."""
     N, h = x.shape
     k = top_i.shape[1]
-    E = _wshape(gate)[1]
+    E = _wshape(up)[1]
     A = N * k
     if not router_experts or router_experts == E:
         y, count, multiplied = _grouped_products(
@@ -1171,7 +1215,7 @@ def _moe_dispatch(x, top_p, top_i, gate, up, down, act, capacity,
                   valid=None, held: bool = False):
     """Scatter-based capacity dispatch (see module docstring)."""
     N, h = x.shape
-    E = _wshape(gate)[0]
+    E = _wshape(up)[0]
     k = top_i.shape[1]
 
     # padding tokens must not compete for expert capacity: they are
@@ -1207,7 +1251,8 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             router_score: str = "softmax", router_bias=None,
             routed_scale: float = 1.0, expert_offset: int = 0):
     """MoE feed-forward. x [N, h]; router_w [h, E]; gate/up [E, h, i];
-    down [E, i, h]. Returns ([N, h] in x.dtype, ``Work``: the experts
+    down [E, i, h]; gate None: experts without a gate, ``down(act(up(
+    x)))``. Returns ([N, h] in x.dtype, ``Work``: the experts
     whose weights the call read and the rows they multiplied, int32
     scalars).
 
@@ -1242,7 +1287,7 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
     and scale; every path takes the weights it gives unchanged.
     """
     N = x.shape[0]
-    E = _wshape(gate)[-3]
+    E = _wshape(up)[-3]
     with jax.named_scope("moe_router"):
         top_p, top_i = route(x, router_w, top_k, renormalize=renormalize,
                              score=router_score, bias=router_bias,
@@ -1257,9 +1302,9 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate: jnp.ndarray,
             top_i = jnp.where(here, top_i - expert_offset, E)
             top_p = jnp.where(here, top_p, 0.0)
     if layer is not None:
-        h, inter = _wshape(gate)[-2:]
-        shape = (N // positions, positions, h, inter, stored_dtype(gate),
-                 x.dtype)
+        h, inter = _wshape(up)[-2:]
+        shape = (N // positions, positions, h, inter, stored_dtype(up),
+                 x.dtype, None, gate is not None)
         assert exact is not False and (
             list_path(*shape) or grouped_path(*shape)), (
             "moe_mlp was handed whole stacks where neither list_path "

@@ -1501,3 +1501,151 @@ def test_plan_step_program_compiles_at_phi4flash_widths(
     assert (compiled.memory_analysis().alias_size_in_bytes
             >= 2 * 9 * N * 10 * BS * 128 * 2 + 9 * 9 * 16 * 5120 * 4)
     _fits(compiled, f"phi4flash whole {program}")
+
+
+def _nemotron_runner(topo, monkeypatch):
+    """The runner skeleton at the benchmark's Nemotron-3-Nano file,
+    whole (52 blocks in four runs of periods), 8 slots of 16384 tokens
+    over the six-layer K/V pool and 9 state pages (the cell's
+    geometry)."""
+    import json
+    from chipbench.engine_child import model_config
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.models import config as model_configs
+    from production_stack_tpu.models.kv import cache_for
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "chipbench", "configs",
+            "nemotron-3-nano-30b-a3b-int8-e32.json")) as f:
+        conf = json.load(f)
+    mcfg = model_config(conf, "nemotron-share")
+    monkeypatch.setitem(model_configs.PRESETS, "nemotron-share", mcfg)
+    monkeypatch.setattr(
+        "production_stack_tpu.models.kv.cache_for",
+        lambda cfg, n, bs, state_pages=0, **kw: cache_for(
+            cfg, n, bs, state_pages=9, **kw))
+    runner, params, _, rep = _runner_shapes(
+        topo, 1, kv_blocks=2049, model="nemotron-share")
+    runner.engine_cfg = EngineConfig(
+        model="nemotron-share", quantization="int8", max_num_seqs=8,
+        max_model_len=16384, kv_pool_tokens=131072, prefill_chunk=2048)
+    cache = jax.tree.map(
+        lambda x: rep(x.shape, x.dtype),
+        jax.eval_shape(partial(cache_for, mcfg, 2049, 64, state_pages=9)))
+    return runner, params, cache, rep
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk"])
+def test_sublayer_step_program_compiles_at_nemotron_widths(
+        topo, tpu_branches, monkeypatch, program):
+    """One decode window of 8 rows and one 2048-token prefill chunk of
+    one row at the longest kv bucket of ALL 52 blocks (four runs of
+    periods, 14 traced sublayers), compiled for the described v5e: the
+    scan is ops/mamba2.py's kernel of the forward's kind (the chunked
+    form's products on the matrix unit), the attention the paged
+    kernels' plain K/V case at 16 query heads a pool head of 128, the
+    experts WITHOUT a gate the list and the grouped kernel with the
+    held share's rounds, their stacks (stored 1920 wide) read in place
+    from inside the plan run; neither the six-layer K/V pool nor the
+    state pool is copied or sliced (both aliased to the result), nor a
+    layer of the expert stacks; the program fits the chip beside 9.1 GB
+    of weights and the pools."""
+    import re
+    N = 2049
+    runner, params, cache, rep = _nemotron_runner(topo, monkeypatch)
+    B = runner.engine_cfg.max_num_seqs
+    a = _step_args(runner, rep, B)
+    tables = rep(runner.table_shape, jnp.int32)
+    assert runner.table_shape == (8, 257)
+    small = (a["sampling"], a["key"], a["guide_next"], a["guide_id"],
+             a["guide_state"], a["counts"], a["seen"])
+    if program == "decode_window":
+        fn = jax.jit(partial(runner._decode_impl, steps=8, kv_len=16384,
+                             greedy=True), donate_argnums=(1,))
+        compiled = fn.lower(params, cache, tables, rep((B,), jnp.int32),
+                            rep((B,), jnp.int32), *small).compile()
+        want = {"paged_decode_attention", "moe_list_experts",
+                "mamba2_recurrent_step"}
+        path, moe_path = "pallas_paged_decode", "list"
+    else:
+        fn = jax.jit(partial(runner._prefill_impl, kv_len=16384),
+                     donate_argnums=(1,))
+        compiled = fn.lower(params, cache, tables,
+                            rep((1,), jnp.int32), rep((1, 2048), jnp.int32),
+                            rep((1,), jnp.int32), rep((1,), jnp.int32),
+                            *small).compile()
+        want = {"paged_attention", "moe_grouped_experts", "moe_held_sum",
+                "mamba2_chunk_scan"}
+        path, moe_path = "pallas_paged", "grouped"
+    hlo = compiled.as_text()
+    calls = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_0-9]+?)[.\-\d]* = [^=]*? custom-call\(", hlo)}
+    assert {c for c in calls
+            if c.startswith(("paged", "moe", "mamba"))} == want
+    positions = 1 if program == "decode_window" else 2048
+    assert runner._attention_path(positions, None, 16384) == path
+    runner.params = params      # (_moe_path asks the stacks' dtype)
+    assert runner._moe_path(8 if positions == 1 else 1, positions) \
+        == moe_path
+    assert runner._mixer_path(positions) == (
+        "mamba2_recurrent_step" if positions == 1 else "mamba2_chunk_scan")
+    pools = re.compile(
+        r"([\w.\-]+) = \(?\w+\[(?:6,{n},2,{bs},128|23,9,128,4096|"
+        r"23,32,2688,1920|23,32,1920,2688)\]\S* "
+        r"([\w\-]+)\(".format(n=N, bs=BS))
+    moved = [m.group(1) + ": " + m.group(2)
+             for m in map(pools.search, hlo.splitlines()) if m
+             and re.search(r"copy|dynamic.slice|dynamic.update.slice",
+                           m.group(1) + " " + m.group(2))]
+    assert not moved, moved
+    # no expert layer's slice of the stacks is copied out either
+    slices = re.findall(r"= s8\[32,(?:2688,1920|1920,2688)\]\S* [\w\-]+\(",
+                        hlo)
+    assert not slices, slices[:3]
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= 2 * 6 * N * 2 * BS * 128 * 2 + 23 * 9 * 128 * 4096 * 4)
+    _fits(compiled, f"nemotron share {program}")
+
+
+@pytest.mark.parametrize("rows,positions,kernel", [
+    (8, 1, "moe_list_experts"), (1, 2048, "moe_grouped_experts")])
+def test_ungated_expert_kernels_at_nemotron_widths(
+        topo, tpu_branches, monkeypatch, rows, positions, kernel):
+    """The list and the grouped kernel on experts WITHOUT a gate (two
+    matrices a slot, relu^2), a share of 32 behind a 128-wide sigmoid
+    router, hidden 2688: STORED 1920 wide they compile for the described
+    v5e; at the published 1856 = 14.5 x 128 the compiler refuses the
+    expert's copy out of the stacks (an int8 array 1856 wide lies 1920
+    wide in HBM's tiles anyway, so the stored width costs no byte
+    more): why the stacks are stored padded (PERF.md, PR 54)."""
+    from production_stack_tpu.ops import moe
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def lower(inter):
+        up = {"w8": shaped((2, 32, 2688, inter), jnp.int8),
+              "scale": shaped((2, 32, inter), jnp.float32)}
+        down = {"w8": shaped((2, 32, inter, 2688), jnp.int8),
+                "scale": shaped((2, 32, 2688), jnp.float32)}
+        return jax.jit(lambda x, rw, bias, up, down, layer: moe.moe_mlp(
+            x, rw, None, up, down, top_k=6, act=moe.relu2,
+            exact=True if positions == 1 else None, layer=layer,
+            positions=positions, router_score="sigmoid", router_bias=bias,
+            routed_scale=2.5)).lower(
+                shaped((rows * positions, 2688), jnp.bfloat16),
+                shaped((2688, 128), jnp.bfloat16),
+                shaped((128,), jnp.float32), up, down,
+                shaped((), jnp.int32))
+
+    hlo = lower(1920).compile().as_text()
+    assert kernel in hlo
+    assert moe.expert_tiles(2688, 1856, jnp.int8, jnp.bfloat16,
+                            gated=False) == 0
+    tiles = moe.expert_tiles
+    monkeypatch.setattr(moe, "expert_tiles",
+                        lambda h, i, *a, **k: 1 if i == 1856
+                        else tiles(h, i, *a, **k))
+    with pytest.raises(Exception, match="aligned to tiling"):
+        lower(1856).compile()

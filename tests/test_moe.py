@@ -42,9 +42,10 @@ def _reference_moe(x, rw, g, u, d, k, capacity=None, valid=None,
     dispatch. capacity simulates per-expert slots filled in token-major
     assignment order (the dispatch path's ranking); valid marks padding
     rows that contribute nothing and consume no capacity."""
-    x, rw, g, u, d = map(np.asarray, (x, rw, g, u, d))
+    x, rw, u, d = map(np.asarray, (x, rw, u, d))
+    g = None if g is None else np.asarray(g)
     N = x.shape[0]
-    E = g.shape[0]
+    E = u.shape[0]
     out = np.zeros_like(x)
     counts = np.zeros(E, np.int64)
     for t in range(N):
@@ -60,10 +61,26 @@ def _reference_moe(x, rw, g, u, d, k, capacity=None, valid=None,
                 if counts[e] >= capacity:
                     continue          # dropped: rides the residual
                 counts[e] += 1
-            hidden = (x[t] @ g[e])
-            hidden = hidden / (1 + np.exp(-hidden)) * (x[t] @ u[e])
-            out[t] += wi * (hidden @ d[e])
+            out[t] += wi * (_numpy_expert(x[t], g, u, e) @ d[e])
     return out
+
+
+def _numpy_expert(x_t, g, u, e):
+    """One expert's intermediate values for one token: silu(x gate) x
+    (x up), or relu(x up)^2 where the experts have no gate (g None)."""
+    if g is None:
+        return np.maximum(x_t @ u[e], 0.0) ** 2
+    hidden = x_t @ g[e]
+    return hidden / (1 + np.exp(-hidden)) * (x_t @ u[e])
+
+
+def _ungate(case: str, g, u, d):
+    """The stacks and the activation of a case: its name says
+    ``ungated`` where the experts are ``down(relu(up(x))^2)``, two
+    matrices (Nemotron-H's)."""
+    if case.startswith("ungated"):
+        return (None, u, d), moe.relu2
+    return (g, u, d), jax.nn.silu
 
 
 def test_route_weights_normalized():
@@ -75,20 +92,28 @@ def test_route_weights_normalized():
     assert (np.asarray(idx)[:, 0] != np.asarray(idx)[:, 1]).all()
 
 
-def test_exact_path_matches_reference():
-    x, rw, g, u, d = _rand_moe(jax.random.PRNGKey(1))
-    got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1000)
+GATES = ["gated", "ungated-relu2"]
+
+
+@pytest.mark.parametrize("case", GATES)
+def test_exact_path_matches_reference(case):
+    x, rw, *w = _rand_moe(jax.random.PRNGKey(1))
+    (g, u, d), act = _ungate(case, *w)
+    got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1000,
+                         act=act)
     np.testing.assert_allclose(np.asarray(got),
                                _reference_moe(x, rw, g, u, d, 2),
                                atol=1e-4, rtol=1e-4)
 
 
-def test_dispatch_path_matches_reference():
-    x, rw, g, u, d = _rand_moe(jax.random.PRNGKey(2))
+@pytest.mark.parametrize("case", GATES)
+def test_dispatch_path_matches_reference(case):
+    x, rw, *w = _rand_moe(jax.random.PRNGKey(2))
+    (g, u, d), act = _ungate(case, *w)
     # capacity_factor 1.6 -> capacity < N (dispatch branch) but above the
     # realized max expert load for this seed, so no token is dropped
     got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
-                      capacity_factor=1.6)
+                         capacity_factor=1.6, act=act)
     cap = moe.capacity_for(x.shape[0], 4, 2, 1.6)
     assert cap < x.shape[0], "capacity must not force the exact branch"
     np.testing.assert_allclose(np.asarray(got),
@@ -96,12 +121,14 @@ def test_dispatch_path_matches_reference():
                                atol=1e-4, rtol=1e-4)
 
 
-def test_dispatch_with_drops_matches_reference():
+@pytest.mark.parametrize("case", GATES)
+def test_dispatch_with_drops_matches_reference(case):
     """Over-capacity assignments drop in token-major rank order — the
     numpy reference simulates the same fill and must agree exactly."""
-    x, rw, g, u, d = _rand_moe(jax.random.PRNGKey(3))
+    x, rw, *w = _rand_moe(jax.random.PRNGKey(3))
+    (g, u, d), act = _ungate(case, *w)
     got, _ = moe.moe_mlp(x, rw, g, u, d, top_k=2, dense_threshold=1,
-                      capacity_factor=0.5)
+                         capacity_factor=0.5, act=act)
     cap = moe.capacity_for(x.shape[0], 4, 2, 0.5)
     ref = _reference_moe(x, rw, g, u, d, 2, capacity=cap)
     assert np.isfinite(np.asarray(got)).all()
@@ -337,6 +364,10 @@ LIST_CASES = {
     "same-4-experts": ("same4", 16, 16, "int8", False),
     "all-60-hit": ("all", 16, 16, "int8", False),
     "renormalized": ("random", 16, 15, "int8", True),
+    # experts without a gate (two matrices a slot, relu^2)
+    "ungated-int8": ("random", 16, 15, "int8", True),
+    "ungated-bf16-weights": ("random", 16, 16, "bf16", False),
+    "ungated-all-60-hit": ("all", 16, 16, "int8", False),
 }
 
 
@@ -351,8 +382,9 @@ def test_list_path_matches_exact_and_reference(kernels_on, case):
     x, rw, stacks = _rigged_stacks(jax.random.PRNGKey(11), routing, N=N,
                                    weights=weights)
     valid = jnp.arange(N) < n_valid
+    stacks, act = _ungate(case, *stacks)
     got, read = jax.jit(lambda x, *w: moe.moe_mlp(
-        x, rw, *w, top_k=k, valid=valid, renormalize=renorm,
+        x, rw, *w, top_k=k, valid=valid, renormalize=renorm, act=act,
         exact=True, layer=jnp.int32(layer)))(x, *stacks)
 
     top_p, top_i = moe.route(x, rw, k, renormalize=renorm)
@@ -361,11 +393,10 @@ def test_list_path_matches_exact_and_reference(kernels_on, case):
     assert int(read.expert_rows) == len(chosen) * N
     assert len(chosen) == {"same4": 4, "all": 60}.get(routing, len(chosen))
     one = [jax.tree_util.tree_map(lambda a: a[layer], w) for w in stacks]
-    exact = moe._moe_exact(x, top_p * valid[:, None], top_i, *one,
-                           jax.nn.silu)
+    exact = moe._moe_exact(x, top_p * valid[:, None], top_i, *one, act)
     ref = _reference_moe(x.astype(jnp.float32), rw.astype(jnp.float32),
-                         *(_float32(w) for w in one), k,
-                         valid=np.asarray(valid), renormalize=renorm)
+                         *(w if w is None else _float32(w) for w in one),
+                         k, valid=np.asarray(valid), renormalize=renorm)
     got = np.asarray(got.astype(jnp.float32))
     scale = max(np.abs(ref).max(), 1e-6)
     # bf16 activations on both paths; the list path rounds less often
@@ -560,13 +591,12 @@ def test_forward_of_several_positions_never_walks_the_list(kernels_on, B,
 def _reference_routed(x, top_p, top_i, g, u, d, valid):
     """Per-token numpy loop over the experts each token chose, at the
     weights the router gave: float32, independent of ops/moe.py."""
-    x, top_p, top_i, g, u, d = map(np.asarray, (x, top_p, top_i, g, u, d))
+    x, top_p, top_i, u, d = map(np.asarray, (x, top_p, top_i, u, d))
+    g = None if g is None else np.asarray(g)
     out = np.zeros_like(x)
     for t in np.flatnonzero(valid):
         for w, e in zip(top_p[t], top_i[t]):
-            hidden = x[t] @ g[e]
-            hidden = hidden / (1 + np.exp(-hidden)) * (x[t] @ u[e])
-            out[t] += w * (hidden @ d[e])
+            out[t] += w * (_numpy_expert(x[t], g, u, e) @ d[e])
     return out
 
 
@@ -602,6 +632,13 @@ GROUPED_CASES = {
                             None, "softmax"),
     "no-valid-token": (1, 128, 8, 2, "bfloat16", "int8", "random", [0],
                        "softmax"),
+    # experts without a gate (relu^2), Nemotron-H's router
+    "ungated-128-f32-raw-8-top2": (1, 128, 8, 2, "float32", "raw",
+                                   "random", None, "softmax"),
+    "ungated-256-bf16-int8-64-top4-sigmoid": (
+        1, 256, 64, 4, "bfloat16", "int8", "random", [131], "sigmoid"),
+    "ungated-every-token-on-one-pair": (1, 256, 64, 2, "bfloat16", "int8",
+                                        "same", [200], "softmax"),
 }
 
 
@@ -649,9 +686,10 @@ def test_grouped_path_matches_exact_and_reference(kernels_on, case):
     x, rw, stacks, valid, k, router = _grouped_case(case)
     rows, tokens, E = GROUPED_CASES[case][:3]
     layer = 1
+    stacks, act = _ungate(case, *stacks)
     got, work = jax.jit(lambda x, *w: moe.moe_mlp(
         x, rw, *w, top_k=k, valid=valid, layer=jnp.int32(layer),
-        positions=tokens, **router))(x, *stacks)
+        positions=tokens, act=act, **router))(x, *stacks)
     assert got.dtype == x.dtype and got.shape == x.shape
 
     top_p, top_i = moe.route(
@@ -664,16 +702,16 @@ def test_grouped_path_matches_exact_and_reference(kernels_on, case):
     assert int(work.experts_read) == (per_expert > 0).sum()
     R = moe.GROUPED_ROWS
     assert int(work.expert_rows) == (-(-per_expert // R) * R).sum()
-    if case == "every-token-on-one-pair":
+    if case.endswith("every-token-on-one-pair"):
         assert sorted(per_expert[per_expert > 0]) == [200, 200]
     if case == "experts-with-no-row":
         assert (per_expert[8:] == 0).all() and per_expert.sum() == 256
 
     one = [jax.tree_util.tree_map(lambda a: a[layer], w) for w in stacks]
-    exact = moe._moe_exact(x, top_p * v[:, None], top_i, *one,
-                           jax.nn.silu)
+    exact = moe._moe_exact(x, top_p * v[:, None], top_i, *one, act)
     ref = _reference_routed(x.astype(jnp.float32), top_p, top_i,
-                            *(_float32(w) for w in one), v)
+                            *(w if w is None else _float32(w)
+                              for w in one), v)
     got = np.asarray(got.astype(jnp.float32))
     scale = max(np.abs(ref).max(), 1e-6)
     loose = x.dtype == jnp.bfloat16
@@ -726,6 +764,11 @@ HELD_CASES = {
                                      "raw", 1),
     "bf16-int8-two-rounds": ([3] * 70, None, "bfloat16", "int8", 1),
     "tiled-two-rounds": ([4] * 40, None, "bfloat16", "int8", 2),
+    # experts without a gate: the rounds and ``moe_held_sum`` alike
+    "ungated-f32-two-rounds": ([4] * 32 + [1], None, "float32", "raw", 1),
+    "ungated-bf16-int8-two-rounds": ([3] * 70, None, "bfloat16", "int8",
+                                     1),
+    "ungated-tiled-two-rounds": ([4] * 40, None, "bfloat16", "int8", 2),
 }
 
 
@@ -771,18 +814,21 @@ def test_held_grouped_path_matches_exact_and_reference(
     x, top_p, top_i, stacks, valid = _held_case(case)
     N, k = top_i.shape
     E, tiles = 8, HELD_CASES[case][4]
+    stacks, act = _ungate(case, *stacks)
+    gated = stacks[0] is not None
     if tiles > 1:       # two slots of a HALF of an expert fit, no more
         monkeypatch.setattr(
             moe, "_LIST_VMEM_SHARE",
-            1.5 * moe.list_scratch_bytes(128, 128, jnp.int8, jnp.bfloat16)
+            1.5 * moe.list_scratch_bytes(128, 128, jnp.int8, jnp.bfloat16,
+                                         gated)
             / pallas_paged.VMEM_LIMIT_BYTES)
-    assert moe.expert_tiles(128, 256, moe.stored_dtype(stacks[0]),
-                            x.dtype) == tiles
+    assert moe.expert_tiles(128, 256, moe.stored_dtype(stacks[1]),
+                            x.dtype, gated) == tiles
     B = moe.held_block(N, k, E, 64)
     assert B == 128
     layer = 1
     got, work = jax.jit(lambda x, *w: moe._moe_grouped(
-        x, top_p, top_i, *w, jax.nn.silu,
+        x, top_p, top_i, *w, act,
         None if valid is None else jnp.asarray(valid), jnp.int32(layer),
         64))(x, *stacks)
     assert got.dtype == x.dtype and got.shape == x.shape
@@ -802,14 +848,18 @@ def test_held_grouped_path_matches_exact_and_reference(
                    "every-assignment-here-4-rounds": 4,
                    "total-exactly-a-block": 1, "total-a-block-and-one": 2,
                    "padding-that-would-land-here": 2,
-                   "bf16-int8-two-rounds": 2, "tiled-two-rounds": 2}
+                   "bf16-int8-two-rounds": 2, "tiled-two-rounds": 2,
+                   "ungated-f32-two-rounds": 2,
+                   "ungated-bf16-int8-two-rounds": 2,
+                   "ungated-tiled-two-rounds": 2}
     assert int(work.rounds) == want_rounds[case]
 
     one = [jax.tree_util.tree_map(lambda a: a[layer], w) for w in stacks]
-    exact = moe._moe_exact(x, top_p, top_i, *one, jax.nn.silu)
+    exact = moe._moe_exact(x, top_p, top_i, *one, act)
     ref = _reference_routed(
         x.astype(jnp.float32), top_p, np.minimum(np.asarray(top_i), E - 1),
-        *(_float32(w) for w in one), v)           # (weight 0 elsewhere)
+        *(w if w is None else _float32(w) for w in one),
+        v)                                        # (weight 0 elsewhere)
     got = np.asarray(got.astype(jnp.float32))
     scale = max(np.abs(ref).max(), 1e-6)
     loose = x.dtype == jnp.bfloat16
@@ -1153,6 +1203,8 @@ def test_route_refuses_an_unknown_score():
 
 GLM47 = (2048, 1536)           # GLM-4.7-Flash's experts [h, i]
 GLM5 = (6144, 2048)            # GLM-5's
+NEMOTRON = (2688, 1856)        # Nemotron-3-Nano's, as published
+NEMOTRON_STORED = (2688, 1920)  # and as the stacks are stored
 
 
 @pytest.mark.parametrize("widths,weights,share,tiles", [
@@ -1162,7 +1214,10 @@ GLM5 = (6144, 2048)            # GLM-5's
     # a smaller share (what the tests below set) tiles GLM-4.7-Flash's
     # 12 lanes of 128; Qwen's 11 split into no power of two
     (GLM47, jnp.int8, 0.2, 2), (GLM47, jnp.int8, 0.1, 4),
-    (QWEN, jnp.int8, 0.2, 0), ((2048, 1400), jnp.int8, 0.5, 0)])
+    (QWEN, jnp.int8, 0.2, 0), ((2048, 1400), jnp.int8, 0.5, 0),
+    # Nemotron-3-Nano's: 1856 = 14.5 lanes tiles nowhere; stored 1920
+    # wide the expert comes whole
+    (NEMOTRON, jnp.int8, 0.5, 0), (NEMOTRON_STORED, jnp.int8, 0.5, 1)])
 def test_expert_tiles_rule(monkeypatch, widths, weights, share, tiles):
     """The fewest equal tiles, a power of two of them and each whole
     lanes wide, of which two slots fit the kernels' share of VMEM."""
@@ -1182,6 +1237,16 @@ def test_moe_path_names_the_tiled_kernels(kernels_on):
     assert moe.moe_path(16, 1, 64, 4, *GLM47, *args) == "list"
     assert moe.moe_path(1, 256, 60, 4, *QWEN, *args) == "grouped"
     assert moe.moe_path(4, 1, 8, 2, *MIXTRAL, *args) == "exact"
+    # experts without a gate hold two matrices a slot, and
+    # Nemotron-3-Nano's stored width runs both kernels, untiled
+    assert moe.list_scratch_bytes(*GLM5, *args, gated=False) \
+        == 6144 * 2048 * (4 + 2)
+    assert moe.moe_path(8, 1, 128, 6, *NEMOTRON_STORED, *args,
+                        gated=False) == "list"
+    assert moe.moe_path(1, 2048, 128, 6, *NEMOTRON_STORED, *args,
+                        gated=False) == "grouped"
+    assert moe.moe_path(8, 1, 128, 6, *NEMOTRON, *args,
+                        gated=False) == "exact"
 
 
 @pytest.mark.parametrize("widths", [QWEN, GLM47], ids=["qwen", "glm47"])
