@@ -573,7 +573,10 @@ def _mamba2_mixer(cfg: ModelConfig, hidden, lp: Params, state, state_ids,
     over x, B and C together; the output is the GROUP RMSNorm of y
     silu(z) (cfg.mamba_groups groups, one weight a channel). Scopes
     mamba2_proj, mamba2_conv, mamba2_chunk_scan / mamba2_recurrent_step
-    (ops/mamba2.ssd_scan), mamba2_gate_norm, mamba2_out_proj."""
+    (ops/mamba2.ssd_scan), mamba2_gate_norm, mamba2_out_proj; a prefill
+    chunk on the kernels (ops/mamba2.chunk_mix) has the convolution,
+    the scan, the gate and the norm inside mamba2_chunk_scan, and
+    mamba2_conv holds only the convolution's new state."""
     B, T, _ = hidden.shape
     di, H, G, N = (cfg.mamba_d_inner, cfg.mamba_heads, cfg.mamba_groups,
                    cfg.mamba_d_state)
@@ -588,6 +591,21 @@ def _mamba2_mixer(cfg: ModelConfig, hidden, lp: Params, state, state_ids,
         z, xbc, dt = zxd[..., :di], zxd[..., di:di + ch], zxd[..., di + ch:]
         dt = jnp.where(token_valid[..., None],
                        jax.nn.softplus(dt + lp["dt_bias"]), 0.0)
+    if mamba2.mamba2_path(T, di, H, G, N) == mamba2.CHUNKED:
+        # a prefill chunk on the kernels: everything between the two
+        # projections is ONE kernel on in_proj's output where it lies
+        prev = jnp.where(fresh[:, None, None], 0, conv[state_layer, ids])
+        y, hs = mamba2.chunk_mix(
+            zxd, dt, prev, lp["conv"], lp["conv_bias"],
+            -jnp.exp(lp["A_log"]), lp["D"], lp["gate_norm"],
+            cfg.rms_norm_eps, hs, ids, state_layer, fresh, hidden.dtype)
+        with jax.named_scope("mamba2_conv"):
+            conv = conv.at[state_layer, ids].set(mamba2.conv_tail(
+                zxd, di, prev,
+                jnp.sum(token_valid, axis=1, dtype=jnp.int32)))
+        with jax.named_scope("mamba2_out_proj"):
+            out = quant.dequant_matmul(y, lp["out_proj"], exact_scale=True)
+        return out, (hs, conv)
     with jax.named_scope("mamba2_conv"):
         prev = jnp.where(fresh[:, None, None], 0, conv[state_layer, ids])
         xbc, new_conv = gdn.causal_conv(

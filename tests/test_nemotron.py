@@ -278,25 +278,35 @@ def kernels_on(monkeypatch):
 
 
 def test_the_kernels_serve_what_the_jnp_forms_do(kernels_on):
-    """The same model with the Pallas kernels in interpret mode: both
-    of ops/mamba2.py's, and ops/moe.py's list and grouped kernels on
-    experts WITHOUT a gate, their stacks read in place from inside a
-    plan run (the attention heads of 32 are not whole lanes: the paged
-    kernels stay off, tests/test_pallas_paged.py holds them). Against
-    the reference, and the experts' work counted from inside the run:
-    a decode step reads at most top-3 experts a layer in 5 layers, a
-    chunk multiplies whole passes of 128 rows."""
+    """The same model, its state 128 wide as published (a group's B and
+    C whole vectors of lanes: what ops/mamba2.py's kernels tile), with
+    the Pallas kernels in interpret mode: both of ops/mamba2.py's (a
+    prefill chunk's mixer between its projections ONE kernel, across a
+    dispatch boundary and a padded tail), and ops/moe.py's list and
+    grouped kernels on experts WITHOUT a gate, their stacks read in
+    place from inside a plan run (the attention heads of 32 are not
+    whole lanes: the paged kernels stay off, tests/test_pallas_paged.py
+    holds them). Against the reference, and the experts' work counted
+    from inside the run: a decode step reads at most top-3 experts a
+    layer in 5 layers, a chunk multiplies whole passes of 128 rows. The
+    debug preset's own state of 16 is a shape the kernels refuse: it
+    runs the ``jax.numpy`` forms, kernels or no."""
     from production_stack_tpu.ops import mamba2
-    assert mamba2.mamba2_path(1, 256, 8, 2, 16) == mamba2.RECURRENT
-    assert mamba2.mamba2_path(32, 256, 8, 2, 16) == mamba2.CHUNKED
+    assert mamba2.mamba2_path(1, 256, 8, 2, 16) == mamba2.RECURRENT + "_jnp"
+    assert mamba2.mamba2_path(32, 256, 8, 2, 16) == "mamba2_chunk_scan_jnp"
+    cfg = dataclasses.replace(CFG, mamba_d_state=128)
+    assert mamba2.mamba2_path(1, 256, 8, 2, 128) == mamba2.RECURRENT
+    assert mamba2.mamba2_path(32, 256, 8, 2, 128) == mamba2.CHUNKED
     assert moe.moe_path(2, 1, 8, 3, 128, 128, jnp.float32, jnp.float32,
                         gated=False) == "list"
     assert moe.moe_path(2, 32, 8, 3, 128, 128, jnp.float32, jnp.float32,
                         gated=False) == "grouped"
-    params = live_params()
+    params = live_params(cfg)
     work = []
-    got = _served_logprobs(params, TOKS[:50], prefill_to=44, work_out=work)
-    assert worst(got, ref.logprobs(params, HF, TOKS[:50])) < 1e-4
+    got = _served_logprobs(params, TOKS[:50], prefill_to=44, cfg=cfg,
+                           work_out=work)
+    assert worst(got, ref.logprobs(
+        params, {**HF, "ssm_state_size": 128}, TOKS[:50])) < 1e-4
     for kind, n, w in work:
         if kind == "decode":
             assert 5 <= int(w.experts_read) <= 5 * 3

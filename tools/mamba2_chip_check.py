@@ -111,7 +111,7 @@ def main(argv=None) -> int:
     if args.tiny:
         hf.update(hybrid_override_pattern="MEM*EMEM*EME",
                   num_hidden_layers=12, hidden_size=128, mamba_num_heads=8,
-                  mamba_head_dim=32, n_groups=2, ssm_state_size=16,
+                  mamba_head_dim=32, n_groups=2, ssm_state_size=128,
                   num_attention_heads=4, num_key_value_heads=2,
                   head_dim=32, n_routed_experts=4, num_experts_per_tok=3,
                   moe_intermediate_size=48,
