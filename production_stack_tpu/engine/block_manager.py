@@ -42,7 +42,8 @@ class BlockManager:
                  namespace: str = "", bytes_per_token: int = 0,
                  layout: str = "kv_heads", index_bytes_per_token: int = 0,
                  state_pages: int = 0, state_bytes_per_slot: int = 0,
-                 pool_layers: int = 0, reader_layers: int = 0):
+                 pool_layers: int = 0, reader_layers: int = 0,
+                 weight_layers: int = 0):
         if num_blocks < 2:
             raise ValueError("pool needs at least one non-trash block")
         self.num_blocks = num_blocks          # includes trash block 0
@@ -57,6 +58,10 @@ class BlockManager:
         # decoder-hybrid-decoder's cross layers); reported, not used
         self.pool_layers = pool_layers
         self.reader_layers = reader_layers
+        # the model's layers as its weights count them: fewer than the
+        # pool's where a looped model runs them several times, each
+        # pass over pool layers of its own; reported, not used
+        self.weight_layers = weight_layers
         # bytes_per_token's part that a second pool under the same
         # tables takes ("latent+index": the sparse-attention indexer's
         # keys): a block id names a block of both pools, so every
@@ -161,6 +166,7 @@ class BlockManager:
             "layout": self.layout,
             "pool_layers": self.pool_layers,
             "reader_layers": self.reader_layers,
+            "weight_layers": self.weight_layers,
             "free": self.free_blocks,
             "active": self.active_blocks,
             "cached": self.cached_blocks,

@@ -505,6 +505,7 @@ class EngineEffAccounting:
                  hbm_peak_bytes_per_s: Optional[float] = None,
                  ring_entries: int = 256,
                  compile_hist=None, expert_bytes: int = 0,
+                 loop: Optional[Dict[str, int]] = None,
                  now_fn: Callable[[], float] = time.monotonic,
                  wall_fn: Callable[[], float] = time.time,
                  annotate: Optional[Callable[[str], object]] = None,
@@ -513,6 +514,21 @@ class EngineEffAccounting:
                  process_start_unix: Optional[Tuple[float, str]] = None):
         self.weight_bytes = int(weight_bytes)
         self.kv_position_bytes = int(kv_position_bytes)
+        # a looped model (``loop``, kept as ``looped``: passes, weight_layers, pool_layers,
+        # looped_weight_bytes, head_bytes; None for every other): a
+        # decode step reads the layers' weights once a PASS, so a
+        # step's weight bytes are the whole set and (passes - 1) times
+        # the looped part more; kv_position_bytes is of every pool
+        # layer already. passes_run / row_steps / exit_mass: what the
+        # decode windows' steps counted on the device (note_window)
+        self.looped = dict(loop) if loop else None
+        self.step_weight_bytes = self.weight_bytes + (
+            (self.looped["passes"] - 1)
+            * self.looped["looped_weight_bytes"] if self.looped else 0)
+        self.loop_passes_run = 0
+        self.loop_row_steps = 0
+        self.loop_exit_mass = [0.0] * (self.looped["passes"]
+                                       if self.looped else 0)
         # None = no known peak for this device: MBU is not reported
         self.hbm_peak_bytes_per_s = hbm_peak_bytes_per_s
         self.compile_hist = compile_hist
@@ -678,7 +694,7 @@ class EngineEffAccounting:
                     live_rows: int, kv_len: int, real: int, pad: int,
                     dead: int, window_s: float, host_s: float = 0.0,
                     sync_s: float = 0.0, experts_read: int = 0,
-                    experts_resident: int = 0) -> None:
+                    experts_resident: int = 0, loop=None) -> None:
         """One fused decode window: ``batch * steps * positions``
         token-step computations, of which ``real`` emitted tokens the
         client keeps, ``pad`` ran on parked rows, and ``dead`` ran on
@@ -691,10 +707,12 @@ class EngineEffAccounting:
         layers ``experts_read`` experts' weights were fetched, of the
         ``experts_resident`` (steps x layers x experts) that steps
         reading every expert fetch (ops/moe.py, the list path); the
-        bytes of the others are not in the window's."""
+        bytes of the others are not in the window's. A looped model:
+        ``loop`` = (passes run, row-steps, exit mass a pass) as the
+        window's steps summed them on the device."""
         total = batch * steps * positions
         useful = real / total if total else 0.0
-        win_bytes = (steps * (self.weight_bytes
+        win_bytes = (steps * (self.step_weight_bytes
                               + batch * self.kv_position_bytes * kv_len)
                      - (experts_resident - experts_read)
                      * self.expert_bytes)
@@ -727,6 +745,11 @@ class EngineEffAccounting:
             self.bytes_effective += eff_bytes
             self.experts_read += experts_read
             self.experts_resident += experts_resident
+            if loop is not None:
+                self.loop_passes_run += int(loop[0])
+                self.loop_row_steps += int(loop[1])
+                self.loop_exit_mass = [a + float(b) for a, b in zip(
+                    self.loop_exit_mass, loop[2])]
             self._windows.append(entry)
 
     def note_prefill(self, *, bucket: int, batch: int,
@@ -1099,6 +1122,42 @@ class EngineEffAccounting:
 
     # -- reads (off the hot path) ----------------------------------------
 
+    def loop_report(self) -> Optional[Dict[str, object]]:
+        """The ``loop`` block of GET /debug/perf ``device`` (a looped
+        model's; None for every other): ``passes`` the layer stack runs
+        a token, over ``weight_layers`` layers and ``pool_layers`` pool
+        layers; ``passes_run`` and ``row_steps`` as the decode windows'
+        steps counted them on the device (their ratio is ``passes``
+        while every row runs every pass); ``exit_mass``: the mean share
+        of a row-step's exit distribution a pass took."""
+        if not self.looped:
+            return None
+        with self._lock:
+            return self._loop_counts()
+
+    def _loop_counts(self) -> Dict[str, object]:
+        """loop_report's block (``totals.looped`` too, which the
+        scrape-time sync reads); the caller holds the lock."""
+        rows = self.loop_row_steps
+        return {"passes": self.looped["passes"],
+                "weight_layers": self.looped["weight_layers"],
+                "pool_layers": self.looped["pool_layers"],
+                "passes_run": self.loop_passes_run,
+                "row_steps": rows,
+                "exit_mass": [round(m / rows, 6) if rows else None
+                              for m in self.loop_exit_mass]}
+
+    def step_bytes_report(self) -> Dict[str, int]:
+        """``totals.step_bytes`` (a looped model's): the byte model's
+        decode step by its parts: ``weights`` every weight but the head once, the looped
+        part once a pass; ``head``; ``kv_per_position`` K and V of one
+        cached position in every pool layer, which a step reads for
+        every row and position of its kv bucket."""
+        head = self.looped["head_bytes"]
+        return {"weights": self.step_weight_bytes - head, "head": head,
+                "kv_per_position": self.kv_position_bytes,
+                "passes": self.looped["passes"]}
+
     def report(self) -> Dict[str, object]:
         """Cumulative totals (the scrape-time delta-sync source, and
         ``totals`` of GET /debug/perf). ``step`` is the step timeline
@@ -1179,6 +1238,9 @@ class EngineEffAccounting:
                 "builds": self._builds_report(),
                 "weight_bytes": self.weight_bytes,
                 "kv_position_bytes": self.kv_position_bytes,
+                **({"step_bytes": self.step_bytes_report(),
+                    "looped": self._loop_counts()}
+                   if self.looped else {}),
                 "hbm_peak_bytes_per_s": self.hbm_peak_bytes_per_s,
                 "step": {
                     "steps": self.steps,

@@ -73,9 +73,12 @@ class _Window:
     kv_len: int
     batch: int
     host_s: float           # decode_host + decode_dispatch spent on it
-    # MoE models: the experts whose weights each step read, summed over
-    # the layers [steps]; None on a dense model
-    experts_read: object = None
+    # what each step counted (models/llama.Work of [steps] leaves, as
+    # ModelRunner.decode hands it), by named member: ``experts_read``,
+    # the experts whose weights it read summed over the layers (a MoE
+    # model), and ``loop``, its passes (a looped one); a member is None
+    # where the model has no such part
+    work: object = None
 
 
 @dataclass
@@ -266,7 +269,8 @@ class LLMEngine:
             pool_layers=(0 if self.runner.cache.k is None
                          else self.runner.cache.k.shape[0]),
             reader_layers=(self.model_cfg.reader_layers
-                           if self.runner.cache.k is not None else 0))
+                           if self.runner.cache.k is not None else 0),
+            weight_layers=self.model_cfg.num_layers)
         # a slot's row: its blocks and, where the model keeps state a
         # sequence, its state page as one more column (models/kv.
         # split_tables); an empty row names trash block and trash page
@@ -292,22 +296,22 @@ class LLMEngine:
         mc = self.model_cfg
         kv_itemsize = {"bfloat16": 2, "float32": 4,
                        "int8": 1}[engine_cfg.kv_dtype]
-        kv_pos_bytes = (2 * mc.attn_layers * mc.num_kv_heads
+        # (every POOL layer: a looped model's passes keep K and V of
+        # their own, ModelConfig.pool_layers)
+        kv_pos_bytes = (2 * mc.pool_layers * mc.num_kv_heads
                         * mc.head_dim_ * kv_itemsize)
         if engine_cfg.kv_dtype == "int8":
             # per-(token, head) f32 scales stream alongside the blocks
-            kv_pos_bytes += 2 * mc.attn_layers * mc.num_kv_heads * 4
-        from jax import tree_util as _tree_util
-        weight_bytes = sum(
-            x.size * x.dtype.itemsize
-            for x in _tree_util.tree_leaves(self.runner.params))
+            kv_pos_bytes += 2 * mc.pool_layers * mc.num_kv_heads * 4
+        def tree_bytes(tree) -> int:
+            return sum(x.size * x.dtype.itemsize
+                       for x in jax.tree_util.tree_leaves(tree))
+        weight_bytes = tree_bytes(self.runner.params)
         # one expert's gate, up and down (and scales) in one layer: what
         # a decode step leaves unread of an expert off its list
-        expert_bytes = sum(
-            x.size * x.dtype.itemsize
-            for x in _tree_util.tree_leaves(self.runner.expert_stacks())
-        ) // (self._resident_layers() * mc.num_experts
-              ) if mc.num_experts else 0
+        expert_bytes = tree_bytes(self.runner.expert_stacks()) // (
+            self._resident_layers() * mc.num_experts
+        ) if mc.num_experts else 0
         self.eff = EngineEffAccounting(
             weight_bytes=weight_bytes,
             kv_position_bytes=kv_pos_bytes,
@@ -318,6 +322,13 @@ class LLMEngine:
             ring_entries=engine_cfg.perf_ring_entries,
             compile_hist=self.metrics.compile_hist,
             expert_bytes=expert_bytes,
+            # a looped model: its layers' weights are read once a pass
+            loop=None if mc.loop_steps == 1 else {
+                "passes": mc.loop_steps, "weight_layers": mc.num_layers,
+                "pool_layers": mc.pool_layers,
+                "looped_weight_bytes": tree_bytes(
+                    self.runner.params["layers"]),
+                "head_bytes": tree_bytes(self.runner.params["lm_head"])},
             annotate=jax.profiler.TraceAnnotation,
             queue_depth=self._device_queue_depth)
         # the step timeline (efficiency.STEP_PHASES): every phase of
@@ -1709,14 +1720,14 @@ class LLMEngine:
                 self.model_cfg.index_topk, self.runner.selects(kv_len))
         with self._phase("decode_dispatch", dispatches=True) as call:
             (ids_dev, lps_dev, counts_dev, tops_dev,
-             experts_read_dev) = self.runner.decode(
+             work_dev) = self.runner.decode(
                 self._dev_sampling, steps=W, kv_len=kv_len, greedy=greedy,
                 seeded=seeded, guide_table=gtable, guide_ids=gids,
                 spec=spec, spec_ok=spec_ok, plain=plain,
                 penalized=penalized, topk=topk)
         win = _Window(ids_dev, lps_dev, counts_dev, tops_dev, W,
                       list(decode_seqs), call.t1, spec_ok, kv_len, batch,
-                      host_s=call.self_s, experts_read=experts_read_dev)
+                      host_s=call.self_s, work=work_dev)
         self._inflight.append(win)
         self._queue_tail = ids_dev
         return win
@@ -1760,11 +1771,16 @@ class LLMEngine:
         with self._phase(kind + "_process") as walk:
             walked, counted = self._process_window(win, window_s)
         outputs.extend(walked)
-        if win.experts_read is not None:
+        work = win.work
+        if work.experts_read is not None:
             counted.update(
-                experts_read=int(win.experts_read.sum()),
+                experts_read=int(work.experts_read.sum()),
                 experts_resident=win.steps * self._resident_layers()
                 * self.model_cfg.num_experts)
+        if work.loop is not None:
+            counted.update(loop=(work.loop.passes_run.sum(),
+                                 work.loop.row_steps.sum(),
+                                 work.loop.exit_mass.sum(axis=0)))
         self.eff.note_window(**counted, window_s=window_s,
                              host_s=win.host_s + walk.self_s,
                              sync_s=sync.self_s)
@@ -1792,9 +1808,9 @@ class LLMEngine:
             win.counts = np.asarray(win.counts)
         if win.tops is not None:
             win.tops = (np.asarray(win.tops[0]), np.asarray(win.tops[1]))
-        if win.experts_read is not None:
-            # one int32 a step, in the fetch of the window's token ids
-            win.experts_read = np.asarray(win.experts_read)
+        # a few numbers a step, in the fetch of the window's token ids
+        # (None members stay None)
+        win.work = jax.tree.map(np.asarray, win.work)
         return win
 
     def _process_window(self, win: _Window, dt: float):
@@ -2333,6 +2349,10 @@ class LLMEngine:
             # executable of a model whose layers keep state pages
             # (empty on every other)
             "mixer_paths": dict(self.runner.mixer_paths),
+            # a looped model's passes, layers and counters
+            # (efficiency.loop_report); absent on every other model
+            **({"loop": self.eff.loop_report()} if self.eff.looped
+               else {}),
         }
 
     def load_report(self) -> Dict[str, object]:

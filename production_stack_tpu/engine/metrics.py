@@ -199,6 +199,15 @@ class EngineMetrics:
             "tails, discarded rows, rejected draft positions, prefill "
             "bucket padding)",
             list(labels) + ["kind", "phase"], registry=self.registry)
+        # a looped model alone (efficiency.loop_report): what its decode
+        # steps counted on the device
+        self._loop_counts = Counter(
+            "tpu:engine_loop",
+            "A looped model's decode steps as counted on the device: "
+            "kind passes (layer-stack passes its live rows ran) / "
+            "row_steps (those rows' steps); their ratio is the passes a "
+            "row-step runs",
+            list(labels) + ["kind"], registry=self.registry)
         self.effective_bytes_per_s = gauge(
             "tpu:engine_effective_bytes_per_s",
             "Modeled useful HBM traffic per wall-clock second over the "
@@ -398,6 +407,13 @@ class EngineMetrics:
                                       kv_bucket=kv, batch=batch,
                                       **self._labels),
                 self._eff_last, f"compile:{key}", entry["count"])
+        looped = report.get("looped") or {}
+        for kind, key in (("passes", "passes_run"),
+                          ("row_steps", "row_steps")):
+            if key in looped:
+                self._delta_inc(
+                    self._loop_counts.labels(kind=kind, **self._labels),
+                    self._eff_last, f"loop:{kind}", looped[key])
         self.compile_in_flight.set(report.get("compile_in_flight", 0))
         self.effective_bytes_per_s.set(
             rates.get("effective_bytes_per_s", 0.0))

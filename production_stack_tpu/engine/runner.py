@@ -75,6 +75,17 @@ def _named(name: str, fn, **static):
     return bound
 
 
+def _step_counts(work):
+    """What a decode step hands the host beside its tokens, of what the
+    forward counted (models/llama.Work, read by name): (the experts
+    whose weights it read, a looped model's passes), each None where
+    the model has no such part. Two results of the window's executable
+    and not one container: the lowered text names a result by its place
+    in the output, and a MoE model's window is pinned with the experts'
+    count a bare result (tests/test_chip_compile.py)."""
+    return (None, None) if work is None else (work.experts_read, work.loop)
+
+
 def _carry_edit_impl(tokens, positions, gstate, slots, new_tokens,
                      new_positions):
     """The decode carry with rows ``slots`` [R] set to ``new_tokens`` /
@@ -163,6 +174,8 @@ class ModelRunner:
                 f"one query position a row and for prefill chunks")
         if model_cfg.state_layers:
             self._refuse_with_state_pages(lora_stacked)
+        if model_cfg.loop_steps > 1:
+            self._refuse_looped(lora_stacked)
         # K and V per kv head, or the latent pool, with the index pool
         # beside it where the model selects what it attends, or K and V
         # of the attention layers with a state page a slot (and the
@@ -382,6 +395,32 @@ class ModelRunner:
                     f"{name}: {what} is not supported on a model with "
                     f"state pages (KV pool layout {layout!r}): {why}")
 
+    def _refuse_looped(self, lora_stacked) -> None:
+        """What a looped model (cfg.loop_steps > 1: the layer stack run
+        several times over a pool layer a layer and pass) cannot run
+        with yet, each refused by name at start with its reason. Prefix
+        caching, the KV connector, n-gram speculation and an int8 pool
+        work over its pool layers as over any (tests/test_ouro.py)."""
+        cfg, ecfg, mesh = self.model_cfg, self.engine_cfg, self.mesh
+        refused = [
+            (mesh is not None and any(
+                size > 1 for size in mesh.shape.values()),
+             f"a mesh ({dict(mesh.shape) if mesh is not None else {}}: "
+             f"tp, ep, dp must be 1)", "the exit gate's leaves have no "
+             "partition rule and the pass loop has not been compiled "
+             "for several chips"),
+            (lora_stacked is not None or bool(ecfg.lora_adapters),
+             "LoRA adapters", "an adapter's update would join every "
+             "pass of the shared layers, which no adapter of such a "
+             "model has been compared with"),
+        ]
+        for on, what, why in refused:
+            if on:
+                raise ValueError(
+                    f"{cfg.name}: {what} is not supported on a looped "
+                    f"model ({cfg.loop_steps} passes over "
+                    f"{cfg.pool_layers} pool layers): {why}")
+
     def set_lora(self, lora_stacked, lora_scaling: float = None) -> None:
         """Swap the stacked adapter pytree in place (runtime adapter
         load, engine.load_adapter). Same layer_slice + replicate-under-
@@ -481,7 +520,8 @@ class ModelRunner:
         """tokens/positions [B] -> (ids [B, steps], logprobs [B, steps],
         tokens', positions', cache', experts read [steps]: the experts
         whose weights each step read, summed over the layers; None on a
-        dense model).
+        dense model, a looped model's passes as each step counted them,
+        llama.LoopWork of [steps] leaves; None on every other).
 
         `steps` forwards are fused via lax.scan; each step feeds its
         sampled ids back as the next step's tokens, and the final
@@ -529,18 +569,17 @@ class ModelRunner:
                 seeded=seeded, plain=plain, guided=guided,
                 penalized=penalized, eos_id=eos_id, topk=topk)
             return ((cache, ids, pos + 1, gstate, counts, window),
-                    (ids, lp, ti, tl,
-                     None if work is None else work.experts_read))
+                    (ids, lp, ti, tl, *_step_counts(work)))
 
         ((cache, toks, pos, gstate, counts, window),
-         (ids, lps, tis, tls, read)) = jax.lax.scan(
+         (ids, lps, tis, tls, read, loop)) = jax.lax.scan(
             body, (cache, tokens, positions, guide_state, out_counts,
                    window), jnp.arange(steps))
         cache = llama.close_window(cache, window)
         # ids/lps [B, steps]; tis/tls [B, steps, K]
         return (ids.T, lps.T, tis.transpose(1, 0, 2),
                 tls.transpose(1, 0, 2), toks, pos, gstate, counts,
-                cache, read)
+                cache, read, loop)
 
     def _decode_spec_impl(self, params, cache: KVCache,
                           tables: jnp.ndarray,
@@ -581,7 +620,7 @@ class ModelRunner:
         Returns (ids [B, steps, spec+1], logprobs same, top-K ids/lps
         [B, steps, K], counts [B, steps] valid-token counts, tokens',
         positions', history', gstate', out_counts', cache', experts
-        read [steps] as _decode_impl). Rejected
+        read [steps] and a looped model's passes as _decode_impl). Rejected
         draft positions hold garbage K/V past the live length; the
         write-then-attend invariant (models/kv.py) makes them
         unobservable, exactly like window tail waste.
@@ -646,18 +685,17 @@ class ModelRunner:
                                                     (p + 1,))
             hist = jax.vmap(write_row)(hist, pos, expected)
             return ((cache, new_toks, new_pos, hist, gstate, counts),
-                    (expected, lp, ti, tl, count,
-                     None if work is None else work.experts_read))
+                    (expected, lp, ti, tl, count, *_step_counts(work)))
 
         ((cache, toks, pos, hist, gstate, counts),
-         (ids, lps, tis, tls, cnt, read)) = jax.lax.scan(
+         (ids, lps, tis, tls, cnt, read, loop)) = jax.lax.scan(
             body, (cache, tokens, positions, history, guide_state,
                    out_counts),
             jnp.arange(steps))
         # scan stacks on axis 0: -> [B, steps, K+1] / [B, steps]
         return (ids.transpose(1, 0, 2), lps.transpose(1, 0, 2),
                 tis.transpose(1, 0, 2), tls.transpose(1, 0, 2),
-                cnt.T, toks, pos, hist, gstate, counts, cache, read)
+                cnt.T, toks, pos, hist, gstate, counts, cache, read, loop)
 
     def _prefill_impl(self, params, cache: KVCache, tables: jnp.ndarray,
                       slots: jnp.ndarray, tokens: jnp.ndarray,
@@ -723,8 +761,9 @@ class ModelRunner:
             moe_capacity_tokens=self.engine_cfg.max_num_seqs * Tb,
             **(dict(last=at_last, finishing=finishing) if two_depths
                else {}))
-        expert_rows = None if work is None else jnp.stack(
-            [work.expert_rows, work.held_rows, work.rounds])
+        expert_rows = (
+            None if work is None or work.expert_rows is None
+            else jnp.stack([work.expert_rows, work.held_rows, work.rounds]))
         with jax.named_scope("sample"):
             last = (logits if two_depths else jnp.take_along_axis(
                 logits, at_last[:, None, None], axis=1))[:, 0, :]
@@ -874,17 +913,19 @@ class ModelRunner:
         guided ids, penalty carry) is sliced to that bucket, so parked
         rows beyond it are simply not computed. Executables are cached
         per (batch, steps, kv bucket, variant). Returns
-        (ids, logprobs, counts, tops, experts_read): without speculation ids/logprobs
+        (ids, logprobs, counts, tops, work): without speculation ids/logprobs
         are [B, steps] and counts is None; with spec > 0 they are
         [B, steps, spec+1] plus counts [B, steps] of valid tokens per
         macro-step (_decode_spec_impl) — speculation is PER-ROW via
         spec_ok [B] bool (rows with False single-step with the full
         shaping/guided/sampling treatment). tops is None unless
         topk > 0: then (ids [B, steps, K], logprobs [B, steps, K])
-        top-K alternatives per step. experts_read [steps] int32 (MoE
-        models; None on a dense one): the experts whose weights each
-        step read, summed over the layers. The first np.asarray() is
-        the window's single sync.
+        top-K alternatives per step. work (llama.Work of [steps]
+        leaves, by name, each None where the model has no such part):
+        ``experts_read`` int32, the experts whose weights each step
+        read, summed over the layers (a MoE model), and ``loop``, what
+        each step's passes counted (a looped one). The first
+        np.asarray() is the window's single sync.
 
         guide_table [G, S, V] device int32 + guide_ids [B] activate
         constrained sampling (engine/guided.py); the per-row DFA state
@@ -955,10 +996,11 @@ class ModelRunner:
                                positions=spec + 1)
             (ids, lps, tis, tls, cnt, self._dec_tokens, self._dec_pos,
              self._dec_hist, self._dec_gstate, counts_out,
-             self.cache, read) = fn(*args)
+             self.cache, read, loop) = fn(*args)
             if penalized:
                 self._dec_counts = counts_out
-            return ids, lps, cnt, (tis, tls) if topk else None, read
+            return (ids, lps, cnt, (tis, tls) if topk else None,
+                    llama.Work(experts_read=read, loop=loop))
         cache_key = (B, steps, kv_len, greedy, seeded, guided, gshape,
                      plain, penalized, topk)
         args = (self.params, self.cache, tables,
@@ -985,10 +1027,11 @@ class ModelRunner:
                            args, kind="decode", window=steps,
                            kv_len=kv_len, batch=B, positions=1)
         (ids, lps, tis, tls, self._dec_tokens, self._dec_pos,
-         self._dec_gstate, counts_out, self.cache, read) = fn(*args)
+         self._dec_gstate, counts_out, self.cache, read, loop) = fn(*args)
         if penalized:
             self._dec_counts = counts_out
-        return ids, lps, None, (tis, tls) if topk else None, read
+        return (ids, lps, None, (tis, tls) if topk else None,
+                llama.Work(experts_read=read, loop=loop))
 
     def selects(self, kv_len: Optional[int]) -> bool:
         """Does an executable of this kv bucket select what it attends

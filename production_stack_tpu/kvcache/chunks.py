@@ -51,7 +51,10 @@ def chain_digest_bytes(data: bytes, chunk_bytes: int,
 def model_fingerprint(cfg: "ModelConfig",
                       kv_dtype: str = "bfloat16") -> str:
     """Cache-key namespace: everything the KV layout/values depend on."""
-    raw = (f"{cfg.name}|L{cfg.num_layers}|H{cfg.num_kv_heads}"
+    # (a looped model's passes keep K and V of their own: their count
+    # is part of the layout; every other model's string is unchanged)
+    loops = f"x{cfg.loop_steps}" if cfg.loop_steps > 1 else ""
+    raw = (f"{cfg.name}|L{cfg.num_layers}{loops}|H{cfg.num_kv_heads}"
            f"|D{cfg.head_dim_}|rope{cfg.rope_theta}|{kv_dtype}")
     return hashlib.blake2b(raw.encode(), digest_size=8).hexdigest()
 

@@ -144,7 +144,9 @@ class KVConnector:
             remote_breaker_cooldown_s=cfg.remote_breaker_cooldown_s)
         if self.store is None:
             raise ValueError("KV transfer enabled but no tier configured")
-        shape = (model_cfg.num_layers, cfg.chunk_size,
+        # (a chunk holds every POOL layer: a layer and pass of a looped
+        # model, ModelConfig.pool_layers)
+        shape = (model_cfg.pool_layers, cfg.chunk_size,
                  model_cfg.num_kv_heads, model_cfg.head_dim_)
         self._chunk_shape = shape
         # bf16 numpy dtype comes from ml_dtypes (jax dependency)

@@ -72,8 +72,9 @@ class ModelConfig:
     # Gemma-2 attention scale: 1/sqrt(query_pre_attn_scalar) instead
     # of 1/sqrt(head_dim) (None = head_dim)
     query_pre_attn_scalar: Optional[float] = None
-    # Gemma-2 sandwich norms: post-attention and post-feedforward
-    # RMSNorms in ADDITION to the usual pre-norms
+    # sandwich norms (Gemma-2, Ouro): post-attention and
+    # post-feedforward RMSNorms in ADDITION to the usual pre-norms, on
+    # each sublayer's OUTPUT before it joins the residual stream
     sandwich_norms: bool = False
     # RoPE frequency scaling as a hashable spec (ops/rope.py):
     # ("linear", factor) or ("llama3", factor, low_freq_factor,
@@ -213,9 +214,33 @@ class ModelConfig:
     # experts WITHOUT a gate matrix: down(act(up(x))) (ops/moe.py
     # ``gate`` None), with activation "relu2" Nemotron-H's
     expert_gate: bool = True
+    # a looped model (Ouro, ``ouro``; LoopLM): the WHOLE layer stack runs
+    # loop_steps times on every token with the same weights, the final
+    # norm closing each pass, and pass t appends to and attends over
+    # K and V of its OWN: pool layer t * num_layers + l
+    # (models/llama.forward_in_window; ``pool_layers``). 1: every other
+    # model. exit_gate: a Linear(hidden, 1) on each pass's normed
+    # stream, lambda_t = sigmoid(x . g + b), from which the share of a
+    # token's exit mass a pass takes is made; every token runs every
+    # pass (a threshold below 1 is refused by name: _ouro)
+    loop_steps: int = 1
+    exit_gate: bool = False
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps} < 1")
+        if self.loop_steps > 1 and (
+                self.layer_pattern or self.layer_plan or self.mla
+                or self.num_experts or self.sliding_window
+                or self.rms_norm_offset or self.tie_word_embeddings):
+            raise ValueError(
+                f"{self.name}: a looped model (loop_steps "
+                f"{self.loop_steps}) is built for dense full-attention "
+                f"layers alone: no layer pattern or plan, no latent "
+                f"attention, no experts, no sliding window, and with "
+                f"plain norm weights (no rms_norm_offset) and a head of "
+                f"its own (no tie_word_embeddings)")
         kinds = {k for period, _ in self.layer_plan for k in period}
         if kinds & set(SUBLAYER_KINDS) and kinds - set(SUBLAYER_KINDS):
             raise ValueError(
@@ -268,10 +293,18 @@ class ModelConfig:
         return self.kind_layers("attn", "swa", "full")
 
     @property
+    def pool_layers(self) -> int:
+        """Layers of the K/V pool: one a layer that keeps K and V, and
+        of a looped model one a layer and PASS (every pass keeps K and
+        V of its own)."""
+        return self.attn_layers * self.loop_steps
+
+    @property
     def reader_layers(self) -> int:
-        """Layers that READ a pool layer: those that keep K and V and
-        the cross layers, which read another layer's."""
-        return self.attn_layers + self.kind_layers("cross")
+        """Layers that READ a pool layer: those that keep K and V (a
+        pass each, in a looped model) and the cross layers, which read
+        another layer's."""
+        return self.pool_layers + self.kind_layers("cross")
 
     @property
     def mamba_layers(self) -> int:
@@ -498,7 +531,12 @@ class ModelConfig:
         if self.ret_layers:      # the gate a key-value head, its bias
             attn += h * self.num_kv_heads + self.num_kv_heads
         per_layer = attn + 2 * h                         # + norms
-        return self.num_layers * per_layer + rest
+        if self.sandwich_norms:
+            per_layer += 2 * h
+        # a looped model's weights count ONCE, whatever its passes; the
+        # exit gate is a Linear(hidden, 1) with its bias
+        return (self.num_layers * per_layer + rest
+                + (h + 1 if self.exit_gate else 0))
 
     @staticmethod
     def from_hf_config(cfg: Dict[str, Any], name: str = "",
@@ -511,8 +549,8 @@ class ModelConfig:
         Qwen2-MoE, GLM-4.7-Flash (``glm4_moe_lite``) and GLM-5
         (``glm_moe_dsa``), both through _glm4_moe_lite, Qwen3-Next
         (``qwen3_next``, _qwen3_next), Brumby (``brumby``, _brumby),
-        Phi-4-mini-flash (``phi4flash``, _phi4flash) and Nemotron-H
-        (``nemotron_h``, _nemotron_h).
+        Phi-4-mini-flash (``phi4flash``, _phi4flash), Nemotron-H
+        (``nemotron_h``, _nemotron_h) and Ouro (``ouro``, _ouro).
         Keys the mapping does not know are ignored.
         """
         archs = cfg.get("architectures") or []
@@ -542,6 +580,8 @@ class ModelConfig:
             return _phi4flash(cfg, name, dtype)
         if model_type == "nemotron_h" or arch == "NemotronHForCausalLM":
             return _nemotron_h(cfg, name, dtype)
+        if model_type == "ouro" or arch == "OuroForCausalLM":
+            return _ouro(cfg, name, dtype)
         if not (is_qwen2 or is_gemma or is_gemma2 or is_mixtral
                 or is_qwen2_moe or is_glm_lite
                 or is_llama_like) and (model_type or arch):
@@ -550,7 +590,7 @@ class ModelConfig:
                 f"architecture={arch!r}); supported: llama, mistral, "
                 f"qwen2, gemma, gemma2, mixtral, qwen2_moe, "
                 f"glm4_moe_lite, glm_moe_dsa, qwen3_next, brumby, "
-                f"phi4flash, nemotron_h")
+                f"phi4flash, nemotron_h, ouro")
         if is_glm_lite:
             return _glm4_moe_lite(cfg, name, dtype)
         if is_qwen2_moe:
@@ -1029,6 +1069,67 @@ def _nemotron_h(cfg: Dict[str, Any], name: str, dtype: Any) -> ModelConfig:
     )
 
 
+def _ouro(cfg: Dict[str, Any], name: str, dtype: Any) -> ModelConfig:
+    """Ouro (``ouro``; ByteDance Ouro-1.4B / 2.6B, "Scaling Latent
+    Reasoning via Looped Language Models", arXiv 2510.25741): a decoder
+    of Llama-like blocks with sandwich norms (plain RMSNorm weights,
+    ``y = w * x / rms``) whose WHOLE stack runs ``total_ut_steps`` times
+    on every token with the same weights; the final norm closes every
+    pass and its output is the next pass's input; each pass keeps K and
+    V of its own; an exit gate (Linear(hidden, 1)) reads each pass's
+    normed stream. The published ``early_exit_threshold`` is 1: a
+    sigmoid is below 1, every token runs every pass and the last pass's
+    logits are served. What the tree does not build is refused by
+    name."""
+    family = "ouro"
+    threshold = cfg.get("early_exit_threshold", 1.0)
+    if threshold < 1.0:
+        raise ValueError(
+            f"{family}: early_exit_threshold {threshold} below 1 is not "
+            f"supported: rows of one batch would leave the layer loop at "
+            f"different passes, which the scheduler and the step "
+            f"programs do not build (every token runs all "
+            f"total_ut_steps passes)")
+    if cfg.get("sliding_window") and cfg.get("use_sliding_window", False):
+        raise ValueError(f"{family} with a sliding window "
+                         f"(use_sliding_window) is not supported")
+    if set(cfg.get("layer_types") or ()) - {"full_attention"}:
+        raise ValueError(f"{family}: layer_types other than "
+                         f"full_attention are not supported")
+    if cfg.get("tie_word_embeddings", False):
+        raise ValueError(f"{family} with tie_word_embeddings is not "
+                         f"supported")
+    if cfg.get("rope_scaling"):
+        raise ValueError(f"{family} with rope_scaling "
+                         f"{cfg['rope_scaling']!r} is not supported")
+    for key in ("attention_bias", "mlp_bias"):
+        if cfg.get(key, False):
+            raise ValueError(f"{family} with {key} is not supported")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"{family} hidden_act {cfg['hidden_act']!r} is "
+                         f"not supported")
+    heads = cfg["num_attention_heads"]
+    kv_heads = cfg.get("num_key_value_heads", heads)
+    if heads % kv_heads:
+        raise ValueError(f"{family}: num_attention_heads {heads} is not "
+                         f"a multiple of num_key_value_heads {kv_heads}")
+    steps = int(cfg.get("total_ut_steps", 1))
+    if steps < 1:
+        raise ValueError(f"{family}: total_ut_steps {steps} < 1")
+    return ModelConfig(
+        name=name or cfg.get("_name_or_path", "hf-model"),
+        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg["intermediate_size"],
+        num_layers=cfg["num_hidden_layers"], num_heads=heads,
+        num_kv_heads=kv_heads, head_dim=cfg.get("head_dim"),
+        rope_theta=cfg.get("rope_theta", 10000.0),
+        rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        sandwich_norms=True, loop_steps=steps, exit_gate=True,
+        dtype=dtype,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Presets. Dimensions are the publicly documented architecture shapes.
 # ---------------------------------------------------------------------------
@@ -1196,6 +1297,15 @@ PRESETS: Dict[str, ModelConfig] = {
                     (("gmu", "cross"), 1)),
         mamba_d_inner=128, mamba_d_state=4, mamba_d_conv=4,
         mamba_dt_rank=4, exact_dequant_scale=True,
+    ),
+    # Tiny Ouro-style looped model for CPU tests (``ouro``): 3 layers
+    # run 4 times over 12 pool layers, sandwich norms, an exit gate
+    "debug-ouro": ModelConfig(
+        name="debug-ouro", vocab_size=512, hidden_size=128,
+        intermediate_size=384, num_layers=3, num_heads=4, num_kv_heads=4,
+        head_dim=32, max_position_embeddings=512, rms_norm_eps=1e-6,
+        rope_theta=1000000.0, sandwich_norms=True, loop_steps=4,
+        exit_gate=True,
     ),
     # Tiny Nemotron-H-style model for CPU tests (``nemotron_h``): 12
     # blocks M E M * E M E M * E M E = (M E M * E) x 2, (M E) x 1, each

@@ -66,7 +66,17 @@ def params_from_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Dict:
         return jnp.asarray(x, dtype=cfg.dtype)
 
     layer_map = dict(_LAYER_MAP)
-    if cfg.sandwich_norms:
+    # which of the two namings of sandwich norms the CHECKPOINT uses is
+    # read off its own tensor names, not off another field of the model
+    if cfg.sandwich_norms and any(
+            f"{prefix}layers.0.input_layernorm_2.weight" in sd
+            for prefix in ("model.", "")):
+        # Ouro's norm naming (modeling_ouro.py): the two pre-norms keep
+        # Llama's names and each sandwich norm is its pre-norm's "_2"
+        layer_map["post_attn_norm"] = ("input_layernorm_2.weight", False)
+        layer_map["post_mlp_norm"] = (
+            "post_attention_layernorm_2.weight", False)
+    elif cfg.sandwich_norms:
         # Gemma-2 norm naming: post_attention_layernorm is the SANDWICH
         # post-attn norm (not the MLP pre-norm as in Llama), the MLP
         # pre-norm is pre_feedforward_layernorm, and there is a
@@ -135,6 +145,12 @@ def params_from_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Dict:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = cast(get("lm_head.weight", bare=True), True)
+    if cfg.exit_gate:
+        # Linear(hidden, 1) and its bias, float32 as init_params makes them
+        params["exit_gate"] = jnp.asarray(
+            get("early_exit_gate.weight").reshape(-1), jnp.float32)
+        params["exit_gate_bias"] = jnp.asarray(
+            get("early_exit_gate.bias").reshape(()), jnp.float32)
     return params
 
 

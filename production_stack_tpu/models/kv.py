@@ -301,7 +301,8 @@ def cache_for(cfg, num_blocks: int, block_size: int,
     """The pool a model's layers append to and attend over
     (cfg: models/config.ModelConfig): the latent pool for latent
     attention (with the index pool beside it where the model selects
-    what it attends), K and V per kv head for everything else: of the
+    what it attends), K and V per kv head for everything else (a pool
+    layer a layer, and of a looped model a layer and pass): of the
     model's ATTENTION layers, with ``state_pages`` state pages (the
     trash page 0 among them) beside them where it has Gated DeltaNet
     layers; state pages ALONE, ``num_blocks`` of them, where every
@@ -356,7 +357,9 @@ def cache_for(cfg, num_blocks: int, block_size: int,
                 (cfg.num_layers, num_blocks, 1, block_size,
                  cfg.index_head_dim), dtype))
         return cache
-    return make_cache(cfg.num_layers, num_blocks, block_size,
+    # (a looped model keeps K and V a layer and PASS: cfg.pool_layers
+    # = num_layers x loop_steps, pass t's from t x num_layers on)
+    return make_cache(cfg.pool_layers, num_blocks, block_size,
                       cfg.num_kv_heads, cfg.head_dim_, dtype)
 
 

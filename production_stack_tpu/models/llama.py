@@ -20,7 +20,7 @@ this module is the TPU-native engine's compute core.
 """
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +35,50 @@ from production_stack_tpu.ops.norms import rms_norm
 from production_stack_tpu.ops.rope import apply_rope, rope_table
 
 Params = Dict[str, Any]
+
+
+class LoopWork(NamedTuple):
+    """What a looped model's passes (cfg.loop_steps > 1) counted:
+    ``passes_run`` int32, the passes its valid tokens ran (every one
+    runs them all: loop_steps a token), ``row_steps`` int32, those
+    tokens, and ``exit_mass`` float32 [loop_steps], their summed share
+    of the exit distribution a pass (p_t = lambda_t prod_{j<t}
+    (1 - lambda_j), the rest on the last)."""
+    passes_run: jnp.ndarray
+    row_steps: jnp.ndarray
+    exit_mass: jnp.ndarray
+
+
+class Work(NamedTuple):
+    """What a forward counted beside its logits, by named member, each
+    None where the model has no such part (and None for the whole where
+    it has none at all): the experts' work summed over the layers
+    (ops/moe.Work's four members under their names) and a looped
+    model's passes."""
+    experts_read: Optional[jnp.ndarray] = None
+    expert_rows: Optional[jnp.ndarray] = None
+    held_rows: Optional[jnp.ndarray] = None
+    rounds: Optional[jnp.ndarray] = None
+    loop: Optional[LoopWork] = None
+
+
+def _counted(experts: Optional[moe.Work],
+             loop: Optional[LoopWork] = None) -> Optional[Work]:
+    """The experts' summed work and a looped model's passes as the one
+    ``Work`` a forward hands back; None where it counted neither."""
+    return (None if experts is None and loop is None
+            else Work(*(experts or ()), loop=loop))
+
+
+# random weights only (init_params): what the two SANDWICH norms of a
+# looped stack start at. A branch whose output is normed joins the
+# stream at the norm's weight whatever its projections' scale, so the
+# depth-scaled initialisation of residual branches (GPT-2's
+# 1/sqrt(2 L) on the output projections: 0.102 at Ouro-2.6B's 48
+# layers) has to stand here to take effect; at one, four passes over
+# the same layers pile up a stream that no precision tells apart
+# (PERF.md section 2, PR 56)
+LOOPED_SANDWICH_NORM_INIT = 0.1
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "quantizer",
@@ -107,9 +151,12 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         "final_norm": norm_init((h,), cfg.dtype),
     }
     if cfg.sandwich_norms:
-        # Gemma-2: post-attention and post-feedforward norms too
-        params["layers"]["post_attn_norm"] = norm_init((L, h), cfg.dtype)
-        params["layers"]["post_mlp_norm"] = norm_init((L, h), cfg.dtype)
+        # post-attention and post-feedforward norms too (Gemma-2's as
+        # every other norm; a looped stack's: LOOPED_SANDWICH_NORM_INIT)
+        post = norm_init((L, h), cfg.dtype) if cfg.loop_steps == 1 \
+            else jnp.full((L, h), LOOPED_SANDWICH_NORM_INIT, cfg.dtype)
+        params["layers"]["post_attn_norm"] = post
+        params["layers"]["post_mlp_norm"] = post
     # key order matters: dense models must draw gate/up/down from the
     # same key positions as before MoE existed (seeded tests pin outputs)
     if E:
@@ -155,6 +202,12 @@ def init_params(cfg: ModelConfig, key: jax.Array,
                                          "ret_gate")
         params["layers"]["ret_gate_bias"] = jax.random.uniform(
             next(keys), (L, nkv), jnp.float32, 2.0, 8.0)
+    if cfg.exit_gate:
+        # a looped model's exit gate, Linear(hidden, 1): float32, never
+        # quantized (drawn last: every other leaf is the plain model's)
+        params["exit_gate"] = 0.02 * jax.random.normal(
+            next(keys), (h,), jnp.float32)
+        params["exit_gate_bias"] = jnp.zeros((), jnp.float32)
     return params
 
 
@@ -1542,7 +1595,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             finishing: Optional[jnp.ndarray] = None,
             ) -> Tuple[jnp.ndarray, KVCache, Optional[jnp.ndarray]]:
     """Incremental forward. tokens/positions [B,T] -> (logits fp32
-    [B,T,V], cache', the experts' work).
+    [B,T,V], cache', what it counted: ``Work``).
 
     cache is the paged block pool (models/kv.py); block_tables [B, MB]
     map each row's virtual positions to pool blocks (None = identity
@@ -1561,8 +1614,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     many tokens instead of B*T — a prefill of fewer rows than the full
     batch passes the full batch's count, so that it never holds less
     per expert (ops/moe.moe_mlp ``capacity_tokens``).
-    the experts' work (ops/moe.Work, summed over the layers; None on
-    a dense model): ``experts_read``, the experts whose weights the
+    the experts' work (``Work``'s first four members, ops/moe.Work
+    summed over the layers; the whole None on a dense model that is not
+    looped): ``experts_read``, the experts whose weights the
     forward read (layers x experts, or the experts its valid rows were
     routed to where the expert matmuls walk that list: ops/moe
     list_path, decode steps, and grouped_path, prefill chunks), and
@@ -1599,7 +1653,7 @@ def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                       finishing: Optional[jnp.ndarray] = None):
     """``forward`` as one step of a decode window: ``window`` is what
     ``open_window`` gave or the step before returned. -> (logits,
-    cache', the experts' work, window'). With a window the power
+    cache', what it counted, window'). With a window the power
     retention layers read their pages and write nothing (the cache's
     pools come back as they went in) and the step's keys join the
     window; with None this IS ``forward``."""
@@ -1646,19 +1700,20 @@ def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             else:
                 x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         with jax.named_scope("lm_head"):
-            return _lm_head(params, cfg, x), cache, work, window
+            return _lm_head(params, cfg, x), cache, _counted(work), window
     # the scan's unit is one PERIOD of the layer pattern (cfg.pattern_):
     # one attention layer for every model but the hybrid, whose period
     # runs its Gated DeltaNet layers and then its attention layer
     pattern = cfg.pattern_
     period = len(pattern)
 
-    def scan_body(carry, xs):
+    def scan_body(carry, xs, kv_base=None):
         # the pools ride in the CARRY, one buffer each from the
         # executable's donated argument to its result: as the scan's
         # xs -> ys the K/V pool was sliced, rewritten and stacked, a
         # layer's pool per layer and the whole pool per step
-        # (models/kv.py); the state pages beside it alike
+        # (models/kv.py); the state pages beside it alike. kv_base (a
+        # looped model's pass): the first pool layer of the pass
         h, pool, spool = carry
         lp, layer, ll, local, taken = xs
         if pattern == ("ret",):
@@ -1674,7 +1729,8 @@ def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 block_tables=block_tables, mesh=mesh,
                 layer_local=local, layer=layer,
                 moe_capacity_tokens=moe_capacity_tokens,
-                expert_stacks=expert_stacks)
+                expert_stacks=expert_stacks,
+                kv_layer=None if kv_base is None else kv_base + layer)
             return (h, pool, spool), (work, None)
         # ``layer`` is the period's index; its sub-layers in a static
         # loop, each reading its own row of the groups' stacks in place
@@ -1741,6 +1797,12 @@ def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
             (x, pool, spool), _ = scan_body(
                 (x, pool, spool), (lp, jnp.int32(i), None, None, None))
+    if cfg.loop_steps > 1:
+        x, pool, loop = _run_passes(params, cfg, scan_body, x, pool, spool,
+                                    xs, token_valid)
+        with jax.named_scope("lm_head"):
+            return (_lm_head(params, cfg, x), cache.carried_back(pool, spool),
+                    _counted(None, loop), window)
     with jax.named_scope("layers"):
         (x, pool, spool), (work, taken) = jax.lax.scan(
             scan_body, (x, pool, spool), xs)
@@ -1752,8 +1814,52 @@ def forward_in_window(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                      offset=1.0 if cfg.rms_norm_offset else 0.0)
     with jax.named_scope("lm_head"):
         logits = _lm_head(params, cfg, x)
-    return (logits, cache.carried_back(pool, spool),
-            None if work is None else moe.Work(*map(jnp.sum, work)), window)
+    return (logits, cache.carried_back(pool, spool), _counted(
+        None if work is None else moe.Work(*map(jnp.sum, work))), window)
+
+
+def _run_passes(params: Params, cfg: ModelConfig, scan_body, x, pool,
+                spool, xs, token_valid):
+    """A looped model's layers (cfg.loop_steps > 1): the layer scan
+    inside a scan over PASSES, the pools in the carry as in one pass.
+    Pass t runs the same layers over the pass before's normed stream,
+    appending to and attending over pool layers t * num_layers + l; the
+    final norm closes every pass and the exit gate reads its output.
+    One traced layer body and one traced pass, whatever the count.
+    -> (the last pass's normed stream, the pool, LoopWork)."""
+    gate_w, gate_b = params["exit_gate"], params["exit_gate_bias"]
+
+    def pass_body(carry, t):
+        h, pool, spool = carry
+        with jax.named_scope("loop_pass"):
+            with jax.named_scope("layers"):
+                (h, pool, spool), _ = jax.lax.scan(
+                    functools.partial(scan_body,
+                                      kv_base=t * cfg.num_layers),
+                    (h, pool, spool), xs)
+            with jax.named_scope("final_norm"):
+                h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+            with jax.named_scope("exit_gate"):
+                lam = jax.nn.sigmoid(
+                    jnp.einsum("bth,h->bt", h.astype(jnp.float32), gate_w)
+                    + gate_b)
+        return (h, pool, spool), lam
+
+    (x, pool, spool), lam = jax.lax.scan(
+        pass_body, (x, pool, spool), jnp.arange(cfg.loop_steps))
+    with jax.named_scope("exit_gate"):
+        # p_t = lambda_t prod_{j<t} (1 - lambda_j); the last pass takes
+        # what is left, so the shares of a token add up to one
+        stay = jnp.cumprod(1.0 - lam, axis=0)                # [P, B, T]
+        before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+        mass = jnp.concatenate([(lam * before)[:-1], before[-1:]])
+        valid = (jnp.ones(lam.shape[1:], bool) if token_valid is None
+                 else token_valid)
+        rows = jnp.sum(valid, dtype=jnp.int32)
+        work = LoopWork(passes_run=cfg.loop_steps * rows, row_steps=rows,
+                        exit_mass=jnp.sum(jnp.where(valid, mass, 0.0),
+                                          axis=(1, 2)))
+    return x, pool, work
 
 
 def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -1792,6 +1898,14 @@ def encode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     local_flags = (jnp.arange(cfg.num_layers) % 2 == 0
                    if cfg.alternating_sliding
                    else jnp.zeros((cfg.num_layers - Ld,), bool))
+    if cfg.loop_steps > 1:
+        # a looped model: the same layers a pass, the final norm after
+        # every pass (forward_in_window's loop without the caches)
+        def pass_body(h, _):
+            h, _ = jax.lax.scan(scan_body, h,
+                                (params["layers"], local_flags))
+            return rms_norm(h, params["final_norm"], cfg.rms_norm_eps), None
+        return jax.lax.scan(pass_body, x, None, length=cfg.loop_steps)[0]
     x, _ = jax.lax.scan(scan_body, x, (params["layers"], local_flags))
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                     offset=1.0 if cfg.rms_norm_offset else 0.0)

@@ -1649,3 +1649,79 @@ def test_ungated_expert_kernels_at_nemotron_widths(
                         else tiles(h, i, *a, **k))
     with pytest.raises(Exception, match="aligned to tiling"):
         lower(1856).compile()
+
+
+# ---------------------------------------------------------------------
+# a looped model (Ouro-2.6B whole: 48 weight layers run four times over
+# 192 pool layers, 16 query heads on 16 pool heads of 128)
+# ---------------------------------------------------------------------
+
+def _ouro_runner(topo, monkeypatch):
+    """The runner skeleton at the benchmark's Ouro-2.6B file, whole (48
+    layers, four passes), 16 slots of 2048 tokens over a pool of 97
+    blocks in 192 pool layers (the cell's geometry)."""
+    import json
+    from chipbench.engine_child import model_config
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.models import config as model_configs
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "ouro-2.6b-int8.json")) as f:
+        conf = json.load(f)
+    monkeypatch.setitem(model_configs.PRESETS, "ouro-whole",
+                        model_config(conf, "ouro-whole"))
+    runner, params, cache, rep = _runner_shapes(
+        topo, 1, kv_blocks=98, model="ouro-whole")
+    runner.engine_cfg = EngineConfig(
+        model="ouro-whole", quantization="int8", max_num_seqs=16,
+        max_model_len=2048, kv_pool_tokens=6208, prefill_chunk=256)
+    return runner, params, cache, rep
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk"])
+def test_looped_step_program_compiles_at_ouro_widths(
+        topo, tpu_branches, monkeypatch, program):
+    """One decode window of 16 rows and one 256-token prefill chunk of
+    16 rows of ALL 48 layers in four passes (one traced layer body, one
+    traced pass), compiled for the described v5e: the attention is the
+    paged kernels' plain K/V case at one query head a pool head of 128
+    with a pool-layer index up to 191; the 192-layer pool is neither
+    copied nor sliced (aliased to the result); the passes and the exit
+    gate carry their scopes; the program fits the chip beside 2.67 GB of
+    weights and 9.9 GB of pool. (The prefill chunk takes 30-50 s: a whole
+    step program of two nested scans through the TPU's compiler, as the
+    other families' whole programs here do.)"""
+    import re
+    N = 98
+    runner, params, cache, rep = _ouro_runner(topo, monkeypatch)
+    assert cache.k.shape == (192, N, 16, BS, 128)
+    if program == "decode_window":
+        compiled = _lower_decode_window(runner, params, cache,
+                                        rep).compile()
+        want, path, positions = "paged_decode_attention", \
+            "pallas_paged_decode", 1
+    else:
+        compiled = _lower_prefill_chunk(runner, params, cache, rep,
+                                        256).compile()
+        want, path, positions = "paged_attention", "pallas_paged", 256
+    hlo = compiled.as_text()
+    calls = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_0-9]+?)[.\-\d]* = [^=]*? custom-call\(", hlo)}
+    assert {c for c in calls if c.startswith(("paged", "moe"))} == {want}
+    assert runner._attention_path(positions, None, 512) == path
+    # (a prefill chunk hands the gate's value to nobody, and the
+    # compiler drops it: the decode windows count the passes)
+    for scope in ("loop_pass", "final_norm", "lm_head") + (
+            ("exit_gate",) if positions == 1 else ()):
+        assert scope in hlo, scope
+    pools = re.compile(
+        r"([\w.\-]+) = \(?\w+\[192,{n},16,{bs},128\]\S* "
+        r"([\w\-]+)\(".format(n=N, bs=BS))
+    moved = [m.group(1) + ": " + m.group(2)
+             for m in map(pools.search, hlo.splitlines()) if m
+             and re.search(r"copy|dynamic.slice|dynamic.update.slice",
+                           m.group(1) + " " + m.group(2))]
+    assert not moved, moved
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= 2 * 192 * N * 16 * BS * 128 * 2)
+    _fits(compiled, f"ouro whole {program}")
