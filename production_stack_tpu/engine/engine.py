@@ -2321,7 +2321,9 @@ class LLMEngine:
         (one process owns the chip) reads the device from here.
         ``attention_paths`` names the attention implementation every
         compiled executable took ("kind|window|kv_bucket|batch", the
-        ``totals.compiles`` key), ``moe_paths`` the strategy its experts
+        ``totals.compiles`` key), ``kv_appends`` how it lands its new
+        K/V in the pool (ops/pallas_paged.kv_append_path), ``moe_paths``
+        the strategy its experts
         take (ops/moe.moe_path; empty on a dense model);
         ``bytes_in_use`` is per device where
         ``memory_stats()`` gives it (None on the CPU)."""
@@ -2344,6 +2346,10 @@ class LLMEngine:
             "engine_devices": devs,
             "pallas_attention": pallas_paged.mode(),
             "attention_paths": dict(self.runner.attention_paths),
+            # how each lands its new K/V in the pool: "rows" / "blocks"
+            # (not a table of kernels an executable must be on: its
+            # name does not end as those do)
+            "kv_appends": dict(self.runner.kv_appends),
             "moe_paths": dict(self.runner.moe_paths),
             # ops/gdn.gdn_path or ops/retention.retention_path of each
             # executable of a model whose layers keep state pages

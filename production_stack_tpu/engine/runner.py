@@ -56,7 +56,8 @@ from production_stack_tpu.ops import moe, retention
 from production_stack_tpu.ops.gdn import gdn_path
 from production_stack_tpu.ops.mamba import mamba_path
 from production_stack_tpu.ops.mamba2 import mamba2_path
-from production_stack_tpu.ops.pallas_paged import JNP_GATHER, attention_path
+from production_stack_tpu.ops.pallas_paged import (
+    JNP_GATHER, attention_path, kv_append_path)
 from production_stack_tpu.ops.rope import rope_table
 from production_stack_tpu.utils import init_logger
 
@@ -315,6 +316,10 @@ class ModelRunner:
         # "kind|window|kv|batch" (the compile observer's key) -> the
         # attention path that executable was compiled on (_compile)
         self.attention_paths: Dict[str, str] = {}
+        # the same key -> how that executable lands its new K/V or
+        # latents in the pool (ops/pallas_paged.kv_append_path): "rows"
+        # a decode window on a kernel's path, "blocks" everything else
+        self.kv_appends: Dict[str, str] = {}
         # the same key -> the strategy its experts take (ops/moe
         # moe_path); empty on a dense model
         self.moe_paths: Dict[str, str] = {}
@@ -1177,6 +1182,11 @@ class ModelRunner:
         name = f"{kind}|{window}|{kv_len}|{batch}"
         if attends:
             self.attention_paths[name] = path
+            # (models/kv.append asks the same of the same four things
+            # as it is traced: the pool's arrays, the forward's rows
+            # and positions, the mesh the layers are handed)
+            self.kv_appends[name] = kv_append_path(
+                self.cache.carried(), batch, positions, self.mesh)
         if self.model_cfg.num_experts:
             self.moe_paths[name] = self._moe_path(batch, positions)
         if self.model_cfg.state_layers:

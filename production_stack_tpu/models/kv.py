@@ -31,15 +31,37 @@ TPU-first invariants:
   them cost a read and a write of a layer's pool per layer and of the
   whole pool per step — half of a decode step on the chip (PERF.md,
   PR 25).
-- **Appends rewrite whole blocks.** A token's K/V is ``Hkv`` rows of
-  ``D``, one in each head's panel of its block. Scattered token by
-  token (``pool.at[layer, blk, :, off].set``), the TPU compiler wants
-  the pool token-major ([.., Bs, Hkv, D]) for the scatter and
-  head-major for the kernel, and copies the whole pool between the two
-  layouts around every layer's write. ``append_chunk`` instead gathers
-  the few blocks a chunk touches, merges the new rows into them and
-  scatters whole ``[Hkv, Bs, D]`` blocks back: every pool-shaped
-  operation keeps the pool's own layout and updates it in place.
+- **Appends are rows or whole blocks, by what the call observes**
+  (``append``; ops/pallas_paged.kv_append_path; GET /debug/perf
+  ``device.kv_appends`` names the outcome per executable). A token's
+  K/V is ``Hkv`` rows of ``D``, one in each head's panel of its block.
+  Scattered token by token (``pool.at[layer, blk, :, off].set``), the
+  TPU compiler wants the pool token-major ([.., Bs, Hkv, D]) for the
+  scatter and head-major for the kernel, and copies the whole pool
+  between the two layouts around every layer's write. So:
+  BLOCKS, ``append_chunk``: gather the few blocks a chunk touches,
+  merge the new rows into them and scatter whole ``[Hkv, Bs, D]``
+  blocks back; every pool-shaped operation keeps the pool's own layout
+  and updates it in place. The grain of a PREFILL chunk, which fills
+  most of the blocks it rewrites; of the int8 pool, whose scale rows
+  ``[.., Bs]`` are no tile a kernel's copy can address; of a mesh,
+  where the pool is sharded and XLA places the rewrite; and of the CPU,
+  where the kernels are off.
+  ROWS, ops/pallas_paged.append_rows: a decode or speculative window
+  (at most ``DECODE_T_MAX`` positions a row) on one device, the
+  kernels on, a pool without scales, no more rows than one call holds
+  slabs in flight (128 at one position, past every cell's 16; beyond
+  it the blocks, which compile at any batch). One kernel call a layer
+  for K and V (or the latents, with the index keys beside them) that
+  holds the pools in place and moves, a row, the one tile of the block
+  its token lands in (8 rows, every kv head). The block rewrite there
+  cost three or four XLA operations a pool and layer, each with
+  its launch, and a whole block in and out for one row: 67 us a layer
+  application at Ouro's 16 rows x 16 heads, 12.8 ms of a 44 ms decode
+  step (PERF.md, PR 58). No cell can say the int8 pool or a mesh would
+  gain, so they keep the blocks.
+  The same bytes either way on every block a table references
+  (``write_chunk`` is the contract, tests/test_pallas_paged.py).
 - **A layer sees two calls.** ``append`` writes its chunk and
   ``attend`` reads it back under the queries; whether the pool is
   quantized, which implementation reads it and that a kernel exists
@@ -68,7 +90,7 @@ TPU-first invariants:
   all — the values are the first ``kv_lora_rank`` columns of the keys'
   own block, which the kernels slice out of the block they already
   hold, so a decode step reads each live token's W values once. The
-  same tables, trash block, carried buffer and whole-block appends; a
+  same tables, trash block, carried buffer and appends; a
   pool of ONE array is the latent pool (``KVCache.layout``). What
   assumes K and V per head (the int8 pool, tp meshes, the KV
   connector's chunks) refuses it by name.
@@ -487,7 +509,7 @@ def append_chunk(pool: jnp.ndarray, new: jnp.ndarray,
     """The serving path's write: new [B,T,Hkv,D] — row b's tokens at
     the contiguous positions starts[b]..starts[b]+T-1 — into layer
     ``layer`` of the whole pool [L,N,Hkv,Bs,D], which comes back
-    updated in place (module text: appends rewrite whole blocks).
+    updated in place (module text: appends by BLOCKS).
     Every block a table references ends up as write_chunk would leave
     it on that layer; invalid, negative and beyond-capacity tokens are
     written nowhere a table points."""
@@ -586,13 +608,29 @@ def gather_view_q(pool: jnp.ndarray, scales: jnp.ndarray,
 
 def append(pool: Pool, k: jnp.ndarray, v: jnp.ndarray,
            tables: jnp.ndarray, starts: jnp.ndarray,
-           valid: Optional[jnp.ndarray], layer) -> Pool:
+           valid: Optional[jnp.ndarray], layer, *, mesh) -> Pool:
     """One layer's write: k, v [B,T,Hkv,D] — row b's tokens at
     positions starts[b]..starts[b]+T-1 — appended to layer ``layer``
     of the whole pool, which comes back as the same tuple it came in
-    as (append_chunk, or append_chunk_q where the pool carries
-    scales). The latent pool (one array) takes k [B,T,1,W] and no
-    v."""
+    as. The latent pool (one array) takes k [B,T,1,W] and no v; with
+    the index pool beside it (two arrays), v [B,T,1,Di] are the index
+    keys.
+
+    By ROWS (pallas_paged.append_rows: one kernel for all the layer's
+    pools, a tile of the block each row lands in) where
+    pallas_paged.kv_append_path says so from the pool, B, T and
+    ``mesh``, the mesh the layer's read is given (None: one device; it
+    has no default, because a caller that forgot it under a mesh would
+    run the single-device kernel over a sharded pool): a decode or
+    speculative window on a kernel's attention path. By BLOCKS
+    (append_chunk, or append_chunk_q where the pool carries scales)
+    everywhere else (module text). The same bytes either way on every
+    block a table references."""
+    path = pallas_paged.kv_append_path(pool, k.shape[0], k.shape[1], mesh)
+    if path == pallas_paged.KV_APPEND_ROWS:
+        return pallas_paged.append_rows(
+            pool, (k,) if len(pool) == 1 else (k, v), tables, starts,
+            valid, layer, interpret=pallas_paged.needs_interpret())
     if len(pool) == 1:
         return (append_chunk(pool[0], k, tables, starts, valid, layer),)
     k_cache, v_cache, *scales = pool

@@ -728,7 +728,7 @@ def _diff_attention(cfg: ModelConfig, hidden, lp: Params, kv, kv_len,
     if appends:
         with jax.named_scope("kv_write"):
             kv = kv_pool.append(kv, k, v, block_tables, starts,
-                                token_valid, kv_layer)
+                                token_valid, kv_layer, mesh=mesh)
     with jax.named_scope("attention"):
         tables, first, at = block_tables, starts, positions
         if window:
@@ -790,7 +790,8 @@ def _gqa_sublayer(cfg: ModelConfig, hidden, lp: Params, pool, spool, at):
         v = mm(hidden, lp["v"]).reshape(B, T, nkv, hd)
     with jax.named_scope("kv_write"):
         pool = kv_pool.append(pool, k, v, at["block_tables"], at["starts"],
-                              at["token_valid"], at["group_layer"])
+                              at["token_valid"], at["group_layer"],
+                              mesh=at["mesh"])
     with jax.named_scope("attention"):
         attn = kv_pool.attend(q, pool, at["block_tables"], at["starts"],
                               at["positions"], at["kv_len"],
@@ -1290,12 +1291,12 @@ def _mla_attention(cfg: ModelConfig, rope, positions, starts, hidden,
                                    parts[0].dtype))
         return jnp.concatenate(parts, axis=-1)
     with jax.named_scope("kv_write"):
-        latents = kv_pool.append(
-            kv[:1], padded([c[:, :, None, :], k_rope]), None,
-            block_tables, starts, token_valid, layer)
-        kv = latents if not indexed else latents + (
-            kv_pool.append_chunk(kv[1], ik, block_tables, starts,
-                                 token_valid, layer),)
+        # (the index pool's keys ride the same call: models/kv.append)
+        kv = kv_pool.append(
+            kv, padded([c[:, :, None, :], k_rope]),
+            ik if indexed else None, block_tables, starts, token_valid,
+            layer, mesh=mesh)
+        latents = kv[:1]
     q = (padded([q_lat, q_rope]) if expand is None
          else jnp.concatenate([q_nope, q_rope], axis=-1))
     if selecting:
@@ -1445,7 +1446,7 @@ def _layer_body(cfg: ModelConfig, rope: Tuple[jnp.ndarray, jnp.ndarray],
     else:
         with jax.named_scope("kv_write"):
             kv = kv_pool.append(kv, k, v, block_tables, starts,
-                                token_valid, kv_layer)
+                                token_valid, kv_layer, mesh=mesh)
         with jax.named_scope("attention"):
             attn = _windowed(lambda w: kv_pool.attend(
                 q, kv, block_tables, starts, positions, kv_len, kv_layer,

@@ -1,6 +1,10 @@
 """Paged flash attention for TPU: block-table-aware online-softmax GQA.
 
-Two kernels, one contract:
+Two kernels that read the pool, one contract (and one that writes it:
+``append_rows``, a decode window's new K/V rows in place, a tile of the
+block a row; prefill chunks, the int8 pool, meshes and the CPU keep
+models/kv.append_chunk's whole-block rewrite: ``kv_append_path``, its
+account further down):
 
 - ``paged_attention``, **prefill** chunks (T up to the chunk bucket) —
   no per-layer gathered K/V copy and no head-major relayout copy of it;
@@ -62,8 +66,9 @@ path, whose collectives XLA inserts.
 
 Which implementation a cached attention takes is decided HERE, by
 ``attention_path``: it owns every fact the decision consults (the
-run-time gate, ``paged_viable``, ``mesh_tp_only``, ``DECODE_T_MAX``).
-models/kv.py asks it at trace time and engine/runner.py when it
+run-time gate, ``paged_viable``, ``mesh_tp_only``, ``DECODE_T_MAX``);
+``kv_append_path`` decides the same way how the new rows are written.
+models/kv.py asks them at trace time and engine/runner.py when it
 compiles an executable; nobody else reads those facts.
 
 The reference repo ships no kernels (attention lives in the external
@@ -1096,6 +1101,283 @@ def paged_decode_attention(q, k_pool, v_pool, tables, starts, *,
     # [B, Hkv, T*G, Dv] -> [B, T, H, Dv]
     out = out.reshape(B, Hkv, T, G, Dv).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, T, H, Dv)
+
+
+# ---------------------------------------------------------------------
+# the pool's WRITE for short windows: a row's new tokens land as ROWS.
+#
+# models/kv.append_chunk lands a chunk by gathering the blocks it
+# touches, merging and scattering them back whole: the right grain for a
+# prefill chunk, which fills most of the blocks it rewrites. A decode
+# step's ONE new token a row paid the same three or four XLA operations
+# a pool and layer, each with its launch and its gap, and moved a whole
+# [Hkv, Bs, D] block in and out for one [Hkv, D] row (PERF.md, PR 58:
+# 67 us a layer application at 16 rows x 16 heads, 12.8 of a 44 ms step
+# over 192 layer applications).
+#
+# ``append_rows`` is ONE kernel call a layer for every pool of it (K and
+# V, or the latents): no grid, the pools stay in HBM and come back as
+# the same buffers (``input_output_aliases``), the new rows come in
+# through VMEM. A window of T <= DECODE_T_MAX positions touches at most
+# two SLABS a row: a slab is the S = 8 rows of a block, all kv heads
+# ([Hkv, 8, D]), that one tile of the pool's layout IN HBM holds (a
+# tile there is 8 rows by 128 lanes for float32 and for bfloat16 alike,
+# T(8,128)(2,1): two rows a 32-bit word, four words deep), the least a
+# copy into HBM can address: the chip's compiler refuses a slice of one bfloat16 row
+# (tests/test_chip_compile.py compiles the slab for a described v5e;
+# a float32 pool's bare row it accepts: not taken, no cell has one).
+# The first build took the 16 rows a bfloat16 tile holds in VMEM and
+# moved 4 MB a call at Ouro's shapes, 7.9 us, all of it the copies
+# (PERF.md, PR 58). The kernel
+#   - reckons every (row, slab)'s block and offset from the tables and
+#     starts in SMEM, and whether any token lands there (valid, at a
+#     position in [0, MB*Bs)), and starts the copy of every such slab
+#     into VMEM, all before it waits for any;
+#   - waits for a slab, stores the window's rows into it under their
+#     mask (float32 and back, which is exact: the v5e selects no
+#     bfloat16) and starts the copy back;
+#   - waits for the copies back.
+# The three passes are ``lax.fori_loop`` over the slabs and the only
+# Python loops run over the window's T positions and the call's pools:
+# the kernel's text does not grow with rows, heads or blocks, because a
+# Pallas kernel is lowered once a call site of every executable a start
+# builds (PERF.md section 5, "WHAT setup_s IS MADE OF").
+#
+# A slab with no live token is neither read nor written: parked rows,
+# padding and window tails past capacity cost two scalar compares, and
+# trash block 0 is never touched. Two live rows never write one slab (a
+# sequence writes only blocks it owns alone), and the aliased pool
+# orders this call before the read that follows it.
+# ---------------------------------------------------------------------
+
+KV_APPEND_ROWS = "rows"
+KV_APPEND_BLOCKS = "blocks"
+# S, the rows of a block one slab holds: a tile of the pool in HBM (the
+# account above). EngineConfig takes no block that 8 does not divide
+APPEND_SLAB_ROWS = 8
+# what one call of ``append_rows`` may hold at once, every row's slabs
+# in flight: DMA semaphores (the core has 512 words of them, "sflag":
+# a call that asks for more does not compile, tests/test_chip_compile.py)
+# and bytes of VMEM for the slabs and the new rows, beside
+# _PANEL_WORK_BYTES under VMEM_LIMIT_BYTES
+_APPEND_SEMAPHORES = 256
+_APPEND_WORK_BYTES = 32 * 1024 * 1024
+
+
+def _append_slabs(T: int) -> int:
+    """K, the slabs a row's window of T <= 8 positions can touch."""
+    return 1 if T == 1 else 2
+
+
+def _append_holds(pools, rows: int, T: int) -> bool:
+    """Does one call's scratch fit the core (the two bounds above)?
+    A row holds K slabs [Hkv, 8, D] and its news [Hkv, T, D] of every
+    pool, each padded to the sublanes of a VMEM tile (8 float32, 16
+    bfloat16), and K semaphores a pool: 393 KB and 4 at 16 heads of 128
+    and a speculative window, 64 rows; one position a row, 128 rows."""
+    K = _append_slabs(T)
+    item = pools[0].dtype.itemsize
+    tile = 32 // item
+
+    def padded(n):
+        return -(-n // tile) * tile
+
+    held = sum(rows * p.shape[2] * p.shape[4] * item
+               * (K * padded(APPEND_SLAB_ROWS) + padded(T)) for p in pools)
+    return (len(pools) * rows * K <= _APPEND_SEMAPHORES
+            and held <= _APPEND_WORK_BYTES)
+
+
+def kv_append_path(pools, rows: int, T: int, mesh) -> str:
+    """How a forward of ``rows`` rows of T positions lands its new K/V
+    (or latents) in ``pools``, a layer's arrays as models/kv.append is
+    handed them ([L, N, Hkv, Bs, D] each, the int8 pool's scales
+    [L, N, Hkv, Bs] behind them; arrays or their shapes), decided here
+    by what the call can observe, as ``attention_path`` is. The ONE
+    rule: models/kv.append asks it at trace time and engine/runner
+    ``_compile`` asks it of the same four things for GET /debug/perf
+    ``device.kv_appends``.
+
+    ``rows`` (``append_rows``) for a decode or speculative window
+    (T <= DECODE_T_MAX) where the kernels run (``flash_enabled``), on
+    one device, over a pool with no scales, of a batch whose slabs one
+    call can hold in flight (``_append_holds``); ``blocks``
+    (models/kv.append_chunk's whole-block rewrite) for everything
+    else: a prefill chunk mostly fills the blocks it rewrites; the int8
+    pool's scale rows [.., Bs] are no tile a copy can address; under a
+    mesh the pool is sharded and XLA places the rewrite (a kernel over
+    it outside shard_map would gather it); a batch past the bound
+    would not compile (no cell has one: 8 to 16 rows; an operator's
+    256 keeps the executable the parent built); with the kernels off
+    (the CPU) there is no kernel to call. Where this says ``rows`` the
+    read that follows takes the decode kernel (``attention_path``: the
+    same gate, and at T <= 8 its working set holds at any head geometry
+    a cell has); the pool ends the same bytes on either."""
+    payload = [p for p in pools if len(p.shape) == 5]
+    if (T <= DECODE_T_MAX and mesh is None and len(payload) == len(pools)
+            and flash_enabled() and _append_holds(payload, rows, T)):
+        return KV_APPEND_ROWS
+    return KV_APPEND_BLOCKS
+
+
+def _append_rows_kernel(tabs_ref, starts_ref, valid_ref, layer_ref, *refs,
+                        T: int, K: int, block_size: int, npools: int):
+    """Every row's window into every pool of one layer.
+
+    tabs_ref   (SMEM) [B, MB]     block tables
+    starts_ref (SMEM) [B]         position of each row's first new token
+    valid_ref  (SMEM) [B, T]      1 where the token is real
+    layer_ref  (SMEM) [1]         the pool's layer
+    refs    npools of new [B, Hkv, T, D] (VMEM), the pools (HBM; the
+            aliased inputs, not touched), the pools again as outputs
+            (HBM) [L, N, Hkv, Bs, D]; scratch: npools of slabs
+            [B*K, Hkv, S, D], DMA semaphores [npools, B*K] and (SMEM)
+            [3, B*K]: whether slab i is live, its block and its first
+            row in the block.
+
+    Slab i = row i // K's k-th (i % K) from the one its first position
+    lies in; K = 1 for one position a row, else 2 (T <= S).
+    """
+    news = refs[:npools]
+    pools = refs[2 * npools:3 * npools]
+    slabs = refs[3 * npools:4 * npools]
+    sems, plan = refs[4 * npools:]
+    B, MB = tabs_ref.shape
+    Bs, S = block_size, APPEND_SLAB_ROWS
+    cap = MB * Bs
+    layer = layer_ref[0]
+
+    def window(i):
+        """(row, its start, the slab's first position) of slab i."""
+        b = jax.lax.div(i, K)
+        start = starts_ref[b]
+        first = (jax.lax.div(jnp.maximum(start, 0), S)
+                 + jax.lax.rem(i, K)) * S
+        return b, start, first
+
+    def lands(b, start, first, t):
+        """Is token t of row b real and inside the slab at ``first``?"""
+        p = start + t
+        return ((valid_ref[b, t] != 0) & (p >= first) & (p < first + S)
+                & (p < cap))
+
+    def copy(i, o, back: bool):
+        blk = plan[1, i]
+        at = pools[o].at[layer, blk].at[
+            :, pl.ds(pl.multiple_of(plan[2, i], S), S), :]
+        here = slabs[o].at[i]
+        return pltpu.make_async_copy(*((here, at) if back else (at, here)),
+                                     sems.at[o, i])
+
+    def fetch(i, carry):
+        b, start, first = window(i)
+        live = lands(b, start, first, 0)
+        for t in range(1, T):
+            live = live | lands(b, start, first, t)
+        plan[0, i] = live.astype(jnp.int32)
+        plan[1, i] = tabs_ref[b, jnp.minimum(jax.lax.div(first, Bs),
+                                             MB - 1)]
+        plan[2, i] = jax.lax.rem(first, Bs)
+
+        @pl.when(live)
+        def _():
+            for o in range(npools):
+                copy(i, o, back=False).start()
+        return carry
+
+    def merge(i, carry):
+        @pl.when(plan[0, i] != 0)
+        def _():
+            b, start, first = window(i)
+            # the slab row each token takes: none where it does not land
+            rows = [jnp.where(lands(b, start, first, t),
+                              start + t - first, -1) for t in range(T)]
+            for o in range(npools):
+                copy(i, o, back=False).wait()
+                slab = slabs[o][i].astype(jnp.float32)      # [Hkv, S, D]
+                new = news[o][b].astype(jnp.float32)        # [Hkv, T, D]
+                row = jax.lax.broadcasted_iota(jnp.int32, slab.shape, 1)
+                for t in range(T):
+                    slab = jnp.where(row == rows[t], new[:, t:t + 1, :],
+                                     slab)
+                slabs[o][i] = slab.astype(slabs[o].dtype)
+                copy(i, o, back=True).start()
+        return carry
+
+    def settle(i, carry):
+        @pl.when(plan[0, i] != 0)
+        def _():
+            for o in range(npools):
+                copy(i, o, back=True).wait()
+        return carry
+
+    for step in (fetch, merge, settle):
+        jax.lax.fori_loop(0, B * K, step, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def append_rows(pools, news, tables, starts, valid, layer, *,
+                interpret: bool = False):
+    """The new tokens of a short window as rows of layer ``layer`` of
+    the whole pools, in place (the account above).
+
+    pools: the layer's arrays [L, N, Hkv, Bs, D] — (K, V), or the latent
+    pool alone (Hkv 1, D its padded width); widths and heads may differ
+    between them, dtype and block size may not. news: as many [B, T,
+    Hkv, D], row b's tokens at positions starts[b] .. starts[b] + T - 1,
+    T <= DECODE_T_MAX, Bs a multiple of 8, and no more rows than
+    ``kv_append_path`` allows a call (the core's DMA semaphores). tables [B, MB] int32; valid [B, T] bool
+    or None (every token real); layer an int32 scalar (traced).
+
+    -> the pools, the same buffers. Every block a table references
+    holds on that layer what models/kv.write_chunk would leave: tokens
+    that are invalid, negative or at MB*Bs and beyond are written
+    nowhere at all.
+
+    (Jitted, as the reading kernels are, for the trace cache: a start
+    builds the decode window at nine kv buckets and every one calls
+    this at the same shapes, in a model with a layer plan at several
+    sites. The kernel is traced once a process and lowered once a
+    build, not once a call site.)"""
+    B, T = news[0].shape[:2]
+    Bs, dtype = pools[0].shape[3], pools[0].dtype
+    S, K, n = APPEND_SLAB_ROWS, _append_slabs(T), len(pools)
+    assert T <= DECODE_T_MAX and Bs % S == 0, (T, Bs)
+    assert all(p.dtype == dtype and p.shape[3] == Bs for p in pools)
+    valid = (jnp.ones((B, T), jnp.int32) if valid is None
+             else valid.astype(jnp.int32))
+    # [B, T, Hkv, D] -> [B, Hkv, T, D]: a token's row as the block's
+    # head-major panels take it
+    news = [x.astype(dtype).transpose(0, 2, 1, 3) for x in news]
+    kernel = functools.partial(_append_rows_kernel, T=T, K=K,
+                               block_size=Bs, npools=n)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * n
+            + [hbm] * n,
+            out_specs=[hbm] * n,
+            scratch_shapes=[
+                *(pltpu.VMEM((B * K, p.shape[2], S, p.shape[4]), dtype)
+                  for p in pools),
+                pltpu.SemaphoreType.DMA((n, B * K)),
+                pltpu.SMEM((3, B * K), jnp.int32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # (operands count from the scalars: 4 of them, then the news)
+        input_output_aliases={4 + n + o: o for o in range(n)},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="kv_append_rows",
+    )(jnp.asarray(tables, jnp.int32), jnp.asarray(starts, jnp.int32),
+      valid, jnp.asarray(layer, jnp.int32).reshape(1), *news, *pools)
+    return tuple(out)
 
 
 def paged_attention_sharded(q, k_pool, v_pool, tables, starts, mesh, *,

@@ -297,6 +297,66 @@ def test_tp4_sharded_wrapper_compiles(topo, T, int8, layers):
 # each; at two layers ~10 s each)
 # ---------------------------------------------------------------------
 
+@pytest.mark.parametrize("T", [1, 8], ids=["T1", "T8"])
+@pytest.mark.parametrize("B,MB,pools", [
+    (16, 32, [(8, D)] * 2),         # Mistral-7B
+    (16, 8, [(16, D)] * 2),         # Ouro-2.6B, Qwen1.5-MoE
+    (8, 256, [(10, D)] * 2),        # Phi-4-mini-flash's paired heads
+    (8, 256, [(2, D)] * 2),         # Nemotron-3-Nano
+    (16, 32, [(1, 640)]),           # the latent pool (GLM-4.7-Flash)
+    (8, 256, [(1, 640), (1, D)]),   # and the index pool beside it (GLM-5)
+], ids=["gqa_8x128", "mha_16x128", "paired_10x128", "gqa_2x128", "latent",
+        "latent_index"])
+def test_append_rows_kernel_compiles(topo, B, MB, pools, T):
+    """The decode window's write (ops/pallas_paged.append_rows) at the
+    cells' pool geometries, on the whole pool [4, N, Hkv, 64, D] with
+    the layer as an operand and the pool donated, for one position a
+    row and for a speculative window of 8: a slab of 8 bfloat16 rows
+    of every kv head (one tile of the pool in HBM) is copied out at a
+    dynamic, tile-aligned row of a dynamic block and copied back; every pool comes back
+    aliased to its argument."""
+    one = SingleDeviceSharding(topo.devices[0])
+    N = 2 * B + 1
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(pallas_paged.append_rows, donate_argnums=(0,)).lower(
+        tuple(sds((4, N, h, BS, d)) for h, d in pools),
+        tuple(sds((B, T, h, d)) for h, d in pools),
+        sds((B, MB), jnp.int32), sds((B,), jnp.int32),
+        sds((B, T), jnp.bool_), sds((), jnp.int32)).compile()
+    assert "kv_append_rows" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        4 * N * h * BS * d * 2 for h, d in pools)
+
+
+@pytest.mark.parametrize("B,T", [(128, 1), (64, 8)], ids=["B128-T1",
+                                                         "B64-T8"])
+def test_append_rows_compiles_at_the_largest_batch_the_rule_allows(
+        topo, tpu_branches, B, T):
+    """``append_rows`` holds every row's slabs in flight, a DMA
+    semaphore a slab and pool, and the core has 512 words of them
+    (``sflag``): K and V of 256 rows at one position ask for 512 and
+    the chip's compiler refuses the call. ``kv_append_path`` says
+    ``rows`` up to 256 semaphores, at Ouro's 16 heads of 128, and that
+    compiles here; past it the executable keeps the block rewrite,
+    which compiles at any batch."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pools = (sds((2, 2 * B + 1, 16, BS, D)),) * 2
+    assert pallas_paged.kv_append_path(pools, B, T, None) == "rows"
+    assert pallas_paged.kv_append_path(pools, 2 * B, T, None) == "blocks"
+    compiled = jax.jit(pallas_paged.append_rows, donate_argnums=(0,)).lower(
+        pools, (sds((B, T, 16, D)),) * 2, sds((B, MB), jnp.int32),
+        sds((B,), jnp.int32), sds((B, T), jnp.bool_),
+        sds((), jnp.int32)).compile()
+    assert "kv_append_rows" in compiled.as_text()
+
+
 @pytest.fixture
 def tpu_branches(monkeypatch):
     """The serving path asks ``jax.default_backend()`` which attention
@@ -439,18 +499,20 @@ class _StepProgram:
 def step_program(topo):
     """(model, layers, tokens, rows) -> the _StepProgram of a runner's
     decode window (``tokens`` 0) or prefill chunk of ``tokens`` positions
-    a row over a pool of 97 blocks, at the batch bucket ``rows`` (0: all
-    max_num_seqs) and the 512 kv bucket, on one described chip: lowered
+    a row over a pool of ``kv_blocks`` blocks, at the batch bucket
+    ``rows`` (0: all max_num_seqs) and the 512 kv bucket, on one
+    described chip: lowered
     and compiled once a worker, whichever test asks first
     (docs/testing.md; the persistent cache cannot keep a compile for a
     described device). Ask with ``tpu_branches`` in force."""
     made = {}
 
-    def get(model: str, layers: int, *, tokens: int = 0, rows: int = 0):
-        key = (model, layers, tokens, rows)
+    def get(model: str, layers: int, *, tokens: int = 0, rows: int = 0,
+            kv_blocks: int = 97):
+        key = (model, layers, tokens, rows, kv_blocks)
         if key not in made:
-            shapes = _runner_shapes(topo, 1, layers=layers, kv_blocks=97,
-                                    model=model)
+            shapes = _runner_shapes(topo, 1, layers=layers,
+                                    kv_blocks=kv_blocks, model=model)
             made[key] = _StepProgram(
                 _lower_prefill_chunk(*shapes, tokens, rows) if tokens
                 else _lower_decode_window(*shapes, rows))
@@ -458,12 +520,35 @@ def step_program(topo):
     return get
 
 
+# a pool no smaller than a cell's, for the tests that read what the
+# compiler does with the pool: the chip's 128 MiB of VMEM can hold a
+# pool of 97 blocks whole (12.5 MB each for K and V at two layers), and
+# XLA then stages it there around the kernels, which no serving pool
+# allows. 1201 blocks are 315 MB of keys, 265 MB of latents
+POOL_BLOCKS = 1201
+
+
+def _block_rewrites(hlo: str, minor: str) -> dict:
+    """{(result type, opcode): count} of the gathers, scatters and
+    selects in an optimised HLO (fused computations' bodies are in the
+    text) whose result ends in a pool block's ``Bs,D``: what
+    models/kv.append_chunk's whole-block rewrite compiles to."""
+    import re
+    block = re.compile(r" = \(?(\w+\[[\d,]*,{}\])\S* ([\w\-]+)\("
+                       .format(minor))
+    found = {}
+    for m in filter(None, map(block.search, hlo.splitlines())):
+        if re.search(r"gather|scatter|select", m.group(2)):
+            found[m.groups()] = found.get(m.groups(), 0) + 1
+    return found
+
+
 @pytest.mark.parametrize("program", ["decode_window", "prefill_chunk",
                                      "prefill_chunk_1row",
                                      "prefill_chunk_2rows"])
 def test_step_program_never_copies_the_pool(tpu_branches, step_program,
                                             program):
-    """Mistral head geometry, two layers, a pool of 97 blocks: in the
+    """Mistral head geometry, two layers, a pool of 1201 blocks: in the
     optimised HLO no copy, dynamic-slice or dynamic-update-slice (nor
     a fusion named for one) yields an array of the pool's shape or of
     one layer's. The pool handed through the layer scan's xs -> ys was
@@ -473,13 +558,14 @@ def test_step_program_never_copies_the_pool(tpu_branches, step_program,
     token by token it is copied whole into a token-major layout and
     back, per layer (models/kv.py: appends rewrite whole blocks)."""
     import re
-    L, N = 2, 97
+    L, N = 2, POOL_BLOCKS
     if program == "decode_window":
-        made = step_program("mistral-7b", L)
+        made = step_program("mistral-7b", L, kv_blocks=N)
     else:
         rows = {"prefill_chunk": 0, "prefill_chunk_1row": 1,
                 "prefill_chunk_2rows": 2}[program]
-        made = step_program("mistral-7b", L, tokens=128, rows=rows)
+        made = step_program("mistral-7b", L, tokens=128, rows=rows,
+                            kv_blocks=N)
     compiled, hlo = made.compiled, made.hlo
     assert "tpu_custom_call" in hlo
     # "%name = bf16[2,97,8,64,128]{layout} opcode(": a pool-shaped result
@@ -494,6 +580,15 @@ def test_step_program_never_copies_the_pool(tpu_branches, step_program,
     # and the pool is one buffer from argument to result
     pool_bytes = 2 * L * N * 8 * BS * D * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    # a decode window lands its new rows through the writer kernel (PR
+    # 58: ops/pallas_paged.append_rows): no block is gathered, merged
+    # under a mask or scattered back; a prefill chunk rewrites blocks
+    rewrites = _block_rewrites(hlo, f"{BS},{D}")
+    if program == "decode_window":
+        assert not rewrites, rewrites
+        assert "kv_append_rows" in hlo
+    else:
+        assert rewrites and "kv_append_rows" not in hlo
 
 
 # ---------------------------------------------------------------------
@@ -670,7 +765,10 @@ def test_prefill_chunk_multiplies_only_routed_rows(tpu_branches,
     path's; [E, C, ...] at any capacity, the dispatch's) and none an
     array of an expert stack's shape or of one layer's."""
     import re
-    hlo = step_program(model, layers, tokens=256, rows=rows).hlo
+    # (the latent model's chunk is the one never_copies_the_pool reads)
+    hlo = step_program(
+        model, layers, tokens=256, rows=rows,
+        kv_blocks=POOL_BLOCKS if model == "glm-4.7-flash" else 97).hlo
     assert "moe_grouped_experts" in hlo
     assert "moe_list_experts" not in hlo
     # (every expert is held: no rounds, so no sum by token, PR 53)
@@ -688,42 +786,66 @@ def _program_digest(lowered) -> str:
     Mosaic module replaced by its MLIR without debug info (the text
     carries no source locations; the serialized modules do, and those
     move with every line added to ops/)."""
-    import base64
     import hashlib
     import re
-    from jax._src.interpreters import mlir as jax_mlir
-    from jax._src.lib.mlir import ir
 
     def module_digest(m):
-        ctx = jax_mlir.make_ir_context()
-        ctx.allow_unregistered_dialects = True
-        with ctx:
-            asm = ir.Module.parse(base64.b64decode(
-                m.group(1))).operation.get_asm(enable_debug_info=False)
-        return "body: " + hashlib.sha256(asm.encode()).hexdigest()
+        return "body: " + hashlib.sha256(
+            _kernel_asm(m.group(1)).encode()).hexdigest()
 
-    text = re.sub(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', module_digest,
-                  lowered.as_text())
+    text = re.sub(_KERNEL_BODY, module_digest, lowered.as_text())
     return hashlib.sha256(text.encode()).hexdigest()
+
+
+_KERNEL_BODY = r'body\\22: \\22([A-Za-z0-9+/=]+)\\22'
+
+
+def _kernel_asm(serialized: str) -> str:
+    """A kernel's serialized Mosaic module as MLIR without debug
+    info."""
+    import base64
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return ir.Module.parse(base64.b64decode(
+            serialized)).operation.get_asm(enable_debug_info=False)
+
+
+def _kernel_bodies(lowered) -> list:
+    """(sha256, length) of the MLIR of every kernel call site of a
+    lowered program, in the text's order: what Pallas lowered to Mosaic
+    once a call site to make this build."""
+    import hashlib
+    import re
+    return [(hashlib.sha256(asm.encode()).hexdigest(), len(asm))
+            for asm in map(_kernel_asm, re.findall(_KERNEL_BODY,
+                                                   lowered.as_text()))]
 
 
 @pytest.mark.parametrize("model,layers,digest", [
     ("qwen1.5-moe-a2.7b", 2,
-     "159b1ec7338481e961752d3c1eb5691267fe2338ef8f016c98c02c8445611e74"),
+     "689cc4bdc775e502aa120c0e0a3368a34fbaea652d85a5a4a39787e6e0463e30"),
     ("glm-4.7-flash", 3,
-     "1e71969c295a9609fd8f874e28b5a6220e3bb962c87564b61e99e5381d79e1c9")],
+     "248acc4e752f7ff462b32f3405ad7d969a2406c92b5fa66061c0b0d505b57dd9")],
     ids=["qwen15moe", "glm47flash"])
 def test_moe_decode_window_lowers_to_the_pinned_text(tpu_branches,
                                                      step_program, model,
                                                      layers, digest):
     """``jit_decode_window`` of both MoE configurations (16 rows, 8
     steps, the 512 kv bucket, greedy) lowers for the described v5e to
-    the text it lowered to at commit 5d902fa, PR 38 (digests taken on
-    that tree by this test, under pytest: the suite's conftest.py
-    enters the text). PR 39 changed the prefill's experts and nothing
-    a decode step runs, so a chip run before and after differs in the
-    prefill alone. A PR that means to change the decode program pins
-    its own digests here and says so."""
+    the text it lowered to on PR 58's tree (digests taken on that tree
+    by this test, under pytest: the suite's conftest.py enters the
+    text). From commit 5d902fa, PR 38, to PR 57 the text stood (PR 39
+    changed the prefill's experts and nothing a decode step runs);
+    PR 58 MEANT to change the decode program: a layer's new K/V rows,
+    or latents, land through ``append_rows`` where the gather, select
+    and scatter of whole blocks stood, and nothing else moved (the
+    decode kernel's and the list path's bodies are the parent's: the
+    Phi-4-mini-flash test below holds the first bit for bit). A PR that
+    means to change the decode program pins its own digests here and
+    says so."""
     assert _program_digest(step_program(model, layers).lowered) == digest
 
 
@@ -735,7 +857,7 @@ def test_dense_decode_window_has_no_expert_call(tpu_branches,
     commit's, instruction for instruction, was read off both trees'
     compiles for PR 34: PERF.md.)"""
     import re
-    hlo = step_program("mistral-7b", 2).hlo
+    hlo = step_program("mistral-7b", 2, kv_blocks=POOL_BLOCKS).hlo
     calls = {m.group(1) for m in re.finditer(
         r"%([A-Za-z_]+)[\w.\-]* = \S+ custom-call\(", hlo)
         if "tpu_custom_call" in hlo}
@@ -868,16 +990,16 @@ def test_latent_decode_kernel_copies_a_block_once(topo):
                                      "prefill_chunk_1row"])
 def test_latent_step_program_never_copies_the_pool(tpu_branches,
                                                    step_program, program):
-    """never_copies_the_pool for the latent pool [3, 97, 1, 64, 576]:
+    """never_copies_the_pool for the latent pool [3, 1201, 1, 64, 576]:
     the leading dense layer outside the scan and the scanned expert
     layers append to and read ONE carried buffer."""
     import re
-    L, N = 3, 97
+    L, N = 3, POOL_BLOCKS
     if program == "decode_window":
-        made = step_program("glm-4.7-flash", L)
+        made = step_program("glm-4.7-flash", L, kv_blocks=N)
     else:
         made = step_program(
-            "glm-4.7-flash", L, tokens=256,
+            "glm-4.7-flash", L, tokens=256, kv_blocks=N,
             rows={"prefill_chunk": 0, "prefill_chunk_1row": 1}[program])
     compiled, hlo = made.compiled, made.hlo
     assert "tpu_custom_call" in hlo
@@ -891,6 +1013,12 @@ def test_latent_step_program_never_copies_the_pool(tpu_branches,
     assert not moved, moved
     assert (compiled.memory_analysis().alias_size_in_bytes
             >= L * N * BS * GLM["W"] * 2)
+    rewrites = _block_rewrites(hlo, f"{BS},{GLM['W']}")
+    if program == "decode_window":      # the latents land as rows too
+        assert not rewrites, rewrites
+        assert "kv_append_rows" in hlo
+    else:
+        assert rewrites and "kv_append_rows" not in hlo
 
 
 def test_latent_decode_window_makes_no_key_or_value_per_head(
@@ -902,7 +1030,7 @@ def test_latent_decode_window_makes_no_key_or_value_per_head(
     dimensions are [20, 256] / [20, 192] / [20, 448] over the batch's
     16 rows and a context axis (>= one block of 64 tokens)."""
     import re
-    hlo = step_program("glm-4.7-flash", 3).hlo
+    hlo = step_program("glm-4.7-flash", 3, kv_blocks=POOL_BLOCKS).hlo
     assert "%paged_decode_attention" in hlo
     assert "moe_list_experts" in hlo
     def leading(m):
@@ -1501,6 +1629,68 @@ def test_plan_step_program_compiles_at_phi4flash_widths(
     assert (compiled.memory_analysis().alias_size_in_bytes
             >= 2 * 9 * N * 10 * BS * 128 * 2 + 9 * 9 * 16 * 5120 * 4)
     _fits(compiled, f"phi4flash whole {program}")
+
+
+# the kernels of Phi-4-mini-flash's decode window at the parent of PR 58
+# (commit 5c6c63b; sha256 of each body's MLIR without debug info, taken
+# by _kernel_bodies on that tree): the Mamba step (two call sites), the
+# decode kernel as the window layers call it and as the full layer and
+# the cross readers call it. 473 962 characters of MLIR in four sites
+PHI4_DECODE_BODIES = {
+    "mamba_recurrent_step":
+    "ab7863d1c195921b",
+    "paged_decode_attention, a window of 512":
+    "8ca008f073d5f507",
+    "paged_decode_attention, full causal":
+    "bf1c46efc272dc6c",
+}
+PHI4_DECODE_BODY_CHARS = 473962
+PHI4_PREFILL_DIGEST = \
+    "5dc59cff617ee7f71813daf3159058e598600cb03da2d5195dcf21429f38b057"
+
+
+def test_phi4flash_decode_build_lowers_the_parents_kernels_and_two_writers(
+        topo, tpu_branches, monkeypatch):
+    """What refused PR 57, held without the chip. A Pallas kernel is
+    lowered to Mosaic once a CALL SITE of every executable a start
+    builds, warm or cold, and Phi-4-mini-flash's start builds nine
+    decode windows: PR 57 wrote the K/V append into the decode kernel's
+    body, unrolled over heads and blocks, each of the nine lowered 1.3 s
+    longer and the cell's ``setup_s`` rose 13 % (PERF.md section 6).
+    Here the decode window of the whole model (8 steps, the 16 384
+    bucket) is lowered for the described v5e and its kernels are read:
+    the parent's four call sites with the parent's bodies, bit for bit
+    (a call that appends nothing, the cross readers', lowers what it
+    lowered), and TWO more, the writer (ops/pallas_paged.append_rows)
+    in the window layers and in the full layer, 47 064 characters of
+    MLIR each: a fifth of the decode kernel's, whatever the heads, rows
+    and blocks. And the 2048-token prefill chunk lowers to the parent's
+    text byte for byte. A PR that means to change these kernels pins
+    its own here and says so."""
+    runner, params, cache, rep = _phi4flash_runner(topo, monkeypatch)
+    B = runner.engine_cfg.max_num_seqs
+    a = _step_args(runner, rep, B)
+    tables = rep(runner.table_shape, jnp.int32)
+    small = (a["sampling"], a["key"], a["guide_next"], a["guide_id"],
+             a["guide_state"], a["counts"], a["seen"])
+    lowered = jax.jit(partial(runner._decode_impl, steps=8, kv_len=16384,
+                              greedy=True), donate_argnums=(1,)).lower(
+        params, cache, tables, rep((B,), jnp.int32), rep((B,), jnp.int32),
+        *small)
+    bodies = _kernel_bodies(lowered)
+    parents = set(PHI4_DECODE_BODIES.values())
+    shas = [sha[:16] for sha, _ in bodies]
+    assert set(shas) >= parents, bodies
+    writers = [sha for sha in shas if sha not in parents]
+    assert len(bodies) == 6 and len(writers) == 2, bodies
+    assert len(set(writers)) == 1, bodies
+    assert sum(n for _, n in bodies) <= PHI4_DECODE_BODY_CHARS + 100_000
+    prefill = jax.jit(partial(runner._prefill_impl, kv_len=16384),
+                      donate_argnums=(1,)).lower(
+        params, cache, tables, rep((1,), jnp.int32),
+        rep((1, 2048), jnp.int32), rep((1,), jnp.int32),
+        rep((1,), jnp.int32), *small, rep((), jnp.bool_))
+    assert _program_digest(prefill) == PHI4_PREFILL_DIGEST
 
 
 def _nemotron_runner(topo, monkeypatch):

@@ -274,9 +274,9 @@ def test_layer_plan_of_one_dense_and_three_expert_layers():
     written = []
     real = kv_pool.append
 
-    def recording(pool, k, v, tables, starts, valid, layer):
+    def recording(pool, k, v, tables, starts, valid, layer, **kw):
         written.append(int(layer))
-        return real(pool, k, v, tables, starts, valid, layer)
+        return real(pool, k, v, tables, starts, valid, layer, **kw)
 
     tokens = jnp.asarray(tokens_of()[:, :5])
     cache = kv_pool.cache_for(cfg, 5, BS, cfg.dtype)

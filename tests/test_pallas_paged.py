@@ -590,3 +590,254 @@ def test_decode_blocks_per_step_follows_the_shapes(nb, heads_kv,
         assert R == {(8, 2): 8, (16, 2): 4}.get((heads_kv, itemsize), 8)
     # wider heads or longer blocks: fewer blocks, still at least one
     assert decode_blocks_per_step(nb, 64, 256, 256, 4) == 1
+
+
+# ---------------------------------------------------------------------
+# the pool's write by rows (append_rows) against write_chunk and
+# against the whole-block rewrite (append_chunk)
+# ---------------------------------------------------------------------
+
+# the cells' pool geometries (kv heads, head dim): Mistral, Ouro,
+# Phi-4-mini-flash's paired heads, Nemotron-3-Nano, and the latent pool
+# at GLM's 576 values padded as latent_pool_width says
+M_KV, O_KV, P_KV, H_KV = (8, 128), (16, 128), (10, 128), (2, 128)
+ALL_REAL = None
+
+
+def _append_case(*, T, starts, geometry=(2, 32), Bs=16, dtype=BF16,
+                 valid=ALL_REAL, latent=False, scanned=False, MB=3,
+                 layers=3, seed=0):
+    """(pools by rows, pools by blocks, one layer by write_chunk,
+    tables, the layer): the same random pool and the same new rows
+    through ``append_rows`` (interpret mode), ``append_chunk`` and
+    ``write_chunk``. A row of ``starts`` that is PARKED stands at
+    MB*Bs. ``scanned``: the layer is the counter of a lax.scan that
+    writes EVERY layer, a traced operand as the layer loop gives it."""
+    from production_stack_tpu.models import kv as kv_pool
+    from production_stack_tpu.ops.pallas_paged import append_rows
+    Hkv, D = geometry
+    B = len(starts)
+    key = jax.random.PRNGKey(seed)
+    n_blocks = B * MB + 1
+    n = 1 if latent else 2
+    pools = tuple(
+        jax.random.normal(jax.random.fold_in(key, i),
+                          (layers, n_blocks, Hkv, Bs, D), F32).astype(dtype)
+        for i in range(n))
+    news = tuple(
+        jax.random.normal(jax.random.fold_in(key, 10 + i),
+                          (B, T, Hkv, D), F32).astype(dtype)
+        for i in range(n))
+    tables = jnp.asarray(np.asarray(jax.random.permutation(
+        jax.random.fold_in(key, 20), n_blocks - 1)).reshape(B, MB) + 1,
+        jnp.int32)
+    starts = jnp.asarray([MB * Bs if s == PARKED else s for s in starts],
+                         jnp.int32)
+    if valid is not None:
+        valid = jnp.asarray(valid, bool)
+    layer = layers - 2
+
+    def by_rows(pools, layer):
+        return append_rows(pools, news, tables, starts, valid, layer,
+                           interpret=True)
+
+    def by_blocks(pools, layer):
+        return tuple(kv_pool.append_chunk(p, x, tables, starts, valid,
+                                          layer)
+                     for p, x in zip(pools, news))
+
+    if scanned:
+        def every_layer(write):
+            return jax.jit(lambda pools: jax.lax.scan(
+                lambda c, i: (write(c, i), None), pools,
+                jnp.arange(layers))[0])(pools)
+        got, want = every_layer(by_rows), every_layer(by_blocks)
+    else:
+        got = by_rows(pools, jnp.int32(layer))
+        want = by_blocks(pools, layer)
+    positions = starts[:, None] + jnp.arange(T)[None, :]
+    written = tuple(write_chunk(p[layer], x, tables, positions, valid)
+                    for p, x in zip(pools, news))
+    return got, want, written, tables, layer
+
+
+def _same_bits(a, b) -> bool:
+    return bool(jnp.array_equal(
+        jax.lax.bitcast_convert_type(a, jnp.uint16 if a.dtype == BF16
+                                     else jnp.uint32),
+        jax.lax.bitcast_convert_type(b, jnp.uint16 if b.dtype == BF16
+                                     else jnp.uint32)))
+
+
+@pytest.mark.parametrize("case", [
+    dict(T=1, starts=[5, 40]),
+    dict(T=3, starts=[5, 17]),
+    dict(T=8, starts=[3, 33]),
+    # a window across a block boundary (two blocks a row) and across a
+    # tile boundary inside a block (two slabs of one block)
+    dict(T=3, starts=[15, 30]),
+    dict(T=8, starts=[12, 28], Bs=32),
+    dict(T=8, starts=[60, 9], Bs=64, MB=2),
+    # a row at offset 0 and one at offset Bs - 1
+    dict(T=1, starts=[16, 31]),
+    dict(T=3, starts=[32, 15]),
+    # parked rows beside a live one, and every row parked
+    dict(T=1, starts=[PARKED, 7, PARKED]),
+    dict(T=8, starts=[PARKED, PARKED]),
+    # invalid tokens inside a window; a row with none valid
+    dict(T=8, starts=[4, 20],
+         valid=[[1, 1, 0, 1, 0, 1, 1, 0], [0, 1, 1, 1, 1, 1, 1, 1]]),
+    dict(T=3, starts=[4, 20], valid=[[0, 0, 0], [1, 0, 1]]),
+    # two rows that share trash block 0: one parked, one all invalid,
+    # both routed to it by the whole-block rewrite
+    dict(T=1, starts=[PARKED, 9, 11], valid=[[1], [0], [1]]),
+    # a window whose tail runs past the row's capacity MB * Bs
+    dict(T=8, starts=[44, 2]),
+    # a position before 0 (no caller sends one; the contract names it)
+    dict(T=3, starts=[-2, 6]),
+    # float32: a tile of 8 rows; a block of 8: the slab is the block
+    dict(T=8, starts=[5, 20], dtype=F32),
+    dict(T=8, starts=[5, 14], Bs=8, dtype=F32),
+    dict(T=3, starts=[7, 3], Bs=8),
+    # the layer as a traced operand inside lax.scan, every layer written
+    dict(T=1, starts=[5, 40], scanned=True),
+    dict(T=3, starts=[15, PARKED], scanned=True),
+    # the latent pool: one array, one head of 576 values padded to 640
+    dict(T=1, starts=[5, 40], geometry=(1, 640), latent=True),
+    dict(T=4, starts=[14, PARKED], geometry=(1, 640), latent=True),
+    # the cells' geometries, block 64
+    dict(T=1, starts=[63, 64], geometry=M_KV, Bs=64, MB=2),
+    dict(T=1, starts=[100, 17], geometry=O_KV, Bs=64, MB=2),
+    dict(T=1, starts=[70, 127], geometry=P_KV, Bs=64, MB=2),
+    dict(T=3, starts=[62, 5], geometry=H_KV, Bs=64, MB=2),
+], ids=lambda c: "-".join(f"{k}{v}" for k, v in c.items()
+                          if k != "valid").replace(" ", ""))
+def test_append_rows_leaves_what_write_chunk_leaves(case):
+    """``append_rows`` (the decode window's write: a tile of the block
+    each row lands in, one kernel for K and V) against ``write_chunk``
+    and against ``append_chunk`` (the whole-block rewrite it takes the
+    place of): every block a table references holds the same BITS on
+    the written layer, every other layer is untouched, and nothing at
+    all is written for a token that is invalid, parked, negative or
+    past the row's capacity (the whole-block rewrite may leave anything
+    in trash block 0; the rows leave it as it was)."""
+    got, want, written, tables, layer = _append_case(**case)
+    live = np.unique(np.asarray(tables))
+    assert 0 not in live
+    for g, w, c in zip(got, want, written):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert _same_bits(g[:, live], w[:, live])
+        assert _same_bits(g[layer][live], c[live])
+
+
+def test_append_rows_then_read_through_cut_tables():
+    """Phi-4-mini-flash's window layers append through the FULL tables
+    and attend through tables CUT to the blocks their window touches,
+    with the starts shifted (models/llama._diff_attention): the decode
+    kernel reads, through cut tables, the same values from a pool
+    written by rows as from one written by blocks (10 pool heads of
+    128, four query heads a pool head)."""
+    from production_stack_tpu.ops.pallas_paged import paged_decode_attention
+    Bs, window = 64, 100
+    got, want, _, tables, layer = _append_case(
+        T=1, starts=[200, 130], geometry=P_KV, Bs=Bs, MB=4, layers=2)
+    starts = jnp.asarray([200, 130], jnp.int32)
+    few = 3
+    lo = jnp.maximum(starts - (window - 1), 0) // Bs
+    cut = jnp.take_along_axis(
+        tables, jnp.clip(lo[:, None] + jnp.arange(few), 0, 3), axis=1)
+    q = jax.random.normal(jax.random.PRNGKey(5), (2, 1, 40, 128), BF16)
+    outs = [paged_decode_attention(
+        q, k, v, cut, starts - lo * Bs, nb=few, interpret=True,
+        window=window, layer=jnp.int32(layer)) for k, v in (got, want)]
+    assert _same_bits(*outs)
+
+
+@pytest.mark.parametrize("T,rows,geometry,mesh,quantized,gate,want", [
+    (1, 16, O_KV, None, False, True, "rows"),
+    (8, 16, O_KV, None, False, True, "rows"),       # T = DECODE_T_MAX
+    (9, 16, O_KV, None, False, True, "blocks"),     # a prefill chunk
+    (2048, 1, O_KV, None, False, True, "blocks"),
+    (1, 16, O_KV, None, True, True, "blocks"),      # the int8 pool's scales
+    (1, 16, O_KV, "tp", False, True, "blocks"),     # a mesh
+    (1, 16, O_KV, None, False, False, "blocks"),    # the kernels off
+    # a batch whose slabs one call cannot hold in flight: K and V, one
+    # slab a row at one position, two at more (256 DMA semaphores)
+    (1, 128, O_KV, None, False, True, "rows"),
+    (1, 256, O_KV, None, False, True, "blocks"),
+    (8, 64, O_KV, None, False, True, "rows"),
+    (8, 128, M_KV, None, False, True, "blocks"),
+    # 32 heads of 128: 32 MiB of slabs and news bind before the semaphores
+    (1, 64, (32, 128), None, False, True, "rows"),
+    (1, 128, (32, 128), None, False, True, "blocks"),
+    # the latent pool alone: one array, half the semaphores a row
+    (1, 256, (1, 640), None, False, True, "rows"),
+    (8, 256, (1, 640), None, False, True, "blocks"),
+], ids=lambda v: str(v).replace(" ", ""))
+def test_kv_append_path_follows_what_the_call_observes(T, rows, geometry,
+                                                       mesh, quantized,
+                                                       gate, want):
+    """``rows`` only for a decode or speculative window, on one device,
+    over a pool without scales, with the kernels on and a batch one
+    call can hold: everything else keeps the whole-block rewrite. The
+    rule reads shapes alone (engine/runner asks it of the pool it
+    holds, models/kv.append of the traced one)."""
+    from production_stack_tpu.ops import pallas_paged
+    from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
+    Hkv, D = geometry
+    payload = jax.ShapeDtypeStruct((2, 33, Hkv, 64, D),
+                                   jnp.int8 if quantized else BF16)
+    pools = (payload,) * (1 if Hkv == 1 else 2)
+    if quantized:
+        pools += (jax.ShapeDtypeStruct((2, 33, Hkv, 64), F32),) * 2
+    pallas_paged.set_flash_enabled(gate)
+    try:
+        m = mesh and build_mesh(MeshConfig(dp=1, tp=2), jax.devices()[:2])
+        assert pallas_paged.kv_append_path(pools, rows, T, m) == want
+    finally:
+        pallas_paged.set_flash_enabled(None)
+
+
+def test_engine_greedy_stream_by_rows_matches_by_blocks(monkeypatch):
+    """One greedy stream of 40 tokens in decode windows of 4 across a
+    block boundary of 16 (and a second row beside it), float32, the
+    kernels in interpret mode: the engine whose decode windows append
+    by rows gives the stream of the engine whose every append rewrites
+    blocks (the parent's path), token for token; and ``device.
+    kv_appends`` of GET /debug/perf says which executables did which.
+    (About 15 s: two engines, 80 tokens each through the interpreter.)"""
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.engine.engine import LLMEngine
+    from production_stack_tpu.engine.scheduler import SamplingOptions
+    from production_stack_tpu.ops import pallas_paged
+
+    def run(rows: bool):
+        with monkeypatch.context() as mp:
+            mp.setattr(pallas_paged, "_override", True)
+            if not rows:
+                mp.setattr(pallas_paged, "kv_append_path",
+                           lambda *a, **kw: pallas_paged.KV_APPEND_BLOCKS)
+            eng = LLMEngine(EngineConfig(
+                model="debug-tiny", dtype="float32", max_num_seqs=2,
+                decode_window=4, max_model_len=128, prefill_chunk=32,
+                prefill_buckets=(16, 32), kv_block_size=16))
+            opts = SamplingOptions(temperature=0.0, max_tokens=40,
+                                   ignore_eos=True)
+            sids = [eng.add_request(list(range(3, 3 + n)), opts)
+                    for n in (10, 21)]
+            done, steps = set(), 0
+            while len(done) < len(sids):
+                done |= {o.seq_id for o in eng.step() if o.finished}
+                steps += 1
+                assert steps < 500
+            device = eng.device_report()
+            # keyed as the attention paths are, executable by executable
+            assert set(device["kv_appends"]) == set(
+                device["attention_paths"])
+            return ([list(eng.seqs[s].output_tokens) for s in sids],
+                    device["kv_appends"])
+
+    (by_rows, appends), (by_blocks, _) = run(True), run(False)
+    assert by_rows == by_blocks and [len(t) for t in by_rows] == [40, 40]
+    kinds = {k.split("|")[0]: v for k, v in appends.items()}
+    assert kinds == {"decode": "rows", "prefill": "blocks"}
